@@ -66,10 +66,13 @@ class ModelConfig:
     # oracle for tests)
     moe_impl: str = "dispatch"
     moe_capacity_factor: float = 2.0
-    # decode attention impl: "auto" (Pallas kernel on TPU, XLA gather
-    # elsewhere), "on", "off", "interpret" (kernel in interpreter mode, for
-    # CPU tests). On multi-device meshes the kernel runs under shard_map
-    # over the "tp" axis (ops/paged_attention.py decode_paged_attention_sharded).
+    # decode attention impl: "auto" and "off" are the XLA gather path on
+    # every platform (models/llama._decode_kernel_mode says why); "on" is
+    # the compiled Pallas kernel and raises at engine construction where it
+    # cannot serve (geometry, mesh, soft-caps); "interpret" runs the kernel
+    # in interpreter mode for CPU tests. On multi-device meshes the kernel
+    # runs under shard_map over the "tp" axis (ops/paged_attention.py
+    # decode_paged_attention_sharded).
     decode_kernel: str = "auto"
     # Multimodal (Qwen2-VL-style); None means text-only.
     vision: Optional["VisionConfig"] = None

@@ -91,6 +91,11 @@ class NativeEngine:
                                  "configs over the ep axis instead")
             if engine_cfg.sp > 1:
                 raise ValueError("pp and sp (ring attention) do not compose")
+            if model_cfg.decode_kernel == "on":
+                raise ValueError(
+                    "decode_kernel='on' cannot be served on a pp mesh (the "
+                    "Pallas kernel does not run under the pp shard_map); "
+                    "use decode_kernel='auto'")
             model_cfg = dataclasses.replace(model_cfg, decode_kernel="off")
             if engine_cfg.max_slots % self.pp:
                 # decode slot-groups are the pipeline microbatches, so the
@@ -106,30 +111,28 @@ class NativeEngine:
                 engine_cfg = dataclasses.replace(
                     engine_cfg, max_slots=rounded)
         # the compiled kernel has hard constraints the XLA gather path
-        # doesn't: a lane-aligned DMA geometry (ops/paged_attention.py
+        # doesn't: a tile-aligned DMA geometry (ops/paged_attention.py
         # kernel_supported) and, under shard_map, tp dividing the head
-        # counts. Fall back with the reason named rather than failing at
-        # first decode compile. (The q block is grouped [S, Hkv, G, hd] so
-        # any per-shard G compiles — no >=8-head minimum anymore.)
+        # counts. decode_kernel="on" is an explicit request: refuse it
+        # here, by name, rather than serve another path under its label
+        # or die at the first decode compile. (_decode_kernel_mode itself
+        # raises for models whose soft-caps / sliding windows / query
+        # scaling the kernel has no hooks for.)
         tp = self.mesh.shape.get("tp", 1)
         if llama._decode_kernel_mode(model_cfg) == "tpu":
             from dynamo_tpu.ops.paged_attention import kernel_supported
             h, hkv = model_cfg.num_heads, model_cfg.num_kv_heads
-            reason = None
             if not kernel_supported(model_cfg.head_dim,
                                     engine_cfg.page_size):
-                reason = (f"no lane-aligned DMA path for head_dim="
-                          f"{model_cfg.head_dim}, page_size="
-                          f"{engine_cfg.page_size}")
-            elif self.mesh.size > 1 and (h % tp or hkv % tp):
-                reason = (f"num_heads={h} / num_kv_heads={hkv} not "
-                          f"divisible by tp={tp}")
-            if reason:
-                logging.getLogger(__name__).warning(
-                    "decode kernel disabled on this mesh: %s; "
-                    "using the XLA gather path", reason)
-                model_cfg = dataclasses.replace(model_cfg,
-                                                decode_kernel="off")
+                raise ValueError(
+                    f"decode_kernel='on': no tile-aligned DMA path for "
+                    f"head_dim={model_cfg.head_dim}, page_size="
+                    f"{engine_cfg.page_size}; use decode_kernel='auto'")
+            if self.mesh.size > 1 and (h % tp or hkv % tp):
+                raise ValueError(
+                    f"decode_kernel='on': num_heads={h} / num_kv_heads="
+                    f"{hkv} not divisible by tp={tp}; use "
+                    f"decode_kernel='auto'")
         self.model_cfg = model_cfg
         self.cfg = engine_cfg
         self.eos_token_ids = set(eos_token_ids or ())
@@ -265,26 +268,22 @@ class NativeEngine:
             is_leaf=lambda x: isinstance(x, P),
         )
         if params is None:
-            # random init runs UNSHARDED, then device_puts onto the mesh:
-            # with jax_threefry_partitionable=False (this jax build's
-            # default) the RNG bit stream depends on how jit shards the
-            # draw, so init-with-out_shardings produced DIFFERENT weights
-            # on a tp-sharded mesh than on one device — every mesh-vs-
-            # oracle parity test compares engines seeded identically, so
-            # init values must be mesh-invariant. device_put preserves
-            # values exactly; the transient single-device full tree is
-            # fine at random-init scale (checkpoint loads take the
-            # params=... path and never hit this).
+            # random init lands SHARDED (out_shardings): no device ever
+            # holds the whole tree, so llama3-8b --tp 4 asks each 16 GB
+            # chip for its quarter only. jax_threefry_partitionable is on
+            # in the installed JAX, so the drawn values do not depend on
+            # the sharding — every mesh-vs-oracle parity test compares
+            # engines seeded identically and needs init to be
+            # mesh-invariant.
             if model_cfg.quant == "int8":
-                def init_q(key):
+                def init_fn(key):
                     return quantize_params(
                         llama.init_params(key, model_cfg), model_cfg)
-                init = jax.jit(init_q)
             else:
-                init = jax.jit(
-                    functools.partial(llama.init_params, cfg=model_cfg))
-            params = jax.device_put(init(jax.random.PRNGKey(seed)),
-                                    shardings)
+                init_fn = functools.partial(llama.init_params,
+                                            cfg=model_cfg)
+            params = jax.jit(init_fn, out_shardings=shardings)(
+                jax.random.PRNGKey(seed))
         else:
             if model_cfg.quant == "int8":
                 from dynamo_tpu.ops.quant import is_quantized
@@ -512,6 +511,26 @@ class NativeEngine:
         out = np.asarray(jax.device_get(
             self._encode_fn(self.params["vision"], jnp.asarray(pixels))))
         return out[0] if single else out
+
+    def device_info(self) -> dict:
+        """Where this engine runs, for the launchers' READY lines
+        (utils/launch.device_tag): platform and device_kind as JAX reports
+        them, the mesh's device ids and non-trivial axes, and — where the
+        backend reports it — each mesh device's peak HBM bytes so far.
+        After construction that is the weight + cache footprint, which
+        shows whether random init staged the model through one device."""
+        devices = list(self.mesh.devices.flat)
+        info = {
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "devices": [d.id for d in devices],
+            "mesh": {a: n for a, n in self.mesh.shape.items() if n > 1},
+        }
+        stats = [d.memory_stats() for d in devices]
+        if all(s and "peak_bytes_in_use" in s for s in stats):
+            info["peak_bytes_in_use"] = [int(s["peak_bytes_in_use"])
+                                         for s in stats]
+        return info
 
     @property
     def cache_sharding(self) -> NamedSharding:

@@ -52,31 +52,33 @@ def _decode_kernel_mode(cfg: ModelConfig) -> Optional[str]:
     kernel runs under shard_map over "tp" (auto-sharded jit cannot
     partition a pallas_call).
 
-    "auto" now resolves to the GATHER path everywhere: measured on v5e
-    (llama3-1b, batch 8, kv~300-600, the pre-unification kernel trio), the
-    deferred-write gather decode runs 7.5 ms/step vs 34 ms for the Pallas
-    kernel — per-(seq, head, page) small dots ([G<=8, 128] x [rows, 128])
-    are fixed-overhead bound on the MXU, while the gather path's single
-    big einsum amortizes. The ragged kernel walks the same pages with the
-    same dot shapes (grid (s,) instead of (s, hkv)), so the verdict is
-    expected to hold until the BENCH_SELF_r18_ragged_tpu ladder item
-    re-measures it; the kernel stays available ("on") for geometries where
-    gathered-KV HBM traffic dominates (very long contexts with large page
-    buckets), and "interpret" remains the CPU test path exercising the
-    kernel code."""
+    "auto" IS the gather path, on every platform: the one on-chip
+    comparison (llama3-1b, batch 8, kv~300-600, the kernels the ragged
+    one replaced) had the deferred-write gather decode at 7.5 ms/step
+    against 34 ms for Pallas — per-(seq, head, page) dots of
+    [G<=8, 128] x [rows, 128] are fixed-overhead bound on the MXU, while
+    the gather path's single big einsum amortizes. The ragged kernel
+    walks the same pages with the same dot shapes and has not been timed
+    on a chip (chip_smoke.py's kernel phase checks that it compiles and
+    agrees with the gather path, not how fast it is). "on" requests the
+    compiled kernel and raises where it cannot serve; "interpret" is the
+    CPU test path exercising the kernel code."""
     mode = cfg.decode_kernel
     if mode in ("off", "auto"):
         return None
     if cfg.attn_softcap or cfg.sliding_window or cfg.query_scale:
         # Gemma-2 logit soft-caps / sliding windows live only in the
-        # gather paths; the Pallas kernel has no hook for them. Name the
-        # fallback when the kernel was explicitly requested (the engine's
-        # convention: silent fallbacks get misattributed).
+        # gather paths; the Pallas kernel has no hook for them
+        if mode == "on":
+            raise ValueError(
+                "decode_kernel='on' requested but the model uses "
+                "soft-caps/sliding windows/query scaling the Pallas kernel "
+                "has no hooks for; use decode_kernel='auto'")
         import logging
         logging.getLogger(__name__).warning(
-            "decode_kernel=%r requested but the model uses "
-            "soft-caps/sliding windows/query scaling the Pallas kernel "
-            "has no hooks for; using the XLA gather path", mode)
+            "decode_kernel=%r: the model's soft-caps/sliding windows/query "
+            "scaling have no hooks in the Pallas kernel; using the XLA "
+            "gather path", mode)
         return None
     if mode == "interpret":
         return "interpret"

@@ -43,7 +43,6 @@ from dynamo_tpu.models.llama import (
 from dynamo_tpu.ops.attention import (
     _softcap, paged_attention, write_kv_pages, write_kv_pages_quant,
 )
-from dynamo_tpu.parallel.mesh import shard_map_compat
 
 
 def pp_param_shardings(cfg: ModelConfig) -> Params:
@@ -279,8 +278,8 @@ def pp_forward(
         # mm prefill batch is small (one image-bearing request per chunk)
         in_specs = in_specs + (P(), P())
         args = args + (input_embeds, embeds_mask)
-    specs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    out = shard_map_compat(fwd, **specs)(*args)
+    out = jax.shard_map(fwd, mesh=mesh, in_specs=in_specs,
+                        out_specs=out_specs, check_vma=False)(*args)
     if kvq:
         logits, kc, vc, ksc, vsc = out
         return logits, {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc}
@@ -468,8 +467,8 @@ def pp_decode_window(
     if wnds is not None:
         in_specs = in_specs + (P("pp"),)
         args = args + (wnds,)
-    out = shard_map_compat(
-        fwd, mesh=mesh, in_specs=in_specs, out_specs=out_specs)(*args)
+    out = jax.shard_map(fwd, mesh=mesh, in_specs=in_specs,
+                        out_specs=out_specs, check_vma=False)(*args)
     if kvq:
         out_toks, kc, vc, ksc, vsc = out
         new_cache = {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc}

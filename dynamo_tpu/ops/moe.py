@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from dynamo_tpu.ops.quant import is_quantized, qspec, wmat
-from dynamo_tpu.parallel.mesh import shard_map_compat
 
 
 def moe_dispatch_mlp(x: jax.Array, lp, cfg, capacity_factor: float = 2.0,
@@ -180,7 +179,7 @@ def moe_dispatch_mlp_sharded(x, lp, cfg, mesh, capacity_factor: float = 2.0,
                   wspec(P("ep", "tp", None), lp["w_down"]), P("dp")),
         out_specs=(P("dp"), P(), P()),
     )
-    f = shard_map_compat(body, **specs)
+    f = jax.shard_map(body, check_vma=False, **specs)
     out, dropped, routed = f(x, lp["router"], lp["w_gate"], lp["w_up"],
                              lp["w_down"], valid_in)
     if return_dropped:

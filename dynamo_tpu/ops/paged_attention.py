@@ -61,7 +61,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dynamo_tpu.parallel.mesh import shard_map_compat
 
 NEG_INF = -1e30
 
@@ -69,8 +68,13 @@ NEG_INF = -1e30
 def kernel_supported(head_dim: int, page_size: int) -> bool:
     """Whether the compiled (non-interpret) kernel has a lane-aligned path
     for this geometry: hd a multiple of 128 (pack=1 direct DMA) or hd < 128
-    with 128 % hd == 0 and ps % (128//hd) == 0 (packed DMA). Callers gate to
-    the XLA fallback otherwise instead of dying at Mosaic compile."""
+    with 128 % hd == 0 and ps % (128//hd) == 0 (packed DMA). The engine
+    refuses decode_kernel="on" otherwise instead of dying at Mosaic
+    compile. Lanes are the only constraint: page blocks shorter than the
+    dtype's sublane tile (16 rows bf16, 32 rows int8) compile and agree
+    with the gather path on a v5e — chip_smoke.py's kernel phase runs the
+    8-row block (page 16 x hd 64) beside the two registry geometries, bf16
+    and int8, with libtpu 0.0.34."""
     if head_dim >= 128:
         return head_dim % 128 == 0
     return 128 % head_dim == 0 and page_size % (128 // head_dim) == 0
@@ -187,15 +191,15 @@ def _ragged_decode_kernel(ps: int, hkv: int, g: int, hd: int, pack: int,
             v = v_buf[slot, j].astype(jnp.float32)
             k = jnp.where(tail_ok, k, 0.0)
             v = jnp.where(tail_ok, v, 0.0)
-            scores = []
+            scores, live = [], []
             for pk in range(pack):
                 sc = jax.lax.dot_general(
                     q_shifts[j][pk], k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)  # [G, rows]
                 if quant:
                     sc = sc * sk_ref[0, j, pl.ds(i * pack + pk, 1)]
-                pos = i * ps + row * pack + pk
-                scores.append(jnp.where(pos < length, sc, NEG_INF))
+                live.append(i * ps + row * pack + pk < length)
+                scores.append(jnp.where(live[pk], sc, NEG_INF))
             m_new = ms[j]
             for sc in scores:
                 m_new = jnp.maximum(m_new,
@@ -206,8 +210,11 @@ def _ragged_decode_kernel(ps: int, hkv: int, g: int, hd: int, pack: int,
             for pk in range(pack):
                 p = jnp.exp(scores[pk] - m_new)          # [G, rows]
                 l_new = l_new + jnp.sum(p, axis=-1, keepdims=True)
-                pv = (p * sv_ref[0, j, pl.ds(i * pack + pk, 1)] if quant
-                      else p)                            # V dequant fold
+                # V dequant fold; a stale slot's scale is as arbitrary
+                # as its values (p == 0 there does not survive a NaN)
+                pv = (p * jnp.where(
+                    live[pk], sv_ref[0, j, pl.ds(i * pack + pk, 1)], 0.0)
+                    if quant else p)
                 contrib = jax.lax.dot_general(
                     pv, v, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)  # [G, W]
@@ -382,16 +389,16 @@ def decode_paged_attention_prefix_sharded(
             return decode_paged_attention_prefix(
                 q, kc, vc, lyr, pt, lens, interpret=interpret,
                 k_scale=ks, v_scale=vs)
-        f = shard_map_compat(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
+        f = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False)
         return f(q, k_cache, v_cache, layer, page_table, prefix_lens,
                  k_scale, v_scale)
 
     def body(q, kc, vc, lyr, pt, lens):
         return decode_paged_attention_prefix(q, kc, vc, lyr, pt, lens,
                                              interpret=interpret)
-    f = shard_map_compat(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)
+    f = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False)
     return f(q, k_cache, v_cache, layer, page_table, prefix_lens)
 
 
@@ -456,15 +463,16 @@ def decode_paged_attention_sharded(
     in_specs = (head_spec, cache_spec, cache_spec, P(None, None), P(None))
     if k_scale is not None:
         scale_spec = P("tp", None, None)
-        f = shard_map_compat(
+        f = jax.shard_map(
             functools.partial(_decode_local_quant, interpret), mesh=mesh,
             in_specs=in_specs + (scale_spec, scale_spec),
-            out_specs=head_spec)
+            out_specs=head_spec, check_vma=False)
         return f(q, k_cache, v_cache, page_table, kv_lens, k_scale, v_scale)
-    # pallas_call output has no varying-mesh-axis annotation; the compat
-    # shim disables the VMA/rep check
-    f = shard_map_compat(functools.partial(_decode_local, interpret),
-                         mesh=mesh, in_specs=in_specs, out_specs=head_spec)
+    # pallas_call output has no varying-mesh-axis annotation, hence
+    # check_vma=False
+    f = jax.shard_map(functools.partial(_decode_local, interpret),
+                      mesh=mesh, in_specs=in_specs, out_specs=head_spec,
+                      check_vma=False)
     return f(q, k_cache, v_cache, page_table, kv_lens)
 
 

@@ -23,11 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
-
 NEG_INF = -1e30
 
 
@@ -61,10 +56,12 @@ def _ring_local(axis: str, n: int, q, k, v, qpos, kpos):
 
     # mark the fresh accumulators as device-varying over the ring axis so
     # the fori_loop carry types stay consistent (shard_map VMA tracking)
-    pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-    m = pvary(jnp.full((b, hkv, g, tq, 1), NEG_INF, jnp.float32), (axis,))
-    l = pvary(jnp.zeros((b, hkv, g, tq, 1), jnp.float32), (axis,))
-    acc = pvary(jnp.zeros((b, hkv, g, tq, hd), jnp.float32), (axis,))
+    def varying(x):
+        return jax.lax.pcast(x, (axis,), to="varying")
+
+    m = varying(jnp.full((b, hkv, g, tq, 1), NEG_INF, jnp.float32))
+    l = varying(jnp.zeros((b, hkv, g, tq, 1), jnp.float32))
+    acc = varying(jnp.zeros((b, hkv, g, tq, hd), jnp.float32))
 
     def step(i, carry):
         k_c, v_c, kpos_c, m, l, acc = carry
@@ -96,7 +93,7 @@ def ring_attention(
     n = mesh.shape[axis]
     seq = P(None, axis, None, None)
     pos = P(None, axis)
-    f = shard_map(
+    f = jax.shard_map(
         functools.partial(_ring_local, axis, n),
         mesh=mesh,
         in_specs=(seq, seq, seq, pos, pos),

@@ -24,20 +24,6 @@ from jax.sharding import Mesh
 AXES = ("dp", "pp", "ep", "sp", "tp")
 
 
-def shard_map_compat(body, **specs):
-    """shard_map across jax versions: the replication-check kwarg renamed
-    check_rep -> check_vma around jax 0.7. Single shim so every kernel/op
-    call site stays in lockstep (code-review r3 finding)."""
-    try:
-        from jax import shard_map as _sm
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map as _sm
-    try:
-        return _sm(body, check_vma=False, **specs)
-    except TypeError:
-        return _sm(body, check_rep=False, **specs)
-
-
 def make_mesh(
     dp: int = 1, tp: int = 1, pp: int = 1, ep: int = 1, sp: int = 1,
     devices: Optional[Sequence[jax.Device]] = None,

@@ -40,6 +40,7 @@ from dynamo_tpu.llm.worker import (
 )
 from dynamo_tpu.protocols.openai import ChatCompletionRequest
 from dynamo_tpu.runtime.engine import Context
+from dynamo_tpu.utils.launch import device_tag, enable_compile_cache
 
 log = logging.getLogger("dynamo_tpu.run")
 
@@ -65,6 +66,7 @@ async def build_engine(out_spec: str, card: ModelDeploymentCard, args):
 
     from dynamo_tpu.engine.engine import NativeEngine
     from dynamo_tpu.parallel.mesh import make_mesh
+    enable_compile_cache()
     model_cfg = card.model_config()
     if args.quant:
         import dataclasses
@@ -104,7 +106,8 @@ async def run_http(pipe: LocalPipeline, card, port: int) -> None:
     from dynamo_tpu.frontend.service import HttpService
     service = await HttpService(port=port).start()
     service.models.add(card.name, pipe, card.model_type)
-    print(f"READY http=:{service.port} model={card.name}", flush=True)
+    print(f"READY http=:{service.port} model={card.name}"
+          f"{device_tag(pipe.engine)}", flush=True)
     await asyncio.Event().wait()
 
 
@@ -174,7 +177,8 @@ async def run_endpoint(engine, card, spec: str, args) -> None:
                          model_type=card.model_type)
     from dynamo_tpu.llm.worker import install_graceful_drain
     install_graceful_drain(runtime, served)
-    print(f"READY endpoint={spec} model={card.name}", flush=True)
+    print(f"READY endpoint={spec} model={card.name}"
+          f"{device_tag(engine)}", flush=True)
     await runtime.shutdown_event.wait()
 
 
@@ -264,7 +268,8 @@ async def amain() -> None:
     elif in_spec.startswith("batch:"):
         await run_batch(pipe, card, in_spec[len("batch:"):], args.max_tokens)
     elif in_spec == "none":
-        print("READY (in=none; engine built, exiting)", flush=True)
+        print("READY (in=none; engine built, exiting)"
+              f"{device_tag(engine)}", flush=True)
     else:
         raise SystemExit(f"unknown in={in_spec!r}")
 

@@ -141,7 +141,10 @@ class _Conn:
 
     async def _op_lease_keepalive(self, m):
         lease = self.server.leases.get(m["lease"])
-        if lease is None:
+        if lease is None or lease.lost.is_set():
+            # an expired lease has already lost its keys: say so, or the
+            # holder lives on unregistered and nothing ever reports it
+            self.server.leases.pop(m["lease"], None)
             return {"ok": False}
         lease.keep_alive()
         return {"ok": True}

@@ -7,9 +7,16 @@ optionally start the control-plane server, spawn
 `python -m dynamo_tpu.sdk.run_service` per worker with per-service env
 (config JSON + chip assignment), supervise until a child dies or SIGINT.
 
+This process never initialises a JAX backend (importing the graph module
+imports jax, which is harmless; touching a device would take the chips
+from the children): a chip belongs to one process at a time. Services
+declaring resources={"tpu": n} get n of --tpu-chips each
+(sdk/allocator.py); with JAX_PLATFORMS=cpu in the environment the whole
+graph runs on the CPU instead.
+
 Usage:
-  python -m dynamo_tpu.sdk.serve my.graphs:Frontend -f config.json \
-      --start-control-plane --control-port 5550 --tpu-chips 0
+  python -m dynamo_tpu.sdk.serve my.graphs:Frontend -f config.yaml \
+      --start-control-plane --control-port 5550 --tpu-chips 4
 """
 from __future__ import annotations
 
@@ -69,7 +76,9 @@ async def amain() -> None:
     p.add_argument("--control-port", type=int, default=5550)
     p.add_argument("--start-control-plane", action="store_true")
     p.add_argument("--tpu-chips", type=int, default=0,
-                   help="chips available for resources={'tpu': n} services")
+                   help="chips on this host to hand to resources={'tpu': n} "
+                        "services; a graph that asks for more is an error "
+                        "(unless JAX_PLATFORMS=cpu runs it on the CPU)")
     args = p.parse_args()
     from dynamo_tpu.utils.logconfig import configure_logging
     configure_logging()
@@ -77,7 +86,9 @@ async def amain() -> None:
     root = resolve(args.graph)
     specs = collect_graph(root)
     cfg = load_config_file(args.config) if args.config else {}
-    alloc = ChipAllocator(args.tpu_chips)
+    alloc = ChipAllocator(
+        args.tpu_chips,
+        host_is_cpu=os.environ.get("JAX_PLATFORMS") == "cpu")
 
     procs: list = []
 

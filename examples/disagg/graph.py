@@ -13,20 +13,30 @@ Services:
                   + model registration
 - PrefillWorker   queue consumer + RemoteTransferBackend (NIXL-client role)
 
-Run (CPU demo, one command):
+Each engine-owning service declares resources={"tpu": 1}: the supervisor
+(sdk/serve.py) hands it one chip of --tpu-chips and it is the only process
+that touches that chip; the Frontend is host-only.
+
+Run on a TPU host (run on a four-chip v5e by chip_smoke.py's `disagg`
+phase — llama3-1b, one chip for the prefill engine, one for the decode
+engine):
   python -m dynamo_tpu.sdk.serve examples.disagg.graph:Frontend \
+      -f examples/disagg/config.yaml --start-control-plane --tpu-chips 2
+
+Run on the CPU (tiny model, one command; JAX_PLATFORMS=cpu is what keeps
+the engines off the chips):
+  JAX_PLATFORMS=cpu python -m dynamo_tpu.sdk.serve \
+      examples.disagg.graph:Frontend \
       -f examples/disagg/config.cpu.yaml --start-control-plane
 
 then:
   curl -N localhost:8099/v1/chat/completions -H 'Content-Type: application/json' \
     -d '{"model": "tiny", "stream": true, "max_tokens": 16, \
          "messages": [{"role": "user", "content": "hello"}]}'
-
-`config.yaml` carries the reference's canonical values (llama3-8b-class
-model, KV block 64, max_model_len 16384 — examples/llm/configs/
-disagg_router.yaml) for a real TPU deployment.
 """
 from __future__ import annotations
+
+import asyncio
 
 from dynamo_tpu.disagg import (
     DisaggDecodeWorker, DisaggregatedRouter, KvTransferServer, PrefillQueue,
@@ -46,7 +56,10 @@ NS = "dynamo-demo"
 
 
 def _build(cfg: dict):
-    """Model card + engine from one service's config section."""
+    """Model card + engine from one service's config section. Callers run
+    it in a thread: on a chip it takes tens of seconds (backend start-up,
+    weight init), and the service's event loop must keep renewing the
+    runtime's 10 s lease meanwhile."""
     card = build_card(cfg.get("model", "tiny"))
     model_cfg = card.model_config()
     max_len = int(cfg.get("max_model_len",
@@ -66,7 +79,8 @@ def _build(cfg: dict):
     return card, engine
 
 
-@service(name="PrefillWorker", namespace=NS, component="prefill")
+@service(name="PrefillWorker", namespace=NS, component="prefill",
+         resources={"tpu": 1})
 class PrefillWorker:
     """Prefill engine consuming the durable queue; ships KV pages to the
     decode workers over the remote transfer plane."""
@@ -74,16 +88,18 @@ class PrefillWorker:
     @async_on_start
     async def boot(self):
         cfg = ServiceConfig.global_instance().for_service("PrefillWorker")
-        card, engine = _build(cfg)
+        # kept as self.engine: run_service's READY line names its devices
+        card, self.engine = await asyncio.to_thread(_build, cfg)
         queue = PrefillQueue(self.runtime.messaging, NS, card.name)
         transfer = RemoteTransferBackend(self.runtime.kv)
         self.worker = await QueuePrefillWorker(
-            NativeEngineWorker(engine), queue, transfer,
+            NativeEngineWorker(self.engine), queue, transfer,
             self.runtime.messaging,
             max_inflight=int(cfg.get("max_inflight", 4))).start()
 
 
-@service(name="DecodeWorker", namespace=NS, component="backend")
+@service(name="DecodeWorker", namespace=NS, component="backend",
+         resources={"tpu": 1})
 class DecodeWorker:
     """Decode engine with conditional remote prefill + KV-injection server."""
 
@@ -92,7 +108,7 @@ class DecodeWorker:
     @async_on_start
     async def boot(self):
         cfg = ServiceConfig.global_instance().for_service("DecodeWorker")
-        card, engine = _build(cfg)
+        card, self.engine = await asyncio.to_thread(_build, cfg)
         queue = PrefillQueue(self.runtime.messaging, NS, card.name)
         router = DisaggregatedRouter(
             # reference example values: threshold 10, queue gate 2
@@ -104,7 +120,7 @@ class DecodeWorker:
             model=card.name)
         router.start_watching(self.runtime.kv)
         worker = DisaggDecodeWorker(
-            engine, self.runtime.messaging, router, queue,
+            self.engine, self.runtime.messaging, router, queue,
             worker_id=f"decode-{self.runtime.worker_id}",
             prefill_timeout_s=float(cfg.get("prefill_timeout_s", 120.0)))
         await worker.start()

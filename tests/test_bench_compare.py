@@ -1,9 +1,7 @@
-"""Bench regression gate (tools/bench_compare.py + bench.trajectory_row).
-
-Tier-1 runs the gate over the COMMITTED artifacts (BENCH_TRAJECTORY.jsonl
-vs BASELINE.json gates) — a regression landing in the trajectory turns
-the suite red — plus unit coverage of the skip/tolerance/exit-code
-semantics on synthetic trajectories.
+"""Bench regression gate (tools/bench_compare.py + bench.trajectory_row):
+unit coverage of the skip/tolerance/exit-code semantics on synthetic
+trajectories. (No trajectory is committed: the only rows there ever were
+came from the `tiny` model on the CPU backend.)
 """
 import json
 import os
@@ -13,9 +11,6 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO_ROOT, "tools"))
 
 import bench_compare  # noqa: E402
-
-TRAJ = os.path.join(REPO_ROOT, "BENCH_TRAJECTORY.jsonl")
-BASE = os.path.join(REPO_ROOT, "BASELINE.json")
 
 
 def _write(tmp_path, rows, gates=None):
@@ -29,19 +24,6 @@ def _write(tmp_path, rows, gates=None):
 def _row(value, run_id="r1", metric="m", extras=None):
     return {"run_id": run_id, "metric": metric, "value": value,
             "unit": "tok/s", "extras": extras or {}}
-
-
-def test_committed_trajectory_passes_the_gate():
-    """THE tier-1 gate: the committed trajectory vs BASELINE.json."""
-    rc = bench_compare.main(["--trajectory", TRAJ, "--baseline", BASE,
-                             "--quiet"])
-    assert rc == 0
-    report = bench_compare.compare(TRAJ, BASE)
-    assert report["ok"]
-    # the failed TPU-window captures (value 0 / extras.failure) were
-    # skipped as non-measurements, not scored as regressions
-    assert report["skipped_failed_captures"] >= 3
-    assert report["results"][0]["source"] == "baseline"
 
 
 def test_regression_beyond_tolerance_exits_nonzero(tmp_path):
@@ -62,7 +44,7 @@ def test_failed_capture_after_good_row_does_not_regress(tmp_path):
     gates = {"m": {"baseline": 100.0, "rel_tolerance": 0.25}}
     traj, base = _write(tmp_path, [
         _row(110.0, "good"),
-        _row(0.0, "tunnel_down", extras={"failure": "no TPU"}),
+        _row(0.0, "no_tpu", extras={"failure": "no TPU"}),
     ], gates)
     assert bench_compare.main(["--trajectory", traj, "--baseline", base,
                                "--quiet"]) == 0
@@ -103,7 +85,7 @@ def test_trajectory_row_normalization():
         {"metric": "m", "value": 81.33, "unit": "tok/s",
          "vs_baseline": 0.08,
          "extras": {"failure": "x", "quant": "int8",
-                    "tunnel_probes": ["dropped"], "huge": "dropped"}},
+                    "probes": ["dropped"], "huge": "dropped"}},
         run_id="r9")
     assert row["run_id"] == "r9"
     assert row["value"] == 81.33
@@ -117,4 +99,4 @@ def test_gated_metric_with_no_measured_row_is_surfaced(tmp_path):
     report = bench_compare.compare(traj, base)
     skipped = [r for r in report["results"] if r["status"] == "skipped"]
     assert any(r["metric"] == "ghost" for r in skipped)
-    assert report["ok"]   # surfaced, not failed (the tunnel owns it)
+    assert report["ok"]   # surfaced, not failed (nothing measured it)

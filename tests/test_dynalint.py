@@ -2058,7 +2058,7 @@ def test_r21_live_on_async_control_plane():
 # -- jaxpr invariants ----------------------------------------------------------
 
 def test_j1_flags_float64_leak():
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         found = trace_and_audit(
             "j1pos", lambda x: jnp.asarray(np.float64(2.0)) * x,
             jnp.zeros((4,), jnp.float32))

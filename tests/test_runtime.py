@@ -204,6 +204,32 @@ def test_lease_expiry_prunes_instances():
     run(main())
 
 
+def test_expired_lease_is_reported_to_its_holder_over_tcp():
+    """A holder whose event loop stalls past the TTL (a synchronous XLA
+    compile on a chip) loses its keys server-side; its next keepalive must
+    say so. It used to answer ok forever: the worker lived on unregistered
+    and nothing reported it (PR 21, disagg graph on a v5e)."""
+    async def main():
+        server = await ControlPlaneServer(port=0).start()
+        try:
+            rt = await DistributedRuntime.connect("127.0.0.1", server.port)
+            kv = rt.kv
+            lease = await kv.grant_lease(ttl=0.3)
+            await kv.put("ns/components/c/gen:w", b"{}", lease.id)
+            # the stall: no heartbeat leaves this client for > TTL
+            kv._keepalive_tasks.pop(lease.id).cancel()
+            await asyncio.sleep(0.6)
+            assert await kv.get("ns/components/c/gen:w") is None
+            reply = await kv._rpc({"op": "lease_keepalive",
+                                   "lease": lease.id})
+            assert reply["ok"] is False
+            await rt.shutdown()
+        finally:
+            await server.stop()
+
+    run(asyncio.wait_for(main(), 30))
+
+
 def test_cancellation_stops_stream():
     async def main():
         plane = MemoryPlane()

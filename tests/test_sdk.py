@@ -42,8 +42,9 @@ def test_chip_allocator():
 
     alloc = ChipAllocator(4)
     assert alloc.env_for({}) == {"JAX_PLATFORMS": "cpu"}
-    env = alloc.env_for({"tpu": 3})
-    assert env["TPU_VISIBLE_CHIPS"] == "0,1,2"
+    env = alloc.env_for({"tpu": 2})
+    assert env["TPU_VISIBLE_CHIPS"] == "0,1"
+    assert alloc.env_for({"tpu": 1})["TPU_VISIBLE_CHIPS"] == "2"
     with pytest.raises(RuntimeError, match="not enough"):
         alloc.env_for({"tpu": 2})
 

@@ -22,9 +22,10 @@ per mode (no cache bleed). Emits ROUTING_TTFT.json:
 p50/mean TTFT per mode over turns >= 1 (turn 0 is cold everywhere) and
 the improvement ratio.
 
-Scale note: on CPU with the tiny model this demonstrates the mechanism,
-not the reference's absolute numbers; on a TPU backend the same script
-runs unchanged (prefill is bigger, the gap grows).
+Scale note: this tool runs every worker on the CPU backend with the tiny
+model (Stack pins JAX_PLATFORMS=cpu on each child, and this parent never
+initialises a backend): it demonstrates the mechanism, and its result says
+"backend": "cpu" so the TTFT figures are not read as device numbers.
 
 Run: python tools/routing_ttft_bench.py [--conversations 8 --turns 4
      --prefix-tokens 768 --out ROUTING_TTFT.json]
@@ -295,6 +296,9 @@ def main() -> int:
             "num_pages_per_worker": args.num_pages,
             "turn_gap_s": args.turn_gap_s,
             "model": "tiny"},
+        # Stack forces JAX_PLATFORMS=cpu on every child: host-clock TTFT
+        # of the CPU backend, not a device metric
+        "backend": "cpu",
         "round_robin": rr, "kv_routed": kv,
         "ttft_improvement": round(rr["ttft_p50_ms"] / kv["ttft_p50_ms"], 2)
         if kv["ttft_p50_ms"] else None,
