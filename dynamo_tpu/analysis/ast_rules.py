@@ -64,8 +64,11 @@ Rule ids (docs/ANALYSIS.md has the long-form description of each):
       span; (b) span-RECORDING calls inside
       `# dynalint: hot-path-begin/end` regions must use the deferred
       recorder (`defer_phase`, what PhaseTimer routes through) instead
-      of allocating span objects between device dispatches; escape
-      hatch `# dynalint: span-ok=<reason>`
+      of allocating span objects between device dispatches, and a bare
+      profiler annotation (`TraceAnnotation`) there is a finding too:
+      `PhaseTimer.phase` carries the annotation, one call site for the
+      timer, the tracer and the profiler; escape hatch
+      `# dynalint: span-ok=<reason>`
 - R14 unbounded raw stream IO on the data/control wire (disagg/,
       runtime/transports/): an awaited `read_frame` / `readexactly` /
       `readuntil` / `readline` / `drain` with no effective `timeout=`
@@ -1143,7 +1146,11 @@ def r12_retry_loop_without_backoff(tree: ast.AST, lines: List[str],
 #     calls (TRACER.span/begin_span/event/record_span/scope_span) are
 #     forbidden — they allocate and walk attrs between two device
 #     dispatches; the deferred recorder (`defer_phase`, what PhaseTimer
-#     routes through) is the only allowed form there.
+#     routes through) is the only allowed form there. A bare
+#     `jax.profiler.TraceAnnotation` / `StepTraceAnnotation` in a region
+#     is flagged as well: `PhaseTimer.phase` opens the annotation itself,
+#     and a second one would enclose or split the phases a trace reducer
+#     labels idle gaps by.
 # Escape hatch: `# dynalint: span-ok=<reason>` on the line or the line
 # above (e.g. the frontend root span that ends in an idempotent
 # finish() callback every exit funnels through).
@@ -1152,6 +1159,7 @@ _R13_BEGIN = "begin_span"
 _R13_END = {"end_span", "finish"}
 _R13_RECORDING = {"span", "begin_span", "start_span", "event",
                   "record_span", "scope_span"}
+_R13_PROFILER = {"TraceAnnotation", "StepTraceAnnotation"}
 _R13_ANNOT_RE = re.compile(r"#\s*dynalint:\s*span-ok=\S+")
 
 
@@ -1253,9 +1261,19 @@ def r13_span_lifecycle(tree: ast.AST, lines: List[str],
                 continue
             name = _call_name(node)
             term = name.rsplit(".", 1)[-1]
-            if term not in _R13_RECORDING or "tracer" not in name.lower():
-                continue
             if annotated(node.lineno):
+                continue
+            if term in _R13_PROFILER:
+                out.append(_finding(
+                    "R13", path, lines, node,
+                    f"bare profiler annotation `{name}(...)` inside a "
+                    "hot-path region — the phases of a step are flat, and "
+                    "an annotation of its own encloses or splits them",
+                    "time the stretch with `PhaseTimer.phase(name)`, "
+                    "which opens the annotation, or annotate with "
+                    "`# dynalint: span-ok=<reason>`"))
+                continue
+            if term not in _R13_RECORDING or "tracer" not in name.lower():
                 continue
             out.append(_finding(
                 "R13", path, lines, node,

@@ -285,6 +285,7 @@ def sample_fused(
     return jnp.where(temperature <= 0.0, greedy_tok, sampled)
 
 
+@jax.named_scope("sampler")
 def sample_logits(logits, eos_ids, temperature, top_k, top_p, seeds,
                   counters, min_tokens, seen=None, rep_penalty=None,
                   with_lp=False, greedy=False, fused=False):

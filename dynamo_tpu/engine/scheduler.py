@@ -88,6 +88,10 @@ class EngineRequest:
     # preemption victims, and charges cross-class preemptions against
     # the class budget. "" = the policy default class.
     qos: str = ""
+    # the request's trace context (runtime/tracing.py), carried from the
+    # worker's Context so the engine can record `engine.queue` /
+    # `engine.prefill` under the request's own trace; None = untraced
+    trace: Optional[object] = None
 
 
 @dataclasses.dataclass

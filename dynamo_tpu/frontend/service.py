@@ -263,6 +263,7 @@ class HttpService:
         s.route("POST", "/v1/completions", self._completions)
         s.route("GET", "/v1/models", self._models)
         s.route("GET", "/metrics", self._metrics)
+        s.route("POST", "/debug/profile", self._debug_profile)
         s.route("GET", "/health", self._health)
         s.route("GET", "/live", self._health)
 
@@ -295,6 +296,34 @@ class HttpService:
         # observed at the serving layers, appended at render
         return Response.text(self.registry.render() + SERVING.render(),
                              content_type="text/plain; version=0.0.4")
+
+    async def _debug_profile(self, req: Request) -> Response:
+        """`POST /debug/profile?seconds=<n>`: one bounded JAX profiler
+        capture of an in-process engine (llm/worker.py capture_profile).
+        404 unless DYN_JAX_PROFILE_DIR is set: the variable means
+        "captures are allowed, and go here"."""
+        import os
+        from urllib.parse import parse_qs
+        base = os.environ.get("DYN_JAX_PROFILE_DIR")
+        worker = next(
+            (e.engine for e in (*self.models.chat.values(),
+                                *self.models.completion.values())
+             if hasattr(getattr(e, "engine", None), "capture_profile")),
+            None)
+        if not base or worker is None:
+            raise HttpError(404, "no profiler captures here")
+        try:
+            seconds = float(parse_qs(req.query).get("seconds", ["4"])[0])
+        except ValueError:
+            raise HttpError(400, "seconds must be a number")
+        if not 0.0 < seconds <= 60.0:
+            raise HttpError(400, "seconds must be in (0, 60]")
+        out_dir = os.path.join(base, time.strftime("capture-%Y%m%d-%H%M%S"))
+        try:
+            await worker.capture_profile(seconds, out_dir)
+        except RuntimeError as e:
+            raise HttpError(409, str(e))
+        return Response.json({"trace_dir": out_dir, "seconds": seconds})
 
     def _refresh_robustness_gauges(self) -> None:
         """Fold the process-global fault/integrity/drain counters into

@@ -18,6 +18,8 @@ import dataclasses
 import logging
 from typing import AsyncIterator, Dict, Optional
 
+from jax.profiler import TraceAnnotation
+
 from dynamo_tpu.engine.scheduler import EngineRequest, SamplingParams
 from dynamo_tpu.kv_router.publisher import KvEventPublisher, KvMetricsPublisher
 from dynamo_tpu.protocols.common import (
@@ -28,12 +30,9 @@ from dynamo_tpu.runtime.tracing import TRACER
 
 log = logging.getLogger("dynamo_tpu.worker")
 
-# the process-wide JAX profiler session owner (see NativeEngineWorker.start)
-_PROFILE_OWNER = None
 
-
-def _to_engine_request(pre: PreprocessedRequest,
-                       qos: str = "") -> EngineRequest:
+def _to_engine_request(pre: PreprocessedRequest, qos: str = "",
+                       trace=None) -> EngineRequest:
     s, st, out = pre.sampling, pre.stop, pre.output
     # resume-from-prefix (mid-stream migration): token_ids already carries
     # prompt + committed tokens; the whole sequence re-prefills and decode
@@ -63,6 +62,7 @@ def _to_engine_request(pre: PreprocessedRequest,
         mm_pixels=mm_pixels,
         mm_spans=mm_spans,
         qos=qos,
+        trace=trace,
         params=SamplingParams(
             max_tokens=max(1, (st.max_tokens or 16) - resume),
             temperature=s.temperature if s.temperature is not None else 0.0,
@@ -142,7 +142,9 @@ class NativeEngineWorker(AsyncEngine):
         # arbitrary staged engine ops (disagg page inject/extract/activate);
         # run FIFO between device steps
         self._pending_ops: list = []
-        self._profiling = False
+        # a bounded profiler capture in progress (capture_profile): the
+        # JAX trace is process-global, so one at a time
+        self._capturing = False
 
     def submit(self, fn) -> asyncio.Future:
         """Stage `fn(engine)` to run between device steps; returns a future
@@ -154,23 +156,35 @@ class NativeEngineWorker(AsyncEngine):
         return fut
 
     async def start(self) -> "NativeEngineWorker":
-        # profiler hook (reference gap called out in SURVEY.md §5: no
-        # profiler backend; filled here with the JAX profiler): set
-        # DYN_JAX_PROFILE_DIR to capture a perfetto/tensorboard trace of
-        # the serving loop. The JAX trace is process-global, so only the
-        # FIRST worker in a process starts it (and only that owner stops
-        # it) — a second start_trace would raise and kill the worker.
-        import os
-        trace_dir = os.environ.get("DYN_JAX_PROFILE_DIR")
-        global _PROFILE_OWNER
-        if trace_dir and _PROFILE_OWNER is None:
-            import jax
-            jax.profiler.start_trace(trace_dir)
-            _PROFILE_OWNER = self
-            self._profiling = True
-            log.info("jax profiler tracing to %s", trace_dir)
         self._loop_task = asyncio.create_task(self._step_loop())
         return self
+
+    async def capture_profile(self, seconds: float, out_dir: str) -> str:
+        """One bounded JAX profiler capture of the serving loop: start,
+        sleep `seconds`, stop (in the executor: stopping serialises the
+        trace), Python tracer off (it slows the host it measures).
+        Refuses while one runs, here or anywhere else in the process (the
+        JAX trace is process-global). The capture holds the engine's
+        `engine.<phase>` and the loop's `worker.*` annotations next to
+        the device's lines; benchmark/harness/trace_reduce.py reduces it.
+        Returns `out_dir`."""
+        if self._capturing:
+            raise RuntimeError("a profiler capture is already running")
+        import jax
+        self._capturing = True
+        try:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(out_dir, profiler_options=opts)
+            try:
+                await asyncio.sleep(seconds)
+            finally:
+                await asyncio.get_running_loop().run_in_executor(
+                    None, jax.profiler.stop_trace)
+        finally:
+            self._capturing = False
+        log.info("jax profiler: %.1fs captured to %s", seconds, out_dir)
+        return out_dir
 
     async def stop(self) -> None:
         if self._loop_task:
@@ -183,12 +197,6 @@ class NativeEngineWorker(AsyncEngine):
         close = getattr(self.engine, "close", None)
         if close:
             close()
-        global _PROFILE_OWNER
-        if self._profiling and _PROFILE_OWNER is self:
-            import jax
-            jax.profiler.stop_trace()
-            _PROFILE_OWNER = None
-            self._profiling = False
 
     # -- engine loop ----------------------------------------------------------
 
@@ -225,11 +233,16 @@ class NativeEngineWorker(AsyncEngine):
     async def _step_loop(self) -> None:
         loop = asyncio.get_running_loop()
         while True:
-            self._apply_pending()
+            # the synchronous stretches between two steps are annotated for
+            # a profiler capture, never across an await
+            with TraceAnnotation("worker.apply_pending"):
+                self._apply_pending()
             if not self.engine.has_work():
                 self._wake.clear()
                 if not self._pending_adds and not self._pending_ops:
                     self.metrics_publisher.update(self.engine.metrics())
+                    # sleeping is idleness, not host time between steps
+                    self.engine.note_idle()
                     try:
                         await asyncio.wait_for(self._wake.wait(), timeout=1.0)
                     except asyncio.TimeoutError:
@@ -247,20 +260,25 @@ class NativeEngineWorker(AsyncEngine):
                 # anymore — drop them so they never occupy an engine slot
                 self._pending_adds.clear()
                 continue
-            for ev in outputs:
-                q = self._queues.get(ev.request_id)
-                if q is None:
-                    continue
-                q.put_nowait(EngineOutput(
-                    token_ids=[ev.token] if ev.token is not None else [],
-                    log_probs=([ev.logprob] if ev.logprob is not None
-                               else None),
-                    top_logprobs=([[[float(t), lp] for t, lp in
-                                    ev.top_logprobs]]
-                                  if ev.top_logprobs is not None else None),
-                    finish_reason=(FinishReason(ev.finish_reason)
-                                   if ev.finish_reason else None)))
-            self.metrics_publisher.update(self.engine.metrics())
+            # frame fan-out and the metrics snapshot, on the event loop
+            # that also serves the sockets
+            with TraceAnnotation("worker.emit"):
+                for ev in outputs:
+                    q = self._queues.get(ev.request_id)
+                    if q is None:
+                        continue
+                    q.put_nowait(EngineOutput(
+                        token_ids=([ev.token] if ev.token is not None
+                                   else []),
+                        log_probs=([ev.logprob] if ev.logprob is not None
+                                   else None),
+                        top_logprobs=([[[float(t), lp] for t, lp in
+                                        ev.top_logprobs]]
+                                      if ev.top_logprobs is not None
+                                      else None),
+                        finish_reason=(FinishReason(ev.finish_reason)
+                                       if ev.finish_reason else None)))
+                self.metrics_publisher.update(self.engine.metrics())
             pool = getattr(self.engine, "kv_pool", None)
             if self.event_publisher is not None or pool is not None:
                 # the drain also tees sealed pages into the shared pool
@@ -345,8 +363,8 @@ class NativeEngineWorker(AsyncEngine):
             # scheduler orders its waiting queue and selects preemption
             # victims by it
             from dynamo_tpu.runtime.qos import qos_of
-            self._pending_adds.append(
-                _to_engine_request(pre, qos=qos_of(context.baggage)))
+            self._pending_adds.append(_to_engine_request(
+                pre, qos=qos_of(context.baggage), trace=context.trace))
             self._wake.set()
             async for frame in self._stream(pre.request_id, context, q):
                 yield frame

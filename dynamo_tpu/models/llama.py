@@ -296,6 +296,7 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
+@jax.named_scope("moe")
 def _moe_mlp(x: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
     """Dense-compute MoE (top-k routing, all experts evaluated then masked).
 
@@ -320,6 +321,7 @@ def _moe_mlp(x: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
     return jnp.einsum("betd,bte->btd", down.astype(jnp.float32), combine).astype(x.dtype)
 
 
+@jax.named_scope("mlp")
 def _dense_mlp(x: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
     gate = jnp.einsum("btd,df->btf", x, wmat(lp["w_gate"], x.dtype))
     up = jnp.einsum("btd,df->btf", x, wmat(lp["w_up"], x.dtype))

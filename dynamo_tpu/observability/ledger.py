@@ -99,6 +99,23 @@ class LedgerStats:
         "tok_s",                  # EWMA instantaneous useful tok/s
         "mfu",                    # tok_s * flops/token / peak (0 = no peak)
         "samples_dropped",        # ring overwrites (oldest lost)
+        # the engine host loop's own clock (observability/metrics.py
+        # PhaseTimer), cumulative seconds as floats, all step kinds:
+        "host_plan_seconds",      # schedule + offload/onboard/pool work
+        "host_upload_seconds",    # sampling arrays + host->device staging
+        "host_dispatch_seconds",  # inside the jit call (first dispatches too)
+        "host_wait_seconds",      # blocked in device_get/block_until_ready
+        "host_commit_seconds",    # commits, postprocess, events, ledger
+        "host_between_seconds",   # step() return -> next step() entry,
+        #                           while the engine had work
+        "host_exposed_seconds",   # of the above, the part spent with no
+        #                           dispatched-and-unfetched program: the
+        #                           host's estimate of device idle it causes
+        # what XLA really built or loaded in this process (jax.monitoring,
+        # install_jax_listeners): every jit, not only the engine's keys
+        "jax_compiles",           # backend compiles + persistent-cache loads
+        "jax_compile_seconds",    # wall time inside them
+        "jax_cache_hits",         # of those, loads from the persistent cache
     )
 
     def __init__(self):
@@ -113,6 +130,34 @@ class LedgerStats:
 
 
 LEDGER_STATS = LedgerStats()
+
+_JAX_LISTENERS = False
+
+
+def install_jax_listeners() -> None:
+    """Fold jax's own compile events into LEDGER_STATS, once per process.
+    `backend_compile_duration` wraps compile_or_get_cached, so it fires
+    for a program XLA builds and for one it loads from the persistent
+    cache alike; `cache_hits` tells the two apart. Unlike `recompiles`
+    (the engine's set of keys it believes identify a program) these count
+    every jit in the process, with the seconds each took."""
+    global _JAX_LISTENERS
+    if _JAX_LISTENERS:
+        return
+    _JAX_LISTENERS = True
+    from jax import monitoring
+
+    def on_duration(event: str, duration: float, **_kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            LEDGER_STATS.jax_compiles += 1
+            LEDGER_STATS.jax_compile_seconds += duration
+
+    def on_event(event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            LEDGER_STATS.jax_cache_hits += 1
+
+    monitoring.register_event_duration_secs_listener(on_duration)
+    monitoring.register_event_listener(on_event)
 
 
 def model_flops_per_token(cfg) -> float:

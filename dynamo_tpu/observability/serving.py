@@ -35,12 +35,16 @@ QUEUE_BUCKETS = (0.0001, 0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0,
                  5.0, 30.0, float("inf"))
 SCHEDULE_BUCKETS = (0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005,
                     0.01, 0.05, 0.1, float("inf"))
+# engine-side first-token split: a queue wait is sub-millisecond on an idle
+# engine and tens of seconds on a full one; a prefill is one step to dozens
+ENGINE_TTFT_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
+                       0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, float("inf"))
 TRANSFER_BUCKETS = (0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0,
                     float("inf"))
 
 
 class ServingMetrics:
-    """The five serving-path histograms on one registry.
+    """The serving-path histograms on one registry.
 
     - llm_ttft_seconds{model, qos}: request start -> first token frame
       (llm/pipeline._drive_n, per choice stream), partitioned by the
@@ -57,6 +61,13 @@ class ServingMetrics:
       reliability layer's fallback pick when no router is wired).
     - llm_kv_transfer_seconds: one disagg page transfer, send side
       (local or remote backend), staging -> last ack.
+    - llm_engine_queue_wait_seconds: engine.add_request -> the first step
+      whose plan holds a row of the request (waiting for a slot and for
+      the scheduler's turn), observed by the engine's step thread.
+    - llm_engine_prefill_seconds: that step's start -> the request's
+      first sampled token (its prompt riding mixed / prefill steps).
+      With the frontend's llm_ttft_seconds the two split a first-token
+      time into queueing, prefill and everything above the engine.
     """
 
     def __init__(self, registry: Optional[MetricsRegistry] = None):
@@ -80,6 +91,15 @@ class ServingMetrics:
             "llm_kv_transfer_seconds",
             "disagg KV page transfer, send side (stage -> last ack)",
             buckets=TRANSFER_BUCKETS)
+
+        self.engine_queue_wait = r.histogram(
+            "llm_engine_queue_wait_seconds",
+            "engine: add_request until a step first plans the request",
+            buckets=ENGINE_TTFT_BUCKETS)
+        self.engine_prefill = r.histogram(
+            "llm_engine_prefill_seconds",
+            "engine: first planned step until the first sampled token",
+            buckets=ENGINE_TTFT_BUCKETS)
 
     def render(self) -> str:
         return self.registry.render()

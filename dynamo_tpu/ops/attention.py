@@ -58,6 +58,7 @@ def gather_pages(cache: jax.Array, page_table: jax.Array) -> jax.Array:
     return gathered.reshape(hkv, b, pb * ps, hd)
 
 
+@jax.named_scope("attention")
 def paged_attention(
     q: jax.Array,            # [B, Tq, H, hd]
     k_cache: jax.Array,      # [Hkv, P, ps, hd]
@@ -112,6 +113,7 @@ def paged_attention(
     return out.reshape(b, tq, h, hd).astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def decode_attention_split(
     q: jax.Array,            # [B, H, hd] — one query token per sequence
     k_base: jax.Array,       # [Hkv, B, Lb, hd] — read-only pre-window KV
@@ -194,6 +196,7 @@ def decode_attention_split(
     return out.reshape(b, h, hd).astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def decode_attention_deferred(
     q: jax.Array,            # [B, H, hd] — one query token per sequence
     k_cache: jax.Array,      # [Hkv, P, ps, hd]

@@ -43,44 +43,51 @@ def moe_dispatch_mlp(x: jax.Array, lp, cfg, capacity_factor: float = 2.0,
     Padded positions all share one hidden state, so unmasked they would
     pile onto the same experts — consuming capacity real tokens need and
     polluting the drop counters. Masked tokens route nowhere.
+
+    The three legs carry `jax.named_scope`s (moe.dispatch, moe.experts,
+    moe.combine): trace-time metadata for whoever reads a profile.
     """
     b, t, d = x.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
     f32 = jnp.float32
 
-    logits = jnp.einsum("btd,de->bte", x.astype(f32),
-                        lp["router"].astype(f32))
-    weights, idx = jax.lax.top_k(logits, k)          # [B, T, k]
-    weights = jax.nn.softmax(weights, axis=-1)
+    with jax.named_scope("moe.dispatch"):
+        logits = jnp.einsum("btd,de->bte", x.astype(f32),
+                            lp["router"].astype(f32))
+        weights, idx = jax.lax.top_k(logits, k)          # [B, T, k]
+        weights = jax.nn.softmax(weights, axis=-1)
 
-    # flatten (token, choice) pairs in token-major order so earlier tokens
-    # win capacity ties deterministically
-    sel = jax.nn.one_hot(idx, e, dtype=f32)          # [B, T, k, E]
-    if valid is not None:
-        sel = sel * valid.astype(f32)[:, :, None, None]
-    sel_flat = sel.reshape(b, t * k, e)
-    pos = jnp.cumsum(sel_flat, axis=1) - 1.0         # position within expert
-    cap = max(int(t * k / e * capacity_factor), 1)
-    keep = (pos < cap) * sel_flat                    # [B, S, E]
-    pos_oh = jax.nn.one_hot(pos.astype(jnp.int32), cap, dtype=f32)
-    dispatch = keep[..., None] * pos_oh              # [B, S, E, C]
+        # flatten (token, choice) pairs in token-major order so earlier
+        # tokens win capacity ties deterministically
+        sel = jax.nn.one_hot(idx, e, dtype=f32)          # [B, T, k, E]
+        if valid is not None:
+            sel = sel * valid.astype(f32)[:, :, None, None]
+        sel_flat = sel.reshape(b, t * k, e)
+        pos = jnp.cumsum(sel_flat, axis=1) - 1.0     # position within expert
+        cap = max(int(t * k / e * capacity_factor), 1)
+        keep = (pos < cap) * sel_flat                    # [B, S, E]
+        pos_oh = jax.nn.one_hot(pos.astype(jnp.int32), cap, dtype=f32)
+        dispatch = keep[..., None] * pos_oh              # [B, S, E, C]
 
-    w_flat = jnp.broadcast_to(weights[..., None], (b, t, k, 1)
-                              ).reshape(b, t * k, 1)
-    combine = dispatch * w_flat[..., None]           # [B, S, E, C]
+        w_flat = jnp.broadcast_to(weights[..., None], (b, t, k, 1)
+                                  ).reshape(b, t * k, 1)
+        combine = dispatch * w_flat[..., None]           # [B, S, E, C]
 
-    x_rep = jnp.repeat(x, k, axis=1)                 # [B, S, D] (token-major)
-    xin = jnp.einsum("bsec,bsd->becd", dispatch, x_rep.astype(f32)
-                     ).astype(x.dtype)               # [B, E, C, D]
+        x_rep = jnp.repeat(x, k, axis=1)             # [B, S, D] (token-major)
+        xin = jnp.einsum("bsec,bsd->becd", dispatch, x_rep.astype(f32)
+                         ).astype(x.dtype)               # [B, E, C, D]
 
-    gate = jnp.einsum("becd,edf->becf", xin, wmat(lp["w_gate"], x.dtype))
-    up = jnp.einsum("becd,edf->becf", xin, wmat(lp["w_up"], x.dtype))
-    act = jax.nn.silu(gate.astype(f32)).astype(x.dtype) * up
-    y = jnp.einsum("becf,efd->becd", act,
-                   wmat(lp["w_down"], x.dtype))  # [B, E, C, D]
+    with jax.named_scope("moe.experts"):
+        gate = jnp.einsum("becd,edf->becf", xin,
+                          wmat(lp["w_gate"], x.dtype))
+        up = jnp.einsum("becd,edf->becf", xin, wmat(lp["w_up"], x.dtype))
+        act = jax.nn.silu(gate.astype(f32)).astype(x.dtype) * up
+        y = jnp.einsum("becf,efd->becd", act,
+                       wmat(lp["w_down"], x.dtype))  # [B, E, C, D]
 
-    out = jnp.einsum("bsec,becd->bsd", combine, y.astype(f32))
-    out = out.reshape(b, t, k, d).sum(axis=2).astype(x.dtype)
+    with jax.named_scope("moe.combine"):
+        out = jnp.einsum("bsec,becd->bsd", combine, y.astype(f32))
+        out = out.reshape(b, t, k, d).sum(axis=2).astype(x.dtype)
     if return_dropped:
         routed = jnp.sum(sel_flat)
         dropped = routed - jnp.sum(keep)
@@ -138,26 +145,30 @@ def moe_dispatch_mlp_sharded(x, lp, cfg, mesh, capacity_factor: float = 2.0,
         # runs per (dp, ep, tp) shard: x is the dp-local batch, w_* leading
         # dim is E/ep, last dim F/tp
         bl, tl, dl = x.shape
-        sel_flat, keep, pos, w_flat, cap = _route(
-            x, router, e, k, capacity_factor, valid_arr)
-        ei = jax.lax.axis_index("ep")
-        e_loc = e // ep
-        # slice MY experts' columns out of the replicated routing tensors
-        keep_l = jax.lax.dynamic_slice_in_dim(keep, ei * e_loc, e_loc, 2)
-        pos_l = jax.lax.dynamic_slice_in_dim(pos, ei * e_loc, e_loc, 2)
-        pos_oh = jax.nn.one_hot(pos_l.astype(jnp.int32), cap, dtype=f32)
-        dispatch = keep_l[..., None] * pos_oh          # [B, S, E/ep, C]
-        combine = dispatch * w_flat[..., None]
-        x_rep = jnp.repeat(x, k, axis=1)
-        xin = jnp.einsum("bsec,bsd->becd", dispatch,
-                         x_rep.astype(f32)).astype(x.dtype)
-        gate = jnp.einsum("becd,edf->becf", xin, wmat(w_gate, x.dtype))
-        up = jnp.einsum("becd,edf->becf", xin, wmat(w_up, x.dtype))
-        act = jax.nn.silu(gate.astype(f32)).astype(x.dtype) * up
-        y = jnp.einsum("becf,efd->becd", act, wmat(w_down, x.dtype))
-        out = jnp.einsum("bsec,becd->bsd", combine, y.astype(f32))
-        out = jax.lax.psum(out, ("ep", "tp"))
-        out = out.reshape(bl, tl, k, dl).sum(axis=2).astype(x.dtype)
+        with jax.named_scope("moe.dispatch"):
+            sel_flat, keep, pos, w_flat, cap = _route(
+                x, router, e, k, capacity_factor, valid_arr)
+            ei = jax.lax.axis_index("ep")
+            e_loc = e // ep
+            # slice MY experts' columns out of the replicated routing
+            # tensors
+            keep_l = jax.lax.dynamic_slice_in_dim(keep, ei * e_loc, e_loc, 2)
+            pos_l = jax.lax.dynamic_slice_in_dim(pos, ei * e_loc, e_loc, 2)
+            pos_oh = jax.nn.one_hot(pos_l.astype(jnp.int32), cap, dtype=f32)
+            dispatch = keep_l[..., None] * pos_oh      # [B, S, E/ep, C]
+            combine = dispatch * w_flat[..., None]
+            x_rep = jnp.repeat(x, k, axis=1)
+            xin = jnp.einsum("bsec,bsd->becd", dispatch,
+                             x_rep.astype(f32)).astype(x.dtype)
+        with jax.named_scope("moe.experts"):
+            gate = jnp.einsum("becd,edf->becf", xin, wmat(w_gate, x.dtype))
+            up = jnp.einsum("becd,edf->becf", xin, wmat(w_up, x.dtype))
+            act = jax.nn.silu(gate.astype(f32)).astype(x.dtype) * up
+            y = jnp.einsum("becf,efd->becd", act, wmat(w_down, x.dtype))
+        with jax.named_scope("moe.combine"):
+            out = jnp.einsum("bsec,becd->bsd", combine, y.astype(f32))
+            out = jax.lax.psum(out, ("ep", "tp"))
+            out = out.reshape(bl, tl, k, dl).sum(axis=2).astype(x.dtype)
         routed = jax.lax.psum(jnp.sum(sel_flat), "dp")
         dropped = routed - jax.lax.psum(jnp.sum(keep), "dp")
         return out, dropped, routed

@@ -324,6 +324,7 @@ def ragged_decode_attention(
     return acc, m[..., :1].reshape(s, h, 1), l[..., :1].reshape(s, h, 1)
 
 
+@jax.named_scope("attention")
 def decode_paged_attention_prefix(
     q: jax.Array,            # [S, H, hd] — one query token per sequence
     k_cache: jax.Array,      # [L, Hkv, P, ps, hd] (whole stack, all layers)
@@ -345,6 +346,7 @@ def decode_paged_attention_prefix(
         interpret=interpret, k_scale=k_scale, v_scale=v_scale)
 
 
+@jax.named_scope("attention")
 def combine_self_attention(q, k_new, v_new, acc, m, l):
     """Fold the current token's kv into the prefix flash state.
 
@@ -369,6 +371,7 @@ def combine_self_attention(q, k_new, v_new, acc, m, l):
     return out.astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def decode_paged_attention_prefix_sharded(
     q, k_cache, v_cache, layer, page_table, prefix_lens, mesh,
     *, interpret: bool = False, k_scale=None, v_scale=None,

@@ -812,6 +812,34 @@ def test_r13_flags_span_recording_in_hot_path_region():
     assert len(found) == 2          # the span AND the event
 
 
+@pytest.mark.parametrize("call,flagged", [
+    ('jax.profiler.TraceAnnotation("engine.dispatch")', True),
+    ('TraceAnnotation("engine.dispatch")', True),
+    ('jax.profiler.StepTraceAnnotation("step", step_num=1)', True),
+    ('self.phases.phase("dispatch")', False),
+    ('self._dispatch_phase(key)', False),
+])
+def test_r13_profiler_annotation_in_hot_path_region(call, flagged):
+    """Inside a hot-path region the only recording form is
+    PhaseTimer.phase, which carries the profiler annotation itself; a
+    bare TraceAnnotation there is a finding, outside it is not."""
+    hot = f"""
+        import jax
+        from jax.profiler import TraceAnnotation
+
+        def _dispatch_staged(self, staged, key):
+            # dynalint: hot-path-begin
+            with {call}:
+                outs = self._fn(staged)
+            # dynalint: hot-path-end
+            return outs
+    """
+    assert ("R13" in rules(lint(hot))) is flagged
+    cold = hot.replace("# dynalint: hot-path-begin", "").replace(
+        "# dynalint: hot-path-end", "")
+    assert "R13" not in rules(lint(cold))
+
+
 def test_r13_quiet_on_deferred_recorder_in_region():
     deferred = """
         from dynamo_tpu.runtime.tracing import TRACER

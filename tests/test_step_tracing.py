@@ -1,0 +1,578 @@
+"""The engine's step loop on the profiler's clock (ISSUE 24).
+
+- every engine program lowers to a stable XLA module name, the decode
+  window by ladder rung (a device trace no longer says `jit__unknown`);
+- every step kind passes through one phase vocabulary
+  (plan / upload / dispatch / wait / commit), each phase at most once a
+  step, and phases + `between` account for the loop's wall time;
+- a profiler capture holds the phases as flat `engine.<phase>` host
+  events, and the benchmark's reducer labels idle gaps by them;
+- `jax_compiles` counts what XLA really compiled;
+- `engine.queue` / `engine.prefill` split a request's first-token time
+  under its `worker.generate` span, one histogram observation each;
+- every series, field and module a listed layer metric reads exists;
+- the worker's bounded `capture_profile` and its `/debug/profile` route.
+"""
+import asyncio
+import contextlib
+import json
+import os
+import re
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+from dynamo_tpu.engine.engine import NativeEngine
+from dynamo_tpu.engine.scheduler import EngineRequest, SamplingParams
+from dynamo_tpu.observability.ledger import LEDGER_STATS
+from dynamo_tpu.observability.metrics import PhaseTimer
+from dynamo_tpu.observability.serving import SERVING
+from dynamo_tpu.runtime.engine import Context
+from dynamo_tpu.runtime.tracing import TRACER, TraceContext
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "benchmark"))
+from harness import readers, trace_reduce  # noqa: E402
+
+CFG = ModelConfig(dtype="float32", max_model_len=512)
+PHASES = PhaseTimer.PHASES
+WINDOWS = ("engine_decode_window_full", "engine_decode_window_w2",
+           "engine_decode_window_w1")
+
+
+def make_engine(**kw):
+    defaults = dict(page_size=8, num_pages=64, max_slots=4,
+                    max_prefill_chunk=32, prefill_buckets=(8, 16, 32),
+                    max_model_len=512, decode_steps=8)
+    defaults.update(kw)
+    return NativeEngine(CFG, EngineConfig(**defaults), seed=0)
+
+
+def sampled(max_tokens, seed=3):
+    return SamplingParams(max_tokens=max_tokens, temperature=0.7,
+                          top_p=0.95, seed=seed)
+
+
+PHRASE = [11, 12, 13, 14, 15, 16]
+
+
+@contextlib.contextmanager
+def spec_engine():
+    """A speculating engine whose proposer always has a draft: a
+    random-weight model never repeats itself, so the real n-gram proposer
+    goes silent after the first token (tests/test_spec_decode.py). At a
+    1-step window any draft passes the cost gate."""
+    import dynamo_tpu.engine.spec as spec_mod
+    real = spec_mod.ngram_propose
+    spec_mod.ngram_propose = lambda tokens, k, *a, **kw: [7] * min(k, 2)
+    try:
+        yield make_engine(spec_decode="ngram", spec_k=4, pipeline_depth=1,
+                          decode_steps=1)
+    finally:
+        spec_mod.ngram_propose = real
+
+
+# -- (a) module names ----------------------------------------------------------
+
+def _record_lowered(fns: dict, names: set):
+    """Wrap each jitted program so that a dispatch also records the name
+    of the XLA module the same arguments lower to."""
+    for key, fn in list(fns.items()):
+        def spy(*a, _fn=fn, **k):
+            text = _fn.lower(*a, **k).as_text()
+            names.add(re.search(r"module @(\w+)", text).group(1))
+            return _fn(*a, **k)
+        fns[key] = spy
+
+
+@pytest.fixture(scope="module")
+def lowered_names():
+    names: set = set()
+    eng = make_engine(pipeline_depth=1)
+    _record_lowered(eng._step_fns, names)
+    _record_lowered(eng._decode_fns, names)
+    # prefill 1 + full rung 8 + rung 2, then prefill 1 + 8 + rung 1
+    eng.generate(list(range(10, 30)), sampled(11), "r2")
+    eng.generate(list(range(40, 60)), sampled(10), "r1")
+    with spec_engine() as spec:
+        verify = {"verify": spec._verify_fn}
+        _record_lowered(verify, names)
+        spec._verify_fn = verify["verify"]
+        spec.generate(PHRASE * 4, SamplingParams(max_tokens=6,
+                                                 temperature=0.0), "s")
+        assert spec.spec_steps > 0
+    return names
+
+
+@pytest.mark.parametrize("program", ("engine_step", "engine_verify_step")
+                         + WINDOWS)
+def test_program_lowers_to_its_module_name(lowered_names, program):
+    assert f"jit_{program}" in lowered_names
+    assert not any("unknown" in n for n in lowered_names)
+
+
+def test_window_names_follow_the_ladder():
+    eng = make_engine(decode_steps=16)
+    assert [eng._window_name(w) for w in eng._window_sizes] == [
+        "engine_decode_window_full", "engine_decode_window_w4",
+        "engine_decode_window_w1"]
+
+
+# -- (b) one phase vocabulary, counted once a step -----------------------------
+
+def _drive(eng, arrivals):
+    """Step `eng` to completion; `arrivals` maps a step index to the
+    request added before it. Returns per step (kinds committed, phase
+    count deltas) and the loop's wall time against the timer's sums."""
+    kinds = ("prefill", "mixed", "decode", "spec")
+    steps = []
+    eng.phases.reset()
+    t0 = time.perf_counter()
+    i = 0
+    while eng.has_work() or i in arrivals:
+        if i in arrivals:
+            eng.add_request(arrivals[i])
+        before = dict(eng.phases.counts)
+        k0 = {k: getattr(LEDGER_STATS, "steps_" + k) for k in kinds}
+        eng.step()
+        delta = {p: eng.phases.counts.get(p, 0) - before.get(p, 0)
+                 for p in PHASES + ("between",)}
+        kind = [k for k in kinds
+                if getattr(LEDGER_STATS, "steps_" + k) > k0[k]]
+        steps.append((kind, delta))
+        i += 1
+    wall = time.perf_counter() - t0
+    return steps, wall, sum(eng.phases.seconds.values())
+
+
+@pytest.fixture(scope="module")
+def driven():
+    arrivals = {0: EngineRequest("a", list(range(10, 40)), sampled(30)),
+                3: EngineRequest("b", list(range(50, 90)), sampled(20, 5))}
+    out = {}
+    out["sync"] = _drive(make_engine(pipeline_depth=1), arrivals)
+    out["pipelined"] = _drive(make_engine(pipeline_depth=2), arrivals)
+    with spec_engine() as spec:
+        out["spec"] = _drive(spec, {0: EngineRequest(
+            "s", PHRASE * 4, SamplingParams(max_tokens=12,
+                                            temperature=0.0))})
+    return out
+
+
+@pytest.mark.parametrize("run,kind", [
+    ("sync", "prefill"), ("sync", "mixed"), ("sync", "decode"),
+    ("spec", "spec")])
+def test_every_phase_once_per_synchronous_step(driven, run, kind):
+    steps = [d for k, d in driven[run][0] if k == [kind]]
+    assert steps, f"the drive made no {kind} step"
+    for i, d in enumerate(steps):
+        assert {p: d[p] for p in PHASES} == dict.fromkeys(PHASES, 1), (i, d)
+        assert d["between"] <= 1
+
+
+def test_pipelined_steps_keep_the_phases_flat(driven):
+    """A primed window has no wait or commit of its own, a chained one
+    no upload; no phase is ever entered twice in one step, and over the
+    run every dispatched window is waited for and committed once."""
+    steps, _, _ = driven["pipelined"]
+    primed = [d for k, d in steps if not k and d["dispatch"] == 1]
+    chained = [d for k, d in steps if k == ["decode"] and d["upload"] == 0]
+    assert primed and chained
+    for d in primed:
+        assert (d["plan"], d["upload"], d["wait"], d["commit"]) == (1, 1, 0, 0)
+    for d in chained:
+        assert (d["plan"], d["wait"], d["commit"]) == (1, 1, 1)
+        assert d["dispatch"] in (0, 1)
+    assert all(v <= 1 for _, d in steps for v in d.values())
+    total = {p: sum(d[p] for _, d in steps) for p in PHASES}
+    assert total["wait"] == total["commit"]
+    assert total["plan"] == len(steps)
+
+
+@pytest.mark.parametrize("run", ["sync", "pipelined", "spec"])
+def test_phases_and_between_sum_to_the_wall_time(driven, run):
+    """Tolerance: 3 % of the loop's wall time plus 0.2 ms a step (the
+    unphased glue between two `with` blocks)."""
+    steps, wall, accounted = driven[run]
+    assert accounted <= wall
+    assert wall - accounted <= 0.03 * wall + 2e-4 * len(steps)
+
+
+def test_exposed_excludes_wait_and_overlapped_commits():
+    """Synchronous steps expose everything but `wait`; a commit that runs
+    while a follow-up window is in flight is not exposed."""
+    sync = make_engine(pipeline_depth=1)
+    sync.generate(list(range(10, 40)), sampled(30), "a")
+    t = sync.phases
+    assert t.exposed == pytest.approx(
+        sum(s for n, s in t.seconds.items() if n != "wait"), rel=1e-9)
+    piped = make_engine(pipeline_depth=2)
+    piped.generate(list(range(10, 40)), sampled(30), "a")
+    t = piped.phases
+    assert piped.pipeline_overlapped > 0
+    assert t.exposed < sum(s for n, s in t.seconds.items() if n != "wait")
+    assert LEDGER_STATS.host_exposed_seconds >= t.exposed
+
+
+# -- (c) the phases in a profiler capture --------------------------------------
+
+@pytest.fixture(scope="module")
+def captured_planes(tmp_path_factory):
+    eng = make_engine(pipeline_depth=1)
+    eng.generate(list(range(10, 40)), sampled(12), "warm")
+    out = str(tmp_path_factory.mktemp("capture"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(out, profiler_options=opts)
+    try:
+        eng.generate(list(range(10, 40)), sampled(12, 9), "traced")
+    finally:
+        jax.profiler.stop_trace()
+    return trace_reduce.load_planes(trace_reduce.find_xplane(out))
+
+
+def _engine_events(planes):
+    """[(line, [(start, end, name)])] of `engine.*` host events."""
+    out = []
+    for pname, lines in planes:
+        if not trace_reduce.HOST_PLANE.match(pname):
+            continue
+        for lname, evs in lines:
+            mine = sorted(e for e in evs if e[2].startswith("engine."))
+            if mine:
+                out.append((lname, mine))
+    return out
+
+
+@pytest.mark.parametrize("phase", PHASES)
+def test_capture_holds_the_phase_as_a_host_event(captured_planes, phase):
+    names = {n for _, evs in _engine_events(captured_planes)
+             for _, _, n in evs}
+    assert f"engine.{phase}" in names
+
+
+def test_captured_phases_do_not_enclose_each_other(captured_planes):
+    lines = _engine_events(captured_planes)
+    assert lines
+    for lname, evs in lines:
+        for (s0, e0, n0), (s1, e1, n1) in zip(evs, evs[1:]):
+            assert e0 <= s1, (lname, n0, n1)
+
+
+def _ms(x):
+    return int(x * 1e6)
+
+
+def test_reducer_labels_idle_gaps_by_phase():
+    """A synthetic step loop: the device runs 100 ms programs with 8 ms
+    between them; on the host the gap holds wait's tail, commit,
+    worker.emit, plan, upload (with `shard_args` inside it) and dispatch
+    (with `PjitFunction` inside it). The gap goes to the phase that
+    overlaps it most, never to the C++ TraceMe nested in a phase."""
+    ops, host = [], []
+    for i in range(5):
+        t = i * 108.0
+        ops.append((_ms(t), _ms(t + 100), "%fusion.1"))
+        g = t + 100
+        host += [(_ms(t + 3), _ms(g + 0.5), "engine.wait"),
+                 (_ms(g + 0.5), _ms(g + 1.5), "engine.commit"),
+                 (_ms(g + 1.6), _ms(g + 2.4), "worker.emit"),
+                 (_ms(g + 2.6), _ms(g + 3.4), "engine.plan"),
+                 (_ms(g + 3.4), _ms(g + 6.4), "engine.upload"),
+                 (_ms(g + 3.6), _ms(g + 6.2), "shard_args"),
+                 (_ms(g + 6.4), _ms(g + 11), "engine.dispatch"),
+                 (_ms(g + 6.5), _ms(g + 10.9),
+                  "PjitFunction(engine_step)")]
+    planes = [("/device:TPU:0", [
+        ("XLA Ops", ops),
+        ("XLA Modules", [(s, e, "jit_engine_step(123)")
+                         for s, e, _ in ops])]),
+        ("/host:CPU", [("engine-thread", host)])]
+    red = trace_reduce.reduce_planes(planes)
+    labels = [n for n, _ in red["idle_gaps"]]
+    assert labels == ["engine.upload"]
+    assert red["idle_gaps"][0][1] == pytest.approx(4 * 0.008)
+    assert list(red["modules"]) == ["jit_engine_step"]
+    ctx = {"trace": red, "run": {"decode_steps": 8}}
+    assert readers.evaluate(
+        {"trace_module_median_s": "engine_step"}, ctx) == pytest.approx(0.1)
+    assert readers.evaluate(
+        {"trace_module_median_s": "engine_decode_window_full"}, ctx) is None
+
+
+# -- (d) real compile counts ---------------------------------------------------
+
+@pytest.mark.parametrize("case,moves", [
+    ("new shape", True), ("repeat", False), ("another shape", True)])
+def test_jax_compiles_counts_first_dispatches_only(case, moves):
+    from dynamo_tpu.observability.ledger import install_jax_listeners
+    install_jax_listeners()
+    n = {"new shape": 3, "repeat": 3, "another shape": 5}[case]
+    f = jax.jit(lambda x: jnp.tanh(x) * 3.0 + float(n))
+    if case == "repeat":
+        f(jnp.ones((n,))).block_until_ready()
+    c0, s0 = LEDGER_STATS.jax_compiles, LEDGER_STATS.jax_compile_seconds
+    f(jnp.ones((n,))).block_until_ready()
+    assert (LEDGER_STATS.jax_compiles > c0) is moves
+    assert (LEDGER_STATS.jax_compile_seconds > s0) is moves
+
+
+def test_a_warm_engine_compiles_nothing():
+    eng = make_engine(pipeline_depth=1)
+    eng.generate(list(range(10, 40)), sampled(12), "a")
+    c0, r0 = LEDGER_STATS.jax_compiles, eng.ledger.recompiles_total
+    # another prompt of the same length: the same one would hit the prefix
+    # cache, prefill a shorter chunk, and rightly compile that bucket
+    eng.generate(list(range(50, 80)), sampled(12, 9), "b")
+    assert LEDGER_STATS.jax_compiles == c0
+    assert eng.ledger.recompiles_total == r0
+
+
+# -- (e) the engine-side split of first-token time -----------------------------
+
+def _generate(worker, rid, prompt, ctx, max_tokens=6):
+    from dynamo_tpu.protocols.common import (
+        PreprocessedRequest, SamplingOptions, StopConditions,
+    )
+    pre = PreprocessedRequest(
+        request_id=rid, token_ids=prompt,
+        sampling=SamplingOptions(temperature=0.0),
+        stop=StopConditions(max_tokens=max_tokens, ignore_eos=True))
+
+    async def consume():
+        async for _ in worker.generate(pre.model_dump(), ctx):
+            pass
+    return consume()
+
+
+@pytest.fixture(scope="module")
+def traced_requests():
+    """Two requests through a NativeEngineWorker, each under its own
+    `worker.generate` span as runtime/component.py opens it."""
+    from dynamo_tpu.llm.worker import NativeEngineWorker
+    SERVING.reset()
+    TRACER.configure(enabled=True, sample_rate=1.0, seed=0)
+    TRACER.drain()
+
+    async def main():
+        worker = await NativeEngineWorker(
+            make_engine(pipeline_depth=1)).start()
+        spans = {}
+        try:
+            for rid, prompt in (("q1", list(range(10, 30))),
+                                ("q2", list(range(40, 100)))):
+                ctx = Context(rid)
+                with TRACER.span("worker.generate",
+                                 TraceContext(f"trace-{rid}"),
+                                 request_id=rid) as span:
+                    ctx.trace = span.context()
+                    await _generate(worker, rid, prompt, ctx)
+                spans[rid] = span.span_id
+        finally:
+            await worker.stop()
+        return spans
+
+    try:
+        parents = asyncio.run(main())
+        recorded = TRACER.drain()
+    finally:
+        TRACER.configure(enabled=False)
+    counts = (SERVING.engine_queue_wait.count(),
+              SERVING.engine_prefill.count())
+    return parents, recorded, counts
+
+
+@pytest.mark.parametrize("rid", ["q1", "q2"])
+@pytest.mark.parametrize("name", ["engine.queue", "engine.prefill"])
+def test_first_token_split_is_a_child_of_worker_generate(
+        traced_requests, rid, name):
+    parents, recorded, _ = traced_requests
+    mine = [s for s in recorded if s["name"] == name
+            and s["trace_id"] == f"trace-{rid}"]
+    assert len(mine) == 1
+    assert mine[0]["parent_id"] == parents[rid]
+    assert mine[0]["dur"] >= 0.0
+    if name == "engine.prefill":
+        # a 60-token prompt rides two 32-token chunks, a 20-token one one
+        assert mine[0]["attrs"]["steps"] == (2 if rid == "q2" else 1)
+
+
+def test_first_token_histograms_observe_once_a_request(traced_requests):
+    _, recorded, counts = traced_requests
+    assert counts == (2, 2)
+    # the phases ride the same tracer under scope:engine; a program's
+    # first dispatch is recorded as `compile`
+    phases = {s["name"] for s in recorded
+              if s["trace_id"] == "scope:engine"}
+    assert phases >= {"plan", "upload", "wait", "commit"}
+    assert phases & {"dispatch", "compile"}
+
+
+def test_an_aborted_request_leaves_no_mark():
+    eng = make_engine()
+    eng.add_request(EngineRequest("gone", list(range(10, 30)), sampled(4)))
+    assert "gone" in eng._first_token_marks
+    eng.abort("gone")
+    assert not eng._first_token_marks
+    eng.generate(list(range(10, 30)), sampled(4), "kept")
+    assert not eng._first_token_marks
+
+
+# -- (f) what the listed layer metrics read exists -----------------------------
+
+def _listed_metrics():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        return [m["name"] for m in json.load(f)["per_layer"]]
+
+
+def _leaves(expr, out):
+    for key in ("prom", "prom_at_start", "engine", "trace_module_median_s"):
+        if key in expr:
+            out.append((key, expr[key]))
+    if "prom_hist_mean" in expr:
+        out += [("prom", expr["prom_hist_mean"] + "_sum"),
+                ("prom", expr["prom_hist_mean"] + "_count")]
+    for arg in expr.get("args", ()):
+        _leaves(arg, out)
+    return out
+
+
+@pytest.fixture(scope="module")
+def served_sources():
+    """`/metrics` of a tiny in-process service after one chat request, an
+    `EngineMetrics` snapshot, and the module names of its programs."""
+    from dynamo_tpu.frontend.service import HttpService
+    from dynamo_tpu.llm.model_card import ModelDeploymentCard
+    from dynamo_tpu.llm.pipeline import LocalPipeline
+    from dynamo_tpu.llm.worker import NativeEngineWorker
+    from tests.http_client import request
+
+    async def main():
+        eng = make_engine()
+        worker = await NativeEngineWorker(eng).start()
+        card = ModelDeploymentCard(
+            name="tiny-model", arch="tiny", tokenizer_kind="byte",
+            context_length=512, eos_token_ids=[2])
+        svc = await HttpService("127.0.0.1", 0).start()
+        svc.models.add("tiny-model", LocalPipeline(card, worker), "both")
+        try:
+            status, _ = await request(
+                "127.0.0.1", svc.port, "POST", "/v1/chat/completions",
+                {"model": "tiny-model", "max_tokens": 4,
+                 "messages": [{"role": "user", "content": "hi"}]})
+            assert status == 200
+            _, text = await request("127.0.0.1", svc.port, "GET",
+                                    "/metrics")
+            fields = await worker.submit(lambda e: vars(e.metrics()))
+        finally:
+            await svc.stop()
+            await worker.stop()
+        programs = [fn.__wrapped__.__name__ for fns in
+                    (eng._step_fns, eng._decode_fns) for fn in fns.values()]
+        return (readers.parse_prom(text.decode()), fields,
+                {f"jit_{n}" for n in programs})
+
+    return asyncio.run(main())
+
+
+@pytest.mark.parametrize("metric", _listed_metrics())
+def test_listed_layer_metric_reads_something_that_exists(
+        served_sources, metric):
+    """The guard that a rename cannot silently turn a metric into None."""
+    prom, fields, modules = served_sources
+    spec = readers.load_metric(metric, os.path.join(REPO, "benchmark"))
+    for kind, what in _leaves(spec["expr"], []):
+        if kind in ("prom", "prom_at_start"):
+            assert what in prom, f"{metric}: no series {what} on /metrics"
+        elif kind == "engine":
+            assert what in fields, f"{metric}: no EngineMetrics.{what}"
+        else:
+            pat = re.compile(what)
+            assert any(pat.search(m) for m in modules), \
+                f"{metric}: no program matches {what!r}"
+
+
+def test_full_window_pattern_matches_one_rung_only(served_sources):
+    _, _, modules = served_sources
+    pat = re.compile("engine_decode_window_full")
+    assert [m for m in modules if pat.search(m)] \
+        == ["jit_engine_decode_window_full"]
+    assert sum("engine_decode_window" in m for m in modules) == 3
+
+
+# -- the bounded capture -------------------------------------------------------
+
+def test_capture_profile_is_bounded_and_refuses_a_second(tmp_path):
+    from dynamo_tpu.llm.worker import NativeEngineWorker
+
+    async def main():
+        worker = await NativeEngineWorker(make_engine()).start()
+        try:
+            first = asyncio.create_task(
+                worker.capture_profile(0.3, str(tmp_path / "one")))
+            await asyncio.sleep(0.05)
+            with pytest.raises(RuntimeError, match="already running"):
+                await worker.capture_profile(0.1, str(tmp_path / "two"))
+            assert await first == str(tmp_path / "one")
+            # and again, once the first has stopped
+            await worker.capture_profile(0.1, str(tmp_path / "two"))
+        finally:
+            await worker.stop()
+    asyncio.run(main())
+    for d in ("one", "two"):
+        assert trace_reduce.find_xplane(str(tmp_path / d))
+
+
+@pytest.mark.parametrize("env,query,status", [
+    (False, "seconds=0.2", 404), (True, "seconds=0.2", 200),
+    (True, "seconds=abc", 400), (True, "seconds=600", 400)])
+def test_debug_profile_route(tmp_path, monkeypatch, env, query, status):
+    from dynamo_tpu.frontend.service import HttpService
+    from dynamo_tpu.llm.model_card import ModelDeploymentCard
+    from dynamo_tpu.llm.pipeline import LocalPipeline
+    from dynamo_tpu.llm.worker import NativeEngineWorker
+    from tests.http_client import request
+    if env:
+        monkeypatch.setenv("DYN_JAX_PROFILE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("DYN_JAX_PROFILE_DIR", raising=False)
+
+    async def main():
+        worker = await NativeEngineWorker(make_engine()).start()
+        card = ModelDeploymentCard(
+            name="tiny-model", arch="tiny", tokenizer_kind="byte",
+            context_length=512, eos_token_ids=[2])
+        svc = await HttpService("127.0.0.1", 0).start()
+        svc.models.add("tiny-model", LocalPipeline(card, worker), "both")
+        try:
+            return await request("127.0.0.1", svc.port, "POST",
+                                 f"/debug/profile?{query}")
+        finally:
+            await svc.stop()
+            await worker.stop()
+
+    got, body = asyncio.run(main())
+    assert got == status
+    if status == 200:
+        out = json.loads(body)["trace_dir"]
+        assert out.startswith(str(tmp_path))
+        assert trace_reduce.find_xplane(out)
+
+
+def test_no_whole_life_profile_hook_is_left():
+    """`jax.profiler.start_trace` lives in capture_profile alone, and the
+    worker has no process-wide owner any more."""
+    import dynamo_tpu.llm.worker as worker_mod
+    assert not hasattr(worker_mod, "_PROFILE_OWNER")
+    hits = []
+    for root, _, files in os.walk(os.path.join(REPO, "dynamo_tpu")):
+        for name in files:
+            if name.endswith(".py"):
+                with open(os.path.join(root, name)) as f:
+                    if "jax.profiler.start_trace" in f.read():
+                        hits.append(name)
+    assert hits == ["worker.py"]

@@ -9,9 +9,10 @@ breakdown:
 
 1. **Attribution pass** (pipeline_depth=1, engine.profile_sync=True): the
    engine's PhaseTimer splits each decode window's wall time into
-   plan / upload / dispatch / device / fetch / commit, and the harness
-   times detokenization of the emitted events — the full
-   "plan/upload/device/fetch/commit/detok" split per window.
+   plan / upload / dispatch / wait / commit (under profile_sync `wait`
+   is counted twice a window: the device's execution, then the output
+   fetch), and the harness times detokenization of the emitted events —
+   the full "plan/upload/dispatch/wait/commit/detok" split per window.
 2. **Overlap pass** (pipeline_depth=2): the same workload through the
    overlapped pipeline; reports wall-time speedup, the pipeline occupancy
    counters (windows / overlapped / fallbacks / host syncs / plan
@@ -23,7 +24,7 @@ breakdown:
    one-dispatch-per-window invariant (`decode_dispatches`,
    `dispatches_per_window` — the unified ragged kernel keeps the common
    decode window at EXACTLY one device dispatch). The fused sampling
-   tail never shows up in fetch/commit (it runs inside the window
+   tail never shows up in wait/commit (it runs inside the window
    program), so its cost is split out standalone: `sampler_tail` times
    the fused vs unfused tail at the same [slots, vocab] geometry.
 
@@ -155,7 +156,7 @@ def run_pass(args, depth: int, profile_sync: bool, trace_dir=None) -> dict:
 def sampler_tail_split(args, vocab_size: int) -> dict:
     """Standalone fused-vs-unfused sampling-tail timing at the decode
     geometry [slots, vocab]. Inside a fused window the tail's cost rides
-    the device leg (fetch/commit never see it), so attribution needs the
+    the device leg (the fetch and commit never see it), so attribution needs the
     tail measured on its own: `unfused_ms` is the full sort + double
     argsort + softmax-cumsum tail, `fused_ms` the single-argsort rank
     tail the common path dispatches (docs/PERF.md §3g)."""
