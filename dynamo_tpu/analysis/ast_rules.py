@@ -935,7 +935,7 @@ _R11_ANNOT_RE = re.compile(r"#\s*dynalint:\s*kv-codec")
 _R11_FLOAT_RE = re.compile(r"float|bfloat|bf16|f16|f32|fp16")
 _R11_HINT = (
     "route the read/write through ops/kv_quant.py (quantize_"
-    "rows / dequantize_rows / gather_dequant) or the codec-"
+    "rows / dequantize_rows, ops/attention.gather_values) or the codec-"
     "aware attention/write helpers, or annotate with "
     "`# dynalint: kv-codec` and say how the site preserves or "
     "decodes the representation")

@@ -28,8 +28,9 @@ from jax.sharding import PartitionSpec as P
 from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.ops.attention import (
     _softcap, decode_attention_deferred, decode_attention_split,
-    paged_attention, write_kv_pages, write_kv_pages_quant,
+    kv_write_plan, paged_attention, stored_kv_rows, write_kv_rows,
 )
+from dynamo_tpu.ops.kv_quant import cache_keys
 from dynamo_tpu.ops.kv_quant import validate_mode as _validate_kv_quant
 from dynamo_tpu.ops.moe import moe_dispatch_mlp, moe_dispatch_mlp_sharded
 from dynamo_tpu.ops.quant import wmat
@@ -346,11 +347,16 @@ def decode_forward(
 
     Returns (last_logits [B, V] f32, k_new [L, B, Hkv, hd],
     v_new [L, B, Hkv, hd], aux) — the caller scatters the new kv rows into
-    the cache in ONE in-place update per step. Rationale: threading cache
-    slices through the layer scan's outputs made XLA copy the whole cache
-    every step (~8 ms for the 1B flagship — the round-2 decode gap);
-    attention instead adds the current token via an explicit self-term
-    (ops/attention.decode_attention_deferred, ops/paged_attention.
+    the cache in ONE in-place update per step (engine._scatter_new_kv).
+    The pool is read in place: a layer gathers the pages its rows name
+    from the stacked leaves by (layer, page), or the kernel streams them;
+    no [Hkv, P, ps, hd] slice of a layer's pool is ever formed. Rationale:
+    threading cache slices through the layer scan's outputs made XLA copy
+    the whole cache every step (~8 ms for the 1B flagship — the round-2
+    decode gap; the same defect cost forward() a fifth of the device's
+    time until PR 26, PERF.md section 6); attention instead adds the
+    current token via an explicit self-term (ops/attention.
+    decode_attention_deferred, ops/paged_attention.
     combine_self_attention), which is exact because decode is causal.
 
     `window`: window-decode fast path — (k_base, v_base [L, Hkv, B, Lb,
@@ -424,24 +430,19 @@ def decode_forward(
                     prefix_lens, interpret=interp,
                     k_scale=scales[0], v_scale=scales[1])
             attn = combine_self_attention(q[:, 0], k_new, v_new, acc, m, l)
-        elif kvq:
-            # gather fallback, int8 cache: per-layer slices + scales;
-            # dequantization happens right after the page gather
-            # (ops/attention.py)  # dynalint: kv-codec
+        else:
+            # gather fallback: the stacked leaves go in whole and only
+            # this layer's pages come out (ops/attention.gather_values,
+            # which dequantizes an int8 pool right after the gather)
+            # dynalint: kv-codec — consumer gathers by (layer, page)
+            scales = ((cache["k_scale"], cache["v_scale"]) if kvq
+                      else (None, None))
             attn = decode_attention_deferred(
                 # dynalint: kv-codec — consumer dequantizes at gather
-                q[:, 0], cache["k"][lid], cache["v"][lid], k_new, v_new,
+                q[:, 0], cache["k"], cache["v"], k_new, v_new,
                 page_table, prefix_lens, softcap=cfg.attn_softcap,
                 window=wnd, q_scale=cfg.query_scale,
-                # dynalint: kv-codec — scale rows feed the dequant
-                k_scale=cache["k_scale"][lid],
-                v_scale=cache["v_scale"][lid])
-        else:
-            # dynalint: kv-codec — unquantized per-layer value slices
-            attn = decode_attention_deferred(
-                q[:, 0], cache["k"][lid], cache["v"][lid], k_new, v_new,
-                page_table, prefix_lens, softcap=cfg.attn_softcap,
-                window=wnd, q_scale=cfg.query_scale)
+                k_scale=scales[0], v_scale=scales[1], layer=lid)
         attn_out = jnp.einsum("bte,ed->btd",
                               attn.reshape(b, 1, h * hd),
                               wmat(lp["wo"], x.dtype))
@@ -512,6 +513,18 @@ def forward(
     plus an aux dict when with_aux=True (MoE capacity-drop counters summed
     over layers; empty for non-dispatch models).
 
+    The pool stays where it is: the stacked leaves ride the layer scan's
+    CARRY, never its xs / ys. A layer scatters the rows it produced into
+    them at (layer, head, page, slot) (ops/attention.write_kv_rows) and
+    then gathers the pages its rows name by (layer, page)
+    (gather_pages): write, then read, the arithmetic of a per-layer
+    write_kv_pages + paged_attention bit for bit, in place in the
+    donated buffers. As xs / ys every layer sliced its whole pool out of
+    the stack, re-laid it out for the scatter and back and copied it into
+    the stacked output: eight moves of 134 MB a layer for a few hundred
+    kilobytes of new rows (PERF.md section 6, PR 26). On an int8 pool the scale leaves travel
+    the same way.
+
     When sp_mesh is given, prefill (Tq > 1) runs ring attention with the
     sequence sharded over "sp" (ops/ring_attention.py) instead of attending
     to the paged cache — the engine guarantees such prefills are whole-prompt
@@ -564,16 +577,10 @@ def forward(
         kv_positions = jnp.where(idx < meta.kv_lens[:, None],
                                  meta.positions, -1)
 
-    def layer_step(x, layer):
-        if layer_wnd is not None:
-            layer, wnd = layer[:-1], layer[-1]
-        else:
-            wnd = None
-        if kvq:
-            lp, kc, vc, ksc, vsc = layer
-        else:
-            lp, kc, vc = layer
-            ksc = vsc = None
+    def layer_step(carry, layer):
+        x, pool = carry            # pool: (k, v[, k_scale, v_scale]) stacks
+        lp, lid = layer[:2]
+        wnd = layer[2] if layer_wnd is not None else None
         xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
         q = jnp.einsum("btd,de->bte", xn, wmat(lp["wq"], xn.dtype))
         k = jnp.einsum("btd,de->bte", xn, wmat(lp["wk"], xn.dtype))
@@ -585,25 +592,27 @@ def forward(
         v = v.reshape(b, tq, hkv, hd)
         q = apply_rope(q, meta.positions, cfg.rope_theta)
         k = apply_rope(k, meta.positions, cfg.rope_theta)
-        if kvq:
-            # capture-time quantization: rows quantize (per-row scale)
-            # inside this jitted step and scatter as int8+scale — no
-            # extra host sync, no dequantized shadow copy
-            kc, vc, ksc, vsc = write_kv_pages_quant(
-                kc, vc, ksc, vsc, k, v, meta.write_idx)
-        else:
-            kc, vc = write_kv_pages(kc, vc, k, v, meta.write_idx)
+        # rows as stored (an int8 pool quantizes them here, at capture);
+        # [B, Tq, Hkv, ...] -> this layer's [1, B*Tq, Hkv, ...]
+        pool = write_kv_rows(
+            pool, tuple(r.reshape((1, b * tq) + r.shape[2:])
+                        for r in stored_kv_rows(k, v, kvq)),
+            write_plan, lid[None])
+        kc, vc = pool[:2]
+        ksc, vsc = pool[2:] if kvq else (None, None)
         if use_kernel:
             # decode hot path: stream pages HBM->VMEM, no materialized gather
             interp = _decode_kernel_mode(cfg) == "interpret"
             if mesh is not None and mesh.size > 1:
                 attn = decode_paged_attention_sharded(
                     q[:, 0], kc, vc, meta.page_table, meta.kv_lens, mesh,
-                    interpret=interp, k_scale=ksc, v_scale=vsc)[:, None]
+                    interpret=interp, k_scale=ksc, v_scale=vsc,
+                    layer=lid[None])[:, None]
             else:
                 attn = decode_paged_attention(
                     q[:, 0], kc, vc, meta.page_table, meta.kv_lens,
-                    interpret=interp, k_scale=ksc, v_scale=vsc)[:, None]
+                    interpret=interp, k_scale=ksc, v_scale=vsc,
+                    layer=lid[None])[:, None]
         elif use_ring:
             attn = ring_attention(q, k, v, meta.positions, kv_positions,
                                   sp_mesh)
@@ -611,7 +620,7 @@ def forward(
             attn = paged_attention(q, kc, vc, meta.page_table, meta.kv_lens,
                                    meta.positions, softcap=cfg.attn_softcap,
                                    window=wnd, q_scale=cfg.query_scale,
-                                   k_scale=ksc, v_scale=vsc)
+                                   k_scale=ksc, v_scale=vsc, layer=lid)
         attn_out = jnp.einsum("bte,ed->btd", attn.reshape(b, tq, h * hd),
                               wmat(lp["wo"], x.dtype))
         if cfg.post_norms:
@@ -638,39 +647,33 @@ def forward(
             mlp = rms_norm(mlp, lp["post_mlp_norm"], cfg.rms_norm_eps,
                            cfg.norm_plus_one)
         x = x + mlp
-        out_c = (kc, vc, ksc, vsc) if kvq else (kc, vc)
-        ys = out_c + (drop_stats,) if moe_aux else out_c
-        return x, ys
+        return (x, pool), drop_stats
 
     moe_aux = cfg.is_moe and cfg.moe_impl == "dispatch"
     # real (non-padding) positions: padding slots carry write_idx < 0
     token_valid = meta.write_idx >= 0 if moe_aux else None
-    # dynalint: kv-codec — cache leaves enter the layer scan whole; all
-    # value decode/encode happens in the codec-aware paths above
-    scan_xs = (params["layers"], cache["k"], cache["v"])
+    write_plan = kv_write_plan(meta.write_idx)
+    # the stacked leaves ride the scan's carry whole, in the stored
+    # representation  # dynalint: kv-codec — values are encoded at the
+    # write (stored_kv_rows) and decoded at the gather (gather_values)
+    pool = (cache["k"], cache["v"])
     if kvq:
-        # dynalint: kv-codec — scale leaves ride the scan next to values
-        scan_xs = scan_xs + (cache["k_scale"], cache["v_scale"])
+        # dynalint: kv-codec — scale leaves ride the carry next to values
+        pool = pool + (cache["k_scale"], cache["v_scale"])
+    scan_xs = (params["layers"],
+               jnp.arange(cfg.num_layers, dtype=jnp.int32))
     if layer_wnd is not None:
         scan_xs = scan_xs + (layer_wnd,)
-    nc = 4 if kvq else 2
-    if moe_aux:
-        x, ys = jax.lax.scan(layer_step, x, scan_xs)
-        new_cache, drops = ys[:nc], ys[nc]
-        aux = {"moe_dropped": jnp.sum(drops[0]),
-               "moe_routed": jnp.sum(drops[1])}
-    else:
-        x, ys = jax.lax.scan(layer_step, x, scan_xs)
-        new_cache = ys[:nc]
-        aux = {}
+    (x, pool), drops = jax.lax.scan(layer_step, (x, pool), scan_xs)
+    aux = ({"moe_dropped": jnp.sum(drops[0]),
+            "moe_routed": jnp.sum(drops[1])} if moe_aux else {})
 
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
     head = (params["embed"].T if cfg.tie_word_embeddings
             else wmat(params["lm_head"], x.dtype))
     logits = _softcap(jnp.einsum("btd,dv->btv", x,
                                  head).astype(jnp.float32), cfg.final_softcap)
-    keys = ("k", "v", "k_scale", "v_scale") if kvq else ("k", "v")
-    cache_out = dict(zip(keys, new_cache))
+    cache_out = dict(zip(cache_keys(kvq), pool))
     if with_aux:
         return logits, cache_out, aux
     return logits, cache_out

@@ -24,17 +24,24 @@ separate [n_kv_heads, num_pages, page_size, head_dim] arrays per layer
 [2, blocks, block_size, heads, head_dim] tensor: head-major keeps one
 (head, page) slice contiguous (the decode kernel's DMA unit) and lets the
 kv-head axis shard cleanly over the `tp` mesh axis.
+
+The engine's programs touch the stacked leaves [L, Hkv, P, ps, hd] only
+through `gather_pages(..., layer=)` (the pages a page table names, by
+(layer, page)) and `write_kv_rows` (the rows a step produced, by (layer,
+head, page, slot)), both in place in the stored layout; the per-layer
+`write_kv_pages*` serve the pipeline-parallel and streaming layers, which
+keep per-stage and per-layer pools of their own.
 """
 # dynalint: hot-path — every op here runs inside jitted decode/prefill programs;
 # host syncs (.item(), device_get, float()) are dynalint R6 findings
 from __future__ import annotations
 
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.ops.kv_quant import gather_dequant, quantize_rows
+from dynamo_tpu.ops.kv_quant import dequantize_rows, quantize_rows
 
 NEG_INF = -1e30
 
@@ -50,12 +57,46 @@ def _softcap(scores: jax.Array, cap: float) -> jax.Array:
     return jnp.tanh(scores / cap) * cap
 
 
-def gather_pages(cache: jax.Array, page_table: jax.Array) -> jax.Array:
-    """[Hkv, P, ps, hd] gathered by [B, Pb] -> [Hkv, B, Pb*ps, hd]."""
+def gather_pages(cache: jax.Array, page_table: jax.Array,
+                 layer: Optional[jax.Array] = None) -> jax.Array:
+    """[Hkv, P, ps, hd] gathered by [B, Pb] -> [Hkv, B, Pb*ps, hd]; a
+    scale leaf [Hkv, P, ps] gives [Hkv, B, Pb*ps].
+
+    With `layer` (a traced int32 scalar) `cache` is the STACKED leaf
+    [L, Hkv, P, ps, ...] and the pages are read by (layer, page) in one
+    gather on it. `cache[layer]` followed by a take would do the same
+    arithmetic, but XLA materializes the [Hkv, P, ps, hd] slice first: a
+    copy of one layer's whole pool (134 MB at Mistral-7B widths and 1024
+    pages) to read a few dozen pages of it (PERF.md section 6, PR 26)."""
     b, pb = page_table.shape
-    hkv, _, ps, hd = cache.shape
-    gathered = jnp.take(cache, page_table.reshape(-1), axis=1)
-    return gathered.reshape(hkv, b, pb * ps, hd)
+    ids = page_table.reshape(-1)
+    if layer is None:
+        gathered = jnp.take(cache, ids, axis=1)
+    else:
+        gathered = jax.lax.gather(
+            cache,
+            jnp.stack([jnp.full_like(ids, layer), ids], axis=-1),
+            jax.lax.GatherDimensionNumbers(
+                # output [Hkv, B*Pb, ps[, hd]]: what the take above gives
+                offset_dims=(0,) + tuple(range(2, cache.ndim - 1)),
+                collapsed_slice_dims=(0, 2), start_index_map=(0, 2)),
+            slice_sizes=(1, cache.shape[1], 1) + cache.shape[3:],
+            mode="clip")
+    hkv, _, ps = gathered.shape[:3]
+    return gathered.reshape((hkv, b, pb * ps) + gathered.shape[3:])
+
+
+def gather_values(cache: jax.Array, scale: Optional[jax.Array],
+                  page_table: jax.Array, dtype,
+                  layer: Optional[jax.Array] = None) -> jax.Array:
+    """The K or V a page table names, [Hkv, B, Pb*ps, hd]: the pages as
+    stored, or on an int8 pool (`scale` given) dequantized at the gather
+    boundary to `dtype` — the one codec read site of the gather paths."""
+    pages = gather_pages(cache, page_table, layer)
+    if scale is None:
+        return pages
+    return dequantize_rows(pages, gather_pages(scale, page_table, layer),
+                           dtype)
 
 
 @jax.named_scope("attention")
@@ -71,21 +112,22 @@ def paged_attention(
     q_scale: float = 0.0,
     k_scale: Optional[jax.Array] = None,  # [Hkv, P, ps] f32 — int8 cache
     v_scale: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,  # caches/scales are [L, ...] stacks
 ) -> jax.Array:
-    """Causal attention of q against the paged KV prefix. Returns [B, Tq, H, hd]."""
+    """Causal attention of q against the paged KV prefix. Returns [B, Tq, H, hd].
+
+    With `layer` the caches (and scales) are the stacked leaves and only
+    the pages of that layer which `page_table` names are read
+    (gather_pages)."""
     b, tq, h, hd = q.shape
-    hkv = k_cache.shape[0]
+    hkv = k_cache.shape[0 if layer is None else 1]
     g = h // hkv
 
-    if k_scale is not None:
-        # int8 cache: dequantize at the gather boundary (the one codec
-        # read site for this path); downstream math is unchanged
-        k = gather_dequant(k_cache, k_scale, page_table, q.dtype)
-        v = gather_dequant(v_cache, v_scale, page_table, q.dtype)
-    else:
-        k = gather_pages(k_cache, page_table)  # [Hkv, B, Lk, hd]
-        v = gather_pages(v_cache, page_table)
-    lk = k.shape[2]
+    # int8 cache: dequantized at the gather boundary; downstream math is
+    # unchanged
+    k = gather_values(k_cache, k_scale, page_table, q.dtype, layer)
+    v = gather_values(v_cache, v_scale, page_table, q.dtype, layer)
+    lk = k.shape[2]                        # k, v: [Hkv, B, Lk, hd]
 
     qg = q.reshape(b, tq, hkv, g, hd)
     scores = jnp.einsum(
@@ -210,6 +252,7 @@ def decode_attention_deferred(
     q_scale: float = 0.0,
     k_scale: Optional[jax.Array] = None,  # [Hkv, P, ps] f32 — int8 cache
     v_scale: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,  # caches/scales are [L, ...] stacks
 ) -> jax.Array:
     """Decode attention with the current token's kv appended in registers.
 
@@ -221,17 +264,13 @@ def decode_attention_deferred(
     Returns [B, H, hd].
     """
     b, h, hd = q.shape
-    hkv = k_cache.shape[0]
+    hkv = k_cache.shape[0 if layer is None else 1]
     g = h // hkv
 
-    if k_scale is not None:
-        # int8 cache: dequantize at the gather boundary to q.dtype —
-        # the dequantized operand is the same width the bf16 path reads
-        k = gather_dequant(k_cache, k_scale, page_table, q.dtype)
-        v = gather_dequant(v_cache, v_scale, page_table, q.dtype)
-    else:
-        k = gather_pages(k_cache, page_table)  # [Hkv, B, Lk, hd]
-        v = gather_pages(v_cache, page_table)
+    # int8 cache: dequantized at the gather boundary to q.dtype — the
+    # dequantized operand is the same width the bf16 path reads
+    k = gather_values(k_cache, k_scale, page_table, q.dtype, layer)
+    v = gather_values(v_cache, v_scale, page_table, q.dtype, layer)
     lk = k.shape[2]
 
     sc = _scale(hd, q_scale)
@@ -263,6 +302,96 @@ def decode_attention_deferred(
     out = out + p_self[..., None] * v_new.astype(jnp.float32)[:, :, None, :]
     out = out / denom[..., None]
     return out.reshape(b, h, hd).astype(q.dtype)
+
+
+# tokens one scatter call of write_kv_rows writes. On a v5e a scattered
+# row costs ~67 ns whether it lands or is dropped (my chip run, PR 26), so
+# a [32, 16] mixed step that wrote all its 512 token slots would spend 9 ms
+# on 47 real tokens; blocks of valid rows cost what the step really writes.
+KV_WRITE_BLOCK = 32
+
+
+def stored_kv_rows(k_new: jax.Array, v_new: jax.Array, quant: bool) -> tuple:
+    """New K/V rows [..., hd] as the pool stores them, a tuple in
+    kv_quant.cache_keys order: (k, v), or on an int8 pool (k, v, k_scale,
+    v_scale) — capture-time quantization, each row against its own max
+    inside the jitted step, no dequantized shadow copy."""
+    if not quant:
+        return k_new, v_new
+    kq, ks = quantize_rows(k_new)
+    vq, vs = quantize_rows(v_new)
+    return kq, vq, ks, vs
+
+
+class KvWritePlan(NamedTuple):
+    """Which token slots a step writes, valid ones first (kv_write_plan)."""
+    write_idx: jax.Array            # [N] flat slot page*ps + offset; <0 skip
+    order: Optional[jax.Array]      # [ceil(N/block)*block] valid rows first
+    n_valid: Optional[jax.Array]    # scalar int32
+
+
+def kv_write_plan(write_idx: jax.Array) -> KvWritePlan:
+    """Layer-independent half of write_kv_rows: computed once a program,
+    outside the layer scan. Steps of at most one block write every row in
+    one scatter and need no order."""
+    write_idx = write_idx.reshape(-1)
+    n = write_idx.shape[0]
+    if n <= KV_WRITE_BLOCK:
+        return KvWritePlan(write_idx, None, None)
+    valid = write_idx >= 0
+    order = jnp.argsort(jnp.logical_not(valid), stable=True)
+    order = jnp.pad(order, (0, -n % KV_WRITE_BLOCK))
+    return KvWritePlan(write_idx, order,
+                       jnp.sum(valid, dtype=jnp.int32))
+
+
+def write_kv_rows(
+    pools: tuple,        # stacked leaves [L, Hkv, P, ps, hd] / scales [L, Hkv, P, ps]
+    rows: tuple,         # a leaf each: [Lw, N, Hkv, hd] / [Lw, N, Hkv]
+    plan: KvWritePlan,
+    layers: jax.Array,   # [Lw] int32: the layer rows[:, i] belong to
+) -> tuple:
+    """Scatter new KV rows into the stacked pool leaves, in place.
+
+    Every (layer, kv head, page, slot) is its own scatter index and a row
+    of `hd` values (or one scale) the window: the one formulation that
+    XLA:TPU applies to the pool's stored layout. With the kv-head axis a
+    window dimension (`.at[:, slot].set` on a [Hkv, P*ps, hd] view, as
+    write_kv_pages does) it re-lays the operand out so that Hkv sits next
+    to hd: a copy of the whole leaf there and back, 2 x 2.1 GB a leaf
+    for Mistral-7B-16 at 1024 pages (PERF.md section 6, PR 26). The
+    operand keeps its five axes, so a pool sharded over kv heads (`tp`)
+    is written shard by shard with no collective.
+
+    Rows are written a block of KV_WRITE_BLOCK valid tokens at a time in
+    a loop whose trip count follows `plan.n_valid`: padding rows and
+    padding tokens (`write_idx` < 0) cost nothing."""
+    _, hkv, p, ps = pools[0].shape[:4]
+    head = jnp.arange(hkv, dtype=jnp.int32)[None, None, :]
+    layer = layers.astype(jnp.int32)[:, None, None]
+
+    def scatter(leaves, blocks, idx):
+        # idx [n] -> index arrays [Lw, n, Hkv]; page p is out of range,
+        # which mode="drop" skips
+        page = jnp.where(idx >= 0, idx // ps, p)[None, :, None]
+        slot = (idx % ps)[None, :, None]
+        return tuple(
+            leaf.at[layer, head, page, slot].set(
+                blk.astype(leaf.dtype), mode="drop")
+            for leaf, blk in zip(leaves, blocks))
+
+    if plan.order is None:
+        return scatter(pools, rows, plan.write_idx)
+
+    def body(i, leaves):
+        at = i * KV_WRITE_BLOCK
+        take = jax.lax.dynamic_slice_in_dim(plan.order, at, KV_WRITE_BLOCK)
+        live = at + jnp.arange(KV_WRITE_BLOCK) < plan.n_valid
+        idx = jnp.where(live, plan.write_idx[take], -1)
+        return scatter(leaves, tuple(r[:, take] for r in rows), idx)
+
+    n_blocks = (plan.n_valid + KV_WRITE_BLOCK - 1) // KV_WRITE_BLOCK
+    return jax.lax.fori_loop(0, n_blocks, body, tuple(pools))
 
 
 def write_kv_pages(

@@ -106,16 +106,3 @@ def quantize_rows(x: jax.Array) -> tuple:
 def dequantize_rows(q: jax.Array, s: jax.Array, dtype) -> jax.Array:
     """(q int8 [..., hd], s f32 [...]) -> values [..., hd] in `dtype`."""
     return (q.astype(jnp.float32) * s[..., None]).astype(dtype)
-
-
-def gather_dequant(cache: jax.Array, scale: jax.Array,
-                   page_table: jax.Array, dtype) -> jax.Array:
-    """Paged gather + dequantize: [Hkv, P, ps, hd] int8 + [Hkv, P, ps]
-    f32 gathered by [B, Pb] -> [Hkv, B, Pb*ps, hd] in `dtype` — the
-    quantized twin of ops/attention.gather_pages."""
-    b, pb = page_table.shape
-    hkv, _, ps, hd = cache.shape
-    flat = page_table.reshape(-1)
-    g = jnp.take(cache, flat, axis=1).reshape(hkv, b, pb * ps, hd)
-    sg = jnp.take(scale, flat, axis=1).reshape(hkv, b, pb * ps)
-    return dequantize_rows(g, sg, dtype)

@@ -416,14 +416,17 @@ def decode_paged_attention(
     interpret: bool = False,
     k_scale: Optional[jax.Array] = None,  # [Hkv, P, ps] f32 (int8 cache)
     v_scale: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,    # [1] int32: caches are [L, ...]
 ) -> jax.Array:
     """Inclusive-mode view of the ragged kernel: returns [S, H, hd]
     attention of each decode token over its pages, kv_lens INCLUSIVE of
     the current token (already scattered into the pages).
 
-    The per-layer [Hkv, P, ps, hd] cache rides as a free `cache[None]`
-    single-layer view with layer index 0; the kernel's unnormalized
-    (acc, m, l) is normalized here (the historical in-kernel `acc / l`).
+    A per-layer [Hkv, P, ps, hd] cache rides as a free `cache[None]`
+    single-layer view with layer index 0; with `layer` the caches (and
+    scales) are the stacked leaves, which the kernel indexes itself. The
+    kernel's unnormalized (acc, m, l) is normalized here (the historical
+    in-kernel `acc / l`).
 
     With k_scale/v_scale (int8 cache) the scales are gathered by the page
     table outside the kernel and folded into the in-kernel score/prob
@@ -431,11 +434,14 @@ def decode_paged_attention(
     # padded decode slots carry kv_len 0; clamp so the page-0 warm-up DMA
     # and the 1/l normalization stay well-defined (their output is ignored)
     kv_lens = jnp.maximum(kv_lens, 1)
+    if layer is None:
+        layer = jnp.zeros((1,), jnp.int32)
+        k_cache, v_cache = k_cache[None], v_cache[None]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
     acc, _, l = ragged_decode_attention(
-        q, k_cache[None], v_cache[None], jnp.zeros((1,), jnp.int32),
-        page_table, kv_lens, interpret=interpret,
-        k_scale=None if k_scale is None else k_scale[None],
-        v_scale=None if v_scale is None else v_scale[None])
+        q, k_cache, v_cache, layer, page_table, kv_lens,
+        interpret=interpret, k_scale=k_scale, v_scale=v_scale)
     return (acc / l).astype(q.dtype)
 
 
@@ -450,6 +456,7 @@ def decode_paged_attention_sharded(
     interpret: bool = False,
     k_scale: Optional[jax.Array] = None,  # [Hkv, P, ps] f32 (int8 cache)
     v_scale: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,    # [1] int32: caches are [L, ...]
 ) -> jax.Array:
     """Multi-chip inclusive-mode kernel: shard_map over the "tp" mesh axis.
 
@@ -462,30 +469,27 @@ def decode_paged_attention_sharded(
     paged-attention kernels under --tensor-parallel-size (SURVEY.md §2.9).
     """
     head_spec = P(None, "tp", None)
-    cache_spec = P("tp", None, None, None)
+    stack = () if layer is None else (None,)     # the stacked leaves' L axis
+    cache_spec = P(*stack, "tp", None, None, None)
+    scale_spec = P(*stack, "tp", None, None)
+    args = (q, k_cache, v_cache, page_table, kv_lens)
     in_specs = (head_spec, cache_spec, cache_spec, P(None, None), P(None))
+    if layer is not None:
+        args, in_specs = args + (layer,), in_specs + (P(None),)
     if k_scale is not None:
-        scale_spec = P("tp", None, None)
-        f = jax.shard_map(
-            functools.partial(_decode_local_quant, interpret), mesh=mesh,
-            in_specs=in_specs + (scale_spec, scale_spec),
-            out_specs=head_spec, check_vma=False)
-        return f(q, k_cache, v_cache, page_table, kv_lens, k_scale, v_scale)
+        args, in_specs = (args + (k_scale, v_scale),
+                          in_specs + (scale_spec, scale_spec))
+
+    def local(q, k_cache, v_cache, page_table, kv_lens, *rest):
+        rest = list(rest)
+        lyr = rest.pop(0) if layer is not None else None
+        ks, vs = rest if rest else (None, None)
+        return decode_paged_attention(
+            q, k_cache, v_cache, page_table, kv_lens, interpret=interpret,
+            k_scale=ks, v_scale=vs, layer=lyr)
+
     # pallas_call output has no varying-mesh-axis annotation, hence
     # check_vma=False
-    f = jax.shard_map(functools.partial(_decode_local, interpret),
-                      mesh=mesh, in_specs=in_specs, out_specs=head_spec,
-                      check_vma=False)
-    return f(q, k_cache, v_cache, page_table, kv_lens)
-
-
-def _decode_local(interpret, q, k_cache, v_cache, page_table, kv_lens):
-    return decode_paged_attention(q, k_cache, v_cache, page_table, kv_lens,
-                                  interpret=interpret)
-
-
-def _decode_local_quant(interpret, q, k_cache, v_cache, page_table, kv_lens,
-                        k_scale, v_scale):
-    return decode_paged_attention(q, k_cache, v_cache, page_table, kv_lens,
-                                  interpret=interpret, k_scale=k_scale,
-                                  v_scale=v_scale)
+    f = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                      out_specs=head_spec, check_vma=False)
+    return f(*args)

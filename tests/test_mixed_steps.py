@@ -357,3 +357,102 @@ def test_mixed_page_width_uses_admission_bucket():
     ps = s.cfg.page_size
     need = -(-(8 + 100) // ps)  # a's admission-time page need
     assert plan.page_table.shape[1] >= next_bucket(need, s.page_buckets)
+
+
+# ---- the KV pool through the device programs (PERF.md section 6, PR 26) ----
+
+_POOL_PAGES, _POOL_PS = 23, 8      # 23 pages: a size no other axis has
+
+
+def _program_jaxprs(model_cfg):
+    """make_jaxpr of `_engine_step` and `_engine_decode_window` at a tiny
+    size, with the argument lists the engine dispatches them with."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.engine import engine as eng
+    from dynamo_tpu.models import llama
+
+    rows, chunk, pb, nw = 4, 16, 3, 4
+    params = jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), model_cfg))
+    cache = jax.eval_shape(
+        lambda: llama.init_cache(model_cfg, _POOL_PAGES, _POOL_PS))
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    vec, fvec = arr((rows,)), arr((rows,), jnp.float32)
+    step = functools.partial(eng._engine_step, model_cfg, (), None, None,
+                             False, False, False, None)
+    window = functools.partial(eng._engine_decode_window, model_cfg, (), None,
+                               nw, _POOL_PS, False, False, False, False)
+    return {
+        "engine_step": jax.make_jaxpr(step)(
+            params, cache, arr((rows, chunk)), arr((rows, chunk)),
+            arr((rows, pb)), vec, arr((rows, chunk)), vec, fvec, vec, fvec,
+            vec, vec, vec),
+        "engine_decode_window": jax.make_jaxpr(window)(
+            params, cache, vec, vec, arr((rows, pb)), arr((rows, 2)), vec,
+            fvec, vec, fvec, vec, vec, vec, arr((rows,), jnp.bool_),
+            arr((rows, 0))),
+    }, {leaf.shape for leaf in jax.tree_util.tree_leaves(cache)}
+
+
+def _walk(jaxpr, path=()):
+    """(path of enclosing primitives, equation) of a jaxpr and of every
+    jaxpr nested in its equations (scan / while / cond bodies, calls)."""
+    import jax
+    for eqn in jaxpr.eqns:
+        yield path, eqn
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (tuple, list)) else (val,)):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _walk(sub, path + (eqn.primitive.name,))
+
+
+@pytest.mark.parametrize("kv_quant", ["", "int8"])
+def test_no_program_slices_or_copies_a_layers_pool(kv_quant):
+    """Backend-independent shape of PR 26's mechanism: in `_engine_step`
+    and `_engine_decode_window` the pool travels only as WHOLE stacked
+    leaves. (1) no `scan` takes or gives a value with the page axis as
+    xs / ys: per-layer slices of the pool through a scan are what made
+    XLA copy 134 MB a layer six times over; the carry may hold the whole
+    leaf. (2) wherever the page axis appears, in any equation of any
+    nested body, the value has a leaf's full shape: no `[Hkv, P, ps, hd]`
+    slice, no flattened `P*ps` view."""
+    import dataclasses
+    jaxprs, leaf_shapes = _program_jaxprs(
+        dataclasses.replace(CFG, kv_quant=kv_quant))
+
+    def has_pages(var):
+        shape = tuple(getattr(var.aval, "shape", ()))
+        return (_POOL_PAGES in shape
+                or any(d % (_POOL_PAGES * _POOL_PS) == 0 and d
+                       for d in shape))
+
+    for name, closed in jaxprs.items():
+        scans = 0
+        for path, eqn in _walk(closed.jaxpr):
+            for var in list(eqn.invars) + list(eqn.outvars):
+                if has_pages(var):
+                    assert tuple(var.aval.shape) in leaf_shapes, (
+                        f"{name}: {'/'.join(path + (eqn.primitive.name,))} "
+                        f"handles {var.aval.str_short()}, not a whole leaf "
+                        f"of the pool {sorted(leaf_shapes)}")
+            if eqn.primitive.name != "scan":
+                continue
+            scans += 1
+            skip = eqn.params["num_consts"] + eqn.params["num_carry"]
+            sliced = [v.aval.str_short() for v in
+                      list(eqn.invars[skip:])
+                      + list(eqn.outvars[eqn.params["num_carry"]:])
+                      if has_pages(v)]
+            assert not sliced, (
+                f"{name}: a scan under {'/'.join(path) or 'the program'} "
+                f"moves the pool as xs / ys: {sliced}")
+        assert scans, f"{name}: no scan found; the walk is broken"
