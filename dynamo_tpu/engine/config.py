@@ -27,6 +27,10 @@ class ModelConfig:
     rope_theta: float = 500000.0
     rms_norm_eps: float = 1e-5
     attn_bias: bool = False      # q/k/v projection bias (Qwen2-style)
+    # OLMoE: RMSNorm over the WHOLE q and k projections (all heads
+    # together, one weight vector each: leaves q_norm / k_norm), applied
+    # before the split into heads and RoPE
+    qk_norm: bool = False
     # Gemma-family architecture deltas (HF GemmaForCausalLM):
     embed_scale: float = 0.0     # 0 = off; Gemma multiplies embeddings by
     #                              sqrt(hidden_size) before the first layer
@@ -61,11 +65,15 @@ class ModelConfig:
     # MoE (Mixtral-style); num_experts == 0 means dense MLP.
     num_experts: int = 0
     num_experts_per_tok: int = 2
-    # "dispatch" = capacity-based EP dispatch (ops/moe.py, serving default);
+    # router weights: softmax over ALL experts, the k largest kept; True
+    # (Mixtral) rescales the k to sum to one, False (OLMoE's published
+    # `norm_topk_prob`) keeps them as they are
+    norm_topk_prob: bool = True
+    # "dispatch" = routed dispatch (ops/moe.py, serving default: dropless
+    # sorted dispatch on one device, capacity-based on --tp/--ep meshes);
     # "dense" = every expert computes every token (exact, E/k x FLOPs —
     # oracle for tests)
     moe_impl: str = "dispatch"
-    moe_capacity_factor: float = 2.0
     # decode attention impl: "auto" and "off" are the XLA gather path on
     # every platform (models/llama._decode_kernel_mode says why); "on" is
     # the compiled Pallas kernel and raises at engine construction where it
@@ -97,6 +105,21 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def moe_dropless(self) -> bool:
+        """Whether the "dispatch" impl is the dropless sorted dispatch
+        (ops/moe.py moe_dropless_mlp) or the capacity form. Many small
+        experts need the first: at 64 experts of 8 a token the capacity
+        form drops assignments in every chunk and computes every expert
+        for every decode row. Up to eight wide experts (Mixtral) keep the
+        capacity form for now, on measurements (PERF.md section 6, PR
+        27): in its closed cell the dropless form was no slower per
+        token, but each program's first dispatch traces three Pallas
+        calls more (set-up 62 s against 54.5 s warm), and an all-real
+        prefill chunk re-reads an expert's weights once per row tile
+        (8.6 ms a layer against 5.9)."""
+        return self.num_experts > 8
 
 
 @dataclasses.dataclass(frozen=True)

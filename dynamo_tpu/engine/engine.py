@@ -88,6 +88,17 @@ class NativeEngine:
         # VERDICT r3 weak #7 + r4 #6); logprob/penalty plans fall back to
         # per-token dispatch.
         self.pp = self.mesh.shape.get("pp", 1)
+        if self.mesh.size > 1 and model_cfg.moe_dropless \
+                and model_cfg.moe_impl == "dispatch":
+            # the dropless dispatch (ops/moe.py) is one device's; what a
+            # mesh gets is the capacity form, sized for 8 experts: at 64
+            # experts of 8 a token it drops assignments in every chunk
+            # and computes every expert for every decode row
+            raise ValueError(
+                f"num_experts={model_cfg.num_experts}: a many-expert "
+                f"model is served on one device only (the multi-device "
+                f"MoE dispatch is capacity-based and would drop "
+                f"assignments); run it without --tp/--ep/--dp")
         if self.pp > 1:
             if model_cfg.is_moe:
                 raise ValueError("pp requires a dense model; shard MoE "
@@ -775,8 +786,15 @@ class NativeEngine:
             lambda n: next_bucket(n, pow2_buckets(self.cfg.max_model_len)))
 
     def _account_moe(self, aux) -> None:
-        """MoE capacity-drop accounting (GShard dispatch drops tokens over
-        expert capacity silently otherwise — ADVICE r1 medium)."""
+        """Fold a step's MoE stats (ops/moe.py moe_stats, already on the
+        host with the step's outputs) into the `llm_engine_moe_*_total`
+        series, and warn once where a capacity dispatch drops (it does so
+        silently otherwise — ADVICE r1 medium)."""
+        from dynamo_tpu.observability.ledger import LEDGER_STATS
+        for key, value in aux.items():
+            name = f"{key}_total"
+            setattr(LEDGER_STATS, name,
+                    getattr(LEDGER_STATS, name) + float(value))
         self.moe_dropped_tokens += float(aux["moe_dropped"])
         self.moe_routed_tokens += float(aux["moe_routed"])
         rate = self.moe_drop_rate()
@@ -784,11 +802,9 @@ class NativeEngine:
                 and self.moe_routed_tokens > 1000:
             self._moe_drop_warned = True
             logging.getLogger(__name__).warning(
-                "MoE dispatch dropping %.2f%% of (token, expert) "
-                "assignments over capacity (capacity_factor=%s); "
-                "outputs are degraded — raise moe_capacity_factor or "
-                "use moe_impl='dense'", rate * 100,
-                self.model_cfg.moe_capacity_factor)
+                "MoE capacity dispatch dropping %.2f%% of (token, expert) "
+                "assignments; outputs are degraded (llm_engine_moe_"
+                "dropped_total on /metrics)", rate * 100)
 
     def _wants_logprobs(self, reqs) -> bool:
         return any(seq is not None and

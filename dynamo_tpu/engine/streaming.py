@@ -487,6 +487,14 @@ class StreamingDecoder:
         self.engine = engine
         cfg = engine.model_cfg
         ecfg = engine.cfg
+        if cfg.qk_norm:
+            # the streamed layer below is a copy of models/llama's
+            # (ROADMAP D3) without the q/k RMSNorm: it would serve
+            # another model in silence
+            raise ValueError(
+                "qk_norm: tiered-KV streaming's per-layer loop does not "
+                "apply the q/k RMSNorm of this configuration; serve it "
+                "with stream_pages=0")
         self.cfg = cfg
         self.ecfg = ecfg
         self.quant = bool(cfg.kv_quant)

@@ -586,6 +586,14 @@ class HttpService:
                 out.append(one)
                 return out
 
+            async def stop_when_gone():
+                # a request still in prefill yields no chunk for the loop
+                # below to notice a disconnect on: stop it where the
+                # monitor sees the client go, not at its first token
+                await http_req.disconnected.wait()
+                ctx.stop_generating()
+
+            gone = asyncio.create_task(stop_when_gone())
             try:
                 async for chunk in chunk_gen:
                     if http_req.disconnected.is_set():
@@ -658,6 +666,7 @@ class HttpService:
                     event="error", data=str(e))).encode()
                 status = "error"
             finally:
+                gone.cancel()
                 ctx.stop_generating()
                 finish(status)
 

@@ -32,8 +32,10 @@ from dynamo_tpu.ops.attention import (
 )
 from dynamo_tpu.ops.kv_quant import cache_keys
 from dynamo_tpu.ops.kv_quant import validate_mode as _validate_kv_quant
-from dynamo_tpu.ops.moe import moe_dispatch_mlp, moe_dispatch_mlp_sharded
-from dynamo_tpu.ops.quant import wmat
+from dynamo_tpu.ops.moe import (
+    moe_dispatch_mlp, moe_dispatch_mlp_sharded, moe_dropless_mlp, route_topk,
+)
+from dynamo_tpu.ops.quant import is_quantized, wmat
 from dynamo_tpu.ops.paged_attention import (
     combine_self_attention, decode_paged_attention,
     decode_paged_attention_prefix, decode_paged_attention_prefix_sharded,
@@ -134,6 +136,15 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
             "wk_b": jnp.zeros((l, hkv * hd), dt),
             "wv_b": jnp.zeros((l, hkv * hd), dt),
         })
+    if cfg.qk_norm:
+        # seeded, not ones: a norm weight of one would hide a check that
+        # skipped it or applied it per head with the first head's slice
+        layers.update({
+            "q_norm": (1.0 + 0.1 * jax.random.normal(
+                keys[10], (l, h * hd), jnp.float32)).astype(dt),
+            "k_norm": (1.0 + 0.1 * jax.random.normal(
+                keys[11], (l, hkv * hd), jnp.float32)).astype(dt),
+        })
     if cfg.is_moe:
         e = cfg.num_experts
         layers.update({
@@ -190,6 +201,8 @@ def param_shardings(cfg: ModelConfig) -> Params:
             "wk_b": P(None, "tp"),
             "wv_b": P(None, "tp"),
         })
+    if cfg.qk_norm:
+        layers.update({"q_norm": P(None, "tp"), "k_norm": P(None, "tp")})
     if cfg.is_moe:
         # experts shard over "ep", each expert's FFN dim over "tp"; on
         # meshes without those axes (size 1) the specs are no-ops
@@ -308,9 +321,8 @@ def _moe_mlp(x: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
     """
     b, t, d = x.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
-    logits = jnp.einsum("btd,de->bte", x.astype(jnp.float32), lp["router"].astype(jnp.float32))
-    weights, idx = jax.lax.top_k(logits, k)                    # [B, T, k]
-    weights = jax.nn.softmax(weights, axis=-1)
+    weights, idx = route_topk(x, lp["router"], k,
+                              cfg.norm_topk_prob)              # [B, T, k]
     one_hot = jax.nn.one_hot(idx, e, dtype=jnp.float32)        # [B, T, k, E]
     combine = jnp.einsum("btk,btke->bte", weights, one_hot)    # [B, T, E]
 
@@ -328,6 +340,71 @@ def _dense_mlp(x: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
     up = jnp.einsum("btd,df->btf", x, wmat(lp["w_up"], x.dtype))
     act = mlp_activation(gate, cfg) * up
     return jnp.einsum("btf,fd->btd", act, wmat(lp["w_down"], x.dtype))
+
+
+def qkv_proj(xn: jax.Array, lp: Params, cfg: ModelConfig):
+    """x -> (q [B, T, H*hd], k, v [B, T, Hkv*hd]), before the split into
+    heads and RoPE. With `cfg.qk_norm` (OLMoE) q and k each pass an
+    RMSNorm over the WHOLE projection, all heads together, with its own
+    weight vector; what reaches the cache is the normed, rotated k."""
+    q = jnp.einsum("btd,de->bte", xn, wmat(lp["wq"], xn.dtype))
+    k = jnp.einsum("btd,de->bte", xn, wmat(lp["wk"], xn.dtype))
+    v = jnp.einsum("btd,de->bte", xn, wmat(lp["wv"], xn.dtype))
+    if cfg.attn_bias:
+        q, k, v = q + lp["wq_b"], k + lp["wk_b"], v + lp["wv_b"]
+    if cfg.qk_norm:
+        with jax.named_scope("attention.qk_norm"):
+            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
+    return q, k, v
+
+
+EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def _use_dropless(cfg: ModelConfig, mesh) -> bool:
+    return (cfg.is_moe and cfg.moe_impl == "dispatch" and cfg.moe_dropless
+            and (mesh is None or mesh.size == 1))
+
+
+def split_expert_stacks(layers: Params, cfg: ModelConfig, mesh):
+    """(the leaves the layer scan slices, the expert stacks it does not).
+    On the dropless path the stacked [L, E, ...] expert leaves stay out of
+    the scan's xs and are handed to the layer whole with its index: the
+    grouped-matmul kernel reads a layer's experts where they lie, where a
+    slice of the stack would be copied before every call. Quantized
+    leaves are dequantized a layer at a time and stay in the scan."""
+    if not _use_dropless(cfg, mesh) or is_quantized(layers["w_gate"]):
+        return layers, None
+    return ({k: v for k, v in layers.items() if k not in EXPERT_LEAVES},
+            {k: layers[k] for k in EXPERT_LEAVES})
+
+
+def _mlp_block(xn: jax.Array, lp: Params, cfg: ModelConfig, mesh,
+               token_valid, stacks=None, lid=None):
+    """The layer's MLP: (out, stats). stats is None except on the MoE
+    dispatch paths, where it is ops/moe.py's `moe_stats` dict. `stacks`
+    and `lid`: split_expert_stacks' second half and this layer's index."""
+    if not cfg.is_moe:
+        return _dense_mlp(xn, lp, cfg), None
+    if cfg.moe_impl == "dense":
+        return _moe_mlp(xn, lp, cfg), None
+    if mesh is not None and mesh.shape.get("ep", 1) > 1:
+        # explicit O(E/ep) per-shard dispatch (ops/moe.py sharded path)
+        return moe_dispatch_mlp_sharded(
+            xn, lp, cfg, mesh, return_dropped=True, valid=token_valid)
+    if _use_dropless(cfg, mesh):
+        if stacks is not None:
+            return moe_dropless_mlp(xn, {**lp, **stacks}, cfg,
+                                    valid=token_valid, layer=lid)
+        return moe_dropless_mlp(xn, lp, cfg, valid=token_valid)
+    return moe_dispatch_mlp(xn, lp, cfg, return_dropped=True,
+                            valid=token_valid)
+
+
+def _sum_stats(stats) -> dict:
+    """Per-layer (and per-step) stacks of MoE stats -> one scalar each."""
+    return {k: jnp.sum(v) for k, v in (stats or {}).items()}
 
 
 def decode_forward(
@@ -394,11 +471,7 @@ def decode_forward(
         else:
             lp, lid = xs
         xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
-        q = jnp.einsum("btd,de->bte", xn, wmat(lp["wq"], xn.dtype))
-        k = jnp.einsum("btd,de->bte", xn, wmat(lp["wk"], xn.dtype))
-        v = jnp.einsum("btd,de->bte", xn, wmat(lp["wv"], xn.dtype))
-        if cfg.attn_bias:
-            q, k, v = q + lp["wq_b"], k + lp["wk_b"], v + lp["wv_b"]
+        q, k, v = qkv_proj(xn, lp, cfg)
         q = apply_rope(q.reshape(b, 1, h, hd), positions[:, None],
                        cfg.rope_theta)
         k = apply_rope(k.reshape(b, 1, hkv, hd), positions[:, None],
@@ -451,20 +524,8 @@ def decode_forward(
                                 cfg.rms_norm_eps, cfg.norm_plus_one)
         x = x + attn_out
         xn = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
-        drop_stats = None
-        if not cfg.is_moe:
-            mlp = _dense_mlp(xn, lp, cfg)
-        elif cfg.moe_impl == "dense":
-            mlp = _moe_mlp(xn, lp, cfg)
-        elif mesh is not None and mesh.shape.get("ep", 1) > 1:
-            # explicit O(E/ep) per-shard dispatch (ops/moe.py sharded path)
-            mlp, drop_stats = moe_dispatch_mlp_sharded(
-                xn, lp, cfg, mesh, cfg.moe_capacity_factor,
-                return_dropped=True, valid=token_valid)
-        else:
-            mlp, drop_stats = moe_dispatch_mlp(
-                xn, lp, cfg, cfg.moe_capacity_factor, return_dropped=True,
-                valid=token_valid)
+        mlp, drop_stats = _mlp_block(xn, lp, cfg, mesh, token_valid,
+                                     expert_stacks, lid)
         if cfg.post_norms:
             mlp = rms_norm(mlp, lp["post_mlp_norm"], cfg.rms_norm_eps,
                            cfg.norm_plus_one)
@@ -472,21 +533,21 @@ def decode_forward(
         ys = (k_new, v_new, drop_stats) if moe_aux else (k_new, v_new)
         return x, ys
 
+    scan_layers, expert_stacks = split_expert_stacks(params["layers"], cfg,
+                                                     mesh)
     if window is not None:
         kb_all, vb_all, kw_all, vw_all, base_lens, win_lens = window
-        xs = (params["layers"], layer_ids, kb_all, vb_all, kw_all, vw_all)
+        xs = (scan_layers, layer_ids, kb_all, vb_all, kw_all, vw_all)
     else:
-        xs = (params["layers"], layer_ids)
+        xs = (scan_layers, layer_ids)
     if layer_wnd is not None:
         xs = xs + (layer_wnd,)
     x, ys = jax.lax.scan(layer_step, x, xs)
     if moe_aux:
         k_news, v_news, drops = ys
-        aux = {"moe_dropped": jnp.sum(drops[0]),
-               "moe_routed": jnp.sum(drops[1])}
     else:
-        k_news, v_news = ys
-        aux = {}
+        (k_news, v_news), drops = ys, None
+    aux = _sum_stats(drops)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
     head = (params["embed"].T if cfg.tie_word_embeddings
             else wmat(params["lm_head"], x.dtype))
@@ -507,7 +568,7 @@ def forward(
     embeds_mask: Optional[jax.Array] = None,   # [B, Tq] bool: mix per-token
     sp_mesh=None,  # Mesh with an "sp" axis: ring-attention prefill
     mesh=None,     # multi-device Mesh: shard_map the decode kernel over "tp"
-    with_aux: bool = False,  # also return {"moe_dropped","moe_routed"}
+    with_aux: bool = False,  # also return the summed ops/moe.py moe_stats
 ) -> tuple:
     """One paged forward step. Returns (logits [B, Tq, V], updated cache),
     plus an aux dict when with_aux=True (MoE capacity-drop counters summed
@@ -582,11 +643,7 @@ def forward(
         lp, lid = layer[:2]
         wnd = layer[2] if layer_wnd is not None else None
         xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
-        q = jnp.einsum("btd,de->bte", xn, wmat(lp["wq"], xn.dtype))
-        k = jnp.einsum("btd,de->bte", xn, wmat(lp["wk"], xn.dtype))
-        v = jnp.einsum("btd,de->bte", xn, wmat(lp["wv"], xn.dtype))
-        if cfg.attn_bias:
-            q, k, v = q + lp["wq_b"], k + lp["wk_b"], v + lp["wv_b"]
+        q, k, v = qkv_proj(xn, lp, cfg)
         q = q.reshape(b, tq, h, hd)
         k = k.reshape(b, tq, hkv, hd)
         v = v.reshape(b, tq, hkv, hd)
@@ -629,20 +686,8 @@ def forward(
         x = x + attn_out
 
         xn = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
-        drop_stats = None
-        if not cfg.is_moe:
-            mlp = _dense_mlp(xn, lp, cfg)
-        elif cfg.moe_impl == "dense":
-            mlp = _moe_mlp(xn, lp, cfg)
-        elif mesh is not None and mesh.shape.get("ep", 1) > 1:
-            # explicit O(E/ep) per-shard dispatch (ops/moe.py sharded path)
-            mlp, drop_stats = moe_dispatch_mlp_sharded(
-                xn, lp, cfg, mesh, cfg.moe_capacity_factor,
-                return_dropped=True, valid=token_valid)
-        else:
-            mlp, drop_stats = moe_dispatch_mlp(
-                xn, lp, cfg, cfg.moe_capacity_factor, return_dropped=True,
-                valid=token_valid)
+        mlp, drop_stats = _mlp_block(xn, lp, cfg, mesh, token_valid,
+                                     expert_stacks, lid)
         if cfg.post_norms:
             mlp = rms_norm(mlp, lp["post_mlp_norm"], cfg.rms_norm_eps,
                            cfg.norm_plus_one)
@@ -660,13 +705,13 @@ def forward(
     if kvq:
         # dynalint: kv-codec — scale leaves ride the carry next to values
         pool = pool + (cache["k_scale"], cache["v_scale"])
-    scan_xs = (params["layers"],
-               jnp.arange(cfg.num_layers, dtype=jnp.int32))
+    scan_layers, expert_stacks = split_expert_stacks(params["layers"], cfg,
+                                                     mesh)
+    scan_xs = (scan_layers, jnp.arange(cfg.num_layers, dtype=jnp.int32))
     if layer_wnd is not None:
         scan_xs = scan_xs + (layer_wnd,)
     (x, pool), drops = jax.lax.scan(layer_step, (x, pool), scan_xs)
-    aux = ({"moe_dropped": jnp.sum(drops[0]),
-            "moe_routed": jnp.sum(drops[1])} if moe_aux else {})
+    aux = _sum_stats(drops)
 
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
     head = (params["embed"].T if cfg.tie_word_embeddings

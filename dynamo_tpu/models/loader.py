@@ -27,6 +27,7 @@ ARCHES = {
     "GemmaForCausalLM": "gemma",
     "Gemma2ForCausalLM": "gemma2",
     "Phi3ForCausalLM": "phi3",
+    "OlmoeForCausalLM": "olmoe",
 }
 
 
@@ -38,7 +39,14 @@ def config_from_hf(hf: Dict[str, Any], name: str = "") -> ModelConfig:
                          f"(supported: {sorted(ARCHES)})")
     family = ARCHES[arch]
     heads = hf["num_attention_heads"]
-    moe = family == "mixtral"
+    olmoe = family == "olmoe"
+    moe = family == "mixtral" or olmoe
+    if hf.get("clip_qkv") is not None:
+        # OLMoE's optional clamp of q/k/v to +-clip_qkv is not modeled:
+        # ignoring it would serve another function under the model's name
+        raise ValueError(
+            f"clip_qkv={hf['clip_qkv']!r} is not supported (only "
+            f"clip_qkv: null is modeled)")
     gemma = family in ("gemma", "gemma2")
     gemma2 = family == "gemma2"
     act = hf.get("hidden_activation") or hf.get("hidden_act") or "silu"
@@ -103,8 +111,16 @@ def config_from_hf(hf: Dict[str, Any], name: str = "") -> ModelConfig:
         if gemma2 and hf.get("query_pre_attn_scalar") else 0.0,
         sliding_window=sliding,
         sliding_pattern=sliding_pattern,
-        num_experts=int(hf.get("num_local_experts", 0)) if moe else 0,
+        # Mixtral counts its experts in `num_local_experts`, OLMoE in
+        # `num_experts`; in both `intermediate_size` is ONE expert's width
+        num_experts=int(hf.get("num_experts" if olmoe
+                               else "num_local_experts", 0)) if moe else 0,
         num_experts_per_tok=int(hf.get("num_experts_per_tok", 2)),
+        # Mixtral always rescales the k kept router weights; OLMoE says
+        # (published: false, the weights stay a slice of the full softmax)
+        norm_topk_prob=bool(hf.get("norm_topk_prob", False)) if olmoe
+        else True,
+        qk_norm=olmoe,
     )
 
 
@@ -135,6 +151,9 @@ def load_params_from_hf(path: str, cfg: ModelConfig,
       .mlp.{gate,up,down}_proj.weight.T  -> w_gate/w_up/w_down[i]
       .block_sparse_moe.gate.weight.T    -> router[i]        (Mixtral)
       .block_sparse_moe.experts.{e}.w{1,3,2}.T -> w_gate/up/down[i,e]
+      .mlp.gate.weight.T                 -> router[i]        (OLMoE)
+      .mlp.experts.{e}.{gate,up,down}_proj.weight.T -> w_gate/up/down[i,e]
+      .self_attn.{q,k}_norm.weight       -> q_norm/k_norm[i] (OLMoE)
       model.norm.weight                  -> final_norm
       lm_head.weight.T                   -> lm_head (absent when tied)
     """
@@ -189,12 +208,22 @@ def load_params_from_hf(path: str, cfg: ModelConfig,
             layers[ours] = stack(
                 lambda i, p=theirs:
                 w(f"model.layers.{i}.self_attn.{p}.bias"))
+    if cfg.qk_norm:
+        for ours in ("q_norm", "k_norm"):
+            layers[ours] = stack(
+                lambda i, n=ours:
+                w(f"model.layers.{i}.self_attn.{n}.weight"))
     if cfg.is_moe:
-        moe = "model.layers.{}.block_sparse_moe"
+        if "model.layers.0.mlp.gate.weight" in raw:          # OLMoE names
+            moe = "model.layers.{}.mlp"
+            names = (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                     ("w_down", "down_proj"))
+        else:                                                # Mixtral
+            moe = "model.layers.{}.block_sparse_moe"
+            names = (("w_gate", "w1"), ("w_up", "w3"), ("w_down", "w2"))
         layers["router"] = stack(
             lambda i: t(moe.format(i) + ".gate.weight"))
-        for ours, theirs in (("w_gate", "w1"), ("w_up", "w3"),
-                             ("w_down", "w2")):
+        for ours, theirs in names:
             layers[ours] = np.stack([
                 np.stack([t(moe.format(i) + f".experts.{e}.{theirs}.weight")
                           for e in range(cfg.num_experts)])

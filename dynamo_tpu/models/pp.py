@@ -45,8 +45,25 @@ from dynamo_tpu.ops.attention import (
 )
 
 
+def refuse_unimplemented(cfg: ModelConfig) -> None:
+    """`_stage` is a copy of models/llama.forward's layer (ROADMAP D3) and
+    lacks what that layer gained since: it would serve another model in
+    silence. Called where a pp engine is built (pp_param_shardings)."""
+    if cfg.qk_norm:
+        raise ValueError(
+            "qk_norm: the pipeline-parallel stage (models/pp._stage) does "
+            "not apply the q/k RMSNorm of this configuration; serve it "
+            "without a pp mesh")
+    if cfg.is_moe and not cfg.norm_topk_prob:
+        raise ValueError(
+            "norm_topk_prob: the pipeline-parallel stage (models/pp."
+            "_stage) has no expert layer, with or without renormalised "
+            "router weights; serve this configuration without a pp mesh")
+
+
 def pp_param_shardings(cfg: ModelConfig) -> Params:
     """Layer-stacked params: layer axis over "pp", head/FFN dims over "tp"."""
+    refuse_unimplemented(cfg)
     layers = {
         "attn_norm": P("pp", None),
         "wq": P("pp", None, "tp"),

@@ -116,6 +116,14 @@ class LedgerStats:
         "jax_compiles",           # backend compiles + persistent-cache loads
         "jax_compile_seconds",    # wall time inside them
         "jax_cache_hits",         # of those, loads from the persistent cache
+        # MoE dispatch (ops/moe.py moe_stats), cumulative over every layer
+        # call of every step, from the aux a step's outputs already carry:
+        "moe_routed_total",       # (token, expert) assignments of real tokens
+        "moe_dropped_total",      # of those, lost over an expert's capacity
+        "moe_expert_rows_total",  # rows the expert matmuls computed, padding
+        #                           and tile rounding included
+        "moe_experts_hit_total",  # experts with >= 1 assignment, per call
+        "moe_layer_calls_total",  # layer calls the above were summed over
     )
 
     def __init__(self):

@@ -1,0 +1,78 @@
+"""Compiles for the chip, without one (the TPU compiler is installed here
+and compiles for a DESCRIBED v5e): what interpret mode and the CPU cannot
+show. All such tests live in THIS file, and the topology is described
+inside a fixture, never at import: one process at a time may load the
+TPU's library, and every xdist worker imports every test file.
+"""
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from dynamo_tpu.engine.config import ModelConfig
+from dynamo_tpu.models import llama
+from dynamo_tpu.ops import moe
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler here, or it is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# OLMoE's published widths, three layers of expert weights
+OLMOE = ModelConfig(name="olmoe", hidden_size=2048, intermediate_size=1024,
+                    num_layers=3, num_experts=64, num_experts_per_tok=8,
+                    norm_topk_prob=False)
+_MOVES = re.compile(r"=\s*bf16\[64,(?:2048,1024|1024,2048)\]\S*\s+"
+                    r"(copy|fusion|dynamic-slice|transpose)\(")
+
+
+def _expert_layers_hlo(one_chip, in_place: bool) -> str:
+    """Optimised HLO of a scan over OLMOE's expert layers on a [32, 16]
+    step, the way forward() runs them (`in_place`), or with every layer's
+    expert leaves sliced out of the stack by the scan, as before PR 27's
+    second chip call."""
+    cfg = OLMOE
+    l, d, f, e = cfg.num_layers, 2048, 1024, 64
+
+    def run(x, layers):
+        scan_layers, stacks = llama.split_expert_stacks(layers, cfg, None)
+        if not in_place:
+            scan_layers, stacks = layers, None
+
+        def body(x, xs):
+            lp, lid = xs
+            out, stats = llama._mlp_block(x, lp, cfg, None, None, stacks,
+                                          lid)
+            return x + out, stats["moe_routed"]
+        return jax.lax.scan(body, x, (scan_layers,
+                                      jnp.arange(l, dtype=jnp.int32)))
+
+    def arr(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    layers = {"router": arr(l, d, e), "w_gate": arr(l, e, d, f),
+              "w_up": arr(l, e, d, f), "w_down": arr(l, e, f, d)}
+    return jax.jit(run).lower(arr(32, 16, d), layers).compile().as_text()
+
+
+def test_olmoe_expert_layers_compile_for_a_v5e_and_read_the_stack_in_place(
+        one_chip, monkeypatch):
+    """The grouped-matmul kernel at the published widths is taken by the
+    chip's compiler (three custom calls a layer), and no op copies, slices
+    or re-lays-out a layer's expert leaf (268 MB) on the way to it; the
+    same scan with the leaves sliced per layer is caught doing so."""
+    # code that asks jax.default_backend() sees the CPU: take the chip's
+    monkeypatch.setattr(moe, "grouped_matmul_impl", lambda: "gmm")
+    hlo = _expert_layers_hlo(one_chip, in_place=True)
+    assert hlo.count("tpu_custom_call") >= 3
+    assert _MOVES.findall(hlo) == []
+    sliced = _expert_layers_hlo(one_chip, in_place=False)
+    assert _MOVES.findall(sliced) != []

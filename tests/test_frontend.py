@@ -392,6 +392,54 @@ class TestHttpService:
 
         run(main())
 
+    def test_disconnect_before_the_first_chunk_stops_generation(self):
+        """A request still in prefill has yielded nothing for the stream
+        loop to notice a disconnect on: the stop must come from the
+        connection monitor, or the engine prefills for nobody (and, after a
+        benchmark window is cut, runs step shapes nothing warmed)."""
+        class Prefilling:
+            def __init__(self):
+                self.contexts = []
+
+            async def generate_chat(self, request, context):
+                self.contexts.append(context)
+                await context.wait_stopped()     # no first token, ever
+                return
+                yield
+
+        async def main():
+            svc = await HttpService("127.0.0.1", 0).start()
+            eng = Prefilling()
+            svc.models.add("m", eng)
+            body = json.dumps({**CHAT_BODY, "stream": True}).encode()
+            reader, writer = await asyncio.open_connection("127.0.0.1",
+                                                           svc.port)
+            writer.write(
+                b"POST /v1/chat/completions HTTP/1.1\r\nHost: t\r\n"
+                b"Content-Type: application/json\r\nContent-Length: "
+                + str(len(body)).encode() + b"\r\n\r\n" + body)
+            await writer.drain()
+            await reader.readuntil(b"\r\n\r\n")     # the response head
+            for _ in range(100):
+                if eng.contexts:
+                    break
+                await asyncio.sleep(0.01)
+            assert eng.contexts and not eng.contexts[0].is_stopped
+            writer.close()                            # the client goes away
+            for _ in range(100):
+                if eng.contexts[0].is_stopped:
+                    break
+                await asyncio.sleep(0.02)
+            assert eng.contexts[0].is_stopped
+            for _ in range(100):
+                if svc._inflight.get("m") == 0:
+                    break
+                await asyncio.sleep(0.02)
+            assert svc._inflight.get("m") == 0
+            await svc.stop()
+
+        run(asyncio.wait_for(main(), timeout=30))
+
     def test_errors_and_statuses(self):
         async def main():
             svc = await HttpService("127.0.0.1", 0).start()

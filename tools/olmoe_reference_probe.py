@@ -1,0 +1,117 @@
+"""Show, on the chip, that benchmark/checks/reference_logits.py is tight:
+serve a benchmark configuration exactly as a run does (benchmark/run.py's
+own `Served`, launcher and socket), run ONLY that check's measurement, and
+print its readings, with the served model or the reference mutated.
+
+    chiprun -- python3 tools/olmoe_reference_probe.py --mutation none
+    ... --mutation renorm        the router renormalises its top-k weights
+    ... --mutation drop1pct      one (token, expert) assignment in a hundred
+                                 never reaches the sum (drop5pct: in twenty)
+    ... --mutation ref-float8    nothing served is changed; the REFERENCE's
+                                 weights are rounded to float8 (e4m3), the
+                                 nearest precision below bfloat16
+
+One JSON line: the readings, the check's limits for the served dtype and
+`passes`. `none` must pass; PERF.md section 6, PR 27 says which mutations
+the check sees at the published widths in bfloat16 and which only the
+float32 tier-1 test does. `--rehearsal` runs the tiny
+configuration on the CPU (control flow only).
+"""
+from __future__ import annotations
+
+import argparse
+import asyncio
+import importlib.util
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+sys.path.insert(0, BENCH)
+sys.path.insert(0, ROOT)
+
+
+def load(name, path):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def mutate(kind: str) -> None:
+    import jax.numpy as jnp
+    from dynamo_tpu.ops import moe
+    route = moe.route_topk
+    if kind == "renorm":
+        moe.route_topk = lambda x, router, k, renorm: route(x, router, k,
+                                                            True)
+    elif kind.startswith("drop"):
+        every = {"drop1pct": 100, "drop5pct": 20}[kind]
+
+        def dropping(x, router, k, renorm):
+            weights, idx = route(x, router, k, renorm)
+            flat = jnp.arange(weights.size).reshape(weights.shape)
+            return jnp.where(flat % every == every // 3, 0.0, weights), idx
+        moe.route_topk = dropping
+
+
+async def probe(args) -> dict:
+    import jax.numpy as jnp
+    run = load("bench_run", os.path.join(BENCH, "run.py"))
+    check = load("reference_logits",
+                 os.path.join(BENCH, "checks", "reference_logits.py"))
+    if args.prompt_seed is not None:
+        check.SEED = args.prompt_seed
+    config_dir = os.path.join(BENCH, "configs", args.config)
+    with open(os.path.join(config_dir, "meta.json")) as f:
+        meta = json.load(f)
+    if args.rehearsal:
+        config_dir = os.path.join(BENCH, "configs",
+                                  meta["rehearsal_config"])
+    with open(os.path.join(config_dir, "config.json")) as f:
+        model_cfg = json.load(f)
+    out_dir = os.path.join(ROOT, "chiprun_out", "reference_probe",
+                           args.mutation)
+    os.makedirs(out_dir, exist_ok=True)
+    model_dir = run.build_model_dir(config_dir,
+                                    os.path.join(out_dir, "model"))
+    mutate(args.mutation)
+    served = run.Served(model_dir, list(meta["serve"]), run.free_port())
+    await served.start()
+    ctx = run.CheckCtx(served, os.path.basename(model_dir),
+                       int(model_cfg["vocab_size"]))
+    row = await ctx.request(8, 1, 1, {"temperature": 0.0})
+    ctx.template_tokens = row["usage"]["prompt_tokens"] - 8
+    cast = None
+    if args.mutation == "ref-float8":
+        def cast(a):
+            return a.astype(jnp.float8_e4m3fn).astype(a.dtype)
+    got = await check.measure(ctx, cast=cast)
+    limits = check.LIMITS[got["dtype"]]
+    got.update(mutation=args.mutation, prompt_seed=check.SEED, limits=limits,
+               passes=got["largest"] < limits[0]
+               and got["median"] < limits[1],
+               device=served.worker.engine.device_info())
+    return got
+
+
+def main() -> None:
+    p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    p.add_argument("--mutation", default="none",
+                   choices=("none", "renorm", "drop1pct", "drop5pct",
+                            "ref-float8"))
+    p.add_argument("--config", default="olmoe-1b-7b")
+    p.add_argument("--rehearsal", action="store_true")
+    p.add_argument("--prompt-seed", type=int, default=None,
+                   help="draw the check's three prompts from another seed")
+    args = p.parse_args()
+    if args.rehearsal:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+    got = asyncio.run(probe(args))
+    print(json.dumps(got), flush=True)
+    os._exit(0)     # the launcher's tasks have no clean stop from outside
+
+
+if __name__ == "__main__":
+    main()
