@@ -2390,7 +2390,7 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
     so the common greedy path pays for neither the seen-token mask, the
     logprob log_softmax+top_k, nor the full sampling sort, and the common
     SAMPLED path (fused: every row's top_p disabled) swaps the full
-    sort + two-argsort + softmax-cumsum tail for the one-argsort
+    sort + softmax-cumsum + cutoff tail for the one-argsort
     sample_fused tail — the whole window stays ONE device dispatch with
     the sampling leg fused in, and uncommon shapes (top_p, logprobs)
     recompile onto the unfused tail token-identically.

@@ -4,8 +4,8 @@ Matches the sampling-option surface the reference forwards to its engines
 (reference: lib/llm/src/protocols/common.rs:248 SamplingOptions — temperature,
 top_k, top_p, seed; greedy when nvext.greed_sampling or temperature==0).
 
-All-batch vectorized with static vocab: one descending sort powers both top-k
-(rank mask) and top-p (cumulative-probability mask); XLA fuses the rest.
+All-batch vectorized with static vocab: one descending value sort gives both
+top-k's and top-p's cutoff, compared in token order; XLA fuses the rest.
 """
 # dynalint: hot-path — every op here runs inside jitted decode/prefill programs;
 # host syncs (.item(), device_get, float()) are dynalint R6 findings
@@ -202,6 +202,49 @@ def make_keys(seeds: jax.Array, counters: jax.Array) -> jax.Array:
     )(seeds, counters)
 
 
+def keep_mask(
+    scaled: jax.Array,        # [B, V] f32 logits / temperature
+    top_k: jax.Array,         # [B] int32; 0 => disabled
+    top_p: jax.Array,         # [B] f32; 1.0 => disabled
+) -> jax.Array:               # [B, V] bool
+    """The tokens top-k and top-p leave, in token order.
+
+    Both masks are prefixes of ONE order: descending by value and, among
+    equal values, by descending token id (a stable ascending argsort,
+    reversed). top-k keeps its first k; top-p keeps the smallest prefix of
+    the sorted probabilities whose cumulative sum reaches top_p, always
+    with the argmax (the prefix is the meaning: should a rounded
+    `cumprobs - sorted_probs` ever dip after it has crossed top_p, what
+    follows the first crossing stays out). So the kept set is the first
+    n = min(k, n_p) tokens of that order, and it is rebuilt here without
+    the order's ranks: everything above the n-th sorted value, and of the
+    tokens tied with it the highest ids, as many as are still missing.
+    One value sort; no argsort, and no [B, V] gather or scatter."""
+    v = scaled.shape[-1]
+    sorted_logits = jnp.sort(scaled, axis=-1)[:, ::-1]            # [B, V] desc
+
+    # top-k: the first k of the order (k==0 disables)
+    k = jnp.where(top_k > 0, top_k, v)
+
+    # top-p: the leading run of sorted_keep
+    sorted_probs = jax.nn.softmax(sorted_logits, axis=-1)
+    cumprobs = jnp.cumsum(sorted_probs, axis=-1)
+    sorted_keep = (cumprobs - sorted_probs) < top_p[:, None]
+    first_out = jnp.where(sorted_keep, v, jnp.arange(v, dtype=jnp.int32))
+    n = jnp.minimum(k, jnp.min(first_out, axis=-1))               # [B]
+
+    # the n-th sorted value cuts; ties at the cut go to the highest ids.
+    # n == 0 (top_p 0) keeps nothing: need is 0, a tie's count at least 1
+    cut = jnp.take_along_axis(
+        sorted_logits, jnp.maximum(n - 1, 0)[:, None], axis=-1)   # [B, 1]
+    above = scaled > cut
+    tie = scaled == cut
+    need = n - jnp.sum(above, axis=-1, dtype=jnp.int32)
+    ties_from_here_up = jax.lax.cumsum(
+        tie.astype(jnp.int32), axis=1, reverse=True)
+    return above | (tie & (ties_from_here_up <= need[:, None]))
+
+
 def sample(
     logits: jax.Array,        # [B, V] f32
     temperature: jax.Array,   # [B] f32; 0 => greedy
@@ -209,27 +252,12 @@ def sample(
     top_p: jax.Array,         # [B] f32; 1.0 => disabled
     keys: jax.Array,          # [B] PRNG keys (make_keys)
 ) -> jax.Array:               # [B] int32
-    b, v = logits.shape
     greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     temp = jnp.maximum(temperature, 1e-6)[:, None]
     scaled = logits / temp
 
-    sorted_logits = jnp.sort(scaled, axis=-1)[:, ::-1]            # [B, V] desc
-    ranks = jnp.argsort(jnp.argsort(scaled, axis=-1)[:, ::-1], axis=-1)
-
-    # top-k: keep ranks < k (k==0 disables)
-    k = jnp.where(top_k > 0, top_k, v)[:, None]
-    keep_k = ranks < k
-
-    # top-p: keep the smallest prefix of sorted probs with cumsum >= top_p,
-    # always keeping the argmax.
-    sorted_probs = jax.nn.softmax(sorted_logits, axis=-1)
-    cumprobs = jnp.cumsum(sorted_probs, axis=-1)
-    sorted_keep = (cumprobs - sorted_probs) < top_p[:, None]
-    keep_p = jnp.take_along_axis(sorted_keep, ranks, axis=-1)
-
-    masked = jnp.where(keep_k & keep_p, scaled, NEG_INF)
+    masked = jnp.where(keep_mask(scaled, top_k, top_p), scaled, NEG_INF)
     sampled = jax.vmap(
         lambda k, row: jax.random.categorical(k, row)
     )(keys, masked).astype(jnp.int32)
@@ -248,24 +276,25 @@ def sample_fused(
     serving shape (SamplingArrayCache.fused_eligible gates it). Token-
     identical to `sample` there, by construction:
 
-    - ranks: `sample` computes argsort(argsort(scaled)[:, ::-1]) — the
-      inverse permutation of the descending order. Scattering iota through
-      the SAME descending permutation (`ranks[order[j]] = j`) IS that
-      inverse, element-for-element, so tie-breaking is bit-identical while
-      dropping one full-vocab argsort and the jnp.sort.
-    - masked set: with top_p == 1.0, `sample`'s keep_p mask is all-True
-      (the strict `cumprobs - sorted_probs < 1.0` can only exclude a tail
-      element when the f32 cumsum rounds to exactly 1.0 while that
-      element's softmax underflows to 0 — a probability-0 candidate; the
-      PERF.md §3g exactness note), so keep_k alone decides — identical.
+    - order: `keep_mask` keeps the first n tokens of the stable descending
+      order (equal values by descending id). Scattering iota through that
+      SAME permutation (`ranks[order[j]] = j`) gives each token its place
+      in it, so `ranks < k` is the first k of the same order, ties
+      included.
+    - masked set: with top_p == 1.0, `keep_mask`'s top-p prefix is the whole
+      row (the strict `cumprobs - sorted_probs < 1.0` can only exclude a
+      tail element once the f32 cumsum has rounded up to 1.0: what is left
+      there is probability the sum can no longer see), so k alone decides.
     - draw: same make_keys stream, same categorical over the same masked
       row => the same token.
 
-    What this buys inside the jitted window: the full tail keeps FOUR
-    [B, V] intermediates alive (sorted logits, two argsorts, softmax+
-    cumsum) between ops; this one keeps one argsort and one scatter — the
-    zero-intermediate-HBM-round-trip sampling leg of the one-dispatch
-    decode step."""
+    What it bought inside the jitted window, when the full tail still
+    sorted three times and gathered its mask back through the ranks: one
+    argsort and one scatter in their place. Since PR 28 `sample` builds
+    its mask in token space from ONE value sort, with no argsort and no
+    [B, V] gather or scatter, so this tail's argsort + scatter are
+    probably the dearer pair now (not measured: no benchmark cell runs an
+    all-top_p-1 batch; ROADMAP queue D)."""
     b, v = logits.shape
     greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
