@@ -100,9 +100,9 @@ class NativeEngine:
                 f"MoE dispatch is capacity-based and would drop "
                 f"assignments); run it without --tp/--ep/--dp")
         if self.pp > 1:
-            if model_cfg.is_moe:
-                raise ValueError("pp requires a dense model; shard MoE "
-                                 "configs over the ep axis instead")
+            # what the MODEL cannot be on a pp mesh (experts, QK-norm under
+            # tp) is refused in models/pp.refuse_unserved, reached below
+            # through pp_param_shardings
             if engine_cfg.sp > 1:
                 raise ValueError("pp and sp (ring attention) do not compose")
             if model_cfg.decode_kernel == "on":
@@ -279,7 +279,8 @@ class NativeEngine:
 
         if self.pp > 1:
             from dynamo_tpu.models.pp import pp_param_shardings
-            param_specs = pp_param_shardings(model_cfg)
+            param_specs = pp_param_shardings(
+                model_cfg, self.mesh.shape.get("tp", 1))
         else:
             param_specs = llama.param_shardings(model_cfg)
         if model_cfg.quant == "int8":

@@ -55,13 +55,10 @@ import numpy as np
 from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.engine.kv_cache import SequenceState, page_hash
 from dynamo_tpu.engine.sampler import sample_logits
-from dynamo_tpu.models.llama import (
-    apply_rope, rms_norm, scale_embeds, _dense_mlp, _moe_mlp,
-)
+from dynamo_tpu.models import llama
 from dynamo_tpu.ops.attention import NEG_INF, _scale, write_kv_pages, \
     write_kv_pages_quant
 from dynamo_tpu.ops.kv_quant import dequantize_rows, quantize_rows
-from dynamo_tpu.ops.quant import wmat
 
 
 # -- stats --------------------------------------------------------------------
@@ -179,24 +176,15 @@ def _lp_at(layers, lid):
 def _stream_layer_start(cfg: ModelConfig, with_stats: bool, params, lid,
                         x, positions, ck, cv, ksc, vsc, page_table,
                         page_lens):
-    """Per-layer front half: norm + QKV + RoPE, then the resident-pages
-    partial merged with the causal self-chunk partial. x [T, D]; returns
+    """Per-layer front half: models/llama.layer_front on the chunk as a
+    batch of one, then the resident-pages partial merged with the causal
+    self-chunk partial. x [T, D]; returns
     (q, k_new, v_new, acc, m, l[, pm, pl])."""
     lp = _lp_at(params["layers"], lid)
-    t = x.shape[0]
-    h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
-    q = jnp.einsum("td,de->te", xn, wmat(lp["wq"], xn.dtype))
-    k = jnp.einsum("td,de->te", xn, wmat(lp["wk"], xn.dtype))
-    v = jnp.einsum("td,de->te", xn, wmat(lp["wv"], xn.dtype))
-    if cfg.attn_bias:
-        q, k, v = q + lp["wq_b"], k + lp["wk_b"], v + lp["wv_b"]
-    q = apply_rope(q.reshape(1, t, h, hd), positions[None],
-                   cfg.rope_theta)[0]
-    k = apply_rope(k.reshape(1, t, hkv, hd), positions[None],
-                   cfg.rope_theta)[0]
-    v = v.reshape(t, hkv, hd)
-    sc = _scale(hd, cfg.query_scale)
+    q, k, v = (a[0] for a in llama.layer_front(
+        x[None], lp, cfg, positions[None],
+        (cfg.num_heads, cfg.num_kv_heads)))
+    sc = _scale(cfg.head_dim, cfg.query_scale)
     # resident partial: gather this layer's resident pages; int8 caches
     # dequantize at the gather boundary  # dynalint: kv-codec
     ckl = jax.lax.dynamic_index_in_dim(ck, lid, 0, keepdims=False)
@@ -236,44 +224,33 @@ def _stream_seg_merge(cfg: ModelConfig, with_stats: bool, q, kp, vp, ksc,
 
 
 def _stream_layer_finish(cfg: ModelConfig, params, lid, x, acc, l):
-    """Per-layer back half: normalize the merged flash state, output
-    projection, residual, MLP. Returns the next layer's x [T, D]."""
+    """Per-layer back half: normalize the merged flash state, then
+    models/llama.layer_back. Returns the next layer's x [T, D].
+
+    A streamed chunk evaluates every expert (`moe_impl` "dense"), whatever
+    the configuration dispatches elsewhere: exact at any chunk size, where
+    the capacity form's drops depend on the chunk's rows, and no validity
+    mask reaches this program to keep padding rows out of a capacity."""
     lp = _lp_at(params["layers"], lid)
-    t = x.shape[0]
-    h, hd = cfg.num_heads, cfg.head_dim
-    attn = (acc / l[..., None]).reshape(t, h * hd).astype(x.dtype)
-    attn_out = jnp.einsum("te,ed->td", attn, wmat(lp["wo"], x.dtype))
-    if cfg.post_norms:
-        attn_out = rms_norm(attn_out, lp["post_attn_norm"],
-                            cfg.rms_norm_eps, cfg.norm_plus_one)
-    x = x + attn_out
-    xn = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
-    if cfg.is_moe:
-        mlp = _moe_mlp(xn[None], lp, cfg)[0]
-    else:
-        mlp = _dense_mlp(xn[None], lp, cfg)[0]
-    if cfg.post_norms:
-        mlp = rms_norm(mlp, lp["post_mlp_norm"], cfg.rms_norm_eps,
-                       cfg.norm_plus_one)
-    return x + mlp
+    mcfg = dataclasses.replace(cfg, moe_impl="dense")
+    attn = (acc / l[..., None]).astype(x.dtype)
+    x, _ = llama.layer_back(
+        x[None], attn[None], lp, cfg,
+        lambda xn, lp: llama._mlp_block(xn, lp, mcfg, None, None))
+    return x[0]
 
 
 def _stream_embed(cfg: ModelConfig, params, tokens):
     # ids validated at admission; streamed decode feeds committed sampler
     # outputs only  # dynalint: disable-next-line=R1
     x = jnp.take(params["embed"], tokens, axis=0)
-    return scale_embeds(x, cfg)
+    return llama.scale_embeds(x, cfg)
 
 
 def _stream_final(cfg: ModelConfig, params, x_last):
     """final norm + LM head on the last real chunk row; [D] -> [1, V]."""
-    from dynamo_tpu.ops.attention import _softcap
-    x = rms_norm(x_last[None], params["final_norm"], cfg.rms_norm_eps,
-                 cfg.norm_plus_one)
-    head = (params["embed"].T if cfg.tie_word_embeddings
-            else wmat(params["lm_head"], x.dtype))
-    return _softcap(jnp.einsum("td,dv->tv", x, head).astype(jnp.float32),
-                    cfg.final_softcap)
+    return llama.lm_logits(x_last[None], params["final_norm"],
+                           llama.lm_head(params, cfg), cfg)
 
 
 def _stream_scatter(quant: bool, cache_leaves, k_news, v_news, write_idx):
@@ -487,14 +464,6 @@ class StreamingDecoder:
         self.engine = engine
         cfg = engine.model_cfg
         ecfg = engine.cfg
-        if cfg.qk_norm:
-            # the streamed layer below is a copy of models/llama's
-            # (ROADMAP D3) without the q/k RMSNorm: it would serve
-            # another model in silence
-            raise ValueError(
-                "qk_norm: tiered-KV streaming's per-layer loop does not "
-                "apply the q/k RMSNorm of this configuration; serve it "
-                "with stream_pages=0")
         self.cfg = cfg
         self.ecfg = ecfg
         self.quant = bool(cfg.kv_quant)

@@ -407,6 +407,73 @@ def _sum_stats(stats) -> dict:
     return {k: jnp.sum(v) for k, v in (stats or {}).items()}
 
 
+# -- the layer ----------------------------------------------------------------
+# One transformer layer, cut where streamed decode runs host code between
+# the halves. Between them each caller does what is really its own: the
+# cache update and the attention. Callers: forward(), decode_forward(),
+# models/pp._stage, engine/streaming._stream_layer_start / _finish.
+
+def layer_front(x: jax.Array, lp: Params, cfg: ModelConfig,
+                positions: jax.Array, heads: tuple):
+    """x [B, T, D] -> q [B, T, H, hd], k, v [B, T, Hkv, hd]: attention
+    norm, QKV projection (bias, QK-norm), split into heads, RoPE on q and
+    k. `heads` = (H, Hkv) as the caller holds them: a "tp" shard of a
+    manual mesh passes its local counts."""
+    b, t = x.shape[:2]
+    h, hkv = heads
+    xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
+    q, k, v = qkv_proj(xn, lp, cfg)
+    q = apply_rope(q.reshape(b, t, h, cfg.head_dim), positions,
+                   cfg.rope_theta)
+    k = apply_rope(k.reshape(b, t, hkv, cfg.head_dim), positions,
+                   cfg.rope_theta)
+    return q, k, v.reshape(b, t, hkv, cfg.head_dim)
+
+
+def layer_back(x: jax.Array, attn: jax.Array, lp: Params, cfg: ModelConfig,
+               mlp, reduce=None):
+    """(x [B, T, D], attn [B, T, ...heads]) -> (next x, the MLP's stats):
+    output projection, residual, MLP norm, `mlp(xn, lp)` -> (out, stats),
+    residual, with Gemma's post-norms where the configuration has them.
+    `reduce` sums a partial product over the caller's manual "tp" axis; it
+    comes BEFORE the post-norm, which is nonlinear and must see the whole
+    output, not a shard's partial sum."""
+    b, t = x.shape[:2]
+    out = jnp.einsum("bte,ed->btd", attn.reshape(b, t, -1),
+                     wmat(lp["wo"], x.dtype))
+    if reduce is not None:
+        out = reduce(out)
+    if cfg.post_norms:
+        out = rms_norm(out, lp["post_attn_norm"], cfg.rms_norm_eps,
+                       cfg.norm_plus_one)
+    x = x + out
+    xn = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
+    out, stats = mlp(xn, lp)
+    if reduce is not None:
+        out = reduce(out)
+    if cfg.post_norms:
+        out = rms_norm(out, lp["post_mlp_norm"], cfg.rms_norm_eps,
+                       cfg.norm_plus_one)
+    return x + out, stats
+
+
+def lm_head(params: Params, cfg: ModelConfig) -> jax.Array:
+    """The [D, V] head operand: the embedding transposed when tied (may be
+    a quantized leaf)."""
+    return (params["embed"].T if cfg.tie_word_embeddings
+            else params["lm_head"])
+
+
+def lm_logits(x: jax.Array, final_norm: jax.Array, head: jax.Array,
+              cfg: ModelConfig) -> jax.Array:
+    """x [..., D] -> float32 logits [..., V]: final norm, head matmul,
+    final soft-cap. Takes the two arrays, not `params`: a pp stage holds
+    stage-local ones."""
+    x = rms_norm(x, final_norm, cfg.rms_norm_eps, cfg.norm_plus_one)
+    logits = jnp.einsum("...d,dv->...v", x, wmat(head, x.dtype))
+    return _softcap(logits.astype(jnp.float32), cfg.final_softcap)
+
+
 def decode_forward(
     params: Params,
     cfg: ModelConfig,
@@ -447,8 +514,7 @@ def decode_forward(
     gather (~2.5 ms/step, 1B @ b8) and the full-allocation-width reads
     of the round-3 single-buffer design.
     """
-    b = tokens.shape[0]
-    h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    heads = (cfg.num_heads, cfg.num_kv_heads)
     kernel_mode = _decode_kernel_mode(cfg)
     kvq = bool(_validate_kv_quant(cfg.kv_quant))
     lw = cfg.layer_windows()
@@ -470,13 +536,7 @@ def decode_forward(
             lp, lid, kb, vb, kw, vw = xs
         else:
             lp, lid = xs
-        xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
-        q, k, v = qkv_proj(xn, lp, cfg)
-        q = apply_rope(q.reshape(b, 1, h, hd), positions[:, None],
-                       cfg.rope_theta)
-        k = apply_rope(k.reshape(b, 1, hkv, hd), positions[:, None],
-                       cfg.rope_theta)
-        v = v.reshape(b, 1, hkv, hd)
+        q, k, v = layer_front(x, lp, cfg, positions[:, None], heads)
         k_new, v_new = k[:, 0], v[:, 0]                  # [B, Hkv, hd]
         if window is not None:
             attn = decode_attention_split(
@@ -516,20 +576,9 @@ def decode_forward(
                 page_table, prefix_lens, softcap=cfg.attn_softcap,
                 window=wnd, q_scale=cfg.query_scale,
                 k_scale=scales[0], v_scale=scales[1], layer=lid)
-        attn_out = jnp.einsum("bte,ed->btd",
-                              attn.reshape(b, 1, h * hd),
-                              wmat(lp["wo"], x.dtype))
-        if cfg.post_norms:
-            attn_out = rms_norm(attn_out, lp["post_attn_norm"],
-                                cfg.rms_norm_eps, cfg.norm_plus_one)
-        x = x + attn_out
-        xn = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
-        mlp, drop_stats = _mlp_block(xn, lp, cfg, mesh, token_valid,
-                                     expert_stacks, lid)
-        if cfg.post_norms:
-            mlp = rms_norm(mlp, lp["post_mlp_norm"], cfg.rms_norm_eps,
-                           cfg.norm_plus_one)
-        x = x + mlp
+        x, drop_stats = layer_back(
+            x, attn, lp, cfg, lambda xn, lp: _mlp_block(
+                xn, lp, cfg, mesh, token_valid, expert_stacks, lid))
         ys = (k_new, v_new, drop_stats) if moe_aux else (k_new, v_new)
         return x, ys
 
@@ -548,11 +597,8 @@ def decode_forward(
     else:
         (k_news, v_news), drops = ys, None
     aux = _sum_stats(drops)
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
-    head = (params["embed"].T if cfg.tie_word_embeddings
-            else wmat(params["lm_head"], x.dtype))
-    logits = _softcap(jnp.einsum("bd,dv->bv", x[:, 0],
-                                 head).astype(jnp.float32), cfg.final_softcap)
+    logits = lm_logits(x[:, 0], params["final_norm"], lm_head(params, cfg),
+                       cfg)
     if with_aux:
         return logits, k_news, v_news, aux
     return logits, k_news, v_news
@@ -593,7 +639,7 @@ def forward(
     disabled), so chunk-internal attention IS the full attention.
     """
     b, tq = tokens.shape
-    h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    heads = (cfg.num_heads, cfg.num_kv_heads)
     kvq = bool(_validate_kv_quant(cfg.kv_quant))
 
     if input_embeds is None:
@@ -642,13 +688,7 @@ def forward(
         x, pool = carry            # pool: (k, v[, k_scale, v_scale]) stacks
         lp, lid = layer[:2]
         wnd = layer[2] if layer_wnd is not None else None
-        xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
-        q, k, v = qkv_proj(xn, lp, cfg)
-        q = q.reshape(b, tq, h, hd)
-        k = k.reshape(b, tq, hkv, hd)
-        v = v.reshape(b, tq, hkv, hd)
-        q = apply_rope(q, meta.positions, cfg.rope_theta)
-        k = apply_rope(k, meta.positions, cfg.rope_theta)
+        q, k, v = layer_front(x, lp, cfg, meta.positions, heads)
         # rows as stored (an int8 pool quantizes them here, at capture);
         # [B, Tq, Hkv, ...] -> this layer's [1, B*Tq, Hkv, ...]
         pool = write_kv_rows(
@@ -678,20 +718,9 @@ def forward(
                                    meta.positions, softcap=cfg.attn_softcap,
                                    window=wnd, q_scale=cfg.query_scale,
                                    k_scale=ksc, v_scale=vsc, layer=lid)
-        attn_out = jnp.einsum("bte,ed->btd", attn.reshape(b, tq, h * hd),
-                              wmat(lp["wo"], x.dtype))
-        if cfg.post_norms:
-            attn_out = rms_norm(attn_out, lp["post_attn_norm"],
-                                cfg.rms_norm_eps, cfg.norm_plus_one)
-        x = x + attn_out
-
-        xn = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
-        mlp, drop_stats = _mlp_block(xn, lp, cfg, mesh, token_valid,
-                                     expert_stacks, lid)
-        if cfg.post_norms:
-            mlp = rms_norm(mlp, lp["post_mlp_norm"], cfg.rms_norm_eps,
-                           cfg.norm_plus_one)
-        x = x + mlp
+        x, drop_stats = layer_back(
+            x, attn, lp, cfg, lambda xn, lp: _mlp_block(
+                xn, lp, cfg, mesh, token_valid, expert_stacks, lid))
         return (x, pool), drop_stats
 
     moe_aux = cfg.is_moe and cfg.moe_impl == "dispatch"
@@ -713,11 +742,7 @@ def forward(
     (x, pool), drops = jax.lax.scan(layer_step, (x, pool), scan_xs)
     aux = _sum_stats(drops)
 
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
-    head = (params["embed"].T if cfg.tie_word_embeddings
-            else wmat(params["lm_head"], x.dtype))
-    logits = _softcap(jnp.einsum("btd,dv->btv", x,
-                                 head).astype(jnp.float32), cfg.final_softcap)
+    logits = lm_logits(x, params["final_norm"], lm_head(params, cfg), cfg)
     cache_out = dict(zip(cache_keys(kvq), pool))
     if with_aux:
         return logits, cache_out, aux

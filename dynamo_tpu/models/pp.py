@@ -35,35 +35,39 @@ from jax.sharding import PartitionSpec as P
 
 from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.engine.sampler import sample_logits
-from dynamo_tpu.ops.quant import is_quantized, quantize_shardings, wmat
-from dynamo_tpu.models.llama import (
-    AttnMetadata, Params, _dtype, apply_rope, mlp_activation,
-    rms_norm, scale_embeds,
-)
+from dynamo_tpu.models import llama
+from dynamo_tpu.models.llama import AttnMetadata, Params, _dtype, scale_embeds
 from dynamo_tpu.ops.attention import (
-    _softcap, paged_attention, write_kv_pages, write_kv_pages_quant,
+    paged_attention, write_kv_pages, write_kv_pages_quant,
 )
+from dynamo_tpu.ops.quant import is_quantized, quantize_shardings, wmat
 
 
-def refuse_unimplemented(cfg: ModelConfig) -> None:
-    """`_stage` is a copy of models/llama.forward's layer (ROADMAP D3) and
-    lacks what that layer gained since: it would serve another model in
-    silence. Called where a pp engine is built (pp_param_shardings)."""
-    if cfg.qk_norm:
-        raise ValueError(
-            "qk_norm: the pipeline-parallel stage (models/pp._stage) does "
-            "not apply the q/k RMSNorm of this configuration; serve it "
-            "without a pp mesh")
-    if cfg.is_moe and not cfg.norm_topk_prob:
-        raise ValueError(
-            "norm_topk_prob: the pipeline-parallel stage (models/pp."
-            "_stage) has no expert layer, with or without renormalised "
-            "router weights; serve this configuration without a pp mesh")
+def refuse_unserved(cfg: ModelConfig, tp: int = 1) -> None:
+    """What a pp mesh cannot serve, refused here and nowhere else. The
+    stage's layer is models/llama's own (layer_front / layer_back), so
+    this lists only what the manual ("pp", "tp") mesh around it cannot
+    express. Reached where a pp engine is built and from both entry
+    points (through pp_param_shardings)."""
+    if cfg.is_moe:
+        raise NotImplementedError(
+            "is_moe: a pipeline-parallel stage has no expert layer (its "
+            "manual mesh has no \"ep\" axis and pp_param_shardings no "
+            "expert specs); MoE scale-out uses the ep axis (ops/moe.py): "
+            "serve this configuration without a pp mesh")
+    if cfg.qk_norm and tp > 1:
+        # refused, not reduced: the mean square would need a psum over
+        # "tp" threaded through qkv_proj's rms_norm for a mesh no cell or
+        # deployment runs; per-shard it would be another model in silence
+        raise NotImplementedError(
+            "qk_norm with tp > 1 on a pp mesh: the q/k RMSNorm runs over "
+            "the WHOLE projection and a stage holds a \"tp\" shard of it; "
+            "serve this configuration with pp x tp=1 or without a pp mesh")
 
 
-def pp_param_shardings(cfg: ModelConfig) -> Params:
+def pp_param_shardings(cfg: ModelConfig, tp: int = 1) -> Params:
     """Layer-stacked params: layer axis over "pp", head/FFN dims over "tp"."""
-    refuse_unimplemented(cfg)
+    refuse_unserved(cfg, tp)
     layers = {
         "attn_norm": P("pp", None),
         "wq": P("pp", None, "tp"),
@@ -86,6 +90,8 @@ def pp_param_shardings(cfg: ModelConfig) -> Params:
             "wk_b": P("pp", "tp"),
             "wv_b": P("pp", "tp"),
         })
+    if cfg.qk_norm:
+        layers.update({"q_norm": P("pp", "tp"), "k_norm": P("pp", "tp")})
     out: Params = {
         # vocab rows over "tp": the embedding is the largest otherwise-
         # replicated tensor in the 70B plan (2.1 GB/device at bf16);
@@ -128,16 +134,15 @@ def pp_cache_scale_sharding() -> P:
     return P("pp", "tp", None, None)
 
 
-def _head_and_specs(cfg: ModelConfig, params: Params):
+def _head_and_specs(cfg: ModelConfig, params: Params, tp: int):
     """Shared spec selection for both pp entry points: returns
     (layer+head shardings [quantized if the params are], head operand,
     head in_spec, base head spec for out-spec decisions)."""
-    base = pp_param_shardings(cfg)
+    base = pp_param_shardings(cfg, tp)
     shardings = base
     if is_quantized(params["layers"].get("wq")):
         shardings = quantize_shardings(base, cfg)  # does not mutate base
-    head = (params["embed"].T if cfg.tie_word_embeddings
-            else params["lm_head"])
+    head = llama.lm_head(params, cfg)
     # tied head = embed.T: the vocab-sharded embedding rows become
     # vocab-sharded head columns — same layout as an untied lm_head
     base_hs = (P(None, "tp") if cfg.tie_word_embeddings
@@ -150,22 +155,26 @@ def _stage(cfg: ModelConfig, tp: int, x, layers, kc, vc,
            meta: AttnMetadata, wnds=None, ksc=None, vsc=None):
     """Run this stage's local layers (scan) on one microbatch.
 
-    Mirrors models/llama.forward's layer_step (gather attention path) with
-    manual Megatron psums over "tp"; kc/vc are the stage-local
-    [L/pp, Hkv/tp, ...] cache shards. `wnds` is the stage-local slice of
-    the per-layer sliding-window array (None = all layers full attention);
-    post-norms / soft-caps / query scaling follow models/llama.forward.
-    `ksc`/`vsc` (kv_quant engines) are the stage-local scale-stack shards
-    ([L/pp, Hkv/tp, P, ps]): new rows quantize at capture inside the
-    scan (write_kv_pages_quant) and attention dequantizes at the gather,
-    exactly like the single-mesh forward — the int8 codec never crosses
-    a stage or tp boundary because values and scales shard together.
+    The layer is models/llama's (layer_front / layer_back) with this
+    shard's head counts and a psum over "tp" as its `reduce`; the stage's
+    own part is the cache update and the attention between the halves.
+    kc/vc are the stage-local [L/pp, Hkv/tp, ...] cache shards. `wnds` is
+    the stage-local slice of the per-layer sliding-window array (None =
+    all layers full attention). `ksc`/`vsc` (kv_quant engines) are the
+    stage-local scale-stack shards ([L/pp, Hkv/tp, P, ps]): new rows
+    quantize at capture inside the scan (write_kv_pages_quant) and
+    attention dequantizes at the gather, exactly like the single-mesh
+    forward — the int8 codec never crosses a stage or tp boundary because
+    values and scales shard together.
     """
-    b, tq, _ = x.shape
-    h = cfg.num_heads // tp
-    hkv = cfg.num_kv_heads // tp
-    hd = cfg.head_dim
+    heads = (cfg.num_heads // tp, cfg.num_kv_heads // tp)
     kvq = ksc is not None
+
+    def mlp(xn, lp):
+        return llama._mlp_block(xn, lp, cfg, None, None)
+
+    def psum_tp(a):
+        return jax.lax.psum(a, "tp")
 
     def layer_step(x, layer):
         if wnds is not None:
@@ -177,16 +186,7 @@ def _stage(cfg: ModelConfig, tp: int, x, layers, kc, vc,
         else:
             lp, kc, vc = layer
             ksc_l = vsc_l = None
-        xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
-        q = jnp.einsum("btd,de->bte", xn, wmat(lp["wq"], xn.dtype))
-        k = jnp.einsum("btd,de->bte", xn, wmat(lp["wk"], xn.dtype))
-        v = jnp.einsum("btd,de->bte", xn, wmat(lp["wv"], xn.dtype))
-        if cfg.attn_bias:
-            q, k, v = q + lp["wq_b"], k + lp["wk_b"], v + lp["wv_b"]
-        q = apply_rope(q.reshape(b, tq, h, hd), meta.positions, cfg.rope_theta)
-        k = apply_rope(k.reshape(b, tq, hkv, hd), meta.positions,
-                       cfg.rope_theta)
-        v = v.reshape(b, tq, hkv, hd)
+        q, k, v = llama.layer_front(x, lp, cfg, meta.positions, heads)
         if kvq:
             # capture-time quantization inside the stage scan: int8
             # values + f32 scale rows scatter together (ops/kv_quant.py)
@@ -198,25 +198,7 @@ def _stage(cfg: ModelConfig, tp: int, x, layers, kc, vc,
                                meta.positions, softcap=cfg.attn_softcap,
                                window=wnd, q_scale=cfg.query_scale,
                                k_scale=ksc_l, v_scale=vsc_l)
-        o = jnp.einsum("bte,ed->btd", attn.reshape(b, tq, h * hd),
-                       wmat(lp["wo"], x.dtype))
-        # psum BEFORE the post-norm: rms_norm is nonlinear, so it must see
-        # the full attention output, not this tp shard's partial sum
-        o = jax.lax.psum(o, "tp")
-        if cfg.post_norms:
-            o = rms_norm(o, lp["post_attn_norm"], cfg.rms_norm_eps,
-                         cfg.norm_plus_one)
-        x = x + o
-        xn = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
-        gate = jnp.einsum("btd,df->btf", xn, wmat(lp["w_gate"], xn.dtype))
-        up = jnp.einsum("btd,df->btf", xn, wmat(lp["w_up"], xn.dtype))
-        act = mlp_activation(gate, cfg) * up
-        mlp = jnp.einsum("btf,fd->btd", act, wmat(lp["w_down"], x.dtype))
-        mlp = jax.lax.psum(mlp, "tp")
-        if cfg.post_norms:
-            mlp = rms_norm(mlp, lp["post_mlp_norm"], cfg.rms_norm_eps,
-                           cfg.norm_plus_one)
-        x = x + mlp
+        x, _ = llama.layer_back(x, attn, lp, cfg, mlp, psum_tp)
         ys = (kc, vc, ksc_l, vsc_l) if kvq else (kc, vc)
         return x, ys
 
@@ -248,16 +230,13 @@ def pp_forward(
     Returns (logits [B, Tq, V] f32, updated cache). Semantics are oracle-
     identical to the single-mesh forward (tests/test_pp.py).
     """
-    if cfg.is_moe:
-        raise NotImplementedError("pp composes with dense models; MoE "
-                                  "scale-out uses the ep axis (ops/moe.py)")
     pp = mesh.shape["pp"]
     tp = mesh.shape.get("tp", 1)
     b = tokens.shape[0]
     m = n_micro if n_micro > 0 else min(pp, b)
     while b % m:
         m -= 1
-    shardings, head, head_spec, base_hs = _head_and_specs(cfg, params)
+    shardings, head, head_spec, base_hs = _head_and_specs(cfg, params, tp)
     lw = cfg.layer_windows()
     wnds = None if lw is None else jnp.asarray(lw, jnp.int32)
     kvq = "k_scale" in cache
@@ -366,9 +345,7 @@ def _pp_body(cfg, pp, tp, m, kvq, has_wnds, has_mm,
         y, kc, vc, ksc_c, vsc_c = _stage(cfg, tp, x_in, layers, kc, vc,
                                          meta_t, wnds, ksc_c, vsc_c)
         # the LAST stage finishes microbatch i at this tick
-        xf = rms_norm(y, final_norm, cfg.rms_norm_eps, cfg.norm_plus_one)
-        lg = _softcap(jnp.einsum("btd,dv->btv", xf,
-                                 head).astype(jnp.float32), cfg.final_softcap)
+        lg = llama.lm_logits(y, final_norm, head, cfg)
         lg = jnp.where((r == last) & valid, lg, 0.0)
         # hop activations to the next stage (ring; stage 0's recv is unused)
         y_next = jax.lax.ppermute(
@@ -455,7 +432,7 @@ def pp_decode_window(
     tp = mesh.shape.get("tp", 1)
     s = tokens.shape[0]
     assert s % pp == 0, (s, pp)
-    shardings, head, head_spec, _ = _head_and_specs(cfg, params)
+    shardings, head, head_spec, _ = _head_and_specs(cfg, params, tp)
     lw = cfg.layer_windows()
     wnds = None if lw is None else jnp.asarray(lw, jnp.int32)
     kvq = "k_scale" in cache
@@ -563,9 +540,7 @@ def _pp_decode_body(cfg, pp, tp, n_steps, page_size, eos_ids, greedy,
         y, kc, vc, ksc_c, vsc_c = _stage(cfg, tp, x_in, layers, kc, vc,
                                          meta_t, wnds, ksc_c, vsc_c)
         # last stage: greedy-sample this microbatch's token
-        xf = rms_norm(y, final_norm, cfg.rms_norm_eps, cfg.norm_plus_one)
-        lg = _softcap(jnp.einsum("btd,dv->btv", xf,
-                                 head).astype(jnp.float32), cfg.final_softcap)
+        lg = llama.lm_logits(y, final_norm, head, cfg)
         if tp > 1 and head.shape[1] != cfg.vocab_size:
             lg = jax.lax.all_gather(lg, "tp", axis=2, tiled=True)
         lg = lg[:, 0]                          # [bm, V]

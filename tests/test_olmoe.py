@@ -447,21 +447,6 @@ def test_loader_round_trips_olmoe_tensor_names(tmp_path):
     assert np.isfinite(np.asarray(got)).all() and got.shape == (12, v)
 
 
-def test_pp_and_streaming_refuse_what_their_layer_copies_lack():
-    """models/pp._stage and engine/streaming.py are older copies of the
-    layer: a configuration with QK-norm (or, on pp, the un-renormalised
-    router) is refused where they are built, by key."""
-    from dynamo_tpu.models.pp import pp_param_shardings
-    with pytest.raises(ValueError, match="qk_norm"):
-        pp_param_shardings(TINY)
-    with pytest.raises(ValueError, match="norm_topk_prob"):
-        pp_param_shardings(dataclasses.replace(TINY, qk_norm=False))
-    with pytest.raises(ValueError, match="qk_norm"):
-        NativeEngine(dataclasses.replace(TINY, moe_impl="dense"),
-                     EngineConfig(**dict(ENGINE_KW, host_pages=8,
-                                         stream_pages=2)), seed=0)
-
-
 def test_which_dispatch_a_configuration_gets():
     """Selected by what the configuration says, never by an option: more
     than eight experts take the dropless dispatch on one device; Mixtral's

@@ -4,6 +4,8 @@ VERDICT r2 next #8: a real microbatched pipeline over the "pp" mesh axis
 (the reference delegates PP to vLLM, vllm_inc.py:38). The oracle is the
 single-mesh models/llama.forward; pp must be bit-compatible in f32.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -172,7 +174,14 @@ def _drive_engine(eng, prompts, params):
         max_tokens_one_dispatch
 
 
-def test_pp_engine_generates_identically():
+@pytest.mark.parametrize("cfg,meshes", [
+    pytest.param(CFG, ((2, 1), (2, 2)), id="llama"),
+    # the stage's front half is models/llama.layer_front, QK-norm included;
+    # pp x tp > 1 is refused by name (test_pp_refuses_by_name below)
+    pytest.param(dataclasses.replace(CFG, qk_norm=True), ((2, 1),),
+                 id="qk_norm"),
+])
+def test_pp_engine_generates_identically(cfg, meshes):
     """Full engine on a pp=2 mesh (pp=2 x tp=2 too): greedy tokens match the
     single-device engine exactly — the 'dryrun mesh pp=2 generating
     correctly' bar from VERDICT r2 next #8."""
@@ -186,13 +195,13 @@ def test_pp_engine_generates_identically():
     params = SamplingParams(max_tokens=8, temperature=0.0, ignore_eos=True)
     prompts = [list(range(3, 15)), list(range(40, 60))]
 
-    oracle = NativeEngine(CFG, ecfg, seed=0)
+    oracle = NativeEngine(cfg, ecfg, seed=0)
     expect = [oracle.generate(p, params, f"o{i}")
               for i, p in enumerate(prompts)]
 
-    for pp, tp in ((2, 1), (2, 2)):
+    for pp, tp in meshes:
         mesh = make_mesh(pp=pp, tp=tp, devices=jax.devices()[:pp * tp])
-        eng = NativeEngine(CFG, ecfg, mesh=mesh, seed=0)
+        eng = NativeEngine(cfg, ecfg, mesh=mesh, seed=0)
         # multi-token pp decode (VERDICT r3 weak #7): the window survives
         # pp meshes instead of being forced to 1
         assert eng.pp == pp and eng.cfg.decode_steps == ecfg.decode_steps
@@ -201,6 +210,33 @@ def test_pp_engine_generates_identically():
         # the microbatch round-robin serves >1 token per host dispatch
         assert max_tokens_one_dispatch > 1, \
             f"pp={pp} tp={tp}: decode still per-token"
+
+
+@pytest.mark.parametrize("change,tp,match", [
+    (dict(num_experts=4, num_experts_per_tok=2), 1, "is_moe"),
+    (dict(num_experts=4, num_experts_per_tok=2, norm_topk_prob=False), 1,
+     "is_moe"),
+    (dict(qk_norm=True), 2, "qk_norm with tp > 1"),
+])
+def test_pp_refuses_by_name_what_its_mesh_cannot_express(change, tp, match):
+    """One place (models/pp.refuse_unserved, reached through
+    pp_param_shardings) refuses what a pp mesh cannot serve, where the
+    engine is built and at both entry points, naming the field."""
+    from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.engine.engine import NativeEngine
+
+    cfg = dataclasses.replace(CFG, **change)
+    with pytest.raises(NotImplementedError, match=match):
+        pp_param_shardings(cfg, tp)
+    mesh = make_mesh(pp=2, tp=tp, devices=jax.devices()[:2 * tp])
+    with pytest.raises(NotImplementedError, match=match):
+        NativeEngine(cfg, EngineConfig(page_size=8, num_pages=64,
+                                       max_slots=2, max_model_len=128),
+                     mesh=mesh, seed=0)
+    tokens, meta = make_inputs(2, 8, 8)
+    with pytest.raises(NotImplementedError, match=match):
+        pp_forward(llama.init_params(jax.random.PRNGKey(0), cfg), cfg,
+                   tokens, llama.init_cache(cfg, NPAGES, PAGE), meta, mesh)
 
 
 def test_pp_engine_sampled_window_matches_oracle():

@@ -657,18 +657,32 @@ def test_spec_exact_when_draft_crosses_mm_span(monkeypatch):
 # -- pp composition ------------------------------------------------------------
 
 @pytest.mark.parametrize("pp,tp", [(2, 1), (2, 2)])
-def test_spec_pp_mesh_exact(pp, tp):
+def test_spec_pp_mesh_exact(monkeypatch, pp, tp):
     """spec decode composes with pp meshes: the verify block is one
     prefill-shaped pp_forward (the GPipe stage scan handles Tq > 1), and
     its per-position argmax must replay the single-mesh greedy stream
-    token-for-token. Previously rejected at engine init (ROADMAP-1b)."""
+    token-for-token. Previously rejected at engine init (ROADMAP-1b).
+
+    Drafts come from an oracle source fed the plain engine's output, as
+    in test_spec_exact_min_tokens_and_stops: a random-weight model's first
+    token already breaks the prompt's repetition, so the real n-gram
+    proposer is silent on ANY mesh (ROADMAP D12) and the verify path was
+    never reached."""
     import jax
 
+    import dynamo_tpu.engine.spec as spec_mod
     from dynamo_tpu.parallel.mesh import make_mesh
 
     prompt = repetitive_prompt()
     p = SamplingParams(max_tokens=12, temperature=0.0)
     plain = make_engine().generate(prompt, p, "plain")
+
+    def oracle_propose(tokens, k, min_ngram=2, max_ngram=4, max_scan=4096,
+                       vocab_size=None):
+        done = len(tokens) - len(prompt)
+        return plain[done:done + k]
+
+    monkeypatch.setattr(spec_mod, "ngram_propose", oracle_propose)
     mesh = make_mesh(pp=pp, tp=tp, devices=jax.devices()[:pp * tp])
     spec = NativeEngine(
         CFG,

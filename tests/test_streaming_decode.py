@@ -33,16 +33,17 @@ SAMPLED = SamplingParams(max_tokens=16, temperature=0.8, top_k=20,
                          top_p=0.9, seed=1234, ignore_eos=True)
 
 
-def oracle_engine(kv_quant=""):
+def oracle_engine(kv_quant="", **model):
     """Oversized HBM budget: every page stays resident, nothing streams."""
     return NativeEngine(
-        ModelConfig(dtype="float32", max_model_len=256, kv_quant=kv_quant),
+        ModelConfig(dtype="float32", max_model_len=256, kv_quant=kv_quant,
+                    **model),
         EngineConfig(page_size=PAGE, num_pages=64, max_slots=2,
                      max_prefill_chunk=32, prefill_buckets=(8, 16, 32),
                      max_model_len=256, kv_quant=kv_quant), seed=0)
 
 
-def stream_engine(kv_quant="", **kw):
+def stream_engine(kv_quant="", model=None, **kw):
     cfg = dict(page_size=PAGE, num_pages=6, max_slots=2,
                max_prefill_chunk=32, prefill_buckets=(8, 16, 32),
                max_model_len=256, host_pages=64, stream_pages=4,
@@ -50,7 +51,8 @@ def stream_engine(kv_quant="", **kw):
                kv_quant=kv_quant)
     cfg.update(kw)
     return NativeEngine(
-        ModelConfig(dtype="float32", max_model_len=256, kv_quant=kv_quant),
+        ModelConfig(dtype="float32", max_model_len=256, kv_quant=kv_quant,
+                    **(model or {})),
         EngineConfig(**cfg), seed=0)
 
 
@@ -72,10 +74,18 @@ def _clean_faults():
 
 # -- oracle identity -----------------------------------------------------------
 
-def test_stream_greedy_matches_oracle():
-    expect = oracle_engine().generate(PROMPT, GREEDY, "a")
+@pytest.mark.parametrize("model", [
+    pytest.param({}, id="llama"),
+    # the streamed layer's halves are models/llama.layer_front / layer_back:
+    # what they apply (the q/k RMSNorm, the expert layer) streams too
+    pytest.param(dict(qk_norm=True), id="qk_norm"),
+    pytest.param(dict(num_experts=4, num_experts_per_tok=2,
+                      norm_topk_prob=False, moe_impl="dense"), id="moe"),
+])
+def test_stream_greedy_matches_oracle(model):
+    expect = oracle_engine(**model).generate(PROMPT, GREEDY, "a")
     s0 = STREAM_STATS.snapshot()
-    got = stream_engine().generate(PROMPT, GREEDY, "a")
+    got = stream_engine(model=model).generate(PROMPT, GREEDY, "a")
     s1 = STREAM_STATS.snapshot()
     assert got == expect
     # the run must actually have streamed: spills happened, the double
