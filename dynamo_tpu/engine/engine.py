@@ -261,6 +261,8 @@ class NativeEngine:
         self.decode_kernel_tag = ""   # last window's attention+tail tag
         self.decode_host_syncs = 0    # blocking output fetches in decode
         self.decode_plan_uploads = 0  # windows that staged fresh host arrays
+        self.host_buffers = 0         # host->device buffers the step path
+        #                               staged (_stage_operands)
         self.pipeline_windows = 0     # windows committed via the pipeline
         self.pipeline_overlapped = 0  # commits with a follow-up in flight
         self.pipeline_fallbacks = 0   # in-flight windows discarded on
@@ -370,12 +372,19 @@ class NativeEngine:
         # (lax.scan feeds the sampled token to the next step), so host work
         # amortizes over N tokens instead of paying per token.
         pp_mesh = self.mesh if self.pp > 1 else None
+        # every program takes its small host operands as ONE packed buffer
+        # and a static layout (_packed, _stage_operands): a variant's
+        # operand names are fixed here, with the variant
         self._step_fns = {
             (rp, lp, mm): jax.jit(
-                _named("engine_step", functools.partial(
-                    _engine_step, model_cfg, eos_tuple, sp_mesh,
-                    kernel_mesh, rp, lp, mm, pp_mesh)),
-                donate_argnums=(1,))
+                _named("engine_step", _packed(
+                    functools.partial(
+                        _engine_step, model_cfg, eos_tuple, sp_mesh,
+                        kernel_mesh, rp, lp, mm, pp_mesh),
+                    STEP_OPERANDS + ("rep_penalty",) * rp
+                    + ("mm_mask",) * mm,
+                    ("hist",) * rp + ("mm_embeds",) * mm)),
+                static_argnums=(2,), donate_argnums=(1,))
             for rp in (False, True) for lp in (False, True)
             for mm in (False, True)
         }
@@ -395,11 +404,14 @@ class NativeEngine:
         # inside the program.
         self._decode_fns = {
             (rp, lp, greedy, fused, nw): jax.jit(
-                _named(self._window_name(nw), functools.partial(
-                    _engine_decode_window, model_cfg, eos_tuple,
-                    kernel_mesh, nw, engine_cfg.page_size, rp, lp, greedy,
-                    fused)),
-                donate_argnums=(1,))
+                _named(self._window_name(nw), _packed(
+                    functools.partial(
+                        _engine_decode_window, model_cfg, eos_tuple,
+                        kernel_mesh, nw, engine_cfg.page_size, rp, lp,
+                        greedy, fused),
+                    WINDOW_OPERANDS + ("rep_penalty",) * rp,
+                    ("hist",) * rp, carried=True)),
+                static_argnums=(3,), donate_argnums=(1,))
             for rp in (False, True) for lp in (False, True)
             for greedy in (False, True) for fused in (False, True)
             for nw in self._window_sizes
@@ -431,10 +443,12 @@ class NativeEngine:
             # pp_forward — the GPipe scan already handles Tq > 1, so the
             # pipelined multi-token forward comes for free
             self._verify_fn = jax.jit(
-                _named("engine_verify_step", functools.partial(
-                    _engine_verify_step, model_cfg, eos_tuple, None,
-                    kernel_mesh, pp_mesh)),
-                donate_argnums=(1,))
+                _named("engine_verify_step", _packed(
+                    functools.partial(
+                        _engine_verify_step, model_cfg, eos_tuple, None,
+                        kernel_mesh, pp_mesh),
+                    VERIFY_OPERANDS)),
+                static_argnums=(2,), donate_argnums=(1,))
             if engine_cfg.spec_decode == "draft":
                 import os as _os
 
@@ -469,11 +483,13 @@ class NativeEngine:
             from dynamo_tpu.models.pp import pp_decode_window
             self._pp_decode_fns = {
                 (nw, greedy, fused): jax.jit(
-                    _named(self._window_name(nw), functools.partial(
-                        pp_decode_window, self.model_cfg, eos_tuple,
-                        self.mesh, nw, engine_cfg.page_size, greedy,
-                        fused)),
-                    donate_argnums=(1,))
+                    _named(self._window_name(nw), _packed(
+                        functools.partial(
+                            pp_decode_window, self.model_cfg, eos_tuple,
+                            self.mesh, nw, engine_cfg.page_size, greedy,
+                            fused),
+                        PP_WINDOW_OPERANDS, carried=True)),
+                    static_argnums=(3,), donate_argnums=(1,))
                 for nw in self._window_sizes for greedy in (False, True)
                 for fused in (False, True)
             }
@@ -927,31 +943,43 @@ class NativeEngine:
         rp = self._rep_penalty_arrays(reqs, mixed=mixed)
         with_lp = self._wants_logprobs(reqs)
         mm = getattr(plan, "mm_embeds", None) is not None
-        args = (self.params, self.cache,
-                jnp.asarray(plan.tokens), jnp.asarray(plan.positions),
-                jnp.asarray(plan.page_table), jnp.asarray(plan.kv_lens),
-                jnp.asarray(plan.write_idx), jnp.asarray(plan.last_idx),
-                jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
-                jnp.asarray(seeds), jnp.asarray(counters),
-                jnp.asarray(min_toks))
-        kwargs = {}
+        # STEP_OPERANDS' order, then the variant's own (__init__)
+        small = (plan.tokens, plan.positions, plan.page_table, plan.kv_lens,
+                 plan.write_idx, plan.last_idx, temp, top_k, top_p, seeds,
+                 counters, min_toks)
+        own = ()
         if rp is not None:
-            kwargs.update(hist=jnp.asarray(rp[0]),
-                          rep_penalty=jnp.asarray(rp[1]))
+            small, own = small + (rp[1],), own + (rp[0],)
         if mm:
-            kwargs.update(mm_embeds=jnp.asarray(plan.mm_embeds),
-                          mm_mask=jnp.asarray(plan.mm_mask))
+            small, own = small + (plan.mm_mask,), own + (plan.mm_embeds,)
         key = ("step", rp is not None, with_lp, mm, plan.tokens.shape,
                plan.page_table.shape[1],
                None if rp is None else rp[0].shape[1])
-        return key, args, kwargs, with_lp
+        return key, self._stage_operands(small, own), with_lp
+
+    def _stage_operands(self, small: tuple, own: tuple = ()) -> tuple:
+        """THE way a step's host operands reach the device, for every step
+        kind: the `small` NumPy arrays (plan and sampling arrays, int32 /
+        float32 / bool over one row axis) packed into one int32 buffer
+        (pack_operands), put with the few `own` arrays that keep a buffer
+        to themselves (a penalty history, image embeddings, a window's
+        carry) in ONE `jax.device_put`. Returns what a `_packed` program
+        is called with after its params and cache: (layout, packed,
+        *own), the arrays device-resident and, like any uncommitted
+        array, replicated over the mesh by the call. Runs inside the
+        caller's `upload` phase; `host_buffers` counts the buffers."""
+        layout, buf = pack_operands(small)
+        staged = jax.device_put((buf, *own))
+        self.host_buffers += len(staged)
+        self.ledger.stats.host_buffers_total += len(staged)
+        return (layout, *staged)
 
     def _launch_step(self, staged: tuple):
         """dispatch + wait of a staged `_engine_step` program."""
-        key, args, kwargs, with_lp = staged
+        key, args, with_lp = staged
         with self._dispatch_phase(key):
             # key[1:4] is the variant: (with_rp, with_lp, with_mm)
-            out = self._step_fns[key[1:4]](*args, **kwargs)
+            out = self._step_fns[key[1:4]](self.params, self.cache, *args)
         tokens, lp, top_ids, top_lps, self.cache, aux = out
         with self.phases.phase("wait"):
             tokens, lp, top_ids, top_lps, aux = jax.device_get(
@@ -1087,7 +1115,7 @@ class NativeEngine:
                                             greedy, fused)
         if drafts is not None:
             return self._run_spec_decode(plan, drafts, block)
-        outs, nxt = self._dispatch_staged(staged, staged["first"], rp)
+        outs, nxt = self._dispatch_staged(staged, staged["first"])
         self._dec_state = {"sig": staged["sig"], "dev": staged["dev"],
                            "next": nxt}
         return self._fetch_and_commit(plan, outs)
@@ -1169,16 +1197,16 @@ class NativeEngine:
             ign = np.array([
                 bool(self.scheduler.params[s.request_id].ignore_eos)
                 if s is not None else True for s in plan.seqs])
-            dev = (jnp.asarray(plan.page_table),
-                   jnp.asarray(plan.page_table[:, :base_pb]),
-                   jnp.asarray(plan.max_pos),
-                   jnp.asarray(temp), jnp.asarray(top_k),
-                   jnp.asarray(top_p), jnp.asarray(seeds),
-                   jnp.asarray(min_toks), jnp.asarray(ign),
-                   jnp.asarray(plan.stop_ids))
-            first = (jnp.asarray(plan.tokens[:, 0]),
-                     jnp.asarray(plan.positions[:, 0]),
-                     jnp.asarray(counters))
+            # WINDOW_OPERANDS' order (+ the penalty pair); the carry
+            # keeps a buffer of its own: a chained window is handed the
+            # device's
+            small = (plan.page_table, plan.page_table[:, :base_pb],
+                     plan.max_pos, temp, top_k, top_p, seeds, min_toks,
+                     ign, plan.stop_ids)
+            own = (self._window_carry(plan, counters),)
+            if rp is not None:
+                small, own = small + (rp[1],), (rp[0],) + own
+            *dev, first = self._stage_operands(small, own)
             self.decode_plan_uploads += 1
         nw = self._window_rung(plan)
         pregather = llama._decode_kernel_mode(self.model_cfg) is None
@@ -1201,6 +1229,14 @@ class NativeEngine:
                 "base_cap": base_pb * ps if pregather else None,
                 "pp": False}
 
+    @staticmethod
+    def _window_carry(plan: DecodePlan, counters) -> np.ndarray:
+        """[S, 3] int32: the (token, position, counter) a window starts
+        from, the form in which a window program takes its carry and
+        hands on the next."""
+        return np.stack((plan.tokens[:, 0], plan.positions[:, 0], counters),
+                        axis=1)
+
     def _stage_pp_window(self, plan: DecodePlan, samp,
                          greedy: bool, fused: bool = False) -> dict:
         """Stage a pipeline-parallel decode window (models/pp.py). Same
@@ -1221,15 +1257,11 @@ class NativeEngine:
             ign = np.array([
                 bool(self.scheduler.params[s.request_id].ignore_eos)
                 if s is not None else True for s in plan.seqs])
-            dev = (jnp.asarray(plan.page_table),
-                   jnp.asarray(plan.max_pos),
-                   jnp.asarray(min_toks), jnp.asarray(ign),
-                   jnp.asarray(plan.stop_ids), jnp.asarray(temp),
-                   jnp.asarray(top_k), jnp.asarray(top_p),
-                   jnp.asarray(seeds))
-            first = (jnp.asarray(plan.tokens[:, 0]),
-                     jnp.asarray(plan.positions[:, 0]),
-                     jnp.asarray(counters))
+            # PP_WINDOW_OPERANDS' order
+            *dev, first = self._stage_operands(
+                (plan.page_table, plan.max_pos, min_toks, ign,
+                 plan.stop_ids, temp, top_k, top_p, seeds),
+                (self._window_carry(plan, counters),))
             self.decode_plan_uploads += 1
         nw = self._window_rung(plan)
         return {"sig": sig, "dev": dev, "first": first, "nw": nw,
@@ -1240,33 +1272,21 @@ class NativeEngine:
                 "tag": "pp" + ("+fused" if fused else ""),
                 "base_cap": None, "pp": True}
 
-    def _dispatch_staged(self, staged: dict, carry, rp=None):
-        """Dispatch one decode window from staged device arrays + a
-        (token, position, counter) carry. Returns (outs, next_carry) with
-        outs still ON DEVICE — the caller decides when to sync."""
-        tok_d, pos_d, ctr_d = carry
+    def _dispatch_staged(self, staged: dict, carry):
+        """Dispatch one decode window from its staged device operands
+        (`staged["dev"]`: _stage_operands' layout, packed plan and, on a
+        penalty plan, history) + a [S, 3] (token, position, counter)
+        carry. Returns (outs, next_carry) with outs still ON DEVICE — the
+        caller decides when to sync."""
         with self._dispatch_phase(staged["program"]):
             if staged["pp"]:
-                nw, greedy, fused = staged["key"]
-                (page_table_d, max_pos_d, min_toks_d, ign_d, stop_ids_d,
-                 temp_d, top_k_d, top_p_d, seeds_d) = staged["dev"]
-                toks, self.cache, nxt = \
-                    self._pp_decode_fns[nw, greedy, fused](
-                        self.params, self.cache, tok_d, pos_d, page_table_d,
-                        max_pos_d, min_toks_d, ctr_d, ign_d, stop_ids_d,
-                        temp_d, top_k_d, top_p_d, seeds_d)
+                toks, self.cache, nxt = self._pp_decode_fns[staged["key"]](
+                    self.params, self.cache, carry, *staged["dev"])
                 outs = (toks, None, None, None, {})
             else:
-                (page_table_d, base_table_d, max_pos_d, temp_d, top_k_d,
-                 top_p_d, seeds_d, min_toks_d, ign_d, stop_ids_d) = \
-                    staged["dev"]
-                args = (self.params, self.cache, tok_d, pos_d, page_table_d,
-                        base_table_d, max_pos_d, temp_d, top_k_d, top_p_d,
-                        seeds_d, ctr_d, min_toks_d, ign_d, stop_ids_d)
-                if rp is not None:
-                    args += (jnp.asarray(rp[0]), jnp.asarray(rp[1]))
-                out = self._decode_fns[staged["key"]](*args)
-                toks, lps, top_ids, top_lps, self.cache, aux, nxt = out
+                toks, lps, top_ids, top_lps, self.cache, aux, nxt = \
+                    self._decode_fns[staged["key"]](
+                        self.params, self.cache, carry, *staged["dev"])
                 outs = (toks, lps, top_ids, top_lps, aux)
         self.decode_windows += 1
         # one window == one device program launch: attention (ragged
@@ -1609,11 +1629,11 @@ class NativeEngine:
             for j in range(n):
                 write_idx[i, j] = seq.flat_index(pos0 + j, ps)
             kv_lens[i] = pos0 + n
+        # VERIFY_OPERANDS' order
         return (("verify", tokens.shape, plan.page_table.shape[1]),
-                (jnp.asarray(tokens), jnp.asarray(positions),
-                 jnp.asarray(plan.page_table), jnp.asarray(kv_lens),
-                 jnp.asarray(write_idx), jnp.asarray(counters),
-                 jnp.asarray(min_toks)))
+                self._stage_operands(
+                    (tokens, positions, plan.page_table, kv_lens, write_idx,
+                     counters, min_toks)))
 
     def _run_spec_decode(self, plan: DecodePlan, drafts: list,
                          block: tuple) -> List[StepOutput]:
@@ -2072,6 +2092,7 @@ class NativeEngine:
         m.pipeline_fallbacks = self.pipeline_fallbacks
         m.decode_host_syncs = self.decode_host_syncs
         m.decode_plan_uploads = self.decode_plan_uploads
+        m.host_buffers = self.host_buffers
         m.mixed_steps = self.mixed_steps
         m.decode_stall_steps = self.decode_stall_steps
         # KV representation gauges (ops/kv_quant.py): bytes one page
@@ -2269,6 +2290,94 @@ class NativeEngine:
                 sch.allocator.free(pid)
             POOL_STATS.prefetch_pages += warmed
         return warmed
+
+
+def pack_operands(arrays) -> tuple:
+    """A step's small host operands as ONE int32 buffer: (layout, buf).
+
+    `arrays` are NumPy arrays of int32, float32 or bool that share their
+    leading (row) axis, each `[rows]` or `[rows, w]`. `buf` is
+    `[rows, sum of widths]` int32 with the operands side by side in the
+    order given: float32 columns carry their bits, bool columns 0/1.
+    `layout` is one `(dtype char, w)` per operand (`w` None for a `[rows]`
+    operand; 0 is a `[rows, 0]` one, a window without stop ids): a pure
+    function of the operands' shapes and dtypes, which are already in
+    every program's key, so it rides the jitted call as a static argument
+    and `unpack_operands` takes the buffer apart again by static slices."""
+    layout = tuple((a.dtype.char, a.shape[1] if a.ndim == 2 else None)
+                   for a in arrays)
+    buf = np.empty((arrays[0].shape[0],
+                    sum(1 if w is None else w for _, w in layout)), np.int32)
+    off = 0
+    for a, (kind, w) in zip(arrays, layout):
+        if kind == "f":
+            a = np.ascontiguousarray(a).view(np.int32)
+        elif kind not in "i?" or a.ndim > 2:
+            raise TypeError(f"operand {a.dtype}{a.shape} does not pack: "
+                            "int32, float32 or bool, [rows] or [rows, w]")
+        if w is None:
+            buf[:, off] = a
+            off += 1
+        else:
+            buf[:, off:off + w] = a
+            off += w
+    return layout, buf
+
+
+def unpack_operands(layout: tuple, buf) -> tuple:
+    """Inside a program: the operands `pack_operands` laid side by side,
+    each with the shape, dtype and bits it had on the host."""
+    out, off = [], 0
+    for kind, w in layout:
+        col = buf[:, off] if w is None else buf[:, off:off + w]
+        if kind == "f":
+            col = jax.lax.bitcast_convert_type(col, jnp.float32)
+        elif kind == "?":
+            col = col != 0
+        out.append(col)
+        off += 1 if w is None else w
+    return tuple(out)
+
+
+# operand names of each program, in the order its staging site packs them
+# (_stage_step, _stage_window, _stage_pp_window, _stage_spec); a variant
+# appends its own ("rep_penalty", "mm_mask") where the program is built
+STEP_OPERANDS = ("tokens", "positions", "page_table", "kv_lens", "write_idx",
+                 "last_idx", "temperature", "top_k", "top_p", "seeds",
+                 "counters", "min_tokens")
+WINDOW_OPERANDS = ("page_table", "base_table", "max_pos", "temperature",
+                   "top_k", "top_p", "seeds", "min_tokens", "ignore_eos",
+                   "stop_ids")
+PP_WINDOW_OPERANDS = ("page_table", "max_pos", "min_tokens", "ignore_eos",
+                      "stop_ids", "temperature", "top_k", "top_p", "seeds")
+VERIFY_OPERANDS = ("tokens", "positions", "page_table", "kv_lens",
+                   "write_idx", "counters", "min_tokens")
+
+
+def _packed(fn, names: tuple, own: tuple = (), carried: bool = False):
+    """`fn` as the step path calls it (NativeEngine._stage_operands):
+    `program(params, cache, [carry,] layout, packed, *own)`. The operands
+    `names` arrive side by side in ONE buffer and are taken apart by the
+    static `layout` (unpack_operands): the same values, dtypes and shapes
+    reach the same ops of `fn`, which is unchanged. `own` names the
+    operands that keep a buffer to themselves. A `carried` program (a
+    decode window) takes its (token, position, counter) as one [S, 3]
+    array and hands on the next in the same form, so a chained window is
+    fed the device's own."""
+    def program(params, cache, *args):
+        kw = {}
+        if carried:
+            carry, *args = args
+            kw.update(tokens=carry[:, 0], positions=carry[:, 1],
+                      counters=carry[:, 2])
+        layout, buf, *rest = args
+        kw.update(zip(names, unpack_operands(layout, buf), strict=True))
+        kw.update(zip(own, rest, strict=True))
+        out = fn(params, cache, **kw)
+        if carried:
+            out = (*out[:-1], jnp.stack(out[-1], axis=1))
+        return out
+    return program
 
 
 def _named(name: str, fn):

@@ -237,6 +237,8 @@ class EngineMetrics:
     pipeline_fallbacks: int = 0
     decode_host_syncs: int = 0
     decode_plan_uploads: int = 0
+    # host->device buffers the step path staged (engine._stage_operands)
+    host_buffers: int = 0
     # mixed prefill+decode steps (docs/PERF.md): fused [Bb, Tb] steps
     # run, and decode stall steps — device steps where >= 1 running
     # request emitted nothing because the step carried no decode rows

@@ -43,6 +43,7 @@ class WorkerMetrics:
     pipeline_fallbacks: int = 0
     decode_host_syncs: int = 0
     decode_plan_uploads: int = 0
+    host_buffers: int = 0   # host->device buffers the step path staged
     # mixed prefill+decode steps (docs/PERF.md): fused steps run, and
     # decode stall steps (steps where running streams emitted nothing
     # because the step carried no decode rows — ~0 with mixed steps on)

@@ -90,6 +90,8 @@ class MetricsExporter:
                  "In-flight windows discarded on membership change"),
                 ("host_syncs", "Blocking output fetches in decode"),
                 ("plan_uploads", "Windows that staged fresh host arrays"),
+                ("host_buffers",
+                 "Host-to-device buffers the step path staged"),
                 ("mixed_steps",
                  "Fused prefill+decode device steps run"),
                 ("stall_steps",
@@ -351,6 +353,8 @@ class MetricsExporter:
                 worker_id, value=m.decode_host_syncs)
             self.g_pipe["plan_uploads"].set(
                 worker_id, value=m.decode_plan_uploads)
+            self.g_pipe["host_buffers"].set(
+                worker_id, value=m.host_buffers)
             self.g_pipe["mixed_steps"].set(
                 worker_id, value=m.mixed_steps)
             self.g_pipe["stall_steps"].set(

@@ -111,6 +111,9 @@ class LedgerStats:
         "host_exposed_seconds",   # of the above, the part spent with no
         #                           dispatched-and-unfetched program: the
         #                           host's estimate of device idle it causes
+        "host_buffers_total",     # host->device buffers the step path staged
+        #                           (engine._stage_operands): per step 1, a
+        #                           fresh window 2, a chained window 0
         # what XLA really built or loaded in this process (jax.monitoring,
         # install_jax_listeners): every jit, not only the engine's keys
         "jax_compiles",           # backend compiles + persistent-cache loads

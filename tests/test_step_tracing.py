@@ -193,6 +193,45 @@ def test_pipelined_steps_keep_the_phases_flat(driven):
     assert total["plan"] == len(steps)
 
 
+def test_upload_opens_once_a_step_and_encloses_the_staging():
+    """Every trip of host operands to the device (`_stage_operands`, the
+    one place that puts them: PR 30) happens while `upload`, and nothing
+    else, is open, and no step opens `upload` twice."""
+    eng = make_engine(pipeline_depth=2)
+    open_now, staged_under, uploads_a_step = [], [], []
+    real_phase, real_stage = eng.phases.phase, eng._stage_operands
+
+    @contextlib.contextmanager
+    def phase(name, annotation=None):
+        open_now.append(name)
+        try:
+            with real_phase(name, annotation):
+                yield
+        finally:
+            open_now.pop()
+
+    def stage(small, own=()):
+        staged_under.append(tuple(open_now))
+        return real_stage(small, own)
+
+    eng.phases.phase, eng._stage_operands = phase, stage
+    arrivals = {0: EngineRequest("a", list(range(10, 40)), sampled(30)),
+                3: EngineRequest("b", list(range(50, 90)), sampled(20, 5))}
+    i = 0
+    while eng.has_work() or i in arrivals:
+        if i in arrivals:
+            eng.add_request(arrivals[i])
+        before = eng.phases.counts.get("upload", 0)
+        eng.step()
+        uploads_a_step.append(eng.phases.counts.get("upload", 0) - before)
+        i += 1
+    assert eng.mixed_steps and eng.decode_windows and eng.pipeline_windows
+    assert len(staged_under) >= 4
+    assert set(staged_under) == {("upload",)}
+    assert set(uploads_a_step) == {0, 1}
+    assert sum(uploads_a_step) >= len(staged_under)
+
+
 @pytest.mark.parametrize("run", ["sync", "pipelined", "spec"])
 def test_phases_and_between_sum_to_the_wall_time(driven, run):
     """Tolerance: 3 % of the loop's wall time plus 0.2 ms a step (the
