@@ -74,6 +74,34 @@ class ModelConfig:
     # "dense" = every expert computes every token (exact, E/k x FLOPs —
     # oracle for tests)
     moe_impl: str = "dispatch"
+    # DeepSeek-V3-family router and block (Moonlight): `moe_scoring`
+    # "softmax" | "sigmoid" (scores over ALL experts, float32);
+    # `moe_router_bias`: a per-expert `router_bias` leaf is added to the
+    # scores to PICK the k experts and not to weigh them (`noaux_tc`);
+    # `moe_routed_scale` multiplies the kept weights after renormalising;
+    # `shared_expert_size`: width of ONE dense SwiGLU every token also
+    # passes (n_shared_experts x moe_intermediate_size), 0 = none;
+    # `first_dense_layers`: leading layers with a dense MLP of
+    # `dense_intermediate_size` where the rest have experts (the layer
+    # kinds are split once, models/llama.layer_groups)
+    moe_scoring: str = "softmax"
+    moe_router_bias: bool = False
+    moe_routed_scale: float = 1.0
+    shared_expert_size: int = 0
+    first_dense_layers: int = 0
+    dense_intermediate_size: int = 0
+    # Multi-head latent attention (DeepSeek-V2/V3, no q-LoRA):
+    # kv_lora_rank > 0 switches it on. q has num_heads heads of
+    # qk_nope_head_dim | qk_rope_head_dim, the cache holds ONE leaf of
+    # kv_lora_rank + qk_rope_head_dim values a token and layer (the
+    # normalised latent | the rotated shared key), and attention runs in
+    # the absorbed form against it as one KV head whose values are its
+    # first kv_lora_rank columns (`kv_cache_leaves`). `head_dim` is then
+    # v_head_dim (what `wo` takes a head) and `query_scale` the
+    # (nope + rope) ** -0.5 the loader sets.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
     # decode attention impl: "auto" and "off" are the XLA gather path on
     # every platform (models/llama._decode_kernel_mode says why); "on" is
     # the compiled Pallas kernel and raises at engine construction where it
@@ -105,6 +133,29 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    def kv_cache_leaves(self) -> dict:
+        """THE description of the paged cache: value leaf -> (kv heads,
+        width), each stored [L, heads, pages, page_size, width]. Two
+        leaves of num_kv_heads x head_dim, or under latent attention ONE,
+        named "k" because it is what the absorbed queries are scored
+        against: a single head of kv_lora_rank + qk_rope_head_dim whose
+        first kv_lora_rank columns are also the values. init_cache, the
+        shardings, the page-byte gauges and every refusal read this."""
+        if self.is_mla:
+            return {"k": (1, self.kv_lora_rank + self.qk_rope_head_dim)}
+        return {"k": (self.num_kv_heads, self.head_dim),
+                "v": (self.num_kv_heads, self.head_dim)}
+
+    def kv_bytes_per_token(self) -> int:
+        """Bytes one token holds in the unquantized cache, all layers."""
+        itemsize = 4 if self.dtype == "float32" else 2
+        return self.num_layers * itemsize * sum(
+            h * w for h, w in self.kv_cache_leaves().values())
 
     @property
     def moe_dropless(self) -> bool:

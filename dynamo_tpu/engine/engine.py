@@ -88,6 +88,7 @@ class NativeEngine:
         # VERDICT r3 weak #7 + r4 #6); logprob/penalty plans fall back to
         # per-token dispatch.
         self.pp = self.mesh.shape.get("pp", 1)
+        llama.refuse_unserved_latent_cache(model_cfg, engine_cfg, self.mesh)
         if self.mesh.size > 1 and model_cfg.moe_dropless \
                 and model_cfg.moe_impl == "dispatch":
             # the dropless dispatch (ops/moe.py) is one device's; what a
@@ -241,6 +242,7 @@ class NativeEngine:
         self.ledger = StepLedger(
             flops_per_token=model_flops_per_token(model_cfg)
             + sampler_flops_per_token(model_cfg))
+        self.ledger.stats.kv_bytes_per_token = model_cfg.kv_bytes_per_token()
         # (program, bucket) keys already dispatched: a key's first
         # dispatch is an XLA compile that stalls the serving loop —
         # counted as a recompile event on the ledger sample that commits
@@ -802,16 +804,25 @@ class NativeEngine:
             self.model_cfg.vocab_size,
             lambda n: next_bucket(n, pow2_buckets(self.cfg.max_model_len)))
 
-    def _account_moe(self, aux) -> None:
+    def _account_moe(self, aux, window: bool = False) -> None:
         """Fold a step's MoE stats (ops/moe.py moe_stats, already on the
         host with the step's outputs) into the `llm_engine_moe_*_total`
         series, and warn once where a capacity dispatch drops (it does so
-        silently otherwise — ADVICE r1 medium)."""
+        silently otherwise — ADVICE r1 medium). A decode `window`'s
+        experts hit and layer calls are also kept apart
+        (`moe_window_*_total`): its steps hold a row a sequence, so the
+        experts they touch, and with them the weight bytes a window step
+        reads, are far fewer than a chunk's."""
         from dynamo_tpu.observability.ledger import LEDGER_STATS
         for key, value in aux.items():
             name = f"{key}_total"
             setattr(LEDGER_STATS, name,
                     getattr(LEDGER_STATS, name) + float(value))
+        if window:
+            LEDGER_STATS.moe_window_experts_hit_total += float(
+                aux["moe_experts_hit"])
+            LEDGER_STATS.moe_window_layer_calls_total += float(
+                aux["moe_layer_calls"])
         self.moe_dropped_tokens += float(aux["moe_dropped"])
         self.moe_routed_tokens += float(aux["moe_routed"])
         rate = self.moe_drop_rate()
@@ -955,7 +966,20 @@ class NativeEngine:
         key = ("step", rp is not None, with_lp, mm, plan.tokens.shape,
                plan.page_table.shape[1],
                None if rp is None else rp[0].shape[1])
+        self._account_attention(int(plan.kv_lens.sum()),
+                                plan.page_table.size)
         return key, self._stage_operands(small, own), with_lp
+
+    def _account_attention(self, kv_tokens: int, table_pages: int) -> None:
+        """`llm_engine_attn_kv_tokens_total` / `_slots_total`, from a
+        step's plan on the host: the context tokens its real rows attend
+        to, and the token slots the gather path reads for them (rows x
+        page-table width x page size; a decode window: the base it
+        gathers, once a window, whatever its rung). 1 - tokens / slots
+        is the share of gathered KV that is bucket padding."""
+        stats = self.ledger.stats
+        stats.attn_kv_tokens_total += kv_tokens
+        stats.attn_kv_slots_total += table_pages * self.cfg.page_size
 
     def _stage_operands(self, small: tuple, own: tuple = ()) -> tuple:
         """THE way a step's host operands reach the device, for every step
@@ -1227,6 +1251,9 @@ class NativeEngine:
                 # valid-KV capacity of the staged base table; the kernel
                 # path streams from the global cache and has no base cap
                 "base_cap": base_pb * ps if pregather else None,
+                # (context tokens, table pages) of the base this window
+                # gathers, once: _account_attention, at dispatch
+                "attn": (int(base_lens.sum()), len(plan.seqs) * base_pb),
                 "pp": False}
 
     @staticmethod
@@ -1289,6 +1316,8 @@ class NativeEngine:
                         self.params, self.cache, carry, *staged["dev"])
                 outs = (toks, lps, top_ids, top_lps, aux)
         self.decode_windows += 1
+        if "attn" in staged:
+            self._account_attention(*staged["attn"])
         # one window == one device program launch: attention (ragged
         # kernel or gather) + sampling tail all inside it. The counter is
         # the DECODE_PROFILE.jsonl dispatch-count evidence — dispatches /
@@ -1318,7 +1347,7 @@ class NativeEngine:
         self.phases.device_busy = in_flight
         self.decode_host_syncs += 1
         if aux:
-            self._account_moe(aux)
+            self._account_moe(aux, window=True)
         with self.phases.phase("commit"):
             return self._commit_window(plan, np.asarray(toks), lps,
                                        top_ids, top_lps)
@@ -1971,6 +2000,9 @@ class NativeEngine:
         """Gather whole KV pages -> ({k,v[,k_scale,v_scale]}, on-device):
         values [L, Hkv, Nb, ps, hd] plus scale stacks [L, Hkv, Nb, ps] on
         kv_quant engines — the stored representation, never dequantized."""
+        llama.refuse_unserved_latent_cache(
+            self.model_cfg, feature="whole-page extraction (disagg "
+            "transfer, the shared KV pool)")
         ids = jnp.asarray(self._bucket_ids(page_ids))
         ids = jnp.minimum(ids, self.cfg.num_pages - 1)  # clamp padding reads
         return self._extract_fn(self.cache, ids)
@@ -1991,6 +2023,9 @@ class NativeEngine:
         The id padding follows the SENDER's bucket (k_pages.shape[2]), not
         ours — the two engines may have different max_model_len and hence
         different page-count buckets; padding ids drop on scatter."""
+        llama.refuse_unserved_latent_cache(
+            self.model_cfg, feature="whole-page injection (disagg "
+            "transfer, the shared KV pool)")
         if self.kv_quant and k_scale is None:
             raise ValueError(
                 "this engine stores int8 KV pages (kv_quant="
@@ -2101,12 +2136,14 @@ class NativeEngine:
         # global counters so prefill-side sends surface on the sender's
         # own metrics (refreshed per metrics() call, like the PR-4
         # robustness gauges)
-        from dynamo_tpu.ops.kv_quant import page_bytes
+        from dynamo_tpu.ops.kv_quant import leaf_page_bytes
         from dynamo_tpu.runtime.integrity import XFER_STATS
         mc, ec = self.model_cfg, self.cfg
-        m.kv_page_bytes = page_bytes(
-            mc.num_layers, mc.num_kv_heads, ec.page_size, mc.head_dim,
-            jnp.dtype(mc.dtype).itemsize, bool(self.kv_quant))
+        m.kv_page_bytes = sum(
+            leaf_page_bytes(mc.num_layers, heads, ec.page_size, width,
+                            jnp.dtype(mc.dtype).itemsize,
+                            bool(self.kv_quant))
+            for heads, width in mc.kv_cache_leaves().values())
         m.kv_quant_bits = 8 if self.kv_quant == "int8" else 0
         m.kv_transfer_bytes = XFER_STATS.bytes_sent
         m.kv_transfer_fetches = XFER_STATS.fetches
@@ -2164,6 +2201,8 @@ class NativeEngine:
         events ride the KV-event plane under `pool:{source_id}` so the
         router learns pool-resident prefixes (kv_router/protocols.py)."""
         from dynamo_tpu.engine.kv_pool import PoolPublishStream
+        llama.refuse_unserved_latent_cache(
+            self.model_cfg, feature="the shared KV pool")
         self.kv_pool = pool
         self.kv_pool_source = source_id
         self.scheduler.kv_pool = pool
@@ -2436,7 +2475,8 @@ def _scatter_new_kv(cache, k_news, v_news, write_idx):
     """One in-place scatter of all layers' new kv rows (deferred write).
 
     cache {k,v[,k_scale,v_scale]}: [L, Hkv, P, ps, hd] (+ [L, Hkv, P,
-    ps] scales); k_news/v_news [L, S, Hkv, hd] full-precision rows;
+    ps] scales); k_news/v_news [L, S, Hkv, hd] full-precision rows
+    (v_news None: a one-leaf cache, latent attention);
     write_idx [S] flat token slots (<0 = padding, dropped). On kv_quant
     caches the rows quantize HERE — capture time, inside the jitted step
     — and the int8 values + f32 scales scatter together. The leaves are
@@ -2447,7 +2487,7 @@ def _scatter_new_kv(cache, k_news, v_news, write_idx):
         kv_write_plan, stored_kv_rows, write_kv_rows)
     from dynamo_tpu.ops.kv_quant import cache_keys
     quant = "k_scale" in cache
-    keys = cache_keys(quant)
+    keys = tuple(key for key in cache_keys(quant) if key in cache)
     # dynalint: kv-codec — rows enter in the stored representation
     # (stored_kv_rows quantizes them on an int8 pool), values and scales
     # paired: [L, S, Hkv, hd] / [L, S, Hkv]
@@ -2556,12 +2596,15 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
         else:
             # dynalint: kv-codec — unquantized base gather
             kb = gather_base(cache["k"])
-            vb = gather_base(cache["v"])
+            # a one-leaf cache (latent attention) has no values leaf: the
+            # base, the window buffer and the new rows are then k alone
+            # dynalint: kv-codec — unquantized base gather
+            vb = gather_base(cache["v"]) if "v" in cache else None
         # valid kv at window start; fixed across the window (the window
         # buffer covers everything generated after it)
         base_len = jnp.clip(positions, 0, max_pos + 1)
         kw0 = jnp.zeros((l, hkv_n, s, n_steps, hd), kb.dtype)
-        vw0 = jnp.zeros_like(kw0)
+        vw0 = None if vb is None else jnp.zeros_like(kw0)
 
     def global_write_idx(pos, writable):
         """Flat global-cache slot for this step's row (-1 = dropped)."""
@@ -2626,8 +2669,10 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
         # tracked separately (dropped rows get index -1).
         kw = jax.lax.dynamic_update_index_in_dim(
             kw, k_news.transpose(0, 2, 1, 3).astype(kw.dtype), t, axis=3)
-        vw = jax.lax.dynamic_update_index_in_dim(
-            vw, v_news.transpose(0, 2, 1, 3).astype(vw.dtype), t, axis=3)
+        if vw is not None:
+            vw = jax.lax.dynamic_update_index_in_dim(
+                vw, v_news.transpose(0, 2, 1, 3).astype(vw.dtype), t,
+                axis=3)
         nxt, lp, top_ids, top_lps, seen, alive = sample_and_track(
             logits, ctr, seen, alive)
         return (kw, vw, nxt, pos + 1, ctr + 1, seen, alive), \
@@ -2652,10 +2697,10 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
     aux = {k: jnp.sum(v) for k, v in auxs.items()}
     # end-of-window writeback: all N steps' rows -> global paged cache in
     # one scatter ([N, L, S, Hkv, hd] -> [L, N*S, Hkv, hd])
-    k_flat = k_all.transpose(1, 0, 2, 3, 4).reshape(l, n_steps * s,
-                                                    cfg.num_kv_heads, hd)
-    v_flat = v_all.transpose(1, 0, 2, 3, 4).reshape(l, n_steps * s,
-                                                    cfg.num_kv_heads, hd)
+    k_flat, v_flat = (
+        None if rows_all is None else rows_all.transpose(
+            1, 0, 2, 3, 4).reshape(l, n_steps * s, hkv_n, hd)
+        for rows_all in (k_all, v_all))
     cache = _scatter_new_kv(cache, k_flat, v_flat, widx_all.reshape(-1))
     # final (token, position, counter) stay ON DEVICE: when the slot set and
     # page allocation are unchanged, the engine feeds them straight into the

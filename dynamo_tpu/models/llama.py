@@ -1,4 +1,6 @@
-"""Llama-family decoder (also hosts the Mixtral-style MoE MLP variant).
+"""Llama-family decoder (also hosts the Mixtral-style MoE MLP variant, and
+DeepSeek-V3's latent attention, sigmoid router, shared experts and dense
+lead: `_mla_front`, `_mlp_block`, `layer_groups`).
 
 Functional JAX, TPU-first:
 - parameters are a pytree of arrays **stacked over layers** and the layer loop
@@ -19,6 +21,7 @@ cfg.num_experts > 0.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -33,7 +36,7 @@ from dynamo_tpu.ops.attention import (
 from dynamo_tpu.ops.kv_quant import cache_keys
 from dynamo_tpu.ops.kv_quant import validate_mode as _validate_kv_quant
 from dynamo_tpu.ops.moe import (
-    moe_dispatch_mlp, moe_dispatch_mlp_sharded, moe_dropless_mlp, route_topk,
+    moe_dispatch_mlp, moe_dispatch_mlp_sharded, moe_dropless_mlp, route,
 )
 from dynamo_tpu.ops.quant import is_quantized, wmat
 from dynamo_tpu.ops.paged_attention import (
@@ -107,24 +110,76 @@ def _dtype(cfg: ModelConfig):
 
 # -- init ---------------------------------------------------------------------
 
-def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
-    """Random-init parameters (stacked over layers)."""
+def layer_groups(cfg: ModelConfig) -> tuple:
+    """The model's layer kinds, split once: ((key in params, first layer,
+    count, dense MLP?), ...). One group, `params["layers"]`, for every
+    model whose layers are all alike. A model with a leading dense MLP
+    before its expert layers (`first_dense_layers`) has two stacks:
+    `params["dense_layers"]` and then `params["layers"]`, the experts,
+    whose leaves keep their own leading axis so the grouped matmul reads
+    them in place (no slice of one stack by kind). The cache's layer
+    index runs over both, in order. init_params, param_shardings,
+    forward(), decode_forward() and models/loader.py all walk this."""
+    lead = cfg.first_dense_layers if cfg.is_moe else 0
+    if not lead:
+        return (("layers", 0, cfg.num_layers, not cfg.is_moe),)
+    return (("dense_layers", 0, lead, True),
+            ("layers", lead, cfg.num_layers - lead, False))
+
+
+def _group_rows(whole: bool, first: int, count: int):
+    """A per-layer array's rows of one layer group: all of them, untouched,
+    where the model has one group (the older models' programs stay as
+    they were traced), else the group's slice."""
+    return (lambda a: a) if whole else (lambda a: a[first:first + count])
+
+
+def _mlp_width(cfg: ModelConfig, dense: bool) -> int:
+    """A dense MLP's width: the leading dense layers of an expert model
+    have their own (`dense_intermediate_size`)."""
+    return cfg.dense_intermediate_size if dense and cfg.is_moe \
+        else cfg.intermediate_size
+
+
+def _init_layer_stack(keys, cfg: ModelConfig, l: int, dense_mlp: bool
+                      ) -> Params:
+    """One layer group's leaves, stacked over its `l` layers."""
     dt = _dtype(cfg)
     d, hd = cfg.hidden_size, cfg.head_dim
-    h, hkv, f, l = cfg.num_heads, cfg.num_kv_heads, cfg.intermediate_size, cfg.num_layers
-    keys = jax.random.split(rng, 12)
+    h, hkv = cfg.num_heads, cfg.num_kv_heads
+    f = _mlp_width(cfg, dense_mlp)
 
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5).astype(dt)
 
+    def near_one(key, shape, sd=0.1):
+        # seeded, not ones: a norm weight of one would hide a path that
+        # skipped it (or applied it per head with the first head's slice)
+        return (1.0 + sd * jax.random.normal(key, shape, jnp.float32)
+                ).astype(dt)
+
+    more = jax.random.split(jax.random.fold_in(keys[0], 31), 6)
     layers = {
         "attn_norm": jnp.ones((l, d), dt),
-        "wq": dense(keys[0], (l, d, h * hd), d),
-        "wk": dense(keys[1], (l, d, hkv * hd), d),
-        "wv": dense(keys[2], (l, d, hkv * hd), d),
         "wo": dense(keys[3], (l, h * hd, d), h * hd),
         "mlp_norm": jnp.ones((l, d), dt),
     }
+    if cfg.is_mla:
+        r, dn, dr = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                     cfg.qk_rope_head_dim)
+        layers.update({
+            "wq": dense(keys[0], (l, d, h * (dn + dr)), d),
+            "wkv_a": dense(keys[1], (l, d, r + dr), d),
+            "kv_a_norm": near_one(more[0], (l, r)),
+            # per head k_nope[dn] | v[hd], as the checkpoint has them
+            "wkv_b": dense(keys[2], (l, r, h * (dn + hd)), r),
+        })
+    else:
+        layers.update({
+            "wq": dense(keys[0], (l, d, h * hd), d),
+            "wk": dense(keys[1], (l, d, hkv * hd), d),
+            "wv": dense(keys[2], (l, d, hkv * hd), d),
+        })
     if cfg.post_norms:
         layers.update({
             "post_attn_norm": jnp.ones((l, d), dt),
@@ -137,15 +192,11 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
             "wv_b": jnp.zeros((l, hkv * hd), dt),
         })
     if cfg.qk_norm:
-        # seeded, not ones: a norm weight of one would hide a check that
-        # skipped it or applied it per head with the first head's slice
         layers.update({
-            "q_norm": (1.0 + 0.1 * jax.random.normal(
-                keys[10], (l, h * hd), jnp.float32)).astype(dt),
-            "k_norm": (1.0 + 0.1 * jax.random.normal(
-                keys[11], (l, hkv * hd), jnp.float32)).astype(dt),
+            "q_norm": near_one(keys[10], (l, h * hd)),
+            "k_norm": near_one(keys[11], (l, hkv * hd)),
         })
-    if cfg.is_moe:
+    if cfg.is_moe and not dense_mlp:
         e = cfg.num_experts
         layers.update({
             "router": dense(keys[4], (l, d, e), d),
@@ -153,17 +204,46 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
             "w_up": dense(keys[6], (l, e, d, f), d),
             "w_down": dense(keys[7], (l, e, f, d), f),
         })
+        if cfg.moe_router_bias:
+            # float32 like the checkpoint's; seeded, so that a router
+            # which ignored it (or weighed with it) is seen
+            layers["router_bias"] = 0.1 * jax.random.normal(
+                more[1], (l, e), jnp.float32)
+        if cfg.shared_expert_size:
+            fs = cfg.shared_expert_size
+            layers.update({
+                "ws_gate": dense(more[2], (l, d, fs), d),
+                "ws_up": dense(more[3], (l, d, fs), d),
+                "ws_down": dense(more[4], (l, fs, d), fs),
+            })
     else:
         layers.update({
             "w_gate": dense(keys[5], (l, d, f), d),
             "w_up": dense(keys[6], (l, d, f), d),
             "w_down": dense(keys[7], (l, f, d), f),
         })
+    return layers
+
+
+def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
+    """Random-init parameters (stacked over layers, a stack a layer kind:
+    `layer_groups`)."""
+    dt = _dtype(cfg)
+    d = cfg.hidden_size
+    keys = jax.random.split(rng, 12)
+
+    def dense(key, shape, fan_in):
+        return (jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5).astype(dt)
+
     params: Params = {
         "embed": dense(keys[8], (cfg.vocab_size, d), d),
-        "layers": layers,
         "final_norm": jnp.ones((d,), dt),
     }
+    for name, _, count, dense_mlp in layer_groups(cfg):
+        params[name] = _init_layer_stack(
+            keys if name == "layers"
+            else jax.random.split(jax.random.fold_in(rng, 1), 12),
+            cfg, count, dense_mlp)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = dense(keys[9], (d, cfg.vocab_size), d)
     if cfg.vision is not None:
@@ -182,14 +262,36 @@ def param_shardings(cfg: ModelConfig) -> Params:
     MoE experts shard over "tp" as well (expert-parallel uses the same axis
     until the dedicated "ep" mesh is used — see models/moe notes).
     """
+    out: Params = {
+        "embed": P(None, None),
+        "final_norm": P(None),
+    }
+    for name, _, _, dense_mlp in layer_groups(cfg):
+        out[name] = _layer_stack_shardings(cfg, dense_mlp)
+    if not cfg.tie_word_embeddings:
+        out["lm_head"] = P(None, "tp")
+    if cfg.vision is not None:
+        from dynamo_tpu.models import vision
+        out["vision"] = vision.param_shardings(cfg)
+    return out
+
+
+def _layer_stack_shardings(cfg: ModelConfig, dense_mlp: bool) -> Params:
+    """PartitionSpecs of one layer group: `_init_layer_stack`'s tree."""
     layers = {
         "attn_norm": P(None, None),
         "wq": P(None, None, "tp"),
-        "wk": P(None, None, "tp"),
-        "wv": P(None, None, "tp"),
         "wo": P(None, "tp", None),
         "mlp_norm": P(None, None),
     }
+    if cfg.is_mla:
+        # the latent projection and its norm are shared by every head;
+        # no mesh serves this model yet (refuse_unserved_latent_cache)
+        layers.update({"wkv_a": P(None, None, None),
+                       "kv_a_norm": P(None, None),
+                       "wkv_b": P(None, None, "tp")})
+    else:
+        layers.update({"wk": P(None, None, "tp"), "wv": P(None, None, "tp")})
     if cfg.post_norms:
         layers.update({
             "post_attn_norm": P(None, None),
@@ -203,7 +305,7 @@ def param_shardings(cfg: ModelConfig) -> Params:
         })
     if cfg.qk_norm:
         layers.update({"q_norm": P(None, "tp"), "k_norm": P(None, "tp")})
-    if cfg.is_moe:
+    if cfg.is_moe and not dense_mlp:
         # experts shard over "ep", each expert's FFN dim over "tp"; on
         # meshes without those axes (size 1) the specs are no-ops
         layers.update({
@@ -212,23 +314,19 @@ def param_shardings(cfg: ModelConfig) -> Params:
             "w_up": P(None, "ep", None, "tp"),
             "w_down": P(None, "ep", "tp", None),
         })
+        if cfg.moe_router_bias:
+            layers["router_bias"] = P(None, None)
+        if cfg.shared_expert_size:
+            layers.update({"ws_gate": P(None, None, "tp"),
+                           "ws_up": P(None, None, "tp"),
+                           "ws_down": P(None, "tp", None)})
     else:
         layers.update({
             "w_gate": P(None, None, "tp"),
             "w_up": P(None, None, "tp"),
             "w_down": P(None, "tp", None),
         })
-    out: Params = {
-        "embed": P(None, None),
-        "layers": layers,
-        "final_norm": P(None),
-    }
-    if not cfg.tie_word_embeddings:
-        out["lm_head"] = P(None, "tp")
-    if cfg.vision is not None:
-        from dynamo_tpu.models import vision
-        out["vision"] = vision.param_shardings(cfg)
-    return out
+    return layers
 
 
 def cache_sharding(cfg: ModelConfig) -> P:
@@ -248,26 +346,73 @@ def cache_scale_sharding(cfg: ModelConfig) -> P:
 
 def cache_shardings(cfg: ModelConfig) -> Dict[str, P]:
     """Per-leaf PartitionSpecs matching init_cache's dict layout."""
-    out = {"k": cache_sharding(cfg), "v": cache_sharding(cfg)}
+    out = {name: cache_sharding(cfg) for name in cfg.kv_cache_leaves()}
     if _validate_kv_quant(cfg.kv_quant):
-        out["k_scale"] = cache_scale_sharding(cfg)
-        out["v_scale"] = cache_scale_sharding(cfg)
+        out.update({f"{name}_scale": cache_scale_sharding(cfg)
+                    for name in cfg.kv_cache_leaves()})
     return out
 
 
 def init_cache(cfg: ModelConfig, num_pages: int, page_size: int) -> Dict[str, jax.Array]:
-    dt = _dtype(cfg)
-    shape = (cfg.num_layers, cfg.num_kv_heads, num_pages, page_size, cfg.head_dim)
+    """The paged pool, a leaf per entry of `cfg.kv_cache_leaves()`:
+    [L, heads, pages, page_size, width]."""
+    shapes = {name: (cfg.num_layers, heads, num_pages, page_size, width)
+              for name, (heads, width) in cfg.kv_cache_leaves().items()}
     if _validate_kv_quant(cfg.kv_quant):
         # int8 pages + per-row f32 scales (ops/kv_quant.py): the scale
         # array shares the page axis (2) with the values, so every
         # page-indexed move (extract/inject/offload/transfer) carries
         # the scales with the same ids
-        return {"k": jnp.zeros(shape, jnp.int8),
-                "v": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(shape[:-1], jnp.float32),
-                "v_scale": jnp.zeros(shape[:-1], jnp.float32)}
-    return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+        out = {name: jnp.zeros(shape, jnp.int8)
+               for name, shape in shapes.items()}
+        out.update({f"{name}_scale": jnp.zeros(shape[:-1], jnp.float32)
+                    for name, shape in shapes.items()})
+        return out
+    return {name: jnp.zeros(shape, _dtype(cfg))
+            for name, shape in shapes.items()}
+
+
+def refuse_unserved_latent_cache(cfg: ModelConfig, engine_cfg=None,
+                                 mesh=None, feature: str = "") -> None:
+    """THE place that says what a one-leaf latent cache (`cfg.is_mla`)
+    cannot be served with yet; every other model passes. The engine calls
+    it at construction with its configuration and mesh, and the entry
+    points that move whole pages by the names "k" and "v" (disagg
+    transfer, the shared pool) call it with `feature` when they are
+    reached. Each of these reads the cache as two leaves of Hkv heads:
+    the Pallas decode kernel and its sharded form, the int8 page codec,
+    `parallel/mesh.kv_shard_layout` and the tp / pp / ep / sp programs,
+    the host and disk tiers with the streamed decode on top of them, the
+    pool service and the transfer frames (PERF.md section 7)."""
+    if not cfg.is_mla:
+        return
+    why = []
+    if feature:
+        why.append(feature)
+    if mesh is not None and mesh.size > 1:
+        why.append(f"a {dict(mesh.shape)} mesh (--tp/--pp/--ep/--sp/--dp: "
+                   f"the one KV head cannot be sharded)")
+    if cfg.kv_quant:
+        why.append(f"kv_quant={cfg.kv_quant!r} (the codec is per K and V "
+                   f"row)")
+    if cfg.quant:
+        why.append(f"quant={cfg.quant!r} (ops/quant.py names the "
+                   f"wq/wk/wv leaves)")
+    if cfg.decode_kernel not in ("auto", "off"):
+        why.append(f"decode_kernel={cfg.decode_kernel!r} (the Pallas "
+                   f"kernel reads separate K and V pages)")
+    if engine_cfg is not None:
+        if engine_cfg.host_pages or engine_cfg.disk_pages \
+                or engine_cfg.stream_pages:
+            why.append("the host / disk KV tiers and streamed decode "
+                       "(--host-pages, --disk-pages, --stream-pages)")
+        if engine_cfg.kv_quant:
+            why.append(f"kv_quant={engine_cfg.kv_quant!r}")
+    if why:
+        raise ValueError(
+            f"{cfg.name}: latent attention keeps ONE cache leaf of width "
+            f"{cfg.kv_lora_rank + cfg.qk_rope_head_dim} a token; not "
+            f"served with it yet: " + "; ".join(why))
 
 
 # -- forward ------------------------------------------------------------------
@@ -321,8 +466,7 @@ def _moe_mlp(x: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
     """
     b, t, d = x.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
-    weights, idx = route_topk(x, lp["router"], k,
-                              cfg.norm_topk_prob)              # [B, T, k]
+    weights, idx = route(x, lp, cfg)                           # [B, T, k]
     one_hot = jax.nn.one_hot(idx, e, dtype=jnp.float32)        # [B, T, k, E]
     combine = jnp.einsum("btk,btke->bte", weights, one_hot)    # [B, T, E]
 
@@ -335,11 +479,13 @@ def _moe_mlp(x: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
 
 
 @jax.named_scope("mlp")
-def _dense_mlp(x: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
-    gate = jnp.einsum("btd,df->btf", x, wmat(lp["w_gate"], x.dtype))
-    up = jnp.einsum("btd,df->btf", x, wmat(lp["w_up"], x.dtype))
+def _dense_mlp(x: jax.Array, lp: Params, cfg: ModelConfig,
+               leaves: tuple = ("w_gate", "w_up", "w_down")) -> jax.Array:
+    w_gate, w_up, w_down = (wmat(lp[name], x.dtype) for name in leaves)
+    gate = jnp.einsum("btd,df->btf", x, w_gate)
+    up = jnp.einsum("btd,df->btf", x, w_up)
     act = mlp_activation(gate, cfg) * up
-    return jnp.einsum("btf,fd->btd", act, wmat(lp["w_down"], x.dtype))
+    return jnp.einsum("btf,fd->btd", act, w_down)
 
 
 def qkv_proj(xn: jax.Array, lp: Params, cfg: ModelConfig):
@@ -381,30 +527,54 @@ def split_expert_stacks(layers: Params, cfg: ModelConfig, mesh):
 
 
 def _mlp_block(xn: jax.Array, lp: Params, cfg: ModelConfig, mesh,
-               token_valid, stacks=None, lid=None):
+               token_valid, stacks=None, lid=None, dense: bool = False):
     """The layer's MLP: (out, stats). stats is None except on the MoE
     dispatch paths, where it is ops/moe.py's `moe_stats` dict. `stacks`
-    and `lid`: split_expert_stacks' second half and this layer's index."""
+    and `lid`: split_expert_stacks' second half and this layer's index
+    in them. `dense`: a layer of the group with a dense MLP
+    (`layer_groups`), whatever the model's other layers are. An expert
+    layer with `shared_expert_size` adds ONE dense SwiGLU of that width,
+    evaluated on every token, to the routed experts' output."""
+    if dense and cfg.is_moe:
+        with jax.named_scope("mlp.dense_lead"):
+            return _dense_mlp(xn, lp, cfg), None
     if not cfg.is_moe:
         return _dense_mlp(xn, lp, cfg), None
     if cfg.moe_impl == "dense":
-        return _moe_mlp(xn, lp, cfg), None
-    if mesh is not None and mesh.shape.get("ep", 1) > 1:
+        out, stats = _moe_mlp(xn, lp, cfg), None
+    elif mesh is not None and mesh.shape.get("ep", 1) > 1:
         # explicit O(E/ep) per-shard dispatch (ops/moe.py sharded path)
-        return moe_dispatch_mlp_sharded(
+        out, stats = moe_dispatch_mlp_sharded(
             xn, lp, cfg, mesh, return_dropped=True, valid=token_valid)
-    if _use_dropless(cfg, mesh):
+    elif _use_dropless(cfg, mesh):
         if stacks is not None:
-            return moe_dropless_mlp(xn, {**lp, **stacks}, cfg,
-                                    valid=token_valid, layer=lid)
-        return moe_dropless_mlp(xn, lp, cfg, valid=token_valid)
-    return moe_dispatch_mlp(xn, lp, cfg, return_dropped=True,
-                            valid=token_valid)
+            out, stats = moe_dropless_mlp(xn, {**lp, **stacks}, cfg,
+                                          valid=token_valid, layer=lid)
+        else:
+            out, stats = moe_dropless_mlp(xn, lp, cfg, valid=token_valid)
+    else:
+        out, stats = moe_dispatch_mlp(xn, lp, cfg, return_dropped=True,
+                                      valid=token_valid)
+    if cfg.shared_expert_size:
+        with jax.named_scope("moe.shared"):
+            out = out + _dense_mlp(xn, lp, cfg,
+                                   ("ws_gate", "ws_up", "ws_down"))
+    return out, stats
 
 
 def _sum_stats(stats) -> dict:
     """Per-layer (and per-step) stacks of MoE stats -> one scalar each."""
     return {k: jnp.sum(v) for k, v in (stats or {}).items()}
+
+
+def _merge_stats(groups: list) -> dict:
+    """The layer groups' summed stats -> one dict (a group without
+    experts has none)."""
+    out: dict = {}
+    for stats in groups:
+        for k, v in stats.items():
+            out[k] = out[k] + v if k in out else v
+    return out
 
 
 # -- the layer ----------------------------------------------------------------
@@ -418,7 +588,12 @@ def layer_front(x: jax.Array, lp: Params, cfg: ModelConfig,
     """x [B, T, D] -> q [B, T, H, hd], k, v [B, T, Hkv, hd]: attention
     norm, QKV projection (bias, QK-norm), split into heads, RoPE on q and
     k. `heads` = (H, Hkv) as the caller holds them: a "tp" shard of a
-    manual mesh passes its local counts."""
+    manual mesh passes its local counts. Under latent attention
+    (`_mla_front`) q is the absorbed query, k the token's ONE cache row
+    and v None: the attention ops take the whole row as its values and
+    `_mla_out` keeps the latent columns of what they return."""
+    if cfg.is_mla:
+        return _mla_front(x, lp, cfg, positions)
     b, t = x.shape[:2]
     h, hkv = heads
     xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
@@ -430,6 +605,60 @@ def layer_front(x: jax.Array, lp: Params, cfg: ModelConfig,
     return q, k, v.reshape(b, t, hkv, cfg.head_dim)
 
 
+def _mla_up_proj(lp: Params, cfg: ModelConfig, dtype) -> tuple:
+    """`wkv_b` [r, H * (dn + hd)] as (W_UK [r, H, dn], W_UV [r, H, hd])."""
+    w = wmat(lp["wkv_b"], dtype).reshape(
+        cfg.kv_lora_rank, cfg.num_heads, cfg.qk_nope_head_dim + cfg.head_dim)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def _mla_front(x: jax.Array, lp: Params, cfg: ModelConfig,
+               positions: jax.Array):
+    """Multi-head latent attention in the absorbed form, the front half:
+    x [B, T, D] -> (q [B, T, H, r + dr], row [B, T, 1, r + dr], None).
+
+    `row` is what the cache stores for a token, once: the latent
+    c = RMSNorm(x Wkv_a[:, :r]; kv_a_norm) and the rotated key part
+    k_pe = RoPE(x Wkv_a[:, r:]) that every head shares. The query of
+    head h is (q_nope_h W_UK_h^T | RoPE(q_pe_h)), so that q . row =
+    q_nope . (c W_UK_h) + q_pe . k_pe: the published scores without ever
+    expanding c to per-head keys. The values are c itself (the row's
+    first r columns); `_mla_out` applies W_UV after the softmax. RoPE is
+    rotate-half over the dr rope columns only: models/loader.py
+    de-interleaves those columns of Wq / Wkv_a once at load, which is
+    the published code's own first step moved from the activations to
+    the weights."""
+    b, t = x.shape[:2]
+    h, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
+    with jax.named_scope("attention.mla.q"):
+        q = jnp.einsum("btd,de->bte", xn, wmat(lp["wq"], xn.dtype)
+                       ).reshape(b, t, h, dn + dr)
+        q_pe = apply_rope(q[..., dn:], positions, cfg.rope_theta)
+    with jax.named_scope("attention.mla.latent"):
+        ckv = jnp.einsum("btd,de->bte", xn, wmat(lp["wkv_a"], xn.dtype))
+        c = rms_norm(ckv[..., :r], lp["kv_a_norm"], cfg.rms_norm_eps)
+        k_pe = apply_rope(ckv[:, :, None, r:], positions, cfg.rope_theta)
+        row = jnp.concatenate([c[:, :, None, :], k_pe], axis=-1)
+    with jax.named_scope("attention.mla.absorb"):
+        w_uk, _ = _mla_up_proj(lp, cfg, xn.dtype)
+        q_lat = jnp.einsum("bthn,rhn->bthr", q[..., :dn], w_uk)
+        q = jnp.concatenate([q_lat, q_pe], axis=-1)
+    return q, row, None
+
+
+def _mla_out(attn: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
+    """The absorbed form's back half: the softmax-weighted cache rows
+    [B, T, H, r + dr], of which the first r columns are o_lat (the
+    values of a one-leaf cache are its latent columns) -> [B, T, H, hd]
+    through each head's W_UV."""
+    with jax.named_scope("attention.mla.out"):
+        _, w_uv = _mla_up_proj(lp, cfg, attn.dtype)
+        return jnp.einsum("bthr,rhv->bthv", attn[..., :cfg.kv_lora_rank],
+                          w_uv)
+
+
 def layer_back(x: jax.Array, attn: jax.Array, lp: Params, cfg: ModelConfig,
                mlp, reduce=None):
     """(x [B, T, D], attn [B, T, ...heads]) -> (next x, the MLP's stats):
@@ -437,8 +666,11 @@ def layer_back(x: jax.Array, attn: jax.Array, lp: Params, cfg: ModelConfig,
     residual, with Gemma's post-norms where the configuration has them.
     `reduce` sums a partial product over the caller's manual "tp" axis; it
     comes BEFORE the post-norm, which is nonlinear and must see the whole
-    output, not a shard's partial sum."""
+    output, not a shard's partial sum. Latent attention hands over the
+    weighted latents; their value projection (`_mla_out`) comes first."""
     b, t = x.shape[:2]
+    if cfg.is_mla:
+        attn = _mla_out(attn.reshape(b, t, cfg.num_heads, -1), lp, cfg)
     out = jnp.einsum("bte,ed->btd", attn.reshape(b, t, -1),
                      wmat(lp["wo"], x.dtype))
     if reduce is not None:
@@ -492,6 +724,9 @@ def decode_forward(
     Returns (last_logits [B, V] f32, k_new [L, B, Hkv, hd],
     v_new [L, B, Hkv, hd], aux) — the caller scatters the new kv rows into
     the cache in ONE in-place update per step (engine._scatter_new_kv).
+    Under latent attention k_new is the one cache row a token has,
+    [L, B, 1, r + dr], and v_new None (so are `window`'s v_base and
+    v_win): the row's leading columns are its values.
     The pool is read in place: a layer gathers the pages its rows name
     from the stacked leaves by (layer, page), or the kernel streams them;
     no [Hkv, P, ps, hd] slice of a layer's pool is ever formed. Rationale:
@@ -527,18 +762,24 @@ def decode_forward(
     moe_aux = cfg.is_moe and cfg.moe_impl == "dispatch"
     token_valid = valid[:, None] if (moe_aux and valid is not None) else None
 
-    def layer_step(x, xs):
-        if layer_wnd is not None:
-            xs, wnd = xs[:-1], xs[-1]
-        else:
-            wnd = None
-        if window is not None:
-            lp, lid, kb, vb, kw, vw = xs
-        else:
-            lp, lid = xs
+    whole = len(layer_groups(cfg)) == 1
+    if window is not None:
+        kb_all, vb_all, kw_all, vw_all, base_lens, win_lens = window
+        win_leaves = (kb_all, vb_all, kw_all, vw_all)
+
+    def layer_step(x, xs, dense, expert_stacks, first):
+        lp, lid, wnd, win = xs
         q, k, v = layer_front(x, lp, cfg, positions[:, None], heads)
-        k_new, v_new = k[:, 0], v[:, 0]                  # [B, Hkv, hd]
+        k_new = k[:, 0]                                  # [B, Hkv, hd]
+        v_new = None if v is None else v[:, 0]
         if window is not None:
+            # one layer group: the window's leaves are the scan's xs. A
+            # second group reads its layers from the whole leaves by
+            # index, which is what a scan does with its xs; slicing them
+            # by group would copy the gathered base every step
+            kb, vb, kw, vw = win if whole else tuple(
+                None if a is None else jax.lax.dynamic_index_in_dim(
+                    a, lid, keepdims=False) for a in win_leaves)
             attn = decode_attention_split(
                 q[:, 0], kb, vb, kw, vw, k_new, v_new, base_lens, win_lens,
                 softcap=cfg.attn_softcap, window=wnd,
@@ -572,31 +813,35 @@ def decode_forward(
                       else (None, None))
             attn = decode_attention_deferred(
                 # dynalint: kv-codec — consumer dequantizes at gather
-                q[:, 0], cache["k"], cache["v"], k_new, v_new,
+                q[:, 0], cache["k"], cache.get("v"), k_new, v_new,
                 page_table, prefix_lens, softcap=cfg.attn_softcap,
                 window=wnd, q_scale=cfg.query_scale,
                 k_scale=scales[0], v_scale=scales[1], layer=lid)
         x, drop_stats = layer_back(
             x, attn, lp, cfg, lambda xn, lp: _mlp_block(
-                xn, lp, cfg, mesh, token_valid, expert_stacks, lid))
-        ys = (k_new, v_new, drop_stats) if moe_aux else (k_new, v_new)
-        return x, ys
+                xn, lp, cfg, mesh, token_valid, expert_stacks,
+                lid if whole else lid - first, dense))
+        return x, (k_new, v_new, drop_stats if moe_aux else None)
 
-    scan_layers, expert_stacks = split_expert_stacks(params["layers"], cfg,
-                                                     mesh)
-    if window is not None:
-        kb_all, vb_all, kw_all, vw_all, base_lens, win_lens = window
-        xs = (scan_layers, layer_ids, kb_all, vb_all, kw_all, vw_all)
-    else:
-        xs = (scan_layers, layer_ids)
-    if layer_wnd is not None:
-        xs = xs + (layer_wnd,)
-    x, ys = jax.lax.scan(layer_step, x, xs)
-    if moe_aux:
-        k_news, v_news, drops = ys
-    else:
-        (k_news, v_news), drops = ys, None
-    aux = _sum_stats(drops)
+    k_news, v_news, drops = [], [], []
+    for name, first, count, dense in layer_groups(cfg):
+        scan_layers, expert_stacks = (params[name], None) if dense \
+            else split_expert_stacks(params[name], cfg, mesh)
+        part = _group_rows(whole, first, count)
+        xs = (scan_layers, part(layer_ids),
+              None if layer_wnd is None else part(layer_wnd),
+              win_leaves if window is not None and whole else None)
+        x, (k_g, v_g, drop_g) = jax.lax.scan(
+            functools.partial(layer_step, dense=dense,
+                              expert_stacks=expert_stacks, first=first),
+            x, xs)
+        k_news.append(k_g)
+        v_news.append(v_g)
+        drops.append(_sum_stats(drop_g))
+    k_news, v_news = (
+        None if g[0] is None else g[0] if whole
+        else jnp.concatenate(g, axis=0) for g in (k_news, v_news))
+    aux = _merge_stats(drops)
     logits = lm_logits(x[:, 0], params["final_norm"], lm_head(params, cfg),
                        cfg)
     if with_aux:
@@ -684,10 +929,9 @@ def forward(
         kv_positions = jnp.where(idx < meta.kv_lens[:, None],
                                  meta.positions, -1)
 
-    def layer_step(carry, layer):
+    def layer_step(carry, layer, dense, expert_stacks, first):
         x, pool = carry            # pool: (k, v[, k_scale, v_scale]) stacks
-        lp, lid = layer[:2]
-        wnd = layer[2] if layer_wnd is not None else None
+        lp, lid, wnd = layer
         q, k, v = layer_front(x, lp, cfg, meta.positions, heads)
         # rows as stored (an int8 pool quantizes them here, at capture);
         # [B, Tq, Hkv, ...] -> this layer's [1, B*Tq, Hkv, ...]
@@ -695,7 +939,8 @@ def forward(
             pool, tuple(r.reshape((1, b * tq) + r.shape[2:])
                         for r in stored_kv_rows(k, v, kvq)),
             write_plan, lid[None])
-        kc, vc = pool[:2]
+        # a one-leaf pool (latent attention) has no values leaf
+        kc, vc = pool[0], (None if v is None else pool[1])
         ksc, vsc = pool[2:] if kvq else (None, None)
         if use_kernel:
             # decode hot path: stream pages HBM->VMEM, no materialized gather
@@ -720,7 +965,8 @@ def forward(
                                    k_scale=ksc, v_scale=vsc, layer=lid)
         x, drop_stats = layer_back(
             x, attn, lp, cfg, lambda xn, lp: _mlp_block(
-                xn, lp, cfg, mesh, token_valid, expert_stacks, lid))
+                xn, lp, cfg, mesh, token_valid, expert_stacks,
+                lid if whole else lid - first, dense))
         return (x, pool), drop_stats
 
     moe_aux = cfg.is_moe and cfg.moe_impl == "dispatch"
@@ -730,20 +976,26 @@ def forward(
     # the stacked leaves ride the scan's carry whole, in the stored
     # representation  # dynalint: kv-codec — values are encoded at the
     # write (stored_kv_rows) and decoded at the gather (gather_values)
-    pool = (cache["k"], cache["v"])
-    if kvq:
-        # dynalint: kv-codec — scale leaves ride the carry next to values
-        pool = pool + (cache["k_scale"], cache["v_scale"])
-    scan_layers, expert_stacks = split_expert_stacks(params["layers"], cfg,
-                                                     mesh)
-    scan_xs = (scan_layers, jnp.arange(cfg.num_layers, dtype=jnp.int32))
-    if layer_wnd is not None:
-        scan_xs = scan_xs + (layer_wnd,)
-    (x, pool), drops = jax.lax.scan(layer_step, (x, pool), scan_xs)
-    aux = _sum_stats(drops)
+    pool_keys = tuple(key for key in cache_keys(kvq) if key in cache)
+    pool = tuple(cache[key] for key in pool_keys)
+    layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    whole = len(layer_groups(cfg)) == 1
+    drops = []
+    for name, first, count, dense in layer_groups(cfg):
+        scan_layers, expert_stacks = (params[name], None) if dense \
+            else split_expert_stacks(params[name], cfg, mesh)
+        part = _group_rows(whole, first, count)
+        scan_xs = (scan_layers, part(layer_ids),
+                   None if layer_wnd is None else part(layer_wnd))
+        (x, pool), drop_g = jax.lax.scan(
+            functools.partial(layer_step, dense=dense,
+                              expert_stacks=expert_stacks, first=first),
+            (x, pool), scan_xs)
+        drops.append(_sum_stats(drop_g))
+    aux = _merge_stats(drops)
 
     logits = lm_logits(x, params["final_norm"], lm_head(params, cfg), cfg)
-    cache_out = dict(zip(cache_keys(kvq), pool))
+    cache_out = dict(zip(pool_keys, pool))
     if with_aux:
         return logits, cache_out, aux
     return logits, cache_out

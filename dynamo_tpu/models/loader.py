@@ -10,6 +10,7 @@ with param_shardings.
 """
 from __future__ import annotations
 
+import dataclasses
 import glob
 import json
 import os
@@ -28,6 +29,7 @@ ARCHES = {
     "Gemma2ForCausalLM": "gemma2",
     "Phi3ForCausalLM": "phi3",
     "OlmoeForCausalLM": "olmoe",
+    "DeepseekV3ForCausalLM": "deepseek_v3",
 }
 
 
@@ -41,6 +43,7 @@ def config_from_hf(hf: Dict[str, Any], name: str = "") -> ModelConfig:
     heads = hf["num_attention_heads"]
     olmoe = family == "olmoe"
     moe = family == "mixtral" or olmoe
+    deepseek = deepseek_v3_fields(hf) if family == "deepseek_v3" else {}
     if hf.get("clip_qkv") is not None:
         # OLMoE's optional clamp of q/k/v to +-clip_qkv is not modeled:
         # ignoring it would serve another function under the model's name
@@ -82,7 +85,7 @@ def config_from_hf(hf: Dict[str, Any], name: str = "") -> ModelConfig:
         # like phi-3-mini-4k (window 2047) / mistral-v0.1 (4096) stay
         # exact instead of silently diverging past the window
         max_len = min(max_len, int(hf["sliding_window"]))
-    return ModelConfig(
+    return dataclasses.replace(ModelConfig(
         name=name or hf.get("model_type", family),
         vocab_size=hf["vocab_size"],
         hidden_size=hf["hidden_size"],
@@ -121,7 +124,52 @@ def config_from_hf(hf: Dict[str, Any], name: str = "") -> ModelConfig:
         norm_topk_prob=bool(hf.get("norm_topk_prob", False)) if olmoe
         else True,
         qk_norm=olmoe,
-    )
+    ), **deepseek)
+
+
+def deepseek_v3_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """The ModelConfig fields of a `DeepseekV3ForCausalLM` config.json
+    (Moonlight-16B-A3B): latent attention without a query LoRA, a leading
+    run of dense layers, then routed experts behind a sigmoid router with
+    a selection bias, plus shared experts. What is not modelled is
+    refused here, not mis-served."""
+    def refuse(key, ok, modelled):
+        if not ok(hf.get(key)):
+            raise ValueError(f"{key}={hf.get(key)!r} is not supported "
+                             f"(only {modelled} is modelled)")
+    refuse("q_lora_rank", lambda v: v is None, "q_lora_rank: null")
+    refuse("n_group", lambda v: v in (None, 1), "one expert group")
+    refuse("topk_group", lambda v: v in (None, 1), "one expert group")
+    refuse("num_nextn_predict_layers", lambda v: not v,
+           "no multi-token-prediction layers")
+    refuse("scoring_func", lambda v: v in ("sigmoid", "softmax"),
+           "sigmoid or softmax scoring")
+    refuse("topk_method", lambda v: v in ("noaux_tc", "greedy"),
+           "noaux_tc or greedy top-k")
+    refuse("moe_layer_freq", lambda v: v in (None, 1),
+           "an expert block in every layer after the dense lead")
+    refuse("attention_bias", lambda v: not v, "no attention bias")
+    if hf.get("num_key_value_heads", hf["num_attention_heads"]) \
+            != hf["num_attention_heads"]:
+        raise ValueError("latent attention has one key/value set a query "
+                         "head: num_key_value_heads must equal "
+                         "num_attention_heads")
+    dn, dr = int(hf["qk_nope_head_dim"]), int(hf["qk_rope_head_dim"])
+    return dict(
+        head_dim=int(hf["v_head_dim"]),
+        kv_lora_rank=int(hf["kv_lora_rank"]),
+        qk_nope_head_dim=dn, qk_rope_head_dim=dr,
+        query_scale=float(dn + dr) ** -0.5,
+        num_experts=int(hf["n_routed_experts"]),
+        intermediate_size=int(hf["moe_intermediate_size"]),
+        dense_intermediate_size=int(hf["intermediate_size"]),
+        first_dense_layers=int(hf.get("first_k_dense_replace", 0)),
+        shared_expert_size=int(hf.get("n_shared_experts") or 0)
+        * int(hf["moe_intermediate_size"]),
+        norm_topk_prob=bool(hf.get("norm_topk_prob", False)),
+        moe_scoring=hf["scoring_func"],
+        moe_router_bias=hf["topk_method"] == "noaux_tc",
+        moe_routed_scale=float(hf.get("routed_scaling_factor", 1.0)))
 
 
 def _read_all_tensors(path: str) -> Dict[str, np.ndarray]:
@@ -154,6 +202,17 @@ def load_params_from_hf(path: str, cfg: ModelConfig,
       .mlp.gate.weight.T                 -> router[i]        (OLMoE)
       .mlp.experts.{e}.{gate,up,down}_proj.weight.T -> w_gate/up/down[i,e]
       .self_attn.{q,k}_norm.weight       -> q_norm/k_norm[i] (OLMoE)
+    DeepseekV3 (latent attention, `_load_deepseek_v3`; the layers before
+    `first_k_dense_replace` go to `dense_layers`, the rest to `layers`):
+      .self_attn.q_proj.weight.T         -> wq[i], rope columns of every
+                                            head de-interleaved
+      .self_attn.kv_a_proj_with_mqa.weight.T -> wkv_a[i], likewise
+      .self_attn.kv_a_layernorm.weight   -> kv_a_norm[i]
+      .self_attn.kv_b_proj.weight.T      -> wkv_b[i]
+      .mlp.gate.weight.T / .mlp.gate.e_score_correction_bias
+                                         -> router[i] / router_bias[i]
+      .mlp.experts.{e}.{gate,up,down}_proj.weight.T -> w_gate/up/down[i,e]
+      .mlp.shared_experts.{gate,up,down}_proj.weight.T -> ws_gate/up/down
       model.norm.weight                  -> final_norm
       lm_head.weight.T                   -> lm_head (absent when tied)
     """
@@ -169,6 +228,9 @@ def load_params_from_hf(path: str, cfg: ModelConfig,
 
     def stack(fn):
         return np.stack([fn(i) for i in range(cfg.num_layers)])
+
+    if cfg.is_mla:
+        return _load_deepseek_v3(raw, cfg, t, w)
 
     fused_qkv = "model.layers.0.self_attn.qkv_proj.weight" in raw  # Phi-3
     qo, ko = cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim
@@ -252,6 +314,78 @@ def load_params_from_hf(path: str, cfg: ModelConfig,
         "layers": layers,
         "final_norm": w("model.norm.weight"),
     }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = t("lm_head.weight")
+    return params
+
+
+def deinterleave_rope(width: int) -> np.ndarray:
+    """Column order that turns interleaved rotary pairs (x0, x1 | x2, x3
+    | ...: the published DeepSeek code's layout, which it undoes on the
+    activations with a view + transpose before rotating halves) into
+    halves (evens | odds). Applied once, to the weights' rope columns."""
+    return np.concatenate([np.arange(0, width, 2), np.arange(1, width, 2)])
+
+
+def _load_deepseek_v3(raw, cfg: ModelConfig, t, w) -> Dict[str, Any]:
+    """`load_params_from_hf` for latent attention and the two layer
+    groups of models/llama.layer_groups."""
+    from dynamo_tpu.models.llama import layer_groups
+    h, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    perm = deinterleave_rope(dr)
+    # wq's columns are H heads of (dn | dr); wkv_a's are (r | dr)
+    q_cols = np.concatenate([hd * (dn + dr) + np.concatenate(
+        [np.arange(dn), dn + perm]) for hd in range(h)])
+    kv_cols = np.concatenate([np.arange(r), r + perm])
+
+    def group(first, count, dense):
+        def stack(fn):
+            return np.stack([fn(i) for i in range(first, first + count)])
+        pre = "model.layers.{}"
+        attn = pre + ".self_attn"
+        layers = {
+            "attn_norm": stack(
+                lambda i: w(pre.format(i) + ".input_layernorm.weight")),
+            "wq": stack(lambda i: t(attn.format(i) + ".q_proj.weight"
+                                    )[:, q_cols]),
+            "wkv_a": stack(lambda i: t(
+                attn.format(i) + ".kv_a_proj_with_mqa.weight")[:, kv_cols]),
+            "kv_a_norm": stack(
+                lambda i: w(attn.format(i) + ".kv_a_layernorm.weight")),
+            "wkv_b": stack(lambda i: t(attn.format(i) + ".kv_b_proj.weight")),
+            "wo": stack(lambda i: t(attn.format(i) + ".o_proj.weight")),
+            "mlp_norm": stack(lambda i: w(
+                pre.format(i) + ".post_attention_layernorm.weight")),
+        }
+        mlp = pre + ".mlp"
+        names = (("gate", "gate_proj"), ("up", "up_proj"),
+                 ("down", "down_proj"))
+        if dense:
+            for ours, theirs in names:
+                layers[f"w_{ours}"] = stack(
+                    lambda i, p=theirs: t(mlp.format(i) + f".{p}.weight"))
+            return layers
+        layers["router"] = stack(lambda i: t(mlp.format(i) + ".gate.weight"))
+        if cfg.moe_router_bias:
+            layers["router_bias"] = stack(lambda i: np.asarray(
+                raw[mlp.format(i) + ".gate.e_score_correction_bias"],
+                np.float32))
+        for ours, theirs in names:
+            layers[f"w_{ours}"] = stack(lambda i, p=theirs: np.stack([
+                t(mlp.format(i) + f".experts.{e}.{p}.weight")
+                for e in range(cfg.num_experts)]))
+            if cfg.shared_expert_size:
+                layers[f"ws_{ours}"] = stack(lambda i, p=theirs: t(
+                    mlp.format(i) + f".shared_experts.{p}.weight"))
+        return layers
+
+    params: Dict[str, Any] = {
+        "embed": w("model.embed_tokens.weight"),
+        "final_norm": w("model.norm.weight"),
+    }
+    for name, first, count, dense in layer_groups(cfg):
+        params[name] = group(first, count, dense)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = t("lm_head.weight")
     return params

@@ -127,6 +127,15 @@ class LedgerStats:
         #                           and tile rounding included
         "moe_experts_hit_total",  # experts with >= 1 assignment, per call
         "moe_layer_calls_total",  # layer calls the above were summed over
+        "moe_window_experts_hit_total",  # the same two over the layer
+        "moe_window_layer_calls_total",  # calls of decode windows alone
+        # attention's KV reads, from every step's plan on the host
+        # (engine._account_attention), every model:
+        "attn_kv_tokens_total",   # context tokens the real rows attend to
+        "attn_kv_slots_total",    # token slots the gather path reads for
+        #                           them: rows x table width x page size
+        "kv_bytes_per_token",     # gauge: bytes a token holds in the
+        #                           cache, all layers (set at engine start)
     )
 
     def __init__(self):

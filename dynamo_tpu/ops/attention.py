@@ -103,7 +103,7 @@ def gather_values(cache: jax.Array, scale: Optional[jax.Array],
 def paged_attention(
     q: jax.Array,            # [B, Tq, H, hd]
     k_cache: jax.Array,      # [Hkv, P, ps, hd]
-    v_cache: jax.Array,      # [Hkv, P, ps, hd]
+    v_cache: Optional[jax.Array],  # [Hkv, P, ps, hd]; None: a one-leaf cache
     page_table: jax.Array,   # [B, Pb] int32
     kv_lens: jax.Array,      # [B] int32 — valid kv length per sequence
     q_positions: jax.Array,  # [B, Tq] int32 — absolute position of each query
@@ -118,7 +118,14 @@ def paged_attention(
 
     With `layer` the caches (and scales) are the stacked leaves and only
     the pages of that layer which `page_table` names are read
-    (gather_pages)."""
+    (gather_pages).
+
+    A one-leaf cache (latent attention, `v_cache` None): here, in
+    decode_attention_split and in decode_attention_deferred the gathered
+    keys serve as values whole, and the caller keeps the columns of the
+    output that are its values (models/llama._mla_out): an eighth more
+    multiply-adds than slicing the operand, and no copy of the gathered
+    rows."""
     b, tq, h, hd = q.shape
     hkv = k_cache.shape[0 if layer is None else 1]
     g = h // hkv
@@ -126,7 +133,8 @@ def paged_attention(
     # int8 cache: dequantized at the gather boundary; downstream math is
     # unchanged
     k = gather_values(k_cache, k_scale, page_table, q.dtype, layer)
-    v = gather_values(v_cache, v_scale, page_table, q.dtype, layer)
+    v = k if v_cache is None else gather_values(
+        v_cache, v_scale, page_table, q.dtype, layer)
     lk = k.shape[2]                        # k, v: [Hkv, B, Lk, hd]
 
     qg = q.reshape(b, tq, hkv, g, hd)
@@ -159,11 +167,11 @@ def paged_attention(
 def decode_attention_split(
     q: jax.Array,            # [B, H, hd] — one query token per sequence
     k_base: jax.Array,       # [Hkv, B, Lb, hd] — read-only pre-window KV
-    v_base: jax.Array,
+    v_base: Optional[jax.Array],   # None (all three): a one-leaf cache
     k_win: jax.Array,        # [Hkv, B, Nw, hd] — in-window KV buffer
-    v_win: jax.Array,
+    v_win: Optional[jax.Array],
     k_new: jax.Array,        # [B, Hkv, hd] — this step's kv (self-term)
-    v_new: jax.Array,
+    v_new: Optional[jax.Array],
     base_lens: jax.Array,    # [B] int32 — valid kv at WINDOW start
     win_lens: jax.Array,     # [B] int32 — tokens written in-window so far
     softcap: float = 0.0,
@@ -192,6 +200,8 @@ def decode_attention_split(
     g = h // hkv
     lb = k_base.shape[2]
     nw = k_win.shape[2]
+    if v_base is None:
+        v_base, v_win, v_new = k_base, k_win, k_new
     sc = _scale(hd, q_scale)
     qg = q.reshape(b, hkv, g, hd)
     sb = _softcap(jnp.einsum(
@@ -242,9 +252,9 @@ def decode_attention_split(
 def decode_attention_deferred(
     q: jax.Array,            # [B, H, hd] — one query token per sequence
     k_cache: jax.Array,      # [Hkv, P, ps, hd]
-    v_cache: jax.Array,
+    v_cache: Optional[jax.Array],  # None (with v_new): a one-leaf cache
     k_new: jax.Array,        # [B, Hkv, hd] — this step's kv (NOT in cache)
-    v_new: jax.Array,
+    v_new: Optional[jax.Array],
     page_table: jax.Array,   # [B, Pb] int32
     prefix_lens: jax.Array,  # [B] int32 — valid kv BEFORE this token
     softcap: float = 0.0,
@@ -270,7 +280,10 @@ def decode_attention_deferred(
     # int8 cache: dequantized at the gather boundary to q.dtype — the
     # dequantized operand is the same width the bf16 path reads
     k = gather_values(k_cache, k_scale, page_table, q.dtype, layer)
-    v = gather_values(v_cache, v_scale, page_table, q.dtype, layer)
+    if v_cache is None:
+        v, v_new = k, k_new
+    else:
+        v = gather_values(v_cache, v_scale, page_table, q.dtype, layer)
     lk = k.shape[2]
 
     sc = _scale(hd, q_scale)
@@ -311,11 +324,15 @@ def decode_attention_deferred(
 KV_WRITE_BLOCK = 32
 
 
-def stored_kv_rows(k_new: jax.Array, v_new: jax.Array, quant: bool) -> tuple:
+def stored_kv_rows(k_new: jax.Array, v_new: Optional[jax.Array],
+                   quant: bool) -> tuple:
     """New K/V rows [..., hd] as the pool stores them, a tuple in
     kv_quant.cache_keys order: (k, v), or on an int8 pool (k, v, k_scale,
     v_scale) — capture-time quantization, each row against its own max
-    inside the jitted step, no dequantized shadow copy."""
+    inside the jitted step, no dequantized shadow copy. A one-leaf cache
+    (`v_new` None; never quantized: the engine refuses that) stores (k,)."""
+    if v_new is None:
+        return (k_new,)
     if not quant:
         return k_new, v_new
     kq, ks = quantize_rows(k_new)

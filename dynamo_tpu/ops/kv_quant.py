@@ -81,15 +81,24 @@ def cache_keys(quant: bool) -> tuple:
     return ("k", "v", "k_scale", "v_scale") if quant else ("k", "v")
 
 
+def leaf_page_bytes(num_layers: int, heads: int, page_size: int, width: int,
+                    dtype_itemsize: int, quant: bool) -> int:
+    """Bytes one page of ONE cache leaf [L, heads, P, ps, width] occupies
+    in HBM (its scales with it when quantized)."""
+    rows = num_layers * heads * page_size
+    if quant:
+        return rows * width + rows * 4          # int8 values + f32 scales
+    return rows * width * dtype_itemsize
+
+
 def page_bytes(num_layers: int, num_kv_heads: int, page_size: int,
                head_dim: int, dtype_itemsize: int, quant: bool) -> int:
     """Bytes one KV page occupies in HBM (K + V + scales when quantized):
-    the /metrics llm_kv_page_bytes gauge and the bench capacity phase
-    both derive from this single definition."""
-    rows = num_layers * num_kv_heads * page_size
-    if quant:
-        return rows * head_dim * 2 + rows * 4 * 2   # int8 k/v + f32 scales
-    return rows * head_dim * dtype_itemsize * 2
+    the bench capacity phase's definition; the /metrics llm_kv_page_bytes
+    gauge sums `leaf_page_bytes` over the leaves the cache really has
+    (`ModelConfig.kv_cache_leaves`)."""
+    return 2 * leaf_page_bytes(num_layers, num_kv_heads, page_size, head_dim,
+                               dtype_itemsize, quant)
 
 
 def quantize_rows(x: jax.Array) -> tuple:

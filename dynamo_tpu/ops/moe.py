@@ -58,22 +58,47 @@ def grouped_matmul_impl() -> str:
     return "gmm" if jax.default_backend() == "tpu" else "ragged_dot"
 
 
-def route_topk(x, router, k: int, renorm: bool):
+def route_topk(x, router, k: int, renorm: bool, scoring: str = "softmax",
+               bias=None, scale: float = 1.0):
     """Router: x [..., D], router [D, E] -> (weights [..., k] float32,
-    expert ids [..., k]). The probabilities are a float32 softmax over
-    ALL experts at the highest matmul precision (a TPU's default rounds
-    float32 operands to bfloat16, and a near-tie between the k-th and
-    the next expert then flips). `renorm` rescales the k kept weights to
-    sum to one (Mixtral; computed as the softmax over the k chosen
-    logits, the same numbers); without it they stay as they are (OLMoE,
-    `norm_topk_prob: false`)."""
+    expert ids [..., k]). The scores are a float32 softmax (or, `scoring`
+    "sigmoid", independent sigmoids) over ALL experts at the highest
+    matmul precision (a TPU's default rounds float32 operands to
+    bfloat16, and a near-tie between the k-th and the next expert then
+    flips). `renorm` rescales the k kept weights to sum to one (Mixtral;
+    on the plain softmax router computed as the softmax over the k
+    chosen logits, the same numbers); without it they stay as they are
+    (OLMoE, `norm_topk_prob: false`). `bias` [E] (DeepSeek-V3's
+    `e_score_correction_bias`) is added to the scores to PICK the k
+    experts and is no part of their weights; `scale` multiplies the
+    weights last. A tie goes to the lower expert id."""
     f32 = jnp.float32
     logits = jnp.einsum("...d,de->...e", x.astype(f32), router.astype(f32),
                         precision=jax.lax.Precision.HIGHEST)
+    if scoring == "softmax" and bias is None and scale == 1.0:
+        if renorm:
+            weights, idx = jax.lax.top_k(logits, k)
+            return jax.nn.softmax(weights, axis=-1), idx
+        return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"unknown router scoring {scoring!r}")
+    scores = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    pick = scores if bias is None else scores + bias.astype(f32)
+    _, idx = jax.lax.top_k(pick, k)
+    weights = jnp.take_along_axis(scores, idx, axis=-1)
     if renorm:
-        weights, idx = jax.lax.top_k(logits, k)
-        return jax.nn.softmax(weights, axis=-1), idx
-    return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + 1e-20)
+    return weights * scale, idx
+
+
+def route(x, lp, cfg):
+    """`route_topk` with a layer's router leaves and the model's router
+    settings (engine/config.py ModelConfig)."""
+    return route_topk(x, lp["router"], cfg.num_experts_per_tok,
+                      cfg.norm_topk_prob, cfg.moe_scoring,
+                      lp.get("router_bias"), cfg.moe_routed_scale)
 
 
 def moe_stats(routed, dropped, expert_rows, experts_hit):
@@ -163,7 +188,7 @@ def moe_dropless_mlp(x: jax.Array, lp, cfg, valid=None, layer=None):
     xf = x.reshape(n, d)
 
     with jax.named_scope("moe.route"):
-        weights, idx = route_topk(xf, lp["router"], k, cfg.norm_topk_prob)
+        weights, idx = route(xf, lp, cfg)
         if valid is not None:
             ok = valid.reshape(n).astype(bool)
             idx = jnp.where(ok[:, None], idx, e)
@@ -226,8 +251,7 @@ def moe_dispatch_mlp(x: jax.Array, lp, cfg, capacity_factor: float = 2.0,
     f32 = jnp.float32
 
     with jax.named_scope("moe.route"):
-        weights, idx = route_topk(x, lp["router"], k,
-                                  cfg.norm_topk_prob)    # [B, T, k]
+        weights, idx = route(x, lp, cfg)                 # [B, T, k]
 
     with jax.named_scope("moe.dispatch"):
         # flatten (token, choice) pairs in token-major order so earlier

@@ -204,11 +204,11 @@ def _skip_qk_norm(xn, lp, cfg):
 _QKV = llama.qkv_proj
 
 
-def _drop_one_in_a_hundred(x, router, k, renorm):
+def _drop_one_in_a_hundred(x, router, k, renorm, *more):
     """The router, with every hundredth (token, choice) pair's weight set
     to zero: that assignment's expert output never reaches the sum, which
     is what a dispatch that drops it does."""
-    weights, idx = _ROUTE(x, router, k, renorm)
+    weights, idx = _ROUTE(x, router, k, renorm, *more)
     flat = jnp.arange(weights.size).reshape(weights.shape)
     return jnp.where(flat % 100 == 37, 0.0, weights), idx
 

@@ -1,4 +1,6 @@
-"""Show, on the chip, that benchmark/checks/reference_logits.py is tight:
+"""Show, on the chip, that a configuration's reference check
+(benchmark/checks/reference_logits.py, or reference_logits_moonlight.py
+where `meta.json` has a `reference_check` key) is tight:
 serve a benchmark configuration exactly as a run does (benchmark/run.py's
 own `Served`, launcher and socket), run ONLY that check's measurement, and
 print its readings, with the served model or the reference mutated.
@@ -10,6 +12,10 @@ print its readings, with the served model or the reference mutated.
     ... --mutation ref-float8    nothing served is changed; the REFERENCE's
                                  weights are rounded to float8 (e4m3), the
                                  nearest precision below bfloat16
+
+`--prompt-seeds a,b,c` reads further draws of the prompts and
+`--then-float8` the float8 reference, all in the one served process (a
+start costs minutes at these widths): one JSON line each.
 
 One JSON line: the readings, the check's limits for the served dtype and
 `passes`. `none` must pass; PERF.md section 6, PR 27 says which mutations
@@ -44,28 +50,27 @@ def mutate(kind: str) -> None:
     from dynamo_tpu.ops import moe
     route = moe.route_topk
     if kind == "renorm":
-        moe.route_topk = lambda x, router, k, renorm: route(x, router, k,
-                                                            True)
+        moe.route_topk = lambda x, router, k, renorm, *more: route(
+            x, router, k, True, *more)
     elif kind.startswith("drop"):
         every = {"drop1pct": 100, "drop5pct": 20}[kind]
 
-        def dropping(x, router, k, renorm):
-            weights, idx = route(x, router, k, renorm)
+        def dropping(x, router, k, renorm, *more):
+            weights, idx = route(x, router, k, renorm, *more)
             flat = jnp.arange(weights.size).reshape(weights.shape)
             return jnp.where(flat % every == every // 3, 0.0, weights), idx
         moe.route_topk = dropping
 
 
-async def probe(args) -> dict:
+async def probe(args) -> None:
     import jax.numpy as jnp
     run = load("bench_run", os.path.join(BENCH, "run.py"))
-    check = load("reference_logits",
-                 os.path.join(BENCH, "checks", "reference_logits.py"))
-    if args.prompt_seed is not None:
-        check.SEED = args.prompt_seed
     config_dir = os.path.join(BENCH, "configs", args.config)
     with open(os.path.join(config_dir, "meta.json")) as f:
         meta = json.load(f)
+    check = load("reference_check", os.path.join(
+        BENCH, "checks", "reference_logits_moonlight.py"
+        if meta.get("reference_check") else "reference_logits.py"))
     if args.rehearsal:
         config_dir = os.path.join(BENCH, "configs",
                                   meta["rehearsal_config"])
@@ -83,17 +88,37 @@ async def probe(args) -> dict:
                        int(model_cfg["vocab_size"]))
     row = await ctx.request(8, 1, 1, {"temperature": 0.0})
     ctx.template_tokens = row["usage"]["prompt_tokens"] - 8
-    cast = None
-    if args.mutation == "ref-float8":
-        def cast(a):
-            return a.astype(jnp.float8_e4m3fn).astype(a.dtype)
-    got = await check.measure(ctx, cast=cast)
-    limits = check.LIMITS[got["dtype"]]
-    got.update(mutation=args.mutation, prompt_seed=check.SEED, limits=limits,
-               passes=got["largest"] < limits[0]
-               and got["median"] < limits[1],
-               device=served.worker.engine.device_info())
-    return got
+    def float8(a):
+        return a.astype(jnp.float8_e4m3fn).astype(a.dtype)
+
+    seeds = [int(x) for x in args.prompt_seeds.split(",")] \
+        if args.prompt_seeds else [args.prompt_seed]
+    readings = [(seed, args.mutation) for seed in seeds]
+    if args.then_float8:
+        readings.append((seeds[0], "ref-float8"))
+    for seed, mutation in readings:
+        if seed is not None:
+            check.SEED = seed
+        cast = float8 if mutation == "ref-float8" else None
+        if hasattr(check, "problems"):
+            # the check's own comparison, and the differences themselves
+            # beside the line (chiprun_out/reference_probe/<mutation>/)
+            diffs = []
+            got = await check.measure(ctx, cast=cast, keep=diffs)
+            with open(os.path.join(
+                    out_dir, f"diffs-{mutation}-{seed}.json"), "w") as f:
+                json.dump(sorted(diffs), f)
+            found = check.problems(got)
+            got.update(problems=found, passes=not found)
+        else:
+            got = await check.measure(ctx, cast=cast)
+            largest, median = check.LIMITS[got["dtype"]]
+            got.update(passes=got["largest"] < largest
+                       and got["median"] < median)
+        got.update(mutation=mutation, prompt_seed=seed,
+                   limits=check.LIMITS[got["dtype"]],
+                   device=served.worker.engine.device_info())
+        print(json.dumps(got), flush=True)
 
 
 def main() -> None:
@@ -105,11 +130,14 @@ def main() -> None:
     p.add_argument("--rehearsal", action="store_true")
     p.add_argument("--prompt-seed", type=int, default=None,
                    help="draw the check's three prompts from another seed")
+    p.add_argument("--prompt-seeds", default="",
+                   help="several draws, one reading each, one process")
+    p.add_argument("--then-float8", action="store_true",
+                   help="after them, the float8 reference on the first draw")
     args = p.parse_args()
     if args.rehearsal:
         os.environ["JAX_PLATFORMS"] = "cpu"
-    got = asyncio.run(probe(args))
-    print(json.dumps(got), flush=True)
+    asyncio.run(probe(args))
     os._exit(0)     # the launcher's tasks have no clean stop from outside
 
 
