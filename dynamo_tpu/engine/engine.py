@@ -359,6 +359,7 @@ class NativeEngine:
                     "attention soft-caps / sliding windows / query-scale "
                     "overrides; serve Gemma-2-class models with sp=1")
             sp_mesh = self.mesh
+        self._sp_mesh = sp_mesh
         # multi-device meshes hand the mesh to forward() so the Pallas decode
         # kernel runs under shard_map over "tp" instead of falling back to
         # the XLA gather path (a 2-3x HBM-traffic amplification)
@@ -896,7 +897,8 @@ class NativeEngine:
         deferred-recorder discipline the ledger's overhead contract and
         the decode hot-path region both require. `stream_kw` carries a
         streamed step's window-pool deltas (stream_hit/late/spilled/
-        stalls) straight through to record_step."""
+        stalls) and an `_engine_step`'s `dense` rows straight through to
+        record_step."""
         if not self.ledger.enabled:
             return
         alloc = self.scheduler.allocator
@@ -969,6 +971,17 @@ class NativeEngine:
         self._account_attention(int(plan.kv_lens.sum()),
                                 plan.page_table.size)
         return key, self._stage_operands(small, own), with_lp
+
+    def _dense_rows(self, plan) -> int:
+        """The token rows the token-wise layers of `plan`'s `_engine_step`
+        run over: the flat width where the program takes its compact
+        branch, by the predicate the program itself traces
+        (llama.step_compaction) on the same `write_idx`; else the grid."""
+        compact = None if self.pp > 1 else llama.step_compaction(
+            plan.write_idx, self._sp_mesh)
+        if compact is not None and compact[1]:
+            return compact[0]
+        return int(plan.tokens.size)
 
     def _account_attention(self, kv_tokens: int, table_pages: int) -> None:
         """`llm_engine_attn_kv_tokens_total` / `_slots_total`, from a
@@ -1049,7 +1062,8 @@ class NativeEngine:
         self._ledger_record(
             "prefill", len(plan.seqs),
             sum(1 for s in plan.seqs if s is not None),
-            sum(plan.n_valid), int(plan.tokens.size))
+            sum(plan.n_valid), int(plan.tokens.size),
+            dense=self._dense_rows(plan))
         return events
 
     def _run_mixed(self, plan: MixedPlan) -> List[StepOutput]:
@@ -1113,7 +1127,8 @@ class NativeEngine:
         self._ledger_record(
             "mixed", len(plan.seqs),
             sum(1 for s in plan.seqs if s is not None),
-            sum(plan.n_valid), int(plan.tokens.size))
+            sum(plan.n_valid), int(plan.tokens.size),
+            dense=self._dense_rows(plan))
         return events
 
     def _run_decode(self, plan: DecodePlan) -> List[StepOutput]:
@@ -2774,15 +2789,17 @@ def _engine_step(cfg: ModelConfig, eos_ids: tuple, sp_mesh, kernel_mesh,
         # invariant at a fixed seed (tests/test_pp.py sampled oracle)
         logits = jax.lax.with_sharding_constraint(
             logits, NamedSharding(pp_mesh, P(None, None, None)))
+        last = logits[jnp.arange(tokens.shape[0]), last_idx]
         aux = {}
     else:
-        logits, cache, aux = llama.forward(
+        # the head at the sampled rows only, the token-wise layers over
+        # the step's real tokens (llama.forward)
+        last, cache, aux = llama.forward(
             params, cfg, tokens, cache, meta,
             input_embeds=mm_embeds if with_mm else None,
             embeds_mask=mm_mask if with_mm else None,
-            sp_mesh=sp_mesh, mesh=kernel_mesh, with_aux=True)
-    b = tokens.shape[0]
-    last = logits[jnp.arange(b), last_idx]          # [B, V] f32
+            sp_mesh=sp_mesh, mesh=kernel_mesh, with_aux=True,
+            last_idx=last_idx)                      # [B, V] f32
     seen = seen_token_mask(hist, cfg.vocab_size) if with_rp else None
     toks, lp, top_ids, top_lps = _sample_logits(
         last, eos_ids, temperature, top_k, top_p, seeds, counters,

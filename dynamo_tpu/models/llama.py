@@ -30,8 +30,9 @@ from jax.sharding import PartitionSpec as P
 
 from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.ops.attention import (
-    _softcap, decode_attention_deferred, decode_attention_split,
-    kv_write_plan, paged_attention, stored_kv_rows, write_kv_rows,
+    _softcap, compact_index, compact_step, decode_attention_deferred,
+    decode_attention_split, kv_write_plan, paged_attention, stored_kv_rows,
+    write_kv_rows,
 )
 from dynamo_tpu.ops.kv_quant import cache_keys
 from dynamo_tpu.ops.kv_quant import validate_mode as _validate_kv_quant
@@ -849,6 +850,16 @@ def decode_forward(
     return logits, k_news, v_news
 
 
+def step_compaction(write_idx, sp_mesh=None) -> Optional[tuple]:
+    """ops/attention.compact_step for a forward(last_idx=...) step, less
+    what keeps the grid whatever its shape: ring-attention prefill
+    shards the chunk axis itself. For the program and, from the same
+    plan as NumPy, for the host's count of the rows it ran over."""
+    if sp_mesh is not None and write_idx.shape[1] > 1:
+        return None
+    return compact_step(write_idx)
+
+
 def forward(
     params: Params,
     cfg: ModelConfig,
@@ -860,10 +871,37 @@ def forward(
     sp_mesh=None,  # Mesh with an "sp" axis: ring-attention prefill
     mesh=None,     # multi-device Mesh: shard_map the decode kernel over "tp"
     with_aux: bool = False,  # also return the summed ops/moe.py moe_stats
+    last_idx: Optional[jax.Array] = None,  # [B] int32: the sampled positions
 ) -> tuple:
     """One paged forward step. Returns (logits [B, Tq, V], updated cache),
     plus an aux dict when with_aux=True (MoE capacity-drop counters summed
     over layers; empty for non-dispatch models).
+
+    With `last_idx` (the engine's step: each row's last real token) the
+    logits are [B, V]: the hidden state is taken at those positions
+    BEFORE the head, and the token-wise layers run over the step's REAL
+    tokens (`meta.write_idx` >= 0) where they are few. A [32, 16] mixed
+    step holds 31 + 16 real tokens in 512 grid cells; its tokens are
+    gathered into `width` flat rows (ops/attention.compact_step: 128
+    there), and norm, projections, RoPE, `wo`, the MLP or experts and
+    the residuals run over [1, width, D]. Attention and the KV-row
+    write need the grid and the pool: q is spread back to [B, Tq, ...],
+    the new rows lead the token rows the write takes them from, and
+    attention's output is gathered again. A step with more real tokens
+    than `width` takes the same halves at the grid's full width: each
+    half of a layer (`layer_front`, `layer_back`) is one `jax.lax.cond`
+    on the step's real-token count, inside the same program; a shape
+    whose grid is no larger than `width` has no `cond`. Padding cells'
+    results were never read: a row with no real token at `last_idx`
+    reads flat row 0.
+    What keeps the grid, each a static fact of the model or the mesh:
+    ring-attention prefill (`sp_mesh`), and the CAPACITY-form expert
+    block (`moe_dispatch_mlp` and its sharded form). Its capacity is
+    per batch row (`ops/moe._capacity`), so one flat group would change
+    which assignments drop and could drop a decode token that never
+    drops today: that block alone still sees the [B, Tq] rows (its
+    input spread back, its output gathered), the layer around it runs
+    flat. The dropless form takes any rows with a `valid` mask.
 
     The pool stays where it is: the stacked leaves ride the layer scan's
     CARRY, never its xs / ys. A layer scatters the rows it produced into
@@ -887,25 +925,26 @@ def forward(
     heads = (cfg.num_heads, cfg.num_kv_heads)
     kvq = bool(_validate_kv_quant(cfg.kv_quant))
 
-    if input_embeds is None:
-        # admission validated the ids  # dynalint: disable-next-line=R1
-        x = jnp.take(params["embed"], tokens, axis=0)
-    elif embeds_mask is not None:
-        # multimodal prefill: image-patch positions take the vision
-        # encoder's projected embeds, text positions take the token embeds
-        # (the token ids at masked positions are hashing salts, not real
-        # vocab ids — see scheduler._admit)
-        x = jnp.where(embeds_mask[..., None],
-                      input_embeds.astype(_dtype(cfg)),
-                      # masked positions carry salts by design; the where
-                      # drops their NaN embed rows
-                      # dynalint: disable-next-line=R1
-                      jnp.take(params["embed"], tokens, axis=0))
-    else:
-        x = input_embeds.astype(_dtype(cfg))
-    # HF Gemma scales whatever enters the first layer (token embeds and
-    # caller-supplied inputs_embeds alike)
-    x = scale_embeds(x, cfg)
+    def embed(tokens, input_embeds, embeds_mask):
+        if input_embeds is None:
+            # admission validated the ids  # dynalint: disable-next-line=R1
+            x = jnp.take(params["embed"], tokens, axis=0)
+        elif embeds_mask is not None:
+            # multimodal prefill: image-patch positions take the vision
+            # encoder's projected embeds, text positions take the token
+            # embeds (the token ids at masked positions are hashing salts,
+            # not real vocab ids — see scheduler._admit)
+            x = jnp.where(embeds_mask[..., None],
+                          input_embeds.astype(_dtype(cfg)),
+                          # masked positions carry salts by design; the
+                          # where drops their NaN embed rows
+                          # dynalint: disable-next-line=R1
+                          jnp.take(params["embed"], tokens, axis=0))
+        else:
+            x = input_embeds.astype(_dtype(cfg))
+        # HF Gemma scales whatever enters the first layer (token embeds and
+        # caller-supplied inputs_embeds alike)
+        return scale_embeds(x, cfg)
 
     use_kernel = tq == 1 and _decode_kernel_mode(cfg) is not None
     use_ring = sp_mesh is not None and tq > 1
@@ -920,23 +959,109 @@ def forward(
     if use_ring:
         from jax.sharding import NamedSharding
         from dynamo_tpu.ops.ring_attention import ring_attention
-        # shard the token axis so layernorm/projections parallelize over sp
-        x = jax.lax.with_sharding_constraint(
-            x, NamedSharding(sp_mesh, P(None, "sp", None)))
         # padding slots carry position == last valid; mark keys invalid by
         # index (valid tokens occupy the first kv_len slots of the chunk)
         idx = jnp.arange(tq, dtype=jnp.int32)[None, :]
         kv_positions = jnp.where(idx < meta.kv_lens[:, None],
                                  meta.positions, -1)
 
-    def layer_step(carry, layer, dense, expert_stacks, first):
+    moe_aux = cfg.is_moe and cfg.moe_impl == "dispatch"
+    # the capacity form counts an expert's slots per batch row
+    grid_mlp = moe_aux and not _use_dropless(cfg, mesh)
+    # real (non-padding) positions: padding slots carry write_idx < 0
+    grid_valid = meta.write_idx >= 0
+    write_plan = kv_write_plan(meta.write_idx)
+    layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    whole = len(layer_groups(cfg)) == 1
+    n = b * tq
+
+    # the token rows are [B, Tq, ...] arrays throughout. A compact step
+    # (`sel`, where `fits`) keeps its real tokens in the first `width` of
+    # the B * Tq rows, and the token-wise halves of a layer run over
+    # those alone: each half is one branch of a `cond`, whose other
+    # branch is the same half over all the rows, the grid. The pool
+    # never enters a `cond`: XLA:TPU copied both leaves in and out of
+    # every layer's write when the layer scan sat inside one (PERF.md
+    # section 6, PR 32)
+    sel = fits = None
+    compact = None if last_idx is None \
+        else step_compaction(meta.write_idx, sp_mesh)
+    if compact is not None:
+        width, fits = compact
+        sel = compact_index(write_plan, width)
+        write_plan = jax.tree.map(functools.partial(jnp.where, fits),
+                                  sel.plan, write_plan)
+
+    def either(fn, *operands):
+        """fn(sel, ...) over a compact step's flat rows, or fn(None, ...)
+        over the grid: whichever the step's real tokens allow."""
+        if sel is None:
+            return fn(None, *operands)
+        return jax.lax.cond(fits, functools.partial(fn, sel),
+                            functools.partial(fn, None), *operands)
+
+    def flat(a):        # token rows [B, Tq, ...] -> the flat [1, W, ...]
+        return a.reshape((1, n) + a.shape[2:])[:, :width]
+
+    def unflat(a):      # [1, W, ...] -> token rows, the rest zero
+        pad = [(0, 0), (0, n - width)] + [(0, 0)] * (a.ndim - 2)
+        return jnp.pad(a, pad).reshape((b, tq) + a.shape[2:])
+
+    def from_grid(a):   # cells [B, Tq, ...] -> their flat rows [1, W, ...]
+        return jnp.take(a.reshape((n,) + a.shape[2:]), sel.cells, axis=0,
+                        mode="clip")[None]
+
+    def to_grid(a):     # flat rows [1, W, ...] -> their cells [B, Tq, ...]
+        return jnp.take(a[0], sel.slot, axis=0, mode="clip"
+                        ).reshape((b, tq) + a.shape[2:])
+
+    flat_positions = None if sel is None else from_grid(meta.positions)
+
+    def embed_rows(sel):
+        if sel is None:
+            return embed(tokens, input_embeds, embeds_mask)
+        return unflat(embed(
+            from_grid(tokens),
+            None if input_embeds is None else from_grid(input_embeds),
+            None if embeds_mask is None else from_grid(embeds_mask)))
+
+    x = either(embed_rows)
+    if use_ring:
+        # shard the token axis so layernorm/projections parallelize over sp
+        x = jax.lax.with_sharding_constraint(
+            x, NamedSharding(sp_mesh, P(None, "sp", None)))
+
+    def layer_step(carry, layer, stack, dense, expert_stacks, first):
         x, pool = carry            # pool: (k, v[, k_scale, v_scale]) stacks
         lp, lid, wnd = layer
-        q, k, v = layer_front(x, lp, cfg, meta.positions, heads)
+        if lp is None:
+            # a `cond` branch is handed its operands as buffers: a layer's
+            # slice of the stack would be copied for it, so the branches
+            # read the stack where it lies
+            def lp_of():
+                return jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(
+                        a, lid if whole else lid - first, keepdims=False),
+                    stack)
+        else:
+            def lp_of():
+                return lp
+
+        def front(sel, x):
+            """-> q, k, v as token rows [B, Tq, heads, hd]: q at its
+            cells, for attention; k and v where their rows are written
+            from."""
+            if sel is None:
+                return layer_front(x, lp_of(), cfg, meta.positions, heads)
+            q, k, v = layer_front(flat(x), lp_of(), cfg, flat_positions,
+                                  heads)
+            return to_grid(q), unflat(k), None if v is None else unflat(v)
+
+        q, k, v = either(front, x)
         # rows as stored (an int8 pool quantizes them here, at capture);
         # [B, Tq, Hkv, ...] -> this layer's [1, B*Tq, Hkv, ...]
         pool = write_kv_rows(
-            pool, tuple(r.reshape((1, b * tq) + r.shape[2:])
+            pool, tuple(r.reshape((1, n) + r.shape[2:])
                         for r in stored_kv_rows(k, v, kvq)),
             write_plan, lid[None])
         # a one-leaf pool (latent attention) has no values leaf
@@ -959,41 +1084,61 @@ def forward(
             attn = ring_attention(q, k, v, meta.positions, kv_positions,
                                   sp_mesh)
         else:
+            # the one op that needs the grid: every query beside its row's
+            # page table, against the pool just written
             attn = paged_attention(q, kc, vc, meta.page_table, meta.kv_lens,
                                    meta.positions, softcap=cfg.attn_softcap,
                                    window=wnd, q_scale=cfg.query_scale,
                                    k_scale=ksc, v_scale=vsc, layer=lid)
-        x, drop_stats = layer_back(
-            x, attn, lp, cfg, lambda xn, lp: _mlp_block(
-                xn, lp, cfg, mesh, token_valid, expert_stacks,
-                lid if whole else lid - first, dense))
+
+        def back(sel, x, attn):
+            block = functools.partial(
+                _mlp_block, cfg=cfg, mesh=mesh, stacks=expert_stacks,
+                lid=lid if whole else lid - first, dense=dense)
+            if sel is None:
+                return layer_back(
+                    x, attn, lp_of(), cfg, lambda xn, lp: block(
+                        xn, lp, token_valid=grid_valid if moe_aux else None))
+
+            def mlp(xn, lp):
+                if grid_mlp and not dense:
+                    out, stats = block(to_grid(xn), lp,
+                                       token_valid=grid_valid)
+                    return from_grid(out), stats
+                return block(xn, lp, token_valid=sel.live[None]
+                             if moe_aux else None)
+            x, stats = layer_back(flat(x), from_grid(attn), lp_of(), cfg,
+                                  mlp)
+            return unflat(x), stats
+
+        x, drop_stats = either(back, x, attn)
         return (x, pool), drop_stats
 
-    moe_aux = cfg.is_moe and cfg.moe_impl == "dispatch"
-    # real (non-padding) positions: padding slots carry write_idx < 0
-    token_valid = meta.write_idx >= 0 if moe_aux else None
-    write_plan = kv_write_plan(meta.write_idx)
     # the stacked leaves ride the scan's carry whole, in the stored
     # representation  # dynalint: kv-codec — values are encoded at the
     # write (stored_kv_rows) and decoded at the gather (gather_values)
     pool_keys = tuple(key for key in cache_keys(kvq) if key in cache)
     pool = tuple(cache[key] for key in pool_keys)
-    layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-    whole = len(layer_groups(cfg)) == 1
     drops = []
     for name, first, count, dense in layer_groups(cfg):
         scan_layers, expert_stacks = (params[name], None) if dense \
             else split_expert_stacks(params[name], cfg, mesh)
         part = _group_rows(whole, first, count)
-        scan_xs = (scan_layers, part(layer_ids),
+        scan_xs = (scan_layers if sel is None else None, part(layer_ids),
                    None if layer_wnd is None else part(layer_wnd))
         (x, pool), drop_g = jax.lax.scan(
-            functools.partial(layer_step, dense=dense,
+            functools.partial(layer_step, stack=scan_layers, dense=dense,
                               expert_stacks=expert_stacks, first=first),
             (x, pool), scan_xs)
         drops.append(_sum_stats(drop_g))
     aux = _merge_stats(drops)
 
+    if last_idx is not None:
+        # the rows that are sampled, before the head
+        at = jnp.arange(b) * tq + last_idx
+        if sel is not None:
+            at = jnp.where(fits, sel.slot[at], at)
+        x = jnp.take(x.reshape(n, -1), at, axis=0, mode="clip")
     logits = lm_logits(x, params["final_norm"], lm_head(params, cfg), cfg)
     cache_out = dict(zip(pool_keys, pool))
     if with_aux:

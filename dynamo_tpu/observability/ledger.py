@@ -39,7 +39,10 @@ Per-step sample schema (one JSONL record per step after `drain()`):
 stream_* columns carry that step's window-pool prefetch deltas);
 `tokens_padded` is the FULL bucket charge of the step ([Bb, Tb] or
 window steps x slots) so padded - useful is the bucket-ladder waste,
-attributable per step kind. `recompiles` counts NEW (program, bucket)
+attributable per step kind: the plan's grid, which attention and the
+scheduler's budget pay. What the token-wise layers ran over is the
+cumulative `tokens_dense` (LedgerStats), not a column of the sample.
+`recompiles` counts NEW (program, bucket)
 keys first seen at this step's dispatch (an XLA compile stall).
 
 docs/OBSERVABILITY.md §5 documents the gauge catalog and the fleet
@@ -72,6 +75,10 @@ class LedgerStats:
         "recompiles",             # new (program, bucket) keys dispatched
         "tokens_useful",          # committed/consumed tokens, all kinds
         "tokens_padded",          # full bucket charge, all kinds
+        "tokens_dense",           # token rows the token-wise layers ran
+        #                           over: a compact step's flat width
+        #                           (models/llama.forward), else the charge
+        "compact_steps_total",    # steps that took the compact branch
         "useful_tokens_prefill",  # per-kind padding-waste split:
         "padded_tokens_prefill",  # prefill chunk rows
         "useful_tokens_decode",   # decode window (steps x slots)
@@ -269,9 +276,12 @@ class StepLedger:
                     disk_used: int, disk_total: int,
                     waiting: int, recompiles: int,
                     stream_hit: int = 0, stream_late: int = 0,
-                    stream_spilled: int = 0, stream_stalls: int = 0) -> None:
+                    stream_spilled: int = 0, stream_stalls: int = 0,
+                    dense: Optional[int] = None) -> None:
         """Record one committed device step. Every argument is an
         already-known host int — the disabled path is this one branch.
+        `dense`: the token rows the step's token-wise layers ran over
+        where that is less than `padded` (a compact step).
         The stream_* kwargs are this step's window-pool deltas (0 on
         non-streamed kinds); they attribute the prefetch leg per step
         in the drained JSONL (tools/decode_profile.py)."""
@@ -307,6 +317,11 @@ class StepLedger:
         s.recompiles += recompiles
         s.tokens_useful += useful
         s.tokens_padded += padded
+        if dense is not None and dense < padded:
+            s.tokens_dense += dense
+            s.compact_steps_total += 1
+        else:
+            s.tokens_dense += padded
         k = kind if kind in ("prefill", "decode", "mixed") else "decode"
         setattr(s, "useful_tokens_" + k,
                 getattr(s, "useful_tokens_" + k) + useful)

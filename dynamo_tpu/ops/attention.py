@@ -351,7 +351,7 @@ def kv_write_plan(write_idx: jax.Array) -> KvWritePlan:
     """Layer-independent half of write_kv_rows: computed once a program,
     outside the layer scan. Steps of at most one block write every row in
     one scatter and need no order."""
-    write_idx = write_idx.reshape(-1)
+    write_idx = jnp.asarray(write_idx).reshape(-1)
     n = write_idx.shape[0]
     if n <= KV_WRITE_BLOCK:
         return KvWritePlan(write_idx, None, None)
@@ -360,6 +360,63 @@ def kv_write_plan(write_idx: jax.Array) -> KvWritePlan:
     order = jnp.pad(order, (0, -n % KV_WRITE_BLOCK))
     return KvWritePlan(write_idx, order,
                        jnp.sum(valid, dtype=jnp.int32))
+
+
+# a step's flat token rows are rounded up to the MXU's 128 rows. Up to a
+# few hundred rows a v5e's projections and MLP are bound by their weights
+# (Mistral-7B: 436 MB a layer against 0.44 GFLOP a row), so a wider flat
+# step costs what a narrow one does, and a narrower width takes fewer
+# steps: at 64 rows a fifth of the [32, 16] steps of a full closed loop
+# held more (three prefill rows), and ran the grid (PERF.md section 6,
+# PR 32)
+COMPACT_TILE = 128
+
+
+def compact_step(write_idx) -> Optional[tuple]:
+    """Whether a `[rows, chunk]` step's token-wise layers run over its
+    real tokens instead of its grid: None where the shape alone says no,
+    else (width, fits). `width` is STATIC, the next multiple of
+    COMPACT_TILE that holds a full row axis of decode tokens beside two
+    whole chunks (128 for [32, 16] and [16, 32], 256 for [8, 64]), and a
+    shape whose grid is no larger gets None. `fits`: the real tokens
+    (`write_idx` >= 0) number at most `width`. THE predicate, for the
+    program (a traced `write_idx`: `fits` picks the branch of
+    models/llama.forward's `cond`s) and for the host's accounting (a
+    NumPy plan: `fits` is a bool) alike."""
+    rows, chunk = write_idx.shape
+    width = -(-(rows + 2 * chunk) // COMPACT_TILE) * COMPACT_TILE
+    if width >= rows * chunk:
+        return None
+    return width, (write_idx >= 0).sum() <= width
+
+
+class CompactIndex(NamedTuple):
+    """A step's real tokens as the first `width` of its rows * chunk token
+    rows, in the grid's row-major order, and the way back
+    (compact_index)."""
+    cells: jax.Array    # [width] grid cell b * chunk + t of flat row j
+    live: jax.Array     # [width] bool: row j holds a real token
+    slot: jax.Array     # [rows * chunk] flat row of a cell; 0 where none
+    plan: KvWritePlan   # the KV writes of the token rows, flat ones first
+
+
+def compact_index(plan: KvWritePlan, width: int) -> CompactIndex:
+    """The compaction index of a step whose grid is larger than `width`
+    (compact_step), from the grid's own write plan: its `order` already
+    lists the real cells first. Computed once a program, outside the
+    layer scan. Rows past the real tokens name cell 0 and write nothing;
+    a step with more real tokens than `width` must not use it."""
+    valid = plan.write_idx >= 0
+    live = jnp.arange(width) < plan.n_valid
+    cells = jnp.where(live, plan.order[:width], 0).astype(jnp.int32)
+    slot = jnp.where(valid, jnp.cumsum(valid, dtype=jnp.int32) - 1, 0)
+    # the real rows lead already: the order is the identity
+    flat = KvWritePlan(
+        jnp.pad(jnp.where(live, plan.write_idx[cells], -1),
+                (0, valid.shape[0] - width), constant_values=-1),
+        jnp.arange(plan.order.shape[0], dtype=plan.order.dtype),
+        plan.n_valid)
+    return CompactIndex(cells, live, slot, flat)
 
 
 def write_kv_rows(

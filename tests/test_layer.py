@@ -82,8 +82,33 @@ def _run_streamed(params):
     return streaming._stream_final(CFG, params, x[-1])
 
 
+def _run_forward_step(params, real_chunk):
+    """forward() as the engine's step calls it (`last_idx`), at [16, 16]:
+    a grid of 256 cells over 128 flat rows. Every row holds `real_chunk`
+    real tokens: 4 a row (64) run the compact branch of each layer half,
+    16 a row (256) the grid branch."""
+    b, tq, pages = 16, 16, 4
+    tokens = np.random.RandomState(0).randint(
+        1, CFG.vocab_size, (b, tq)).astype(np.int32)
+    positions = np.minimum(np.arange(tq, dtype=np.int32), real_chunk - 1)
+    page_table = np.arange(b * pages, dtype=np.int32).reshape(b, pages)
+    write_idx = np.where(np.arange(tq) < real_chunk,
+                         page_table[:, :1] * PAGE + positions, -1)
+    meta = AttnMetadata(
+        jnp.asarray(np.tile(positions, (b, 1))), jnp.asarray(page_table),
+        jnp.full((b,), real_chunk, jnp.int32),
+        jnp.asarray(write_idx.astype(np.int32)))
+    assert bool(llama.step_compaction(write_idx)[1]) == (real_chunk == 4)
+    return llama.forward(
+        params, CFG, jnp.asarray(tokens),
+        llama.init_cache(CFG, b * pages, PAGE), meta,
+        last_idx=jnp.full((b,), real_chunk - 1, jnp.int32))[0]
+
+
 PATHS = {"forward": _run_forward, "decode_forward": _run_decode_forward,
-         "pp_forward": _run_pp_forward, "streamed": _run_streamed}
+         "pp_forward": _run_pp_forward, "streamed": _run_streamed,
+         "forward_step_compact": lambda p: _run_forward_step(p, 4),
+         "forward_step_grid": lambda p: _run_forward_step(p, 16)}
 
 
 def _other_model(name):
@@ -217,3 +242,15 @@ def test_layer_scans_hold_the_reference_layers_projections(name):
         assert not [p for p in _COLLECTIVES if prims[p]], prims
         assert sum(prims[p] for p in _DOTS) == projections + attn + mlp, (
             prims, projections, attn, mlp)
+    # the engine's step (`last_idx`) at a grid larger than its flat rows:
+    # the layer's token-wise halves twice, once a branch of their `cond`s,
+    # and attention ONCE, between them and outside both
+    step = _layer_scan(jax.make_jaxpr(
+        lambda p, c, t, pos, pt, kl, wi, last: llama.forward(
+            p, cfg, t, c, AttnMetadata(pos, pt, kl, wi), with_aux=True,
+            last_idx=last))(
+        params, cache, _i32(16, 16), _i32(16, 16), _i32(16, pb), _i32(16),
+        _i32(16, 16), _i32(16)), nl)
+    assert step["cond"] == 2 and not [p for p in _COLLECTIVES if step[p]]
+    assert sum(step[p] for p in _DOTS) == 2 * (projections + mlp) + paged, (
+        step, projections, paged, mlp)

@@ -209,6 +209,15 @@ def test_dynamic_series_prove_the_planes_are_plumbed(rendered_surfaces):
     # the ledger fold saw real engine steps (ledger is on by default)
     m = re.search(r"^llm_engine_steps_total (\d+)", frontend, re.M)
     assert m and int(m.group(1)) >= 1
+    # PR 32: what the token-wise layers ran over lies between the real
+    # tokens and the plan's grid, and a compact step is a step
+    val = {name: float(re.search(rf"^llm_engine_{name} (\S+)", frontend,
+                                 re.M).group(1))
+           for name in ("tokens_useful", "tokens_dense", "tokens_padded",
+                        "compact_steps_total", "steps_total")}
+    assert 0 < val["tokens_useful"] <= val["tokens_dense"] \
+        <= val["tokens_padded"]
+    assert 0 <= val["compact_steps_total"] <= val["steps_total"]
     # the exporter scraped a live worker into labeled series
     assert 'llm_kv_blocks_active{worker="w0"} 2' in exporter
     assert re.search(r"^llm_workers 1", exporter, re.M)

@@ -375,7 +375,9 @@ def _program_jaxprs(model_cfg):
     from dynamo_tpu.engine import engine as eng
     from dynamo_tpu.models import llama
 
-    rows, chunk, pb, nw = 4, 16, 3, 4
+    # [16, 16]: a grid larger than its 128 flat rows, so the step holds
+    # the compact branch and the grid branch of every layer half
+    rows, chunk, pb, nw = 16, 16, 3, 4
     params = jax.eval_shape(
         lambda: llama.init_params(jax.random.PRNGKey(0), model_cfg))
     cache = jax.eval_shape(
@@ -424,7 +426,11 @@ def test_no_program_slices_or_copies_a_layers_pool(kv_quant):
     XLA copy 134 MB a layer six times over; the carry may hold the whole
     leaf. (2) wherever the page axis appears, in any equation of any
     nested body, the value has a leaf's full shape: no `[Hkv, P, ps, hd]`
-    slice, no flattened `P*ps` view."""
+    slice, no flattened `P*ps` view. (3) PR 32: the step's `cond`s (a
+    layer's token-wise halves over the real tokens or over the grid) sit
+    INSIDE the layer scan and neither takes nor gives a value with the
+    page axis: the pool is written and read between them, outside both
+    branches, so neither branch can copy, slice or re-lay it out."""
     import dataclasses
     jaxprs, leaf_shapes = _program_jaxprs(
         dataclasses.replace(CFG, kv_quant=kv_quant))
@@ -436,8 +442,17 @@ def test_no_program_slices_or_copies_a_layers_pool(kv_quant):
                        for d in shape))
 
     for name, closed in jaxprs.items():
-        scans = 0
+        scans = conds = 0
         for path, eqn in _walk(closed.jaxpr):
+            if eqn.primitive.name == "cond" and "scan" in path:
+                conds += 1
+                assert len(eqn.params["branches"]) == 2
+                moved = [v.aval.str_short() for v in
+                         list(eqn.invars) + list(eqn.outvars)
+                         if has_pages(v)]
+                assert not moved, (
+                    f"{name}: a cond under {'/'.join(path)} takes or gives "
+                    f"the pool: {moved}")
             for var in list(eqn.invars) + list(eqn.outvars):
                 if has_pages(var):
                     assert tuple(var.aval.shape) in leaf_shapes, (
@@ -456,3 +471,317 @@ def test_no_program_slices_or_copies_a_layers_pool(kv_quant):
                 f"{name}: a scan under {'/'.join(path) or 'the program'} "
                 f"moves the pool as xs / ys: {sliced}")
         assert scans, f"{name}: no scan found; the walk is broken"
+        # front and back of the one layer body; the decode window has none
+        assert conds == (2 if name == "engine_step" else 0), (name, conds)
+
+
+# ---- the token-wise layers over a step's real tokens (PERF.md section 6, PR 32) ----
+
+_ROWS, _CHUNK, _WIDTH = 16, 16, 128     # a [16, 16] grid; 128 flat rows
+_TABLE, _PS = 4, 8
+
+_COMPACT_MODELS = {
+    "dense-gqa": CFG,
+    "int8-kv-pool": ModelConfig(dtype="float32", max_model_len=512,
+                                kv_quant="int8"),
+    # 16 experts of 4 a token: the dropless sorted dispatch
+    "dropless-experts": ModelConfig(
+        dtype="float32", max_model_len=512, num_layers=2, num_experts=16,
+        num_experts_per_tok=4, norm_topk_prob=False),
+    # 8 experts of 2: the capacity form, 8 slots an expert and row here,
+    # which a 16-token chunk overflows
+    "capacity-experts": ModelConfig(
+        dtype="float32", max_model_len=512, num_layers=2, num_experts=8,
+        num_experts_per_tok=2),
+    # latent attention, a sigmoid router with shared experts, a dense lead
+    "latent-shared-dense-lead": ModelConfig(
+        name="tiny-moonlight", vocab_size=128, hidden_size=64,
+        intermediate_size=32, dense_intermediate_size=96,
+        first_dense_layers=1, num_layers=3, num_heads=4, num_kv_heads=4,
+        head_dim=16, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, query_scale=24 ** -0.5, rope_theta=50000.0,
+        rms_norm_eps=1e-5, max_model_len=256, num_experts=16,
+        num_experts_per_tok=4, norm_topk_prob=True, moe_scoring="sigmoid",
+        moe_router_bias=True, moe_routed_scale=2.446,
+        shared_expert_size=64, dtype="float32"),
+}
+
+# (decode rows, the prefill rows' chunk lengths) -> real tokens
+_COMPACT_PLANS = {
+    "mixed-23": (7, (16,)),                    # the compact branch
+    "boundary-128": (7, (16,) * 7 + (9,)),     # == the width: still compact
+    "chunks-over-132": (4, (16,) * 8),         # > the width: the grid
+    "pure-chunk-256": (0, (16,) * 16),         # every cell real: the grid
+}
+
+
+def _mixed_plan_arrays(vocab, n_decode, chunks):
+    """A [_ROWS, _CHUNK] plan laid out as Scheduler._build_prefill lays a
+    MixedPlan out: decode rows first, one real token in column 0; then
+    prefill rows; padding columns repeat the row's last position and
+    write nothing; rows past the last are all padding."""
+    rng = np.random.RandomState(n_decode * 31 + len(chunks))
+    tokens = np.zeros((_ROWS, _CHUNK), np.int32)
+    positions = np.zeros((_ROWS, _CHUNK), np.int32)
+    write_idx = np.full((_ROWS, _CHUNK), -1, np.int32)
+    page_table = np.zeros((_ROWS, _TABLE), np.int32)
+    kv_lens = np.zeros((_ROWS,), np.int32)
+    last = np.zeros((_ROWS,), np.int32)
+    for i in range(n_decode + len(chunks)):
+        page_table[i] = np.arange(i * _TABLE, (i + 1) * _TABLE)
+        start, n = (5 + i, 1) if i < n_decode else (3, chunks[i - n_decode])
+        tokens[i, :n] = rng.randint(1, vocab, n)
+        positions[i, :] = start + n - 1
+        positions[i, :n] = np.arange(start, start + n)
+        at = np.arange(start, start + n)
+        write_idx[i, :n] = page_table[i, at // _PS] * _PS + at % _PS
+        kv_lens[i] = start + n
+        last[i] = n - 1
+    return tokens, positions, page_table, kv_lens, write_idx, last
+
+
+@pytest.fixture(scope="module")
+def compact_programs():
+    """name -> (vocabulary, a filled pool, forward() jitted: with
+    `last_idx` the step as the engine calls it, without it the same step
+    over the grid with every position's logits): two compiles a model,
+    every plan has the one shape."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.models import llama
+
+    @functools.lru_cache(maxsize=None)
+    def build(name):
+        cfg = _COMPACT_MODELS[name]
+        params = llama.init_params(jax.random.PRNGKey(0), cfg)
+        key = jax.random.PRNGKey(7)
+        pool = {
+            k: (jax.random.randint(key, v.shape, -127, 128, v.dtype)
+                if jnp.issubdtype(v.dtype, jnp.integer)
+                else jax.random.uniform(key, v.shape, v.dtype, 0.01, 1.0))
+            for k, v in llama.init_cache(cfg, _ROWS * _TABLE, _PS).items()}
+
+        def step(pool, tokens, positions, page_table, kv_lens, write_idx,
+                 last_idx=None):
+            meta = llama.AttnMetadata(positions, page_table, kv_lens,
+                                      write_idx)
+            return llama.forward(params, cfg, tokens, pool, meta,
+                                 with_aux=True, last_idx=last_idx)
+        return cfg.vocab_size, pool, jax.jit(step)
+    return build
+
+
+@pytest.mark.parametrize("plan", sorted(_COMPACT_PLANS))
+@pytest.mark.parametrize("model", sorted(_COMPACT_MODELS))
+def test_compact_step_is_the_grid_step_at_its_real_tokens(
+        compact_programs, model, plan):
+    """forward(last_idx=...), the engine's step, against forward() over
+    the whole grid: the same [B, V] logits at every real row's last
+    token, the same pool (so the same rows at the plan's `write_idx` and
+    nothing else touched) and the same MoE counters, whichever branch
+    the step takes: compact (23 real tokens), compact at the boundary
+    (128 == the width), and the grid's full width for a step over it
+    (132) and for a pure chunk. The host's predicate and the program's are one
+    function of `write_idx` and agree; a row with no real token tells
+    which branch ran, since it reads flat row 0 on the compact branch
+    (here row 0's own logits) and the padding cell on the grid."""
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.ops.attention import compact_step
+
+    vocab, pool, step = compact_programs(model)
+    n_decode, chunks = _COMPACT_PLANS[plan]
+    *arrays, last = _mixed_plan_arrays(vocab, n_decode, chunks)
+    write_idx = arrays[-1]
+    n_real = int((write_idx >= 0).sum())
+    assert n_real == n_decode + sum(chunks)
+
+    width, fits = llama.step_compaction(write_idx)
+    assert width == _WIDTH and bool(fits) == (n_real <= _WIDTH)
+    assert bool(jax.jit(lambda w: compact_step(w)[1])(
+        jnp.asarray(write_idx))) == bool(fits)
+
+    every, pool_grid, aux_grid = step(pool, *arrays)
+    got, pool_flat, aux_flat = step(pool, *arrays, last)
+    rows = n_decode + len(chunks)
+    want = np.asarray(every)[np.arange(_ROWS), last]
+    assert got.shape == (_ROWS, vocab)
+    np.testing.assert_allclose(np.asarray(got)[:rows], want[:rows],
+                               rtol=2e-5, atol=2e-5)
+    assert set(pool_flat) == set(pool_grid)
+    for leaf in pool_grid:
+        np.testing.assert_allclose(
+            np.asarray(pool_flat[leaf], np.float32),
+            np.asarray(pool_grid[leaf], np.float32), rtol=1e-6, atol=1e-6,
+            err_msg=leaf)
+    cfg = _COMPACT_MODELS[model]
+    assert set(aux_flat) == set(aux_grid)
+    assert bool(aux_grid) == cfg.is_moe
+    for name in ("moe_routed", "moe_dropped", "moe_experts_hit",
+                 "moe_layer_calls") if cfg.is_moe else ():
+        assert float(aux_flat[name]) == float(aux_grid[name]), name
+    if cfg.is_moe:
+        assert float(aux_grid["moe_routed"]) == (
+            n_real * cfg.num_experts_per_tok
+            * (cfg.num_layers - cfg.first_dense_layers))
+    if rows < _ROWS and n_decode:
+        took_compact = np.array_equal(np.asarray(got)[rows:],
+                                      np.tile(np.asarray(got)[:1],
+                                              (_ROWS - rows, 1)))
+        assert took_compact == bool(fits)
+
+
+def test_capacity_form_drops_in_the_compact_tests():
+    """The capacity-form case above compares drop counters that are not
+    all zero: its 16-token chunks overflow an expert's 8 slots."""
+    from dynamo_tpu.ops.moe import _capacity
+    cfg = _COMPACT_MODELS["capacity-experts"]
+    assert not cfg.moe_dropless and _COMPACT_MODELS[
+        "dropless-experts"].moe_dropless
+    assert _capacity(_CHUNK, cfg.num_experts_per_tok, cfg.num_experts,
+                     2.0) < _CHUNK
+
+
+@pytest.mark.parametrize("plan", sorted(_COMPACT_PLANS))
+def test_host_counts_the_rows_the_program_runs_over(eng_mixed, plan):
+    """`NativeEngine._dense_rows`, what `llm_engine_tokens_dense` adds a
+    step: the flat width where the program's own predicate takes the
+    compact branch, the grid where it does not; the ledger then counts
+    a compact step exactly where the rows fell under the charge."""
+    import types
+
+    from dynamo_tpu.observability.ledger import StepLedger
+
+    n_decode, chunks = _COMPACT_PLANS[plan]
+    tokens, *_, write_idx, _ = _mixed_plan_arrays(100, n_decode, chunks)
+    fits = n_decode + sum(chunks) <= _WIDTH
+    dense = eng_mixed._dense_rows(
+        types.SimpleNamespace(tokens=tokens, write_idx=write_idx))
+    assert dense == (_WIDTH if fits else _ROWS * _CHUNK)
+    ledger = StepLedger()
+    ledger.stats = type(ledger.stats)()
+    ledger.record_step("mixed", _ROWS, n_decode + len(chunks),
+                       n_decode + sum(chunks), tokens.size, 0, 1, 0, 0, 0,
+                       0, 0, 0, dense=dense)
+    assert ledger.stats.tokens_dense == dense
+    assert ledger.stats.compact_steps_total == int(fits)
+    assert ledger.stats.tokens_padded == _ROWS * _CHUNK
+
+
+def test_served_mixed_steps_take_the_compact_branch():
+    """End to end through the engine: nine streams decode while a
+    two-chunk prompt and then a short one are admitted, so the mixed
+    steps are [16, 16] plans (a grid of 256 over 128 flat rows; the
+    engines of the identity tests above stop at [2, 32], no larger than
+    their flat width, and hold no `cond`). The ledger says every mixed
+    step took the compact branch, and the streams are token-identical,
+    greedy and seeded-sampled, to the alternating scheduler's, whose
+    prefill steps are the only other `_engine_step`s."""
+    first, n = 9, 11
+    eng = make_engine(1, 512, max_slots=12, max_prefill_chunk=16,
+                      prefill_buckets=(16,))
+    prompts = [list(range(10 * i + 3, 10 * i + 9)) for i in range(first)] \
+        + [list(range(100, 128)), list(range(200, 207))]
+
+    def drive(tag, params):
+        got, done, late = {}, set(), list(range(first, n))
+        for i in range(first):
+            got[f"{tag}{i}"] = []
+            eng.add_request(EngineRequest(f"{tag}{i}", prompts[i], params[i]))
+        for _ in range(400):
+            for ev in eng.step():
+                if ev.token is not None:
+                    got[ev.request_id].append(ev.token)
+                if ev.finished:
+                    done.add(ev.request_id)
+            if late and all(len(got[f"{tag}{i}"]) >= 2
+                            for i in range(first)) \
+                    and (late[0] == first or got[f"{tag}{first}"]):
+                i = late.pop(0)
+                got[f"{tag}{i}"] = []
+                eng.add_request(
+                    EngineRequest(f"{tag}{i}", prompts[i], params[i]))
+            if len(done) == n:
+                return [got[f"{tag}{i}"] for i in range(n)]
+        raise AssertionError(sorted(done))
+
+    stats = eng.ledger.stats
+    mixed_rows, dense_rows = [], eng._dense_rows
+
+    def spy(plan):
+        if isinstance(plan, MixedPlan):
+            mixed_rows.append((plan.tokens.shape, dense_rows(plan)))
+        return dense_rows(plan)
+    eng._dense_rows = spy
+    for name, params in (
+            ("greedy", [SamplingParams(max_tokens=20, temperature=0.0,
+                                       ignore_eos=True)] * n),
+            ("sampled", [SamplingParams(max_tokens=20, temperature=0.8,
+                                        top_p=0.9, seed=5 + i,
+                                        ignore_eos=True)
+                         for i in range(n)])):
+        eng.scheduler.mixed_token_budget = 0
+        ref = drive(name + "r", params)
+        eng.scheduler.mixed_token_budget = 512
+        before = stats.snapshot()
+        del mixed_rows[:]
+        mix = drive(name + "m", params)
+        delta = {k: v - before[k] for k, v in stats.snapshot().items()}
+        assert mix == ref, name
+        assert delta["steps_mixed"] >= 2, delta
+        assert mixed_rows and all(
+            shape == (16, 16) and dense == _WIDTH
+            for shape, dense in mixed_rows), mixed_rows
+        assert delta["compact_steps_total"] >= delta["steps_mixed"], delta
+        assert delta["tokens_useful"] <= delta["tokens_dense"] \
+            < delta["tokens_padded"]
+
+
+@pytest.mark.parametrize("plan", ["mixed-23", "chunks-over-132"])
+def test_compact_step_takes_image_embeds_at_their_cells(plan):
+    """Multimodal prefill through both branches: the embed rows and
+    their mask are gathered with the tokens, so an image span inside a
+    chunk lands on the same tokens as over the grid."""
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.models import llama
+
+    n_decode, chunks = _COMPACT_PLANS[plan]
+    tokens, positions, page_table, kv_lens, write_idx, last = \
+        _mixed_plan_arrays(CFG.vocab_size, n_decode, chunks)
+    rng = np.random.RandomState(3)
+    embeds = rng.randn(_ROWS, _CHUNK, CFG.hidden_size).astype(np.float32)
+    mask = np.zeros((_ROWS, _CHUNK), bool)
+    mask[n_decode, 2:9] = True          # a span inside the first chunk
+    params = llama.init_params(jax.random.PRNGKey(0), CFG)
+    pool = llama.init_cache(CFG, _ROWS * _TABLE, _PS)
+    meta = llama.AttnMetadata(*(jnp.asarray(a) for a in (
+        positions, page_table, kv_lens, write_idx)))
+
+    def step(last_idx):
+        return jax.jit(lambda pool: llama.forward(
+            params, CFG, jnp.asarray(tokens), pool, meta,
+            input_embeds=jnp.asarray(embeds), embeds_mask=jnp.asarray(mask),
+            last_idx=last_idx))(pool)
+    every, pool_grid = step(None)
+    got, pool_flat = step(jnp.asarray(last))
+    rows = n_decode + len(chunks)
+    np.testing.assert_allclose(
+        np.asarray(got)[:rows],
+        np.asarray(every)[np.arange(_ROWS), last][:rows],
+        rtol=2e-5, atol=2e-5)
+    text_only = jax.jit(lambda pool: llama.forward(
+        params, CFG, jnp.asarray(tokens), pool, meta,
+        last_idx=jnp.asarray(last)))(pool)[0]
+    assert np.abs(np.asarray(got)[n_decode]
+                  - np.asarray(text_only)[n_decode]).max() > 1e-3
+    for leaf in pool_grid:
+        np.testing.assert_allclose(np.asarray(pool_flat[leaf]),
+                                   np.asarray(pool_grid[leaf]),
+                                   rtol=1e-6, atol=1e-6)

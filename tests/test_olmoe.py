@@ -81,10 +81,24 @@ class Recorder:
         self.entries = []      # (token, position, logits [V])
         fwd, dec = llama.forward, llama.decode_forward
 
-        def forward(params, cfg, tokens, cache, meta, *a, **kw):
-            out = fwd(params, cfg, tokens, cache, meta, *a, **kw)
-            jax.debug.callback(self._keep, tokens, meta.positions,
-                               meta.write_idx >= 0, out[0])
+        def forward(params, cfg, tokens, cache, meta, *a, last_idx=None,
+                    **kw):
+            out = fwd(params, cfg, tokens, cache, meta, *a,
+                      last_idx=last_idx, **kw)
+            every, real = out[0], meta.write_idx >= 0
+            if last_idx is not None:
+                # the engine's step hands the head the sampled rows only
+                # (PR 32): those are recorded as served, and every other
+                # position from the same step over the grid, which the
+                # compaction tests hold it to (tests/test_mixed_steps.py)
+                rows = jnp.arange(tokens.shape[0])
+                jax.debug.callback(
+                    self._keep, tokens[rows, last_idx],
+                    meta.positions[rows, last_idx], real[rows, last_idx],
+                    every)
+                every = fwd(params, cfg, tokens, cache, meta, *a, **kw)[0]
+            jax.debug.callback(self._keep, tokens, meta.positions, real,
+                               every)
             return out
 
         def decode_forward(params, cfg, tokens, cache, page_table,
