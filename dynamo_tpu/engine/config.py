@@ -102,6 +102,42 @@ class ModelConfig:
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
+    # the hybrid's deltas on latent attention (both off = DeepSeek-V3's):
+    # `mla_qk_norm`: an RMSNorm over each head's (nope | rope) query
+    # (leaf `mla_q_norm` [dn + dr]) and over the shared rope key (leaf
+    # `mla_k_norm` [dr]), before RoPE. The nope keys are a linear map of
+    # the latent, which `kv_a_norm` already norms; a norm over each
+    # HEAD's rebuilt nope key would put a per-(token, head) scale on part
+    # of the score, which the absorbed form's one row a token cannot
+    # carry. `mla_gate`: each head's output is multiplied by
+    # sigmoid(x Wgate)_h (leaf `w_attn_gate` [D, H]) before `wo`
+    mla_qk_norm: bool = False
+    mla_gate: bool = False
+    # Linear-attention layers (Kimi Delta Attention: a gated delta rule
+    # with a per-channel decay and a short causal convolution before q, k
+    # and v). `linear_group_size` g > 0 switches them on: layer i keeps
+    # the model's softmax attention where (i + 1) % g == 0 and is a KDA
+    # layer otherwise (`layer_kinds`). A KDA layer has `num_heads` heads
+    # of `linear_head_dim` for keys and values alike, no KV cache, and a
+    # per-sequence STATE instead (`state_leaves`): the [H, dk, dv]
+    # float32 matrix and the last `linear_conv_size - 1` pre-convolution
+    # inputs. `linear_gate_lower_bound` is the decay's floor: g =
+    # bound * sigmoid(exp(A_log) * (x Wf + dt_bias)), in (bound, 0).
+    linear_group_size: int = 0
+    linear_head_dim: int = 0
+    linear_conv_size: int = 4
+    linear_gate_lower_bound: float = -5.0
+    # A chip's share of an expert layer: the router keeps its published
+    # width (`num_experts`), this chip holds `experts_held` of them from
+    # `expert_first` on (0 = all) and computes their part of the result;
+    # the expert leaves are [L, experts_held, ...]. `moe_n_group` /
+    # `moe_topk_group`: DeepSeek-V3's group-limited pick (a group's score
+    # is the sum of its two largest biased scores, the best
+    # `moe_topk_group` groups stay, the k experts come from them)
+    experts_held: int = 0
+    expert_first: int = 0
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
     # decode attention impl: "auto" and "off" are the XLA gather path on
     # every platform (models/llama._decode_kernel_mode says why); "on" is
     # the compiled Pallas kernel and raises at engine construction where it
@@ -138,6 +174,56 @@ class ModelConfig:
     def is_mla(self) -> bool:
         return self.kv_lora_rank > 0
 
+    @property
+    def has_linear_layers(self) -> bool:
+        return self.linear_group_size > 0
+
+    def layer_kinds(self) -> tuple:
+        """Each layer's attention kind, in order: "kda" | "mla" | "mha".
+        A model without linear layers is one kind throughout."""
+        own = "mla" if self.is_mla else "mha"
+        g = self.linear_group_size
+        return tuple(own if not g or (i + 1) % g == 0 else "kda"
+                     for i in range(self.num_layers))
+
+    @property
+    def num_cache_layers(self) -> int:
+        """Layers that hold a paged KV cache (all but the linear ones)."""
+        return sum(kind != "kda" for kind in self.layer_kinds())
+
+    @property
+    def num_state_layers(self) -> int:
+        return self.num_layers - self.num_cache_layers
+
+    @property
+    def local_experts(self) -> int:
+        """Experts whose weights live here: the share, or all."""
+        return self.experts_held or self.num_experts
+
+    def state_leaves(self) -> dict:
+        """THE description of the per-sequence recurrent state, beside
+        `kv_cache_leaves`: leaf -> (shape a slot and layer, dtype), each
+        stored [state layers, slots, ...]. Empty for a model without
+        linear layers. `kda_s` is the delta rule's matrix, float32
+        whatever the model's dtype; `kda_conv` the last conv_size - 1
+        inputs of the q | k | v convolution."""
+        if not self.has_linear_layers:
+            return {}
+        h, d = self.num_heads, self.linear_head_dim
+        return {"kda_s": ((h, d, d), "float32"),
+                "kda_conv": ((self.linear_conv_size - 1, 3 * h * d),
+                             self.dtype)}
+
+    def state_bytes_per_slot(self) -> int:
+        """Bytes one sequence's state holds, all linear layers."""
+        total = 0
+        for shape, dtype in self.state_leaves().values():
+            n = 1
+            for dim in shape:
+                n *= dim
+            total += n * (4 if dtype == "float32" else 2)
+        return total * self.num_state_layers
+
     def kv_cache_leaves(self) -> dict:
         """THE description of the paged cache: value leaf -> (kv heads,
         width), each stored [L, heads, pages, page_size, width]. Two
@@ -154,7 +240,7 @@ class ModelConfig:
     def kv_bytes_per_token(self) -> int:
         """Bytes one token holds in the unquantized cache, all layers."""
         itemsize = 4 if self.dtype == "float32" else 2
-        return self.num_layers * itemsize * sum(
+        return self.num_cache_layers * itemsize * sum(
             h * w for h, w in self.kv_cache_leaves().values())
 
     @property

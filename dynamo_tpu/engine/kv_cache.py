@@ -174,6 +174,37 @@ class PageAllocator:
         return ev
 
 
+class StateSlots:
+    """The second kind of per-sequence device state, beside the pages: a
+    pool of fixed-size recurrent-state slots (a model with
+    linear-attention layers, ModelConfig.state_leaves). A sequence takes
+    ONE slot at its first prefill chunk, before it has a decode slot,
+    and holds it until it finishes, is aborted or is preempted
+    (preemption is by recompute: the state of a prefix exists nowhere
+    once the sequence has moved on). So the pool is max_slots +
+    max_prefill_batch wide: every decode slot's sequence, and the rows
+    of one prefill batch that have pages but no decode slot yet. Nothing
+    is cleared on the host or the device at reuse: a step whose row
+    starts at position 0 starts from zeros (models/llama.kda_mix)."""
+
+    def __init__(self, n: int):
+        self.n = n
+        self._free = list(range(n - 1, -1, -1))
+
+    @property
+    def used(self) -> int:
+        return self.n - len(self._free)
+
+    def take(self) -> int:
+        """A free slot, or -1."""
+        return self._free.pop() if self._free else -1
+
+    def give(self, slot: int) -> None:
+        if slot >= 0:
+            assert slot not in self._free, f"state slot {slot} freed twice"
+            self._free.append(slot)
+
+
 @dataclasses.dataclass
 class SequenceState:
     """Per-request device-cache bookkeeping owned by the scheduler."""
@@ -186,6 +217,9 @@ class SequenceState:
     num_computed: int = 0     # tokens whose KV was computed by US this request
     output: List[int] = dataclasses.field(default_factory=list)
     slot: int = -1            # decode slot id, -1 while prefilling
+    # recurrent-state slot (StateSlots), -1 = none: taken at the first
+    # prefill chunk on an engine whose model has linear-attention layers
+    state_slot: int = -1
     prefill_only: bool = False  # park after prefill instead of decoding
     # bumped on every preempt-and-readmit: lets the engine's device-resident
     # decode-state signature distinguish a re-prefilled request from an

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +35,10 @@ from dynamo_tpu.ops.attention import (
     write_kv_rows,
 )
 from dynamo_tpu.ops.kv_quant import cache_keys
+from dynamo_tpu.ops.linear_attention import BLOCK as KDA_BLOCK
+from dynamo_tpu.ops.linear_attention import (
+    conv_with_tail, kda_chunk, kda_step, l2_normalize,
+)
 from dynamo_tpu.ops.kv_quant import validate_mode as _validate_kv_quant
 from dynamo_tpu.ops.moe import (
     moe_dispatch_mlp, moe_dispatch_mlp_sharded, moe_dropless_mlp, route,
@@ -103,6 +107,9 @@ class AttnMetadata:
     page_table: jax.Array   # [B, Pb] int32
     kv_lens: jax.Array      # [B] int32 (valid kv length AFTER this step)
     write_idx: jax.Array    # [B, Tq] int32 flat slot indices (<0 = padding)
+    # [B] int32: each row's recurrent-state slot (-1 = none); only a model
+    # with linear-attention layers has one (ModelConfig.state_leaves)
+    state_slots: Optional[jax.Array] = None
 
 
 def _dtype(cfg: ModelConfig):
@@ -111,21 +118,65 @@ def _dtype(cfg: ModelConfig):
 
 # -- init ---------------------------------------------------------------------
 
-def layer_groups(cfg: ModelConfig) -> tuple:
-    """The model's layer kinds, split once: ((key in params, first layer,
-    count, dense MLP?), ...). One group, `params["layers"]`, for every
-    model whose layers are all alike. A model with a leading dense MLP
-    before its expert layers (`first_dense_layers`) has two stacks:
-    `params["dense_layers"]` and then `params["layers"]`, the experts,
-    whose leaves keep their own leading axis so the grouped matmul reads
-    them in place (no slice of one stack by kind). The cache's layer
-    index runs over both, in order. init_params, param_shardings,
-    forward(), decode_forward() and models/loader.py all walk this."""
+class LayerRun(NamedTuple):
+    """A run of consecutive layers of one kind: one stack in `params`,
+    one `lax.scan`."""
+    key: str          # the stack's key in params
+    first: int        # the run's first layer, in the model's order
+    count: int
+    dense: bool       # a dense MLP (False: experts)
+    kind: str         # attention kind: "mha" | "mla" | "kda"
+    # the run's first layer among the layers that share its STORE: the
+    # paged cache's layer axis runs over the "mha" / "mla" layers, the
+    # recurrent state's over the "kda" ones (== first where all alike)
+    store_first: int
+
+    def store_index(self, lid):
+        """Layer `lid` of this run -> its index in its store's layer axis
+        (`lid` itself where the two axes agree: no arithmetic traced)."""
+        if self.store_first == self.first:
+            return lid
+        return lid - self.first + self.store_first
+
+
+def layer_runs(cfg: ModelConfig) -> tuple:
+    """The model's layers, split once into runs of like layers. One run,
+    `params["layers"]`, for every model whose layers are all alike. A
+    model with a leading dense MLP before its expert layers
+    (`first_dense_layers`) has two stacks: `params["dense_layers"]` and
+    then `params["layers"]`, the experts, whose leaves keep their own
+    leading axis so the grouped matmul reads them in place (no slice of
+    one stack by kind). A hybrid (`linear_group_size`) is split by
+    attention kind as well, a stack a run of (attention kind, MLP kind):
+    `params["run0"]`, `params["run1"]`, ... in layer order. init_params,
+    param_shardings, forward(), decode_forward() and models/loader.py
+    all walk this."""
     lead = cfg.first_dense_layers if cfg.is_moe else 0
-    if not lead:
-        return (("layers", 0, cfg.num_layers, not cfg.is_moe),)
-    return (("dense_layers", 0, lead, True),
-            ("layers", lead, cfg.num_layers - lead, False))
+    own = "mla" if cfg.is_mla else "mha"
+    if not cfg.has_linear_layers:
+        if not lead:
+            return (LayerRun("layers", 0, cfg.num_layers, not cfg.is_moe,
+                             own, 0),)
+        return (LayerRun("dense_layers", 0, lead, True, own, 0),
+                LayerRun("layers", lead, cfg.num_layers - lead, False, own,
+                         lead))
+    kinds = cfg.layer_kinds()
+    runs, seen = [], {"kda": 0, own: 0}
+    for i, kind in enumerate(kinds):
+        dense = not cfg.is_moe or i < lead
+        if runs and (runs[-1].kind, runs[-1].dense) == (kind, dense):
+            runs[-1] = runs[-1]._replace(count=runs[-1].count + 1)
+        else:
+            runs.append(LayerRun(f"run{len(runs)}", i, 1, dense, kind,
+                                 seen[kind]))
+        seen[kind] += 1
+    return tuple(runs)
+
+
+def layer_groups(cfg: ModelConfig) -> tuple:
+    """`layer_runs` as ((key in params, first layer, count, dense MLP?),
+    ...), for the callers that know one attention kind."""
+    return tuple(run[:4] for run in layer_runs(cfg))
 
 
 def _group_rows(whole: bool, first: int, count: int):
@@ -142,9 +193,11 @@ def _mlp_width(cfg: ModelConfig, dense: bool) -> int:
         else cfg.intermediate_size
 
 
-def _init_layer_stack(keys, cfg: ModelConfig, l: int, dense_mlp: bool
-                      ) -> Params:
-    """One layer group's leaves, stacked over its `l` layers."""
+def _init_layer_stack(keys, cfg: ModelConfig, l: int, dense_mlp: bool,
+                      kind: str = "") -> Params:
+    """One layer group's leaves, stacked over its `l` layers. `kind`: the
+    run's attention kind (`layer_runs`; "" = the model's own)."""
+    kind = kind or ("mla" if cfg.is_mla else "mha")
     dt = _dtype(cfg)
     d, hd = cfg.hidden_size, cfg.head_dim
     h, hkv = cfg.num_heads, cfg.num_kv_heads
@@ -160,12 +213,40 @@ def _init_layer_stack(keys, cfg: ModelConfig, l: int, dense_mlp: bool
                 ).astype(dt)
 
     more = jax.random.split(jax.random.fold_in(keys[0], 31), 6)
+    if kind == "kda":
+        hd = cfg.linear_head_dim
     layers = {
         "attn_norm": jnp.ones((l, d), dt),
         "wo": dense(keys[3], (l, h * hd, d), h * hd),
         "mlp_norm": jnp.ones((l, d), dt),
     }
-    if cfg.is_mla:
+    if kind == "kda":
+        # every leaf that has a neutral value is drawn away from it, so
+        # that a path which skipped one is seen (tests/test_ling.py)
+        kk = jax.random.split(jax.random.fold_in(keys[0], 57), 8)
+        c = h * hd
+        f32 = jnp.float32
+        layers.update({
+            "kda_wqkv": dense(keys[0], (l, d, 3 * c), d),
+            "kda_conv_w": (jax.random.normal(
+                kk[0], (l, cfg.linear_conv_size, 3 * c), f32)
+                * cfg.linear_conv_size ** -0.5).astype(dt),
+            "kda_wf": dense(keys[1], (l, d, c), d),
+            "kda_wg": dense(keys[2], (l, d, c), d),
+            "kda_wb": dense(kk[1], (l, d, h), d),
+            # exp(A_log) in about (0.5, 1.8) and dt_bias around -3: the
+            # log decay g = bound * sigmoid(exp(A_log) (x Wf + dt_bias))
+            # lies mostly in (-1.3, -0.03), a memory of a few to a few
+            # dozen tokens, with a tail of channels near the bound. A
+            # state that forgets within a token makes o_t ~ (k_t . q_t)
+            # v_t, whose head norm flips sign with k . q: a function no
+            # precision can be held to
+            "kda_a_log": 0.3 * jax.random.normal(kk[2], (l, h), f32),
+            "kda_dt_bias": -3.0 + 0.5 * jax.random.normal(kk[3], (l, c),
+                                                          f32),
+            "kda_o_norm": near_one(kk[4], (l, hd)),
+        })
+    elif cfg.is_mla:
         r, dn, dr = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim)
         layers.update({
@@ -175,6 +256,13 @@ def _init_layer_stack(keys, cfg: ModelConfig, l: int, dense_mlp: bool
             # per head k_nope[dn] | v[hd], as the checkpoint has them
             "wkv_b": dense(keys[2], (l, r, h * (dn + hd)), r),
         })
+        if cfg.mla_qk_norm:
+            layers.update({"mla_q_norm": near_one(more[5], (l, dn + dr)),
+                           "mla_k_norm": near_one(
+                               jax.random.fold_in(more[5], 1), (l, dr))})
+        if cfg.mla_gate:
+            layers["w_attn_gate"] = dense(
+                jax.random.fold_in(more[5], 2), (l, d, h), d)
     else:
         layers.update({
             "wq": dense(keys[0], (l, d, h * hd), d),
@@ -198,12 +286,13 @@ def _init_layer_stack(keys, cfg: ModelConfig, l: int, dense_mlp: bool
             "k_norm": near_one(keys[11], (l, hkv * hd)),
         })
     if cfg.is_moe and not dense_mlp:
-        e = cfg.num_experts
+        # the router's width, and the experts held here (a share, or all)
+        e, held = cfg.num_experts, cfg.local_experts
         layers.update({
             "router": dense(keys[4], (l, d, e), d),
-            "w_gate": dense(keys[5], (l, e, d, f), d),
-            "w_up": dense(keys[6], (l, e, d, f), d),
-            "w_down": dense(keys[7], (l, e, f, d), f),
+            "w_gate": dense(keys[5], (l, held, d, f), d),
+            "w_up": dense(keys[6], (l, held, d, f), d),
+            "w_down": dense(keys[7], (l, held, f, d), f),
         })
         if cfg.moe_router_bias:
             # float32 like the checkpoint's; seeded, so that a router
@@ -223,6 +312,10 @@ def _init_layer_stack(keys, cfg: ModelConfig, l: int, dense_mlp: bool
             "w_up": dense(keys[6], (l, d, f), d),
             "w_down": dense(keys[7], (l, f, d), f),
         })
+    if cfg.has_linear_layers:
+        # a hybrid's block norms too are drawn away from one
+        layers["attn_norm"] = near_one(jax.random.fold_in(more[5], 3), (l, d))
+        layers["mlp_norm"] = near_one(jax.random.fold_in(more[5], 4), (l, d))
     return layers
 
 
@@ -240,11 +333,15 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
         "embed": dense(keys[8], (cfg.vocab_size, d), d),
         "final_norm": jnp.ones((d,), dt),
     }
-    for name, _, count, dense_mlp in layer_groups(cfg):
-        params[name] = _init_layer_stack(
-            keys if name == "layers"
-            else jax.random.split(jax.random.fold_in(rng, 1), 12),
-            cfg, count, dense_mlp)
+    for run in layer_runs(cfg):
+        params[run.key] = _init_layer_stack(
+            keys if run.key == "layers"
+            else jax.random.split(jax.random.fold_in(rng, 1 + run.first),
+                                  12),
+            cfg, run.count, run.dense, run.kind)
+    if cfg.has_linear_layers:
+        params["final_norm"] = (1.0 + 0.1 * jax.random.normal(
+            jax.random.fold_in(rng, 77), (d,), jnp.float32)).astype(dt)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = dense(keys[9], (d, cfg.vocab_size), d)
     if cfg.vision is not None:
@@ -267,8 +364,8 @@ def param_shardings(cfg: ModelConfig) -> Params:
         "embed": P(None, None),
         "final_norm": P(None),
     }
-    for name, _, _, dense_mlp in layer_groups(cfg):
-        out[name] = _layer_stack_shardings(cfg, dense_mlp)
+    for run in layer_runs(cfg):
+        out[run.key] = _layer_stack_shardings(cfg, run.dense, run.kind)
     if not cfg.tie_word_embeddings:
         out["lm_head"] = P(None, "tp")
     if cfg.vision is not None:
@@ -277,7 +374,8 @@ def param_shardings(cfg: ModelConfig) -> Params:
     return out
 
 
-def _layer_stack_shardings(cfg: ModelConfig, dense_mlp: bool) -> Params:
+def _layer_stack_shardings(cfg: ModelConfig, dense_mlp: bool,
+                           kind: str = "") -> Params:
     """PartitionSpecs of one layer group: `_init_layer_stack`'s tree."""
     layers = {
         "attn_norm": P(None, None),
@@ -285,12 +383,26 @@ def _layer_stack_shardings(cfg: ModelConfig, dense_mlp: bool) -> Params:
         "wo": P(None, "tp", None),
         "mlp_norm": P(None, None),
     }
-    if cfg.is_mla:
+    if kind == "kda":
+        # no mesh serves a recurrent state yet
+        # (refuse_unserved_recurrent_state): everything replicated
+        del layers["wq"]
+        layers["wo"] = P(None, None, None)
+        layers.update({name: P(None, None, None) for name in (
+            "kda_wqkv", "kda_conv_w", "kda_wf", "kda_wg", "kda_wb")})
+        layers.update({name: P(None, None) for name in (
+            "kda_a_log", "kda_dt_bias", "kda_o_norm")})
+    elif cfg.is_mla:
         # the latent projection and its norm are shared by every head;
         # no mesh serves this model yet (refuse_unserved_latent_cache)
         layers.update({"wkv_a": P(None, None, None),
                        "kv_a_norm": P(None, None),
                        "wkv_b": P(None, None, "tp")})
+        if cfg.mla_qk_norm:
+            layers.update({"mla_q_norm": P(None, None),
+                           "mla_k_norm": P(None, None)})
+        if cfg.mla_gate:
+            layers["w_attn_gate"] = P(None, None, None)
     else:
         layers.update({"wk": P(None, None, "tp"), "wv": P(None, None, "tp")})
     if cfg.post_norms:
@@ -357,7 +469,8 @@ def cache_shardings(cfg: ModelConfig) -> Dict[str, P]:
 def init_cache(cfg: ModelConfig, num_pages: int, page_size: int) -> Dict[str, jax.Array]:
     """The paged pool, a leaf per entry of `cfg.kv_cache_leaves()`:
     [L, heads, pages, page_size, width]."""
-    shapes = {name: (cfg.num_layers, heads, num_pages, page_size, width)
+    shapes = {name: (cfg.num_cache_layers, heads, num_pages, page_size,
+                     width)
               for name, (heads, width) in cfg.kv_cache_leaves().items()}
     if _validate_kv_quant(cfg.kv_quant):
         # int8 pages + per-row f32 scales (ops/kv_quant.py): the scale
@@ -416,6 +529,62 @@ def refuse_unserved_latent_cache(cfg: ModelConfig, engine_cfg=None,
             f"served with it yet: " + "; ".join(why))
 
 
+def init_state(cfg: ModelConfig, slots: int) -> Dict[str, jax.Array]:
+    """The per-sequence recurrent state, a leaf per entry of
+    `cfg.state_leaves()`: [state layers, slots, ...], zeros. It lives in
+    the engine's cache dict beside the paged pool."""
+    return {name: jnp.zeros((cfg.num_state_layers, slots) + shape,
+                            jnp.dtype(dtype))
+            for name, (shape, dtype) in cfg.state_leaves().items()}
+
+
+def refuse_unserved_recurrent_state(cfg: ModelConfig, engine_cfg=None,
+                                    mesh=None, feature: str = "") -> None:
+    """THE place that says what a model with a per-sequence recurrent
+    state (`cfg.has_linear_layers`) cannot be served with yet; every other
+    model passes. Beside `refuse_unserved_latent_cache`, and called where
+    it is. Each of these moves, shares or rolls back a sequence's context
+    as PAGES, and a page has no state to go with it: a page that another
+    sequence wrote holds keys, while the state after those tokens exists
+    nowhere (prefix reuse is switched off in the scheduler instead, and
+    said once in the log); a rejected draft's state update cannot be
+    undone; the tiers, the pool and the transfer frames carry pages."""
+    if not cfg.has_linear_layers:
+        return
+    why = []
+    if feature:
+        why.append(feature)
+    if mesh is not None and mesh.size > 1:
+        why.append(f"a {dict(mesh.shape)} mesh (--tp/--pp/--ep/--sp/--dp: "
+                   f"the state slots and a share's expert exchange are "
+                   f"one device's)")
+    if cfg.kv_quant:
+        why.append(f"kv_quant={cfg.kv_quant!r}")
+    if cfg.quant:
+        why.append(f"quant={cfg.quant!r} (ops/quant.py names the "
+                   f"wq/wk/wv leaves)")
+    if cfg.decode_kernel not in ("auto", "off"):
+        why.append(f"decode_kernel={cfg.decode_kernel!r} (the Pallas "
+                   f"kernel's window carries the cache alone)")
+    if cfg.vision is not None:
+        why.append("a vision tower")
+    if engine_cfg is not None:
+        if engine_cfg.host_pages or engine_cfg.disk_pages \
+                or engine_cfg.stream_pages:
+            why.append("the host / disk KV tiers and streamed decode "
+                       "(--host-pages, --disk-pages, --stream-pages)")
+        if engine_cfg.kv_quant:
+            why.append(f"kv_quant={engine_cfg.kv_quant!r}")
+        if engine_cfg.spec_decode:
+            why.append(f"spec_decode={engine_cfg.spec_decode!r} (a "
+                       f"rejected draft's state update has no rollback)")
+    if why:
+        raise ValueError(
+            f"{cfg.name}: linear-attention layers keep a recurrent state "
+            f"a sequence ({cfg.state_bytes_per_slot()} bytes); not served "
+            f"with it yet: " + "; ".join(why))
+
+
 # -- forward ------------------------------------------------------------------
 
 def rms_norm(x: jax.Array, w: jax.Array, eps: float,
@@ -470,6 +639,9 @@ def _moe_mlp(x: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
     weights, idx = route(x, lp, cfg)                           # [B, T, k]
     one_hot = jax.nn.one_hot(idx, e, dtype=jnp.float32)        # [B, T, k, E]
     combine = jnp.einsum("btk,btke->bte", weights, one_hot)    # [B, T, E]
+    if cfg.experts_held:      # a share: the absent experts add nothing
+        combine = combine[..., cfg.expert_first:
+                          cfg.expert_first + cfg.experts_held]
 
     gate = jnp.einsum("btd,edf->betf", x, wmat(lp["w_gate"], x.dtype))
     up = jnp.einsum("btd,edf->betf", x, wmat(lp["w_up"], x.dtype))
@@ -585,14 +757,18 @@ def _merge_stats(groups: list) -> dict:
 # models/pp._stage, engine/streaming._stream_layer_start / _finish.
 
 def layer_front(x: jax.Array, lp: Params, cfg: ModelConfig,
-                positions: jax.Array, heads: tuple):
+                positions: jax.Array, heads: tuple, kind: str = ""):
     """x [B, T, D] -> q [B, T, H, hd], k, v [B, T, Hkv, hd]: attention
     norm, QKV projection (bias, QK-norm), split into heads, RoPE on q and
     k. `heads` = (H, Hkv) as the caller holds them: a "tp" shard of a
     manual mesh passes its local counts. Under latent attention
     (`_mla_front`) q is the absorbed query, k the token's ONE cache row
     and v None: the attention ops take the whole row as its values and
-    `_mla_out` keeps the latent columns of what they return."""
+    `_mla_out` keeps the latent columns of what they return. A linear
+    layer (`kind` "kda", `_kda_front`) hands back what its state update
+    takes instead: the convolution's inputs, the decay and beta."""
+    if kind == "kda":
+        return _kda_front(x, lp, cfg)
     if cfg.is_mla:
         return _mla_front(x, lp, cfg, positions)
     b, t = x.shape[:2]
@@ -636,11 +812,16 @@ def _mla_front(x: jax.Array, lp: Params, cfg: ModelConfig,
     with jax.named_scope("attention.mla.q"):
         q = jnp.einsum("btd,de->bte", xn, wmat(lp["wq"], xn.dtype)
                        ).reshape(b, t, h, dn + dr)
+        if cfg.mla_qk_norm:
+            q = rms_norm(q, lp["mla_q_norm"], cfg.rms_norm_eps)
         q_pe = apply_rope(q[..., dn:], positions, cfg.rope_theta)
     with jax.named_scope("attention.mla.latent"):
         ckv = jnp.einsum("btd,de->bte", xn, wmat(lp["wkv_a"], xn.dtype))
         c = rms_norm(ckv[..., :r], lp["kv_a_norm"], cfg.rms_norm_eps)
-        k_pe = apply_rope(ckv[:, :, None, r:], positions, cfg.rope_theta)
+        k_pe = ckv[:, :, None, r:]
+        if cfg.mla_qk_norm:
+            k_pe = rms_norm(k_pe, lp["mla_k_norm"], cfg.rms_norm_eps)
+        k_pe = apply_rope(k_pe, positions, cfg.rope_theta)
         row = jnp.concatenate([c[:, :, None, :], k_pe], axis=-1)
     with jax.named_scope("attention.mla.absorb"):
         w_uk, _ = _mla_up_proj(lp, cfg, xn.dtype)
@@ -660,8 +841,172 @@ def _mla_out(attn: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
                           w_uv)
 
 
+def _mla_gate(attn: jax.Array, x: jax.Array, lp: Params,
+              cfg: ModelConfig) -> jax.Array:
+    """The head-wise output gate: attn [B, T, H, hd] times
+    sigmoid(x_normed Wgate)_h (the norm recomputed: one pass over x)."""
+    with jax.named_scope("attention.mla.gate"):
+        xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps,
+                      cfg.norm_plus_one)
+        gate = jax.nn.sigmoid(jnp.einsum(
+            "btd,dh->bth", xn, wmat(lp["w_attn_gate"], xn.dtype)
+        ).astype(jnp.float32))
+        return (attn.astype(jnp.float32) * gate[..., None]
+                ).astype(attn.dtype)
+
+
+def _kda_front(x: jax.Array, lp: Params, cfg: ModelConfig):
+    """A linear layer's token-wise front half: x [B, T, D] -> (pre [B, T,
+    3 H d] the q | k | v projections BEFORE their convolution, g [B, T,
+    H, d] float32 the per-channel log decay in (lower bound, 0), beta
+    [B, T, H] float32). The convolution needs a row's neighbours and the
+    state update its slot: both are the grid's (`kda_mix`)."""
+    b, t = x.shape[:2]
+    h, d = cfg.num_heads, cfg.linear_head_dim
+    f32 = jnp.float32
+    xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
+    pre = jnp.einsum("btd,de->bte", xn, wmat(lp["kda_wqkv"], xn.dtype))
+    with jax.named_scope("linattn.gate"):
+        f = jnp.einsum("btd,de->bte", xn, wmat(lp["kda_wf"], xn.dtype)
+                       ).astype(f32) + lp["kda_dt_bias"].astype(f32)
+        g = cfg.linear_gate_lower_bound * jax.nn.sigmoid(
+            jnp.exp(lp["kda_a_log"].astype(f32))[:, None]
+            * f.reshape(b, t, h, d))
+        beta = jax.nn.sigmoid(jnp.einsum(
+            "btd,dh->bth", xn, wmat(lp["kda_wb"], xn.dtype)).astype(f32))
+    return pre, g, beta
+
+
+def _kda_qkv(y: jax.Array, cfg: ModelConfig):
+    """The convolution's output [..., 3 H d] float32 -> q, k, v [..., H,
+    d]: SiLU, the split, q and k L2-normalised a head, q scaled."""
+    h, d = cfg.num_heads, cfg.linear_head_dim
+    y = jax.nn.silu(y)
+    q, k, v = (a.reshape(a.shape[:-1] + (h, d))
+               for a in jnp.split(y, 3, axis=-1))
+    return l2_normalize(q) * d ** -0.5, l2_normalize(k), v
+
+
+# how many of a step's chunk rows `kda_mix` takes through the chunkwise
+# form at a time: the group's size, not a limit on the rows
+KDA_CHUNK_ROWS = 8
+
+
+def _slot_index(slots: jax.Array, n_slots: int) -> jax.Array:
+    """Row -> state slot, a row without one (-1) sent out of range: its
+    read clips, its write drops."""
+    return jnp.where(slots < 0, n_slots, slots)
+
+
+def kda_mix(state: tuple, lk, slots: jax.Array, lp: Params,
+            cfg: ModelConfig, pre, g, beta, valid, fresh):
+    """A linear layer's state update for a [B, T] step, between
+    `_kda_front` and `_kda_out`: the causal convolution over each row's
+    tokens (continued from the slot's tail), then the chunkwise delta
+    rule from the slot's state. state: (kda_s [Lk, slots, H, d, d],
+    kda_conv [Lk, slots, K - 1, 3 H d]), `lk` this layer's index in them;
+    valid [B, T]: real tokens, a prefix of each row; fresh [B]: the row
+    starts its sequence (position 0), so it starts from zeros whatever
+    the slot held: a reused slot needs no clearing. Each touched slot's
+    state is read once and written once; a row without a slot, and a row
+    of padding, write nothing. A step of more than KDA_CHUNK_ROWS rows is
+    split by what each row holds: a row of one token (a decode row) takes
+    `kda_step`, a row of more takes `kda_chunk`, whatever the number of
+    either. Returns (state, o [B, T, H, d] float32)."""
+    kda_s, kda_conv = state
+    b, tq = valid.shape
+    at = _slot_index(slots, kda_s.shape[1])
+    n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
+    keep = ~fresh
+    with jax.named_scope("linattn.conv"):
+        tail = kda_conv.at[lk, at].get(mode="clip")
+        tail = jnp.where(keep[:, None, None], tail, 0)
+        y, tail = conv_with_tail(pre, tail, lp["kda_conv_w"], n_valid)
+        q, k, v = _kda_qkv(y, cfg)
+    with jax.named_scope("linattn.chunk"):
+        m = valid[:, :, None, None]
+        q, k, v, g = (jnp.where(m, a, 0.0) for a in (q, k, v, g))
+        beta = jnp.where(valid[:, :, None], beta, 0.0)
+        s0 = kda_s.at[lk, at].get(mode="clip")
+        s0 = jnp.where(keep[:, None, None, None], s0, 0.0)
+        if tq > 1 and b > KDA_CHUNK_ROWS:
+            # a mixed step: most rows are decode rows with ONE token and
+            # take the one-token form; the rows with more (chunk rows,
+            # the plan's non-decode rows) take the chunkwise form,
+            # KDA_CHUNK_ROWS of them at a time, for as many groups as
+            # the step holds: one beside a full batch, never a row less
+            # than there are
+            o0, s1 = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                              beta[:, 0], s0)
+            o = jnp.zeros((b, tq) + o0.shape[1:], o0.dtype).at[:, 0].set(o0)
+            groups = -(-b // KDA_CHUNK_ROWS)
+            # longest first; past the last row: read clipped, write dropped
+            order = jnp.pad(jnp.argsort(-n_valid).astype(jnp.int32),
+                            (0, groups * KDA_CHUNK_ROWS - b),
+                            constant_values=b)
+
+            def group(j, carry):
+                o, s1 = carry
+                rows = jax.lax.dynamic_slice_in_dim(
+                    order, j * KDA_CHUNK_ROWS, KDA_CHUNK_ROWS)
+                o_g, s_g = kda_chunk(*(
+                    a.at[rows].get(mode="clip")
+                    for a in (q, k, v, g, beta, s0)))
+                return (o.at[rows].set(o_g, mode="drop"),
+                        s1.at[rows].set(s_g, mode="drop"))
+
+            n_long = jnp.sum(n_valid > 1).astype(jnp.int32)
+            o, s1 = jax.lax.fori_loop(
+                0, -(-n_long // KDA_CHUNK_ROWS), group, (o, s1))
+        else:
+            o, s1 = kda_chunk(q, k, v, g, beta, s0)
+        kda_s = kda_s.at[lk, at].set(s1, mode="drop")
+        kda_conv = kda_conv.at[lk, at].set(tail.astype(kda_conv.dtype),
+                                           mode="drop")
+    return (kda_s, kda_conv), o
+
+
+def kda_decode(state: tuple, lk, slots: jax.Array, lp: Params,
+               cfg: ModelConfig, pre, g, beta, valid):
+    """`kda_mix` for a decode step: one token a row, the one-token form
+    of the update. pre [B, 3 H d], g [B, H, d], beta [B, H]; valid [B]:
+    rows that are live (a finished or padding row writes nothing).
+    Returns (state, o [B, H, d] float32)."""
+    kda_s, kda_conv = state
+    at = _slot_index(jnp.where(valid, slots, -1), kda_s.shape[1])
+    with jax.named_scope("linattn.conv"):
+        tail = kda_conv.at[lk, at].get(mode="clip")
+        xp = jnp.concatenate([tail, pre[:, None].astype(tail.dtype)], 1)
+        w = lp["kda_conv_w"].astype(jnp.float32)
+        y = jnp.sum(w[None] * xp.astype(jnp.float32), axis=1)
+        q, k, v = _kda_qkv(y, cfg)
+    with jax.named_scope("linattn.step"):
+        s0 = kda_s.at[lk, at].get(mode="clip")
+        o, s1 = kda_step(q, k, v, g, beta, s0)
+        kda_s = kda_s.at[lk, at].set(s1, mode="drop")
+        kda_conv = kda_conv.at[lk, at].set(xp[:, 1:], mode="drop")
+    return (kda_s, kda_conv), o
+
+
+def _kda_out(o: jax.Array, x: jax.Array, lp: Params,
+             cfg: ModelConfig) -> jax.Array:
+    """A linear layer's back half before `wo`: o [B, T, H, d] float32 ->
+    RMSNorm over each head, times sigmoid(x_normed Wg) element-wise, in
+    the model's dtype."""
+    with jax.named_scope("linattn.out"):
+        xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps,
+                      cfg.norm_plus_one)
+        gate = jax.nn.sigmoid(jnp.einsum(
+            "btd,de->bte", xn, wmat(lp["kda_wg"], xn.dtype)
+        ).astype(jnp.float32)).reshape(o.shape)
+        o = rms_norm(o, lp["kda_o_norm"].astype(jnp.float32),
+                     cfg.rms_norm_eps)
+        return (o * gate).astype(x.dtype)
+
+
 def layer_back(x: jax.Array, attn: jax.Array, lp: Params, cfg: ModelConfig,
-               mlp, reduce=None):
+               mlp, reduce=None, kind: str = ""):
+
     """(x [B, T, D], attn [B, T, ...heads]) -> (next x, the MLP's stats):
     output projection, residual, MLP norm, `mlp(xn, lp)` -> (out, stats),
     residual, with Gemma's post-norms where the configuration has them.
@@ -670,8 +1015,12 @@ def layer_back(x: jax.Array, attn: jax.Array, lp: Params, cfg: ModelConfig,
     output, not a shard's partial sum. Latent attention hands over the
     weighted latents; their value projection (`_mla_out`) comes first."""
     b, t = x.shape[:2]
-    if cfg.is_mla:
+    if kind == "kda":
+        attn = _kda_out(attn, x, lp, cfg)
+    elif cfg.is_mla:
         attn = _mla_out(attn.reshape(b, t, cfg.num_heads, -1), lp, cfg)
+        if cfg.mla_gate:
+            attn = _mla_gate(attn, x, lp, cfg)
     out = jnp.einsum("bte,ed->btd", attn.reshape(b, t, -1),
                      wmat(lp["wo"], x.dtype))
     if reduce is not None:
@@ -719,8 +1068,15 @@ def decode_forward(
     mesh=None,
     with_aux: bool = False,
     window: Optional[tuple] = None,  # split-KV window fast path, see below
+    state: Optional[tuple] = None,   # ((kda_s, kda_conv), slots [B])
 ) -> tuple:
     """Deferred-write decode step: the KV cache is READ-ONLY.
+
+    `state` (a model with linear-attention layers): the recurrent state's
+    leaves and each row's slot in them. Unlike the cache it is WRITTEN
+    here, a layer at a time (`kda_decode`), and handed back as the last
+    element of the result: the caller carries it to the next step. The
+    new-row stacks k_new / v_new then cover the layers that HAVE a cache.
 
     Returns (last_logits [B, V] f32, k_new [L, B, Hkv, hd],
     v_new [L, B, Hkv, hd], aux) — the caller scatters the new kv rows into
@@ -763,13 +1119,32 @@ def decode_forward(
     moe_aux = cfg.is_moe and cfg.moe_impl == "dispatch"
     token_valid = valid[:, None] if (moe_aux and valid is not None) else None
 
-    whole = len(layer_groups(cfg)) == 1
+    runs = layer_runs(cfg)
+    whole = len(runs) == 1
     if window is not None:
         kb_all, vb_all, kw_all, vw_all, base_lens, win_lens = window
         win_leaves = (kb_all, vb_all, kw_all, vw_all)
+    row_valid = valid if valid is not None else jnp.ones(tokens.shape, bool)
 
-    def layer_step(x, xs, dense, expert_stacks, first):
+    def kda_step_layer(carry, xs, run, expert_stacks):
+        """A linear layer: no cache row, a state update instead."""
+        x, st = carry
+        lp, lid = xs
+        pre, g, beta = layer_front(x, lp, cfg, None, heads, "kda")
+        st, o = kda_decode(st, run.store_index(lid), state[1],
+                           lp, cfg, pre[:, 0], g[:, 0], beta[:, 0],
+                           row_valid)
+        x, drop_stats = layer_back(
+            x, o[:, None], lp, cfg, lambda xn, lp: _mlp_block(
+                xn, lp, cfg, mesh, token_valid, expert_stacks,
+                lid - run.first, run.dense), kind="kda")
+        return (x, st), drop_stats if moe_aux else None
+
+    def layer_step(x, xs, run, expert_stacks):
         lp, lid, wnd, win = xs
+        first, dense = run.first, run.dense
+        # this layer's index in the cache's (and the window's) layer axis
+        cl = run.store_index(lid)
         q, k, v = layer_front(x, lp, cfg, positions[:, None], heads)
         k_new = k[:, 0]                                  # [B, Hkv, hd]
         v_new = None if v is None else v[:, 0]
@@ -780,7 +1155,7 @@ def decode_forward(
             # by group would copy the gathered base every step
             kb, vb, kw, vw = win if whole else tuple(
                 None if a is None else jax.lax.dynamic_index_in_dim(
-                    a, lid, keepdims=False) for a in win_leaves)
+                    a, cl, keepdims=False) for a in win_leaves)
             attn = decode_attention_split(
                 q[:, 0], kb, vb, kw, vw, k_new, v_new, base_lens, win_lens,
                 softcap=cfg.attn_softcap, window=wnd,
@@ -801,7 +1176,7 @@ def decode_forward(
             else:
                 acc, m, l = decode_paged_attention_prefix(
                     # dynalint: kv-codec — kernels dequantize in-read
-                    q[:, 0], cache["k"], cache["v"], lid[None], page_table,
+                    q[:, 0], cache["k"], cache["v"], cl[None], page_table,
                     prefix_lens, interpret=interp,
                     k_scale=scales[0], v_scale=scales[1])
             attn = combine_self_attention(q[:, 0], k_new, v_new, acc, m, l)
@@ -817,7 +1192,7 @@ def decode_forward(
                 q[:, 0], cache["k"], cache.get("v"), k_new, v_new,
                 page_table, prefix_lens, softcap=cfg.attn_softcap,
                 window=wnd, q_scale=cfg.query_scale,
-                k_scale=scales[0], v_scale=scales[1], layer=lid)
+                k_scale=scales[0], v_scale=scales[1], layer=cl)
         x, drop_stats = layer_back(
             x, attn, lp, cfg, lambda xn, lp: _mlp_block(
                 xn, lp, cfg, mesh, token_valid, expert_stacks,
@@ -825,29 +1200,37 @@ def decode_forward(
         return x, (k_new, v_new, drop_stats if moe_aux else None)
 
     k_news, v_news, drops = [], [], []
-    for name, first, count, dense in layer_groups(cfg):
+    st = None if state is None else state[0]
+    for run in runs:
+        name, first, count, dense = run[:4]
         scan_layers, expert_stacks = (params[name], None) if dense \
             else split_expert_stacks(params[name], cfg, mesh)
         part = _group_rows(whole, first, count)
+        if run.kind == "kda":
+            (x, st), drop_g = jax.lax.scan(
+                functools.partial(kda_step_layer, run=run,
+                                  expert_stacks=expert_stacks),
+                (x, st), (scan_layers, part(layer_ids)))
+            drops.append(_sum_stats(drop_g))
+            continue
         xs = (scan_layers, part(layer_ids),
               None if layer_wnd is None else part(layer_wnd),
               win_leaves if window is not None and whole else None)
         x, (k_g, v_g, drop_g) = jax.lax.scan(
-            functools.partial(layer_step, dense=dense,
-                              expert_stacks=expert_stacks, first=first),
+            functools.partial(layer_step, run=run,
+                              expert_stacks=expert_stacks),
             x, xs)
         k_news.append(k_g)
         v_news.append(v_g)
         drops.append(_sum_stats(drop_g))
     k_news, v_news = (
-        None if g[0] is None else g[0] if whole
+        None if g[0] is None else g[0] if len(g) == 1
         else jnp.concatenate(g, axis=0) for g in (k_news, v_news))
     aux = _merge_stats(drops)
     logits = lm_logits(x[:, 0], params["final_norm"], lm_head(params, cfg),
                        cfg)
-    if with_aux:
-        return logits, k_news, v_news, aux
-    return logits, k_news, v_news
+    out = (logits, k_news, v_news) + ((aux,) if with_aux else ())
+    return out if state is None else out + (st,)
 
 
 def step_compaction(write_idx, sp_mesh=None) -> Optional[tuple]:
@@ -972,7 +1355,8 @@ def forward(
     grid_valid = meta.write_idx >= 0
     write_plan = kv_write_plan(meta.write_idx)
     layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-    whole = len(layer_groups(cfg)) == 1
+    runs = layer_runs(cfg)
+    whole = len(runs) == 1
     n = b * tq
 
     # the token rows are [B, Tq, ...] arrays throughout. A compact step
@@ -1031,9 +1415,14 @@ def forward(
         x = jax.lax.with_sharding_constraint(
             x, NamedSharding(sp_mesh, P(None, "sp", None)))
 
-    def layer_step(carry, layer, stack, dense, expert_stacks, first):
-        x, pool = carry            # pool: (k, v[, k_scale, v_scale]) stacks
+    def layer_step(carry, layer, stack, run, expert_stacks):
+        # pool: (k, v[, k_scale, v_scale]) stacks; state: the recurrent
+        # state's leaves, () for a model without linear layers
+        x, pool, state = carry
         lp, lid, wnd = layer
+        first, dense, kind = run.first, run.dense, run.kind
+        # this layer's index among the layers that share its store
+        sl = run.store_index(lid)
         if lp is None:
             # a `cond` branch is handed its operands as buffers: a layer's
             # slice of the stack would be copied for it, so the branches
@@ -1050,20 +1439,53 @@ def forward(
         def front(sel, x):
             """-> q, k, v as token rows [B, Tq, heads, hd]: q at its
             cells, for attention; k and v where their rows are written
-            from."""
+            from. A linear layer: what its state update takes, all three
+            at their cells."""
             if sel is None:
-                return layer_front(x, lp_of(), cfg, meta.positions, heads)
+                return layer_front(x, lp_of(), cfg, meta.positions, heads,
+                                   kind)
             q, k, v = layer_front(flat(x), lp_of(), cfg, flat_positions,
-                                  heads)
+                                  heads, kind)
+            if kind == "kda":
+                return to_grid(q), to_grid(k), to_grid(v)
             return to_grid(q), unflat(k), None if v is None else unflat(v)
 
+        def back(sel, x, attn):
+            block = functools.partial(
+                _mlp_block, cfg=cfg, mesh=mesh, stacks=expert_stacks,
+                lid=lid if whole else lid - first, dense=dense)
+            if sel is None:
+                return layer_back(
+                    x, attn, lp_of(), cfg, lambda xn, lp: block(
+                        xn, lp, token_valid=grid_valid if moe_aux else None),
+                    kind=kind)
+
+            def mlp(xn, lp):
+                if grid_mlp and not dense:
+                    out, stats = block(to_grid(xn), lp,
+                                       token_valid=grid_valid)
+                    return from_grid(out), stats
+                return block(xn, lp, token_valid=sel.live[None]
+                             if moe_aux else None)
+            x, stats = layer_back(flat(x), from_grid(attn), lp_of(), cfg,
+                                  mlp, kind=kind)
+            return unflat(x), stats
+
         q, k, v = either(front, x)
+        if kind == "kda":
+            # the grid's part of a linear layer: each row's convolution
+            # and state update, in its slot (no cache row, no attention)
+            state, attn = kda_mix(
+                state, sl, meta.state_slots, lp_of(), cfg, q, k, v,
+                grid_valid, meta.positions[:, 0] == 0)
+            x, drop_stats = either(back, x, attn)
+            return (x, pool, state), drop_stats
         # rows as stored (an int8 pool quantizes them here, at capture);
         # [B, Tq, Hkv, ...] -> this layer's [1, B*Tq, Hkv, ...]
         pool = write_kv_rows(
             pool, tuple(r.reshape((1, n) + r.shape[2:])
                         for r in stored_kv_rows(k, v, kvq)),
-            write_plan, lid[None])
+            write_plan, sl[None])
         # a one-leaf pool (latent attention) has no values leaf
         kc, vc = pool[0], (None if v is None else pool[1])
         ksc, vsc = pool[2:] if kvq else (None, None)
@@ -1074,12 +1496,12 @@ def forward(
                 attn = decode_paged_attention_sharded(
                     q[:, 0], kc, vc, meta.page_table, meta.kv_lens, mesh,
                     interpret=interp, k_scale=ksc, v_scale=vsc,
-                    layer=lid[None])[:, None]
+                    layer=sl[None])[:, None]
             else:
                 attn = decode_paged_attention(
                     q[:, 0], kc, vc, meta.page_table, meta.kv_lens,
                     interpret=interp, k_scale=ksc, v_scale=vsc,
-                    layer=lid[None])[:, None]
+                    layer=sl[None])[:, None]
         elif use_ring:
             attn = ring_attention(q, k, v, meta.positions, kv_positions,
                                   sp_mesh)
@@ -1089,47 +1511,30 @@ def forward(
             attn = paged_attention(q, kc, vc, meta.page_table, meta.kv_lens,
                                    meta.positions, softcap=cfg.attn_softcap,
                                    window=wnd, q_scale=cfg.query_scale,
-                                   k_scale=ksc, v_scale=vsc, layer=lid)
-
-        def back(sel, x, attn):
-            block = functools.partial(
-                _mlp_block, cfg=cfg, mesh=mesh, stacks=expert_stacks,
-                lid=lid if whole else lid - first, dense=dense)
-            if sel is None:
-                return layer_back(
-                    x, attn, lp_of(), cfg, lambda xn, lp: block(
-                        xn, lp, token_valid=grid_valid if moe_aux else None))
-
-            def mlp(xn, lp):
-                if grid_mlp and not dense:
-                    out, stats = block(to_grid(xn), lp,
-                                       token_valid=grid_valid)
-                    return from_grid(out), stats
-                return block(xn, lp, token_valid=sel.live[None]
-                             if moe_aux else None)
-            x, stats = layer_back(flat(x), from_grid(attn), lp_of(), cfg,
-                                  mlp)
-            return unflat(x), stats
+                                   k_scale=ksc, v_scale=vsc, layer=sl)
 
         x, drop_stats = either(back, x, attn)
-        return (x, pool), drop_stats
+        return (x, pool, state), drop_stats
 
     # the stacked leaves ride the scan's carry whole, in the stored
     # representation  # dynalint: kv-codec — values are encoded at the
     # write (stored_kv_rows) and decoded at the gather (gather_values)
     pool_keys = tuple(key for key in cache_keys(kvq) if key in cache)
     pool = tuple(cache[key] for key in pool_keys)
+    state_keys = tuple(cfg.state_leaves())
+    state = tuple(cache[key] for key in state_keys)
     drops = []
-    for name, first, count, dense in layer_groups(cfg):
+    for run in runs:
+        name, first, count, dense = run[:4]
         scan_layers, expert_stacks = (params[name], None) if dense \
             else split_expert_stacks(params[name], cfg, mesh)
         part = _group_rows(whole, first, count)
         scan_xs = (scan_layers if sel is None else None, part(layer_ids),
                    None if layer_wnd is None else part(layer_wnd))
-        (x, pool), drop_g = jax.lax.scan(
-            functools.partial(layer_step, stack=scan_layers, dense=dense,
-                              expert_stacks=expert_stacks, first=first),
-            (x, pool), scan_xs)
+        (x, pool, state), drop_g = jax.lax.scan(
+            functools.partial(layer_step, stack=scan_layers, run=run,
+                              expert_stacks=expert_stacks),
+            (x, pool, state), scan_xs)
         drops.append(_sum_stats(drop_g))
     aux = _merge_stats(drops)
 
@@ -1140,7 +1545,7 @@ def forward(
             at = jnp.where(fits, sel.slot[at], at)
         x = jnp.take(x.reshape(n, -1), at, axis=0, mode="clip")
     logits = lm_logits(x, params["final_norm"], lm_head(params, cfg), cfg)
-    cache_out = dict(zip(pool_keys, pool))
+    cache_out = dict(zip(pool_keys + state_keys, pool + state))
     if with_aux:
         return logits, cache_out, aux
     return logits, cache_out

@@ -30,6 +30,7 @@ ARCHES = {
     "Phi3ForCausalLM": "phi3",
     "OlmoeForCausalLM": "olmoe",
     "DeepseekV3ForCausalLM": "deepseek_v3",
+    "BailingHybridForCausalLM": "bailing_hybrid",
 }
 
 
@@ -43,7 +44,10 @@ def config_from_hf(hf: Dict[str, Any], name: str = "") -> ModelConfig:
     heads = hf["num_attention_heads"]
     olmoe = family == "olmoe"
     moe = family == "mixtral" or olmoe
-    deepseek = deepseek_v3_fields(hf) if family == "deepseek_v3" else {}
+    # what a family sets beyond the shared fields below
+    own = {"deepseek_v3": deepseek_v3_fields,
+           "bailing_hybrid": bailing_hybrid_fields}.get(
+               family, lambda hf: {})(hf)
     if hf.get("clip_qkv") is not None:
         # OLMoE's optional clamp of q/k/v to +-clip_qkv is not modeled:
         # ignoring it would serve another function under the model's name
@@ -124,7 +128,7 @@ def config_from_hf(hf: Dict[str, Any], name: str = "") -> ModelConfig:
         norm_topk_prob=bool(hf.get("norm_topk_prob", False)) if olmoe
         else True,
         qk_norm=olmoe,
-    ), **deepseek)
+    ), **own)
 
 
 def deepseek_v3_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
@@ -172,6 +176,82 @@ def deepseek_v3_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
         moe_routed_scale=float(hf.get("routed_scaling_factor", 1.0)))
 
 
+def bailing_hybrid_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """The ModelConfig fields of a `bailing_hybrid` config.json (the
+    language model of Ling-3.0-flash-VL): periods of `layer_group_size`
+    layers, Kimi Delta Attention in all but each period's last, which has
+    latent attention (no query LoRA) with a QK-norm and a head-wise
+    output gate; leading dense MLPs, then routed experts behind a
+    sigmoid router with a selection bias and a group-limited pick, plus
+    shared experts. `num_experts` may be a chip's SHARE of the router's
+    `num_experts_published` (from `expert_first` on). What is not
+    modelled is refused here, by key, not mis-served."""
+    def refuse(key, ok, modelled):
+        if not ok(hf.get(key)):
+            raise ValueError(f"{key}={hf.get(key)!r} is not supported "
+                             f"(only {modelled} is modelled)")
+    refuse("q_lora_rank", lambda v: v is None, "q_lora_rank: null")
+    for key in ("use_kda_lora", "use_mla_nope", "use_nGPT", "value_norm",
+                "up_proj_norm", "scale_router_input", "use_bias",
+                "use_qkv_bias", "mtp_use_kda"):
+        refuse(key, lambda v: not v, f"{key}: false")
+    refuse("no_kda_lora", lambda v: v in (None, True), "full-rank KDA gates")
+    refuse("kda_safe_gate", lambda v: v in (None, True),
+           "the lower-bound gate")
+    refuse("linear_silu", lambda v: v in (None, True),
+           "SiLU after the short convolution")
+    refuse("score_function", lambda v: v == "sigmoid", "sigmoid scoring")
+    refuse("gated_attention_proj_granularity_type",
+           lambda v: v == "head_wise", "a head-wise attention gate")
+    refuse("group_norm_size", lambda v: v in (None, 1), "group_norm_size 1")
+    refuse("num_nextn_predict_layers", lambda v: not v,
+           "no multi-token-prediction layers")
+    refuse("num_kv_heads_for_linear_attn", lambda v: not v,
+           "as many linear-attention key/value heads as query heads")
+    refuse("rope_scaling", lambda v: not v, "no rope scaling")
+    refuse("norm_topk_prob", lambda v: v in (None, True),
+           "renormalised router weights")
+    heads, layers = hf["num_attention_heads"], hf["num_hidden_layers"]
+    if hf.get("num_key_value_heads", heads) != heads:
+        raise ValueError("latent attention has one key/value set a query "
+                         "head: num_key_value_heads must equal "
+                         "num_attention_heads")
+    for key in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
+        if any((hf.get(key) or [])[:layers]):
+            raise ValueError(
+                f"{key} is nonzero at one of the {layers} layers kept: "
+                f"the clamped SwiGLU is not modelled")
+    dn, dr = int(hf["qk_nope_head_dim"]), int(hf["qk_rope_head_dim"])
+    held = int(hf["num_experts"])
+    published = int(hf.get("num_experts_published", held))
+    width = int(hf["moe_intermediate_size"])
+    return dict(
+        head_dim=int(hf["v_head_dim"]),
+        kv_lora_rank=int(hf["kv_lora_rank"]),
+        qk_nope_head_dim=dn, qk_rope_head_dim=dr,
+        query_scale=float(dn + dr) ** -0.5,
+        mla_qk_norm=bool(hf.get("use_qk_norm", False)), mla_gate=True,
+        linear_group_size=int(hf["layer_group_size"]),
+        linear_head_dim=int(hf["head_dim"]),
+        linear_conv_size=int(hf.get("short_conv_kernel_size", 4)),
+        linear_gate_lower_bound=float(hf.get("kda_lower_bound", -5)),
+        num_experts=published,
+        experts_held=held if held < published else 0,
+        expert_first=int(hf.get("expert_first", 0)),
+        moe_n_group=int(hf.get("n_group") or 1),
+        moe_topk_group=int(hf.get("topk_group") or 1),
+        intermediate_size=width,
+        dense_intermediate_size=int(hf["intermediate_size"]),
+        first_dense_layers=int(hf.get("first_k_dense_replace", 0)),
+        shared_expert_size=int(
+            hf.get("moe_shared_expert_intermediate_size")
+            or int(hf.get("num_shared_experts") or 0) * width),
+        norm_topk_prob=True, moe_scoring="sigmoid",
+        moe_router_bias=bool(hf.get("moe_router_enable_expert_bias")),
+        moe_routed_scale=float(hf.get("routed_scaling_factor", 1.0)),
+        qk_norm=False)
+
+
 def _read_all_tensors(path: str) -> Dict[str, np.ndarray]:
     from safetensors import safe_open
     files = sorted(glob.glob(os.path.join(path, "*.safetensors")))
@@ -216,6 +296,13 @@ def load_params_from_hf(path: str, cfg: ModelConfig,
       model.norm.weight                  -> final_norm
       lm_head.weight.T                   -> lm_head (absent when tied)
     """
+    if cfg.has_linear_layers:
+        # the catalog gives the hybrid's config and no tensor names: a
+        # guessed mapping would serve another function under its name
+        raise ValueError(
+            f"{cfg.name}: no checkpoint mapping for a model with "
+            f"linear-attention layers (its tensor names are not known); "
+            f"remove the *.safetensors to serve seeded weights")
     import jax.numpy as jnp
     dt = jnp.empty((), dtype or cfg.dtype).dtype
     raw = _read_all_tensors(path)

@@ -57,6 +57,44 @@ Pre-norm residual block, plain RMSNorm, eps 1e-5, final norm, untied head.
               SwiGLU of n_shared_experts x that (2816) on every token.
               Every expert is evaluated on every token and masked.
 
+And from the row `Ling-3.0-flash-VL` of the architecture catalog
+(`bailing_hybrid`; the layer equations from the Kimi Linear report,
+arXiv:2510.26692, for the linear layers, DeepSeek-V2/V3 for latent attention
+and the grouped router, arXiv:2505.06708 for the head-wise output gate;
+benchmark/reference/ling.py is the benchmark's copy of these lines). Layer
+i is latent attention where (i + 1) % `layer_group_size` == 0 and Kimi
+Delta Attention (KDA) otherwise; eps 1e-6.
+
+  KDA         (`attention_kda`, the per-token recurrence) per head h of H,
+              d = `head_dim`: q~, k~, v = SiLU(conv(x Wq)), SiLU(conv(x
+              Wk)), SiLU(conv(x Wv)), conv a causal depthwise convolution
+              of `short_conv_kernel_size` (4) over the sequence; q = q~ /
+              |q~|_2 d**-0.5, k = k~ / |k~|_2; decay g = `kda_lower_bound`
+              sigmoid(exp(A_log_h) (x Wf + dt_bias)) per CHANNEL, in
+              (-5, 0); beta = sigmoid(x Wb) per head; S_t = (I - beta_t k_t
+              k_t^T) diag(exp g_t) S_{t-1} + beta_t k_t v_t^T, o_t = S_t^T
+              q_t; out = (RMSNorm_head(o_t) * sigmoid(x Wg)) Wo.
+  the served form  (models/llama._kda_front / kda_chunk / kda_step, the same
+              function): a chunk's tokens at once by the WY form, U = (I +
+              diag(beta) A)^-1 diag(beta) (V - (K e^G) S_0) with A[t, i] =
+              sum_c k_t k_i e^(G_t - G_i), i < t, G the running sum of g;
+              the state lives in a per-sequence slot beside the page cache.
+  latent attention  `attention_mla` with the row's two additions:
+              `use_qk_norm`, an RMSNorm over each head's (nope | rope)
+              query and over the shared rope key before RoPE (leaves
+              `mla_q_norm`, `mla_k_norm`; the nope keys come from the
+              latent, which `kv_a_layernorm` norms); and the head-wise
+              gate, o_h * sigmoid(x Wgate)_h before Wo (`w_attn_gate`).
+  experts     s = sigmoid(x Wr) over all E; the pick is on s + bias and
+              group-limited: a group's score is the sum of its two
+              largest, the `topk_group` best of `n_group` groups stay, the
+              k best experts come from them; w = s_i / sum s_picked x
+              `routed_scaling_factor`. A chip's SHARE (`expert_first`,
+              `experts_held`): the router and the pick are over all E, the
+              expert leaves hold the share's experts alone, and what the
+              absent experts would add is left out (model-configs guide,
+              section 4); the shared expert is computed here whole.
+
 Departures from the published model, each deliberate:
   * weights are taken in this repo's layout: projections stored
     [in, out] (the checkpoint's are [out, in]; models/loader.py
@@ -142,9 +180,12 @@ def attention_mla(x, lp, *, num_heads, head_dim, kv_lora_rank,
     t, h, r = x.shape[0], num_heads, kv_lora_rank
     dn, dr = qk_nope_head_dim, qk_rope_head_dim
     q = (x @ lp["wq"]).reshape(t, h, dn + dr)
-    q_nope, q_pe = q[..., :dn], q[..., dn:]
     ckv = x @ lp["wkv_a"]                                   # [T, r + dr]
     k_pe = ckv[:, None, r:]                                 # [T, 1, dr]
+    if "mla_q_norm" in lp:           # the hybrid's QK-norm, before RoPE
+        q = rms_norm(q, lp["mla_q_norm"], rms_norm_eps)
+        k_pe = rms_norm(k_pe, lp["mla_k_norm"], rms_norm_eps)
+    q_nope, q_pe = q[..., :dn], q[..., dn:]
     c = rms_norm(ckv[:, :r], lp["kv_a_norm"], rms_norm_eps)
     kv = (c @ lp["wkv_b"]).reshape(t, h, dn + head_dim)
     k_nope, v = kv[..., :dn], kv[..., dn:]
@@ -160,7 +201,60 @@ def attention_mla(x, lp, *, num_heads, head_dim, kv_lora_rank,
     scores = jnp.where(causal[None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("hqk,khd->qhd", probs, v)
+    if "w_attn_gate" in lp:          # head-wise output gate
+        out = out * jax.nn.sigmoid(x @ lp["w_attn_gate"])[:, :, None]
     return out.reshape(t, h * head_dim) @ lp["wo"]
+
+
+def causal_conv(x, w):
+    """Causal depthwise convolution over the sequence. x [T, C], w [K, C]:
+    y_t = sum_j w[j] x_{t - (K - 1) + j}, zeros before the sequence."""
+    k = w.shape[0]
+    xp = jnp.concatenate([jnp.zeros((k - 1, x.shape[1]), x.dtype), x])
+    return sum(w[j] * xp[j:j + x.shape[0]] for j in range(k))
+
+
+def l2_normalize(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def round_to(x, dtype):
+    """x rounded to `dtype`'s exponent and mantissa, still float32. Not a
+    cast there and back: XLA drops such a pair (it may keep excess
+    precision), and the rounding is the point."""
+    info = jnp.finfo(dtype)
+    if info.bits >= 32:
+        return x
+    return jax.lax.reduce_precision(x, info.nexp, info.nmant)
+
+
+def attention_kda(x, lp, *, num_heads, head_dim, lower_bound, rms_norm_eps,
+                  state_dtype=F32):
+    """Kimi Delta Attention as the per-token recurrence. x [T, D], the
+    normed input; `head_dim` is the linear layers' own. `state_dtype`:
+    what S is rounded to after every token (float32: not at all)."""
+    t, h, d = x.shape[0], num_heads, head_dim
+    qkv = jax.nn.silu(causal_conv(x @ lp["kda_wqkv"], lp["kda_conv_w"]))
+    q, k, v = (a.reshape(t, h, d) for a in jnp.split(qkv, 3, axis=-1))
+    q, k = l2_normalize(q) * d ** -0.5, l2_normalize(k)
+    g = lower_bound * jax.nn.sigmoid(
+        jnp.exp(lp["kda_a_log"])[None, :, None]
+        * (x @ lp["kda_wf"] + lp["kda_dt_bias"]).reshape(t, h, d))
+    beta = jax.nn.sigmoid(x @ lp["kda_wb"])                     # [T, H]
+
+    def step(s, xs):                       # s [H, dk, dv]
+        q_t, k_t, v_t, g_t, b_t = xs
+        s = jnp.exp(g_t)[:, :, None] * s
+        s = s + jnp.einsum("hk,hv->hkv", k_t, b_t[:, None] * (
+            v_t - jnp.einsum("hk,hkv->hv", k_t, s)))
+        s = round_to(s, state_dtype)
+        return s, jnp.einsum("hkv,hk->hv", s, q_t)
+
+    _, o = jax.lax.scan(step, jnp.zeros((h, d, d), F32),
+                        (q, k, v, g, beta))
+    o = rms_norm(o, lp["kda_o_norm"], rms_norm_eps)
+    o = o * jax.nn.sigmoid(x @ lp["kda_wg"]).reshape(t, h, d)
+    return o.reshape(t, h * d) @ lp["wo"]
 
 
 def dense_mlp(x, lp, names=("w_gate", "w_up", "w_down")):
@@ -168,14 +262,29 @@ def dense_mlp(x, lp, names=("w_gate", "w_up", "w_down")):
     return (jax.nn.silu(x @ gate) * (x @ up)) @ down
 
 
+def group_limited(pick, n_group, topk_group):
+    """DeepSeek-V3's group-limited pick: the experts lie in `n_group`
+    equal groups in order; a group's score is the sum of its two largest
+    `pick`; outside the `topk_group` best groups `pick` becomes -inf."""
+    t, e = pick.shape
+    grouped = pick.reshape(t, n_group, e // n_group)
+    score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)     # [T, G]
+    _, kept = jax.lax.top_k(score, topk_group)
+    mask = jnp.sum(jax.nn.one_hot(kept, n_group, dtype=F32), 1) > 0
+    return jnp.where(mask[:, :, None], grouped, -jnp.inf).reshape(t, e)
+
+
 def router_weights(x, lp, *, num_experts_per_tok, norm_topk_prob,
-                   moe_scoring="softmax", moe_routed_scale=1.0):
+                   moe_scoring="softmax", moe_routed_scale=1.0,
+                   n_group=1, topk_group=1):
     """[T, E] float32: each token's weight on every expert, zero outside
     its top-k. A `router_bias` leaf picks and does not weigh."""
     logits = x @ lp["router"]                                  # [T, E]
     scores = (jax.nn.sigmoid(logits) if moe_scoring == "sigmoid"
               else jax.nn.softmax(logits, axis=-1))
     pick = scores + lp["router_bias"] if "router_bias" in lp else scores
+    if n_group > 1:
+        pick = group_limited(pick, n_group, topk_group)
     _, chosen = jax.lax.top_k(pick, num_experts_per_tok)       # [T, k]
     mask = jnp.sum(jax.nn.one_hot(chosen, scores.shape[-1], dtype=F32), 1)
     weights = scores * mask
@@ -185,10 +294,14 @@ def router_weights(x, lp, *, num_experts_per_tok, norm_topk_prob,
     return weights * moe_routed_scale
 
 
-def expert_mlp(x, lp, **router):
+def expert_mlp(x, lp, expert_first=0, **router):
     """Every expert on every token, masked by the top-k; plus the shared
-    expert (leaves `ws_*`) where the layer has one."""
+    expert (leaves `ws_*`) where the layer has one. Where the expert
+    leaves hold a share of the router's experts (fewer than its columns),
+    they are experts `expert_first` on, and the others add nothing."""
     weights = router_weights(x, lp, **router)
+    held = lp["w_gate"].shape[0]
+    weights = weights[:, expert_first:expert_first + held]
     hidden = (jax.nn.silu(jnp.einsum("td,edf->etf", x, lp["w_gate"]))
               * jnp.einsum("td,edf->etf", x, lp["w_up"]))
     y = jnp.einsum("etf,efd->etd", hidden, lp["w_down"])       # [E, T, D]
@@ -201,12 +314,17 @@ def expert_mlp(x, lp, **router):
 def layer(x, lp, *, num_heads, num_kv_heads, head_dim, rope_theta,
           rms_norm_eps, qk_norm=False, num_experts=0,
           num_experts_per_tok=0, norm_topk_prob=True, mla=None,
-          moe_scoring="softmax", moe_routed_scale=1.0):
+          moe_scoring="softmax", moe_routed_scale=1.0, kda=None,
+          n_group=1, topk_group=1, expert_first=0):
     """One pre-norm residual block. x: [T, D]; lp: this layer's weights,
-    float32. `mla`: attention_mla's sizes (a dict) for latent attention.
-    A layer without a `router` leaf has a dense MLP."""
+    float32. `mla`: attention_mla's sizes (a dict) for latent attention;
+    `kda`: attention_kda's, for a layer that has its leaves. A layer
+    without a `router` leaf has a dense MLP."""
     xn = rms_norm(x, lp["attn_norm"], rms_norm_eps)
-    if mla:
+    if "kda_wqkv" in lp:
+        x = x + attention_kda(xn, lp, num_heads=num_heads,
+                              rms_norm_eps=rms_norm_eps, **kda)
+    elif mla:
         x = x + attention_mla(xn, lp, num_heads=num_heads,
                               head_dim=head_dim, rope_theta=rope_theta,
                               rms_norm_eps=rms_norm_eps, **mla)
@@ -221,7 +339,10 @@ def layer(x, lp, *, num_heads, num_kv_heads, head_dim, rope_theta,
                               num_experts_per_tok=num_experts_per_tok,
                               norm_topk_prob=norm_topk_prob,
                               moe_scoring=moe_scoring,
-                              moe_routed_scale=moe_routed_scale)
+                              moe_routed_scale=moe_routed_scale,
+                              expert_first=expert_first,
+                              **(dict(n_group=n_group, topk_group=topk_group)
+                                 if n_group > 1 else {}))
     return x + dense_mlp(xn, lp)
 
 
@@ -237,10 +358,24 @@ def arch_kwargs(cfg) -> dict:
                 num_experts_per_tok=cfg.num_experts_per_tok,
                 norm_topk_prob=cfg.norm_topk_prob, mla=mla,
                 moe_scoring=cfg.moe_scoring,
-                moe_routed_scale=cfg.moe_routed_scale)
+                moe_routed_scale=cfg.moe_routed_scale,
+                kda=dict(head_dim=cfg.linear_head_dim,
+                         lower_bound=cfg.linear_gate_lower_bound)
+                if cfg.has_linear_layers else None,
+                n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group,
+                expert_first=cfg.expert_first)
 
 
 LAYER_GROUPS = ("dense_layers", "layers")   # in the model's layer order
+
+
+def layer_stacks(params) -> list:
+    """The model's layer stacks in layer order: the two named above, or a
+    hybrid's runs of like layers, `run0`, `run1`, ... (models/llama.
+    layer_groups)."""
+    runs = sorted((k for k in params if k.startswith("run")),
+                  key=lambda k: int(k[3:]))
+    return [params[k] for k in runs or LAYER_GROUPS if k in params]
 
 
 def forward(params, tokens, **arch):
@@ -251,9 +386,8 @@ def forward(params, tokens, **arch):
         params = jax.tree.map(lambda a: jnp.asarray(a, F32), params)
         # ids the engine served  # dynalint: disable-next-line=R1
         x = params["embed"][jnp.asarray(tokens)]
-        for group in LAYER_GROUPS:
-            stack = params.get(group, {"wq": ()})
-            for i in range(len(stack["wq"])):
+        for stack in layer_stacks(params):
+            for i in range(len(stack["attn_norm"])):
                 lp = {name: leaf[i] for name, leaf in stack.items()}
                 x = layer(x, lp, **arch)
         x = rms_norm(x, params["final_norm"], arch["rms_norm_eps"])

@@ -143,6 +143,24 @@ class LedgerStats:
         #                           them: rows x table width x page size
         "kv_bytes_per_token",     # gauge: bytes a token holds in the
         #                           cache, all layers (set at engine start)
+        # a share of an expert layer (ops/moe.py): assignments of real
+        # tokens to experts this engine does not hold, left out
+        "moe_routed_absent_total",
+        # linear-attention layers and their recurrent state, from every
+        # step's plan on the host (engine._account_linattn):
+        "linattn_tokens_total",        # (token, linear layer) updates
+        "linattn_chunk_tokens_total",  # of those, by the chunkwise form
+        #                                (an _engine_step's rows); the rest
+        #                                by a decode window's one-token form
+        "linattn_state_bytes_total",   # state bytes read + written: live
+        #                                rows x linear layers x slot bytes x 2
+        "linattn_steps_total",         # device steps the above were summed
+        #                                over: 1 an _engine_step, a window
+        #                                its steps
+        "linattn_window_state_bytes_total",  # the same two over decode
+        "linattn_window_steps_total",        # windows alone
+        "state_slots_used",       # gauge: recurrent-state slots held
+        "state_bytes_per_slot",   # gauge: bytes of one slot, all layers
     )
 
     def __init__(self):

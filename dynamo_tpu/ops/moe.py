@@ -58,8 +58,26 @@ def grouped_matmul_impl() -> str:
     return "gmm" if jax.default_backend() == "tpu" else "ragged_dot"
 
 
+def group_limited(pick, n_group: int, topk_group: int):
+    """DeepSeek-V3's group-limited pick. pick [..., E]: the (biased)
+    scores the experts are picked on; the experts lie in `n_group` equal
+    groups in order. A group's score is the sum of its two largest; the
+    `topk_group` best groups stay, every other group's experts become
+    -inf, so the k experts come from the groups that stay. A tie between
+    groups goes to the lower group."""
+    with jax.named_scope("moe.route.groups"):
+        lead, e = pick.shape[:-1], pick.shape[-1]
+        grouped = pick.reshape(lead + (n_group, e // n_group))
+        score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, kept = jax.lax.top_k(score, topk_group)            # [..., g]
+        mask = jnp.any(kept[..., None] == jnp.arange(n_group), axis=-2)
+        return jnp.where(mask[..., None], grouped, -jnp.inf
+                         ).reshape(lead + (e,))
+
+
 def route_topk(x, router, k: int, renorm: bool, scoring: str = "softmax",
-               bias=None, scale: float = 1.0):
+               bias=None, scale: float = 1.0, n_group: int = 1,
+               topk_group: int = 1):
     """Router: x [..., D], router [D, E] -> (weights [..., k] float32,
     expert ids [..., k]). The scores are a float32 softmax (or, `scoring`
     "sigmoid", independent sigmoids) over ALL experts at the highest
@@ -71,11 +89,13 @@ def route_topk(x, router, k: int, renorm: bool, scoring: str = "softmax",
     (OLMoE, `norm_topk_prob: false`). `bias` [E] (DeepSeek-V3's
     `e_score_correction_bias`) is added to the scores to PICK the k
     experts and is no part of their weights; `scale` multiplies the
-    weights last. A tie goes to the lower expert id."""
+    weights last. `n_group` > 1: the pick is group-limited
+    (`group_limited`). A tie goes to the lower expert id."""
     f32 = jnp.float32
     logits = jnp.einsum("...d,de->...e", x.astype(f32), router.astype(f32),
                         precision=jax.lax.Precision.HIGHEST)
-    if scoring == "softmax" and bias is None and scale == 1.0:
+    if scoring == "softmax" and bias is None and scale == 1.0 \
+            and n_group == 1:
         if renorm:
             weights, idx = jax.lax.top_k(logits, k)
             return jax.nn.softmax(weights, axis=-1), idx
@@ -85,6 +105,8 @@ def route_topk(x, router, k: int, renorm: bool, scoring: str = "softmax",
     scores = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
               else jax.nn.softmax(logits, axis=-1))
     pick = scores if bias is None else scores + bias.astype(f32)
+    if n_group > 1:
+        pick = group_limited(pick, n_group, topk_group)
     _, idx = jax.lax.top_k(pick, k)
     weights = jnp.take_along_axis(scores, idx, axis=-1)
     if renorm:
@@ -98,7 +120,8 @@ def route(x, lp, cfg):
     settings (engine/config.py ModelConfig)."""
     return route_topk(x, lp["router"], cfg.num_experts_per_tok,
                       cfg.norm_topk_prob, cfg.moe_scoring,
-                      lp.get("router_bias"), cfg.moe_routed_scale)
+                      lp.get("router_bias"), cfg.moe_routed_scale,
+                      cfg.moe_n_group, cfg.moe_topk_group)
 
 
 def moe_stats(routed, dropped, expert_rows, experts_hit):
@@ -175,6 +198,14 @@ def moe_dropless_mlp(x: jax.Array, lp, cfg, valid=None, layer=None):
     assignments carry the expert id E, sort behind every real one and
     belong to no group, so no expert computes them.
 
+    A SHARE (`cfg.experts_held` of the router's `cfg.num_experts`, from
+    `cfg.expert_first` on): the router and the pick are over all the
+    experts, lp's expert leaves hold the share alone, and an assignment
+    to an expert that is not held is taken out BEFORE the sort, like a
+    padding position's (it costs no row tile) and counted apart
+    (`moe_routed_absent`): what the absent experts would add is left
+    out, here and in the reference alike; no exchange is built.
+
     The assignments (S = B*T*k of them, padded up to the kernel's row
     tile) are sorted by expert with a stable argsort, the rows gathered
     in that order, gate / up / down each run as ONE grouped matmul with
@@ -182,13 +213,23 @@ def moe_dropless_mlp(x: jax.Array, lp, cfg, valid=None, layer=None):
     inverse permutation, weighted in float32 and summed over k.
     """
     b, t, d = x.shape
-    e, k = cfg.num_experts, cfg.num_experts_per_tok
+    # e: the experts whose weights are here, and the id of no expert
+    e, k = cfg.local_experts, cfg.num_experts_per_tok
     f32 = jnp.float32
     n = b * t
     xf = x.reshape(n, d)
 
     with jax.named_scope("moe.route"):
         weights, idx = route(xf, lp, cfg)
+        absent = None
+        if cfg.experts_held:
+            idx = idx - cfg.expert_first
+            held = (idx >= 0) & (idx < e)
+            if valid is not None:
+                held = held | ~valid.reshape(n, 1).astype(bool)
+            absent = jnp.sum(~held)
+            idx = jnp.where(held, idx, e)
+            weights = jnp.where(held, weights, 0.0)
         if valid is not None:
             ok = valid.reshape(n).astype(bool)
             idx = jnp.where(ok[:, None], idx, e)
@@ -224,6 +265,8 @@ def moe_dropless_mlp(x: jax.Array, lp, cfg, valid=None, layer=None):
     stats = moe_stats(routed=jnp.sum(group_sizes), dropped=0.0,
                       expert_rows=computed,
                       experts_hit=jnp.sum(group_sizes > 0))
+    if absent is not None:
+        stats["moe_routed_absent"] = absent.astype(f32)
     return out.reshape(b, t, d), stats
 
 

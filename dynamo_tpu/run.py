@@ -57,6 +57,20 @@ def build_card(model_spec: str) -> ModelDeploymentCard:
                                tokenizer_kind="byte")
 
 
+def chunk_buckets(spec: str, cap: int) -> tuple:
+    """--prefill-buckets: the ladder of prefill chunk buckets, ascending;
+    its largest holds --max-prefill-chunk (a chunk of the cap must have a
+    bucket). Empty: EngineConfig's own."""
+    if not spec:
+        return EngineConfig.prefill_buckets
+    ladder = tuple(sorted({int(b) for b in spec.split(",")}))
+    if ladder[0] < 1 or ladder[-1] < cap:
+        raise ValueError(
+            f"--prefill-buckets {spec}: the largest bucket must hold "
+            f"--max-prefill-chunk {cap}, and every bucket a token")
+    return ladder
+
+
 async def build_engine(out_spec: str, card: ModelDeploymentCard, args):
     if out_spec == "echo":
         return EchoTokenEngine(delay_s=args.echo_delay)
@@ -90,6 +104,11 @@ async def build_engine(out_spec: str, card: ModelDeploymentCard, args):
     eng_cfg = EngineConfig(
         page_size=card.kv_page_size, num_pages=args.num_pages,
         max_slots=args.max_slots, max_prefill_chunk=args.max_prefill_chunk,
+        prefill_buckets=chunk_buckets(args.prefill_buckets,
+                                      args.max_prefill_chunk),
+        mixed_token_budget=args.mixed_token_budget,
+        max_prefill_batch=args.max_prefill_batch,
+        decode_steps=args.decode_steps,
         max_model_len=min(card.context_length, model_cfg.max_model_len),
         tp=args.tp, sp=args.sp, host_pages=args.host_pages,
         spec_decode=args.spec_decode, spec_k=args.spec_k,
@@ -191,6 +210,28 @@ async def amain() -> None:
     p.add_argument("--num-pages", type=int, default=512)
     p.add_argument("--max-slots", type=int, default=8)
     p.add_argument("--max-prefill-chunk", type=int, default=512)
+    p.add_argument("--prefill-buckets", default="",
+                   help="comma-separated prefill chunk buckets, one program "
+                        "set each (default: EngineConfig's 16..512); a "
+                        "deployment whose prompts are short drops the "
+                        "buckets it never fills")
+    p.add_argument("--mixed-token-budget", type=int,
+                   default=EngineConfig.mixed_token_budget,
+                   help="device compute tokens of one fused prefill+decode "
+                        "step, every row charged its chunk bucket: sets "
+                        "the prefill chunk that rides beside the decode "
+                        "rows (with 64 slots the default leaves 16 tokens "
+                        "a step, and admission bounds the engine)")
+    p.add_argument("--max-prefill-batch", type=int,
+                   default=EngineConfig.max_prefill_batch,
+                   help="prompts whose chunks may share one step; each "
+                        "holds a state slot of a recurrent-state model "
+                        "beyond --max-slots while it prefills")
+    p.add_argument("--decode-steps", type=int,
+                   default=EngineConfig.decode_steps,
+                   help="device steps of a full decode window: a stream "
+                        "sees no token for that many steps, then all of "
+                        "them at once")
     p.add_argument("--tp", type=int, default=1)
     p.add_argument("--pp", type=int, default=1,
                    help="pipeline-parallel stages (layer-sharded params + "

@@ -210,9 +210,9 @@ def test_upload_opens_once_a_step_and_encloses_the_staging():
         finally:
             open_now.pop()
 
-    def stage(small, own=()):
+    def stage(small, own=(), **kw):
         staged_under.append(tuple(open_now))
-        return real_stage(small, own)
+        return real_stage(small, own, **kw)
 
     eng.phases.phase, eng._stage_operands = phase, stage
     arrivals = {0: EngineRequest("a", list(range(10, 40)), sampled(30)),

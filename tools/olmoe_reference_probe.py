@@ -1,6 +1,6 @@
 """Show, on the chip, that a configuration's reference check
-(benchmark/checks/reference_logits.py, or reference_logits_moonlight.py
-where `meta.json` has a `reference_check` key) is tight:
+(benchmark/checks/reference_logits.py, or reference_logits_<module>.py
+where `meta.json` has a `reference_check` key naming the module) is tight:
 serve a benchmark configuration exactly as a run does (benchmark/run.py's
 own `Served`, launcher and socket), run ONLY that check's measurement, and
 print its readings, with the served model or the reference mutated.
@@ -13,9 +13,12 @@ print its readings, with the served model or the reference mutated.
                                  weights are rounded to float8 (e4m3), the
                                  nearest precision below bfloat16
 
-`--prompt-seeds a,b,c` reads further draws of the prompts and
-`--then-float8` the float8 reference, all in the one served process (a
+`--prompt-seeds a,b,c` reads further draws of the prompts,
+`--then-float8` the float8 reference and `--then-bf16-state` the reference
+with its recurrent state in bfloat16, all in the one served process (a
 start costs minutes at these widths): one JSON line each.
+`--served-from FILE` reads the controls alone over what an earlier run of
+the cell served (the check leaves it in the run's output directory).
 
 One JSON line: the readings, the check's limits for the served dtype and
 `passes`. `none` must pass; PERF.md section 6, PR 27 says which mutations
@@ -68,9 +71,10 @@ async def probe(args) -> None:
     config_dir = os.path.join(BENCH, "configs", args.config)
     with open(os.path.join(config_dir, "meta.json")) as f:
         meta = json.load(f)
+    module = (meta.get("reference_check") or {}).get("module")
     check = load("reference_check", os.path.join(
-        BENCH, "checks", "reference_logits_moonlight.py"
-        if meta.get("reference_check") else "reference_logits.py"))
+        BENCH, "checks", f"reference_logits_{module}.py"
+        if module else "reference_logits.py"))
     if args.rehearsal:
         config_dir = os.path.join(BENCH, "configs",
                                   meta["rehearsal_config"])
@@ -96,6 +100,15 @@ async def probe(args) -> None:
     readings = [(seed, args.mutation) for seed in seeds]
     if args.then_float8:
         readings.append((seeds[0], "ref-float8"))
+    if args.then_bf16_state:
+        readings.append((seeds[0], "ref-bf16-state"))
+    served_rows = None
+    if args.served_from:
+        # what an earlier run served (the check's own file in that run's
+        # output directory): the controls alone, one reference pass each
+        with open(args.served_from) as f:
+            served_rows = json.load(f)
+        readings = readings[len(seeds):]
     for seed, mutation in readings:
         if seed is not None:
             check.SEED = seed
@@ -104,7 +117,11 @@ async def probe(args) -> None:
             # the check's own comparison, and the differences themselves
             # beside the line (chiprun_out/reference_probe/<mutation>/)
             diffs = []
-            got = await check.measure(ctx, cast=cast, keep=diffs)
+            more = {"state_dtype": "bfloat16"} \
+                if mutation == "ref-bf16-state" else {}
+            if served_rows is not None:
+                more["served"] = served_rows
+            got = await check.measure(ctx, cast=cast, keep=diffs, **more)
             with open(os.path.join(
                     out_dir, f"diffs-{mutation}-{seed}.json"), "w") as f:
                 json.dump(sorted(diffs), f)
@@ -134,6 +151,14 @@ def main() -> None:
                    help="several draws, one reading each, one process")
     p.add_argument("--then-float8", action="store_true",
                    help="after them, the float8 reference on the first draw")
+    p.add_argument("--then-bf16-state", action="store_true",
+                   help="after them, the reference with its recurrent "
+                        "state rounded to bfloat16 (a configuration with "
+                        "linear-attention layers)")
+    p.add_argument("--served-from", default="",
+                   help="the controls alone, over what an earlier run "
+                        "served (chiprun_out/benchmark/<cell>/<run>/"
+                        "reference_logits_ling.served.json): no serving")
     args = p.parse_args()
     if args.rehearsal:
         os.environ["JAX_PLATFORMS"] = "cpu"

@@ -253,30 +253,49 @@ def build_programs(config_dir: str, rows: int, chunk: int, pages: int,
     cache = abstract(jax.eval_shape(
         lambda: llama.init_cache(cfg, num_pages, ecfg.page_size)),
         llama.cache_shardings(cfg))
+    state = cfg.has_linear_layers
+    if state:
+        # the recurrent state rides the cache dict (NativeEngine), one
+        # slot a decode row and one a row of a prefill batch
+        cache.update(abstract(
+            jax.eval_shape(lambda: llama.init_state(
+                cfg, rows + ecfg.max_prefill_batch)),
+            {name: PartitionSpec() for name in cfg.state_leaves()}))
+
+    def with_slots(fn):
+        """The program with its last operand as `state_slots`."""
+        if not state:
+            return fn
+        return lambda params, cache, *args: fn(
+            params, cache, *args[:-1], state_slots=args[-1])
+
     # what ONE device holds of one layer's K pool
-    layer_pool = (cache["k"].size // cfg.num_layers
+    layer_pool = (cache["k"].size // cfg.num_cache_layers
                   * cache["k"].dtype.itemsize // tp)
     if tp > 1:
         target += f", --tp {tp}"
     f32 = jnp.float32
     vec = arr((rows,))
     step = jax.jit(
-        eng._named("engine_step", functools.partial(
+        eng._named("engine_step", with_slots(functools.partial(
             eng._engine_step, cfg, (), None, kernel_mesh, False, False,
-            False, None)), donate_argnums=(1,))
+            False, None))), donate_argnums=(1,))
     step_args = (params, cache, arr((rows, chunk)), arr((rows, chunk)),
                  arr((rows, pages)), vec, arr((rows, chunk)), vec,
                  arr((rows,), f32), vec, arr((rows,), f32), vec, vec, vec)
     nw = window_ladder(ecfg.decode_steps)[0]
     window = jax.jit(
-        eng._named("engine_decode_window_full", functools.partial(
-            eng._engine_decode_window, cfg, (), kernel_mesh, nw,
-            ecfg.page_size, False, False, False, False)),
+        eng._named("engine_decode_window_full", with_slots(
+            functools.partial(
+                eng._engine_decode_window, cfg, (), kernel_mesh, nw,
+                ecfg.page_size, False, False, False, False))),
         donate_argnums=(1,))
     window_args = (params, cache, vec, vec, arr((rows, pages)),
                    arr((rows, base_pages)), vec, arr((rows,), f32), vec,
                    arr((rows,), f32), vec, vec, vec, arr((rows,), jnp.bool_),
                    arr((rows, 0)))
+    if state:
+        step_args, window_args = step_args + (vec,), window_args + (vec,)
     return ([(f"jit_engine_step[{rows},{chunk}]", step, step_args),
              (f"jit_engine_decode_window_full[{rows}x{nw}]", window,
               window_args)], layer_pool, (num_pages, ecfg.page_size), target)
