@@ -989,8 +989,11 @@ class NativeEngine:
                  counters, min_toks)
         if self._state_slots:
             small += (plan.state_slots,)
-            self._account_linattn(int((plan.write_idx >= 0).sum()),
-                                  int((plan.state_slots >= 0).sum()))
+            real = (plan.write_idx >= 0).sum(axis=1)
+            self._account_linattn(
+                int(real.sum()), int((plan.state_slots >= 0).sum()),
+                inplace=int((real == 1).sum()) if llama.kda_mix_splits(
+                    *plan.write_idx.shape) else 0)
         own = ()
         if rp is not None:
             small, own = small + (rp[1],), own + (rp[0],)
@@ -1015,19 +1018,24 @@ class NativeEngine:
         return int(plan.tokens.size)
 
     def _account_linattn(self, tokens: int, rows: int,
-                         window_steps: int = 0) -> None:
+                         window_steps: int = 0, inplace: int = 0) -> None:
         """`llm_engine_linattn_*_total`, from a step's plan on the host:
         the (token, linear layer) state updates of its real tokens,
-        which of them the chunkwise form computed (an `_engine_step`:
-        `window_steps` 0; a decode window's rows take the one-token
-        form), the state bytes its live `rows` read and wrote (every
-        touched slot's state, once each way, a linear layer and a step),
-        and the device steps that is over. A window's bytes and steps
-        are also kept apart, as its experts are (`_account_moe`)."""
+        which of them rode an `_engine_step` (`window_steps` 0; the
+        chunkwise form's, and the one-token rows beside them), which
+        were made where the state rests (`kda_step_slots`: every token
+        of a decode window, and the `inplace` one-token rows of a step
+        that `llama.kda_mix_splits`), the state bytes its live `rows`
+        read and wrote (every touched slot's state, once each way, a
+        linear layer and a step), and the device steps that is over. A
+        window's bytes and steps are also kept apart, as its experts are
+        (`_account_moe`)."""
         stats = self.ledger.stats
         layers = self.model_cfg.num_state_layers
         moved = 2 * rows * self.model_cfg.state_bytes_per_slot()
         stats.linattn_tokens_total += tokens * layers
+        stats.linattn_inplace_updates_total += layers * (
+            tokens if window_steps else inplace)
         stats.linattn_state_bytes_total += moved
         stats.linattn_steps_total += window_steps or 1
         if window_steps:

@@ -37,7 +37,7 @@ from dynamo_tpu.ops.attention import (
 from dynamo_tpu.ops.kv_quant import cache_keys
 from dynamo_tpu.ops.linear_attention import BLOCK as KDA_BLOCK
 from dynamo_tpu.ops.linear_attention import (
-    conv_with_tail, kda_chunk, kda_step, l2_normalize,
+    conv_with_tail, kda_chunk, kda_step_slots, l2_normalize,
 )
 from dynamo_tpu.ops.kv_quant import validate_mode as _validate_kv_quant
 from dynamo_tpu.ops.moe import (
@@ -531,9 +531,12 @@ def refuse_unserved_latent_cache(cfg: ModelConfig, engine_cfg=None,
 
 def init_state(cfg: ModelConfig, slots: int) -> Dict[str, jax.Array]:
     """The per-sequence recurrent state, a leaf per entry of
-    `cfg.state_leaves()`: [state layers, slots, ...], zeros. It lives in
-    the engine's cache dict beside the paged pool."""
-    return {name: jnp.zeros((cfg.num_state_layers, slots) + shape,
+    `cfg.state_leaves()`: [state layers, slots + 1, ...], zeros. It lives
+    in the engine's cache dict beside the paged pool. The last slot is no
+    sequence's: it is the SCRATCH slot that the slot-addressed update
+    points its dead rows at (`ops/linear_attention.kda_step_slots`);
+    `slots` is what the scheduler hands out."""
+    return {name: jnp.zeros((cfg.num_state_layers, slots + 1) + shape,
                             jnp.dtype(dtype))
             for name, (shape, dtype) in cfg.state_leaves().items()}
 
@@ -898,24 +901,35 @@ def _slot_index(slots: jax.Array, n_slots: int) -> jax.Array:
     return jnp.where(slots < 0, n_slots, slots)
 
 
+def kda_mix_splits(rows: int, tq: int) -> bool:
+    """Whether a [rows, tq] step's linear layers split its rows by what
+    each holds (`kda_mix`): the program traces it, the engine's counters
+    read it off the plan."""
+    return tq > 1 and rows > KDA_CHUNK_ROWS
+
+
 def kda_mix(state: tuple, lk, slots: jax.Array, lp: Params,
             cfg: ModelConfig, pre, g, beta, valid, fresh):
     """A linear layer's state update for a [B, T] step, between
     `_kda_front` and `_kda_out`: the causal convolution over each row's
-    tokens (continued from the slot's tail), then the chunkwise delta
-    rule from the slot's state. state: (kda_s [Lk, slots, H, d, d],
-    kda_conv [Lk, slots, K - 1, 3 H d]), `lk` this layer's index in them;
+    tokens (continued from the slot's tail), then the delta rule from
+    the slot's state. state: (kda_s [Lk, slots + 1, H, d, d], kda_conv
+    [Lk, slots + 1, K - 1, 3 H d]), `lk` this layer's index in them;
     valid [B, T]: real tokens, a prefix of each row; fresh [B]: the row
     starts its sequence (position 0), so it starts from zeros whatever
     the slot held: a reused slot needs no clearing. Each touched slot's
     state is read once and written once; a row without a slot, and a row
-    of padding, write nothing. A step of more than KDA_CHUNK_ROWS rows is
-    split by what each row holds: a row of one token (a decode row) takes
-    `kda_step`, a row of more takes `kda_chunk`, whatever the number of
-    either. Returns (state, o [B, T, H, d] float32)."""
+    of padding, write nothing. A step of more than KDA_CHUNK_ROWS rows
+    (`kda_mix_splits`) is split by what each row holds: a row of ONE
+    token (a decode row, a one-token chunk) is updated where it rests
+    (`kda_step_slots`), a row of more takes `kda_chunk`, gathered and
+    scattered KDA_CHUNK_ROWS at a time, whatever the number of either;
+    each kind is a dead row to the other, so no row is updated twice.
+    Returns (state, o [B, T, H, d] float32)."""
     kda_s, kda_conv = state
     b, tq = valid.shape
-    at = _slot_index(slots, kda_s.shape[1])
+    n_slots = kda_s.shape[1]
+    at = _slot_index(slots, n_slots)
     n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
     keep = ~fresh
     with jax.named_scope("linattn.conv"):
@@ -923,68 +937,79 @@ def kda_mix(state: tuple, lk, slots: jax.Array, lp: Params,
         tail = jnp.where(keep[:, None, None], tail, 0)
         y, tail = conv_with_tail(pre, tail, lp["kda_conv_w"], n_valid)
         q, k, v = _kda_qkv(y, cfg)
+        kda_conv = kda_conv.at[lk, at].set(tail.astype(kda_conv.dtype),
+                                           mode="drop")
     with jax.named_scope("linattn.chunk"):
         m = valid[:, :, None, None]
         q, k, v, g = (jnp.where(m, a, 0.0) for a in (q, k, v, g))
         beta = jnp.where(valid[:, :, None], beta, 0.0)
-        s0 = kda_s.at[lk, at].get(mode="clip")
-        s0 = jnp.where(keep[:, None, None, None], s0, 0.0)
-        if tq > 1 and b > KDA_CHUNK_ROWS:
+        if kda_mix_splits(b, tq):
             # a mixed step: most rows are decode rows with ONE token and
-            # take the one-token form; the rows with more (chunk rows,
-            # the plan's non-decode rows) take the chunkwise form,
+            # are updated in their slots; the rows with more (chunk
+            # rows, the plan's non-decode rows) take the chunkwise form,
             # KDA_CHUNK_ROWS of them at a time, for as many groups as
             # the step holds: one beside a full batch, never a row less
             # than there are
-            o0, s1 = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                              beta[:, 0], s0)
+            o0, kda_s = kda_step_slots(
+                kda_s, lk, jnp.where(n_valid == 1, slots, -1), q[:, 0],
+                k[:, 0], v[:, 0], g[:, 0], beta[:, 0], fresh)
             o = jnp.zeros((b, tq) + o0.shape[1:], o0.dtype).at[:, 0].set(o0)
             groups = -(-b // KDA_CHUNK_ROWS)
             # longest first; past the last row: read clipped, write dropped
             order = jnp.pad(jnp.argsort(-n_valid).astype(jnp.int32),
                             (0, groups * KDA_CHUNK_ROWS - b),
                             constant_values=b)
+            # a group's rows of one token or none (the last group's
+            # fill) are the kernel's: theirs is dropped here
+            long_row = jnp.where(n_valid > 1, jnp.arange(b), b)
+            long_at = jnp.where(n_valid > 1, at, n_slots)
 
             def group(j, carry):
-                o, s1 = carry
+                o, kda_s = carry
                 rows = jax.lax.dynamic_slice_in_dim(
                     order, j * KDA_CHUNK_ROWS, KDA_CHUNK_ROWS)
+                at_g = long_at.at[rows].get(mode="fill", fill_value=n_slots)
+                s_g = jnp.where(
+                    keep.at[rows].get(mode="clip")[:, None, None, None],
+                    kda_s.at[lk, at_g].get(mode="clip"), 0.0)
                 o_g, s_g = kda_chunk(*(
                     a.at[rows].get(mode="clip")
-                    for a in (q, k, v, g, beta, s0)))
-                return (o.at[rows].set(o_g, mode="drop"),
-                        s1.at[rows].set(s_g, mode="drop"))
+                    for a in (q, k, v, g, beta)), s_g)
+                return (o.at[long_row.at[rows].get(
+                            mode="fill", fill_value=b)].set(o_g, mode="drop"),
+                        kda_s.at[lk, at_g].set(s_g, mode="drop"))
 
             n_long = jnp.sum(n_valid > 1).astype(jnp.int32)
-            o, s1 = jax.lax.fori_loop(
-                0, -(-n_long // KDA_CHUNK_ROWS), group, (o, s1))
+            o, kda_s = jax.lax.fori_loop(
+                0, -(-n_long // KDA_CHUNK_ROWS), group, (o, kda_s))
         else:
+            s0 = kda_s.at[lk, at].get(mode="clip")
+            s0 = jnp.where(keep[:, None, None, None], s0, 0.0)
             o, s1 = kda_chunk(q, k, v, g, beta, s0)
-        kda_s = kda_s.at[lk, at].set(s1, mode="drop")
-        kda_conv = kda_conv.at[lk, at].set(tail.astype(kda_conv.dtype),
-                                           mode="drop")
+            kda_s = kda_s.at[lk, at].set(s1, mode="drop")
     return (kda_s, kda_conv), o
 
 
 def kda_decode(state: tuple, lk, slots: jax.Array, lp: Params,
                cfg: ModelConfig, pre, g, beta, valid):
-    """`kda_mix` for a decode step: one token a row, the one-token form
-    of the update. pre [B, 3 H d], g [B, H, d], beta [B, H]; valid [B]:
-    rows that are live (a finished or padding row writes nothing).
-    Returns (state, o [B, H, d] float32)."""
+    """`kda_mix` for a decode step: one token a row, every row updated
+    where its state rests (`kda_step_slots`: no [B, H, d, d] copy of the
+    rows' states exists). pre [B, 3 H d], g [B, H, d], beta [B, H];
+    valid [B]: rows that are live (a finished or padding row is a dead
+    row to the kernel and writes nothing). Returns (state, o [B, H, d]
+    float32)."""
     kda_s, kda_conv = state
-    at = _slot_index(jnp.where(valid, slots, -1), kda_s.shape[1])
+    slots = jnp.where(valid, slots, -1)
+    at = _slot_index(slots, kda_conv.shape[1])
     with jax.named_scope("linattn.conv"):
         tail = kda_conv.at[lk, at].get(mode="clip")
         xp = jnp.concatenate([tail, pre[:, None].astype(tail.dtype)], 1)
         w = lp["kda_conv_w"].astype(jnp.float32)
         y = jnp.sum(w[None] * xp.astype(jnp.float32), axis=1)
         q, k, v = _kda_qkv(y, cfg)
-    with jax.named_scope("linattn.step"):
-        s0 = kda_s.at[lk, at].get(mode="clip")
-        o, s1 = kda_step(q, k, v, g, beta, s0)
-        kda_s = kda_s.at[lk, at].set(s1, mode="drop")
         kda_conv = kda_conv.at[lk, at].set(xp[:, 1:], mode="drop")
+    with jax.named_scope("linattn.step"):
+        o, kda_s = kda_step_slots(kda_s, lk, slots, q, k, v, g, beta)
     return (kda_s, kda_conv), o
 
 

@@ -123,6 +123,8 @@ Departures from the published model, each deliberate:
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -228,6 +230,18 @@ def round_to(x, dtype):
     return jax.lax.reduce_precision(x, info.nexp, info.nmant)
 
 
+def kda_recurrence(s, xs, state_dtype=F32):
+    """One token of the gated delta rule: (s [H, dk, dv], (q, k [H, dk],
+    v [H, dv], g [H, dk], b [H])) -> (s', o [H, dv]): decay, the delta
+    against what the decayed state holds at k, read at q."""
+    q_t, k_t, v_t, g_t, b_t = xs
+    s = jnp.exp(g_t)[:, :, None] * s
+    s = s + jnp.einsum("hk,hv->hkv", k_t, b_t[:, None] * (
+        v_t - jnp.einsum("hk,hkv->hv", k_t, s)))
+    s = round_to(s, state_dtype)
+    return s, jnp.einsum("hkv,hk->hv", s, q_t)
+
+
 def attention_kda(x, lp, *, num_heads, head_dim, lower_bound, rms_norm_eps,
                   state_dtype=F32):
     """Kimi Delta Attention as the per-token recurrence. x [T, D], the
@@ -242,16 +256,9 @@ def attention_kda(x, lp, *, num_heads, head_dim, lower_bound, rms_norm_eps,
         * (x @ lp["kda_wf"] + lp["kda_dt_bias"]).reshape(t, h, d))
     beta = jax.nn.sigmoid(x @ lp["kda_wb"])                     # [T, H]
 
-    def step(s, xs):                       # s [H, dk, dv]
-        q_t, k_t, v_t, g_t, b_t = xs
-        s = jnp.exp(g_t)[:, :, None] * s
-        s = s + jnp.einsum("hk,hv->hkv", k_t, b_t[:, None] * (
-            v_t - jnp.einsum("hk,hkv->hv", k_t, s)))
-        s = round_to(s, state_dtype)
-        return s, jnp.einsum("hkv,hk->hv", s, q_t)
-
-    _, o = jax.lax.scan(step, jnp.zeros((h, d, d), F32),
-                        (q, k, v, g, beta))
+    _, o = jax.lax.scan(
+        functools.partial(kda_recurrence, state_dtype=state_dtype),
+        jnp.zeros((h, d, d), F32), (q, k, v, g, beta))
     o = rms_norm(o, lp["kda_o_norm"], rms_norm_eps)
     o = o * jax.nn.sigmoid(x @ lp["kda_wg"]).reshape(t, h, d)
     return o.reshape(t, h * d) @ lp["wo"]

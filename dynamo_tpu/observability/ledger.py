@@ -149,9 +149,15 @@ class LedgerStats:
         # linear-attention layers and their recurrent state, from every
         # step's plan on the host (engine._account_linattn):
         "linattn_tokens_total",        # (token, linear layer) updates
-        "linattn_chunk_tokens_total",  # of those, by the chunkwise form
-        #                                (an _engine_step's rows); the rest
-        #                                by a decode window's one-token form
+        "linattn_chunk_tokens_total",  # of those, the rows of an
+        #                                _engine_step (chunk rows and the
+        #                                one-token rows beside them); the
+        #                                rest rode a decode window
+        "linattn_inplace_updates_total",   # of all of them, those made
+        #                                where the state rests (ops/
+        #                                linear_attention.kda_step_slots):
+        #                                a window's, and a mixed step's
+        #                                one-token rows
         "linattn_state_bytes_total",   # state bytes read + written: live
         #                                rows x linear layers x slot bytes x 2
         "linattn_steps_total",         # device steps the above were summed

@@ -9,9 +9,21 @@ per-token recurrence): per head, S [dk, dv] float32,
 
 with g_t in (lower bound, 0) per channel and b_t in (0, 1).
 
-`kda_step`: one token a row (a decode step). The state is read twice and
-written once: W = S^T [a*q, a*k] (a = exp g) from the old state, d =
-b (v - W_k), S' = a * S + k d^T, o = W_q + (k . q) d.
+`kda_step`: one token a row, the DEFINITION of the one-token form: W =
+S^T [a*q, a*k] (a = exp g) from the old state, d = b (v - W_k), S' =
+a * S + k d^T, o = W_q + (k . q) d. Written the plain way it reads the
+state twice and writes it once, on a copy of the rows' states that the
+caller gathered and scatters back: about six passes over them. The tests
+hold the served form to it, and a backend without the kernel runs it.
+
+`kda_step_slots`: the served form of the same update, addressed by slot
+in the whole leaf [Lk, slots, H, dk, dv], in place. On a TPU one Pallas
+kernel holds a row's heads in VMEM for the whole update (both products
+float32 reductions on the vector unit, no operand rounded), so each live
+slot's state crosses HBM once each way a layer and step, no other slot is
+read or written but the scratch slot that dead rows name, and no [B, H,
+dk, dv] copy exists outside VMEM. Elsewhere (`kda_step_slots_impl`) it is
+`kda_step` on gathered rows, with the same dead rows and scratch slot.
 
 `kda_chunk`: a chunk of T tokens a row at once, in blocks of at most
 `BLOCK` tokens (the WY form of Gated DeltaNet / Kimi Linear). With G the
@@ -41,6 +53,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK = 16
 F32 = jnp.float32
@@ -76,6 +90,121 @@ def kda_step(q, k, v, g, beta, s):
     s = a[..., None] * s + k[..., None] * d[:, :, None, :]
     o = w[:, :, 0] + jnp.sum(k * q, axis=-1, keepdims=True) * d
     return o, s
+
+
+# heads of a row that one grid step of the slot-addressed kernel holds in
+# VMEM (a head's state is dk x dv float32: 64 KB at 128 x 128). Blocks in
+# and out are double-buffered, so hb heads cost 4 x hb x 64 KB of VMEM.
+# PERF.md section 6, PR 34 has the sweep on the chip that chose it.
+STEP_SLOTS_HEADS = 16
+
+
+def kda_step_slots_impl() -> str:
+    """"pallas": the slot-addressed kernel, compiled, on a TPU. "plain":
+    `kda_step` on the rows' states gathered by slot and scattered back,
+    which every backend lowers, elsewhere. ("interpret" runs the kernel's
+    body in the Pallas interpreter: what a CPU test asks for.)"""
+    return "pallas" if jax.default_backend() == "tpu" else "plain"
+
+
+def _step_slots_kernel(hb, lk_ref, slot_ref, fresh_ref, cols_ref, rows_ref,
+                       s_ref, o_ref, s_out_ref):
+    """One row's `hb` heads. cols_ref [1, 1, dk, 3 hb]: a | q | k with dk
+    on the sublanes, a head a lane (what scales a state's ROWS has to be
+    a column; the caller transposes the small operands, nothing here
+    does); rows_ref [1, 3, hb, dv]: v | beta | k . q, the two scalars
+    spread over dv; s_ref, s_out_ref [1, 1, hb, dk, dv]: the same block
+    of the aliased leaf. Each head's state is loaded once, both
+    products are float32 reductions over dk on the vector unit, and the
+    new state is stored once."""
+    del lk_ref, slot_ref
+    fresh = fresh_ref[pl.program_id(0)] != 0
+    cols = cols_ref[0, 0]
+    for i in range(hb):
+        a, q, k = (cols[:, n * hb + i:n * hb + i + 1] for n in range(3))
+        s = s_ref[0, 0, i]
+        sa = a * jnp.where(fresh, 0.0, s)               # diag(a) S
+        kb = jnp.broadcast_to(k, sa.shape)
+        w_q = jnp.sum(q * sa, axis=0, keepdims=True)    # [1, dv]
+        w_k = jnp.sum(kb * sa, axis=0, keepdims=True)
+        v, beta, kq = (rows_ref[0, n, i:i + 1, :] for n in range(3))
+        d = beta * (v - w_k)
+        s_out_ref[0, 0, i] = sa + kb * d
+        o_ref[0, i:i + 1, :] = w_q + kq * d
+
+
+def kda_step_slots(kda_s, lk, slots, q, k, v, g, beta, fresh=None,
+                   impl=None, heads_per_block: int = STEP_SLOTS_HEADS):
+    """`kda_step` where the state rests. kda_s [Lk, S, H, dk, dv] float32:
+    the whole leaf; lk: this layer's index in it (traced); slots [B]
+    int32: each row's slot, -1 for a row that must change nothing (a
+    DEAD row: padding, finished, or one whose tokens another form
+    takes); q, k, g [B, H, dk], v [B, H, dv], beta [B, H] float32 in ROW
+    order; fresh [B] bool: the row starts from zeros whatever its slot
+    holds. -> (o [B, H, dv], kda_s'), the leaf aliased in to out.
+
+    Each live row's slot is read once and written once a call; no other
+    slot of the leaf is touched but the SCRATCH slot, the leaf's last
+    (`models/llama.init_state` makes it; the scheduler never hands it
+    out). An aliased output writes every grid step's block back, and a
+    block's read is pipelined ahead of the step before's write, so a
+    dead row must not name a slot that a live row of the same call
+    updates: every dead row names the scratch slot, with b = 0, g = 0
+    and k = q = v = 0 (an identity update, o = 0), so the scratch slot
+    keeps what it held and what it holds reaches no live row. Two live
+    rows of one call never share a slot (a slot is one sequence's).
+    tests/test_linattn_kernel.py holds all of it."""
+    impl = impl or kda_step_slots_impl()
+    _, n_s, h, dk, dv = kda_s.shape
+    b = slots.shape[0]
+    live = slots >= 0
+    at = jnp.where(live, slots, n_s - 1).astype(jnp.int32)
+    fresh = jnp.zeros((b,), bool) if fresh is None else fresh
+    q, k, v, g = (jnp.where(live[:, None, None], x, 0.0)
+                  for x in (q, k, v, g))
+    beta = jnp.where(live[:, None], beta, 0.0)
+    if impl == "plain":
+        s0 = jnp.where(fresh[:, None, None, None], 0.0, kda_s[lk, at])
+        o, s1 = kda_step(q, k, v, g, beta, s0)
+        # dead rows all name the scratch slot: theirs is dropped
+        return o, kda_s.at[lk, jnp.where(live, slots, n_s)].set(
+            s1, mode="drop")
+    hb = min(heads_per_block, h)
+    assert h % hb == 0, (h, hb)
+    # the operands that scale a state's rows, as columns: [B, H/hb, dk,
+    # a | q | k of hb heads]; and those that scale its columns, as rows
+    cols = jnp.stack([jnp.exp(g), q, k], axis=1).reshape(
+        b, 3, h // hb, hb, dk).transpose(0, 2, 4, 1, 3).reshape(
+        b, h // hb, dk, 3 * hb)
+    kq = jnp.sum(k * q, axis=-1)
+    rows = jnp.stack([v, jnp.broadcast_to(beta[..., None], v.shape),
+                      jnp.broadcast_to(kq[..., None], v.shape)], axis=1)
+
+    def state_block(i, j, lk_ref, slot_ref, fresh_ref):
+        return lk_ref[0], slot_ref[i], j, 0, 0
+
+    state_spec = pl.BlockSpec((1, 1, hb, dk, dv), state_block)
+    o, kda_s = pl.pallas_call(
+        functools.partial(_step_slots_kernel, hb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, h // hb),
+            in_specs=[
+                pl.BlockSpec((1, 1, dk, 3 * hb),
+                             lambda i, j, *_: (i, j, 0, 0)),
+                pl.BlockSpec((1, 3, hb, dv), lambda i, j, *_: (i, 0, j, 0)),
+                state_spec],
+            out_specs=[
+                pl.BlockSpec((1, hb, dv), lambda i, j, *_: (i, j, 0)),
+                state_spec]),
+        out_shape=[jax.ShapeDtypeStruct((b, h, dv), F32),
+                   jax.ShapeDtypeStruct(kda_s.shape, kda_s.dtype)],
+        # operands count the three prefetched scalars: the leaf is the 6th
+        input_output_aliases={5: 1},
+        interpret=impl == "interpret",
+    )(jnp.reshape(lk, (1,)).astype(jnp.int32), at, fresh.astype(jnp.int32),
+      cols, rows, kda_s)
+    return o, kda_s
 
 
 def _unit_lower_inverse(n):

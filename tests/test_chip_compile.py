@@ -4,6 +4,7 @@ show. All such tests live in THIS file, and the topology is described
 inside a fixture, never at import: one process at a time may load the
 TPU's library, and every xdist worker imports every test file.
 """
+import functools
 import re
 
 import jax
@@ -76,3 +77,43 @@ def test_olmoe_expert_layers_compile_for_a_v5e_and_read_the_stack_in_place(
     assert _MOVES.findall(hlo) == []
     sliced = _expert_layers_hlo(one_chip, in_place=False)
     assert _MOVES.findall(sliced) != []
+
+
+# ling-3.0-flash-vl's served state leaf: 7 linear layers, 64 + 3 slots and
+# the scratch slot, 32 heads of 128 x 128 float32; 64 decode rows
+_STATE = (7, 68, 32, 128, 128)
+_STATE_MOVES = re.compile(
+    r"=\s*f32\[(?:7,68|64),32,128,128\]\S*\s+"
+    r"(copy|fusion|gather|scatter|dynamic-slice|dynamic-update-slice)\(")
+
+
+def _state_layers_hlo(one_chip, in_place: bool) -> str:
+    """Optimised HLO of a scan over the linear layers of a decode step
+    that carries the state leaf, as `decode_forward` does
+    (tools/linattn_step_bench.py's program): each layer's one-token
+    update by the slot-addressed kernel (`in_place`), or by `kda_step` on
+    states gathered by slot and scattered back."""
+    from dynamo_tpu.ops import linear_attention as la
+    from tools.linattn_step_bench import gather_form, layers_of
+
+    def arr(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    l, _, h, d, _ = _STATE
+    ops = (arr(l, 64, h, d), arr(l, 64, h, d), arr(l, 64, h, d),
+           arr(l, 64, h, d), arr(l, 64, h))
+    update = functools.partial(la.kda_step_slots, impl="pallas") \
+        if in_place else gather_form
+    return layers_of(update).lower(
+        arr(*_STATE), arr(64, dtype=jnp.int32), ops).compile().as_text()
+
+
+def test_the_state_update_compiles_for_a_v5e_and_moves_no_copy_of_the_leaf(
+        one_chip):
+    """The slot-addressed kernel at the served shape is taken by the
+    chip's compiler, the leaf aliased through it (no op copies, gathers
+    or scatters the leaf or a [rows, 32, 128, 128] slice of it); the
+    gather / update / scatter form is caught doing so."""
+    hlo = _state_layers_hlo(one_chip, in_place=True)
+    assert hlo.count("tpu_custom_call") >= 1
+    assert _STATE_MOVES.findall(hlo) == []
+    assert _STATE_MOVES.findall(_state_layers_hlo(one_chip, False)) != []

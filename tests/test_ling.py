@@ -126,8 +126,9 @@ def test_served_logits_match_the_plain_reference(served_f32):
     assert largest < TOL[0] and median < TOL[1], (largest, median)
     assert stats["mixed"] > 0 and stats["windows"] > 0, stats
     # the cache runs over the ONE latent layer, the state over the seven
-    # linear ones, a slot a decode slot and a prefill-batch row
-    slots = ENGINE_KW["max_slots"] + EngineConfig().max_prefill_batch
+    # linear ones, a slot a decode slot and a prefill-batch row, and the
+    # scratch slot of the slot-addressed update (llama.init_state)
+    slots = ENGINE_KW["max_slots"] + EngineConfig().max_prefill_batch + 1
     assert stats["cache"] == {
         "k": ((1, 1, 64, 16, 40), "float32"),
         "kda_s": ((7, slots, 4, 16, 16), "float32"),
@@ -168,11 +169,17 @@ def test_the_share_and_the_state_are_counted(served_f32):
     tokens = sum(n + g - 1 for n, g in REQUESTS)
     assert 7 * tokens <= d["linattn_tokens_total"] <= 7 * (tokens + 12)
     assert 0 < d["linattn_chunk_tokens_total"] < d["linattn_tokens_total"]
+    # updated where the state rests: every token of a window, and none
+    # of these steps' (three rows: no step here splits its rows)
+    assert not llama.kda_mix_splits(ENGINE_KW["max_slots"], 16)
+    assert d["linattn_inplace_updates_total"] \
+        == d["linattn_tokens_total"] - d["linattn_chunk_tokens_total"]
     assert d["linattn_state_bytes_total"] > d[
         "linattn_window_state_bytes_total"] > 0
     assert d["linattn_steps_total"] > d["linattn_window_steps_total"] > 0
     assert {"moe_routed_absent_total", "linattn_tokens_total",
-            "linattn_chunk_tokens_total", "linattn_state_bytes_total",
+            "linattn_chunk_tokens_total", "linattn_inplace_updates_total",
+            "linattn_state_bytes_total",
             "state_slots_used", "state_bytes_per_slot"} \
         <= set(LedgerStats.FIELDS)
 
@@ -310,6 +317,48 @@ def test_a_mixed_step_loses_no_row(bare, chunks):
                                 32)
     np.testing.assert_allclose(cache["kda_s"][:, 0], c_alone["kda_s"][:, 2],
                                atol=2e-5)
+
+
+@pytest.mark.parametrize("impl", ["plain", "interpret"])
+def test_a_full_mixed_step_updates_every_row_once(bare, monkeypatch, impl):
+    """The served mixed step: 61 decode rows beside 3 chunk rows, one a
+    continued chunk, one a FRESH chunk over a slot that held another
+    sequence's state, one a fresh ONE-token chunk. The one-token rows are
+    updated where their state rests (`kda_step_slots`, dead to the
+    chunkwise groups), the others by `kda_chunk` (dead to the kernel):
+    every row's logits are the recurrence's and every slot holds what its
+    own tokens leave alone, so the split loses no row and updates none
+    twice. Once in the form a CPU takes and once with the kernel's body
+    in the Pallas interpreter."""
+    monkeypatch.setattr(la, "kda_step_slots_impl", lambda: impl)
+    params, toks, want = bare
+    cache = {**llama.init_cache(TINY, 256, 16), **llama.init_state(TINY, 64)}
+    had = [(5, 16, 17, 31)[i % 4] for i in range(61)]
+    first = [(0, m) for m in had] + [(0, 9), (0, 30), None]
+    got, cache = _grid_step(params, cache, toks, first, 32)
+    for (_, m), out in zip(first[:63], got):
+        assert np.abs(out - want[:m]).max() < TOL[0], m
+    # slot 62 held 30 tokens of a finished sequence: the fresh chunk in
+    # it starts from zeros; slot 63 was never used
+    second = [(m, 1) for m in had] + [(9, 17), (0, 20), (0, 1)]
+    got, cache = _grid_step(params, cache, toks, second, 32)
+    for (pos, n), out in zip(second, got):
+        assert np.abs(out - want[pos:pos + n]).max() < TOL[0], (pos, n)
+    # ... to 1e-4: alone they rode ONE chunk, here a chunk and a token
+    # or two chunks (2.4e-5 read); a token lost or doubled moves a state
+    # by a tenth
+    ends = [pos + n for pos, n in second]
+    alone = {}
+    for end in sorted(set(ends)):
+        _, c = _bare_step(params, _fresh_cache(), toks, 0, end, 32)
+        alone[end] = c
+    for name in ("kda_s", "kda_conv"):
+        for slot, end in enumerate(ends):
+            np.testing.assert_allclose(
+                cache[name][:, slot], alone[end][name][:, 2], atol=1e-4,
+                err_msg=f"{name} slot {slot} after {end} tokens")
+        # the scratch slot is no row's: dead rows left it as it was
+        assert not np.asarray(cache[name][:, 64]).any()
 
 
 def test_a_reused_slot_starts_from_zeros_and_padding_rows_write_nothing(

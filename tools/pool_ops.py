@@ -194,9 +194,11 @@ def _devices():
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     # code that asks jax.default_backend() sees the CPU here: take the
-    # branch the chip takes (ops/moe.py's grouped matmul kernel)
-    from dynamo_tpu.ops import moe
+    # branch the chip takes (ops/moe.py's grouped matmul kernel, ops/
+    # linear_attention.py's slot-addressed state update)
+    from dynamo_tpu.ops import linear_attention, moe
     moe.grouped_matmul_impl = lambda: "gmm"
+    linear_attention.kda_step_slots_impl = lambda: "pallas"
     return (list(topo.devices),
             f"described {topo.devices[0].device_kind} (v5e:2x2), no chip")
 
