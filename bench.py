@@ -1625,7 +1625,8 @@ def run_phases(st: Run) -> None:
     # windows committed while a follow-up executed on device, how many
     # reconciliation fallbacks, and whether steady-state windows really
     # stayed plan-upload-free — the attribution companion to the tok/s
-    # number (the full phase split comes from tools/decode_profile.py)
+    # number (the phase split of every call is the StepLedger's record:
+    # engine.ledger.calls(), `llm_engine_host_*_seconds` on /metrics)
     st.result["extras"]["decode_pipeline"] = {
         "depth": engine.cfg.pipeline_depth,
         "windows": engine.decode_windows,

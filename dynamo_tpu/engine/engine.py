@@ -231,16 +231,17 @@ class NativeEngine:
         self._mixed_rp_cache = RepPenaltyCache()
         # host-loop phase attribution, one vocabulary on every step kind
         # (plan / upload / dispatch / wait / commit; observability/metrics
-        # PhaseTimer; tools/decode_profile.py and the llm_engine_host_*
-        # gauges read it). profile_sync=True makes a window's dispatch
-        # block until the device finishes, so `wait` isolates device time
-        # from the output fetch — attribution harness mode only, it
-        # defeats the pipeline's overlap
+        # PhaseTimer; the llm_engine_host_* gauges and each call's record
+        # in the StepLedger read it)
         from dynamo_tpu.observability.metrics import PhaseTimer
         self.phases = PhaseTimer()
         # perf_counter at the last step()'s return, None while idle:
         # step() charges the gap to `between` (note_idle resets it)
         self._t_step_exit: Optional[float] = None
+        # the caller's marks inside that gap (note_between), and the
+        # program key of the step() call in progress (_dispatch_phase)
+        self._between_marks: Optional[tuple] = None
+        self._call_key: Optional[tuple] = None
         # requests that have not sampled a first token yet:
         # request_id -> [t_add, t_first_planned | None, steps, trace]
         # (the engine-side split of first-token time, _mark_planned)
@@ -276,7 +277,6 @@ class NativeEngine:
         # recorder; branch-only when tracing is disabled) and as
         # `engine.<phase>` annotations in a profiler capture
         self.phases.trace_scope = "engine"
-        self.profile_sync = False
         # pipeline occupancy counters (EngineMetrics / /metrics gauges)
         self.decode_windows = 0       # windows dispatched via the window path
         self.decode_dispatches = 0    # device program launches in decode
@@ -706,12 +706,22 @@ class NativeEngine:
         if self._draft is not None:
             self._draft.forget(request_id)
         self._first_token_marks.pop(request_id, None)
+        self.ledger.forget(request_id)
         return self.scheduler.abort(request_id)
 
     def note_idle(self) -> None:
         """The caller's loop is about to sleep for lack of work: the time
         until the next step() is idleness, not host time between steps."""
         self._t_step_exit = None
+
+    def note_between(self, t_resumed: float, t_emitted: float,
+                     t_applied: float) -> None:
+        """The caller's loop marks the time since the last step()
+        returned (`time.perf_counter()`): its coroutine running again,
+        the end of its loop body, its staged ops applied. The next
+        step() splits `between` at them (`host_resume_seconds`,
+        `_emit_`, `_apply_pending_`, `_submit_`: llm/worker.py)."""
+        self._between_marks = (t_resumed, t_emitted, t_applied)
 
     def close(self) -> None:
         """Release background resources (host-tier copy + pool publish
@@ -749,14 +759,30 @@ class NativeEngine:
 
         Every step kind passes through the same five host phases (plan,
         upload, dispatch, wait, commit: PhaseTimer), flat and contiguous;
-        the time since the previous step() returned is `between`."""
+        the time since the previous step() returned is `between`. The
+        call's record (its kind and bucket, entry and exit, its own
+        phases, `between` and its parts) goes to the StepLedger."""
+        t_entry = time.perf_counter()
+        between, parts = 0.0, (0.0, 0.0, 0.0, 0.0)
+        marks, self._between_marks = self._between_marks, None
         if self._t_step_exit is not None:
-            self.phases.add("between",
-                            time.perf_counter() - self._t_step_exit)
+            between = t_entry - self._t_step_exit
+            self.phases.add("between", between, self._t_step_exit)
+            if marks is not None:
+                # four parts that sum to `between` by construction
+                parts = (marks[0] - self._t_step_exit, marks[1] - marks[0],
+                         marks[2] - marks[1], t_entry - marks[2])
+                self.ledger.split_between(parts)
+        self._call_key = None
         try:
             return self._step()
         finally:
-            self._t_step_exit = time.perf_counter()
+            self._t_step_exit = t_exit = time.perf_counter()
+            key = self._call_key
+            self.ledger.close_call(
+                "decode" if key and key[0] in ("window", "ppwindow")
+                else "", self._key_bucket(key), t_entry, t_exit, between,
+                parts, self.phases.take_call())
 
     def _step(self) -> List[StepOutput]:
         if self._pipeline is not None:
@@ -874,11 +900,23 @@ class NativeEngine:
         annotated `engine.compile` (a trace then names the step that
         stalled), logged once with its seconds, and counted as a
         recompile on the next ledger sample."""
+        self._call_key = key
         if key in self._seen_programs:
             return self.phases.phase("dispatch")
         self._seen_programs.add(key)
         self._pending_recompiles += 1
         return self._first_dispatch(key)
+
+    # where a program key holds its bucket: the `[Bb, Tb]` grid of an
+    # `_engine_step` or a verify block, a decode window's rung
+    _BUCKET_AT = {"step": 4, "window": 5, "ppwindow": 3, "verify": 1}
+
+    @classmethod
+    def _key_bucket(cls, key: Optional[tuple]):
+        """The bucket of a (program, bucket-shape) key, for the call's
+        record in the StepLedger; None where the call dispatched none."""
+        at = cls._BUCKET_AT.get(key[0]) if key else None
+        return key[at] if at is not None else None
 
     @contextlib.contextmanager
     def _first_dispatch(self, key: tuple):
@@ -924,7 +962,8 @@ class NativeEngine:
         deferred-recorder discipline the ledger's overhead contract and
         the decode hot-path region both require. `stream_kw` carries a
         streamed step's window-pool deltas (stream_hit/late/spilled/
-        stalls) and an `_engine_step`'s `dense` rows straight through to
+        stalls), an `_engine_step`'s `dense` rows, a window's
+        `dev_steps` and the commit's `events` straight through to
         record_step."""
         if not self.ledger.enabled:
             return
@@ -965,7 +1004,8 @@ class NativeEngine:
         self._ledger_record(
             "stream", 1, 1, 1 if tok is not None else 0, 1,
             stream_hit=st1[0] - st0[0], stream_late=st1[1] - st0[1],
-            stream_spilled=st1[2] - st0[2], stream_stalls=st1[3] - st0[3])
+            stream_spilled=st1[2] - st0[2], stream_stalls=st1[3] - st0[3],
+            events=events)
         return events
 
     def _run_device_step(self, plan, reqs, mixed: bool = False):
@@ -1133,7 +1173,7 @@ class NativeEngine:
             "prefill", len(plan.seqs),
             sum(1 for s in plan.seqs if s is not None),
             sum(plan.n_valid), int(plan.tokens.size),
-            dense=self._dense_rows(plan))
+            dense=self._dense_rows(plan), events=events)
         return events
 
     def _run_mixed(self, plan: MixedPlan) -> List[StepOutput]:
@@ -1198,7 +1238,7 @@ class NativeEngine:
             "mixed", len(plan.seqs),
             sum(1 for s in plan.seqs if s is not None),
             sum(plan.n_valid), int(plan.tokens.size),
-            dense=self._dense_rows(plan))
+            dense=self._dense_rows(plan), events=events)
         return events
 
     def _run_decode(self, plan: DecodePlan) -> List[StepOutput]:
@@ -1330,7 +1370,7 @@ class NativeEngine:
                             fused, nw, len(plan.seqs),
                             plan.page_table.shape[1], base_pb,
                             plan.stop_ids.shape[1]),
-                # per-window attribution tag (tools/decode_profile.py):
+                # per-window attribution tag (`decode_kernel_tag`):
                 # which attention path + sampling tail this window's one
                 # device program runs
                 "tag": (("gather" if pregather else "ragged")
@@ -1412,19 +1452,10 @@ class NativeEngine:
         if staged.get("linattn"):
             self._account_linattn(*staged["linattn"])
         # one window == one device program launch: attention (ragged
-        # kernel or gather) + sampling tail all inside it. The counter is
-        # the DECODE_PROFILE.jsonl dispatch-count evidence — dispatches /
+        # kernel or gather) + sampling tail all inside it: dispatches /
         # windows must hold at exactly 1.0 on the common path
         self.decode_dispatches += 1
         self.decode_kernel_tag = staged.get("tag", "")
-        if self.profile_sync:
-            # attribution harness mode (tools/decode_profile.py): this
-            # `wait` is the device's execution, the one in
-            # _fetch_and_commit then only the fetch; serving never sets it
-            with self.phases.phase("wait"):
-                # dynalint: sync-point(profile_sync attribution mode only)
-                jax.block_until_ready(outs)
-            self.phases.device_busy = False
         return outs, nxt
 
     def _fetch_and_commit(self, plan: DecodePlan, outs,
@@ -1589,6 +1620,8 @@ class NativeEngine:
         pend, self._pipeline = self._pipeline, None
         self.step_count += 1
         plan, staged = pend["plan"], pend["staged"]
+        # this call commits the staged window, whether or not it chains
+        self._call_key = staged["program"]
         with self.phases.phase("plan"):
             self._process_offloads()
             self._process_onboards()
@@ -1837,7 +1870,7 @@ class NativeEngine:
         self._ledger_record(
             "spec", s_count,
             sum(1 for s in plan.seqs if s is not None),
-            len(events), s_count * kp1)
+            len(events), s_count * kp1, events=events)
         return events
 
     def _commit_window(self, plan: DecodePlan, toks: np.ndarray, lps=None,
@@ -1888,7 +1921,8 @@ class NativeEngine:
         # every (step, slot) pair of the window; useful = tokens that
         # actually committed (post-finish tail + padding rows = waste)
         self._ledger_record("decode", len(plan.seqs), n_live,
-                            len(events), n_steps * len(plan.seqs))
+                            len(events), n_steps * len(plan.seqs),
+                            dev_steps=n_steps, events=events)
         return events
 
     def _run_decode_pp(self, plan: DecodePlan) -> List[StepOutput]:
@@ -1941,7 +1975,7 @@ class NativeEngine:
             else:
                 events.append(self._postprocess(seq, seq.output[-1]))
         self._ledger_record("decode", len(plan.seqs), len(events),
-                            len(events), len(plan.seqs))
+                            len(events), len(plan.seqs), events=events)
         return events
 
     def _postprocess(self, seq: SequenceState, tok: int,

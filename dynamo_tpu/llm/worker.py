@@ -15,7 +15,11 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import glob
+import json
 import logging
+import os
+import time
 from typing import AsyncIterator, Dict, Optional
 
 from jax.profiler import TraceAnnotation
@@ -167,7 +171,9 @@ class NativeEngineWorker(AsyncEngine):
         JAX trace is process-global). The capture holds the engine's
         `engine.<phase>` and the loop's `worker.*` annotations next to
         the device's lines; benchmark/harness/trace_reduce.py reduces it.
-        Returns `out_dir`."""
+        Beside the `*.xplane.pb` it leaves `steps.jsonl`, the engine's
+        step() records of the captured stretch on the trace's own clock
+        (`_write_step_records`). Returns `out_dir`."""
         if self._capturing:
             raise RuntimeError("a profiler capture is already running")
         import jax
@@ -176,15 +182,71 @@ class NativeEngineWorker(AsyncEngine):
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             jax.profiler.start_trace(out_dir, profiler_options=opts)
+            t_start = time.perf_counter()
             try:
                 await asyncio.sleep(seconds)
             finally:
+                # one instant on both clocks: perf_counter, which times
+                # the records, and the wall clock, which the trace's
+                # host events count from the trace's own start
+                anchor = (time.perf_counter_ns(), time.time_ns())
                 await asyncio.get_running_loop().run_in_executor(
-                    None, jax.profiler.stop_trace)
+                    None, self._stop_capture, out_dir, t_start, anchor)
         finally:
             self._capturing = False
         log.info("jax profiler: %.1fs captured to %s", seconds, out_dir)
         return out_dir
+
+    def _stop_capture(self, out_dir: str, t_start: float,
+                      anchor: tuple) -> None:
+        import jax
+        jax.profiler.stop_trace()
+        try:
+            self._write_step_records(out_dir, t_start, anchor)
+        except Exception:  # the capture itself is whole without them
+            log.exception("no steps.jsonl beside the capture")
+
+    def _write_step_records(self, out_dir: str, t_start: float,
+                            anchor: tuple) -> None:
+        """`steps.jsonl` beside the capture's `*.xplane.pb`: a first line
+        with the anchor (one instant as `perf_counter_ns` and as
+        `trace_ns`, the clock the xplane's host events carry:
+        nanoseconds since the `profile_start_time` its `Task
+        Environment` plane states), then the StepLedger's record of
+        every step() call whose period overlaps the capture, its
+        `t_entry` / `t_exit` and each phase's start converted to
+        `trace_ns`. A gap of the device can then be put down to the kind
+        and bucket of the calls around it and to the part of `between`
+        it fell in, which no annotation can say (an annotation may not
+        cross an `await`)."""
+        from jax.profiler import ProfileData
+        path = sorted(glob.glob(os.path.join(
+            out_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+        zero = next(
+            int(value) for plane in ProfileData.from_file(path).planes
+            if plane.name == "Task Environment"
+            for name, value in plane.stats if name == "profile_start_time")
+        perf_ns, wall_ns = anchor
+        shift = wall_ns - zero - perf_ns
+
+        def trace_ns(t: float) -> int:
+            return round(t * 1e9) + shift
+
+        lines = [{"anchor": {"perf_counter_ns": perf_ns,
+                             "trace_ns": perf_ns + shift},
+                  "capture_ns": [trace_ns(t_start), perf_ns + shift]}]
+        for rec in self.engine.ledger.calls(t_start, perf_ns / 1e9):
+            for key in ("ts", "dt", "tok_s", "mfu"):
+                del rec[key]
+            rec["t_entry_ns"] = trace_ns(rec.pop("t_entry"))
+            rec["t_exit_ns"] = trace_ns(rec.pop("t_exit"))
+            rec["phases"] = {
+                name: {"start_ns": trace_ns(t0), "seconds": dt}
+                for name, (t0, dt) in rec["phases"].items()}
+            lines.append(rec)
+        with open(os.path.join(os.path.dirname(path), "steps.jsonl"),
+                  "w") as f:
+            f.writelines(json.dumps(line) + "\n" for line in lines)
 
     async def stop(self) -> None:
         if self._loop_task:
@@ -232,11 +294,15 @@ class NativeEngineWorker(AsyncEngine):
 
     async def _step_loop(self) -> None:
         loop = asyncio.get_running_loop()
+        # perf_counter marks of the time since the last step() returned:
+        # this coroutine running again, and the end of the loop body
+        t_resumed = t_emitted = 0.0
         while True:
             # the synchronous stretches between two steps are annotated for
             # a profiler capture, never across an await
             with TraceAnnotation("worker.apply_pending"):
                 self._apply_pending()
+            t_applied = time.perf_counter()
             if not self.engine.has_work():
                 self._wake.clear()
                 if not self._pending_adds and not self._pending_ops:
@@ -248,6 +314,10 @@ class NativeEngineWorker(AsyncEngine):
                     except asyncio.TimeoutError:
                         pass
                 continue
+            # the engine splits the time between two steps at the three
+            # marks (llm_engine_host_resume_ / _emit_ / _apply_pending_ /
+            # _submit_seconds); after a sleep it charges none (note_idle)
+            self.engine.note_between(t_resumed, t_emitted, t_applied)
             try:
                 outputs = await loop.run_in_executor(None, self.engine.step)
             except Exception:
@@ -259,7 +329,9 @@ class NativeEngineWorker(AsyncEngine):
                 # requests staged during the failing step have no consumer
                 # anymore — drop them so they never occupy an engine slot
                 self._pending_adds.clear()
+                t_resumed = t_emitted = time.perf_counter()
                 continue
+            t_resumed = time.perf_counter()
             # frame fan-out and the metrics snapshot, on the event loop
             # that also serves the sockets
             with TraceAnnotation("worker.emit"):
@@ -295,6 +367,7 @@ class NativeEngineWorker(AsyncEngine):
                 pev = pool.drain_events(self.engine.kv_pool_source)
                 if pev:
                     await self._pool_publisher.publish_allocator_events(pev)
+            t_emitted = time.perf_counter()
 
     # -- AsyncEngine ----------------------------------------------------------
 

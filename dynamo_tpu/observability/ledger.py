@@ -16,24 +16,35 @@ runtime/tracing.py `defer_phase`):
   scheduler/allocator state the commit path already holds (allocator
   free counts, plan array shapes, deque lengths) — the ledger never
   touches a jax array;
-- **disabled path is branch-only**: `record_step()` is one `if` when
-  off (`DYN_LEDGER=0`), so the decode pipeline's hot-path region pays
-  nothing and stays token-identical either way (it is token-identical
+- **disabled path is branch-only**: `record_step()`, `split_between()`
+  and `close_call()` are one `if` each when off (`DYN_LEDGER=0`), so
+  the decode pipeline's hot-path region pays nothing and stays
+  token-identical either way (it is token-identical
   with the ledger ON too — the ledger only reads, tested in
   tests/test_decode_pipeline.py);
 - **bounded**: the ring overwrites oldest samples (`samples_dropped`
   counted), so a week of serving cannot grow memory.
 
-The ledger is ON by default (like PhaseTimer): one tuple append plus
-~20 plain attribute bumps per device step, at most a few thousand
-steps/s — unmeasurable next to a forward pass. `DYN_LEDGER=0` turns
-even that off.
+The ledger is ON by default (like PhaseTimer): per step() call one list
+append, ~30 plain attribute bumps and float adds, and per committed
+stream one dict read and write and two adds — tens of microseconds
+next to a forward pass (PERF.md section 6, PR 35 has the measurement).
+`DYN_LEDGER=0` turns even that off.
+
+A sample is the record of one `step()` CALL (ISSUE 35): the commit site
+records the step it committed (`record_step`), and the engine closes the
+call at `step()`'s end with the call's own clock (`close_call`). A call
+that only primed or chained the decode pipeline and committed nothing
+has a record too, of the kind it dispatched (`calls()` lists it;
+`drain()`, the per-step export, keeps to the calls that committed).
 
 Per-step sample schema (one JSONL record per step after `drain()`):
     {"ts", "dt", "kind", "rows", "rows_live", "tokens_useful",
      "tokens_padded", "kv_used", "kv_total", "host_used", "host_total",
      "disk_used", "disk_total", "waiting", "recompiles", "stream_hit",
-     "stream_late", "stream_spilled", "stream_stalls", "tok_s", "mfu"}
+     "stream_late", "stream_spilled", "stream_stalls", "tok_s", "mfu",
+     "dev_steps", "streams", "tokens", "bucket", "t_entry", "t_exit",
+     "between", "resume", "emit", "apply_pending", "submit", "phases"}
 `kind` is the step kind ("prefill" | "decode" | "mixed" | "spec" |
 "stream" — the last is a tiered-KV streamed long-context step, whose
 stream_* columns carry that step's window-pool prefetch deltas);
@@ -44,6 +55,16 @@ scheduler's budget pay. What the token-wise layers ran over is the
 cumulative `tokens_dense` (LedgerStats), not a column of the sample.
 `recompiles` counts NEW (program, bucket)
 keys first seen at this step's dispatch (an XLA compile stall).
+The call's fields: `dev_steps` device steps of the program it committed
+(a window's rung, else 1; 0 where it committed none), `streams` that
+got a token and `tokens` committed, `bucket` of its program (`[Bb, Tb]`
+of an `_engine_step`, a window's rung), `t_entry` / `t_exit` of the
+call (`time.perf_counter()`), `between` (the time since the call
+before returned, while the engine had work) and its four parts as the
+worker's loop marks them (`resume`, `emit`, `apply_pending`, `submit`:
+llm/worker.py `_step_loop`), and `phases`, name -> [start, seconds] of
+the PhaseTimer phases this call ran. `between` + `t_exit` - `t_entry`
+is the call's PERIOD: over a busy stretch the periods tile the wall time.
 
 docs/OBSERVABILITY.md §5 documents the gauge catalog and the fleet
 rollup (observability/fleet.py) that consumes the per-worker fields.
@@ -167,6 +188,42 @@ class LedgerStats:
         "linattn_window_steps_total",        # windows alone
         "state_slots_used",       # gauge: recurrent-state slots held
         "state_bytes_per_slot",   # gauge: bytes of one slot, all layers
+        # one record a step() call (close_call), folded by kind. A call's
+        # period = the `between` before it + its time inside step(); a
+        # pipelined window's is the call that commits it, a call that
+        # only dispatched one is of the kind `decode` too
+        "period_mixed_seconds",   # calls that committed a mixed step
+        "period_decode_seconds",  # calls that committed or only
+        #                           dispatched a decode window
+        "period_other_seconds",   # prefill, spec, stream, and calls
+        #                           that found nothing to run
+        "period_seconds",         # all: the busy wall time
+        "window_steps_total",     # device steps of committed windows
+        #                           (`steps_decode` counts the windows)
+        # `host_between_seconds` in four parts, by the marks of the
+        # worker's loop (llm/worker.py _step_loop); they sum to it
+        "host_resume_seconds",    # step() exit -> the coroutine running
+        #                           again: the future's way back through
+        #                           the event loop
+        "host_emit_seconds",      # -> the end of the loop body: frame
+        #                           fan-out, metrics snapshot, event plane
+        "host_apply_pending_seconds",  # -> after _apply_pending
+        "host_submit_seconds",    # -> step() entry: the executor's pick-up
+        "host_exposed_between_seconds",  # of `between`, the part with no
+        #                           program in flight (PhaseTimer.add)
+        # what made each gap between two commits to one stream
+        # (_account_gaps): the gap before the FIRST token a commit gives
+        # a stream goes whole to one class, by the programs committed
+        # since the stream's last commit
+        "gap_mixed_seconds",      # one program since: a mixed step
+        "gap_mixed_total",
+        "gap_window_seconds",     # one program since: a decode window of
+        "gap_window_total",       # any rung (or a verify / streamed step)
+        "gap_multi_seconds",      # two or more: the stream sat out a
+        "gap_multi_total",        # program, or its commit was deferred
+        "gap_total",              # the three classes' counts
+        "gap_burst_total",        # further tokens of one commit: they
+        #                           reach the client ~0 ms apart, no seconds
     )
 
     def __init__(self):
@@ -241,6 +298,20 @@ def sampler_flops_per_token(cfg) -> float:
 
 _KINDS = ("prefill", "decode", "mixed", "spec", "stream")
 
+# one ring record: the committed step's sample (record_step), then the
+# call's own fields (close_call; _NO_CALL until the call is closed, and
+# for a sample recorded outside a step() call)
+_KEYS = ("ts", "dt", "kind", "rows", "rows_live", "tokens_useful",
+         "tokens_padded", "kv_used", "kv_total", "host_used",
+         "host_total", "disk_used", "disk_total", "waiting",
+         "recompiles", "stream_hit", "stream_late", "stream_spilled",
+         "stream_stalls", "tok_s", "mfu", "dev_steps", "streams",
+         "tokens", "bucket", "t_entry", "t_exit", "between", "resume",
+         "emit", "apply_pending", "submit", "phases")
+_DEV_STEPS = _KEYS.index("dev_steps")
+_CALL = _KEYS.index("bucket")
+_NO_CALL = (None, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0, None)
+
 
 class StepLedger:
     """The bounded per-step sample ring + gauge fold for one engine.
@@ -267,9 +338,16 @@ class StepLedger:
         self.flops_per_token = float(flops_per_token)
         self.peak_flops = float(
             os.environ.get("DYN_PEAK_TFLOPS", "0")) * 1e12
-        self._recs: List[tuple] = []
+        self._recs: List[list] = []
         self._pos = 0
         self.dropped = 0
+        # the sample record_step appended in the step() call in progress,
+        # until close_call gives it the call's clock
+        self._open: Optional[list] = None
+        # request_id -> (perf_counter of its last commit, programs
+        # committed by then): what made each gap (_account_gaps)
+        self._last_commit: Dict[str, tuple] = {}
+        self._programs = 0
         self._last_ts = 0.0
         self._tok_s = 0.0
         # per-INSTANCE cumulative counters (metrics() reads these; the
@@ -286,7 +364,7 @@ class StepLedger:
             self.enabled = enabled
         if capacity is not None:
             self.capacity = max(1, int(capacity))
-            self._recs, self._pos = [], 0
+            self._recs, self._pos, self._open = [], 0, None
         if peak_tflops is not None:
             self.peak_flops = peak_tflops * 1e12
         return self
@@ -301,16 +379,21 @@ class StepLedger:
                     waiting: int, recompiles: int,
                     stream_hit: int = 0, stream_late: int = 0,
                     stream_spilled: int = 0, stream_stalls: int = 0,
-                    dense: Optional[int] = None) -> None:
-        """Record one committed device step. Every argument is an
-        already-known host int — the disabled path is this one branch.
+                    dense: Optional[int] = None, dev_steps: int = 1,
+                    events=()) -> None:
+        """Record one committed device step. Every argument is
+        already-known host state — the disabled path is this one branch.
         `dense`: the token rows the step's token-wise layers ran over
         where that is less than `padded` (a compact step).
         The stream_* kwargs are this step's window-pool deltas (0 on
         non-streamed kinds); they attribute the prefetch leg per step
-        in the drained JSONL (tools/decode_profile.py)."""
+        in the drained JSONL. `dev_steps`: the device steps the
+        committed program ran (a window's rung). `events`: the commit's
+        StepOutputs, from which the gaps between a stream's commits are
+        classed (_account_gaps)."""
         if not self.enabled:
             return
+        streams, tokens = self._account_gaps(kind, events)
         now = time.monotonic()
         dt = now - self._last_ts if self._last_ts else 0.0
         self._last_ts = now
@@ -320,17 +403,12 @@ class StepLedger:
         mfu = 0.0
         if self.peak_flops > 0.0 and self.flops_per_token > 0.0:
             mfu = self._tok_s * self.flops_per_token / self.peak_flops
-        rec = (now, dt, kind, rows, rows_live, useful, padded,
-               kv_used, kv_total, host_used, host_total,
-               disk_used, disk_total, waiting, recompiles,
-               stream_hit, stream_late, stream_spilled, stream_stalls,
-               self._tok_s, mfu)
-        if len(self._recs) < self.capacity:
-            self._recs.append(rec)
-        else:
-            self._recs[self._pos] = rec
-            self._pos = (self._pos + 1) % self.capacity
-            self.dropped += 1
+        self._open = self._append(
+            [now, dt, kind, rows, rows_live, useful, padded,
+             kv_used, kv_total, host_used, host_total,
+             disk_used, disk_total, waiting, recompiles,
+             stream_hit, stream_late, stream_spilled, stream_stalls,
+             self._tok_s, mfu, dev_steps, streams, tokens, *_NO_CALL])
         self.steps += 1
         self.recompiles_total += recompiles
         self.useful_total += useful
@@ -338,6 +416,8 @@ class StepLedger:
         s = self.stats
         s.steps_total += 1
         setattr(s, "steps_" + kind, getattr(s, "steps_" + kind) + 1)
+        if kind == "decode":
+            s.window_steps_total += dev_steps
         s.recompiles += recompiles
         s.tokens_useful += useful
         s.tokens_padded += padded
@@ -366,7 +446,107 @@ class StepLedger:
         s.stream_stall_steps += stream_stalls
         s.tok_s = self._tok_s
         s.mfu = mfu
-        s.samples_dropped = self.dropped
+
+    def _append(self, rec: list) -> list:
+        if len(self._recs) < self.capacity:
+            self._recs.append(rec)
+        else:
+            self._recs[self._pos] = rec
+            self._pos = (self._pos + 1) % self.capacity
+            self.dropped += 1
+            self.stats.samples_dropped = self.dropped
+        return rec
+
+    def _account_gaps(self, kind: str, events) -> tuple:
+        """Class the gap before the first token this commit gives each
+        stream, by the programs committed since the stream's last commit
+        (this one included): one and a mixed step `mixed`, one and
+        anything else (a decode window of any rung, a verify or streamed
+        step) `window`, two or more `multi`. The gap's seconds go whole
+        to that class, so a stream's classes sum to its last commit less
+        its first; a request's first token is no gap; further tokens of
+        one commit are `burst`, counted without seconds. Returns
+        (streams that got a token, tokens committed)."""
+        now = time.perf_counter()
+        self._programs += 1
+        n_prog = self._programs
+        last = self._last_commit
+        counts: Dict[str, int] = {}
+        done = []
+        for ev in events:
+            if ev.token is not None:
+                counts[ev.request_id] = counts.get(ev.request_id, 0) + 1
+            if ev.finished:
+                done.append(ev.request_id)
+        s = self.stats
+        tokens = 0
+        for rid, n in counts.items():
+            tokens += n
+            prev = last.get(rid)
+            if prev is not None:
+                gap = now - prev[0]
+                if n_prog - prev[1] > 1:
+                    s.gap_multi_seconds += gap
+                    s.gap_multi_total += 1
+                elif kind == "mixed":
+                    s.gap_mixed_seconds += gap
+                    s.gap_mixed_total += 1
+                else:
+                    s.gap_window_seconds += gap
+                    s.gap_window_total += 1
+                s.gap_total += 1
+            last[rid] = (now, n_prog)
+        s.gap_burst_total += tokens - len(counts)
+        for rid in done:
+            last.pop(rid, None)
+        return len(counts), tokens
+
+    def forget(self, request_id: str) -> None:
+        """An aborted request commits no more: drop its last commit."""
+        self._last_commit.pop(request_id, None)
+
+    def split_between(self, parts: tuple) -> None:
+        """The four parts of the `between` a step() call just charged
+        (PhaseTimer.add, at the call's entry: the parts are summed at
+        the same instant, so a scrape finds them equal to it)."""
+        if not self.enabled:
+            return
+        s = self.stats
+        s.host_resume_seconds += parts[0]
+        s.host_emit_seconds += parts[1]
+        s.host_apply_pending_seconds += parts[2]
+        s.host_submit_seconds += parts[3]
+
+    def close_call(self, dispatched: str, bucket, t_entry: float,
+                   t_exit: float, between: float, parts: tuple,
+                   phases: Dict[str, list]) -> None:
+        """The end of one step() call: fold its period into the series of
+        its kind and give its record the call's clock. The kind is the
+        committed step's; `dispatched` ("decode") names a call that
+        committed nothing and primed or chained a window; a call that
+        did neither found nothing to run, adds to `period_other_seconds`
+        and leaves no record. `parts`: the four parts of `between`, for
+        the record (split_between has summed them)."""
+        if not self.enabled:
+            return
+        rec, self._open = self._open, None
+        kind = rec[2] if rec is not None else dispatched
+        s = self.stats
+        period = between + t_exit - t_entry
+        s.period_seconds += period
+        if kind == "mixed":
+            s.period_mixed_seconds += period
+        elif kind == "decode":
+            s.period_decode_seconds += period
+        else:
+            s.period_other_seconds += period
+        if rec is None:
+            if not kind:
+                return
+            rec = self._append(
+                [time.monotonic(), 0.0, kind, *(0,) * 16, self._tok_s,
+                 self.mfu, 0, 0, 0, *_NO_CALL])
+        rec[_CALL:] = (bucket, t_entry, t_exit, between, *parts, phases)
 
     # -- derived figures (engine metrics()) -----------------------------------
 
@@ -390,27 +570,39 @@ class StepLedger:
     # -- export (off the serving path) ----------------------------------------
 
     def __len__(self) -> int:
-        return len(self._recs)
+        """Samples `drain()` would return: the calls that committed."""
+        return sum(1 for rec in self._recs if rec[_DEV_STEPS])
 
-    def drain(self, clear: bool = True) -> List[Dict[str, Any]]:
-        """Collect the ring, oldest first, as JSONL-ready dicts."""
+    def calls(self, t0: float = float("-inf"), t1: float = float("inf"),
+              clear: bool = False) -> List[Dict[str, Any]]:
+        """The ring, oldest first, as JSONL-ready dicts: the whole of it,
+        or, given a stretch [t0, t1] (`time.perf_counter()`), every
+        closed call whose period overlaps it, the ones that committed
+        nothing included. A sample that no call has closed (the call in
+        progress on the engine's thread, a sample recorded outside
+        step()) has no clock and belongs to no stretch."""
         recs = self._recs[self._pos:] + self._recs[:self._pos]
         if clear:
-            self._recs, self._pos = [], 0
-        keys = ("ts", "dt", "kind", "rows", "rows_live", "tokens_useful",
-                "tokens_padded", "kv_used", "kv_total", "host_used",
-                "host_total", "disk_used", "disk_total", "waiting",
-                "recompiles", "stream_hit", "stream_late",
-                "stream_spilled", "stream_stalls", "tok_s", "mfu")
+            self._recs, self._pos, self._open = [], 0, None
+        whole = t0 == float("-inf") and t1 == float("inf")
         out = []
         for rec in recs:
-            d = dict(zip(keys, rec))
+            d = dict(zip(_KEYS, rec))
+            if not whole and (d["phases"] is None
+                              or d["t_entry"] - d["between"] > t1
+                              or d["t_exit"] < t0):
+                continue
             d["ts"] = round(d["ts"], 6)
             d["dt"] = round(d["dt"], 6)
             d["tok_s"] = round(d["tok_s"], 3)
             d["mfu"] = round(d["mfu"], 6)
             out.append(d)
         return out
+
+    def drain(self, clear: bool = True) -> List[Dict[str, Any]]:
+        """Collect the per-step samples, oldest first, as JSONL-ready
+        dicts: the records of the calls that committed a step."""
+        return [d for d in self.calls(clear=clear) if d["dev_steps"]]
 
     def write_jsonl(self, path: str, clear: bool = True) -> int:
         """Append the drained samples to an evidence JSONL under the

@@ -51,8 +51,15 @@ class PhaseTimer:
     any phase but `wait`, and between steps, while it is false is
     `exposed`: the host's own estimate of the device idle it causes.
     `stats`, when set (the engine passes its LedgerStats), receives the
-    same sums as `host_<phase>_seconds`, `host_between_seconds` and
-    `host_exposed_seconds`, which /metrics renders as `llm_engine_*`.
+    same sums as `host_<phase>_seconds`, `host_between_seconds`,
+    `host_exposed_seconds` and, for the exposed part of `between` alone,
+    `host_exposed_between_seconds`, which /metrics renders as
+    `llm_engine_*`.
+
+    `call` holds the phases of the step() call in progress, name ->
+    [start (perf_counter), seconds]: the engine takes it at step()'s end
+    (`take_call`) for the call's record in the StepLedger; the totals
+    above are not touched by that.
     """
 
     PHASES = ("plan", "upload", "dispatch", "wait", "commit")
@@ -65,10 +72,16 @@ class PhaseTimer:
         self.stats = None
         self.device_busy = False
         self.exposed = 0.0
+        self.call: Dict[str, list] = {}
 
-    def add(self, name: str, dt: float) -> None:
+    def add(self, name: str, dt: float, t0: float = 0.0) -> None:
         self.seconds[name] = self.seconds.get(name, 0.0) + dt
         self.counts[name] = self.counts.get(name, 0) + 1
+        mine = self.call.get(name)
+        if mine is None:
+            self.call[name] = [t0, dt]
+        else:
+            mine[1] += dt
         exposed = name != "wait" and not self.device_busy
         if exposed:
             self.exposed += dt
@@ -79,6 +92,14 @@ class PhaseTimer:
                 setattr(s, field, getattr(s, field) + dt)
             if exposed:
                 s.host_exposed_seconds += dt
+                if name == "between":
+                    s.host_exposed_between_seconds += dt
+
+    def take_call(self) -> Dict[str, list]:
+        """The phases of the call that just ended, and a clean slate for
+        the next."""
+        call, self.call = self.call, {}
+        return call
 
     @contextlib.contextmanager
     def phase(self, name: str, annotation: Optional[str] = None):
@@ -96,7 +117,7 @@ class PhaseTimer:
                 yield
         finally:
             dt = time.perf_counter() - t0
-            self.add(name, dt)
+            self.add(name, dt, t0)
             if name == "dispatch":
                 self.device_busy = True
             if self.trace_scope is not None:
@@ -106,17 +127,8 @@ class PhaseTimer:
     def reset(self) -> None:
         self.seconds.clear()
         self.counts.clear()
+        self.call.clear()
         self.exposed = 0.0
-
-    def split(self) -> Dict[str, dict]:
-        """Per-phase {seconds, count, fraction} over the accumulated total."""
-        total = sum(self.seconds.values()) or 1.0
-        return {
-            name: {"seconds": round(s, 6),
-                   "count": self.counts.get(name, 0),
-                   "fraction": round(s / total, 4)}
-            for name, s in sorted(self.seconds.items())
-        }
 
 
 _STAT_FIELD = {name: f"host_{name}_seconds"

@@ -11,7 +11,11 @@
 - `engine.queue` / `engine.prefill` split a request's first-token time
   under its `worker.generate` span, one histogram observation each;
 - every series, field and module a listed layer metric reads exists;
-- the worker's bounded `capture_profile` and its `/debug/profile` route.
+- the worker's bounded `capture_profile` and its `/debug/profile` route;
+- one record a `step()` call (ISSUE 35): periods by kind tile the busy
+  wall time, the worker's marks partition `between`, every gap between
+  a stream's commits goes whole to one class, and `steps.jsonl` puts the
+  records on a capture's own clock.
 """
 import asyncio
 import contextlib
@@ -28,7 +32,7 @@ import pytest
 from dynamo_tpu.engine.config import EngineConfig, ModelConfig
 from dynamo_tpu.engine.engine import NativeEngine
 from dynamo_tpu.engine.scheduler import EngineRequest, SamplingParams
-from dynamo_tpu.observability.ledger import LEDGER_STATS
+from dynamo_tpu.observability.ledger import LEDGER_STATS, LedgerStats
 from dynamo_tpu.observability.metrics import PhaseTimer
 from dynamo_tpu.observability.serving import SERVING
 from dynamo_tpu.runtime.engine import Context
@@ -255,6 +259,279 @@ def test_exposed_excludes_wait_and_overlapped_commits():
     assert piped.pipeline_overlapped > 0
     assert t.exposed < sum(s for n, s in t.seconds.items() if n != "wait")
     assert LEDGER_STATS.host_exposed_seconds >= t.exposed
+
+
+# -- (g) one record a step() call (ISSUE 35) -----------------------------------
+
+def _private_stats(eng):
+    """Give `eng` a LedgerStats of its own, so a test reads its sums and
+    not the process's."""
+    stats = LedgerStats()
+    eng.ledger.stats = eng.phases.stats = stats
+    return stats
+
+
+def _drive_records(eng, arrivals):
+    """Step `eng` to completion on a LedgerStats of its own. Returns the
+    stats, the loop's wall time, the ledger's call records, and per
+    stream the perf_counter of each of its commits with the tokens it
+    got (the clock read is the ledger's own, seen through a shim)."""
+    import dynamo_tpu.observability.ledger as ledger_mod
+    stats = _private_stats(eng)
+    seen = []
+
+    class Clock:
+        monotonic = staticmethod(time.monotonic)
+
+        @staticmethod
+        def perf_counter():
+            seen.append(time.perf_counter())
+            return seen[-1]
+
+    real, ledger_mod.time = ledger_mod.time, Clock
+    commits = {}
+    try:
+        t0 = time.perf_counter()
+        i = 0
+        while eng.has_work() or i in arrivals:
+            if i in arrivals:
+                eng.add_request(arrivals[i])
+            del seen[:]
+            got = {}
+            for ev in eng.step():
+                if ev.token is not None:
+                    got[ev.request_id] = got.get(ev.request_id, 0) + 1
+            assert len(seen) <= 1, "more than one commit in a step() call"
+            for rid, n in got.items():
+                commits.setdefault(rid, []).append((seen[0], n))
+            i += 1
+        wall = time.perf_counter() - t0
+    finally:
+        ledger_mod.time = real
+    return stats, wall, eng.ledger.calls(), commits
+
+
+ARRIVALS = {0: EngineRequest("a", list(range(10, 40)), sampled(30)),
+            3: EngineRequest("b", list(range(50, 90)), sampled(20, 5))}
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    out = {}
+    out["sync"] = _drive_records(make_engine(pipeline_depth=1), ARRIVALS)
+    out["pipelined"] = _drive_records(make_engine(pipeline_depth=2),
+                                      ARRIVALS)
+    with spec_engine() as spec:
+        out["spec"] = _drive_records(spec, {0: EngineRequest(
+            "s", PHRASE * 4, SamplingParams(max_tokens=12,
+                                            temperature=0.0))})
+    # a long prompt's chunks beside one short stream: every gap is a
+    # mixed step's
+    out["mixed-only"] = _drive_records(make_engine(pipeline_depth=1), {
+        0: EngineRequest("a", list(range(10, 30)), sampled(6)),
+        1: EngineRequest("b", [3 + i % 200 for i in range(250)],
+                         sampled(1, 5))})
+    out["window-only"] = _drive_records(make_engine(pipeline_depth=1), {
+        0: EngineRequest("a", list(range(10, 30)), sampled(21))})
+    # no mixed steps: a prefill step between two windows is a program a
+    # running stream sits out
+    out["alternating"] = _drive_records(
+        make_engine(pipeline_depth=1, mixed_token_budget=0), ARRIVALS)
+    return out
+
+
+@pytest.mark.parametrize("run", ["sync", "pipelined", "spec"])
+def test_periods_by_kind_tile_the_busy_wall_time(recorded, run):
+    """Every call's period (the `between` before it + its time inside
+    step()) goes to one kind, and together they are the loop's wall time
+    but for what precedes the first call (tolerance as above)."""
+    stats, wall, calls, _ = recorded[run]
+    by_kind = (stats.period_mixed_seconds + stats.period_decode_seconds
+               + stats.period_other_seconds)
+    assert by_kind == pytest.approx(stats.period_seconds, rel=1e-9)
+    assert stats.period_seconds <= wall
+    assert wall - stats.period_seconds <= 0.03 * wall + 2e-4 * len(calls)
+    # the records tile it too: a call starts where the last one's ended
+    for prev, rec in zip(calls, calls[1:]):
+        assert rec["t_entry"] - rec["between"] == pytest.approx(
+            prev["t_exit"], abs=1e-9)
+    assert sum(r["between"] + r["t_exit"] - r["t_entry"] for r in calls) \
+        == pytest.approx(stats.period_seconds, rel=1e-9)
+    assert stats.period_seconds == pytest.approx(
+        calls[-1]["t_exit"] - calls[0]["t_entry"], rel=1e-9)
+    for kind in ("mixed", "decode"):
+        mine = sum(r["between"] + r["t_exit"] - r["t_entry"]
+                   for r in calls if r["kind"] == kind)
+        assert mine == pytest.approx(
+            getattr(stats, f"period_{kind}_seconds"), rel=1e-9)
+    # each record holds this call's own phases, inside its interval
+    for rec in calls:
+        for name, (start, dt) in rec["phases"].items():
+            if name != "between":
+                assert rec["t_entry"] <= start
+                assert start + dt <= rec["t_exit"] + 1e-9
+        assert rec["between"] == pytest.approx(
+            rec["phases"].get("between", (0, 0.0))[1])
+
+
+def test_a_pipelined_window_is_counted_once(recorded):
+    """A call that only primes or chains the pipeline has a record of
+    the kind it dispatched and commits nothing; the window counts where
+    it commits: windows, device steps and tokens agree with the calls
+    that committed."""
+    stats, _, calls, commits = recorded["pipelined"]
+    primed = [r for r in calls if not r["dev_steps"]]
+    assert primed and all(
+        r["kind"] == "decode" and r["bucket"] == 8 and r["tokens"] == 0
+        and "commit" not in r["phases"] and "dispatch" in r["phases"]
+        for r in primed)
+    windows = [r for r in calls if r["kind"] == "decode" and r["dev_steps"]]
+    assert stats.steps_decode == len(windows)
+    assert stats.window_steps_total == sum(r["dev_steps"] for r in windows)
+    assert stats.steps_total == len(calls) - len(primed)
+    assert sum(r["tokens"] for r in calls) == sum(
+        n for times in commits.values() for _, n in times) == 50
+    mixed = [r for r in calls if r["kind"] == "mixed"]
+    assert mixed and all(r["dev_steps"] == 1 and len(r["bucket"]) == 2
+                         for r in mixed)
+    # the synchronous loop ran the same programs, with no call between
+    sync_stats, _, sync_calls, _ = recorded["sync"]
+    assert all(r["dev_steps"] for r in sync_calls)
+    assert sync_stats.window_steps_total == stats.window_steps_total
+
+
+@pytest.mark.parametrize("run", ["mixed-only", "window-only", "pipelined",
+                                 "sync", "alternating"])
+def test_gap_classes_partition_every_gap(recorded, run):
+    """For every stream the classes' seconds sum to its last commit less
+    its first, each commit after the first is one timed gap, and the
+    other tokens of a commit are `burst`."""
+    stats, _, calls, commits = recorded[run]
+    timed = sum(len(times) - 1 for times in commits.values())
+    spans = sum(times[-1][0] - times[0][0] for times in commits.values())
+    tokens = sum(n for times in commits.values() for _, n in times)
+    classes = ("mixed", "window", "multi")
+    assert stats.gap_total == timed == sum(
+        getattr(stats, f"gap_{c}_total") for c in classes)
+    assert sum(getattr(stats, f"gap_{c}_seconds") for c in classes) \
+        == pytest.approx(spans, rel=1e-9)
+    assert stats.gap_burst_total == tokens - timed - len(commits)
+    assert tokens == sum(r["tokens"] for r in calls)
+    for c in classes:
+        assert (getattr(stats, f"gap_{c}_seconds") > 0) \
+            == (getattr(stats, f"gap_{c}_total") > 0)
+    if run == "mixed-only":
+        assert stats.gap_mixed_total == timed > 0
+    elif run == "window-only":
+        assert stats.gap_window_total == timed > 0
+        assert stats.gap_burst_total > 0
+    elif run == "pipelined":
+        # a priming call is no program of its own: with one a window the
+        # gaps between two windows' commits would all read `multi`
+        assert any(not r["dev_steps"] for r in calls)
+        assert stats.gap_multi_total == 0
+        assert stats.gap_window_total > 0 and stats.gap_mixed_total > 0
+    elif run == "alternating":
+        assert stats.steps_mixed == 0 and stats.gap_multi_total > 0
+
+
+def test_an_aborted_stream_leaves_no_last_commit():
+    eng = make_engine(pipeline_depth=1)
+    _private_stats(eng)
+    eng.add_request(EngineRequest("gone", list(range(10, 30)), sampled(40)))
+    eng.step()
+    assert "gone" in eng.ledger._last_commit
+    eng.abort("gone")
+    assert not eng.ledger._last_commit
+    eng.generate(list(range(40, 60)), sampled(4), "kept")
+    assert not eng.ledger._last_commit
+
+
+def test_a_sample_no_call_has_closed_belongs_to_no_stretch():
+    """A capture's `steps.jsonl` asks for a stretch from another thread
+    than the engine's: the sample of the call in progress there has no
+    clock yet and is left out; the whole ring and `drain()` list it."""
+    from dynamo_tpu.observability.ledger import StepLedger
+    led = StepLedger(capacity=8, enabled=True, stats=LedgerStats())
+    sample = ("mixed", 4, 3, 5, 64) + (0,) * 8
+    led.record_step(*sample)
+    led.close_call("", [4, 16], 10.0, 10.5, 0.25, (0.1, 0.1, 0.03, 0.02),
+                   {"commit": [10.4, 0.05]})
+    led.record_step(*sample)
+    assert [r["phases"] is None for r in led.calls()] == [False, True]
+    assert len(led.drain(clear=False)) == len(led) == 2
+    assert [r["t_exit"] for r in led.calls(9.0, 11.0)] == [10.5]
+    assert [r["t_exit"] for r in led.calls(0.0, 9.8)] == [10.5]    # between
+    assert led.calls(0.0, 9.7) == led.calls(10.6, 11.0) == []
+
+
+def _serve_two(worker):
+    async def main():
+        await worker.start()
+        try:
+            await asyncio.gather(
+                _generate(worker, "p1", list(range(10, 30)), Context("p1"),
+                          max_tokens=12),
+                _generate(worker, "p2", list(range(40, 100)), Context("p2"),
+                          max_tokens=9))
+        finally:
+            await worker.stop()
+    asyncio.run(main())
+
+
+@pytest.mark.parametrize("clock", ["real", "stubbed"])
+def test_the_workers_marks_partition_between(monkeypatch, clock):
+    """Four marks in `_step_loop` split the time between two step()
+    calls: within float rounding on the real clock, and to the digit on
+    a clock that ticks whole seconds."""
+    from dynamo_tpu.llm.worker import NativeEngineWorker
+    eng = make_engine(pipeline_depth=2)
+    eng.generate(list(range(10, 40)), sampled(12), "warm")
+    stats = _private_stats(eng)
+    eng.ledger.calls(clear=True)
+    if clock == "stubbed":
+        import itertools
+        ticks = itertools.count(1000)
+        monkeypatch.setattr(time, "perf_counter",
+                            lambda: float(next(ticks)))
+    _serve_two(NativeEngineWorker(eng))
+    monkeypatch.undo()
+    parts = [stats.host_resume_seconds, stats.host_emit_seconds,
+             stats.host_apply_pending_seconds, stats.host_submit_seconds]
+    assert all(p > 0 for p in parts)
+    assert stats.host_between_seconds > 0
+    if clock == "stubbed":
+        assert sum(parts) == stats.host_between_seconds
+    else:
+        assert sum(parts) == pytest.approx(stats.host_between_seconds,
+                                           rel=1e-9)
+    assert 0 < stats.host_exposed_between_seconds \
+        <= stats.host_between_seconds
+    for rec in eng.ledger.calls():
+        mine = [rec[k] for k in ("resume", "emit", "apply_pending",
+                                 "submit")]
+        assert all(p >= 0 for p in mine)
+        if clock == "stubbed":
+            assert sum(mine) == rec["between"]
+        else:
+            assert sum(mine) == pytest.approx(rec["between"], abs=1e-9)
+
+
+def test_a_bare_step_loop_charges_no_parts():
+    """Without the worker's marks `between` is still counted whole, and
+    after `note_idle` none of it is."""
+    eng = make_engine(pipeline_depth=1)
+    stats = _private_stats(eng)
+    eng.generate(list(range(10, 40)), sampled(12), "a")
+    assert stats.host_between_seconds > 0
+    assert stats.host_resume_seconds == stats.host_submit_seconds == 0
+    eng.note_idle()
+    eng.note_between(1.0, 2.0, 3.0)
+    before = stats.host_between_seconds
+    eng.add_request(EngineRequest("b", list(range(50, 70)), sampled(2)))
+    eng.step()
+    assert stats.host_between_seconds == before
+    assert stats.host_resume_seconds == 0
 
 
 # -- (c) the phases in a profiler capture --------------------------------------
@@ -566,6 +843,81 @@ def test_capture_profile_is_bounded_and_refuses_a_second(tmp_path):
         assert trace_reduce.find_xplane(str(tmp_path / d))
 
 
+@pytest.fixture(scope="module")
+def captured_steps(tmp_path_factory):
+    """A `capture_profile` of a worker serving two requests: the planes
+    of its xplane and the lines of the `steps.jsonl` beside it."""
+    from dynamo_tpu.llm.worker import NativeEngineWorker
+    eng = make_engine(pipeline_depth=2)
+    eng.generate(list(range(10, 40)), sampled(12), "warm")
+    out = str(tmp_path_factory.mktemp("steps"))
+
+    async def main():
+        worker = await NativeEngineWorker(eng).start()
+
+        def serve(tag, shift):
+            # other words of the same lengths, and none that a request
+            # before began with: no prefix to reuse, so both rounds run
+            # the same programs and the capture holds no compile
+            return asyncio.gather(
+                _generate(worker, f"{tag}1", list(range(shift, shift + 20)),
+                          Context(f"{tag}1"), max_tokens=20),
+                _generate(worker, f"{tag}2", list(range(shift, shift + 60)),
+                          Context(f"{tag}2"), max_tokens=12))
+        try:
+            await serve("w", 70)
+            capture = asyncio.create_task(worker.capture_profile(1.0, out))
+            await asyncio.sleep(0.1)
+            await serve("c", 130)
+            await capture
+        finally:
+            await worker.stop()
+    asyncio.run(main())
+    xplane = trace_reduce.find_xplane(out)
+    with open(os.path.join(os.path.dirname(xplane), "steps.jsonl")) as f:
+        lines = [json.loads(line) for line in f]
+    return trace_reduce.load_planes(xplane), lines
+
+
+def test_steps_jsonl_encloses_its_engine_host_events(captured_steps):
+    """On the trace's own clock every record's interval encloses that
+    call's `engine.*` host events within 100 us, no two records
+    overlap, and a phase a record names starts where its event does."""
+    planes, (head, *recs) = captured_steps
+    slack = 100_000
+    anchor = head["anchor"]
+    assert set(anchor) == {"perf_counter_ns", "trace_ns"}
+    lo, hi = head["capture_ns"]
+    assert 0 <= lo < hi == anchor["trace_ns"]
+    assert len(recs) >= 4
+    assert {"mixed", "decode"} <= {r["kind"] for r in recs}
+    for prev, rec in zip(recs, recs[1:]):
+        assert prev["t_entry_ns"] < prev["t_exit_ns"] <= rec["t_entry_ns"]
+    events = [e for _, evs in _engine_events(planes) for e in evs]
+    assert len(events) >= 4 * len(recs) - 8
+    off = []      # a phase's recorded start less its event's
+    for start, end, name in events:
+        home = [r for r in recs if r["t_entry_ns"] - slack <= start
+                and end <= r["t_exit_ns"] + slack]
+        if not home:
+            # only a call the capture cut at either end may have none
+            assert end <= recs[0]["t_entry_ns"] + slack \
+                or start >= recs[-1]["t_exit_ns"] - slack, name
+            continue
+        assert len(home) == 1, (name, start, home)
+        phase = name.split(".", 1)[1]
+        phase = "dispatch" if phase == "compile" else phase
+        # the phase is this call's own (one event a phase and call); its
+        # clock is read just outside the annotation, so the two starts
+        # agree but for a preemption between the two reads
+        off.append(abs(home[0]["phases"][phase]["start_ns"] - start))
+    assert len(off) >= 4 * (len(recs) - 2)
+    assert sorted(off)[len(off) // 2] <= slack
+    # every phase of a call inside the capture has its event
+    assert len(off) >= sum(len(r["phases"]) - ("between" in r["phases"])
+                           for r in recs[1:-1])
+
+
 @pytest.mark.parametrize("env,query,status", [
     (False, "seconds=0.2", 404), (True, "seconds=0.2", 200),
     (True, "seconds=abc", 400), (True, "seconds=600", 400)])
@@ -615,3 +967,29 @@ def test_no_whole_life_profile_hook_is_left():
                     if "jax.profiler.start_trace" in f.read():
                         hits.append(name)
     assert hits == ["worker.py"]
+
+
+def test_the_older_attribution_harness_is_gone():
+    """`tools/decode_profile.py`, its artifact, the engine's
+    `profile_sync` branch and `PhaseTimer.split()` went with ISSUE 35:
+    one record a step() call replaced them, and nothing names them."""
+    assert not hasattr(make_engine(), "profile_sync")
+    assert not hasattr(PhaseTimer, "split")
+    for gone in ("tools/decode_profile.py", "DECODE_PROFILE.jsonl"):
+        assert not os.path.exists(os.path.join(REPO, gone))
+    names = ("decode_profile", "DECODE_PROFILE", "profile_sync")
+    hits = []
+    for top in ("dynamo_tpu", "tools", "docs", "benchmark", "tests",
+                "bench.py", "BASELINE.json", "README.md"):
+        path = os.path.join(REPO, top)
+        files = [path] if os.path.isfile(path) else [
+            os.path.join(root, name) for root, _, found in os.walk(path)
+            for name in found if name.endswith((".py", ".md", ".json"))]
+        for name in files:
+            if os.path.abspath(name) == os.path.abspath(__file__):
+                continue
+            with open(name, errors="replace") as f:
+                text = f.read()
+            hits += [(os.path.relpath(name, REPO), n) for n in names
+                     if n in text]
+    assert hits == []

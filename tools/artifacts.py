@@ -8,7 +8,7 @@ enforced by routing every evidence write through this module:
 - artifacts are written under their FINAL name, directly — never via a
   temp file + rename, never renamed afterwards;
 - multi-run artifacts are append-only JSONL (one JSON record per line,
-  like DECODE_PROFILE.jsonl and real_ckpt_e2e's log): re-runs add
+  like LEDGER_r10.jsonl and real_ckpt_e2e's log): re-runs add
   records, they never rewrite history;
 - single-record artifacts refuse to silently clobber an existing capture
   (pass overwrite=True only when regenerating the same evidence is the
