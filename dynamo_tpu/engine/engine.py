@@ -1030,10 +1030,11 @@ class NativeEngine:
         if self._state_slots:
             small += (plan.state_slots,)
             real = (plan.write_idx >= 0).sum(axis=1)
+            splits = llama.kda_mix_splits(*plan.write_idx.shape)
             self._account_linattn(
                 int(real.sum()), int((plan.state_slots >= 0).sum()),
-                inplace=int((real == 1).sum()) if llama.kda_mix_splits(
-                    *plan.write_idx.shape) else 0)
+                inplace=int((real == 1).sum()) if splits else 0,
+                flat=splits and self._dense_rows(plan) < plan.tokens.size)
         own = ()
         if rp is not None:
             small, own = small + (rp[1],), own + (rp[0],)
@@ -1058,14 +1059,18 @@ class NativeEngine:
         return int(plan.tokens.size)
 
     def _account_linattn(self, tokens: int, rows: int,
-                         window_steps: int = 0, inplace: int = 0) -> None:
+                         window_steps: int = 0, inplace: int = 0,
+                         flat: bool = False) -> None:
         """`llm_engine_linattn_*_total`, from a step's plan on the host:
         the (token, linear layer) state updates of its real tokens,
         which of them rode an `_engine_step` (`window_steps` 0; the
         chunkwise form's, and the one-token rows beside them), which
         were made where the state rests (`kda_step_slots`: every token
         of a decode window, and the `inplace` one-token rows of a step
-        that `llama.kda_mix_splits`), the state bytes its live `rows`
+        that `llama.kda_mix_splits`), whether such a step's linear
+        layers worked over a compact step's `flat` token rows
+        (`llama.kda_mix_rows` where `_dense_rows` is the flat width; else
+        over the grid's), the state bytes its live `rows`
         read and wrote (every touched slot's state, once each way, a
         linear layer and a step), and the device steps that is over. A
         window's bytes and steps are also kept apart, as its experts are
@@ -1076,6 +1081,7 @@ class NativeEngine:
         stats.linattn_tokens_total += tokens * layers
         stats.linattn_inplace_updates_total += layers * (
             tokens if window_steps else inplace)
+        stats.linattn_flat_steps_total += int(flat)
         stats.linattn_state_bytes_total += moved
         stats.linattn_steps_total += window_steps or 1
         if window_steps:

@@ -37,7 +37,7 @@ from dynamo_tpu.ops.attention import (
 from dynamo_tpu.ops.kv_quant import cache_keys
 from dynamo_tpu.ops.linear_attention import BLOCK as KDA_BLOCK
 from dynamo_tpu.ops.linear_attention import (
-    conv_with_tail, kda_chunk, kda_step_slots, l2_normalize,
+    conv_one_token, conv_with_tail, kda_chunk, kda_step_slots, l2_normalize,
 )
 from dynamo_tpu.ops.kv_quant import validate_mode as _validate_kv_quant
 from dynamo_tpu.ops.moe import (
@@ -863,7 +863,8 @@ def _kda_front(x: jax.Array, lp: Params, cfg: ModelConfig):
     3 H d] the q | k | v projections BEFORE their convolution, g [B, T,
     H, d] float32 the per-channel log decay in (lower bound, 0), beta
     [B, T, H] float32). The convolution needs a row's neighbours and the
-    state update its slot: both are the grid's (`kda_mix`)."""
+    state update its slot: both are a ROW's (`kda_mix` on the grid,
+    `kda_mix_rows` over a split step's rows)."""
     b, t = x.shape[:2]
     h, d = cfg.num_heads, cfg.linear_head_dim
     f32 = jnp.float32
@@ -890,9 +891,14 @@ def _kda_qkv(y: jax.Array, cfg: ModelConfig):
     return l2_normalize(q) * d ** -0.5, l2_normalize(k), v
 
 
-# how many of a step's chunk rows `kda_mix` takes through the chunkwise
-# form at a time: the group's size, not a limit on the rows
+# a step of more rows than this is split by what each row holds
+# (`kda_mix_splits`, `kda_mix_rows`); up to it, every row takes the
+# chunkwise form on the grid (`kda_mix`)
 KDA_CHUNK_ROWS = 8
+# how many of a split step's chunk rows `kda_mix_rows` takes through the
+# chunkwise form at a time: the group's size, not a limit on the rows.
+# Chosen on the chip (PERF.md section 6, PR 37)
+KDA_GROUP_ROWS = 4
 
 
 def _slot_index(slots: jax.Array, n_slots: int) -> jax.Array:
@@ -903,31 +909,27 @@ def _slot_index(slots: jax.Array, n_slots: int) -> jax.Array:
 
 def kda_mix_splits(rows: int, tq: int) -> bool:
     """Whether a [rows, tq] step's linear layers split its rows by what
-    each holds (`kda_mix`): the program traces it, the engine's counters
-    read it off the plan."""
+    each holds (`kda_mix_rows`): the program traces it, the engine's
+    counters read it off the plan."""
     return tq > 1 and rows > KDA_CHUNK_ROWS
 
 
 def kda_mix(state: tuple, lk, slots: jax.Array, lp: Params,
             cfg: ModelConfig, pre, g, beta, valid, fresh):
-    """A linear layer's state update for a [B, T] step, between
-    `_kda_front` and `_kda_out`: the causal convolution over each row's
-    tokens (continued from the slot's tail), then the delta rule from
-    the slot's state. state: (kda_s [Lk, slots + 1, H, d, d], kda_conv
-    [Lk, slots + 1, K - 1, 3 H d]), `lk` this layer's index in them;
-    valid [B, T]: real tokens, a prefix of each row; fresh [B]: the row
-    starts its sequence (position 0), so it starts from zeros whatever
-    the slot held: a reused slot needs no clearing. Each touched slot's
-    state is read once and written once; a row without a slot, and a row
-    of padding, write nothing. A step of more than KDA_CHUNK_ROWS rows
-    (`kda_mix_splits`) is split by what each row holds: a row of ONE
-    token (a decode row, a one-token chunk) is updated where it rests
-    (`kda_step_slots`), a row of more takes `kda_chunk`, gathered and
-    scattered KDA_CHUNK_ROWS at a time, whatever the number of either;
-    each kind is a dead row to the other, so no row is updated twice.
-    Returns (state, o [B, T, H, d] float32)."""
+    """A linear layer's state update for a [B, T] step of at most
+    KDA_CHUNK_ROWS rows, on the grid, between `_kda_front` and
+    `_kda_out`: the causal convolution over each row's tokens (continued
+    from the slot's tail), then the delta rule from the slot's state,
+    every row through `kda_chunk`. state: (kda_s [Lk, slots + 1, H, d,
+    d], kda_conv [Lk, slots + 1, K - 1, 3 H d]), `lk` this layer's index
+    in them; valid [B, T]: real tokens, a prefix of each row; fresh [B]:
+    the row starts its sequence (position 0), so it starts from zeros
+    whatever the slot held: a reused slot needs no clearing. Each touched
+    slot's state is read once and written once; a row without a slot,
+    and a row of padding, write nothing. It is also the DEFINITION that
+    the tests hold `kda_mix_rows` to, at any number of rows. Returns
+    (state, o [B, T, H, d] float32)."""
     kda_s, kda_conv = state
-    b, tq = valid.shape
     n_slots = kda_s.shape[1]
     at = _slot_index(slots, n_slots)
     n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
@@ -943,73 +945,134 @@ def kda_mix(state: tuple, lk, slots: jax.Array, lp: Params,
         m = valid[:, :, None, None]
         q, k, v, g = (jnp.where(m, a, 0.0) for a in (q, k, v, g))
         beta = jnp.where(valid[:, :, None], beta, 0.0)
-        if kda_mix_splits(b, tq):
-            # a mixed step: most rows are decode rows with ONE token and
-            # are updated in their slots; the rows with more (chunk
-            # rows, the plan's non-decode rows) take the chunkwise form,
-            # KDA_CHUNK_ROWS of them at a time, for as many groups as
-            # the step holds: one beside a full batch, never a row less
-            # than there are
-            o0, kda_s = kda_step_slots(
-                kda_s, lk, jnp.where(n_valid == 1, slots, -1), q[:, 0],
-                k[:, 0], v[:, 0], g[:, 0], beta[:, 0], fresh)
-            o = jnp.zeros((b, tq) + o0.shape[1:], o0.dtype).at[:, 0].set(o0)
-            groups = -(-b // KDA_CHUNK_ROWS)
-            # longest first; past the last row: read clipped, write dropped
-            order = jnp.pad(jnp.argsort(-n_valid).astype(jnp.int32),
-                            (0, groups * KDA_CHUNK_ROWS - b),
-                            constant_values=b)
-            # a group's rows of one token or none (the last group's
-            # fill) are the kernel's: theirs is dropped here
-            long_row = jnp.where(n_valid > 1, jnp.arange(b), b)
-            long_at = jnp.where(n_valid > 1, at, n_slots)
-
-            def group(j, carry):
-                o, kda_s = carry
-                rows = jax.lax.dynamic_slice_in_dim(
-                    order, j * KDA_CHUNK_ROWS, KDA_CHUNK_ROWS)
-                at_g = long_at.at[rows].get(mode="fill", fill_value=n_slots)
-                s_g = jnp.where(
-                    keep.at[rows].get(mode="clip")[:, None, None, None],
-                    kda_s.at[lk, at_g].get(mode="clip"), 0.0)
-                o_g, s_g = kda_chunk(*(
-                    a.at[rows].get(mode="clip")
-                    for a in (q, k, v, g, beta)), s_g)
-                return (o.at[long_row.at[rows].get(
-                            mode="fill", fill_value=b)].set(o_g, mode="drop"),
-                        kda_s.at[lk, at_g].set(s_g, mode="drop"))
-
-            n_long = jnp.sum(n_valid > 1).astype(jnp.int32)
-            o, kda_s = jax.lax.fori_loop(
-                0, -(-n_long // KDA_CHUNK_ROWS), group, (o, kda_s))
-        else:
-            s0 = kda_s.at[lk, at].get(mode="clip")
-            s0 = jnp.where(keep[:, None, None, None], s0, 0.0)
-            o, s1 = kda_chunk(q, k, v, g, beta, s0)
-            kda_s = kda_s.at[lk, at].set(s1, mode="drop")
+        s0 = kda_s.at[lk, at].get(mode="clip")
+        s0 = jnp.where(keep[:, None, None, None], s0, 0.0)
+        o, s1 = kda_chunk(q, k, v, g, beta, s0)
+        kda_s = kda_s.at[lk, at].set(s1, mode="drop")
     return (kda_s, kda_conv), o
 
 
+class KdaRows(NamedTuple):
+    """What `kda_mix_rows` reads of a step's plan, the same for every
+    linear layer: computed once a program, outside the layer scan
+    (`kda_rows`)."""
+    start: jax.Array    # [B] the token row that holds a row's first token
+    n_valid: jax.Array  # [B] a row's real tokens
+    order: jax.Array    # [B] rows, longest first
+    n_long: jax.Array   # () chunk rows: rows of more than one token
+
+
+def kda_rows(valid: jax.Array, start: jax.Array) -> KdaRows:
+    """valid [B, T]: real tokens, a prefix of each row; start [B]: where
+    in the step's B * T token rows a row's tokens begin. They are
+    CONTIGUOUS there in both layouts a step has: on the grid row r
+    starts at r * T, and a compact step (ops/attention.compact_index)
+    keeps the grid's row-major order, so row r starts at the flat row of
+    its first cell."""
+    n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
+    return KdaRows(start.astype(jnp.int32), n_valid,
+                   jnp.argsort(-n_valid).astype(jnp.int32),
+                   jnp.sum(n_valid > 1).astype(jnp.int32))
+
+
+def kda_mix_rows(state: tuple, lk, slots: jax.Array, lp: Params,
+                 cfg: ModelConfig, x, rows: KdaRows, valid, fresh,
+                 group: int = KDA_GROUP_ROWS):
+    """A linear layer from `_kda_front` to the input of `_kda_out` for a
+    step that `kda_mix_splits`, over the step's ROWS by what each holds,
+    never over its [B, T] grid. x [B * T, D]: the step's token rows in
+    either layout (`kda_rows`); state: (kda_s, kda_conv, o [B * T, H,
+    d] float32), `o` a scratch the layers share: each real token's row
+    is overwritten, no other row is read.
+
+    A row of ONE token (a decode row, a one-token chunk): its token is
+    token row `start`; the front half over [B, D], then what a decode
+    window's step does (`kda_decode`: the convolution against its
+    slot's tail, one tap window a row, `_kda_qkv` over [B, 3 H d], the
+    state updated where it rests).
+    A row of more (a chunk row): `group` of them at a time, for as many
+    groups as the step holds; a group's tokens are T-long runs of token
+    rows from `start` on, masked by `valid`, and the front half, the
+    convolution, the masks and `kda_chunk` run over [group, T, ...]
+    alone; each row's state and tail are gathered and scattered once.
+    Each kind is dead to the other; `fresh`, a row without a slot and a
+    row of padding as in `kda_mix`, which is what the tests hold this
+    to. Returns (state, with o's real rows written)."""
+    kda_s, kda_conv, o = state
+    b, tq = valid.shape
+    n, n_slots = x.shape[0], kda_s.shape[1]
+    keep = ~fresh
+    one = rows.n_valid == 1
+    w = lp["kda_conv_w"]
+    pre, g, beta = _kda_front(
+        x.at[rows.start].get(mode="clip")[:, None], lp, cfg)
+    (kda_s, kda_conv), o1 = kda_decode(
+        (kda_s, kda_conv), lk, slots, lp, cfg, pre[:, 0], g[:, 0],
+        beta[:, 0], one, fresh)
+    o = o.at[jnp.where(one, rows.start, n)].set(o1, mode="drop")
+    long_at = jnp.where(rows.n_valid > 1, _slot_index(slots, n_slots), -1)
+
+    def chunk_group(j, carry):
+        o, kda_s, kda_conv = carry
+        # past the last row (the last group's fill), and a row of one
+        # token or none: read clipped, every write dropped
+        r = rows.order.at[j * group + jnp.arange(group)].get(
+            mode="fill", fill_value=b)
+        at = long_at.at[r].get(mode="fill", fill_value=-1)
+        live = at >= 0
+        at = jnp.where(live, at, n_slots)
+        valid_g = valid.at[r].get(mode="clip") & live[:, None]
+        keep_g = keep.at[r].get(mode="clip")
+        cells = rows.start.at[r].get(mode="clip")[:, None] \
+            + jnp.arange(tq, dtype=jnp.int32)[None, :]
+        pre, g, beta = _kda_front(x.at[cells].get(mode="clip"), lp, cfg)
+        with jax.named_scope("linattn.conv"):
+            tail = kda_conv.at[lk, at].get(mode="clip")
+            y, tail = conv_with_tail(
+                pre, jnp.where(keep_g[:, None, None], tail, 0), w,
+                jnp.sum(valid_g, axis=1).astype(jnp.int32))
+            q, k, v = _kda_qkv(y, cfg)
+            kda_conv = kda_conv.at[lk, at].set(
+                tail.astype(kda_conv.dtype), mode="drop")
+        with jax.named_scope("linattn.chunk"):
+            m = valid_g[:, :, None, None]
+            q, k, v, g = (jnp.where(m, a, 0.0) for a in (q, k, v, g))
+            beta = jnp.where(valid_g[:, :, None], beta, 0.0)
+            s0 = jnp.where(keep_g[:, None, None, None],
+                           kda_s.at[lk, at].get(mode="clip"), 0.0)
+            o_g, s1 = kda_chunk(q, k, v, g, beta, s0)
+            kda_s = kda_s.at[lk, at].set(s1, mode="drop")
+        o = o.at[jnp.where(valid_g, cells, n).reshape(-1)].set(
+            o_g.reshape((group * tq,) + o_g.shape[2:]), mode="drop")
+        return o, kda_s, kda_conv
+
+    o, kda_s, kda_conv = jax.lax.fori_loop(
+        0, -(-rows.n_long // group), chunk_group, (o, kda_s, kda_conv))
+    return kda_s, kda_conv, o
+
+
 def kda_decode(state: tuple, lk, slots: jax.Array, lp: Params,
-               cfg: ModelConfig, pre, g, beta, valid):
-    """`kda_mix` for a decode step: one token a row, every row updated
-    where its state rests (`kda_step_slots`: no [B, H, d, d] copy of the
-    rows' states exists). pre [B, 3 H d], g [B, H, d], beta [B, H];
-    valid [B]: rows that are live (a finished or padding row is a dead
-    row to the kernel and writes nothing). Returns (state, o [B, H, d]
-    float32)."""
+               cfg: ModelConfig, pre, g, beta, valid, fresh=None):
+    """`kda_mix` for one token a row (a decode step's rows, a mixed
+    step's one-token rows), every row updated where its state rests
+    (`kda_step_slots`: no [B, H, d, d] copy of the rows' states exists).
+    pre [B, 3 H d], g [B, H, d], beta [B, H]; valid [B]: rows that are
+    live (a finished or padding row, or one another form takes, is a
+    dead row to the kernel and writes nothing); fresh [B]: the row
+    starts its sequence, from zeros whatever its slot held (a decode
+    step has none). Returns (state, o [B, H, d] float32)."""
     kda_s, kda_conv = state
     slots = jnp.where(valid, slots, -1)
     at = _slot_index(slots, kda_conv.shape[1])
     with jax.named_scope("linattn.conv"):
         tail = kda_conv.at[lk, at].get(mode="clip")
-        xp = jnp.concatenate([tail, pre[:, None].astype(tail.dtype)], 1)
-        w = lp["kda_conv_w"].astype(jnp.float32)
-        y = jnp.sum(w[None] * xp.astype(jnp.float32), axis=1)
+        if fresh is not None:
+            tail = jnp.where(fresh[:, None, None], 0, tail)
+        y, tail = conv_one_token(pre, tail, lp["kda_conv_w"])
         q, k, v = _kda_qkv(y, cfg)
-        kda_conv = kda_conv.at[lk, at].set(xp[:, 1:], mode="drop")
+        kda_conv = kda_conv.at[lk, at].set(tail, mode="drop")
     with jax.named_scope("linattn.step"):
-        o, kda_s = kda_step_slots(kda_s, lk, slots, q, k, v, g, beta)
+        o, kda_s = kda_step_slots(kda_s, lk, slots, q, k, v, g, beta, fresh)
     return (kda_s, kda_conv), o
 
 
@@ -1295,7 +1358,11 @@ def forward(
     the residuals run over [1, width, D]. Attention and the KV-row
     write need the grid and the pool: q is spread back to [B, Tq, ...],
     the new rows lead the token rows the write takes them from, and
-    attention's output is gathered again. A step with more real tokens
+    attention's output is gathered again. A linear layer of a step that
+    `kda_mix_splits` needs neither: it runs whole over the step's rows
+    (`kda_mix_rows`: a row's tokens are contiguous token rows in both
+    layouts), outside every `cond`, and hands `back` its output as
+    token rows in x's own layout. A step with more real tokens
     than `width` takes the same halves at the grid's full width: each
     half of a layer (`layer_front`, `layer_back`) is one `jax.lax.cond`
     on the step's real-token count, inside the same program; a shape
@@ -1425,6 +1492,13 @@ def forward(
                         ).reshape((b, tq) + a.shape[2:])
 
     flat_positions = None if sel is None else from_grid(meta.positions)
+    # a step whose linear layers work over its rows (`kda_mix_rows`): a
+    # row's tokens are contiguous token rows in both layouts
+    kda_plan = None
+    if cfg.has_linear_layers and kda_mix_splits(b, tq):
+        row0 = jnp.arange(b, dtype=jnp.int32) * tq
+        kda_plan = kda_rows(grid_valid, row0 if sel is None else jnp.where(
+            fits, sel.slot[row0], row0))
 
     def embed_rows(sel):
         if sel is None:
@@ -1475,11 +1549,14 @@ def forward(
                 return to_grid(q), to_grid(k), to_grid(v)
             return to_grid(q), unflat(k), None if v is None else unflat(v)
 
-        def back(sel, x, attn):
+        def back(sel, x, attn, stored=False):
+            # stored: attn is [B * Tq, ...] token rows in x's own layout
             block = functools.partial(
                 _mlp_block, cfg=cfg, mesh=mesh, stacks=expert_stacks,
                 lid=lid if whole else lid - first, dense=dense)
             if sel is None:
+                if stored:
+                    attn = attn.reshape((b, tq) + attn.shape[1:])
                 return layer_back(
                     x, attn, lp_of(), cfg, lambda xn, lp: block(
                         xn, lp, token_valid=grid_valid if moe_aux else None),
@@ -1492,10 +1569,22 @@ def forward(
                     return from_grid(out), stats
                 return block(xn, lp, token_valid=sel.live[None]
                              if moe_aux else None)
-            x, stats = layer_back(flat(x), from_grid(attn), lp_of(), cfg,
-                                  mlp, kind=kind)
+            x, stats = layer_back(
+                flat(x), attn[None, :width] if stored else from_grid(attn),
+                lp_of(), cfg, mlp, kind=kind)
             return unflat(x), stats
 
+        if kind == "kda" and kda_plan is not None:
+            # a linear layer of a split step, whole, over the step's
+            # rows: no [B, Tq] tensor of the layer's width, and the state
+            # leaves in no `cond` (the branches of `back` read o's rows)
+            state = kda_mix_rows(
+                state, sl, meta.state_slots, lp_of(), cfg,
+                x.reshape(n, -1), kda_plan, grid_valid,
+                meta.positions[:, 0] == 0)
+            x, drop_stats = either(functools.partial(back, stored=True),
+                                   x, state[2])
+            return (x, pool, state), drop_stats
         q, k, v = either(front, x)
         if kind == "kda":
             # the grid's part of a linear layer: each row's convolution
@@ -1548,6 +1637,10 @@ def forward(
     pool = tuple(cache[key] for key in pool_keys)
     state_keys = tuple(cfg.state_leaves())
     state = tuple(cache[key] for key in state_keys)
+    if kda_plan is not None:
+        # the scratch that carries a linear layer's o to its back half
+        state += (jnp.zeros((n, cfg.num_heads, cfg.linear_head_dim),
+                            jnp.float32),)
     drops = []
     for run in runs:
         name, first, count, dense = run[:4]
@@ -1570,7 +1663,8 @@ def forward(
             at = jnp.where(fits, sel.slot[at], at)
         x = jnp.take(x.reshape(n, -1), at, axis=0, mode="clip")
     logits = lm_logits(x, params["final_norm"], lm_head(params, cfg), cfg)
-    cache_out = dict(zip(pool_keys + state_keys, pool + state))
+    cache_out = dict(zip(pool_keys + state_keys,
+                         pool + state[:len(state_keys)]))
     if with_aux:
         return logits, cache_out, aux
     return logits, cache_out

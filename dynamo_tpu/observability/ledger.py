@@ -179,6 +179,13 @@ class LedgerStats:
         #                                linear_attention.kda_step_slots):
         #                                a window's, and a mixed step's
         #                                one-token rows
+        "linattn_flat_steps_total",    # the _engine_steps whose linear
+        #                                layers worked over a compact
+        #                                step's FLAT token rows (models/
+        #                                llama.kda_mix_rows where the real
+        #                                tokens fit the flat width); a split
+        #                                step that is not counted read the
+        #                                grid's rows
         "linattn_state_bytes_total",   # state bytes read + written: live
         #                                rows x linear layers x slot bytes x 2
         "linattn_steps_total",         # device steps the above were summed

@@ -81,6 +81,17 @@ def conv_with_tail(pre, tail, w, n_valid):
     return y, jnp.take_along_axis(xp, at[:, :, None], axis=1)
 
 
+def conv_one_token(pre, tail, w):
+    """`conv_with_tail` for ONE token a row, the one definition every
+    one-token form shares (a decode window's step, a mixed step's decode
+    rows): pre [B, C], tail [B, K - 1, C], w [K, C] -> (y [B, C] float32,
+    the next tail [B, K - 1, C] in the tail's dtype). One tap window a
+    row: K * C values, whatever the step's grid."""
+    xp = jnp.concatenate([tail, pre[:, None].astype(tail.dtype)], axis=1)
+    y = jnp.sum(w.astype(F32)[None] * xp.astype(F32), axis=1)
+    return y, xp[:, 1:]
+
+
 def kda_step(q, k, v, g, beta, s):
     """One token a row. q, k [B, H, dk], v [B, H, dv], g [B, H, dk],
     beta [B, H], s [B, H, dk, dv], all float32 -> (o [B, H, dv], s')."""

@@ -117,3 +117,61 @@ def test_the_state_update_compiles_for_a_v5e_and_moves_no_copy_of_the_leaf(
     assert hlo.count("tpu_custom_call") >= 1
     assert _STATE_MOVES.findall(hlo) == []
     assert _STATE_MOVES.findall(_state_layers_hlo(one_chip, False)) != []
+
+
+# a [64, 64] mixed step of ling-3.0-flash-vl: 3 chunk rows beside 60 decode
+# rows, 4096 cells of which 252 hold a token
+_GRID_WIDE = re.compile(r"=\s*f32\[(?:64,64|4096),(?:12288|32,128)\]\S*\s+"
+                        r"(?!parameter|get-tuple-element|tuple|bitcast|"
+                        r"while|scatter|broadcast)([\w\-]+)\(")
+
+
+def _grid_wide_ops(hlo: str) -> list:
+    """Ops that MAKE a float32 array of the grid's cells at the layer's
+    widths; a fused in-place scatter (the scratch's rows written) is not
+    one."""
+    return [m.group(1) for line in hlo.splitlines()
+            for m in [_GRID_WIDE.search(line)]
+            if m and '/scatter"' not in line]
+
+
+_LEAF_MOVES = re.compile(
+    r"=\s*f32\[7,68,32,128,128\]\S*\s+(copy|copy-start|gather|"
+    r"dynamic-slice)\(")
+
+
+def _mixed_layers_hlo(one_chip, form: str) -> str:
+    """Optimised HLO of the seven linear layers' mix of that step at the
+    published widths (tools/linattn_step_bench.py's --mixed program: the
+    leaves carried through a scan as `forward` carries them), over the
+    step's rows (`llama.kda_mix_rows`) or over its grid as until PR 37."""
+    from tools import linattn_step_bench as bench
+
+    cfg, layers, slots_n = bench.LING, _STATE[0], _STATE[1]
+    plan = bench.mixed_plan(64, 64, 3, slots_n)
+    assert plan["fits"] and plan["width"] == 256
+    kda_s, kda_conv, x, lp = jax.eval_shape(
+        lambda: bench.mixed_operands(cfg, plan, layers, slots_n))
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    return bench.mixed_layers_of(form, cfg, plan).lower(
+        *jax.tree.map(on_chip, (kda_s, kda_conv, x, lp))
+    ).compile().as_text()
+
+
+def test_a_mixed_steps_linear_layers_compile_for_a_v5e_over_its_rows(
+        one_chip, monkeypatch):
+    """The row form at the served shape is taken by the chip's compiler
+    with the slot-addressed kernel in it; no op makes a float32 array of
+    the grid's 64 x 64 cells (or its 4096 token rows) at the layer's
+    widths but the in-place writes of the one scratch that carries `o`,
+    and nothing copies, gathers or slices the state leaf whole. The grid
+    form is caught making them."""
+    from dynamo_tpu.ops import linear_attention as la
+    monkeypatch.setattr(la, "kda_step_slots_impl", lambda: "pallas")
+    hlo = _mixed_layers_hlo(one_chip, "rows")
+    assert hlo.count("tpu_custom_call") >= 1
+    assert _grid_wide_ops(hlo) == []
+    assert _LEAF_MOVES.findall(hlo) == []
+    assert _grid_wide_ops(_mixed_layers_hlo(one_chip, "grid")) != []
