@@ -44,9 +44,25 @@ class ModelConfig:
     #                              (Gemma-2 uses query_pre_attn_scalar**-0.5)
     sliding_window: int = 0      # sliding-window attention width; 0 = full
     # which layers use the sliding window (only meaningful when
-    # sliding_window > 0): "alternate" = even layers sliding, odd global
-    # (the Gemma-2 pattern); "all" = every layer sliding
-    sliding_pattern: str = "alternate"
+    # sliding_window > 0): one entry a layer, "sliding_attention" |
+    # "full_attention", as HF's `layer_types`. () = Gemma-2's default,
+    # even layers sliding and odd ones global. Any pattern is one list:
+    # Gemma-2's alternation, every layer sliding, three sliding to one full
+    layer_types: tuple = ()
+    # How a sliding layer is SERVED. False (Gemma-2): a mask of its width
+    # over the full-length gather of the one pool every layer shares, one
+    # traced width a layer (`layer_windows`). True: the sliding layers
+    # are a layer kind of their own ("swa" in `layer_kinds`) with their
+    # own cache leaves over a second page pool (`window_cache_leaves`),
+    # in which a sequence holds only the pages its next step can see
+    # (engine/scheduler.py), and a step gathers that short table.
+    window_pool: bool = False
+    # RoPE by layer kind (HF `rope_parameters`): None = plain RoPE at
+    # `rope_theta`. `rope_full` serves the full-attention layers (and
+    # every layer of a model with one kind), `rope_sliding` the sliding
+    # ones (models/llama.rope_table).
+    rope_full: Optional["RopeParams"] = None
+    rope_sliding: Optional["RopeParams"] = None
     max_model_len: int = 2048
     tie_word_embeddings: bool = False
     dtype: str = "bfloat16"
@@ -153,18 +169,31 @@ class ModelConfig:
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
 
-    def layer_windows(self):
-        """Per-layer attention window as an int32 list: the sliding width
-        for sliding layers, a huge sentinel (2**30, effectively full) for
-        global layers. None when every layer is full-attention."""
+    def sliding_layers(self) -> tuple:
+        """One bool a layer: whether it attends inside `sliding_window`."""
         if not self.sliding_window:
+            return (False,) * self.num_layers
+        if not self.layer_types:
+            # Gemma-2: even layers sliding, odd layers global
+            return tuple(l % 2 == 0 for l in range(self.num_layers))
+        if len(self.layer_types) != self.num_layers:
+            raise ValueError(
+                f"{self.name}: layer_types has {len(self.layer_types)} "
+                f"entries for {self.num_layers} layers")
+        return tuple(t == "sliding_attention" for t in self.layer_types)
+
+    def layer_windows(self):
+        """Per-layer attention window as an int32 list, for a model whose
+        sliding layers are a MASK over the shared pool: the sliding width
+        for sliding layers, a huge sentinel (2**30, effectively full) for
+        global layers. None when every layer is full-attention, and for a
+        `window_pool` model, whose sliding layers are a kind of their own
+        with a static width."""
+        if not self.sliding_window or self.window_pool:
             return None
         full = 1 << 30
-        if self.sliding_pattern == "all":
-            return [self.sliding_window] * self.num_layers
-        # Gemma-2: even layers sliding, odd layers global
-        return [self.sliding_window if l % 2 == 0 else full
-                for l in range(self.num_layers)]
+        return [self.sliding_window if s else full
+                for s in self.sliding_layers()]
 
     @property
     def is_moe(self) -> bool:
@@ -179,21 +208,31 @@ class ModelConfig:
         return self.linear_group_size > 0
 
     def layer_kinds(self) -> tuple:
-        """Each layer's attention kind, in order: "kda" | "mla" | "mha".
-        A model without linear layers is one kind throughout."""
+        """Each layer's attention kind, in order: "kda" | "mla" | "mha" |
+        "swa" (a sliding layer served from the window pool; "mha" in all
+        but its cache and its RoPE table). A model without linear layers
+        and without a window pool is one kind throughout."""
         own = "mla" if self.is_mla else "mha"
         g = self.linear_group_size
+        if self.window_pool:
+            return tuple("swa" if s else own for s in self.sliding_layers())
         return tuple(own if not g or (i + 1) % g == 0 else "kda"
                      for i in range(self.num_layers))
 
     @property
     def num_cache_layers(self) -> int:
-        """Layers that hold a paged KV cache (all but the linear ones)."""
-        return sum(kind != "kda" for kind in self.layer_kinds())
+        """Layers that hold pages of the FULL pool: every page of their
+        sequence's context (all but the linear and the window layers)."""
+        return sum(kind in ("mha", "mla") for kind in self.layer_kinds())
+
+    @property
+    def num_window_layers(self) -> int:
+        """Layers whose pages live in the window pool."""
+        return sum(kind == "swa" for kind in self.layer_kinds())
 
     @property
     def num_state_layers(self) -> int:
-        return self.num_layers - self.num_cache_layers
+        return sum(kind == "kda" for kind in self.layer_kinds())
 
     @property
     def local_experts(self) -> int:
@@ -237,11 +276,30 @@ class ModelConfig:
         return {"k": (self.num_kv_heads, self.head_dim),
                 "v": (self.num_kv_heads, self.head_dim)}
 
+    def window_cache_leaves(self) -> dict:
+        """The window layers' leaves, beside `kv_cache_leaves`: value leaf
+        -> (kv heads, width), each stored [window layers, heads, window
+        pages, page_size, width] over the SECOND page pool. Empty for a
+        model without a window pool."""
+        if not self.window_pool:
+            return {}
+        return {"wk": (self.num_kv_heads, self.head_dim),
+                "wv": (self.num_kv_heads, self.head_dim)}
+
     def kv_bytes_per_token(self) -> int:
-        """Bytes one token holds in the unquantized cache, all layers."""
+        """Bytes one token holds in the unquantized FULL pool, all the
+        layers that have pages there."""
+        return self._token_bytes(self.num_cache_layers,
+                                 self.kv_cache_leaves())
+
+    def window_kv_bytes_per_token(self) -> int:
+        """Bytes one token holds in the window pool, all window layers."""
+        return self._token_bytes(self.num_window_layers,
+                                 self.window_cache_leaves())
+
+    def _token_bytes(self, layers: int, leaves: dict) -> int:
         itemsize = 4 if self.dtype == "float32" else 2
-        return self.num_cache_layers * itemsize * sum(
-            h * w for h, w in self.kv_cache_leaves().values())
+        return layers * itemsize * sum(h * w for h, w in leaves.values())
 
     @property
     def moe_dropless(self) -> bool:
@@ -257,6 +315,25 @@ class ModelConfig:
         prefill chunk re-reads an expert's weights once per row tile
         (8.6 ms a layer against 5.9)."""
         return self.num_experts > 8
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeParams:
+    """One layer kind's RoPE (an entry of HF's `rope_parameters`).
+    `rope_type` "default": plain RoPE at `theta`. "yarn": the frequencies
+    blend interpolated (1 / (factor f_i)) and extrapolated (1 / f_i) by a
+    linear ramp between the dimensions that turn `beta_fast` and
+    `beta_slow` times within `original_max_position`, and cos / sin are
+    multiplied by `attention_factor` (0 = 0.1 ln(factor) + 1) at every
+    position (models/llama.rope_table)."""
+
+    theta: float = 10000.0
+    rope_type: str = "default"
+    factor: float = 1.0
+    original_max_position: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)

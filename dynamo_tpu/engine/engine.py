@@ -91,6 +91,7 @@ class NativeEngine:
         llama.refuse_unserved_recurrent_state(model_cfg, engine_cfg,
                                               self.mesh)
         llama.refuse_unserved_latent_cache(model_cfg, engine_cfg, self.mesh)
+        llama.refuse_unserved_window_cache(model_cfg, engine_cfg, self.mesh)
         if model_cfg.experts_held and model_cfg.moe_impl == "dispatch" \
                 and not model_cfg.moe_dropless:
             raise ValueError(
@@ -187,8 +188,22 @@ class NativeEngine:
         if model_cfg.has_linear_layers:
             self._state_slots = engine_cfg.max_slots \
                 + max(1, engine_cfg.max_prefill_batch)
+        # a model whose sliding layers keep a page pool of their own:
+        # sized so that it never refuses what the full pool admits, the
+        # most a sequence can hold there (the table of the widest chunk)
+        # for every decode slot and every row of a prefill batch
+        self._window_pages = 0
+        window = None
+        if model_cfg.window_pool:
+            from dynamo_tpu.engine.scheduler import window_table_pages
+            rows = engine_cfg.max_slots + max(1, engine_cfg.max_prefill_batch)
+            self._window_pages = rows * window_table_pages(
+                engine_cfg, model_cfg.sliding_window,
+                engine_cfg.max_prefill_chunk)
+            window = (model_cfg.sliding_window, self._window_pages)
         self.scheduler = Scheduler(engine_cfg, host_pool=self.host_pool,
-                                   state_slots=self._state_slots)
+                                   state_slots=self._state_slots,
+                                   window=window)
         self._pending_offloads: list = []
         self._copy_stream = None
         # cluster-wide shared KV pool (engine/kv_pool.py): attach_kv_pool
@@ -262,6 +277,11 @@ class NativeEngine:
             flops_per_token=model_flops_per_token(model_cfg)
             + sampler_flops_per_token(model_cfg))
         self.ledger.stats.kv_bytes_per_token = model_cfg.kv_bytes_per_token()
+        self.ledger.stats.kv_bytes_per_token_full = \
+            model_cfg.kv_bytes_per_token()
+        self.ledger.stats.kv_bytes_per_token_window = \
+            model_cfg.window_kv_bytes_per_token()
+        self.ledger.stats.kv_window_pages_total = self._window_pages
         self.ledger.stats.state_bytes_per_slot = \
             model_cfg.state_bytes_per_slot()
         # (program, bucket) keys already dispatched: a key's first
@@ -354,7 +374,8 @@ class NativeEngine:
         init_cache = jax.jit(
             functools.partial(
                 llama.init_cache, model_cfg,
-                num_pages=engine_cfg.num_pages, page_size=engine_cfg.page_size),
+                num_pages=engine_cfg.num_pages, page_size=engine_cfg.page_size,
+                window_pages=self._window_pages),
             out_shardings=self.cache_shardings)
         self.cache = init_cache()
         if self._state_slots:
@@ -405,13 +426,16 @@ class NativeEngine:
         # and a static layout (_packed, _stage_operands): a variant's
         # operand names are fixed here, with the variant
         state_op = ("state_slots",) * bool(self._state_slots)
+        wstep_op = ("wtable", "woff", "wwrite_idx") * bool(self._window_pages)
+        wwin_op = ("wtable", "woff") * bool(self._window_pages)
         self._step_fns = {
             (rp, lp, mm): jax.jit(
                 _named("engine_step", _packed(
                     functools.partial(
                         _engine_step, model_cfg, eos_tuple, sp_mesh,
                         kernel_mesh, rp, lp, mm, pp_mesh),
-                    STEP_OPERANDS + state_op + ("rep_penalty",) * rp
+                    STEP_OPERANDS + state_op + wstep_op
+                    + ("rep_penalty",) * rp
                     + ("mm_mask",) * mm,
                     ("hist",) * rp + ("mm_embeds",) * mm)),
                 static_argnums=(2,), donate_argnums=(1,))
@@ -439,7 +463,8 @@ class NativeEngine:
                         _engine_decode_window, model_cfg, eos_tuple,
                         kernel_mesh, nw, engine_cfg.page_size, rp, lp,
                         greedy, fused),
-                    WINDOW_OPERANDS + state_op + ("rep_penalty",) * rp,
+                    WINDOW_OPERANDS + state_op + wwin_op
+                    + ("rep_penalty",) * rp,
                     ("hist",) * rp, carried=True)),
                 static_argnums=(3,), donate_argnums=(1,))
             for rp in (False, True) for lp in (False, True)
@@ -1035,6 +1060,9 @@ class NativeEngine:
                 int(real.sum()), int((plan.state_slots >= 0).sum()),
                 inplace=int((real == 1).sum()) if splits else 0,
                 flat=splits and self._dense_rows(plan) < plan.tokens.size)
+        if self._window_pages:
+            small += (plan.wtable, plan.woff, plan.wwrite_idx)
+            self._account_window_pool(plan)
         own = ()
         if rp is not None:
             small, own = small + (rp[1],), own + (rp[0],)
@@ -1043,8 +1071,9 @@ class NativeEngine:
         key = ("step", rp is not None, with_lp, mm, plan.tokens.shape,
                plan.page_table.shape[1],
                None if rp is None else rp[0].shape[1])
-        self._account_attention(int(plan.kv_lens.sum()),
-                                plan.page_table.size)
+        self._account_attention(
+            int(plan.kv_lens.sum()), plan.page_table.size,
+            *self._window_reads(plan, plan.kv_lens))
         return key, self._stage_operands(small, own), with_lp
 
     def _dense_rows(self, plan) -> int:
@@ -1091,16 +1120,49 @@ class NativeEngine:
             stats.linattn_chunk_tokens_total += tokens * layers
         stats.state_slots_used = self.scheduler.state_slots.used
 
-    def _account_attention(self, kv_tokens: int, table_pages: int) -> None:
+    def _account_attention(self, kv_tokens: int, table_pages: int,
+                           wkv_tokens: int = 0, wtable_pages: int = 0
+                           ) -> None:
         """`llm_engine_attn_kv_tokens_total` / `_slots_total`, from a
         step's plan on the host: the context tokens its real rows attend
         to, and the token slots the gather path reads for them (rows x
         page-table width x page size; a decode window: the base it
         gathers, once a window, whatever its rung). 1 - tokens / slots
-        is the share of gathered KV that is bucket padding."""
+        is the share of gathered KV that is bucket padding. They count
+        the FULL pool's tables; `llm_engine_attn_kv_window_tokens_total`
+        / `_window_slots_total` count the window pool's, for the layers
+        that read it (`_window_reads`): window slots / slots is the share
+        of a full-length gather that a window layer's gather is."""
         stats = self.ledger.stats
         stats.attn_kv_tokens_total += kv_tokens
         stats.attn_kv_slots_total += table_pages * self.cfg.page_size
+        stats.attn_kv_window_tokens_total += wkv_tokens
+        stats.attn_kv_window_slots_total += wtable_pages * self.cfg.page_size
+
+    def _window_reads(self, plan, kv_lens) -> tuple:
+        """(tokens, table pages) of a plan's window-pool gather: the keys
+        its real rows can see in a sliding layer (their context, at most
+        the window), and its table's cells. () without that pool."""
+        if not self._window_pages:
+            return ()
+        seen = np.minimum(kv_lens, self.model_cfg.sliding_window)
+        return int(seen.sum()), plan.wtable.size
+
+    def _account_window_pool(self, plan) -> None:
+        """The window pool's gauges and counters, from a step's plan:
+        pages its live rows hold there (`llm_engine_kv_window_pages_held`:
+        the mean over this plan's rows; `_held_sum_total` / `_rows_total`
+        the same, summed over plans), pages handed back so far, pages in
+        use."""
+        stats = self.ledger.stats
+        held = [len(s.wpages) for s in plan.seqs if s is not None]
+        stats.kv_window_pages_held = sum(held) / max(1, len(held))
+        stats.kv_window_pages_held_sum_total += sum(held)
+        stats.kv_window_rows_total += len(held)
+        stats.kv_window_pages_released_total = \
+            self.scheduler.window_released
+        alloc = self.scheduler.window_alloc
+        stats.kv_window_pages_used = alloc.num_pages - alloc.num_free
 
     def _stage_operands(self, small: tuple, own: tuple = (),
                         commit: bool = False) -> tuple:
@@ -1335,13 +1397,16 @@ class NativeEngine:
         the caller's `upload` phase."""
         temp, top_k, top_p, seeds, counters, min_toks = samp
         ps = self.cfg.page_size
+        if self._window_pages:
+            self._account_window_pool(plan)
         base_lens = np.clip(plan.positions[:, 0], 0, plan.max_pos + 1)
         base_pages = max(1, int(-(-int(base_lens.max()) // ps)))
         base_pb = min(next_bucket(base_pages, self.scheduler.page_buckets),
                       plan.page_table.shape[1])
         sig = (tuple((s.request_id, s.epoch) if s else None
                      for s in plan.seqs),
-               tuple(len(s.pages) if s else 0 for s in plan.seqs),
+               tuple((len(s.pages), s.wfirst, len(s.wpages)) if s else 0
+                     for s in plan.seqs),
                plan.page_table.shape[1], base_pb, plan.stop_ids.shape[1],
                rp is None, with_lp, greedy, fused)
         st = self._dec_state
@@ -1360,6 +1425,8 @@ class NativeEngine:
                      ign, plan.stop_ids)
             if self._state_slots:
                 small += (plan.state_slots,)
+            if self._window_pages:
+                small += (plan.wtable, plan.woff)
             own = (self._window_carry(plan, counters),)
             if rp is not None:
                 small, own = small + (rp[1],), (rp[0],) + own
@@ -1386,7 +1453,8 @@ class NativeEngine:
                 "base_cap": base_pb * ps if pregather else None,
                 # (context tokens, table pages) of the base this window
                 # gathers, once: _account_attention, at dispatch
-                "attn": (int(base_lens.sum()), len(plan.seqs) * base_pb),
+                "attn": (int(base_lens.sum()), len(plan.seqs) * base_pb)
+                + self._window_reads(plan, base_lens),
                 # (tokens, rows) of a window's state updates:
                 # _account_linattn, at dispatch
                 "linattn": (nw * sum(s is not None for s in plan.seqs),) * 2
@@ -2090,6 +2158,9 @@ class NativeEngine:
         llama.refuse_unserved_recurrent_state(
             self.model_cfg, feature="disagg transfer (a remote prefill "
             "leaves pages and no state)")
+        llama.refuse_unserved_window_cache(
+            self.model_cfg, feature="disagg transfer (a remote prefill "
+            "leaves the pages of one pool)")
         # per-hash copy settling happens inside the prefix walk, as in
         # add_request (this path also matches against the host tier)
         return self.scheduler.add_remote(
@@ -2153,6 +2224,9 @@ class NativeEngine:
         llama.refuse_unserved_latent_cache(
             self.model_cfg, feature="whole-page extraction (disagg "
             "transfer, the shared KV pool)")
+        llama.refuse_unserved_window_cache(
+            self.model_cfg, feature="whole-page extraction (disagg "
+            "transfer, the shared KV pool)")
         ids = jnp.asarray(self._bucket_ids(page_ids))
         ids = jnp.minimum(ids, self.cfg.num_pages - 1)  # clamp padding reads
         return self._extract_fn(self.cache, ids)
@@ -2177,6 +2251,9 @@ class NativeEngine:
             self.model_cfg, feature="whole-page injection (disagg "
             "transfer, the shared KV pool)")
         llama.refuse_unserved_latent_cache(
+            self.model_cfg, feature="whole-page injection (disagg "
+            "transfer, the shared KV pool)")
+        llama.refuse_unserved_window_cache(
             self.model_cfg, feature="whole-page injection (disagg "
             "transfer, the shared KV pool)")
         if self.kv_quant and k_scale is None:
@@ -2357,6 +2434,8 @@ class NativeEngine:
         llama.refuse_unserved_recurrent_state(
             self.model_cfg, feature="the shared KV pool")
         llama.refuse_unserved_latent_cache(
+            self.model_cfg, feature="the shared KV pool")
+        llama.refuse_unserved_window_cache(
             self.model_cfg, feature="the shared KV pool")
         self.kv_pool = pool
         self.kv_pool_source = source_id
@@ -2626,13 +2705,15 @@ def _inject_pages_slice(cache, ids, pages, slices=()):
     return out
 
 
-def _scatter_new_kv(cache, k_news, v_news, write_idx):
+def _scatter_new_kv(cache, k_news, v_news, write_idx, keys=None):
     """One in-place scatter of all layers' new kv rows (deferred write).
 
     cache {k,v[,k_scale,v_scale]}: [L, Hkv, P, ps, hd] (+ [L, Hkv, P,
     ps] scales); k_news/v_news [L, S, Hkv, hd] full-precision rows
     (v_news None: a one-leaf cache, latent attention);
-    write_idx [S] flat token slots (<0 = padding, dropped). On kv_quant
+    write_idx [S] flat token slots (<0 = padding, dropped). `keys`: the
+    leaves to write where they are not the pool's own (a window pool's
+    ("wk", "wv"), engine/config.ModelConfig.window_cache_leaves). On kv_quant
     caches the rows quantize HERE — capture time, inside the jitted step
     — and the int8 values + f32 scales scatter together. The leaves are
     written in the layout they are stored in, row by row
@@ -2642,7 +2723,7 @@ def _scatter_new_kv(cache, k_news, v_news, write_idx):
         kv_write_plan, stored_kv_rows, write_kv_rows)
     from dynamo_tpu.ops.kv_quant import cache_keys
     quant = "k_scale" in cache
-    keys = tuple(key for key in cache_keys(quant) if key in cache)
+    keys = keys or tuple(key for key in cache_keys(quant) if key in cache)
     # dynalint: kv-codec — rows enter in the stored representation
     # (stored_kv_rows quantizes them on an int8 pool), values and scales
     # paired: [L, S, Hkv, hd] / [L, S, Hkv]
@@ -2660,7 +2741,7 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
                           base_table, max_pos, temperature, top_k, top_p,
                           seeds, counters, min_tokens, ignore_eos=None,
                           stop_ids=None, hist=None, rep_penalty=None,
-                          state_slots=None):
+                          state_slots=None, wtable=None, woff=None):
     """N fused decode iterations: forward + sample per step, the sampled
     token feeding the next step on device (lax.scan), so one dispatch and
     one [N, S] token download serve N tokens (VERDICT r2 weak #1 fix).
@@ -2699,6 +2780,14 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
     into `cache` once, after the scan. A row that is not `writable`
     (finished, out of budget, padding) leaves its slot as it is.
 
+    `wtable` [S, Wb], `woff` [S] (a model with a window pool): each
+    row's pages in the window pool and the position of its table's first
+    key. The window layers get a base, a window buffer and an
+    end-of-window writeback of their own over their own leaves ("wk",
+    "wv"): the base is the row's WHOLE short table (a sliding layer's
+    base is bounded by the window, whatever the context), its valid
+    length `base_len - woff`.
+
     with_rp / with_lp / greedy / fused pick separately-compiled variants
     so the common greedy path pays for neither the seen-token mask, the
     logprob log_softmax+top_k, nor the full sampling sort, and the common
@@ -2735,9 +2824,10 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
         # spares the gathered base (537 MB a leaf for Mistral-7B-16 at 32
         # slots x 512 tokens) the fill pass of take's default mode, a
         # broadcast and a select as large as the base itself
-        def gather_base(c):
-            g = jnp.take(c, base_table.reshape(-1), axis=2, mode="clip")
-            return g.reshape(l, hkv_n, s, lb, hd)
+        def gather_base(c, table=base_table):
+            g = jnp.take(c, table.reshape(-1), axis=2, mode="clip")
+            return g.reshape(c.shape[0], hkv_n, s,
+                             table.shape[1] * page_size, hd)
 
         def gather_base_scale(sc):
             g = jnp.take(sc, base_table.reshape(-1), axis=2, mode="clip")
@@ -2768,12 +2858,25 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
         base_len = jnp.clip(positions, 0, max_pos + 1)
         kw0 = jnp.zeros((l, hkv_n, s, n_steps, hd), kb.dtype)
         vw0 = None if vb is None else jnp.zeros_like(kw0)
+    swa0 = None
+    if cfg.window_pool:
+        # dynalint: kv-codec — unquantized base gather (window pool)
+        wkb, wvb = (gather_base(cache[key], wtable) for key in ("wk", "wv"))
+        swa0 = (jnp.zeros(wkb.shape[:3] + (n_steps, hd), wkb.dtype),) * 2
 
     def global_write_idx(pos, writable):
         """Flat global-cache slot for this step's row (-1 = dropped)."""
         page = page_table[rows, jnp.maximum(
             jnp.minimum(pos, max_pos), 0) // page_size]
         return jnp.where(writable, page * page_size + pos % page_size, -1)
+
+    def window_write_idx(pos, writable):
+        """The same slot in the window pool: the row's table starts at
+        its first held page."""
+        at = (jnp.maximum(jnp.minimum(pos, max_pos), 0) - woff) // page_size
+        page = wtable[rows, jnp.clip(at, 0, wtable.shape[1] - 1)]
+        return jnp.where(writable & (at >= 0),
+                         page * page_size + pos % page_size, -1)
 
     def sample_and_track(logits, ctr, seen, alive):
         """Shared step tail: sampling + rep-penalty seen set + eos alive.
@@ -2818,19 +2921,30 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
     state0 = tuple(cache[key] for key in state_keys)
 
     def body(carry, t):
-        kw, vw, state, tok, pos, ctr, seen, alive = carry
+        kw, vw, state, swa_win, tok, pos, ctr, seen, alive = carry
         writable = (pos <= max_pos) & alive
         prefix = jnp.clip(pos, 0, max_pos + 1)
         # tokens written in-window so far; window index j == step index
         # (all slots step together), valid entries are j < win_len
         win_len = prefix - base_len
-        logits, k_news, v_news, aux, *state_out = llama.decode_forward(
+        logits, k_news, v_news, aux, *more = llama.decode_forward(
             params, cfg, tok, cache, page_table, prefix, pos,
             valid=writable, mesh=kernel_mesh, with_aux=True,
             window=(kb, vb, kw, vw, base_len, win_len),
-            state=(state, state_slots) if state_keys else None)
+            state=(state, state_slots) if state_keys else None,
+            swa=None if swa_win is None
+            else (wkb, wvb, *swa_win, base_len - woff))
         if state_keys:
-            state = state_out[0]
+            state = more[0]
+        w_out = ()
+        if swa_win is not None:
+            # the window layers' rows: into their buffer at step t, and
+            # out for their own end-of-window writeback
+            w_news = more[-1]
+            swa_win = tuple(jax.lax.dynamic_update_index_in_dim(
+                buf, new.transpose(0, 2, 1, 3).astype(buf.dtype), t, axis=3)
+                for buf, new in zip(swa_win, w_news))
+            w_out = (*w_news, window_write_idx(pos, writable))
         # this step's rows land at window index t for every slot; slots
         # that may not write (finished/padding) still store garbage there
         # but their win_len stops growing, so attention never reads it.
@@ -2844,9 +2958,10 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
                 axis=3)
         nxt, lp, top_ids, top_lps, seen, alive = sample_and_track(
             logits, ctr, seen, alive)
-        return (kw, vw, state, nxt, pos + 1, ctr + 1, seen, alive), \
+        return (kw, vw, state, swa_win, nxt, pos + 1, ctr + 1, seen,
+                alive), \
             (nxt, lp, top_ids, top_lps, aux, k_news, v_news,
-             global_write_idx(pos, writable))
+             global_write_idx(pos, writable), w_out)
 
     alive0 = max_pos >= 0
     if not pregather:
@@ -2858,20 +2973,29 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
         aux = {k: jnp.sum(v) for k, v in auxs.items()}
         return (toks, lps, top_ids, top_lps, cache, aux,
                 (tok_f, pos_f, ctr_f))
-    (kw, vw, state_f, tok_f, pos_f, ctr_f, *_), \
-        (toks, lps, top_ids, top_lps, auxs, k_all, v_all, widx_all) = \
+    (kw, vw, state_f, _, tok_f, pos_f, ctr_f, *_), \
+        (toks, lps, top_ids, top_lps, auxs, k_all, v_all, widx_all,
+         w_all) = \
         jax.lax.scan(body,
-                     (kw0, vw0, state0, tokens, positions, counters, seen0,
-                      alive0),
+                     (kw0, vw0, state0, swa0, tokens, positions, counters,
+                      seen0, alive0),
                      jnp.arange(n_steps), length=n_steps)
     aux = {k: jnp.sum(v) for k, v in auxs.items()}
     # end-of-window writeback: all N steps' rows -> global paged cache in
     # one scatter ([N, L, S, Hkv, hd] -> [L, N*S, Hkv, hd])
-    k_flat, v_flat = (
-        None if rows_all is None else rows_all.transpose(
-            1, 0, 2, 3, 4).reshape(l, n_steps * s, hkv_n, hd)
-        for rows_all in (k_all, v_all))
-    cache = _scatter_new_kv(cache, k_flat, v_flat, widx_all.reshape(-1))
+    def flat_rows(rows_all):
+        return None if rows_all is None else rows_all.transpose(
+            1, 0, 2, 3, 4).reshape(-1, n_steps * s, hkv_n, hd)
+
+    pools = _scatter_new_kv(cache, flat_rows(k_all), flat_rows(v_all),
+                            widx_all.reshape(-1))
+    if w_all:
+        # the window layers' rows -> the window pool, the same way
+        wk_all, wv_all, wwidx_all = w_all
+        pools.update(_scatter_new_kv(
+            cache, flat_rows(wk_all), flat_rows(wv_all),
+            wwidx_all.reshape(-1), keys=("wk", "wv")))
+    cache = pools
     cache.update(zip(state_keys, state_f))
     # final (token, position, counter) stay ON DEVICE: when the slot set and
     # page allocation are unchanged, the engine feeds them straight into the
@@ -2928,11 +3052,12 @@ def _engine_step(cfg: ModelConfig, eos_ids: tuple, sp_mesh, kernel_mesh,
                  tokens, positions, page_table, kv_lens, write_idx, last_idx,
                  temperature, top_k, top_p, seeds, counters, min_tokens,
                  hist=None, rep_penalty=None, mm_embeds=None, mm_mask=None,
-                 state_slots=None):
+                 state_slots=None, wtable=None, woff=None, wwrite_idx=None):
     """forward + gather last logits + sample, fused into one XLA program."""
     meta = AttnMetadata(positions=positions, page_table=page_table,
                         kv_lens=kv_lens, write_idx=write_idx,
-                        state_slots=state_slots)
+                        state_slots=state_slots, wtable=wtable, woff=woff,
+                        wwrite_idx=wwrite_idx)
     if pp_mesh is not None:
         from dynamo_tpu.models.pp import pp_forward
         logits, cache = pp_forward(

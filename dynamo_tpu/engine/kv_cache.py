@@ -213,6 +213,14 @@ class SequenceState:
     prompt: List[int]
     pages: List[int] = dataclasses.field(default_factory=list)
     page_hashes: List[int] = dataclasses.field(default_factory=list)
+    # a model with a window pool (ModelConfig.window_pool): the pages the
+    # sequence holds in the SECOND pool, for its sliding layers, logical
+    # pages wfirst, wfirst + 1, ... of its context. Only the pages its
+    # next step can see: the scheduler hands back, at every commit, those
+    # wholly behind the window of the next position (never hashed, never
+    # shared)
+    wpages: List[int] = dataclasses.field(default_factory=list)
+    wfirst: int = 0
     num_cached: int = 0       # tokens whose KV is already valid in the cache
     num_computed: int = 0     # tokens whose KV was computed by US this request
     output: List[int] = dataclasses.field(default_factory=list)
@@ -261,3 +269,8 @@ class SequenceState:
 
     def flat_index(self, pos: int, page_size: int) -> int:
         return self.pages[pos // page_size] * page_size + pos % page_size
+
+    def wflat_index(self, pos: int, page_size: int) -> int:
+        """`flat_index` in the window pool: `pos` lies in a held page."""
+        return (self.wpages[pos // page_size - self.wfirst] * page_size
+                + pos % page_size)

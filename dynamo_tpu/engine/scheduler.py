@@ -136,6 +136,13 @@ class PrefillPlan:
     # each row's recurrent-state slot, -1 for padding; None on an engine
     # whose model keeps no such state
     state_slots: Optional[np.ndarray] = None   # [Bb] int32
+    # a model with a window pool: each row's table of the pages it holds
+    # there (width Scheduler.window_table_pages(Tb)), the position of the
+    # table's first key, and the window pool's slot of every new row
+    # (>= 0 exactly where write_idx is); None on every other engine
+    wtable: Optional[np.ndarray] = None      # [Bb, Wb] int32
+    woff: Optional[np.ndarray] = None        # [Bb] int32
+    wwrite_idx: Optional[np.ndarray] = None  # [Bb, Tb] int32
     # multimodal rows: embeds to mix in at masked positions (None = all-text)
     mm_embeds: Optional[np.ndarray] = None  # [Bb, Tb, D] f32
     mm_mask: Optional[np.ndarray] = None    # [Bb, Tb] bool
@@ -192,6 +199,9 @@ class DecodePlan:
     stop_ids: np.ndarray = None  # [S, K]
     # each slot's recurrent-state slot (-1 = padding), as PrefillPlan's
     state_slots: Optional[np.ndarray] = None  # [S] int32
+    # the window pool's table and its first key's position, as PrefillPlan's
+    wtable: Optional[np.ndarray] = None   # [S, Wb] int32
+    woff: Optional[np.ndarray] = None     # [S] int32
 
 
 @dataclasses.dataclass
@@ -337,6 +347,19 @@ def page_bucket_ladder(max_value: int) -> List[int]:
     return sorted(set(out))
 
 
+def window_table_pages(cfg: EngineConfig, window_tokens: int,
+                       chunk: int) -> int:
+    """Width of a step's window-pool page table (a model whose sliding
+    layers keep a pool of their own), a function of the step's STATIC
+    chunk width alone, so it adds no program: the most pages a row can
+    hold there when the step is planned. A row holds the pages of
+    [num_cached - window + 1, num_cached + ahead), ahead being the chunk
+    or, for a decode row, the pipeline's lookahead: window + ahead - 1
+    positions touch at most ceil((window + ahead) / page) + 1 pages."""
+    ahead = max(chunk, cfg.decode_steps * max(1, cfg.pipeline_depth), 1)
+    return -(-(window_tokens + ahead) // cfg.page_size) + 1
+
+
 def next_bucket(n: int, buckets: Sequence[int]) -> int:
     for b in buckets:
         if b >= n:
@@ -346,9 +369,24 @@ def next_bucket(n: int, buckets: Sequence[int]) -> int:
 
 class Scheduler:
     def __init__(self, cfg: EngineConfig, host_pool=None,
-                 state_slots: int = 0):
+                 state_slots: int = 0, window: Optional[tuple] = None):
         self.cfg = cfg
         self.allocator = PageAllocator(cfg.num_pages, cfg.page_size)
+        # the second page pool (`window`: (sliding width in tokens, pages);
+        # a model whose sliding layers keep their own cache leaves,
+        # ModelConfig.window_pool), None for every other model. A
+        # sequence's list there (SequenceState.wpages) grows with the
+        # first list and is cut from the front at every commit
+        # (_release_window_pages); admission and preemption read BOTH
+        # allocators (_ensure_pages). A page there is one sequence's and
+        # has no hash, so prefix reuse is off, as with a recurrent state
+        self.window_alloc = None
+        self.window_tokens = 0
+        self.window_released = 0   # pages handed back before their
+        #                            sequence ended, so far
+        if window is not None:
+            self.window_tokens, pages = window
+            self.window_alloc = PageAllocator(pages, cfg.page_size)
         # the recurrent-state slots (engine/kv_cache.StateSlots), None
         # for a model without linear-attention layers. With them a page
         # hit has no state to go with it, so prefix reuse is off
@@ -711,16 +749,18 @@ class Scheduler:
             # ring-attention prefill attends only within its chunk, so a
             # shared prefix cannot be skipped — disable prefix matching
             return [], 0
-        if self.state_slots is not None:
-            # a page hit has no recurrent state to go with it: every
-            # sequence computes its whole context (_match_prefix and
-            # peek_prefix both give 0)
+        if self.state_slots is not None or self.window_alloc is not None:
+            # a page hit has no recurrent state to go with it, and none
+            # of the window layers' pages of its last tokens (released as
+            # their sequence moved on): every sequence computes its whole
+            # context (_match_prefix and peek_prefix both give 0)
             if not self._prefix_off_logged:
                 self._prefix_off_logged = True
                 import logging
                 logging.getLogger(__name__).info(
                     "prefix reuse is off: the model keeps a recurrent "
-                    "state a sequence, and a cached page has none")
+                    "state a sequence or a window pool, and a cached "
+                    "page of the full pool has neither")
             return [], 0
         from dynamo_tpu.engine.kv_cache import page_hash
         ps = self.cfg.page_size
@@ -854,6 +894,7 @@ class Scheduler:
         for pid in seq.pages:
             self.allocator.free(pid)
         seq.pages = []
+        self._free_window_pages(seq)
         self.params.pop(seq.request_id, None)
 
     def abort(self, request_id: str) -> bool:
@@ -893,16 +934,84 @@ class Scheduler:
         return -1
 
     def _ensure_pages(self, seq: SequenceState, upto_len: int) -> bool:
-        """Allocate pages so positions [0, upto_len) have slots."""
+        """Allocate pages so positions [0, upto_len) have slots: in the
+        full pool all of them, in the window pool (where there is one)
+        those from the sequence's first held page on. Both or neither:
+        a sequence blocked on either pool takes nothing from the other."""
         ps = self.cfg.page_size
-        need = -(-upto_len // ps) - len(seq.pages)
-        if need <= 0:
+        upto_pages = -(-upto_len // ps)
+        need = max(0, upto_pages - len(seq.pages))
+        wneed = 0
+        if self.window_alloc is not None:
+            if not seq.wpages:
+                # nothing held: the list starts where the next position's
+                # window does
+                seq.wfirst = self._window_first_page(seq)
+            wneed = max(0, upto_pages - seq.wfirst - len(seq.wpages))
+        if not need and not wneed:
             return True
-        if not self.allocator.can_allocate(need):
+        if not self.allocator.can_allocate(need) or (
+                wneed and not self.window_alloc.can_allocate(wneed)):
             return False
         for _ in range(need):
             seq.pages.append(self.allocator.allocate())
+        for _ in range(wneed):
+            seq.wpages.append(self.window_alloc.allocate())
         return True
+
+    def _window_first_page(self, seq: SequenceState) -> int:
+        """The first logical page the sequence's NEXT position still
+        sees in a sliding layer: position p = num_cached attends keys j
+        with p - window < j <= p."""
+        return max(0, seq.num_cached - self.window_tokens + 1) \
+            // self.cfg.page_size
+
+    def _release_window_pages(self, seq: SequenceState) -> None:
+        """At a commit: hand back the window-pool pages that lie wholly
+        behind the window of the sequence's next position. A chunk's
+        pages outlive the chunk (its FIRST token saw them); what goes is
+        what no later token sees. A window already dispatched against
+        the old table may still gather a released page: every key in it
+        is outside that window's masks, and the device runs programs in
+        order, so whoever takes the page next writes it afterwards."""
+        if self.window_alloc is None:
+            return
+        first = self._window_first_page(seq)
+        while seq.wpages and seq.wfirst < first:
+            self.window_alloc.free(seq.wpages.pop(0))
+            seq.wfirst += 1
+            self.window_released += 1
+
+    def _free_window_pages(self, seq: SequenceState) -> None:
+        """The sequence ends or is preempted: its whole second list."""
+        if self.window_alloc is None:
+            return
+        for pid in seq.wpages:
+            self.window_alloc.free(pid)
+        seq.wpages, seq.wfirst = [], 0
+
+    def window_table_pages(self, chunk: int) -> int:
+        """`window_table_pages` for this scheduler's window."""
+        return window_table_pages(self.cfg, self.window_tokens, chunk)
+
+    def _window_rows(self, seqs, wb: int) -> dict:
+        """A plan's window-pool tables (PrefillPlan / DecodePlan fields),
+        {} on an engine without that pool: `wtable` [rows, wb] and `woff`
+        [rows], the position of each table's first key."""
+        if self.window_alloc is None:
+            return {}
+        ps = self.cfg.page_size
+        wtable = np.zeros((len(seqs), wb), np.int32)
+        woff = np.zeros((len(seqs),), np.int32)
+        for i, seq in enumerate(seqs):
+            if seq is None:
+                continue
+            assert len(seq.wpages) <= wb, (
+                f"{seq.request_id} holds {len(seq.wpages)} window pages, "
+                f"the table has {wb}")
+            wtable[i, :len(seq.wpages)] = seq.wpages
+            woff[i] = seq.wfirst * ps
+        return {"wtable": wtable, "woff": woff}
 
     def _seal_full_pages(self, seq: SequenceState) -> None:
         """Hash pages that just became full of computed tokens (emit events)."""
@@ -1196,6 +1305,9 @@ class Scheduler:
         tokens = np.zeros((bb, tb), np.int32)
         positions = np.zeros((bb, tb), np.int32)
         write_idx = np.full((bb, tb), -1, np.int32)
+        # the same cells' slots in the window pool, where there is one
+        windowed = self.window_alloc is not None
+        wwrite_idx = np.full((bb, tb), -1, np.int32) if windowed else None
         kv_lens = np.zeros((bb,), np.int32)
         last = np.zeros((bb,), np.int32)
         max_pages = max(max(len(s.pages) for s, _, _ in batch), 1)
@@ -1224,6 +1336,8 @@ class Scheduler:
             tokens[i, 0] = seq.output[-1] if seq.output else seq.prompt[-1]
             positions[i, :] = pos
             write_idx[i, 0] = seq.flat_index(pos, ps)
+            if windowed:
+                wwrite_idx[i, 0] = seq.wflat_index(pos, ps)
             page_table[i, :len(seq.pages)] = seq.pages
             kv_lens[i] = pos + 1
             last[i] = 0
@@ -1238,6 +1352,8 @@ class Scheduler:
             positions[i, :n] = np.arange(start, start + n)
             for t in range(n):
                 write_idx[i, t] = seq.flat_index(start + t, ps)
+                if windowed:
+                    wwrite_idx[i, t] = seq.wflat_index(start + t, ps)
             page_table[i, :len(seq.pages)] = seq.pages
             kv_lens[i] = start + n
             last[i] = n - 1
@@ -1257,7 +1373,8 @@ class Scheduler:
             page_table=page_table, kv_lens=kv_lens, write_idx=write_idx,
             last_idx=last, n_valid=n_valid, is_last_chunk=is_last,
             mm_embeds=mm_embeds, mm_mask=mm_mask,
-            state_slots=self._state_slot_row(seqs))
+            state_slots=self._state_slot_row(seqs), wwrite_idx=wwrite_idx,
+            **self._window_rows(seqs, self.window_table_pages(tb)))
         if nd:
             return MixedPlan(is_decode=is_decode, **kw)
         return PrefillPlan(**kw)
@@ -1272,6 +1389,7 @@ class Scheduler:
         seq.num_cached += plan.n_valid[i]
         seq.num_computed += plan.n_valid[i]
         self._seal_full_pages(seq)
+        self._release_window_pages(seq)
         if plan.is_last_chunk[i]:
             assert sampled_token is not None
             if seq.prefill_only:
@@ -1395,7 +1513,9 @@ class Scheduler:
             page_table=page_table, kv_lens=kv_lens, write_idx=write_idx,
             last_idx=np.zeros((s_count,), np.int32), max_pos=max_pos,
             n_window=n_window, stop_ids=stop_ids,
-            state_slots=self._state_slot_row(seqs))
+            state_slots=self._state_slot_row(seqs),
+            # (a window computes its own slots in the window pool)
+            **self._window_rows(seqs, self.window_table_pages(1)))
 
     def _preempt_one(self) -> None:
         """Evict one running seq back to waiting under MEMORY pressure.
@@ -1479,6 +1599,7 @@ class Scheduler:
         for pid in victim.pages:
             self.allocator.free(pid)
         victim.pages = []
+        self._free_window_pages(victim)
         victim.page_hashes = []
         victim.num_cached = 0
         victim.num_computed = 0
@@ -1509,6 +1630,7 @@ class Scheduler:
         seq.num_cached += 1  # the fed token's KV is now resident
         seq.num_computed += 1
         self._seal_full_pages(seq)
+        self._release_window_pages(seq)
         seq.output.append(int(tok))
 
     def commit_decode(self, plan: DecodePlan, sampled: np.ndarray):

@@ -20,11 +20,14 @@ cfg.num_experts > 0.
 # host syncs (.item(), device_get, float()) are dynalint R6 findings
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, NamedTuple, Optional
 
 import jax
+import numpy as np
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -110,6 +113,15 @@ class AttnMetadata:
     # [B] int32: each row's recurrent-state slot (-1 = none); only a model
     # with linear-attention layers has one (ModelConfig.state_leaves)
     state_slots: Optional[jax.Array] = None
+    # a model with a window pool (`cfg.window_pool`): each row's table of
+    # the pages it holds THERE [B, Wb], the position of that table's first
+    # key [B] (a row holds only the pages its step can see, so its table
+    # starts at its first held page, not at position 0), and the flat
+    # slots its new rows take in the window pool [B, Tq] (>= 0 exactly
+    # where `write_idx` is)
+    wtable: Optional[jax.Array] = None
+    woff: Optional[jax.Array] = None
+    wwrite_idx: Optional[jax.Array] = None
 
 
 def _dtype(cfg: ModelConfig):
@@ -125,10 +137,11 @@ class LayerRun(NamedTuple):
     first: int        # the run's first layer, in the model's order
     count: int
     dense: bool       # a dense MLP (False: experts)
-    kind: str         # attention kind: "mha" | "mla" | "kda"
+    kind: str         # attention kind: "mha" | "mla" | "kda" | "swa"
     # the run's first layer among the layers that share its STORE: the
     # paged cache's layer axis runs over the "mha" / "mla" layers, the
-    # recurrent state's over the "kda" ones (== first where all alike)
+    # recurrent state's over the "kda" ones, the window pool's over the
+    # "swa" ones (== first where all alike)
     store_first: int
 
     def store_index(self, lid):
@@ -148,20 +161,30 @@ def layer_runs(cfg: ModelConfig) -> tuple:
     leading axis so the grouped matmul reads them in place (no slice of
     one stack by kind). A hybrid (`linear_group_size`) is split by
     attention kind as well, a stack a run of (attention kind, MLP kind):
-    `params["run0"]`, `params["run1"]`, ... in layer order. init_params,
+    `params["run0"]`, `params["run1"]`, ... in layer order. A model
+    whose sliding layers have a pool of their own (`window_pool`) has a
+    stack a KIND, `run0` and `run1` in the order the kinds first appear:
+    ALL its "swa" layers and ALL its "mha" layers, which do not lie side
+    by side in the model (S S S F S S S F ...); `layer_period` says in
+    what order the loop takes them. init_params,
     param_shardings, forward(), decode_forward() and models/loader.py
     all walk this."""
     lead = cfg.first_dense_layers if cfg.is_moe else 0
     own = "mla" if cfg.is_mla else "mha"
-    if not cfg.has_linear_layers:
+    kinds = cfg.layer_kinds()
+    if cfg.window_pool:
+        order = list(dict.fromkeys(kinds))
+        return tuple(LayerRun(f"run{i}", kinds.index(kind),
+                              kinds.count(kind), not cfg.is_moe, kind, 0)
+                     for i, kind in enumerate(order))
+    if len(set(kinds)) == 1:
         if not lead:
             return (LayerRun("layers", 0, cfg.num_layers, not cfg.is_moe,
                              own, 0),)
         return (LayerRun("dense_layers", 0, lead, True, own, 0),
                 LayerRun("layers", lead, cfg.num_layers - lead, False, own,
                          lead))
-    kinds = cfg.layer_kinds()
-    runs, seen = [], {"kda": 0, own: 0}
+    runs, seen = [], dict.fromkeys(kinds, 0)
     for i, kind in enumerate(kinds):
         dense = not cfg.is_moe or i < lead
         if runs and (runs[-1].kind, runs[-1].dense) == (kind, dense):
@@ -171,6 +194,43 @@ def layer_runs(cfg: ModelConfig) -> tuple:
                                  seen[kind]))
         seen[kind] += 1
     return tuple(runs)
+
+
+class LayerPeriod(NamedTuple):
+    """The order in which a `window_pool` model's loop takes the layers
+    of its two stacks: `count` periods, each the same `parts`."""
+    count: int
+    # ((index in layer_runs, the kind's layers a period, the part's first
+    # among them, the part's layers), ...): layer j of a part in period p
+    # is layer p * per + offset + j of its run's stack
+    parts: tuple
+
+
+def layer_period(cfg: ModelConfig) -> Optional[LayerPeriod]:
+    """None but for a `window_pool` model. Its kinds repeat with a period
+    (S S S F: the shortest prefix whose repetition is the model; the
+    whole model where nothing repeats), and ONE compiled loop serves it:
+    a scan over periods whose body is a scan a part (models/llama.forward,
+    decode_forward). A scan a run of like layers, as the hybrid has,
+    would compile six layer bodies for S S S F x 3 where this compiles
+    two, and every engine program pays that (PERF.md section 6, PR 38)."""
+    if not cfg.window_pool:
+        return None
+    kinds, runs = cfg.layer_kinds(), layer_runs(cfg)
+    n = len(kinds)
+    size = next(p for p in range(1, n + 1)
+                if n % p == 0 and kinds == kinds[:p] * (n // p))
+    index = {run.kind: i for i, run in enumerate(runs)}
+    parts, seen = [], dict.fromkeys(index, 0)
+    for i, kind in enumerate(kinds[:size]):
+        if parts and parts[-1][0] == index[kind] and i > 0 \
+                and kinds[i - 1] == kind:
+            parts[-1][3] += 1
+        else:
+            parts.append([index[kind], kinds[:size].count(kind),
+                          seen[kind], 1])
+        seen[kind] += 1
+    return LayerPeriod(n // size, tuple(tuple(part) for part in parts))
 
 
 def layer_groups(cfg: ModelConfig) -> tuple:
@@ -312,7 +372,7 @@ def _init_layer_stack(keys, cfg: ModelConfig, l: int, dense_mlp: bool,
             "w_up": dense(keys[6], (l, d, f), d),
             "w_down": dense(keys[7], (l, f, d), f),
         })
-    if cfg.has_linear_layers:
+    if cfg.has_linear_layers or cfg.window_pool:
         # a hybrid's block norms too are drawn away from one
         layers["attn_norm"] = near_one(jax.random.fold_in(more[5], 3), (l, d))
         layers["mlp_norm"] = near_one(jax.random.fold_in(more[5], 4), (l, d))
@@ -339,7 +399,7 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
             else jax.random.split(jax.random.fold_in(rng, 1 + run.first),
                                   12),
             cfg, run.count, run.dense, run.kind)
-    if cfg.has_linear_layers:
+    if cfg.has_linear_layers or cfg.window_pool:
         params["final_norm"] = (1.0 + 0.1 * jax.random.normal(
             jax.random.fold_in(rng, 77), (d,), jnp.float32)).astype(dt)
     if not cfg.tie_word_embeddings:
@@ -459,19 +519,26 @@ def cache_scale_sharding(cfg: ModelConfig) -> P:
 
 def cache_shardings(cfg: ModelConfig) -> Dict[str, P]:
     """Per-leaf PartitionSpecs matching init_cache's dict layout."""
-    out = {name: cache_sharding(cfg) for name in cfg.kv_cache_leaves()}
+    out = {name: cache_sharding(cfg) for name in
+           (*cfg.kv_cache_leaves(), *cfg.window_cache_leaves())}
     if _validate_kv_quant(cfg.kv_quant):
         out.update({f"{name}_scale": cache_scale_sharding(cfg)
                     for name in cfg.kv_cache_leaves()})
     return out
 
 
-def init_cache(cfg: ModelConfig, num_pages: int, page_size: int) -> Dict[str, jax.Array]:
+def init_cache(cfg: ModelConfig, num_pages: int, page_size: int,
+               window_pages: int = 0) -> Dict[str, jax.Array]:
     """The paged pool, a leaf per entry of `cfg.kv_cache_leaves()`:
-    [L, heads, pages, page_size, width]."""
+    [L, heads, pages, page_size, width]; and, for a model with a window
+    pool, a leaf per entry of `cfg.window_cache_leaves()` over ITS
+    layers and `window_pages` pages."""
     shapes = {name: (cfg.num_cache_layers, heads, num_pages, page_size,
                      width)
               for name, (heads, width) in cfg.kv_cache_leaves().items()}
+    shapes.update({
+        name: (cfg.num_window_layers, heads, window_pages, page_size, width)
+        for name, (heads, width) in cfg.window_cache_leaves().items()})
     if _validate_kv_quant(cfg.kv_quant):
         # int8 pages + per-row f32 scales (ops/kv_quant.py): the scale
         # array shares the page axis (2) with the values, so every
@@ -588,6 +655,59 @@ def refuse_unserved_recurrent_state(cfg: ModelConfig, engine_cfg=None,
             f"with it yet: " + "; ".join(why))
 
 
+def refuse_unserved_window_cache(cfg: ModelConfig, engine_cfg=None,
+                                 mesh=None, feature: str = "") -> None:
+    """THE place that says what a model whose sliding layers keep their
+    pages in a pool of their own (`cfg.window_pool`) cannot be served
+    with yet; every other model passes. Beside
+    `refuse_unserved_latent_cache` / `refuse_unserved_recurrent_state`,
+    and called where they are. A sequence there has TWO page lists, and
+    the second forgets: each of these names pages by one list, moves a
+    context as the pages of one pool, or goes back over positions whose
+    window pages may be gone. Prefix reuse is switched off in the
+    scheduler instead, and said once in the log: a hit would need the
+    window layers' pages of its last `sliding_window` tokens too, and
+    those were released as their sequence moved on."""
+    if not cfg.window_pool:
+        return
+    why = []
+    if feature:
+        why.append(feature)
+    if cfg.is_mla or cfg.has_linear_layers:
+        why.append("latent or linear attention beside the window layers")
+    if mesh is not None and mesh.size > 1:
+        why.append(f"a {dict(mesh.shape)} mesh (--tp/--pp/--ep/--sp/--dp: "
+                   f"parallel/mesh.kv_shard_layout and the pp / sp "
+                   f"programs know one pool)")
+    if cfg.kv_quant:
+        why.append(f"kv_quant={cfg.kv_quant!r} (the codec's scale leaves "
+                   f"follow one pool)")
+    if cfg.quant:
+        why.append(f"quant={cfg.quant!r} (ops/quant.py names "
+                   f"params['layers'])")
+    if cfg.decode_kernel not in ("auto", "off"):
+        why.append(f"decode_kernel={cfg.decode_kernel!r} (the Pallas "
+                   f"kernel has no window and walks one page table)")
+    if cfg.vision is not None:
+        why.append("a vision tower")
+    if engine_cfg is not None:
+        if engine_cfg.host_pages or engine_cfg.disk_pages \
+                or engine_cfg.stream_pages:
+            why.append("the host / disk KV tiers and streamed decode "
+                       "(--host-pages, --disk-pages, --stream-pages)")
+        if engine_cfg.kv_quant:
+            why.append(f"kv_quant={engine_cfg.kv_quant!r}")
+        if engine_cfg.spec_decode:
+            why.append(f"spec_decode={engine_cfg.spec_decode!r} (a "
+                       f"verify block is not planned over two page "
+                       f"lists)")
+    if why:
+        raise ValueError(
+            f"{cfg.name}: {cfg.num_window_layers} sliding layers keep "
+            f"their last {cfg.sliding_window} tokens in a page pool of "
+            f"their own; not served with it yet: " + "; ".join(why))
+
+
 # -- forward ------------------------------------------------------------------
 
 def rms_norm(x: jax.Array, w: jax.Array, eps: float,
@@ -616,13 +736,79 @@ def scale_embeds(x: jax.Array, cfg: ModelConfig) -> jax.Array:
     return x
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [B, T, H, hd]; positions: [B, T]."""
+def yarn_inv_freq(p, dim: int) -> np.ndarray:
+    """YaRN's frequency table [dim / 2] for one layer kind (`p`:
+    engine/config.RopeParams), float32 on the host, as `transformers`
+    computes it: f_i = theta ** (2i / dim); the extrapolated 1 / f_i and
+    the interpolated 1 / (factor f_i) blend by a linear ramp over i
+    between low = floor(c(beta_fast)) and high = ceil(c(beta_slow)),
+    c(r) = dim ln(L0 / (2 pi r)) / (2 ln theta), clipped to [0, dim - 1]:
+    dimensions that turn more than beta_fast times within the original
+    context L0 keep their frequency, those that turn less than beta_slow
+    times are stretched by `factor`."""
+    f32 = np.float32
+    pos_freqs = f32(p.theta) ** (np.arange(0, dim, 2, dtype=f32) / f32(dim))
+    extrapolated = f32(1.0) / pos_freqs
+    interpolated = f32(1.0) / (f32(p.factor) * pos_freqs)
+
+    def correction(turns):
+        return dim * math.log(p.original_max_position
+                              / (turns * 2 * math.pi)) \
+            / (2 * math.log(p.theta))
+    low = max(math.floor(correction(p.beta_fast)), 0)
+    high = min(math.ceil(correction(p.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001   # transformers' guard against a zero-width ramp
+    ramp = np.clip((np.arange(dim // 2, dtype=f32) - f32(low))
+                   / f32(high - low), 0, 1).astype(f32)
+    return (interpolated * ramp + extrapolated * (f32(1.0) - ramp)
+            ).astype(f32)
+
+
+def _full_scope(cfg: ModelConfig):
+    """`attention.full` around a full layer's attention where the model
+    also has window layers (`attention.window`); no scope elsewhere."""
+    return jax.named_scope("attention.full") if cfg.window_pool \
+        else contextlib.nullcontext()
+
+
+def rope_table(cfg: ModelConfig, kind: str = "") -> tuple:
+    """(theta, frequency table or None, cos / sin scale) of one layer
+    kind's RoPE, built on the host from the config: what `apply_rope`
+    takes after x and the positions. `kind` "swa": `cfg.rope_sliding`;
+    any other: `cfg.rope_full`. A kind without parameters of its own, or
+    with plain ones, is (theta, None, 1.0): `apply_rope` then computes
+    1 / theta ** (2i / d) inline, the one expression every program had
+    before RoPE went by kind, so those programs are the same programs."""
+    p = cfg.rope_sliding if kind == "swa" else cfg.rope_full
+    if p is None:
+        return cfg.rope_theta, None, 1.0
+    if p.rope_type == "default":
+        return p.theta, None, 1.0
+    if p.rope_type != "yarn":
+        raise ValueError(f"{cfg.name}: rope_type {p.rope_type!r} is not "
+                         f"modelled (default, yarn)")
+    # Python numbers of the config throughout: nothing here is traced
+    scale = p.attention_factor or 0.1 * math.log(p.factor) + 1.0
+    return p.theta, yarn_inv_freq(p, cfg.head_dim), scale
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               inv_freq: Optional[np.ndarray] = None,
+               scale: float = 1.0) -> jax.Array:
+    """x: [B, T, H, hd]; positions: [B, T]. `inv_freq` [hd / 2] (a host
+    float32 table, `rope_table`) replaces the plain 1 / theta ** (2i /
+    hd); `scale` multiplies cos and sin (YaRN's attention factor)."""
     hd = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, jnp.float32) / hd))  # [hd/2]
+    if inv_freq is None:
+        freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, jnp.float32) / hd))
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)                     # [hd/2]
     angles = positions[..., None].astype(jnp.float32) * freqs          # [B, T, hd/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -778,10 +964,9 @@ def layer_front(x: jax.Array, lp: Params, cfg: ModelConfig,
     h, hkv = heads
     xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
     q, k, v = qkv_proj(xn, lp, cfg)
-    q = apply_rope(q.reshape(b, t, h, cfg.head_dim), positions,
-                   cfg.rope_theta)
-    k = apply_rope(k.reshape(b, t, hkv, cfg.head_dim), positions,
-                   cfg.rope_theta)
+    rope = rope_table(cfg, kind)
+    q = apply_rope(q.reshape(b, t, h, cfg.head_dim), positions, *rope)
+    k = apply_rope(k.reshape(b, t, hkv, cfg.head_dim), positions, *rope)
     return q, k, v.reshape(b, t, hkv, cfg.head_dim)
 
 
@@ -1157,8 +1342,20 @@ def decode_forward(
     with_aux: bool = False,
     window: Optional[tuple] = None,  # split-KV window fast path, see below
     state: Optional[tuple] = None,   # ((kda_s, kda_conv), slots [B])
+    swa: Optional[tuple] = None,     # the window layers' split-KV view
 ) -> tuple:
     """Deferred-write decode step: the KV cache is READ-ONLY.
+
+    `swa` (a model with a window pool, `cfg.window_pool`; only with
+    `window`): (k_base, v_base [Lw, Hkv, B, Wb * ps, hd], k_win, v_win
+    [Lw, Hkv, B, Nw, hd], base_lens [B]) — what `window` is to the full
+    layers, gathered from each row's table in the WINDOW pool, whose
+    first key sits at the row's `woff`; `base_lens` is in that table's
+    coordinates (the valid kv at window start less `woff`), in which
+    every mask of ops/attention.decode_attention_split holds as it is
+    (`win_lens` is a difference and moves with neither). The window
+    layers' new rows come back LAST, as (wk_new, wv_new [Lw, B, Hkv,
+    hd]): k_new / v_new cover the layers of the full pool.
 
     `state` (a model with linear-attention layers): the recurrent state's
     leaves and each row's slot in them. Unlike the cache it is WRITTEN
@@ -1212,6 +1409,13 @@ def decode_forward(
     if window is not None:
         kb_all, vb_all, kw_all, vw_all, base_lens, win_lens = window
         win_leaves = (kb_all, vb_all, kw_all, vw_all)
+    if cfg.window_pool and (window is None or swa is None):
+        raise NotImplementedError(
+            f"{cfg.name}: a decode step of a model with a window pool "
+            f"reads its window layers from the split-KV view (`window` "
+            f"and `swa`); the per-step gather and the kernel are not "
+            f"planned over two page tables")
+    swa_wnd = jnp.int32(cfg.sliding_window) if cfg.window_pool else None
     row_valid = valid if valid is not None else jnp.ones(tokens.shape, bool)
 
     def kda_step_layer(carry, xs, run, expert_stacks):
@@ -1228,15 +1432,30 @@ def decode_forward(
                 lid - run.first, run.dense), kind="kda")
         return (x, st), drop_stats if moe_aux else None
 
-    def layer_step(x, xs, run, expert_stacks):
+    def layer_step(x, xs, run, expert_stacks, stack=None):
         lp, lid, wnd, win = xs
         first, dense = run.first, run.dense
+        if lp is None:
+            # a period's part: the layer read from its kind's stack
+            lp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+                a, lid - first, keepdims=False), stack)
         # this layer's index in the cache's (and the window's) layer axis
         cl = run.store_index(lid)
-        q, k, v = layer_front(x, lp, cfg, positions[:, None], heads)
+        q, k, v = layer_front(x, lp, cfg, positions[:, None], heads,
+                              run.kind)
         k_new = k[:, 0]                                  # [B, Hkv, hd]
         v_new = None if v is None else v[:, 0]
-        if window is not None:
+        if run.kind == "swa":
+            # a window layer: its own base and buffer, by its index among
+            # the window layers, in its table's coordinates
+            kb, vb, kw, vw = (jax.lax.dynamic_index_in_dim(
+                a, cl, keepdims=False) for a in swa[:4])
+            with jax.named_scope("attention.window"):
+                attn = decode_attention_split(
+                    q[:, 0], kb, vb, kw, vw, k_new, v_new, swa[4], win_lens,
+                    softcap=cfg.attn_softcap, window=swa_wnd,
+                    q_scale=cfg.query_scale)
+        elif window is not None:
             # one layer group: the window's leaves are the scan's xs. A
             # second group reads its layers from the whole leaves by
             # index, which is what a scan does with its xs; slicing them
@@ -1244,10 +1463,11 @@ def decode_forward(
             kb, vb, kw, vw = win if whole else tuple(
                 None if a is None else jax.lax.dynamic_index_in_dim(
                     a, cl, keepdims=False) for a in win_leaves)
-            attn = decode_attention_split(
-                q[:, 0], kb, vb, kw, vw, k_new, v_new, base_lens, win_lens,
-                softcap=cfg.attn_softcap, window=wnd,
-                q_scale=cfg.query_scale)
+            with _full_scope(cfg):
+                attn = decode_attention_split(
+                    q[:, 0], kb, vb, kw, vw, k_new, v_new, base_lens,
+                    win_lens, softcap=cfg.attn_softcap, window=wnd,
+                    q_scale=cfg.query_scale)
         elif kernel_mode is not None:
             interp = kernel_mode == "interpret"
             # int8 caches hand the kernels the raw pages plus the scale
@@ -1288,7 +1508,42 @@ def decode_forward(
         return x, (k_new, v_new, drop_stats if moe_aux else None)
 
     k_news, v_news, drops = [], [], []
+    wk_news, wv_news = [], []
     st = None if state is None else state[0]
+    period = layer_period(cfg)
+    if period is not None:
+        # ONE loop: a scan over periods, a scan a part inside it; the new
+        # rows come out [periods, a kind's layers a period, ...] = the
+        # kind's store order
+        stacks = [split_expert_stacks(params[run.key], cfg, mesh)
+                  for run in runs]
+
+        def period_step(x, p):
+            rows = [([], []) for _ in runs]
+            stats = []
+            for ri, per, offset, count in period.parts:
+                run = runs[ri]
+                lids = run.first + p * per + offset \
+                    + jnp.arange(count, dtype=jnp.int32)
+                x, (k_g, v_g, drop_g) = jax.lax.scan(
+                    functools.partial(layer_step, run=run,
+                                      expert_stacks=stacks[ri][1],
+                                      stack=stacks[ri][0]),
+                    x, (None, lids, None, None))
+                rows[ri][0].append(k_g)
+                rows[ri][1].append(v_g)
+                stats.append(_sum_stats(drop_g))
+            return x, (tuple(tuple(jnp.concatenate(g, axis=0) for g in kv)
+                             for kv in rows), _merge_stats(stats))
+
+        x, (rows, drop_p) = jax.lax.scan(
+            period_step, x, jnp.arange(period.count, dtype=jnp.int32))
+        for run, (k_g, v_g) in zip(runs, rows):
+            k_g, v_g = (g.reshape((-1,) + g.shape[2:]) for g in (k_g, v_g))
+            (wk_news if run.kind == "swa" else k_news).append(k_g)
+            (wv_news if run.kind == "swa" else v_news).append(v_g)
+        drops.append(_sum_stats(drop_p))
+        runs = ()
     for run in runs:
         name, first, count, dense = run[:4]
         scan_layers, expert_stacks = (params[name], None) if dense \
@@ -1311,14 +1566,17 @@ def decode_forward(
         k_news.append(k_g)
         v_news.append(v_g)
         drops.append(_sum_stats(drop_g))
-    k_news, v_news = (
-        None if g[0] is None else g[0] if len(g) == 1
-        else jnp.concatenate(g, axis=0) for g in (k_news, v_news))
+    k_news, v_news, wk_news, wv_news = (
+        None if not g or g[0] is None else g[0] if len(g) == 1
+        else jnp.concatenate(g, axis=0)
+        for g in (k_news, v_news, wk_news, wv_news))
     aux = _merge_stats(drops)
     logits = lm_logits(x[:, 0], params["final_norm"], lm_head(params, cfg),
                        cfg)
     out = (logits, k_news, v_news) + ((aux,) if with_aux else ())
-    return out if state is None else out + (st,)
+    if state is not None:
+        out += (st,)
+    return out + ((wk_news, wv_news),) if cfg.window_pool else out
 
 
 def step_compaction(write_idx, sp_mesh=None) -> Optional[tuple]:
@@ -1446,6 +1704,22 @@ def forward(
     # real (non-padding) positions: padding slots carry write_idx < 0
     grid_valid = meta.write_idx >= 0
     write_plan = kv_write_plan(meta.write_idx)
+    # a model with a window pool writes every real token's row a second
+    # time, into the window pool at ITS slot: the same rows in the same
+    # order (`wwrite_idx` >= 0 exactly where `write_idx` is)
+    wwrite_plan = wkv_lens = wpositions = None
+    if cfg.window_pool:
+        if use_ring or use_kernel or meta.wtable is None:
+            raise NotImplementedError(
+                f"{cfg.name}: a window pool is served by the gather path "
+                f"alone, with the window tables in `meta`")
+        wwrite_plan = write_plan._replace(
+            write_idx=meta.wwrite_idx.reshape(-1))
+        # the window table's own coordinates: key 0 is the row's first
+        # held key, and every mask of paged_attention holds as it is
+        wkv_lens = meta.kv_lens - meta.woff
+        wpositions = meta.positions - meta.woff[:, None]
+        swa_wnd = jnp.int32(cfg.sliding_window)
     layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
     runs = layer_runs(cfg)
     whole = len(runs) == 1
@@ -1467,6 +1741,12 @@ def forward(
         sel = compact_index(write_plan, width)
         write_plan = jax.tree.map(functools.partial(jnp.where, fits),
                                   sel.plan, write_plan)
+        if wwrite_plan is not None:
+            flat_w = sel.plan._replace(write_idx=jnp.pad(
+                jnp.where(sel.live, wwrite_plan.write_idx[sel.cells], -1),
+                (0, n - width), constant_values=-1))
+            wwrite_plan = jax.tree.map(
+                functools.partial(jnp.where, fits), flat_w, wwrite_plan)
 
     def either(fn, *operands):
         """fn(sel, ...) over a compact step's flat rows, or fn(None, ...)
@@ -1516,8 +1796,9 @@ def forward(
 
     def layer_step(carry, layer, stack, run, expert_stacks):
         # pool: (k, v[, k_scale, v_scale]) stacks; state: the recurrent
-        # state's leaves, () for a model without linear layers
-        x, pool, state = carry
+        # state's leaves, () for a model without linear layers; wpool:
+        # the window layers' (wk, wv), () for a model without that pool
+        x, pool, state, wpool = carry
         lp, lid, wnd = layer
         first, dense, kind = run.first, run.dense, run.kind
         # this layer's index among the layers that share its store
@@ -1584,7 +1865,7 @@ def forward(
                 meta.positions[:, 0] == 0)
             x, drop_stats = either(functools.partial(back, stored=True),
                                    x, state[2])
-            return (x, pool, state), drop_stats
+            return (x, pool, state, wpool), drop_stats
         q, k, v = either(front, x)
         if kind == "kda":
             # the grid's part of a linear layer: each row's convolution
@@ -1593,7 +1874,21 @@ def forward(
                 state, sl, meta.state_slots, lp_of(), cfg, q, k, v,
                 grid_valid, meta.positions[:, 0] == 0)
             x, drop_stats = either(back, x, attn)
-            return (x, pool, state), drop_stats
+            return (x, pool, state, wpool), drop_stats
+        if kind == "swa":
+            # a window layer: the same write and read against ITS pool,
+            # over the short table of the pages the rows hold there
+            wpool = write_kv_rows(
+                wpool, tuple(r.reshape((1, n) + r.shape[2:])
+                             for r in stored_kv_rows(k, v, False)),
+                wwrite_plan, sl[None])
+            with jax.named_scope("attention.window"):
+                attn = paged_attention(
+                    q, wpool[0], wpool[1], meta.wtable, wkv_lens,
+                    wpositions, softcap=cfg.attn_softcap, window=swa_wnd,
+                    q_scale=cfg.query_scale, layer=sl)
+            x, drop_stats = either(back, x, attn)
+            return (x, pool, state, wpool), drop_stats
         # rows as stored (an int8 pool quantizes them here, at capture);
         # [B, Tq, Hkv, ...] -> this layer's [1, B*Tq, Hkv, ...]
         pool = write_kv_rows(
@@ -1622,13 +1917,15 @@ def forward(
         else:
             # the one op that needs the grid: every query beside its row's
             # page table, against the pool just written
-            attn = paged_attention(q, kc, vc, meta.page_table, meta.kv_lens,
-                                   meta.positions, softcap=cfg.attn_softcap,
-                                   window=wnd, q_scale=cfg.query_scale,
-                                   k_scale=ksc, v_scale=vsc, layer=sl)
+            with _full_scope(cfg):
+                attn = paged_attention(
+                    q, kc, vc, meta.page_table, meta.kv_lens,
+                    meta.positions, softcap=cfg.attn_softcap, window=wnd,
+                    q_scale=cfg.query_scale, k_scale=ksc, v_scale=vsc,
+                    layer=sl)
 
         x, drop_stats = either(back, x, attn)
-        return (x, pool, state), drop_stats
+        return (x, pool, state, wpool), drop_stats
 
     # the stacked leaves ride the scan's carry whole, in the stored
     # representation  # dynalint: kv-codec — values are encoded at the
@@ -1637,11 +1934,38 @@ def forward(
     pool = tuple(cache[key] for key in pool_keys)
     state_keys = tuple(cfg.state_leaves())
     state = tuple(cache[key] for key in state_keys)
+    wpool_keys = tuple(cfg.window_cache_leaves())
+    wpool = tuple(cache[key] for key in wpool_keys)
     if kda_plan is not None:
         # the scratch that carries a linear layer's o to its back half
         state += (jnp.zeros((n, cfg.num_heads, cfg.linear_head_dim),
                             jnp.float32),)
     drops = []
+    period = layer_period(cfg)
+    if period is not None:
+        # ONE loop: a scan over periods, a scan a part inside it, every
+        # layer read from its kind's stack where it lies (`lp_of`)
+        stacks = [split_expert_stacks(params[run.key], cfg, mesh)
+                  for run in runs]
+
+        def period_step(carry, p):
+            stats = []
+            for ri, per, offset, count in period.parts:
+                run = runs[ri]
+                lids = run.first + p * per + offset \
+                    + jnp.arange(count, dtype=jnp.int32)
+                carry, drop_g = jax.lax.scan(
+                    functools.partial(layer_step, stack=stacks[ri][0],
+                                      run=run, expert_stacks=stacks[ri][1]),
+                    carry, (None, lids, None))
+                stats.append(_sum_stats(drop_g))
+            return carry, _merge_stats(stats)
+
+        (x, pool, state, wpool), drop_p = jax.lax.scan(
+            period_step, (x, pool, state, wpool),
+            jnp.arange(period.count, dtype=jnp.int32))
+        drops.append(_sum_stats(drop_p))
+        runs = ()
     for run in runs:
         name, first, count, dense = run[:4]
         scan_layers, expert_stacks = (params[name], None) if dense \
@@ -1649,10 +1973,10 @@ def forward(
         part = _group_rows(whole, first, count)
         scan_xs = (scan_layers if sel is None else None, part(layer_ids),
                    None if layer_wnd is None else part(layer_wnd))
-        (x, pool, state), drop_g = jax.lax.scan(
+        (x, pool, state, wpool), drop_g = jax.lax.scan(
             functools.partial(layer_step, stack=scan_layers, run=run,
                               expert_stacks=expert_stacks),
-            (x, pool, state), scan_xs)
+            (x, pool, state, wpool), scan_xs)
         drops.append(_sum_stats(drop_g))
     aux = _merge_stats(drops)
 
@@ -1663,8 +1987,8 @@ def forward(
             at = jnp.where(fits, sel.slot[at], at)
         x = jnp.take(x.reshape(n, -1), at, axis=0, mode="clip")
     logits = lm_logits(x, params["final_norm"], lm_head(params, cfg), cfg)
-    cache_out = dict(zip(pool_keys + state_keys,
-                         pool + state[:len(state_keys)]))
+    cache_out = dict(zip(pool_keys + state_keys + wpool_keys,
+                         pool + state[:len(state_keys)] + wpool))
     if with_aux:
         return logits, cache_out, aux
     return logits, cache_out

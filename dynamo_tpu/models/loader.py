@@ -31,6 +31,7 @@ ARCHES = {
     "OlmoeForCausalLM": "olmoe",
     "DeepseekV3ForCausalLM": "deepseek_v3",
     "BailingHybridForCausalLM": "bailing_hybrid",
+    "MellumForCausalLM": "mellum",
 }
 
 
@@ -46,7 +47,8 @@ def config_from_hf(hf: Dict[str, Any], name: str = "") -> ModelConfig:
     moe = family == "mixtral" or olmoe
     # what a family sets beyond the shared fields below
     own = {"deepseek_v3": deepseek_v3_fields,
-           "bailing_hybrid": bailing_hybrid_fields}.get(
+           "bailing_hybrid": bailing_hybrid_fields,
+           "mellum": mellum_fields}.get(
                family, lambda hf: {})(hf)
     if hf.get("clip_qkv") is not None:
         # OLMoE's optional clamp of q/k/v to +-clip_qkv is not modeled:
@@ -60,34 +62,44 @@ def config_from_hf(hf: Dict[str, Any], name: str = "") -> ModelConfig:
     if hf.get("rope_scaling"):
         # e.g. phi-3 128k "longrope", llama-3.1 "llama3" scaling: silently
         # using plain rope_theta would produce wrong logits past the
-        # original context, so refuse rather than mis-serve
+        # original context, so refuse rather than mis-serve. What IS
+        # modelled: YaRN by layer kind, from `rope_parameters` under the
+        # `mellum` family (mellum_fields -> models/llama.rope_table)
         kind = (hf["rope_scaling"].get("rope_type")
                 or hf["rope_scaling"].get("type") or "?")
         raise ValueError(
-            f"rope_scaling={kind!r} is not supported; use a checkpoint "
-            f"without rope scaling (e.g. the base-context variant)")
+            f"rope_scaling={kind!r} is not supported: a flat `rope_scaling` "
+            f"(longrope, llama3, linear, dynamic, yarn) is modelled for no "
+            f"family; YaRN is, per layer kind, where a `mellum` file gives "
+            f"it under `rope_parameters`. Use a checkpoint without rope "
+            f"scaling (e.g. the base-context variant)")
     max_len = int(hf.get("max_position_embeddings", 2048))
-    sliding = 0
-    sliding_pattern = "alternate"
-    if gemma2 and hf.get("sliding_window"):
-        # modeled natively: per-layer sliding/global alternation
+    sliding, layer_types = 0, ()
+    if family == "mellum":
+        pass   # window layers served as windows: no cap (mellum_fields)
+    elif gemma2 and hf.get("sliding_window"):
+        # modeled natively: a traced mask width a layer over the shared
+        # pool, whatever the pattern (the published one alternates)
         sliding = int(hf["sliding_window"])
         types = hf.get("layer_types")
-        if types is not None and all(t == "sliding_attention"
-                                     for t in types):
-            sliding_pattern = "all"
-        elif types is not None and types != [
-                "sliding_attention" if i % 2 == 0 else "full_attention"
-                for i in range(hf["num_hidden_layers"])]:
-            raise ValueError(
-                "unsupported gemma2 layer_types pattern (only the "
-                "alternating default or all-sliding are modeled)")
+        if types is not None:
+            unknown = set(types) - {"sliding_attention", "full_attention"}
+            if unknown or len(types) != hf["num_hidden_layers"]:
+                raise ValueError(
+                    f"unsupported gemma2 layer_types {sorted(unknown)!r} / "
+                    f"{len(types)} entries for {hf['num_hidden_layers']} "
+                    f"layers (sliding_attention | full_attention, one a "
+                    f"layer)")
+            layer_types = tuple(types)
     # Qwen2 configs carry sliding_window but disable it by default
     elif hf.get("sliding_window") and hf.get("use_sliding_window", True):
         # full attention == sliding-window attention while the context
-        # fits inside the window; cap the serving length there so models
-        # like phi-3-mini-4k (window 2047) / mistral-v0.1 (4096) stay
-        # exact instead of silently diverging past the window
+        # fits inside the window. This family's window layers are NOT
+        # served as windows (only `mellum`'s are, from a page pool of
+        # their own; Gemma-2's are a mask), so the serving length is
+        # capped at the window: models like phi-3-mini-4k (window 2047) /
+        # mistral-v0.1 (4096) stay exact instead of silently diverging
+        # past it
         max_len = min(max_len, int(hf["sliding_window"]))
     return dataclasses.replace(ModelConfig(
         name=name or hf.get("model_type", family),
@@ -117,7 +129,7 @@ def config_from_hf(hf: Dict[str, Any], name: str = "") -> ModelConfig:
         query_scale=float(hf.get("query_pre_attn_scalar", 0)) ** -0.5
         if gemma2 and hf.get("query_pre_attn_scalar") else 0.0,
         sliding_window=sliding,
-        sliding_pattern=sliding_pattern,
+        layer_types=layer_types,
         # Mixtral counts its experts in `num_local_experts`, OLMoE in
         # `num_experts`; in both `intermediate_size` is ONE expert's width
         num_experts=int(hf.get("num_experts" if olmoe
@@ -252,6 +264,96 @@ def bailing_hybrid_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
         qk_norm=False)
 
 
+def mellum_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """The ModelConfig fields of a `mellum` config.json
+    (Mellum2-12B-A2.5B): GQA in every layer, `layer_types` saying which
+    layers attend inside `sliding_window` (served from the window pool,
+    `window_pool`) and which over the whole context, a RoPE a layer kind
+    from `rope_parameters` (plain on the sliding layers, YaRN on the full
+    ones), and a softmax router over `num_experts` experts of
+    `moe_intermediate_size` in every layer, the k largest renormalised.
+    No key declares a QK-norm, a shared expert, a selection bias or a
+    multi-token-prediction head, so none is modelled; what a later file
+    says otherwise is refused here, by key, not mis-served."""
+    def refuse(key, ok, modelled):
+        if not ok(hf.get(key)):
+            raise ValueError(f"{key}={hf.get(key)!r} is not supported "
+                             f"(only {modelled} is modelled)")
+    layers = int(hf["num_hidden_layers"])
+    types = hf.get("layer_types")
+    if not types or len(types) != layers or \
+            set(types) - {"sliding_attention", "full_attention"}:
+        raise ValueError(
+            f"layer_types={types!r}: one of sliding_attention | "
+            f"full_attention a layer ({layers}) is what is modelled "
+            f"(max_window_layers is not read: layer_types governs)")
+    refuse("mlp_layer_types",
+           lambda v: v is None or (len(v) == layers
+                                   and set(v) == {"sparse"}),
+           "an expert block in every layer (all 'sparse')")
+    refuse("use_sliding_window", lambda v: v in (None, True),
+           "use_sliding_window: true")
+    refuse("attention_bias", lambda v: not v, "no attention bias")
+    refuse("hidden_act", lambda v: v in (None, "silu"), "silu")
+    refuse("norm_topk_prob", lambda v: v in (None, True),
+           "renormalised router weights")
+    for key in ("use_qk_norm", "qk_norm", "n_shared_experts",
+                "num_shared_experts", "shared_expert_intermediate_size",
+                "num_nextn_predict_layers", "mtp_num_layers",
+                "moe_router_enable_expert_bias", "attn_logit_softcapping",
+                "final_logit_softcapping"):
+        refuse(key, lambda v: not v, f"{key} absent")
+    refuse("routed_scaling_factor", lambda v: v in (None, 1, 1.0),
+           "no scale on the routed output")
+    if not hf.get("sliding_window"):
+        raise ValueError("a mellum file states its sliding_window")
+    ropes = hf.get("rope_parameters") or {}
+    if set(ropes) != {"full_attention", "sliding_attention"}:
+        raise ValueError(
+            f"rope_parameters keys {sorted(ropes)!r}: one entry for "
+            f"full_attention and one for sliding_attention is what is "
+            f"modelled")
+    return dict(
+        sliding_window=int(hf["sliding_window"]),
+        layer_types=tuple(types), window_pool=True,
+        rope_full=rope_params(ropes["full_attention"], hf),
+        rope_sliding=rope_params(ropes["sliding_attention"], hf),
+        rope_theta=float(ropes["full_attention"]["rope_theta"]),
+        num_experts=int(hf["num_experts"]),
+        intermediate_size=int(hf["moe_intermediate_size"]),
+        norm_topk_prob=True)
+
+
+def rope_params(entry: Dict[str, Any], hf: Dict[str, Any]):
+    """One entry of `rope_parameters` -> RopeParams. Plain RoPE
+    ("default") and YaRN are modelled; longrope, llama3, linear and
+    dynamic scaling are refused by name."""
+    from dynamo_tpu.engine.config import RopeParams
+    kind = entry.get("rope_type") or entry.get("type") or "default"
+    theta = float(entry.get("rope_theta", hf.get("rope_theta", 10000.0)))
+    if kind == "default":
+        return RopeParams(theta=theta)
+    if kind != "yarn":
+        raise ValueError(
+            f"rope_type={kind!r} is not supported (default and yarn are "
+            f"modelled; longrope, llama3, linear and dynamic are not)")
+    for key in ("mscale", "mscale_all_dim"):
+        if entry.get(key):
+            raise ValueError(f"yarn {key}={entry[key]!r} is not supported "
+                             f"(attention_factor, or its default 0.1 "
+                             f"ln(factor) + 1, is what is modelled)")
+    if entry.get("truncate") is False:
+        raise ValueError("yarn truncate=false is not supported")
+    return RopeParams(
+        theta=theta, rope_type="yarn", factor=float(entry["factor"]),
+        original_max_position=int(
+            entry.get("original_max_position_embeddings")
+            or hf["max_position_embeddings"]),
+        beta_fast=float(entry.get("beta_fast") or 32.0),
+        beta_slow=float(entry.get("beta_slow") or 1.0),
+        attention_factor=float(entry.get("attention_factor") or 0.0))
+
+
 def _read_all_tensors(path: str) -> Dict[str, np.ndarray]:
     from safetensors import safe_open
     files = sorted(glob.glob(os.path.join(path, "*.safetensors")))
@@ -296,13 +398,14 @@ def load_params_from_hf(path: str, cfg: ModelConfig,
       model.norm.weight                  -> final_norm
       lm_head.weight.T                   -> lm_head (absent when tied)
     """
-    if cfg.has_linear_layers:
-        # the catalog gives the hybrid's config and no tensor names: a
-        # guessed mapping would serve another function under its name
+    if cfg.has_linear_layers or cfg.window_pool:
+        # the catalog gives these families' configs and no tensor names:
+        # a guessed mapping would serve another function under its name
         raise ValueError(
             f"{cfg.name}: no checkpoint mapping for a model with "
-            f"linear-attention layers (its tensor names are not known); "
-            f"remove the *.safetensors to serve seeded weights")
+            f"linear-attention layers or a window pool (its tensor names "
+            f"are not known); remove the *.safetensors to serve seeded "
+            f"weights")
     import jax.numpy as jnp
     dt = jnp.empty((), dtype or cfg.dtype).dtype
     raw = _read_all_tensors(path)

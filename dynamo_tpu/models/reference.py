@@ -95,6 +95,39 @@ Delta Attention (KDA) otherwise; eps 1e-6.
               absent experts would add is left out (model-configs guide,
               section 4); the shared expert is computed here whole.
 
+And from the row `Mellum2-12B-A2.5B-Instruct` of the architecture catalog
+(`model_type` mellum; the class name is assumed, `MellumForCausalLM`;
+benchmark/reference/mellum.py is the benchmark's copy of these lines).
+Pre-norm residual block, plain RMSNorm (eps 1e-6), final norm, untied
+head, no biases.
+
+  attention   (every layer) `attention` above without a QK-norm (no key
+              of the row's config declares one): q -> [T, 32, 128], k, v ->
+              [T, 4, 128], rotate-half RoPE over the full head with THIS
+              LAYER KIND's table, 8 query heads a KV head, causal softmax
+              in float32 at 128 ** -0.5, Wo.
+  sliding_attention layers (`layer_types[i]`, 3 of every 4): a query at
+              p sees keys j with p - `sliding_window` < j <= p (`window`).
+              RoPE: `rope_parameters.sliding_attention`, plain.
+  full_attention layers: all keys j <= p. RoPE:
+              `rope_parameters.full_attention`, YaRN as `transformers`
+              computes it (`yarn_inv_freq`): f_i = theta ** (2i / d);
+              extrapolated 1 / f_i, interpolated 1 / (factor f_i); c(r) =
+              d ln(L0 / (2 pi r)) / (2 ln theta); low = floor(c(beta_fast)),
+              high = ceil(c(beta_slow)), clipped to [0, d - 1]; ramp_i =
+              clip((i - low) / (high - low), 0, 1); inv_freq_i =
+              interpolated_i ramp_i + extrapolated_i (1 - ramp_i); cos and
+              sin are multiplied by `attention_factor` at EVERY position,
+              not only past the original context L0.
+  experts     (every layer, `mlp_layer_types` all sparse) `expert_mlp`
+              with the softmax router, the 8 largest of 64 renormalised
+              (`norm_topk_prob` true), width `moe_intermediate_size`; no
+              shared expert, no selection bias, no scale.
+  left out    `described_as` mentions a multi-token-prediction head that
+              no key of `config` describes; `max_window_layers: 0` beside
+              an explicit `layer_types` (`layer_types` governs);
+              `intermediate_size`, unused where every layer is sparse.
+
 Departures from the published model, each deliberate:
   * weights are taken in this repo's layout: projections stored
     [in, out] (the checkpoint's are [out, in]; models/loader.py
@@ -124,6 +157,7 @@ Departures from the published model, each deliberate:
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -136,18 +170,46 @@ def rms_norm(x, w, eps):
                                   + eps))
 
 
-def rope(x, positions, theta):
-    """Rotate-half RoPE over the full head. x: [T, H, hd]."""
+def yarn_inv_freq(dim, theta, factor, original_max_position, beta_fast,
+                  beta_slow):
+    """YaRN's frequencies [dim / 2]: a ramp over the dimensions between
+    the interpolated and the extrapolated ones (module docstring)."""
+    f = theta ** (jnp.arange(0, dim, 2, dtype=F32) / dim)
+    extrapolated, interpolated = 1.0 / f, 1.0 / (factor * f)
+
+    def c(turns):
+        return dim * math.log(original_max_position / (2 * math.pi * turns)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(c(beta_fast)), 0)
+    high = min(math.ceil(c(beta_slow)), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=F32) - low) / (high - low),
+                    0.0, 1.0)
+    return interpolated * ramp + extrapolated * (1.0 - ramp)
+
+
+def rope(x, positions, theta, yarn=None):
+    """Rotate-half RoPE over the full head. x: [T, H, hd]. `yarn`: a dict
+    of `yarn_inv_freq`'s arguments after theta, and `attention_factor`
+    (0: 0.1 ln(factor) + 1), which multiplies cos and sin."""
     hd = x.shape[-1]
     inv_freq = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=F32) / hd)
+    scale = 1.0
+    if yarn:
+        inv_freq = yarn_inv_freq(
+            hd, theta, yarn["factor"], yarn["original_max_position"],
+            yarn["beta_fast"], yarn["beta_slow"])
+        scale = yarn.get("attention_factor") \
+            or 0.1 * math.log(yarn["factor"]) + 1.0
     angle = positions.astype(F32)[:, None] * inv_freq[None, :]  # [T, hd/2]
-    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    cos, sin = (scale * jnp.cos(angle)[:, None, :],
+                scale * jnp.sin(angle)[:, None, :])
     x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
 def attention(x, lp, *, num_heads, num_kv_heads, head_dim, rope_theta,
-              rms_norm_eps, qk_norm):
+              rms_norm_eps, qk_norm, window=0, yarn=None):
+    """`window` > 0: a query at p sees keys j with p - window < j <= p."""
     t = x.shape[0]
     q, k, v = x @ lp["wq"], x @ lp["wk"], x @ lp["wv"]
     if "wq_b" in lp:
@@ -156,13 +218,16 @@ def attention(x, lp, *, num_heads, num_kv_heads, head_dim, rope_theta,
         q = rms_norm(q, lp["q_norm"], rms_norm_eps)
         k = rms_norm(k, lp["k_norm"], rms_norm_eps)
     positions = jnp.arange(t)
-    q = rope(q.reshape(t, num_heads, head_dim), positions, rope_theta)
-    k = rope(k.reshape(t, num_kv_heads, head_dim), positions, rope_theta)
+    q = rope(q.reshape(t, num_heads, head_dim), positions, rope_theta, yarn)
+    k = rope(k.reshape(t, num_kv_heads, head_dim), positions, rope_theta,
+             yarn)
     v = v.reshape(t, num_kv_heads, head_dim)
     group = num_heads // num_kv_heads          # grouped-query: share k, v
     k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     scores = jnp.einsum("qhd,khd->hqk", q, k) * head_dim ** -0.5
     causal = positions[None, :] <= positions[:, None]          # [q, k]
+    if window:
+        causal &= positions[:, None] - positions[None, :] < window
     scores = jnp.where(causal[None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("hqk,khd->qhd", probs, v)
@@ -322,11 +387,12 @@ def layer(x, lp, *, num_heads, num_kv_heads, head_dim, rope_theta,
           rms_norm_eps, qk_norm=False, num_experts=0,
           num_experts_per_tok=0, norm_topk_prob=True, mla=None,
           moe_scoring="softmax", moe_routed_scale=1.0, kda=None,
-          n_group=1, topk_group=1, expert_first=0):
+          n_group=1, topk_group=1, expert_first=0, window=0, yarn=None):
     """One pre-norm residual block. x: [T, D]; lp: this layer's weights,
     float32. `mla`: attention_mla's sizes (a dict) for latent attention;
     `kda`: attention_kda's, for a layer that has its leaves. A layer
-    without a `router` leaf has a dense MLP."""
+    without a `router` leaf has a dense MLP. `window`, `yarn`: THIS
+    layer's sliding width and RoPE scaling (`layer_kind_kwargs`)."""
     xn = rms_norm(x, lp["attn_norm"], rms_norm_eps)
     if "kda_wqkv" in lp:
         x = x + attention_kda(xn, lp, num_heads=num_heads,
@@ -339,7 +405,8 @@ def layer(x, lp, *, num_heads, num_kv_heads, head_dim, rope_theta,
         x = x + attention(
             xn, lp, num_heads=num_heads, num_kv_heads=num_kv_heads,
             head_dim=head_dim, rope_theta=rope_theta,
-            rms_norm_eps=rms_norm_eps, qk_norm=qk_norm)
+            rms_norm_eps=rms_norm_eps, qk_norm=qk_norm, window=window,
+            yarn=yarn)
     xn = rms_norm(x, lp["mlp_norm"], rms_norm_eps)
     if num_experts and "router" in lp:
         return x + expert_mlp(xn, lp,
@@ -358,6 +425,13 @@ def arch_kwargs(cfg) -> dict:
     mla = dict(kv_lora_rank=cfg.kv_lora_rank,
                qk_nope_head_dim=cfg.qk_nope_head_dim,
                qk_rope_head_dim=cfg.qk_rope_head_dim) if cfg.is_mla else None
+    by_kind = {}
+    if cfg.window_pool:
+        import dataclasses
+        by_kind = dict(layer_types=tuple(cfg.layer_types),
+                       sliding_window=cfg.sliding_window,
+                       rope_full=dataclasses.asdict(cfg.rope_full),
+                       rope_sliding=dataclasses.asdict(cfg.rope_sliding))
     return dict(num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
                 head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
                 rms_norm_eps=cfg.rms_norm_eps, qk_norm=cfg.qk_norm,
@@ -370,7 +444,22 @@ def arch_kwargs(cfg) -> dict:
                          lower_bound=cfg.linear_gate_lower_bound)
                 if cfg.has_linear_layers else None,
                 n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group,
-                expert_first=cfg.expert_first)
+                expert_first=cfg.expert_first, **by_kind)
+
+
+def layer_kind_kwargs(index, layer_types=(), sliding_window=0,
+                      rope_full=None, rope_sliding=None) -> dict:
+    """`layer`'s arguments that go by layer KIND, for layer `index` of a
+    model whose `layer_types` says which layers slide: its window (0 on a
+    full layer) and its RoPE (`rope_theta`, and `yarn` where the kind's
+    `rope_type` is yarn). {} for a model of one kind."""
+    if not layer_types:
+        return {}
+    sliding = layer_types[index] == "sliding_attention"
+    p = rope_sliding if sliding else rope_full
+    return dict(window=sliding_window if sliding else 0,
+                rope_theta=p["theta"],
+                yarn=p if p["rope_type"] == "yarn" else None)
 
 
 LAYER_GROUPS = ("dense_layers", "layers")   # in the model's layer order
@@ -385,18 +474,41 @@ def layer_stacks(params) -> list:
     return [params[k] for k in runs or LAYER_GROUPS if k in params]
 
 
+def layers_in_order(params, layer_types=()) -> list:
+    """Every layer's weights, in the model's order. Where `layer_types`
+    says which layers slide, the stacks are a stack a KIND, in the order
+    the kinds first appear (models/llama.layer_runs), and the model's
+    order interleaves them."""
+    stacks = layer_stacks(params)
+    if not layer_types:
+        return [{name: leaf[i] for name, leaf in stack.items()}
+                for stack in stacks for i in range(len(stack["attn_norm"]))]
+    kinds = list(dict.fromkeys(layer_types))
+    taken = [0] * len(kinds)
+    out = []
+    for kind in layer_types:
+        s = kinds.index(kind)
+        out.append({name: leaf[taken[s]]
+                    for name, leaf in stacks[s].items()})
+        taken[s] += 1
+    return out
+
+
 def forward(params, tokens, **arch):
     """tokens [T] -> logits [T, V] float32: one full forward pass over one
     sequence. `params` is the engine's tree (models/llama.init_params /
     models/loader.load_params_from_hf), in any dtype: upcast here."""
+    by_kind = {k: arch.pop(k) for k in (
+        "layer_types", "sliding_window", "rope_full", "rope_sliding")
+        if k in arch}
     with jax.default_matmul_precision("highest"):
         params = jax.tree.map(lambda a: jnp.asarray(a, F32), params)
         # ids the engine served  # dynalint: disable-next-line=R1
         x = params["embed"][jnp.asarray(tokens)]
-        for stack in layer_stacks(params):
-            for i in range(len(stack["attn_norm"])):
-                lp = {name: leaf[i] for name, leaf in stack.items()}
-                x = layer(x, lp, **arch)
+        for index, lp in enumerate(layers_in_order(
+                params, by_kind.get("layer_types", ()))):
+            x = layer(x, lp, **{**arch,
+                                **layer_kind_kwargs(index, **by_kind)})
         x = rms_norm(x, params["final_norm"], arch["rms_norm_eps"])
         head = params["lm_head"] if "lm_head" in params \
             else params["embed"].T

@@ -164,6 +164,25 @@ class LedgerStats:
         #                           them: rows x table width x page size
         "kv_bytes_per_token",     # gauge: bytes a token holds in the
         #                           cache, all layers (set at engine start)
+        # a model whose sliding layers keep a page pool of their own
+        # (ModelConfig.window_pool; engine._account_attention,
+        # _account_window_pool). The two series above then count the
+        # FULL pool's tables alone; all of these are 0 on other models
+        "attn_kv_window_tokens_total",  # keys the real rows can see in a
+        #                                 sliding layer (<= the window)
+        "attn_kv_window_slots_total",   # token slots the window layers'
+        #                                 gather reads for them
+        "kv_bytes_per_token_full",    # gauge: bytes a token holds in the
+        #                               full pool, its layers together
+        "kv_bytes_per_token_window",  # gauge: the same in the window pool
+        "kv_window_pages_held",       # gauge: pages a live row of the
+        #                               last planned step holds there, mean
+        "kv_window_pages_held_sum_total",  # the same summed over every
+        "kv_window_rows_total",            # planned step, and its rows
+        "kv_window_pages_released_total",  # pages handed back before
+        #                               their sequence ended
+        "kv_window_pages_used",       # gauge: the window pool's usage,
+        "kv_window_pages_total",      # gauge: and its size
         # a share of an expert layer (ops/moe.py): assignments of real
         # tokens to experts this engine does not hold, left out
         "moe_routed_absent_total",

@@ -531,7 +531,7 @@ def test_spec_composes_with_gemma2_class_attention(monkeypatch):
 
     g2 = dataclasses.replace(
         CFG, attn_softcap=30.0, final_softcap=20.0, sliding_window=16,
-        sliding_pattern="alternate", post_norms=True, norm_plus_one=True)
+        post_norms=True, norm_plus_one=True)
     prompt = repetitive_prompt() * 2   # long enough to cross the window
     p = SamplingParams(max_tokens=8, temperature=0.0)
     kw = dict(page_size=8, num_pages=64, max_slots=4, max_prefill_chunk=64,

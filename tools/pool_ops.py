@@ -252,8 +252,15 @@ def build_programs(config_dir: str, rows: int, chunk: int, pages: int,
     params = abstract(jax.eval_shape(
         lambda: llama.init_params(jax.random.PRNGKey(0), cfg)),
         llama.param_shardings(cfg))
+    # a window pool's pages and table widths, as NativeEngine sizes them
+    from dynamo_tpu.engine.scheduler import window_table_pages
+    wsched = cfg.window_pool
+    wtable = functools.partial(window_table_pages, ecfg, cfg.sliding_window)
+    window_pages = (rows + ecfg.max_prefill_batch) \
+        * wtable(ecfg.max_prefill_chunk) if wsched else 0
     cache = abstract(jax.eval_shape(
-        lambda: llama.init_cache(cfg, num_pages, ecfg.page_size)),
+        lambda: llama.init_cache(cfg, num_pages, ecfg.page_size,
+                                 window_pages)),
         llama.cache_shardings(cfg))
     state = cfg.has_linear_layers
     if state:
@@ -264,12 +271,16 @@ def build_programs(config_dir: str, rows: int, chunk: int, pages: int,
                 cfg, rows + ecfg.max_prefill_batch)),
             {name: PartitionSpec() for name in cfg.state_leaves()}))
 
-    def with_slots(fn):
-        """The program with its last operand as `state_slots`."""
-        if not state:
+    def with_slots(fn, names=()):
+        """The program with its last operands as `state_slots` (a model
+        with a recurrent state) or as `names` (one with a window pool)."""
+        if state:
+            names = ("state_slots",)
+        if not names:
             return fn
         return lambda params, cache, *args: fn(
-            params, cache, *args[:-1], state_slots=args[-1])
+            params, cache, *args[:-len(names)],
+            **dict(zip(names, args[-len(names):])))
 
     # what ONE device holds of one layer's K pool
     layer_pool = (cache["k"].size // cfg.num_cache_layers
@@ -281,7 +292,8 @@ def build_programs(config_dir: str, rows: int, chunk: int, pages: int,
     step = jax.jit(
         eng._named("engine_step", with_slots(functools.partial(
             eng._engine_step, cfg, (), None, kernel_mesh, False, False,
-            False, None))), donate_argnums=(1,))
+            False, None), ("wtable", "woff", "wwrite_idx") * bool(wsched))),
+        donate_argnums=(1,))
     step_args = (params, cache, arr((rows, chunk)), arr((rows, chunk)),
                  arr((rows, pages)), vec, arr((rows, chunk)), vec,
                  arr((rows,), f32), vec, arr((rows,), f32), vec, vec, vec)
@@ -290,7 +302,8 @@ def build_programs(config_dir: str, rows: int, chunk: int, pages: int,
         eng._named("engine_decode_window_full", with_slots(
             functools.partial(
                 eng._engine_decode_window, cfg, (), kernel_mesh, nw,
-                ecfg.page_size, False, False, False, False))),
+                ecfg.page_size, False, False, False, False),
+            ("wtable", "woff") * bool(wsched))),
         donate_argnums=(1,))
     window_args = (params, cache, vec, vec, arr((rows, pages)),
                    arr((rows, base_pages)), vec, arr((rows,), f32), vec,
@@ -298,6 +311,9 @@ def build_programs(config_dir: str, rows: int, chunk: int, pages: int,
                    arr((rows, 0)))
     if state:
         step_args, window_args = step_args + (vec,), window_args + (vec,)
+    if wsched:
+        step_args += (arr((rows, wtable(chunk))), vec, arr((rows, chunk)))
+        window_args += (arr((rows, wtable(1))), vec)
     return ([(f"jit_engine_step[{rows},{chunk}]", step, step_args),
              (f"jit_engine_decode_window_full[{rows}x{nw}]", window,
               window_args)], layer_pool, (num_pages, ecfg.page_size), target)
