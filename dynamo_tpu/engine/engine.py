@@ -307,8 +307,13 @@ class NativeEngine:
         #                               staged (_stage_operands)
         self.pipeline_windows = 0     # windows committed via the pipeline
         self.pipeline_overlapped = 0  # commits with a follow-up in flight
-        self.pipeline_fallbacks = 0   # in-flight windows discarded on
-        #                               membership change (reconciliation)
+        self.pipeline_fallbacks = 0   # commits that changed a row's occupant
+        #                               under an in-flight follow-up
+        self.window_steps_reconciled = 0  # device steps of the follow-ups
+        #                               committed after such a commit, for
+        #                               the rows still live
+        self.window_steps_discarded = 0   # device steps of windows that
+        #                               reached no row (every row had left)
         # mixed prefill+decode steps (docs/PERF.md): fused [Bb, Tb] steps
         # run, and the stall counter — device steps where >= 1 running
         # request emitted nothing because the step carried no decode rows
@@ -1533,10 +1538,12 @@ class NativeEngine:
         return outs, nxt
 
     def _fetch_and_commit(self, plan: DecodePlan, outs,
-                          in_flight: bool = False) -> List[StepOutput]:
+                          in_flight: bool = False,
+                          reconciled: bool = False) -> List[StepOutput]:
         """Blocking output fetch + host commit for one window.
         `in_flight`: a follow-up window was dispatched before this fetch,
-        so the device stays busy through the commit."""
+        so the device stays busy through the commit. `reconciled`: the
+        window ran under a commit that ended one of its rows."""
         with self.phases.phase("wait"):
             toks, lps, top_ids, top_lps, aux = \
                 jax.device_get(outs)  # dynalint: sync-point — the one
@@ -1548,7 +1555,7 @@ class NativeEngine:
             self._account_moe(aux, window=True)
         with self.phases.phase("commit"):
             return self._commit_window(plan, np.asarray(toks), lps,
-                                       top_ids, top_lps)
+                                       top_ids, top_lps, reconciled)
 
     # -- overlapped decode pipeline ------------------------------------------
 
@@ -1667,6 +1674,13 @@ class NativeEngine:
                 return False
         return True
 
+    def _live_rows(self, plan: DecodePlan) -> List[bool]:
+        """Which rows of `plan` still hold the sequence they were staged
+        with: the rows a window's results may be committed for."""
+        running = self.scheduler.running
+        return [seq is not None and running[i] is seq
+                for i, seq in enumerate(plan.seqs)]
+
     def _slots_grown(self, plan: DecodePlan) -> bool:
         """A slot the staged plan held as padding is now occupied (an
         admission landed since staging): in-flight results stay valid,
@@ -1684,13 +1698,15 @@ class NativeEngine:
         2. fetch the in-flight window's outputs (the one host sync);
         3. commit them on host — CONCURRENT with device execution of the
            follow-up dispatched in (1);
-        4. reconcile: if the commit changed slot membership (stop/eos/
-           length/abort), the follow-up was computed off a stale plan —
-           discard its results and fall back to a synchronous re-plan.
-           Its KV writes are harmless: they land past every committed
-           position, inside pages the staged table owned, and are
-           overwritten by the deterministic re-run (docs/PERF.md has the
-           full exactness argument)."""
+        4. reconcile: if the commit ended a row (stop/eos/length), the
+           follow-up still holds, for every row that lives on, exactly
+           what a re-plan would compute next (rows of a window do not see
+           each other). It stays in flight flagged `drain`: the next
+           step() commits it for the rows whose slot still holds the
+           same sequence (_commit_window's identity guard), and the step
+           after that re-plans. What the row that ended wrote meanwhile
+           lands past its committed positions, in pages the staged table
+           owned (docs/PERF.md has the full exactness argument)."""
         pend, self._pipeline = self._pipeline, None
         self.step_count += 1
         plan, staged = pend["plan"], pend["staged"]
@@ -1726,8 +1742,9 @@ class NativeEngine:
             follow = {"plan": plan, "staged": staged, "outs": follow_outs,
                       "nxt": follow_nxt, "j": pend["j"] + 1,
                       "t_dispatch": time.perf_counter()}
-        events = self._fetch_and_commit(plan, pend["outs"],
-                                        in_flight=follow is not None)
+        events = self._fetch_and_commit(
+            plan, pend["outs"], in_flight=follow is not None,
+            reconciled=pend.get("reconciled", False))
         self.pipeline_windows += 1
         intact = self._membership_intact(plan)
         if follow is not None:
@@ -1747,23 +1764,23 @@ class NativeEngine:
                                    "dev": staged["dev"],
                                    "next": follow["nxt"]}
             else:
-                # reconciliation fallback: the follow-up's results assume
-                # row occupants the commit just changed — drop them (the
-                # donated cache already advanced; its garbage KV writes
-                # are overwritten by the synchronous re-plan)
+                # the commit ended a row under the follow-up. For the
+                # rows that live on its results are exact (rows are
+                # independent of each other, the dropless dispatch and a
+                # per-row expert capacity included), its KV rows rest
+                # where a re-run would write the same values, and a
+                # recurrent state it advanced cannot be run over twice:
+                # commit it next step for those rows, then re-plan, as
+                # for a grown slot set. The slot set changed, so the
+                # next window stages afresh
                 self.pipeline_fallbacks += 1
                 self._dec_state = None
-                if self._state_slots:
-                    # a recurrent state cannot be run over twice: the
-                    # follow-up has ADVANCED every surviving row's slot,
-                    # and a re-run would start from there. Its results
-                    # for those rows are exact (rows are independent of
-                    # each other, the dropless dispatch included), so it
-                    # is committed next step for the rows still live
-                    # (_commit_window's identity guard) and then
-                    # re-planned, as for a grown slot set
-                    follow["drain"] = True
+                if any(self._live_rows(plan)):
+                    follow["drain"] = follow["reconciled"] = True
                     self._pipeline = follow
+                else:
+                    # no row lives on: there is no one to commit it for
+                    self.window_steps_discarded += staged["nw"]
         elif not intact:
             self._dec_state = None
         return events
@@ -1948,25 +1965,32 @@ class NativeEngine:
         return events
 
     def _commit_window(self, plan: DecodePlan, toks: np.ndarray, lps=None,
-                       top_ids=None, top_lps=None) -> List[StepOutput]:
+                       top_ids=None, top_lps=None,
+                       reconciled: bool = False) -> List[StepOutput]:
         """Unpack a [N, S] window of sampled tokens step-major so each
         request's tokens stream in generation order; stop accounting a
         sequence at its first finished token (later window tokens for it
-        are garbage by construction)."""
+        are garbage by construction). `reconciled`: a follow-up window
+        that a commit ended a row under; it is committed for the rows
+        still live like any other, and counted."""
         n_steps = toks.shape[0]
         self.step_count += n_steps - 1             # window counts as N steps
         events: List[StepOutput] = []
         done: Set[str] = set()
         finish_step: Dict[str, int] = {}
-        # identity guard for the pipelined loop: a slot aborted while its
-        # window was in flight is no longer backed by this seq — committing
-        # its tokens would double-free pages (or poison a reused request
-        # id); the synchronous path commits immediately after scheduling,
-        # so the guard is vacuous there
-        running = self.scheduler.running
-        live = [seq is not None and running[i] is seq
-                for i, seq in enumerate(plan.seqs)]
+        # identity guard for the pipelined loop: a slot whose sequence
+        # ended or was aborted while this window was in flight is no
+        # longer backed by this seq — committing its tokens would
+        # double-free pages (or poison a reused request id); the
+        # synchronous path commits immediately after scheduling, so the
+        # guard is vacuous there
+        live = self._live_rows(plan)
         n_live = sum(live)
+        if not n_live:
+            # every row left while the window ran: its rung reached no one
+            self.window_steps_discarded += n_steps
+        elif reconciled:
+            self.window_steps_reconciled += n_steps
         for step in range(n_steps):
             for i, seq in enumerate(plan.seqs):
                 if not live[i] or seq.request_id in done:
@@ -1996,7 +2020,8 @@ class NativeEngine:
         # actually committed (post-finish tail + padding rows = waste)
         self._ledger_record("decode", len(plan.seqs), n_live,
                             len(events), n_steps * len(plan.seqs),
-                            dev_steps=n_steps, events=events)
+                            dev_steps=n_steps if n_live else 0,
+                            events=events)
         return events
 
     def _run_decode_pp(self, plan: DecodePlan) -> List[StepOutput]:
@@ -2355,6 +2380,8 @@ class NativeEngine:
         m.pipeline_windows = self.pipeline_windows
         m.pipeline_overlapped = self.pipeline_overlapped
         m.pipeline_fallbacks = self.pipeline_fallbacks
+        m.window_steps_reconciled = self.window_steps_reconciled
+        m.window_steps_discarded = self.window_steps_discarded
         m.decode_host_syncs = self.decode_host_syncs
         m.decode_plan_uploads = self.decode_plan_uploads
         m.host_buffers = self.host_buffers

@@ -241,9 +241,10 @@ class EngineMetrics:
     # decode pipeline occupancy (engine pipelined loop, docs/PERF.md):
     # windows dispatched / committed while a follow-up window was already
     # in flight on device (true host/device overlap) / reconciliation
-    # fallbacks (the in-flight window was discarded because commit changed
-    # slot membership) / blocking output fetches / windows that staged
-    # fresh host plan arrays (0-upload steady state when this stays flat)
+    # fallbacks (a commit ended a row under the in-flight follow-up, which
+    # is then committed for the rows still live) / blocking output fetches
+    # / windows that staged fresh host plan arrays (0-upload steady state
+    # when this stays flat)
     decode_windows: int = 0
     # device program launches in decode — the one-dispatch-per-window
     # invariant (PR 18): dispatches / windows holds at exactly 1.0 on the
@@ -252,6 +253,11 @@ class EngineMetrics:
     pipeline_windows: int = 0
     pipeline_overlapped: int = 0
     pipeline_fallbacks: int = 0
+    # device steps of the follow-ups committed after a fallback, and of
+    # windows that were dispatched and reached no row (every row had
+    # ended or been aborted by the commit)
+    window_steps_reconciled: int = 0
+    window_steps_discarded: int = 0
     decode_host_syncs: int = 0
     decode_plan_uploads: int = 0
     # host->device buffers the step path staged (engine._stage_operands)

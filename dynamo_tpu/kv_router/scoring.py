@@ -36,11 +36,15 @@ class WorkerMetrics:
     # overlapped decode pipeline occupancy (engine pipelined loop,
     # docs/PERF.md): dispatched windows / committed via the pipeline /
     # committed while a follow-up ran on device / reconciliation
-    # fallbacks / blocking fetches / fresh host plan stagings
+    # fallbacks, the device steps of the follow-ups committed after one
+    # and of windows that reached no row / blocking fetches / fresh host
+    # plan stagings
     decode_windows: int = 0
     pipeline_windows: int = 0
     pipeline_overlapped: int = 0
     pipeline_fallbacks: int = 0
+    window_steps_reconciled: int = 0
+    window_steps_discarded: int = 0
     decode_host_syncs: int = 0
     decode_plan_uploads: int = 0
     host_buffers: int = 0   # host->device buffers the step path staged

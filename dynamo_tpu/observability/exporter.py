@@ -76,8 +76,11 @@ class MetricsExporter:
             "Of those, drafts accepted (free decode tokens)", labels)
         # overlapped decode pipeline occupancy (engine pipelined loop):
         # overlapped/pipelined is the live host-overlap rate; fallbacks
-        # count reconciliation discards; plan_uploads staying flat while
-        # windows climbs is the zero-upload steady-state invariant
+        # count commits that ended a row under an in-flight follow-up,
+        # window_steps_reconciled the device steps of those follow-ups
+        # (committed for the rows still live), window_steps_discarded
+        # the device steps that reached no row; plan_uploads staying flat
+        # while windows climbs is the zero-upload steady-state invariant
         self.g_pipe = {
             name: r.gauge(f"{PREFIX}_decode_{name}", help_, labels)
             for name, help_ in (
@@ -87,7 +90,14 @@ class MetricsExporter:
                 ("pipeline_overlapped",
                  "Commits that ran while a follow-up window executed"),
                 ("pipeline_fallbacks",
-                 "In-flight windows discarded on membership change"),
+                 "Commits that ended a row under an in-flight follow-up "
+                 "window"),
+                ("window_steps_reconciled",
+                 "Device steps of follow-up windows committed, for the "
+                 "rows still live, after such a commit"),
+                ("window_steps_discarded",
+                 "Device steps of windows dispatched and committed for "
+                 "no row"),
                 ("host_syncs", "Blocking output fetches in decode"),
                 ("plan_uploads", "Windows that staged fresh host arrays"),
                 ("host_buffers",
@@ -349,6 +359,10 @@ class MetricsExporter:
                 worker_id, value=m.pipeline_overlapped)
             self.g_pipe["pipeline_fallbacks"].set(
                 worker_id, value=m.pipeline_fallbacks)
+            self.g_pipe["window_steps_reconciled"].set(
+                worker_id, value=m.window_steps_reconciled)
+            self.g_pipe["window_steps_discarded"].set(
+                worker_id, value=m.window_steps_discarded)
             self.g_pipe["host_syncs"].set(
                 worker_id, value=m.decode_host_syncs)
             self.g_pipe["plan_uploads"].set(

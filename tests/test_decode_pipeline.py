@@ -1,9 +1,11 @@
 """Overlapped decode pipeline (engine two-deep host/device loop).
 
 Exactness bar: pipelined streams must be TOKEN-IDENTICAL to the
-synchronous loop — greedy and seeded-sampling, including stop-mid-window
-and abort-mid-window, both of which force the reconciliation fallback
-(the in-flight follow-up window is discarded and the engine re-plans).
+synchronous loop — greedy and seeded-sampling, including a row that ends
+mid-window (a stop id, its length) with a follow-up window in flight
+behind it: the reconciliation fallback, after which the follow-up is
+COMMITTED for the rows that live on and the engine re-plans (it is never
+run twice, and dropped only when no row lives on), and abort-mid-window.
 Invariant bar (the CPU microbench): the pipelined loop issues exactly one
 blocking host sync per committed window, and steady-state windows upload
 zero plan arrays. docs/PERF.md has the design and exactness argument.
@@ -14,6 +16,10 @@ and reused across tests — engine rebuilds recompile every jitted program
 scenario the pipeline must survive: counter assertions therefore diff
 against a snapshot instead of assuming zero.
 """
+import dataclasses
+import json
+import os
+
 import numpy as np
 import pytest
 
@@ -46,7 +52,9 @@ def eng_pipe():
 def snap(eng):
     return {k: getattr(eng, k) for k in (
         "decode_windows", "pipeline_windows", "pipeline_overlapped",
-        "pipeline_fallbacks", "decode_host_syncs", "decode_plan_uploads")}
+        "pipeline_fallbacks", "window_steps_reconciled",
+        "window_steps_discarded", "decode_host_syncs",
+        "decode_plan_uploads")}
 
 
 def delta(eng, before):
@@ -96,8 +104,10 @@ def test_pipelined_token_identity_greedy_and_sampled(eng_sync, eng_pipe):
 
 def test_stop_mid_window_fallback_token_identity(eng_sync, eng_pipe):
     """A hidden stop id sampled mid-window changes slot membership at
-    commit: the in-flight follow-up must be discarded (fallback counter)
-    and the stream must still equal the synchronous loop's."""
+    commit, under an in-flight follow-up (the fallback counter): the
+    stream must still equal the synchronous loop's. The row was the
+    plan's only one, so the follow-up has no one to be committed for and
+    is dropped, and counted."""
     prompt = list(range(10, 26))
     ref = eng_sync.generate(
         prompt, SamplingParams(max_tokens=12, ignore_eos=True), "probe")
@@ -108,7 +118,191 @@ def test_stop_mid_window_fallback_token_identity(eng_sync, eng_pipe):
     before = snap(eng_pipe)
     pipe = eng_pipe.generate(prompt, p, "stop_p")
     assert pipe == sync == ref[:5]
-    assert delta(eng_pipe, before)["pipeline_fallbacks"] >= 1
+    d = delta(eng_pipe, before)
+    assert d["pipeline_fallbacks"] >= 1
+    assert d["window_steps_discarded"] == eng_pipe.cfg.decode_steps
+    assert d["window_steps_reconciled"] == 0
+    assert eng_pipe._pipeline is None and not eng_pipe.has_work()
+
+
+# -- a row ends under an in-flight follow-up: commit it for the rest --------
+
+SAMPLING = {"greedy": dict(temperature=0.0),
+            "sampled": dict(temperature=0.8, top_k=20, seed=11)}
+both_samplings = pytest.mark.parametrize("mode", list(SAMPLING))
+
+
+def fresh_prompts(seed, lens, vocab=CFG.vocab_size):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(2, vocab, n).tolist() for n in lens]
+
+
+def hidden_stop(stream, after=9):
+    """A token the stream generates for the first time at its `after`-th
+    step or later: as a stop id it ends the row mid-window, in the
+    pipeline's steady state, where no plan foresees it."""
+    return next(t for i, t in enumerate(stream)
+                if i >= after and t not in stream[:i])
+
+
+def stop_case(sync, pipe, prompts, samp, tag, max_tokens=30):
+    """Row 0 of `prompts` ends on a stop id that only the commit sees;
+    (synchronous streams, pipelined streams, the pipelined counters)."""
+    def params(stop=()):
+        return [SamplingParams(max_tokens=max_tokens, ignore_eos=True,
+                               stop_token_ids=stop if i == 0 else (),
+                               **samp) for i in range(len(prompts))]
+    free = drive(sync, prompts, params(), f"{tag}_free")
+    stop = (hidden_stop(free[0]),)
+    want = drive(sync, prompts, params(stop), f"{tag}_s")
+    assert len(want[0]) < max_tokens == len(want[1])
+    before = snap(pipe)
+    got = drive(pipe, prompts, params(stop), f"{tag}_p")
+    return want, got, delta(pipe, before)
+
+
+def assert_committed_not_rerun(d):
+    """The follow-up that ran under the commit which ended a row was
+    committed for the rows still live: none dropped, and every window
+    program dispatched was fetched and committed (a re-run of the
+    follow-up's positions would be a dispatch without a commit)."""
+    assert d["pipeline_fallbacks"] >= 1, "the fallback was not exercised"
+    assert d["window_steps_reconciled"] > 0
+    assert d["window_steps_discarded"] == 0
+    assert d["decode_windows"] == d["decode_host_syncs"]
+
+
+@both_samplings
+def test_a_row_ending_on_a_stop_id_keeps_the_follow_up(eng_sync, eng_pipe,
+                                                       mode):
+    """The KV twin of test_ling_state's
+    test_a_discarded_follow_up_window_is_committed_not_rerun: three rows,
+    one ends mid-window on a stop id no plan foresees. The window in
+    flight behind that commit is committed for the two rows that live on
+    and not run again: depth 2 equals depth 1 token for token."""
+    prompts = fresh_prompts(5, (12, 9, 14))
+    want, got, d = stop_case(eng_sync, eng_pipe, prompts, SAMPLING[mode],
+                             f"stopid_{mode}")
+    assert got == want
+    assert_committed_not_rerun(d)
+
+
+@both_samplings
+def test_a_row_ending_by_length_keeps_the_follow_up(eng_sync, eng_pipe,
+                                                    mode):
+    """The benchmark's case (every request carries ignore_eos): a row's
+    budget runs out inside a FULL rung (the scheduler keeps the full rung
+    while the smallest remaining budget is 3 or more: 11 tokens after
+    the prefill's are two rungs of 4 and 3 of the third) while the
+    follow-up runs, in which the row is dead on the device from max_pos."""
+    prompts = fresh_prompts(6, (10, 13, 11))
+    tag = f"length_{mode}"
+    params = [SamplingParams(max_tokens=n, ignore_eos=True, **SAMPLING[mode])
+              for n in (12, 30, 30)]
+    want = drive(eng_sync, prompts, params, f"{tag}_s")
+    before = snap(eng_pipe)
+    got = drive(eng_pipe, prompts, params, f"{tag}_p")
+    assert got == want and [len(o) for o in got] == [12, 30, 30]
+    d = delta(eng_pipe, before)
+    assert_committed_not_rerun(d)
+    assert d["window_steps_reconciled"] % eng_pipe.cfg.decode_steps == 0
+
+
+@both_samplings
+def test_the_freed_slot_goes_to_a_waiting_request(eng_sync, eng_pipe, mode):
+    """Every slot is busy; a request arrives while the reconciled
+    follow-up is in flight and waits for the slot the finished row freed.
+    The follow-up is committed first (the arrival's prefill is dispatched
+    behind it), then the re-plan admits the arrival: its stream is its
+    solo stream, the survivors' the synchronous loop's, and every page
+    comes back exactly once."""
+    tag = f"slot_{mode}"
+    prompts = fresh_prompts(7, (10, 13, 11, 9, 15))
+    params = [SamplingParams(max_tokens=n, ignore_eos=True, **SAMPLING[mode])
+              for n in (12, 30, 30, 30, 20)]
+    want = [eng_sync.generate(pr, p, f"{tag}_solo{i}")
+            for i, (pr, p) in enumerate(zip(prompts, params))]
+    eng = eng_pipe
+    assert len(prompts) - 1 == eng.cfg.max_slots
+    before = snap(eng)
+    got = {f"{tag}{i}": [] for i in range(len(prompts))}
+    for i in range(4):
+        eng.add_request(EngineRequest(f"{tag}{i}", prompts[i], params[i]))
+    arrived = False
+    while eng.has_work():
+        for ev in eng.step():
+            if ev.token is not None:
+                got[ev.request_id].append(ev.token)
+        pend = eng._pipeline
+        if not arrived and pend is not None and pend.get("reconciled"):
+            # the commit just ended row 0 under this follow-up
+            assert eng.scheduler.running.count(None) == 1
+            eng.add_request(EngineRequest(f"{tag}4", prompts[4], params[4]))
+            arrived = True
+    assert arrived
+    assert [got[f"{tag}{i}"] for i in range(len(prompts))] == want
+    assert_committed_not_rerun(delta(eng, before))
+    assert eng.scheduler.allocator.num_free == eng.cfg.num_pages
+
+
+def _bench_config(name, **change):
+    from dynamo_tpu.models.loader import config_from_hf
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "benchmark", "configs", name,
+                           "config.json")) as f:
+        hf = json.load(f)
+    return dataclasses.replace(config_from_hf(dict(hf, **change), name=name),
+                               dtype="float32", max_model_len=512)
+
+
+CACHE_KINDS = {
+    # per-row scales beside the int8 rows: a row's write touches its own
+    # scale alone
+    "int8-pool": lambda: (dataclasses.replace(CFG, kv_quant="int8"), {}),
+    # ONE leaf of latents a token (DeepseekV3), expert layers behind it
+    "latent-leaf": lambda: (_bench_config("rehearsal-tiny-moonlight"), {}),
+    # a second pool for the sliding layers, cut from the front at every
+    # commit: a 16-token window over 4-token pages, so that a commit
+    # releases pages while the follow-up is in flight against the old
+    # table (the file's own window, 1024, is never left in 40 tokens)
+    "window-pool": lambda: (
+        _bench_config("rehearsal-tiny-mellum", sliding_window=16),
+        dict(page_size=4, num_pages=128)),
+}
+
+
+@pytest.mark.parametrize("kind", list(CACHE_KINDS))
+def test_the_follow_up_is_kept_in_every_cache_kind(kind):
+    """Over the cache kinds the exactness argument names (docs/PERF.md
+    section 3) that a CPU can build: row 0 ends by length at each of
+    eight successive positions (small pages chain one follow-up a plan,
+    so only some of them fall under one) and once on a stop id; depth 2
+    equals depth 1 token for token every time, the follow-ups are
+    committed and not run again, and both pools come back whole."""
+    cfg, kw = CACHE_KINDS[kind]()
+
+    def build(depth):
+        return NativeEngine(cfg, EngineConfig(**dict(dict(
+            page_size=16, num_pages=64, max_slots=4, max_prefill_chunk=32,
+            prefill_buckets=(8, 16, 32), max_model_len=512, decode_steps=4,
+            pipeline_depth=depth), **kw)), seed=0)
+    sync, pipe = build(1), build(2)
+    prompts = fresh_prompts(8, (12, 9, 14), cfg.vocab_size)
+    before = snap(pipe)
+    for n in range(8, 16):
+        params = [SamplingParams(max_tokens=m, ignore_eos=True,
+                                 **SAMPLING["greedy"])
+                  for m in (n, 30, 30)]
+        want = drive(sync, prompts, params, f"{kind}{n}_s")
+        assert drive(pipe, prompts, params, f"{kind}{n}_p") == want
+    want, got, _ = stop_case(sync, pipe, prompts, SAMPLING["sampled"], kind)
+    assert got == want
+    assert_committed_not_rerun(delta(pipe, before))
+    sch = pipe.scheduler
+    assert sch.allocator.num_free == pipe.cfg.num_pages
+    if kind == "window-pool":
+        assert sch.window_released > 0
+        assert sch.window_alloc.num_free == sch.window_alloc.num_pages
 
 
 def test_abort_mid_window_drops_cleanly(eng_sync, eng_pipe):

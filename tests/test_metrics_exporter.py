@@ -24,6 +24,8 @@ def test_exporter_two_worker_graph():
         # step loop does) and assert the gauges follow
         pipe_stats = {"decode_windows": 4, "pipeline_windows": 3,
                       "pipeline_overlapped": 2, "pipeline_fallbacks": 1,
+                      "window_steps_reconciled": 8,
+                      "window_steps_discarded": 0,
                       "decode_host_syncs": 4, "decode_plan_uploads": 1}
         for i, (active, total) in enumerate(((3, 16), (5, 16))):
             rt = await DistributedRuntime.create_local(plane, f"w{i}")
@@ -68,11 +70,18 @@ def test_exporter_two_worker_graph():
             assert 'llm_decode_windows{worker="w0"} 4' in body
             assert 'llm_decode_pipeline_overlapped{worker="w0"} 2' in body
             assert 'llm_decode_pipeline_fallbacks{worker="w0"} 1' in body
+            # a follow-up committed after that fallback, none dropped
+            assert 'llm_decode_window_steps_reconciled{worker="w0"} 8' \
+                in body
+            assert 'llm_decode_window_steps_discarded{worker="w0"} 0' \
+                in body
             assert 'llm_decode_plan_uploads{worker="w0"} 1' in body
             # the engine keeps committing overlapped windows: the gauges
             # must ADVANCE with the next scrape
             pipe_stats.update(decode_windows=11, pipeline_windows=10,
-                              pipeline_overlapped=9, decode_host_syncs=10)
+                              pipeline_overlapped=9, decode_host_syncs=10,
+                              pipeline_fallbacks=2,
+                              window_steps_reconciled=16)
 
             # reliability counter snapshots ride the event plane the same
             # way ({ns}.{source}.reliability) and fold into gauges labeled
@@ -101,6 +110,8 @@ def test_exporter_two_worker_graph():
             assert 'llm_decode_windows{worker="w0"} 11' in body2
             assert 'llm_decode_pipeline_overlapped{worker="w0"} 9' in body2
             assert 'llm_decode_host_syncs{worker="w0"} 10' in body2
+            assert 'llm_decode_window_steps_reconciled{worker="w0"} 16' \
+                in body2
             assert 'llm_reliability_migrations{source="front0"} 3' in body2
             assert 'llm_reliability_retries{source="front0"} 2' in body2
             assert 'llm_reliability_breaker_opens{source="front0"} 1' \
