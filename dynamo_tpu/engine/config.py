@@ -9,7 +9,7 @@ dynamo_tpu/llm/model_card.py.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Union
 
 
 @dataclasses.dataclass(frozen=True)
@@ -27,17 +27,28 @@ class ModelConfig:
     rope_theta: float = 500000.0
     rms_norm_eps: float = 1e-5
     attn_bias: bool = False      # q/k/v projection bias (Qwen2-style)
-    # OLMoE: RMSNorm over the WHOLE q and k projections (all heads
-    # together, one weight vector each: leaves q_norm / k_norm), applied
-    # before the split into heads and RoPE
-    qk_norm: bool = False
-    # Gemma-family architecture deltas (HF GemmaForCausalLM):
-    embed_scale: float = 0.0     # 0 = off; Gemma multiplies embeddings by
-    #                              sqrt(hidden_size) before the first layer
+    # An RMSNorm on q and k before RoPE (leaves q_norm / k_norm), by its
+    # granularity: True (OLMoE) = over the WHOLE projection, all heads
+    # together, one weight vector of H x head_dim each, before the split
+    # into heads; "head" (afmoe) = over EACH head's head_dim values, one
+    # weight vector of head_dim shared by the heads
+    qk_norm: Union[bool, str] = False   # False | True | "head"
+    # softmax attention's output gate (afmoe): o = Wo (attn *
+    # sigmoid(x_normed Wg)), element-wise, Wg the leaf `w_out_gate`
+    # [D, H x head_dim]. The latent path has its own, head-wise
+    # (`mla_gate`), the linear layers theirs (`kda_wg`)
+    attn_out_gate: bool = False
+    # Gemma-family architecture deltas (HF GemmaForCausalLM), which other
+    # families share one at a time (afmoe: `embed_scale` under
+    # `mup_enabled`, and `post_norms`):
+    embed_scale: float = 0.0     # 0 = off; the embeddings are multiplied
+    #                              by this (sqrt(hidden_size)) before the
+    #                              first layer
     norm_plus_one: bool = False  # RMSNorm weight applied as (1 + w), in f32
     mlp_act: str = "silu"        # "silu" | "gelu_tanh" (Gemma GeGLU)
     # Gemma-2 deltas:
     post_norms: bool = False     # extra post-attention / post-ffw RMSNorms
+    #                              (four norms a block; afmoe has them too)
     attn_softcap: float = 0.0    # tanh soft-cap on attention logits (50.0)
     final_softcap: float = 0.0   # tanh soft-cap on lm-head logits (30.0)
     query_scale: float = 0.0     # q scaling; 0 = default head_dim**-0.5
@@ -99,7 +110,9 @@ class ModelConfig:
     # passes (n_shared_experts x moe_intermediate_size), 0 = none;
     # `first_dense_layers`: leading layers with a dense MLP of
     # `dense_intermediate_size` where the rest have experts (the layer
-    # kinds are split once, models/llama.layer_groups)
+    # kinds are split once, models/llama.layer_runs; in a `window_pool`
+    # model the lead has stacks of its own and runs BEFORE the loop over
+    # periods, models/llama.layer_period)
     moe_scoring: str = "softmax"
     moe_router_bias: bool = False
     moe_routed_scale: float = 1.0
@@ -320,7 +333,9 @@ class ModelConfig:
 @dataclasses.dataclass(frozen=True)
 class RopeParams:
     """One layer kind's RoPE (an entry of HF's `rope_parameters`).
-    `rope_type` "default": plain RoPE at `theta`. "yarn": the frequencies
+    `rope_type` "default": plain RoPE at `theta`. "none": the kind has NO
+    positional embedding: q and k go to attention unrotated, and no op is
+    traced for it (afmoe's full-attention layers). "yarn": the frequencies
     blend interpolated (1 / (factor f_i)) and extrapolated (1 / f_i) by a
     linear ramp between the dimensions that turn `beta_fast` and
     `beta_slow` times within `original_max_position`, and cos / sin are

@@ -1,6 +1,9 @@
-"""Llama-family decoder (also hosts the Mixtral-style MoE MLP variant, and
+"""Llama-family decoder (also hosts the Mixtral-style MoE MLP variant,
 DeepSeek-V3's latent attention, sigmoid router, shared experts and dense
-lead: `_mla_front`, `_mlp_block`, `layer_groups`).
+lead: `_mla_front`, `_mlp_block`, `layer_groups`; and afmoe's head-wise
+QK-norm, output gate, RoPE-less full layers and dense lead in front of a
+window pool's period loop: `qkv_proj`, `_out_gate`, `rope_table`,
+`layer_period`).
 
 Functional JAX, TPU-first:
 - parameters are a pytree of arrays **stacked over layers** and the layer loop
@@ -144,6 +147,11 @@ class LayerRun(NamedTuple):
     # "swa" ones (== first where all alike)
     store_first: int
 
+    @property
+    def is_lead(self) -> bool:
+        """A stack of a `window_pool` model's dense lead (`layer_runs`)."""
+        return self.key.startswith("lead")
+
     def store_index(self, lid):
         """Layer `lid` of this run -> its index in its store's layer axis
         (`lid` itself where the two axes agree: no arithmetic traced)."""
@@ -166,17 +174,38 @@ def layer_runs(cfg: ModelConfig) -> tuple:
     stack a KIND, `run0` and `run1` in the order the kinds first appear:
     ALL its "swa" layers and ALL its "mha" layers, which do not lie side
     by side in the model (S S S F S S S F ...); `layer_period` says in
-    what order the loop takes them. init_params,
+    what order the loop takes them. A dense lead in FRONT of such a
+    model is no part of those stacks: it has stacks of its own, a run of
+    like kinds each (`lead0`, ...), which come first in this tuple, run
+    before the loop over periods, and lie FIRST in their kind's store
+    (the window pool's layer axis: the lead's "swa" layers, then
+    `run0`'s). init_params,
     param_shardings, forward(), decode_forward() and models/loader.py
     all walk this."""
     lead = cfg.first_dense_layers if cfg.is_moe else 0
     own = "mla" if cfg.is_mla else "mha"
     kinds = cfg.layer_kinds()
+    seen = dict.fromkeys(kinds, 0)
+
+    def like_runs(kinds, prefix):
+        """Runs of consecutive layers alike in (attention kind, MLP kind),
+        `seen` counting each kind's layers so far (its store's axis)."""
+        runs = []
+        for i, kind in enumerate(kinds):
+            dense = not cfg.is_moe or i < lead
+            if runs and (runs[-1].kind, runs[-1].dense) == (kind, dense):
+                runs[-1] = runs[-1]._replace(count=runs[-1].count + 1)
+            else:
+                runs.append(LayerRun(f"{prefix}{len(runs)}", i, 1, dense,
+                                     kind, seen[kind]))
+            seen[kind] += 1
+        return tuple(runs)
     if cfg.window_pool:
-        order = list(dict.fromkeys(kinds))
-        return tuple(LayerRun(f"run{i}", kinds.index(kind),
-                              kinds.count(kind), not cfg.is_moe, kind, 0)
-                     for i, kind in enumerate(order))
+        rest = kinds[lead:]
+        return like_runs(kinds[:lead], "lead") + tuple(
+            LayerRun(f"run{i}", lead + rest.index(kind), rest.count(kind),
+                     not cfg.is_moe, kind, seen[kind])
+            for i, kind in enumerate(dict.fromkeys(rest)))
     if len(set(kinds)) == 1:
         if not lead:
             return (LayerRun("layers", 0, cfg.num_layers, not cfg.is_moe,
@@ -184,26 +213,21 @@ def layer_runs(cfg: ModelConfig) -> tuple:
         return (LayerRun("dense_layers", 0, lead, True, own, 0),
                 LayerRun("layers", lead, cfg.num_layers - lead, False, own,
                          lead))
-    runs, seen = [], dict.fromkeys(kinds, 0)
-    for i, kind in enumerate(kinds):
-        dense = not cfg.is_moe or i < lead
-        if runs and (runs[-1].kind, runs[-1].dense) == (kind, dense):
-            runs[-1] = runs[-1]._replace(count=runs[-1].count + 1)
-        else:
-            runs.append(LayerRun(f"run{len(runs)}", i, 1, dense, kind,
-                                 seen[kind]))
-        seen[kind] += 1
-    return tuple(runs)
+    return like_runs(kinds, "run")
 
 
 class LayerPeriod(NamedTuple):
     """The order in which a `window_pool` model's loop takes the layers
-    of its two stacks: `count` periods, each the same `parts`."""
+    of its kind stacks: `count` periods, each the same `parts`, after the
+    `lead` runs that stand in front of the loop."""
     count: int
     # ((index in layer_runs, the kind's layers a period, the part's first
     # among them, the part's layers), ...): layer j of a part in period p
     # is layer p * per + offset + j of its run's stack
     parts: tuple
+    # how many of layer_runs' first entries are the dense lead's: each a
+    # scan of its own BEFORE the scan over periods (0: no lead)
+    lead: int = 0
 
 
 def layer_period(cfg: ModelConfig) -> Optional[LayerPeriod]:
@@ -213,14 +237,22 @@ def layer_period(cfg: ModelConfig) -> Optional[LayerPeriod]:
     a scan over periods whose body is a scan a part (models/llama.forward,
     decode_forward). A scan a run of like layers, as the hybrid has,
     would compile six layer bodies for S S S F x 3 where this compiles
-    two, and every engine program pays that (PERF.md section 6, PR 38)."""
+    two, and every engine program pays that (PERF.md section 6, PR 38).
+    The period is found over the layers AFTER a dense lead
+    (`first_dense_layers`), whose runs stand before the loop
+    (`LayerPeriod.lead`): one more layer body a run of the lead."""
     if not cfg.window_pool:
         return None
-    kinds, runs = cfg.layer_kinds(), layer_runs(cfg)
+    runs = layer_runs(cfg)
+    lead = sum(run.is_lead for run in runs)
+    kinds = cfg.layer_kinds()[sum(run.count for run in runs[:lead]):]
     n = len(kinds)
+    if not n:
+        raise ValueError(f"{cfg.name}: first_dense_layers leaves no layer "
+                         f"behind the lead")
     size = next(p for p in range(1, n + 1)
                 if n % p == 0 and kinds == kinds[:p] * (n // p))
-    index = {run.kind: i for i, run in enumerate(runs)}
+    index = {run.kind: i for i, run in enumerate(runs) if i >= lead}
     parts, seen = [], dict.fromkeys(index, 0)
     for i, kind in enumerate(kinds[:size]):
         if parts and parts[-1][0] == index[kind] and i > 0 \
@@ -230,7 +262,8 @@ def layer_period(cfg: ModelConfig) -> Optional[LayerPeriod]:
             parts.append([index[kind], kinds[:size].count(kind),
                           seen[kind], 1])
         seen[kind] += 1
-    return LayerPeriod(n // size, tuple(tuple(part) for part in parts))
+    return LayerPeriod(n // size, tuple(tuple(part) for part in parts),
+                       lead)
 
 
 def layer_groups(cfg: ModelConfig) -> tuple:
@@ -329,6 +362,9 @@ def _init_layer_stack(keys, cfg: ModelConfig, l: int, dense_mlp: bool,
             "wk": dense(keys[1], (l, d, hkv * hd), d),
             "wv": dense(keys[2], (l, d, hkv * hd), d),
         })
+        if cfg.attn_out_gate:
+            layers["w_out_gate"] = dense(jax.random.fold_in(more[5], 5),
+                                         (l, d, h * hd), d)
     if cfg.post_norms:
         layers.update({
             "post_attn_norm": jnp.ones((l, d), dt),
@@ -341,9 +377,12 @@ def _init_layer_stack(keys, cfg: ModelConfig, l: int, dense_mlp: bool,
             "wv_b": jnp.zeros((l, hkv * hd), dt),
         })
     if cfg.qk_norm:
+        # one weight vector for all heads where the norm is a head's
+        per_head = cfg.qk_norm == "head"
         layers.update({
-            "q_norm": near_one(keys[10], (l, h * hd)),
-            "k_norm": near_one(keys[11], (l, hkv * hd)),
+            "q_norm": near_one(keys[10], (l, hd if per_head else h * hd)),
+            "k_norm": near_one(keys[11],
+                               (l, hd if per_head else hkv * hd)),
         })
     if cfg.is_moe and not dense_mlp:
         # the router's width, and the experts held here (a share, or all)
@@ -376,6 +415,11 @@ def _init_layer_stack(keys, cfg: ModelConfig, l: int, dense_mlp: bool,
         # a hybrid's block norms too are drawn away from one
         layers["attn_norm"] = near_one(jax.random.fold_in(more[5], 3), (l, d))
         layers["mlp_norm"] = near_one(jax.random.fold_in(more[5], 4), (l, d))
+        if cfg.post_norms:
+            layers["post_attn_norm"] = near_one(
+                jax.random.fold_in(more[5], 6), (l, d))
+            layers["post_mlp_norm"] = near_one(
+                jax.random.fold_in(more[5], 7), (l, d))
     return layers
 
 
@@ -465,6 +509,8 @@ def _layer_stack_shardings(cfg: ModelConfig, dense_mlp: bool,
             layers["w_attn_gate"] = P(None, None, None)
     else:
         layers.update({"wk": P(None, None, "tp"), "wv": P(None, None, "tp")})
+        if cfg.attn_out_gate:
+            layers["w_out_gate"] = P(None, None, "tp")
     if cfg.post_norms:
         layers.update({
             "post_attn_norm": P(None, None),
@@ -477,7 +523,9 @@ def _layer_stack_shardings(cfg: ModelConfig, dense_mlp: bool,
             "wv_b": P(None, "tp"),
         })
     if cfg.qk_norm:
-        layers.update({"q_norm": P(None, "tp"), "k_norm": P(None, "tp")})
+        # a head's norm has one weight vector, whole on every shard
+        spec = P(None, None) if cfg.qk_norm == "head" else P(None, "tp")
+        layers.update({"q_norm": spec, "k_norm": spec})
     if cfg.is_moe and not dense_mlp:
         # experts shard over "ep", each expert's FFN dim over "tp"; on
         # meshes without those axes (size 1) the specs are no-ops
@@ -765,6 +813,14 @@ def yarn_inv_freq(p, dim: int) -> np.ndarray:
             ).astype(f32)
 
 
+def _lead_scope(run: LayerRun):
+    """`layers.lead` around the scan of a window-pool model's dense lead
+    (`layer_runs`), whose layer body is its own beside the bodies of the
+    period loop; no scope around any other run."""
+    return jax.named_scope("layers.lead") if run.is_lead \
+        else contextlib.nullcontext()
+
+
 def _full_scope(cfg: ModelConfig):
     """`attention.full` around a full layer's attention where the model
     also has window layers (`attention.window`); no scope elsewhere."""
@@ -779,15 +835,19 @@ def rope_table(cfg: ModelConfig, kind: str = "") -> tuple:
     any other: `cfg.rope_full`. A kind without parameters of its own, or
     with plain ones, is (theta, None, 1.0): `apply_rope` then computes
     1 / theta ** (2i / d) inline, the one expression every program had
-    before RoPE went by kind, so those programs are the same programs."""
+    before RoPE went by kind, so those programs are the same programs.
+    None for a kind WITHOUT a positional embedding (`rope_type` "none"):
+    the caller then traces nothing for it (no multiply by cos 0 = 1)."""
     p = cfg.rope_sliding if kind == "swa" else cfg.rope_full
     if p is None:
         return cfg.rope_theta, None, 1.0
     if p.rope_type == "default":
         return p.theta, None, 1.0
+    if p.rope_type == "none":
+        return None
     if p.rope_type != "yarn":
         raise ValueError(f"{cfg.name}: rope_type {p.rope_type!r} is not "
-                         f"modelled (default, yarn)")
+                         f"modelled (default, none, yarn)")
     # Python numbers of the config throughout: nothing here is traced
     scale = p.attention_factor or 0.1 * math.log(p.factor) + 1.0
     return p.theta, yarn_inv_freq(p, cfg.head_dim), scale
@@ -852,15 +912,24 @@ def _dense_mlp(x: jax.Array, lp: Params, cfg: ModelConfig,
 
 def qkv_proj(xn: jax.Array, lp: Params, cfg: ModelConfig):
     """x -> (q [B, T, H*hd], k, v [B, T, Hkv*hd]), before the split into
-    heads and RoPE. With `cfg.qk_norm` (OLMoE) q and k each pass an
+    heads and RoPE. With `cfg.qk_norm` True (OLMoE) q and k each pass an
     RMSNorm over the WHOLE projection, all heads together, with its own
-    weight vector; what reaches the cache is the normed, rotated k."""
+    weight vector; with "head" (afmoe) over EACH head's `head_dim`
+    values, one weight vector shared by the heads. What reaches the
+    cache is the normed (and, where the kind rotates, rotated) k."""
     q = jnp.einsum("btd,de->bte", xn, wmat(lp["wq"], xn.dtype))
     k = jnp.einsum("btd,de->bte", xn, wmat(lp["wk"], xn.dtype))
     v = jnp.einsum("btd,de->bte", xn, wmat(lp["wv"], xn.dtype))
     if cfg.attn_bias:
         q, k, v = q + lp["wq_b"], k + lp["wk_b"], v + lp["wv_b"]
-    if cfg.qk_norm:
+    if cfg.qk_norm == "head":
+        with jax.named_scope("attention.head_qk_norm"):
+            by_head = q.shape[:2] + (-1, cfg.head_dim)
+            q = rms_norm(q.reshape(by_head), lp["q_norm"],
+                         cfg.rms_norm_eps).reshape(q.shape)
+            k = rms_norm(k.reshape(by_head), lp["k_norm"],
+                         cfg.rms_norm_eps).reshape(k.shape)
+    elif cfg.qk_norm:
         with jax.named_scope("attention.qk_norm"):
             q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
             k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
@@ -949,7 +1018,7 @@ def layer_front(x: jax.Array, lp: Params, cfg: ModelConfig,
                 positions: jax.Array, heads: tuple, kind: str = ""):
     """x [B, T, D] -> q [B, T, H, hd], k, v [B, T, Hkv, hd]: attention
     norm, QKV projection (bias, QK-norm), split into heads, RoPE on q and
-    k. `heads` = (H, Hkv) as the caller holds them: a "tp" shard of a
+    k where `kind` has one (`rope_table`). `heads` = (H, Hkv) as the caller holds them: a "tp" shard of a
     manual mesh passes its local counts. Under latent attention
     (`_mla_front`) q is the absorbed query, k the token's ONE cache row
     and v None: the attention ops take the whole row as its values and
@@ -965,9 +1034,12 @@ def layer_front(x: jax.Array, lp: Params, cfg: ModelConfig,
     xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
     q, k, v = qkv_proj(xn, lp, cfg)
     rope = rope_table(cfg, kind)
-    q = apply_rope(q.reshape(b, t, h, cfg.head_dim), positions, *rope)
-    k = apply_rope(k.reshape(b, t, hkv, cfg.head_dim), positions, *rope)
-    return q, k, v.reshape(b, t, hkv, cfg.head_dim)
+
+    def heads_of(a, n):
+        a = a.reshape(b, t, n, cfg.head_dim)
+        return a if rope is None else apply_rope(a, positions, *rope)
+    return heads_of(q, h), heads_of(k, hkv), \
+        v.reshape(b, t, hkv, cfg.head_dim)
 
 
 def _mla_up_proj(lp: Params, cfg: ModelConfig, dtype) -> tuple:
@@ -1029,18 +1101,33 @@ def _mla_out(attn: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
                           w_uv)
 
 
+def _input_gate(x: jax.Array, lp: Params, cfg: ModelConfig,
+                leaf: str) -> jax.Array:
+    """sigmoid(x_normed W) in float32, W the leaf `leaf` [D, n]: the gate
+    a layer's back half puts on its attention's output, from the block's
+    input (the attention norm recomputed: one pass over x)."""
+    xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
+    return jax.nn.sigmoid(jnp.einsum(
+        "btd,de->bte", xn, wmat(lp[leaf], xn.dtype)).astype(jnp.float32))
+
+
 def _mla_gate(attn: jax.Array, x: jax.Array, lp: Params,
               cfg: ModelConfig) -> jax.Array:
     """The head-wise output gate: attn [B, T, H, hd] times
-    sigmoid(x_normed Wgate)_h (the norm recomputed: one pass over x)."""
+    sigmoid(x_normed Wgate)_h."""
     with jax.named_scope("attention.mla.gate"):
-        xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps,
-                      cfg.norm_plus_one)
-        gate = jax.nn.sigmoid(jnp.einsum(
-            "btd,dh->bth", xn, wmat(lp["w_attn_gate"], xn.dtype)
-        ).astype(jnp.float32))
+        gate = _input_gate(x, lp, cfg, "w_attn_gate")
         return (attn.astype(jnp.float32) * gate[..., None]
                 ).astype(attn.dtype)
+
+
+def _out_gate(attn: jax.Array, x: jax.Array, lp: Params,
+              cfg: ModelConfig) -> jax.Array:
+    """Softmax attention's element-wise output gate (`attn_out_gate`):
+    attn [B, T, H * hd] times sigmoid(x_normed Wg), before `wo`."""
+    with jax.named_scope("attention.out_gate"):
+        return (attn.astype(jnp.float32)
+                * _input_gate(x, lp, cfg, "w_out_gate")).astype(attn.dtype)
 
 
 def _kda_front(x: jax.Array, lp: Params, cfg: ModelConfig):
@@ -1267,11 +1354,7 @@ def _kda_out(o: jax.Array, x: jax.Array, lp: Params,
     RMSNorm over each head, times sigmoid(x_normed Wg) element-wise, in
     the model's dtype."""
     with jax.named_scope("linattn.out"):
-        xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps,
-                      cfg.norm_plus_one)
-        gate = jax.nn.sigmoid(jnp.einsum(
-            "btd,de->bte", xn, wmat(lp["kda_wg"], xn.dtype)
-        ).astype(jnp.float32)).reshape(o.shape)
+        gate = _input_gate(x, lp, cfg, "kda_wg").reshape(o.shape)
         o = rms_norm(o, lp["kda_o_norm"].astype(jnp.float32),
                      cfg.rms_norm_eps)
         return (o * gate).astype(x.dtype)
@@ -1286,7 +1369,8 @@ def layer_back(x: jax.Array, attn: jax.Array, lp: Params, cfg: ModelConfig,
     `reduce` sums a partial product over the caller's manual "tp" axis; it
     comes BEFORE the post-norm, which is nonlinear and must see the whole
     output, not a shard's partial sum. Latent attention hands over the
-    weighted latents; their value projection (`_mla_out`) comes first."""
+    weighted latents; their value projection (`_mla_out`) comes first.
+    Softmax attention's output gate (`_out_gate`) sits before `wo`."""
     b, t = x.shape[:2]
     if kind == "kda":
         attn = _kda_out(attn, x, lp, cfg)
@@ -1294,6 +1378,8 @@ def layer_back(x: jax.Array, attn: jax.Array, lp: Params, cfg: ModelConfig,
         attn = _mla_out(attn.reshape(b, t, cfg.num_heads, -1), lp, cfg)
         if cfg.mla_gate:
             attn = _mla_gate(attn, x, lp, cfg)
+    elif cfg.attn_out_gate:
+        attn = _out_gate(attn.reshape(b, t, -1), x, lp, cfg)
     out = jnp.einsum("bte,ed->btd", attn.reshape(b, t, -1),
                      wmat(lp["wo"], x.dtype))
     if reduce is not None:
@@ -1511,40 +1597,9 @@ def decode_forward(
     wk_news, wv_news = [], []
     st = None if state is None else state[0]
     period = layer_period(cfg)
-    if period is not None:
-        # ONE loop: a scan over periods, a scan a part inside it; the new
-        # rows come out [periods, a kind's layers a period, ...] = the
-        # kind's store order
-        stacks = [split_expert_stacks(params[run.key], cfg, mesh)
-                  for run in runs]
-
-        def period_step(x, p):
-            rows = [([], []) for _ in runs]
-            stats = []
-            for ri, per, offset, count in period.parts:
-                run = runs[ri]
-                lids = run.first + p * per + offset \
-                    + jnp.arange(count, dtype=jnp.int32)
-                x, (k_g, v_g, drop_g) = jax.lax.scan(
-                    functools.partial(layer_step, run=run,
-                                      expert_stacks=stacks[ri][1],
-                                      stack=stacks[ri][0]),
-                    x, (None, lids, None, None))
-                rows[ri][0].append(k_g)
-                rows[ri][1].append(v_g)
-                stats.append(_sum_stats(drop_g))
-            return x, (tuple(tuple(jnp.concatenate(g, axis=0) for g in kv)
-                             for kv in rows), _merge_stats(stats))
-
-        x, (rows, drop_p) = jax.lax.scan(
-            period_step, x, jnp.arange(period.count, dtype=jnp.int32))
-        for run, (k_g, v_g) in zip(runs, rows):
-            k_g, v_g = (g.reshape((-1,) + g.shape[2:]) for g in (k_g, v_g))
-            (wk_news if run.kind == "swa" else k_news).append(k_g)
-            (wv_news if run.kind == "swa" else v_news).append(v_g)
-        drops.append(_sum_stats(drop_p))
-        runs = ()
-    for run in runs:
+    # every run but a window-pool model's kind stacks: a scan a run, in
+    # layer order (such a model's dense lead among them, BEFORE its loop)
+    for run in runs if period is None else runs[:period.lead]:
         name, first, count, dense = run[:4]
         scan_layers, expert_stacks = (params[name], None) if dense \
             else split_expert_stacks(params[name], cfg, mesh)
@@ -1559,13 +1614,47 @@ def decode_forward(
         xs = (scan_layers, part(layer_ids),
               None if layer_wnd is None else part(layer_wnd),
               win_leaves if window is not None and whole else None)
-        x, (k_g, v_g, drop_g) = jax.lax.scan(
-            functools.partial(layer_step, run=run,
-                              expert_stacks=expert_stacks),
-            x, xs)
-        k_news.append(k_g)
-        v_news.append(v_g)
+        with _lead_scope(run):
+            x, (k_g, v_g, drop_g) = jax.lax.scan(
+                functools.partial(layer_step, run=run,
+                                  expert_stacks=expert_stacks),
+                x, xs)
+        (wk_news if run.kind == "swa" else k_news).append(k_g)
+        (wv_news if run.kind == "swa" else v_news).append(v_g)
         drops.append(_sum_stats(drop_g))
+    if period is not None:
+        # ONE loop: a scan over periods, a scan a part inside it; the new
+        # rows come out [periods, a kind's layers a period, ...] = the
+        # kind's store order, behind the lead's
+        loop = range(period.lead, len(runs))
+        stacks = {ri: split_expert_stacks(params[runs[ri].key], cfg, mesh)
+                  for ri in loop}
+
+        def period_step(x, p):
+            rows = {ri: ([], []) for ri in loop}
+            stats = []
+            for ri, per, offset, count in period.parts:
+                run = runs[ri]
+                lids = run.first + p * per + offset \
+                    + jnp.arange(count, dtype=jnp.int32)
+                x, (k_g, v_g, drop_g) = jax.lax.scan(
+                    functools.partial(layer_step, run=run,
+                                      expert_stacks=stacks[ri][1],
+                                      stack=stacks[ri][0]),
+                    x, (None, lids, None, None))
+                rows[ri][0].append(k_g)
+                rows[ri][1].append(v_g)
+                stats.append(_sum_stats(drop_g))
+            return x, (tuple(tuple(jnp.concatenate(g, axis=0) for g in kv)
+                             for kv in rows.values()), _merge_stats(stats))
+
+        x, (rows, drop_p) = jax.lax.scan(
+            period_step, x, jnp.arange(period.count, dtype=jnp.int32))
+        for ri, (k_g, v_g) in zip(loop, rows):
+            k_g, v_g = (g.reshape((-1,) + g.shape[2:]) for g in (k_g, v_g))
+            (wk_news if runs[ri].kind == "swa" else k_news).append(k_g)
+            (wv_news if runs[ri].kind == "swa" else v_news).append(v_g)
+        drops.append(_sum_stats(drop_p))
     k_news, v_news, wk_news, wv_news = (
         None if not g or g[0] is None else g[0] if len(g) == 1
         else jnp.concatenate(g, axis=0)
@@ -1942,11 +2031,26 @@ def forward(
                             jnp.float32),)
     drops = []
     period = layer_period(cfg)
+    # every run but a window-pool model's kind stacks: a scan a run, in
+    # layer order (such a model's dense lead among them, BEFORE its loop)
+    for run in runs if period is None else runs[:period.lead]:
+        name, first, count, dense = run[:4]
+        scan_layers, expert_stacks = (params[name], None) if dense \
+            else split_expert_stacks(params[name], cfg, mesh)
+        part = _group_rows(whole, first, count)
+        scan_xs = (scan_layers if sel is None else None, part(layer_ids),
+                   None if layer_wnd is None else part(layer_wnd))
+        with _lead_scope(run):
+            (x, pool, state, wpool), drop_g = jax.lax.scan(
+                functools.partial(layer_step, stack=scan_layers, run=run,
+                                  expert_stacks=expert_stacks),
+                (x, pool, state, wpool), scan_xs)
+        drops.append(_sum_stats(drop_g))
     if period is not None:
         # ONE loop: a scan over periods, a scan a part inside it, every
         # layer read from its kind's stack where it lies (`lp_of`)
-        stacks = [split_expert_stacks(params[run.key], cfg, mesh)
-                  for run in runs]
+        stacks = {ri: split_expert_stacks(params[runs[ri].key], cfg, mesh)
+                  for ri in range(period.lead, len(runs))}
 
         def period_step(carry, p):
             stats = []
@@ -1965,19 +2069,6 @@ def forward(
             period_step, (x, pool, state, wpool),
             jnp.arange(period.count, dtype=jnp.int32))
         drops.append(_sum_stats(drop_p))
-        runs = ()
-    for run in runs:
-        name, first, count, dense = run[:4]
-        scan_layers, expert_stacks = (params[name], None) if dense \
-            else split_expert_stacks(params[name], cfg, mesh)
-        part = _group_rows(whole, first, count)
-        scan_xs = (scan_layers if sel is None else None, part(layer_ids),
-                   None if layer_wnd is None else part(layer_wnd))
-        (x, pool, state, wpool), drop_g = jax.lax.scan(
-            functools.partial(layer_step, stack=scan_layers, run=run,
-                              expert_stacks=expert_stacks),
-            (x, pool, state, wpool), scan_xs)
-        drops.append(_sum_stats(drop_g))
     aux = _merge_stats(drops)
 
     if last_idx is not None:

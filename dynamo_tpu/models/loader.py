@@ -32,7 +32,11 @@ ARCHES = {
     "DeepseekV3ForCausalLM": "deepseek_v3",
     "BailingHybridForCausalLM": "bailing_hybrid",
     "MellumForCausalLM": "mellum",
+    "AfmoeForCausalLM": "afmoe",
 }
+# the families whose sliding layers are served as WINDOWS, from a page
+# pool of their own (`window_pool`): their serving length is not capped
+WINDOW_POOL_FAMILIES = ("mellum", "afmoe")
 
 
 def config_from_hf(hf: Dict[str, Any], name: str = "") -> ModelConfig:
@@ -48,7 +52,8 @@ def config_from_hf(hf: Dict[str, Any], name: str = "") -> ModelConfig:
     # what a family sets beyond the shared fields below
     own = {"deepseek_v3": deepseek_v3_fields,
            "bailing_hybrid": bailing_hybrid_fields,
-           "mellum": mellum_fields}.get(
+           "mellum": mellum_fields,
+           "afmoe": afmoe_fields}.get(
                family, lambda hf: {})(hf)
     if hf.get("clip_qkv") is not None:
         # OLMoE's optional clamp of q/k/v to +-clip_qkv is not modeled:
@@ -64,7 +69,8 @@ def config_from_hf(hf: Dict[str, Any], name: str = "") -> ModelConfig:
         # using plain rope_theta would produce wrong logits past the
         # original context, so refuse rather than mis-serve. What IS
         # modelled: YaRN by layer kind, from `rope_parameters` under the
-        # `mellum` family (mellum_fields -> models/llama.rope_table)
+        # `mellum` family (mellum_fields -> models/llama.rope_table), and
+        # NO RoPE on a kind (`afmoe`'s full layers, afmoe_fields)
         kind = (hf["rope_scaling"].get("rope_type")
                 or hf["rope_scaling"].get("type") or "?")
         raise ValueError(
@@ -75,8 +81,9 @@ def config_from_hf(hf: Dict[str, Any], name: str = "") -> ModelConfig:
             f"scaling (e.g. the base-context variant)")
     max_len = int(hf.get("max_position_embeddings", 2048))
     sliding, layer_types = 0, ()
-    if family == "mellum":
-        pass   # window layers served as windows: no cap (mellum_fields)
+    if family in WINDOW_POOL_FAMILIES:
+        pass   # window layers served as windows: no cap (mellum_fields,
+        #        afmoe_fields)
     elif gemma2 and hf.get("sliding_window"):
         # modeled natively: a traced mask width a layer over the shared
         # pool, whatever the pattern (the published one alternates)
@@ -95,8 +102,9 @@ def config_from_hf(hf: Dict[str, Any], name: str = "") -> ModelConfig:
     elif hf.get("sliding_window") and hf.get("use_sliding_window", True):
         # full attention == sliding-window attention while the context
         # fits inside the window. This family's window layers are NOT
-        # served as windows (only `mellum`'s are, from a page pool of
-        # their own; Gemma-2's are a mask), so the serving length is
+        # served as windows (only `mellum`'s and `afmoe`'s are, from a
+        # page pool of their own; Gemma-2's are a mask), so the serving
+        # length is
         # capped at the window: models like phi-3-mini-4k (window 2047) /
         # mistral-v0.1 (4096) stay exact instead of silently diverging
         # past it
@@ -143,16 +151,37 @@ def config_from_hf(hf: Dict[str, Any], name: str = "") -> ModelConfig:
     ), **own)
 
 
+def _refuser(hf: Dict[str, Any]):
+    """refuse(key, ok, modelled): raise, naming the key, where the file's
+    value of `key` is not one that `ok` accepts."""
+    def refuse(key, ok, modelled):
+        if not ok(hf.get(key)):
+            raise ValueError(f"{key}={hf.get(key)!r} is not supported "
+                             f"(only {modelled} is modelled)")
+    return refuse
+
+
+def _layer_types(hf: Dict[str, Any], unread: str) -> tuple:
+    """A window-pool family's `layer_types`, one of sliding_attention |
+    full_attention a layer; `unread`: the key beside it that is not read."""
+    layers = int(hf["num_hidden_layers"])
+    types = hf.get("layer_types")
+    if not types or len(types) != layers or \
+            set(types) - {"sliding_attention", "full_attention"}:
+        raise ValueError(
+            f"layer_types={types!r}: one of sliding_attention | "
+            f"full_attention a layer ({layers}) is what is modelled "
+            f"({unread} is not read: layer_types governs)")
+    return tuple(types)
+
+
 def deepseek_v3_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
     """The ModelConfig fields of a `DeepseekV3ForCausalLM` config.json
     (Moonlight-16B-A3B): latent attention without a query LoRA, a leading
     run of dense layers, then routed experts behind a sigmoid router with
     a selection bias, plus shared experts. What is not modelled is
     refused here, not mis-served."""
-    def refuse(key, ok, modelled):
-        if not ok(hf.get(key)):
-            raise ValueError(f"{key}={hf.get(key)!r} is not supported "
-                             f"(only {modelled} is modelled)")
+    refuse = _refuser(hf)
     refuse("q_lora_rank", lambda v: v is None, "q_lora_rank: null")
     refuse("n_group", lambda v: v in (None, 1), "one expert group")
     refuse("topk_group", lambda v: v in (None, 1), "one expert group")
@@ -198,10 +227,7 @@ def bailing_hybrid_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
     shared experts. `num_experts` may be a chip's SHARE of the router's
     `num_experts_published` (from `expert_first` on). What is not
     modelled is refused here, by key, not mis-served."""
-    def refuse(key, ok, modelled):
-        if not ok(hf.get(key)):
-            raise ValueError(f"{key}={hf.get(key)!r} is not supported "
-                             f"(only {modelled} is modelled)")
+    refuse = _refuser(hf)
     refuse("q_lora_rank", lambda v: v is None, "q_lora_rank: null")
     for key in ("use_kda_lora", "use_mla_nope", "use_nGPT", "value_norm",
                 "up_proj_norm", "scale_router_input", "use_bias",
@@ -272,21 +298,15 @@ def mellum_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
     from `rope_parameters` (plain on the sliding layers, YaRN on the full
     ones), and a softmax router over `num_experts` experts of
     `moe_intermediate_size` in every layer, the k largest renormalised.
-    No key declares a QK-norm, a shared expert, a selection bias or a
-    multi-token-prediction head, so none is modelled; what a later file
-    says otherwise is refused here, by key, not mis-served."""
-    def refuse(key, ok, modelled):
-        if not ok(hf.get(key)):
-            raise ValueError(f"{key}={hf.get(key)!r} is not supported "
-                             f"(only {modelled} is modelled)")
-    layers = int(hf["num_hidden_layers"])
-    types = hf.get("layer_types")
-    if not types or len(types) != layers or \
-            set(types) - {"sliding_attention", "full_attention"}:
-        raise ValueError(
-            f"layer_types={types!r}: one of sliding_attention | "
-            f"full_attention a layer ({layers}) is what is modelled "
-            f"(max_window_layers is not read: layer_types governs)")
+    No key of THIS family's file declares a QK-norm, a shared expert, a
+    selection bias, a dense lead or a multi-token-prediction head, so
+    none is read; what a later `mellum` file says otherwise is refused
+    here, by key, not mis-served. (A window-pool model CAN have a head's
+    QK-norm, an output gate, a sigmoid router with a selection bias, a
+    shared expert and a dense lead: `afmoe_fields` maps them.)"""
+    refuse = _refuser(hf)
+    types = _layer_types(hf, "max_window_layers")
+    layers = len(types)
     refuse("mlp_layer_types",
            lambda v: v is None or (len(v) == layers
                                    and set(v) == {"sparse"}),
@@ -315,13 +335,78 @@ def mellum_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
             f"modelled")
     return dict(
         sliding_window=int(hf["sliding_window"]),
-        layer_types=tuple(types), window_pool=True,
+        layer_types=types, window_pool=True,
         rope_full=rope_params(ropes["full_attention"], hf),
         rope_sliding=rope_params(ropes["sliding_attention"], hf),
         rope_theta=float(ropes["full_attention"]["rope_theta"]),
         num_experts=int(hf["num_experts"]),
         intermediate_size=int(hf["moe_intermediate_size"]),
         norm_topk_prob=True)
+
+
+def afmoe_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """The ModelConfig fields of an `afmoe` config.json (Trinity-Mini):
+    GQA in every layer with an RMSNorm over each head's q and k and a
+    sigmoid output gate; `layer_types` saying which layers attend inside
+    `sliding_window` (served from the window pool) and which over the
+    whole context; RoPE at `rope_theta` on the SLIDING layers and no
+    positional embedding on the full ones; four norms a block; the
+    embeddings times sqrt(hidden_size) under `mup_enabled`;
+    `num_dense_layers` leading layers with a dense MLP of
+    `intermediate_size`, then `num_experts` routed experts of
+    `moe_intermediate_size` behind a sigmoid (or softmax) router with a
+    selection bias, the k picked renormalised under `route_norm` and
+    scaled by `route_scale`, plus `num_shared_experts` shared ones.
+    What no key states (the gate, the head norm, no RoPE on the full
+    layers, the four norms, the selection bias) is the family's
+    published modelling code. Every key of the catalog row's config is
+    either mapped, or named here as not read, or refused."""
+    refuse = _refuser(hf)
+    types = _layer_types(hf, "global_attn_every_n_layers")
+    layers = len(types)
+    # not read: `global_attn_every_n_layers` (layer_types governs),
+    # `load_balance_coeff` (training), `use_grouped_mm` (an
+    # implementation's choice of kernel)
+    for key in ("n_group", "num_expert_groups", "topk_group",
+                "num_limited_groups"):
+        refuse(key, lambda v: v in (None, 1), "one expert group")
+    refuse("hidden_act", lambda v: v in (None, "silu"), "silu")
+    refuse("score_func", lambda v: v in ("sigmoid", "softmax"),
+           "sigmoid or softmax scoring")
+    refuse("tie_word_embeddings", lambda v: not v, "an untied head")
+    refuse("attention_bias", lambda v: not v, "no attention bias")
+    refuse("use_sliding_window", lambda v: v in (None, True),
+           "use_sliding_window: true")
+    for key in ("num_nextn_predict_layers", "mtp_num_layers",
+                "attn_logit_softcapping", "final_logit_softcapping",
+                "q_lora_rank", "kv_lora_rank"):
+        refuse(key, lambda v: not v, f"{key} absent")
+    if not hf.get("sliding_window"):
+        raise ValueError("an afmoe file states its sliding_window")
+    lead = int(hf.get("num_dense_layers") or 0)
+    if not 0 <= lead < layers:
+        raise ValueError(f"num_dense_layers={lead} of {layers} layers: "
+                         f"at least one expert layer behind the lead is "
+                         f"what is modelled")
+    from dynamo_tpu.engine.config import RopeParams
+    theta = float(hf.get("rope_theta", 10000.0))
+    width = int(hf["moe_intermediate_size"])
+    return dict(
+        sliding_window=int(hf["sliding_window"]),
+        layer_types=types, window_pool=True,
+        rope_sliding=RopeParams(theta=theta),
+        rope_full=RopeParams(theta=theta, rope_type="none"),
+        qk_norm="head", attn_out_gate=True, post_norms=True,
+        embed_scale=float(hf["hidden_size"]) ** 0.5
+        if hf.get("mup_enabled") else 0.0,
+        num_experts=int(hf["num_experts"]),
+        intermediate_size=width,
+        dense_intermediate_size=int(hf["intermediate_size"]),
+        first_dense_layers=lead,
+        shared_expert_size=int(hf.get("num_shared_experts") or 0) * width,
+        norm_topk_prob=bool(hf.get("route_norm", True)),
+        moe_scoring=hf["score_func"], moe_router_bias=True,
+        moe_routed_scale=float(hf.get("route_scale", 1.0)))
 
 
 def rope_params(entry: Dict[str, Any], hf: Dict[str, Any]):

@@ -92,6 +92,8 @@ def pp_param_shardings(cfg: ModelConfig, tp: int = 1) -> Params:
         })
     if cfg.qk_norm:
         layers.update({"q_norm": P("pp", "tp"), "k_norm": P("pp", "tp")})
+    if cfg.attn_out_gate:
+        layers["w_out_gate"] = P("pp", None, "tp")
     out: Params = {
         # vocab rows over "tp": the embedding is the largest otherwise-
         # replicated tensor in the 70B plan (2.1 GB/device at bf16);

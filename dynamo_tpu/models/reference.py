@@ -128,6 +128,40 @@ head, no biases.
               an explicit `layer_types` (`layer_types` governs);
               `intermediate_size`, unused where every layer is sparse.
 
+And from the row `Trinity-Mini` of the architecture catalog (`model_type`
+afmoe; `transformers` 4.57.6 here has no such class and there is no
+network, so what no key of the row's config states is written from the
+family's published modelling code as the issue that asked for it gives it,
+one line each below; benchmark/reference/trinity.py is the benchmark's copy
+of these lines). RMSNorm eps 1e-5, final norm, untied head, no biases.
+
+  embedding   h0 = E[ids] * sqrt(hidden_size) (`mup_enabled`;
+              `embed_scale`).
+  attention   (every layer) q -> [T, 32, 128], k, v -> [T, 4, 128];
+              g = x Wg [T, 4096]. RMSNorm over EACH head's 128 values of
+              q and of k, one weight vector for all heads (`qk_norm`
+              "head": code-sourced), BEFORE RoPE. sliding_attention
+              layers: rotate-half RoPE over the full head at `rope_theta`
+              and keys j with p - `sliding_window` < j <= p.
+              full_attention layers: NO positional embedding (`rope=None`:
+              code-sourced) and all keys j <= p. 8 query heads a KV head,
+              causal softmax in float32 at 128 ** -0.5; the output times
+              sigmoid(g), element-wise, before Wo (`w_out_gate`:
+              code-sourced).
+  block       four norms (code-sourced): h = h + RMSNorm(attention(
+              RMSNorm(h; attn_norm)); post_attn_norm); h = h +
+              RMSNorm(mlp(RMSNorm(h; mlp_norm)); post_mlp_norm).
+  layer 0..   (`num_dense_layers` layers, a stack of their own IN FRONT
+              of the kind stacks: `lead0`, ...) a dense SwiGLU of
+              `intermediate_size` (6144).
+  the others  `expert_mlp` with Moonlight's router: s = sigmoid(x Wr) in
+              float32 over all 128; idx = top8(s + b), b the selection
+              bias, which picks and does not weigh (code-sourced); w =
+              s[idx] / (sum + 1e-20) (`route_norm`) x `route_scale`
+              2.826; plus ONE shared SwiGLU of `num_shared_experts` x
+              `moe_intermediate_size` on every token. `n_group` 1: no
+              group limit.
+
 Departures from the published model, each deliberate:
   * weights are taken in this repo's layout: projections stored
     [in, out] (the checkpoint's are [out, in]; models/loader.py
@@ -190,7 +224,10 @@ def yarn_inv_freq(dim, theta, factor, original_max_position, beta_fast,
 def rope(x, positions, theta, yarn=None):
     """Rotate-half RoPE over the full head. x: [T, H, hd]. `yarn`: a dict
     of `yarn_inv_freq`'s arguments after theta, and `attention_factor`
-    (0: 0.1 ln(factor) + 1), which multiplies cos and sin."""
+    (0: 0.1 ln(factor) + 1), which multiplies cos and sin. `theta` None:
+    a layer kind without a positional embedding, x as it is."""
+    if theta is None:
+        return x
     hd = x.shape[-1]
     inv_freq = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=F32) / hd)
     scale = 1.0
@@ -209,18 +246,25 @@ def rope(x, positions, theta, yarn=None):
 
 def attention(x, lp, *, num_heads, num_kv_heads, head_dim, rope_theta,
               rms_norm_eps, qk_norm, window=0, yarn=None):
-    """`window` > 0: a query at p sees keys j with p - window < j <= p."""
+    """`window` > 0: a query at p sees keys j with p - window < j <= p.
+    `qk_norm` True: over the whole projection; "head": over each head,
+    one weight vector for all. `rope_theta` None: no rotation. A
+    `w_out_gate` leaf: the output times sigmoid(x Wg) before Wo."""
     t = x.shape[0]
     q, k, v = x @ lp["wq"], x @ lp["wk"], x @ lp["wv"]
     if "wq_b" in lp:
         q, k, v = q + lp["wq_b"], k + lp["wk_b"], v + lp["wv_b"]
-    if qk_norm:                      # over the whole projection, pre-split
+    if qk_norm is True:              # over the whole projection, pre-split
+        q = rms_norm(q, lp["q_norm"], rms_norm_eps)
+        k = rms_norm(k, lp["k_norm"], rms_norm_eps)
+    q = q.reshape(t, num_heads, head_dim)
+    k = k.reshape(t, num_kv_heads, head_dim)
+    if qk_norm == "head":            # over each head's values, post-split
         q = rms_norm(q, lp["q_norm"], rms_norm_eps)
         k = rms_norm(k, lp["k_norm"], rms_norm_eps)
     positions = jnp.arange(t)
-    q = rope(q.reshape(t, num_heads, head_dim), positions, rope_theta, yarn)
-    k = rope(k.reshape(t, num_kv_heads, head_dim), positions, rope_theta,
-             yarn)
+    q = rope(q, positions, rope_theta, yarn)
+    k = rope(k, positions, rope_theta, yarn)
     v = v.reshape(t, num_kv_heads, head_dim)
     group = num_heads // num_kv_heads          # grouped-query: share k, v
     k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
@@ -231,7 +275,10 @@ def attention(x, lp, *, num_heads, num_kv_heads, head_dim, rope_theta,
     scores = jnp.where(causal[None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("hqk,khd->qhd", probs, v)
-    return out.reshape(t, num_heads * head_dim) @ lp["wo"]
+    out = out.reshape(t, num_heads * head_dim)
+    if "w_out_gate" in lp:           # element-wise output gate
+        out = out * jax.nn.sigmoid(x @ lp["w_out_gate"])
+    return out @ lp["wo"]
 
 
 def deinterleave(x):
@@ -392,32 +439,39 @@ def layer(x, lp, *, num_heads, num_kv_heads, head_dim, rope_theta,
     float32. `mla`: attention_mla's sizes (a dict) for latent attention;
     `kda`: attention_kda's, for a layer that has its leaves. A layer
     without a `router` leaf has a dense MLP. `window`, `yarn`: THIS
-    layer's sliding width and RoPE scaling (`layer_kind_kwargs`)."""
+    layer's sliding width and RoPE scaling (`layer_kind_kwargs`). A
+    layer with `post_attn_norm` / `post_mlp_norm` leaves norms each
+    half's output before the residual (four norms a block)."""
+    def post(out, name):
+        return rms_norm(out, lp[name], rms_norm_eps) if name in lp else out
     xn = rms_norm(x, lp["attn_norm"], rms_norm_eps)
     if "kda_wqkv" in lp:
-        x = x + attention_kda(xn, lp, num_heads=num_heads,
-                              rms_norm_eps=rms_norm_eps, **kda)
+        out = attention_kda(xn, lp, num_heads=num_heads,
+                            rms_norm_eps=rms_norm_eps, **kda)
     elif mla:
-        x = x + attention_mla(xn, lp, num_heads=num_heads,
-                              head_dim=head_dim, rope_theta=rope_theta,
-                              rms_norm_eps=rms_norm_eps, **mla)
+        out = attention_mla(xn, lp, num_heads=num_heads,
+                            head_dim=head_dim, rope_theta=rope_theta,
+                            rms_norm_eps=rms_norm_eps, **mla)
     else:
-        x = x + attention(
+        out = attention(
             xn, lp, num_heads=num_heads, num_kv_heads=num_kv_heads,
             head_dim=head_dim, rope_theta=rope_theta,
             rms_norm_eps=rms_norm_eps, qk_norm=qk_norm, window=window,
             yarn=yarn)
+    x = x + post(out, "post_attn_norm")
     xn = rms_norm(x, lp["mlp_norm"], rms_norm_eps)
     if num_experts and "router" in lp:
-        return x + expert_mlp(xn, lp,
-                              num_experts_per_tok=num_experts_per_tok,
-                              norm_topk_prob=norm_topk_prob,
-                              moe_scoring=moe_scoring,
-                              moe_routed_scale=moe_routed_scale,
-                              expert_first=expert_first,
-                              **(dict(n_group=n_group, topk_group=topk_group)
-                                 if n_group > 1 else {}))
-    return x + dense_mlp(xn, lp)
+        out = expert_mlp(xn, lp,
+                         num_experts_per_tok=num_experts_per_tok,
+                         norm_topk_prob=norm_topk_prob,
+                         moe_scoring=moe_scoring,
+                         moe_routed_scale=moe_routed_scale,
+                         expert_first=expert_first,
+                         **(dict(n_group=n_group, topk_group=topk_group)
+                            if n_group > 1 else {}))
+    else:
+        out = dense_mlp(xn, lp)
+    return x + post(out, "post_mlp_norm")
 
 
 def arch_kwargs(cfg) -> dict:
@@ -432,6 +486,8 @@ def arch_kwargs(cfg) -> dict:
                        sliding_window=cfg.sliding_window,
                        rope_full=dataclasses.asdict(cfg.rope_full),
                        rope_sliding=dataclasses.asdict(cfg.rope_sliding))
+    if cfg.embed_scale:     # beside them: `forward` takes it out again
+        by_kind["embed_scale"] = cfg.embed_scale
     return dict(num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
                 head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
                 rms_norm_eps=cfg.rms_norm_eps, qk_norm=cfg.qk_norm,
@@ -452,13 +508,15 @@ def layer_kind_kwargs(index, layer_types=(), sliding_window=0,
     """`layer`'s arguments that go by layer KIND, for layer `index` of a
     model whose `layer_types` says which layers slide: its window (0 on a
     full layer) and its RoPE (`rope_theta`, and `yarn` where the kind's
-    `rope_type` is yarn). {} for a model of one kind."""
+    `rope_type` is yarn; `rope_theta` None where it is none: the kind
+    has no positional embedding). {} for a model of one kind."""
     if not layer_types:
         return {}
     sliding = layer_types[index] == "sliding_attention"
     p = rope_sliding if sliding else rope_full
     return dict(window=sliding_window if sliding else 0,
-                rope_theta=p["theta"],
+                rope_theta=None if p["rope_type"] == "none"
+                else p["theta"],
                 yarn=p if p["rope_type"] == "yarn" else None)
 
 
@@ -478,14 +536,20 @@ def layers_in_order(params, layer_types=()) -> list:
     """Every layer's weights, in the model's order. Where `layer_types`
     says which layers slide, the stacks are a stack a KIND, in the order
     the kinds first appear (models/llama.layer_runs), and the model's
-    order interleaves them."""
-    stacks = layer_stacks(params)
-    if not layer_types:
+    order interleaves them; a dense lead in front of them has stacks of
+    its own (`lead0`, ...), whose layers come first."""
+    def unstacked(stacks):
         return [{name: leaf[i] for name, leaf in stack.items()}
                 for stack in stacks for i in range(len(stack["attn_norm"]))]
+    stacks = layer_stacks(params)
+    if not layer_types:
+        return unstacked(stacks)
+    out = unstacked(params[k] for k in sorted(
+        (k for k in params if k.startswith("lead")),
+        key=lambda k: int(k[4:])))
+    layer_types = layer_types[len(out):]
     kinds = list(dict.fromkeys(layer_types))
     taken = [0] * len(kinds)
-    out = []
     for kind in layer_types:
         s = kinds.index(kind)
         out.append({name: leaf[taken[s]]
@@ -501,10 +565,13 @@ def forward(params, tokens, **arch):
     by_kind = {k: arch.pop(k) for k in (
         "layer_types", "sliding_window", "rope_full", "rope_sliding")
         if k in arch}
+    embed_scale = arch.pop("embed_scale", 0.0)
     with jax.default_matmul_precision("highest"):
         params = jax.tree.map(lambda a: jnp.asarray(a, F32), params)
         # ids the engine served  # dynalint: disable-next-line=R1
         x = params["embed"][jnp.asarray(tokens)]
+        if embed_scale:
+            x = x * embed_scale
         for index, lp in enumerate(layers_in_order(
                 params, by_kind.get("layer_types", ()))):
             x = layer(x, lp, **{**arch,

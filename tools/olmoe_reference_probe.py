@@ -12,6 +12,11 @@ print its readings, with the served model or the reference mutated.
     ... --mutation ref-float8    nothing served is changed; the REFERENCE's
                                  weights are rounded to float8 (e4m3), the
                                  nearest precision below bfloat16
+    ... --mutation rotate-full   a model whose full-attention layers have no
+                                 positional embedding (`rope_type` "none")
+                                 is served with them rotated as the sliding
+                                 layers are
+    ... --mutation no-out-gate   softmax attention's output gate is skipped
 
 `--prompt-seeds a,b,c` reads further draws of the prompts,
 `--then-float8` the float8 reference and `--then-bf16-state` the reference
@@ -24,7 +29,8 @@ One JSON line: the readings, the check's limits for the served dtype and
 `passes`. `none` must pass; PERF.md section 6, PR 27 says which mutations
 the check sees at the published widths in bfloat16 and which only the
 float32 tier-1 test does. `--rehearsal` runs the tiny
-configuration on the CPU (control flow only).
+configuration on the CPU (control flow only; with `--float32` the check's
+float32 limits apply, and the served-side mutations must fail them).
 """
 from __future__ import annotations
 
@@ -63,6 +69,13 @@ def mutate(kind: str) -> None:
             flat = jnp.arange(weights.size).reshape(weights.shape)
             return jnp.where(flat % every == every // 3, 0.0, weights), idx
         moe.route_topk = dropping
+    elif kind == "rotate-full":
+        from dynamo_tpu.models import llama
+        table = llama.rope_table
+        llama.rope_table = lambda cfg, kind="": table(cfg, "swa")
+    elif kind == "no-out-gate":
+        from dynamo_tpu.models import llama
+        llama._out_gate = lambda attn, x, lp, cfg: attn
 
 
 async def probe(args) -> None:
@@ -86,6 +99,14 @@ async def probe(args) -> None:
     model_dir = run.build_model_dir(config_dir,
                                     os.path.join(out_dir, "model"))
     mutate(args.mutation)
+    if args.float32:
+        # the launcher has no dtype flag (it serves what a deployment
+        # serves); here the served ModelConfig alone is made float32
+        import dataclasses
+        from dynamo_tpu.models import loader
+        base = loader.config_from_hf
+        loader.config_from_hf = lambda hf, name="": dataclasses.replace(
+            base(hf, name), dtype="float32")
     served = run.Served(model_dir, list(meta["serve"]), run.free_port())
     await served.start()
     ctx = run.CheckCtx(served, os.path.basename(model_dir),
@@ -142,9 +163,13 @@ def main() -> None:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--mutation", default="none",
                    choices=("none", "renorm", "drop1pct", "drop5pct",
-                            "ref-float8"))
+                            "ref-float8", "rotate-full", "no-out-gate"))
     p.add_argument("--config", default="olmoe-1b-7b")
     p.add_argument("--rehearsal", action="store_true")
+    p.add_argument("--float32", action="store_true",
+                   help="serve in float32 (with --rehearsal: rounding "
+                        "out of the way, the check's float32 limits "
+                        "apply and a model served wrong fails them)")
     p.add_argument("--prompt-seed", type=int, default=None,
                    help="draw the check's three prompts from another seed")
     p.add_argument("--prompt-seeds", default="",
