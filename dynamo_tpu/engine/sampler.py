@@ -4,8 +4,10 @@ Matches the sampling-option surface the reference forwards to its engines
 (reference: lib/llm/src/protocols/common.rs:248 SamplingOptions — temperature,
 top_k, top_p, seed; greedy when nvext.greed_sampling or temperature==0).
 
-All-batch vectorized with static vocab: one descending value sort gives both
-top-k's and top-p's cutoff, compared in token order; XLA fuses the rest.
+All-batch vectorized with static vocab: top-k's and top-p's one cutoff is
+found by a threshold search over the row's values (`keep_mask`: a fixed
+number of masked reductions, no sort of the vocabulary) and compared in
+token order; XLA fuses the rest.
 """
 # dynalint: hot-path — every op here runs inside jitted decode/prefill programs;
 # host syncs (.item(), device_get, float()) are dynalint R6 findings
@@ -202,6 +204,18 @@ def make_keys(seeds: jax.Array, counters: jax.Array) -> jax.Array:
     )(seeds, counters)
 
 
+def _ordered(bits: jax.Array) -> jax.Array:
+    """float32 bits (as int32) <-> the int32 that orders as the floats do:
+    non-negative floats keep their bits, negative ones have the bits under
+    the sign flipped. Its own inverse."""
+    return bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+
+# the key below every float's: where a row's search starts (never looked at)
+_BELOW_NEG_INF = int(_ordered(np.float32(-np.inf).view(np.int32))) - 1
+CUT_SEARCH_STEPS = 32   # halvings that take any int32 interval to one value
+
+
 def keep_mask(
     scaled: jax.Array,        # [B, V] f32 logits / temperature
     top_k: jax.Array,         # [B] int32; 0 => disabled
@@ -211,38 +225,70 @@ def keep_mask(
 
     Both masks are prefixes of ONE order: descending by value and, among
     equal values, by descending token id (a stable ascending argsort,
-    reversed). top-k keeps its first k; top-p keeps the smallest prefix of
-    the sorted probabilities whose cumulative sum reaches top_p, always
-    with the argmax (the prefix is the meaning: should a rounded
-    `cumprobs - sorted_probs` ever dip after it has crossed top_p, what
-    follows the first crossing stays out). So the kept set is the first
-    n = min(k, n_p) tokens of that order, and it is rebuilt here without
-    the order's ranks: everything above the n-th sorted value, and of the
-    tokens tied with it the highest ids, as many as are still missing.
-    One value sort; no argsort, and no [B, V] gather or scatter."""
+    reversed). top-k keeps its first k; top-p keeps the smallest prefix
+    whose float32 probability mass reaches top_p, always with the argmax,
+    nothing at top_p 0, and everything at top_p >= 1.0 (disabled: the mass
+    is not looked at). So the kept set is the first n = min(k, n_p) tokens
+    of that order, and it is found here WITHOUT the order: no sort.
+
+    For a value x let C(x) be the count of the row's entries > x and M(x)
+    the sum of exp(scaled - rowmax) over them, over the row's whole sum
+    (as jax.nn.softmax takes them). The group of entries equal to x is
+    entered iff C(x) < k and M(x) < top_p. Both fall as x rises (a sum of
+    non-negative float32 in one fixed tree never falls when an addend
+    rises), so the cut is the smallest value whose group is entered:
+    bisected over the int32 that orders as float32 does, CUT_SEARCH_STEPS
+    masked reductions over [B, V], all rows at once. The smallest such x
+    IS a row value (C and M only change there); comparisons are made in
+    float, so -0.0 and +0.0 are one value. Everything above the cut is
+    kept; of the entries tied with it the highest ids, the i-th of them
+    (from 0) while C + i < k and M + i * p(cut) < top_p.
+    No [B, V] gather or scatter either."""
     v = scaled.shape[-1]
-    sorted_logits = jnp.sort(scaled, axis=-1)[:, ::-1]            # [B, V] desc
+    k = jnp.where(top_k > 0, jnp.minimum(top_k, v), v)[:, None]   # [B, 1]
+    top_p = top_p[:, None]
+    open_p = top_p >= 1.0
 
-    # top-k: the first k of the order (k==0 disables)
-    k = jnp.where(top_k > 0, top_k, v)
+    row_max = jnp.max(scaled, axis=-1, keepdims=True)
+    unnorm = jnp.exp(scaled - row_max)
+    total = jnp.sum(unnorm, axis=-1, keepdims=True)
 
-    # top-p: the leading run of sorted_keep
-    sorted_probs = jax.nn.softmax(sorted_logits, axis=-1)
-    cumprobs = jnp.cumsum(sorted_probs, axis=-1)
-    sorted_keep = (cumprobs - sorted_probs) < top_p[:, None]
-    first_out = jnp.where(sorted_keep, v, jnp.arange(v, dtype=jnp.int32))
-    n = jnp.minimum(k, jnp.min(first_out, axis=-1))               # [B]
+    def entered(x):
+        """(C(x) < k and M(x) < top_p, C(x), M(x)), [B, 1] each."""
+        over = scaled > x
+        count = jnp.sum(over, axis=-1, keepdims=True, dtype=jnp.int32)
+        mass = jnp.sum(jnp.where(over, unnorm, 0.0), axis=-1,
+                       keepdims=True) / total
+        return (count < k) & (open_p | (mass < top_p)), count, mass
 
-    # the n-th sorted value cuts; ties at the cut go to the highest ids.
-    # n == 0 (top_p 0) keeps nothing: need is 0, a tie's count at least 1
-    cut = jnp.take_along_axis(
-        sorted_logits, jnp.maximum(n - 1, 0)[:, None], axis=-1)   # [B, 1]
+    def value(key):
+        return jax.lax.bitcast_convert_type(_ordered(key), jnp.float32)
+
+    def halve(_, state):
+        # lo: not entered, hi: entered (the row's maximum always is, unless
+        # top_p is 0, and then nothing moves hi off it), with C and M at hi
+        lo, hi, count, mass = state
+        mid = (lo | hi) - ((lo ^ hi) >> 1)      # ceil of the mean, no overflow
+        ok, c, m = entered(value(mid))
+        return (jnp.where(ok, lo, mid), jnp.where(ok, mid, hi),
+                jnp.where(ok, c, count), jnp.where(ok, m, mass))
+
+    hi = _ordered(jax.lax.bitcast_convert_type(row_max, jnp.int32))
+    _, hi, count, mass = jax.lax.fori_loop(
+        0, CUT_SEARCH_STEPS, halve,
+        (jnp.full_like(hi, _BELOW_NEG_INF), hi,
+         jnp.zeros_like(hi), jnp.zeros_like(row_max)))
+
+    # ties at the cut go to the highest ids, while both conditions hold
+    cut = value(hi)                                               # [B, 1]
     above = scaled > cut
     tie = scaled == cut
-    need = n - jnp.sum(above, axis=-1, dtype=jnp.int32)
     ties_from_here_up = jax.lax.cumsum(
         tie.astype(jnp.int32), axis=1, reverse=True)
-    return above | (tie & (ties_from_here_up <= need[:, None]))
+    p_cut = jnp.exp(cut - row_max) / total
+    mass_before = mass + (ties_from_here_up - 1).astype(jnp.float32) * p_cut
+    return above | (tie & (count + ties_from_here_up <= k)
+                    & (open_p | (mass_before < top_p)))
 
 
 def sample(
@@ -281,20 +327,19 @@ def sample_fused(
       SAME permutation (`ranks[order[j]] = j`) gives each token its place
       in it, so `ranks < k` is the first k of the same order, ties
       included.
-    - masked set: with top_p == 1.0, `keep_mask`'s top-p prefix is the whole
-      row (the strict `cumprobs - sorted_probs < 1.0` can only exclude a
-      tail element once the f32 cumsum has rounded up to 1.0: what is left
-      there is probability the sum can no longer see), so k alone decides.
+    - masked set: at top_p >= 1.0 `keep_mask` does not look at the mass,
+      so k alone decides there too.
     - draw: same make_keys stream, same categorical over the same masked
       row => the same token.
 
     What it bought inside the jitted window, when the full tail still
     sorted three times and gathered its mask back through the ranks: one
-    argsort and one scatter in their place. Since PR 28 `sample` builds
-    its mask in token space from ONE value sort, with no argsort and no
-    [B, V] gather or scatter, so this tail's argsort + scatter are
-    probably the dearer pair now (not measured: no benchmark cell runs an
-    all-top_p-1 batch; ROADMAP queue D)."""
+    argsort and one scatter in their place. Since PR 41 `sample` finds its
+    cut without any sort, and this tail's argsort + scatter are the dearer
+    by far: 2.1-15.3 ms a call against 0.73-1.32 for `sample` on the same
+    top_p-free batch (tools/sampler_tail_bench.py on a v5e, PERF.md
+    section 6, PR 41; no benchmark cell runs such a batch; deleting this
+    tail is ROADMAP D13)."""
     b, v = logits.shape
     greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
@@ -343,8 +388,9 @@ def sample_logits(logits, eos_ids, temperature, top_k, top_p, seeds,
         eos_mask = jnp.zeros((logits.shape[-1],), bool).at[eos].set(True)
         logits = jnp.where(ban & eos_mask[None, :], -1e30, logits)
     if greedy:
-        # all-greedy plan: argmax only — the full sampler's vocab sort
-        # costs ~1.5 ms/step on a 128k vocab (measured, v5e)
+        # all-greedy plan: argmax only — the full sampler's cut search,
+        # noise and argmax over the vocabulary cost 0.7-1.3 ms a call
+        # (tools/sampler_tail_bench.py, v5e, host-timed)
         toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     elif fused:
         keys = make_keys(seeds, counters)

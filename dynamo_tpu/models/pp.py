@@ -413,7 +413,7 @@ def pp_decode_window(
     previously greedy-only, with sampled plans paying full host-dispatch
     latency x pipeline bubble per token). `greedy` picks the
     argmax-only compiled variant so all-greedy plans skip the sampler's
-    vocab sort; `fused` picks the top_p-free sample_fused tail for
+    cut search; `fused` picks the top_p-free sample_fused tail for
     sampled plans whose every row has top_p disabled — the same static
     window-key bit as the single-mesh engine, so pp plans fuse the
     common sampling tail identically. Logprob/penalty plans stay

@@ -424,16 +424,19 @@ def test_the_window_tables_width_follows_the_window():
 # (latent attention, a dense lead, sigmoid router, shared experts) and
 # Mellum (window pool, period loop, YaRN by kind). A PR that MEANS to
 # change one of these programs replaces its digest (and says so); one
-# that does not has changed a program it did not mean to.
+# that does not has changed a program it did not mean to. PR 41 replaced
+# all eight: every program ends in the sampler tail, whose `keep_mask`
+# lost its sort (with tests/test_sampler_tail.one_sort_keep_mask patched
+# over it, all eight read PR 40's digests again: nothing else moved).
 PARENT_PROGRAMS = {
-    ("rehearsal-tiny", "step"): "2fd17faa6e9c6d79",
-    ("rehearsal-tiny", "window"): "ec12ce2e3da02fa7",
-    ("rehearsal-tiny-olmoe", "step"): "9a635756498ed7ac",
-    ("rehearsal-tiny-olmoe", "window"): "01ac365b52b2b469",
-    ("rehearsal-tiny-moonlight", "step"): "11b2c7e1aa499dcb",
-    ("rehearsal-tiny-moonlight", "window"): "9171f8850ae87988",
-    ("rehearsal-tiny-mellum", "step"): "86f6bd055e20a9a5",
-    ("rehearsal-tiny-mellum", "window"): "5f004154ad97634e",
+    ("rehearsal-tiny", "step"): "8b3a1bcc2cd60304",
+    ("rehearsal-tiny", "window"): "f9966fa4a465991d",
+    ("rehearsal-tiny-olmoe", "step"): "fc8c262d1fc40d22",
+    ("rehearsal-tiny-olmoe", "window"): "c484f91bd464d383",
+    ("rehearsal-tiny-moonlight", "step"): "285056b88b57c562",
+    ("rehearsal-tiny-moonlight", "window"): "1f212b404bab2dbb",
+    ("rehearsal-tiny-mellum", "step"): "6921cab248da7e90",
+    ("rehearsal-tiny-mellum", "window"): "826993a03ea7d66a",
 }
 
 

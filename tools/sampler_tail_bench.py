@@ -1,5 +1,7 @@
-"""Time the sampler tail on the attached device: `sampler.sample` against
-the tail it replaced (the oracle kept in tests/test_sampler_tail.py).
+"""Time the sampler tail on the attached device: `sampler.sample` (the cut
+found by a threshold search, PR 41) against the one-sort tail it replaced
+(kept in tests/test_sampler_tail.py), and `sampler.sample_fused` beside
+`sample` on a batch without top_p.
 
     chiprun -- python3 tools/sampler_tail_bench.py            # the chip
     python3 tools/sampler_tail_bench.py --aot                 # compile only,
@@ -7,13 +9,18 @@ the tail it replaced (the oracle kept in tests/test_sampler_tail.py).
                                                               # described here
 
 At each [rows, vocabulary] the cells run ([32, 32000], [16, 32000],
-[8, 32000]: Mistral and Mixtral; [32, 50304]: OLMoE), with every row at
-the traffic's temperature 0.7 and top_p 0.95 over bfloat16-rounded logits:
+[8, 32000]: Mistral and Mixtral; [32, 50304]: OLMoE; [8, 163840]:
+Moonlight; [8, 200192]: Trinity; [8, 98304]: Mellum; [64, 39296]: Ling),
+every row at the traffic's temperature 0.7 over bfloat16-rounded logits:
 one JSON line a reading, milliseconds a call (median of 20 after 3 warm
-calls), and whether mask and tokens agreed. PERF.md section 6, PR 28
-quotes its output. A time comes from the chip only: `--aot` proves that
-the chip's compiler takes each form, counts its sorts and gathers, and
-prints no time.
+calls). Forms `one_sort` and `sample` run at the traffic's top_p 0.95,
+`fused_p1` and `sample_p1` at top_p 1.0 and top_k 50 (ROADMAP D13's
+number); the line after them counts where the new mask and tokens differ
+from the three-sort oracle's and the one-sort tail's (the kept prefix
+may differ in length inside the float64 band the test states). PERF.md
+section 6, PRs 28 and 41 quote its output. A time comes from the chip
+only: `--aot` proves that the chip's compiler takes each form, counts its
+sorts and gathers, and prints no time.
 """
 from __future__ import annotations
 
@@ -33,18 +40,26 @@ import jax.numpy as jnp   # noqa: E402
 import numpy as np   # noqa: E402
 
 from dynamo_tpu.engine import sampler   # noqa: E402
-from test_sampler_tail import oracle, tail   # noqa: E402
+from test_sampler_tail import (   # noqa: E402
+    one_sort_keep_mask, one_sort_sample, oracle, tail,
+)
 
-SHAPES = ((32, 32000), (16, 32000), (8, 32000), (32, 50304))
+SHAPES = ((32, 32000), (16, 32000), (8, 32000), (32, 50304),
+          (8, 163840), (8, 200192), (8, 98304), (64, 39296))
 
 
-def inputs(b, v):
+def inputs(b, v, top_k=0, top_p=0.95):
     x = jax.random.normal(jax.random.PRNGKey(b + v), (b, v), jnp.float32) * 3
     x = x.astype(jnp.bfloat16).astype(jnp.float32)
     rows = jnp.arange(b, dtype=jnp.int32)
-    return (x, jnp.full((b,), 0.7, jnp.float32), jnp.zeros((b,), jnp.int32),
-            jnp.full((b,), 0.95, jnp.float32),
+    return (x, jnp.full((b,), 0.7, jnp.float32),
+            jnp.full((b,), top_k, jnp.int32),
+            jnp.full((b,), top_p, jnp.float32),
             sampler.make_keys(rows + 17, rows * 5))
+
+
+def fused(logits, temperature, top_k, top_p, keys):
+    return sampler.sample_fused(logits, temperature, top_k, keys)
 
 
 def main() -> None:
@@ -64,11 +79,15 @@ def main() -> None:
     print(json.dumps({"device": jax.devices()[0].device_kind,
                       "aot": args.aot}), flush=True)
     # timed: tokens alone, as a step program takes them; compared: mask too
-    forms = (("oracle", jax.jit(lambda *a: oracle(*a)[1])),
-             ("sample", jax.jit(sampler.sample)))
+    p1 = {"top_k": 50, "top_p": 1.0}
+    forms = (("one_sort", jax.jit(one_sort_sample), {}),
+             ("sample", jax.jit(sampler.sample), {}),
+             ("fused_p1", jax.jit(fused), p1),
+             ("sample_p1", jax.jit(sampler.sample), p1))
+    fns = {form: fn for form, fn, _ in forms}
     for b, v in SHAPES:
-        xs = inputs(b, v)
-        for form, fn in forms:
+        for form, fn, how in forms:
+            xs = inputs(b, v, **how)
             line = {"shape": [b, v], "form": form}
             if args.aot:
                 abstract = jax.tree.map(
@@ -89,14 +108,25 @@ def main() -> None:
                 line["ms"] = round(1e3 * statistics.median(times), 4)
             print(json.dumps(line), flush=True)
         if not args.aot:
-            want_keep, want_tok, _ = jax.jit(oracle)(*xs)
-            got_keep, got_tok = jax.jit(tail)(*xs)
+            xs = inputs(b, v)
+            want_keep, want_tok = jax.jit(oracle)(*xs)
+            got_keep, got_tok = map(np.asarray, jax.jit(tail)(*xs))
+            scaled = xs[0] / xs[1][:, None]
+            one_keep = jax.jit(one_sort_keep_mask)(scaled, xs[2], xs[3])
+            xs1 = inputs(b, v, **p1)
             print(json.dumps({
                 "shape": [b, v],
+                "kept_a_row": [int(got_keep.sum(-1).min()),
+                               int(got_keep.sum(-1).max())],
                 "mask_mismatches": int(
-                    (np.asarray(want_keep) != np.asarray(got_keep)).sum()),
+                    (np.asarray(want_keep) != got_keep).sum()),
+                "mask_mismatches_one_sort": int(
+                    (np.asarray(one_keep) != got_keep).sum()),
                 "token_mismatches": int(
-                    (np.asarray(want_tok) != np.asarray(got_tok)).sum()),
+                    (np.asarray(want_tok) != got_tok).sum()),
+                "token_mismatches_fused_p1": int(
+                    (np.asarray(fns["fused_p1"](*xs1))
+                     != np.asarray(fns["sample_p1"](*xs1))).sum()),
             }), flush=True)
 
 
