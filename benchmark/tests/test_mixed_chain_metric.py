@@ -1,0 +1,50 @@
+"""PR 43's per-layer metric over the mixed chain's counters, on a fixture
+of two scrapes. By hand, on the CPU:
+`python -m pytest benchmark/tests/test_mixed_chain_metric.py -q`."""
+import json
+import os
+
+import pytest
+
+HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(HERE)
+from harness import readers  # noqa: E402
+
+METRIC = "pipeline.mixed_chained_share"
+with open(os.path.join(HERE, "tests", "fixtures",
+                       "mixed_chain_counters.json")) as f:
+    FIXTURE = json.load(f)
+
+
+@pytest.mark.parametrize("program", ["with", "lone", "no_mixed", "without"])
+def test_the_share_on_two_scrapes(program):
+    """With the counters the share of the window's mixed steps that were
+    dispatched behind one in flight, 0 where none was or the window held
+    no mixed step; on a program without them (the parent) nothing, and
+    the line leaves the metric out."""
+    spec = readers.load_metric(METRIC, HERE)
+    case = FIXTURE[program]
+    got = readers.evaluate(spec["expr"], {"engine": tuple(case["engine"])})
+    want = case[METRIC]
+    assert got is None if want is None else got == pytest.approx(want)
+
+
+def test_the_entry_is_appended_and_lists_every_cell():
+    """(That an entry agrees with its file is test_harness's
+    test_benchmark_json_names_units_and_files, for every metric.)"""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    assert bench["per_layer"][-1] == {
+        "name": METRIC, "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "engine host loop",
+        "moves": "output_tok_s",
+        "workloads": [w["name"] for w in bench["workloads"]]}
+
+
+def test_the_counters_are_engine_metrics_fields():
+    """The `engine` reader scrapes `EngineMetrics`: the two counters the
+    chain brings are fields of it, beside the step count they divide by."""
+    from dynamo_tpu.engine.scheduler import EngineMetrics
+    fields = EngineMetrics.__dataclass_fields__
+    assert {"mixed_steps", "mixed_steps_chained",
+            "mixed_steps_replanned"} <= set(fields)

@@ -33,6 +33,7 @@ from dynamo_tpu.engine.sampler import (
     sample_logits as _sample_logits, seen_token_mask,
 )
 from dynamo_tpu.engine.scheduler import (
+    PENDING_TOKEN,
     DecodePlan, EngineRequest, MixedPlan, PrefillPlan, SamplingParams,
     Scheduler, StreamPlan, next_bucket, pow2_buckets,
 )
@@ -234,6 +235,11 @@ class NativeEngine:
         # commit deferred to the next step() so host bookkeeping for window
         # N runs concurrently with device execution of window N+1
         self._pipeline = None
+        # the mixed chain (_chain_step): the mixed step in flight, which
+        # the next step() commits behind the dispatch of its successor;
+        # `_ahead_failed`: the last plan made ahead came to nothing
+        self._flight = None
+        self._ahead_failed = False
         # host staging caches: static sampling-param blocks and incremental
         # repetition-penalty history rebuild only when the slot set changes.
         # Mixed steps get their OWN cache pair: a mixed step's row set
@@ -321,6 +327,11 @@ class NativeEngine:
         # with mixed on, counts the alternating baseline's prefill tax)
         self.mixed_steps = 0
         self.decode_stall_steps = 0
+        self.mixed_steps_chained = 0    # dispatched behind a mixed step
+        #                                 still in flight
+        self.mixed_steps_replanned = 0  # planned again after the commit
+        #                                 before them (the plan made ahead
+        #                                 came to nothing)
         # cumulative MoE capacity-drop counters (dispatch impl only)
         self.moe_dropped_tokens = 0.0
         self.moe_routed_tokens = 0.0
@@ -375,6 +386,15 @@ class NativeEngine:
             params = jax.device_put(params, shardings)
         self.params = params
         self._replicated = NamedSharding(self.mesh, P())
+        # what an `_engine_step` with no step in flight before it is
+        # handed as that step's tokens (`prev_tokens`; none of its rows
+        # reads it): one fixed length, the row ladder's cap, and put with
+        # the sharding a step's own output has, so that a chained step
+        # and a lone one are one program
+        self._no_prev = jax.device_put(
+            np.full((engine_cfg.max_slots
+                     + max(1, engine_cfg.max_prefill_batch),), -1, np.int32),
+            self._replicated)
 
         init_cache = jax.jit(
             functools.partial(
@@ -442,8 +462,8 @@ class NativeEngine:
                     STEP_OPERANDS + state_op + wstep_op
                     + ("rep_penalty",) * rp
                     + ("mm_mask",) * mm,
-                    ("hist",) * rp + ("mm_embeds",) * mm)),
-                static_argnums=(2,), donate_argnums=(1,))
+                    ("hist",) * rp + ("mm_embeds",) * mm, fed=True)),
+                static_argnums=(3,), donate_argnums=(1,))
             for rp in (False, True) for lp in (False, True)
             for mm in (False, True)
         }
@@ -737,7 +757,18 @@ class NativeEngine:
             self._draft.forget(request_id)
         self._first_token_marks.pop(request_id, None)
         self.ledger.forget(request_id)
-        return self.scheduler.abort(request_id)
+        if self.scheduler.abort(request_id):
+            return True
+        # a prefill row of the mixed step in flight is in no queue: it
+        # ends here, and the step is committed for the other rows
+        flight = self._flight
+        for i, seq in enumerate(flight["plan"].seqs if flight else ()):
+            if seq is not None and seq.request_id == request_id \
+                    and not flight["plan"].is_decode[i]:
+                self.scheduler.finish(seq)
+                flight["dead"].add(i)
+                return True
+        return False
 
     def note_idle(self) -> None:
         """The caller's loop is about to sleep for lack of work: the time
@@ -755,7 +786,8 @@ class NativeEngine:
 
     def close(self) -> None:
         """Release background resources (host-tier copy + pool publish
-        threads)."""
+        threads); a step in flight is let go uncommitted."""
+        self._flight = None
         if self._copy_stream is not None:
             self._copy_stream.close()
             self._copy_stream = None
@@ -772,8 +804,8 @@ class NativeEngine:
             # covers its transfer list; the watermark check runs HERE,
             # before planning, on the same thread that applies injects
             s.poll_overlap_gates()
-        return (self._pipeline is not None or bool(s.waiting)
-                or bool(s.stream_active)
+        return (self._pipeline is not None or self._flight is not None
+                or bool(s.waiting) or bool(s.stream_active)
                 or any(x is not None for x in s.running))
 
     def step(self) -> List[StepOutput]:
@@ -785,7 +817,9 @@ class NativeEngine:
         the in-flight window's outputs while the follow-up executes on
         device. Events for a pipelined window therefore arrive one step()
         call after its dispatch; greedy and seeded-sampled streams stay
-        token-identical to the synchronous loop (docs/PERF.md).
+        token-identical to the synchronous loop (docs/PERF.md). Mixed
+        steps chain the same way (_chain_step): a call that finds one in
+        flight plans and dispatches the next before it fetches it.
 
         Every step kind passes through the same five host phases (plan,
         upload, dispatch, wait, commit: PhaseTimer), flat and contiguous;
@@ -811,12 +845,15 @@ class NativeEngine:
             key = self._call_key
             self.ledger.close_call(
                 "decode" if key and key[0] in ("window", "ppwindow")
-                else "", self._key_bucket(key), t_entry, t_exit, between,
+                else "mixed" if self._flight is not None else "",
+                self._key_bucket(key), t_entry, t_exit, between,
                 parts, self.phases.take_call())
 
     def _step(self) -> List[StepOutput]:
         if self._pipeline is not None:
             return self._pipeline_step()
+        if self._flight is not None:
+            return self._chain_step()
         with self.phases.phase("plan"):
             plan = self.scheduler.schedule()
             self._process_offloads()  # save evicted pages before any overwrite
@@ -829,6 +866,7 @@ class NativeEngine:
             return self._run_stream(plan)
         if isinstance(plan, MixedPlan):
             return self._run_mixed(plan)
+        self._ahead_failed = False
         if isinstance(plan, PrefillPlan):
             # decode-stall accounting: a pure prefill step while decode
             # slots are live starves every running stream for this step
@@ -1045,18 +1083,31 @@ class NativeEngine:
             staged = self._stage_step(plan, reqs, mixed)
         return self._launch_step(staged)
 
-    def _stage_step(self, plan, reqs, mixed: bool = False) -> tuple:
+    def _stage_step(self, plan, reqs, mixed: bool = False,
+                    after: Optional[dict] = None) -> tuple:
         """Sampling arrays and device staging of one `_engine_step`
-        program; runs inside the caller's `upload` phase."""
+        program; runs inside the caller's `upload` phase. `after`: the
+        mixed step in flight that `plan` was made behind (_chain_step);
+        a decode row whose last token that step is still sampling takes
+        it from that step's tokens on the device (`src`: its row there)."""
         temp, top_k, top_p, seeds, counters, min_toks = \
             self._sampling_arrays(reqs, mixed=mixed)
         rp = self._rep_penalty_arrays(reqs, mixed=mixed)
         with_lp = self._wants_logprobs(reqs)
         mm = getattr(plan, "mm_embeds", None) is not None
+        src = np.full_like(plan.last_idx, -1)      # [Bb] int32
+        prev = self._no_prev
+        if after is not None:
+            prev = after["prev"]
+            row_of = {id(row[1]): row[0] for row in after["rows"]}
+            for i, seq in enumerate(plan.seqs):
+                if seq is not None and plan.is_decode[i] \
+                        and seq.output[-1] == PENDING_TOKEN:
+                    src[i] = row_of[id(seq)]
         # STEP_OPERANDS' order, then the variant's own (__init__)
         small = (plan.tokens, plan.positions, plan.page_table, plan.kv_lens,
                  plan.write_idx, plan.last_idx, temp, top_k, top_p, seeds,
-                 counters, min_toks)
+                 counters, min_toks, src)
         if self._state_slots:
             small += (plan.state_slots,)
             real = (plan.write_idx >= 0).sum(axis=1)
@@ -1079,7 +1130,7 @@ class NativeEngine:
         self._account_attention(
             int(plan.kv_lens.sum()), plan.page_table.size,
             *self._window_reads(plan, plan.kv_lens))
-        return key, self._stage_operands(small, own), with_lp
+        return key, (prev, *self._stage_operands(small, own)), with_lp
 
     def _dense_rows(self, plan) -> int:
         """The token rows the token-wise layers of `plan`'s `_engine_step`
@@ -1194,21 +1245,34 @@ class NativeEngine:
         self.ledger.stats.host_buffers_total += len(staged)
         return (layout, *staged)
 
-    def _launch_step(self, staged: tuple):
-        """dispatch + wait of a staged `_engine_step` program."""
-        key, args, with_lp = staged
+    def _dispatch_step(self, staged: tuple) -> tuple:
+        """dispatch of a staged `_engine_step` program: (its outputs,
+        still on the device; its tokens as the step behind it reads
+        them)."""
+        key, args, _ = staged
         with self._dispatch_phase(key):
             # key[1:4] is the variant: (with_rp, with_lp, with_mm)
-            out = self._step_fns[key[1:4]](self.params, self.cache, *args)
-        tokens, lp, top_ids, top_lps, self.cache, aux = out
+            *outs, self.cache, aux, prev = self._step_fns[key[1:4]](
+                self.params, self.cache, *args)
+        return (*outs, aux), prev
+
+    def _fetch_step(self, outs: tuple, with_lp: bool = False,
+                    in_flight: bool = False):
+        """wait of a dispatched `_engine_step` program: its sampled
+        tokens. `in_flight`: the step behind it was dispatched before
+        this fetch, so the device stays busy through the commit."""
         with self.phases.phase("wait"):
-            tokens, lp, top_ids, top_lps, aux = jax.device_get(
-                (tokens, lp, top_ids, top_lps, aux))
-        self.phases.device_busy = False
+            tokens, lp, top_ids, top_lps, aux = jax.device_get(outs)
+        self.phases.device_busy = in_flight
         if aux:
             self._account_moe(aux)
         self._last_logprobs = (lp, top_ids, top_lps) if with_lp else None
         return np.asarray(tokens)
+
+    def _launch_step(self, staged: tuple):
+        """dispatch + wait of a staged `_engine_step` program."""
+        outs, _ = self._dispatch_step(staged)
+        return self._fetch_step(outs, with_lp=staged[2])
 
     def _run_prefill(self, plan: PrefillPlan) -> List[StepOutput]:
         self._mark_planned(plan.seqs)
@@ -1261,11 +1325,182 @@ class NativeEngine:
         token-identical to the alternating scheduler (CPU/f32 exact; on
         TPU bf16 the prefill-shaped forward and the window program
         differ arithmetically at near-tie level, the same caveat as the
-        spec-decode verify path)."""
+        spec-decode verify path).
+
+        Where mixed steps may chain (_chain_ok) the step is dispatched
+        and left in flight: the next call, `_chain_step`, commits it (its
+        events surface there, as a primed window's do) behind the
+        dispatch of the step after it where there is one. Any other step
+        takes the synchronous lines below."""
         self._mark_planned(plan.seqs)
+        if self._ahead_failed:
+            self._ahead_failed = False
+            self.mixed_steps_replanned += 1
+        if self._chain_ok(plan.seqs):
+            self._flight = self._launch_mixed(plan)
+            return []
         sampled = self._run_device_step(plan, plan.seqs, mixed=True)
         with self.phases.phase("commit"):
             return self._commit_mixed(plan, sampled)
+
+    # -- the mixed chain -----------------------------------------------------
+
+    def _chain_ok(self, seqs=()) -> bool:
+        """May a mixed step be dispatched before the mixed step in front
+        of it is fetched? As `_pipeline_ok` for windows: not under pp or
+        a spec-decode hand-off, not beside streamed decode or pending
+        offloads / onboards / pool injects, and not while any sequence
+        the engine holds (running, queued, or among `seqs`, a plan's
+        rows) wants what the chain does not carry: logprobs, a penalty
+        history, image embeddings, a prefill-only hand-over. All of it
+        is read from the queues; such a step runs as it always did."""
+        sch = self.scheduler
+        if self.cfg.pipeline_depth < 2 or self.pp > 1 \
+                or self._verify_fn is not None or self._draft is not None:
+            return False
+        if sch.stream_active or sch.pending_onboards \
+                or sch.pending_pool_injects or self._pending_offloads \
+                or sch.overlap_gates:
+            return False
+        params = sch.params
+        for group in (seqs, sch.running, sch.waiting):
+            for seq in group:
+                if seq is None:
+                    continue
+                p = params[seq.request_id]
+                if p.logprobs is not None or p.repetition_penalty != 1.0 \
+                        or seq.mm_spans or seq.prefill_only:
+                    return False
+        return True
+
+    def _launch_mixed(self, plan: MixedPlan,
+                      after: Optional[dict] = None) -> dict:
+        """upload and dispatch of a mixed step that is left in flight,
+        its outputs on their way to the host. `dead`: prefill rows an
+        abort ended under it; `rows`: `_open_mixed`'s."""
+        with self.phases.phase("upload"):
+            staged = self._stage_step(plan, plan.seqs, mixed=True,
+                                      after=after)
+        outs, prev = self._dispatch_step(staged)
+        self._copy_outs_async(outs)
+        return {"plan": plan, "key": staged[0], "outs": outs, "prev": prev,
+                "dead": set(), "rows": ()}
+
+    def _chain_step(self) -> List[StepOutput]:
+        """Advance the chain of mixed steps by one step() call. A mixed
+        step N is in flight (`self._flight`: the call before dispatched
+        it):
+
+        1. open N's commit (_open_mixed): everything the host knows of
+           it without its tokens. Counts advance, a last chunk's row
+           takes its slot, a row whose token is its last by `max_tokens`
+           gives up slot and pages; the tokens themselves stand as
+           PENDING_TOKEN;
+        2. plan N+1 on that state with the ordinary planner
+           (Scheduler.schedule_ahead), so an end by length, a first
+           token and an arrival are simply what the plan finds, and
+           dispatch it: a row that decodes on reads its token from N's
+           on the device;
+        3. fetch N (the one host sync) and close its commit
+           (_close_mixed) while N+1 runs: the tokens take their places,
+           stops are seen, the events go out.
+
+        An end the host could not foresee (a stop id, EOS) and an abort
+        leave N+1 in flight with a row nobody holds any more: the next
+        call opens it for the rows still live (_mixed_live) and never
+        throws it away, because a recurrent state it advanced cannot be
+        run twice (docs/PERF.md has the exactness argument). Where no
+        mixed step can follow, 1 and 3 are the synchronous commit. Every
+        phase is entered at most once a call, as everywhere."""
+        flight, self._flight = self._flight, None
+        follow = plan = None
+        with self.phases.phase("plan"):
+            self._open_mixed(flight)
+            ahead = bool(self.scheduler.waiting) and self._chain_ok()
+            if ahead:
+                plan = self.scheduler.schedule_ahead()
+                self._process_offloads()
+                self._process_onboards()
+                self._process_pool_injects()
+            self._ahead_failed = ahead and plan is None
+        if plan is not None:
+            self.step_count += 1
+            self._mark_planned(plan.seqs)
+            follow = self._launch_mixed(plan, after=flight)
+            self.mixed_steps_chained += 1
+        # this call commits N, whatever it dispatched
+        self._call_key = flight["key"]
+        sampled = self._fetch_step(flight["outs"],
+                                   in_flight=follow is not None)
+        with self.phases.phase("commit"):
+            events = self._close_mixed(flight, sampled)
+        self._flight = follow
+        return events
+
+    def _mixed_live(self, flight: dict) -> List[bool]:
+        """Which rows of the mixed step in flight still hold the sequence
+        they were planned with, the rows it is committed for (`_live_rows`
+        for a window): a decode row whose slot still holds it, a prefill
+        row that no abort ended."""
+        plan, running = flight["plan"], self.scheduler.running
+        return [seq is not None and (
+                    seq.slot >= 0 and running[seq.slot] is seq
+                    if plan.is_decode[i] else i not in flight["dead"])
+                for i, seq in enumerate(plan.seqs)]
+
+    def _open_mixed(self, flight: dict) -> None:
+        """The commit of the mixed step in flight, as far as the host
+        knows it before the step's tokens: `_commit_mixed`'s scheduler
+        calls in `_commit_mixed`'s order, each sampled token standing as
+        PENDING_TOKEN. A row whose token is its last by `max_tokens`
+        ends here, slot, state and pages, exactly as the closed commit
+        would end it a moment later; its event waits for the token.
+        `flight["rows"]`: (row, sequence, its params, where in its
+        output the token goes, ended) of every row that sampled."""
+        plan, sch = flight["plan"], self.scheduler
+        live = self._mixed_live(flight)
+        rows = flight["rows"] = []
+
+        def opened(i: int, seq: SequenceState, p) -> None:
+            ended = len(seq.output) >= p.max_tokens
+            if ended:
+                sch.finish(seq)
+            rows.append((i, seq, p, len(seq.output) - 1, ended))
+
+        for i, seq in enumerate(plan.seqs):
+            if live[i] and plan.is_decode[i]:
+                p = sch.params[seq.request_id]
+                sch.commit_decode_token(seq, PENDING_TOKEN)
+                opened(i, seq, p)
+        for i in reversed(range(len(plan.seqs))):
+            seq = plan.seqs[i]
+            if not live[i] or plan.is_decode[i]:
+                continue
+            p = sch.params[seq.request_id]
+            if sch.commit_prefill_row(
+                    plan, i, PENDING_TOKEN if plan.is_last_chunk[i]
+                    else None) is not None:
+                opened(i, seq, p)
+        flight["live"] = sum(live)
+
+    def _close_mixed(self, flight: dict, sampled) -> List[StepOutput]:
+        """The rest of that commit, the step's tokens in hand: each takes
+        its place in its sequence's output, stop conditions run on it,
+        and the events go out in `_commit_mixed`'s order."""
+        plan = flight["plan"]
+        events: List[StepOutput] = []
+        for i, seq, p, at, ended in flight["rows"]:
+            tok = seq.output[at] = int(sampled[i])
+            if not plan.is_decode[i]:
+                self._mark_first_token(seq)
+            events.append(self._postprocess(seq, tok, opened=(p, ended)))
+        self._dec_state = None
+        self.mixed_steps += 1
+        self._ledger_record(
+            "mixed", len(plan.seqs), flight["live"], sum(plan.n_valid),
+            int(plan.tokens.size), dense=self._dense_rows(plan),
+            events=events)
+        return events
 
     def _commit_mixed(self, plan: MixedPlan, sampled) -> List[StepOutput]:
         lps = self._last_logprobs
@@ -2079,8 +2314,12 @@ class NativeEngine:
 
     def _postprocess(self, seq: SequenceState, tok: int,
                      lp: Optional[float] = None, top_ids=None,
-                     top_lps=None) -> StepOutput:
-        p = self.scheduler.params[seq.request_id]
+                     top_lps=None, opened: Optional[tuple] = None
+                     ) -> StepOutput:
+        """`opened`: (params, ended) of a row whose commit was opened
+        before its token was known (_open_mixed): the params it had then
+        and whether its end by length has been carried out."""
+        p, ended = opened or (self.scheduler.params[seq.request_id], False)
         n_out = len(seq.output)
         finish = None
         emit: Optional[int] = tok
@@ -2093,7 +2332,7 @@ class NativeEngine:
             finish, emit = "stop", None
         elif n_out >= p.max_tokens:
             finish = "length"
-        if finish is not None:
+        if finish is not None and not ended:
             self.scheduler.finish(seq)
             if self._draft is not None:
                 self._draft.forget(seq.request_id)
@@ -2386,6 +2625,8 @@ class NativeEngine:
         m.decode_plan_uploads = self.decode_plan_uploads
         m.host_buffers = self.host_buffers
         m.mixed_steps = self.mixed_steps
+        m.mixed_steps_chained = self.mixed_steps_chained
+        m.mixed_steps_replanned = self.mixed_steps_replanned
         m.decode_stall_steps = self.decode_stall_steps
         # KV representation gauges (ops/kv_quant.py): bytes one page
         # occupies in HBM (k+v+scales) and the quant mode's bit width
@@ -2644,7 +2885,7 @@ def unpack_operands(layout: tuple, buf) -> tuple:
 # appends its own ("rep_penalty", "mm_mask") where the program is built
 STEP_OPERANDS = ("tokens", "positions", "page_table", "kv_lens", "write_idx",
                  "last_idx", "temperature", "top_k", "top_p", "seeds",
-                 "counters", "min_tokens")
+                 "counters", "min_tokens", "src")
 WINDOW_OPERANDS = ("page_table", "base_table", "max_pos", "temperature",
                    "top_k", "top_p", "seeds", "min_tokens", "ignore_eos",
                    "stop_ids")
@@ -2654,7 +2895,8 @@ VERIFY_OPERANDS = ("tokens", "positions", "page_table", "kv_lens",
                    "write_idx", "counters", "min_tokens")
 
 
-def _packed(fn, names: tuple, own: tuple = (), carried: bool = False):
+def _packed(fn, names: tuple, own: tuple = (), carried: bool = False,
+            fed: bool = False):
     """`fn` as the step path calls it (NativeEngine._stage_operands):
     `program(params, cache, [carry,] layout, packed, *own)`. The operands
     `names` arrive side by side in ONE buffer and are taken apart by the
@@ -2663,9 +2905,12 @@ def _packed(fn, names: tuple, own: tuple = (), carried: bool = False):
     operands that keep a buffer to themselves. A `carried` program (a
     decode window) takes its (token, position, counter) as one [S, 3]
     array and hands on the next in the same form, so a chained window is
-    fed the device's own."""
+    fed the device's own. A `fed` program (`_engine_step`) takes the
+    tokens of the step before it in the same place, a device array too."""
     def program(params, cache, *args):
         kw = {}
+        if fed:
+            kw["prev_tokens"], *args = args
         if carried:
             carry, *args = args
             kw.update(tokens=carry[:, 0], positions=carry[:, 1],
@@ -3079,8 +3324,21 @@ def _engine_step(cfg: ModelConfig, eos_ids: tuple, sp_mesh, kernel_mesh,
                  tokens, positions, page_table, kv_lens, write_idx, last_idx,
                  temperature, top_k, top_p, seeds, counters, min_tokens,
                  hist=None, rep_penalty=None, mm_embeds=None, mm_mask=None,
-                 state_slots=None, wtable=None, woff=None, wwrite_idx=None):
-    """forward + gather last logits + sample, fused into one XLA program."""
+                 state_slots=None, wtable=None, woff=None, wwrite_idx=None,
+                 prev_tokens=None, src=None):
+    """forward + gather last logits + sample, fused into one XLA program.
+
+    `prev_tokens` [cap] and `src` [B] (the engine hands both, always): a
+    row with `src >= 0` feeds `prev_tokens[src]`, the token row `src` of
+    the step before sampled, which never left the device, in place of
+    its `tokens[:, 0]`; the program then also returns its own sampled
+    tokens at that one length for the step behind it to read."""
+    if prev_tokens is not None:
+        # a select over the grid and no scatter into column 0, which an
+        # "sp" mesh's ring prefill would have to re-shard
+        first = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :] == 0
+        tokens = jnp.where(first & (src >= 0)[:, None],
+                           prev_tokens[jnp.maximum(src, 0)][:, None], tokens)
     meta = AttnMetadata(positions=positions, page_table=page_table,
                         kv_lens=kv_lens, write_idx=write_idx,
                         state_slots=state_slots, wtable=wtable, woff=woff,
@@ -3115,4 +3373,7 @@ def _engine_step(cfg: ModelConfig, eos_ids: tuple, sp_mesh, kernel_mesh,
         last, eos_ids, temperature, top_k, top_p, seeds, counters,
         min_tokens, seen=seen, rep_penalty=rep_penalty if with_rp else None,
         with_lp=with_lp)
-    return toks, lp, top_ids, top_lps, cache, aux
+    if prev_tokens is None:
+        return toks, lp, top_ids, top_lps, cache, aux
+    return toks, lp, top_ids, top_lps, cache, aux, \
+        jnp.full_like(prev_tokens, -1).at[:toks.shape[0]].set(toks)

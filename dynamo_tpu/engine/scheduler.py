@@ -38,6 +38,12 @@ from dynamo_tpu.runtime.qos import (
 )
 
 
+# what a sequence's output holds for a token that a step still in flight
+# is sampling (NativeEngine._open_mixed): the step planned behind it reads
+# the token on the device, and the commit's close writes it here
+PENDING_TOKEN = -1
+
+
 @dataclasses.dataclass
 class SamplingParams:
     """Engine-level sampling options.
@@ -270,6 +276,12 @@ class EngineMetrics:
     # otherwise)
     mixed_steps: int = 0
     decode_stall_steps: int = 0
+    # the mixed chain (engine._chain_step): mixed steps dispatched with
+    # the mixed step before them still in flight, and mixed steps planned
+    # afresh after a commit because the plan made ahead of it came to
+    # nothing (it would have needed a preemption, or found no row)
+    mixed_steps_chained: int = 0
+    mixed_steps_replanned: int = 0
     # KV representation (ops/kv_quant.py): bytes one page occupies in
     # HBM (k+v+scales), quant bit width (0 = unquantized pages), and
     # cumulative transfer volume in the WIRE representation — quantized
@@ -1238,9 +1250,26 @@ class Scheduler:
             return None  # blocked (slots, or memory pressure draining)
         return self._build_prefill(batch, tb)
 
-    def _schedule_mixed(self) -> Optional[MixedPlan]:
+    def schedule_ahead(self) -> Optional[MixedPlan]:
+        """The mixed step to dispatch behind one still in flight, or None
+        where the next step is no mixed step or cannot be planned without
+        evicting a sequence. The engine has opened the commit of the step
+        in flight (every count advanced, the sampled tokens still
+        PENDING_TOKEN in their outputs), so this is the ordinary planner
+        on the state that step leaves: a row that ended by length is
+        gone, a last chunk's row decodes, an arrival is in the queue.
+        What it may not do is preempt: a victim would re-queue with a
+        token nobody knows yet. The next schedule(), made with nothing in
+        flight, may."""
+        if not self.waiting or self.stream_active \
+                or self.mixed_token_budget <= 0 or self.cfg.sp != 1:
+            return None
+        return self._schedule_mixed(ahead=True)
+
+    def _schedule_mixed(self, ahead: bool = False) -> Optional[MixedPlan]:
         """One fused prefill+decode step (MixedPlan), or None when no
-        prefill row is admissible right now.
+        prefill row is admissible right now (`ahead`: or when a running
+        row's next token would need a preemption, schedule_ahead).
 
         Budget accounting (docs/PERF.md): the per-step token budget is
         total [rows x Tb] device compute. Decode rows are charged the
@@ -1259,6 +1288,8 @@ class Scheduler:
             # fed-token slot
             while seq.slot >= 0 \
                     and not self._ensure_pages(seq, seq.total_len + 1):
+                if ahead:
+                    return None
                 # memory-pressure preemption: lowest class first,
                 # youngest within a class; victim starvation bounded by
                 # the class-band requeue + queue aging limit (R19)

@@ -53,6 +53,10 @@ class WorkerMetrics:
     # because the step carried no decode rows — ~0 with mixed steps on)
     mixed_steps: int = 0
     decode_stall_steps: int = 0
+    # the mixed chain: mixed steps dispatched behind one still in flight,
+    # and mixed steps planned again after the commit before them
+    mixed_steps_chained: int = 0
+    mixed_steps_replanned: int = 0
     # KV representation (ops/kv_quant.py): HBM bytes per page, quant bit
     # width (0 = unquantized), cumulative wire-representation transfer
     # volume (quantized bytes on kv_quant engines)

@@ -107,6 +107,12 @@ class MetricsExporter:
                 ("stall_steps",
                  "Steps where running streams emitted nothing (decode "
                  "stalled by a prefill-only step)"),
+                ("mixed_steps_chained",
+                 "Mixed steps dispatched with the mixed step before them "
+                 "still in flight"),
+                ("mixed_steps_replanned",
+                 "Mixed steps planned again after the commit before "
+                 "them, the plan made ahead of it having come to nothing"),
             )}
         # KV representation gauges (ops/kv_quant.py): page HBM footprint,
         # quant mode bit width (0 = unquantized, 8 = int8 pages), and
@@ -373,6 +379,10 @@ class MetricsExporter:
                 worker_id, value=m.mixed_steps)
             self.g_pipe["stall_steps"].set(
                 worker_id, value=m.decode_stall_steps)
+            self.g_pipe["mixed_steps_chained"].set(
+                worker_id, value=m.mixed_steps_chained)
+            self.g_pipe["mixed_steps_replanned"].set(
+                worker_id, value=m.mixed_steps_replanned)
             self.g_kv_repr["page_bytes"].set(
                 worker_id, value=m.kv_page_bytes)
             self.g_kv_repr["quant_mode"].set(

@@ -382,9 +382,12 @@ def test_a_pipelined_window_is_counted_once(recorded):
     stats, _, calls, commits = recorded["pipelined"]
     primed = [r for r in calls if not r["dev_steps"]]
     assert primed and all(
-        r["kind"] == "decode" and r["bucket"] == 8 and r["tokens"] == 0
-        and "commit" not in r["phases"] and "dispatch" in r["phases"]
-        for r in primed)
+        r["tokens"] == 0 and "commit" not in r["phases"]
+        and "dispatch" in r["phases"] for r in primed)
+    # a window that was only primed or chained, and the mixed step a
+    # chain of mixed steps starts with (NativeEngine._chain_step)
+    assert {r["kind"] for r in primed} == {"decode", "mixed"}
+    assert all(r["bucket"] == 8 for r in primed if r["kind"] == "decode")
     windows = [r for r in calls if r["kind"] == "decode" and r["dev_steps"]]
     assert stats.steps_decode == len(windows)
     assert stats.window_steps_total == sum(r["dev_steps"] for r in windows)
@@ -392,8 +395,9 @@ def test_a_pipelined_window_is_counted_once(recorded):
     assert sum(r["tokens"] for r in calls) == sum(
         n for times in commits.values() for _, n in times) == 50
     mixed = [r for r in calls if r["kind"] == "mixed"]
-    assert mixed and all(r["dev_steps"] == 1 and len(r["bucket"]) == 2
+    assert mixed and all(r["dev_steps"] <= 1 and len(r["bucket"]) == 2
                          for r in mixed)
+    assert stats.steps_mixed == sum(r["dev_steps"] for r in mixed)
     # the synchronous loop ran the same programs, with no call between
     sync_stats, _, sync_calls, _ = recorded["sync"]
     assert all(r["dev_steps"] for r in sync_calls)
