@@ -157,12 +157,9 @@ def append_trajectory(best: dict, platform: str) -> None:
         f"sharded_disagg_ttft_ratio_{suffix}":
             sh.get("disagg_ttft_ratio"),
         # ragged kernel (ISSUE 18): unified/legacy step time
-        # must stay at or under parity, and the fused tail
-        # under the unfused — both gated "lower"
+        # must stay at or under parity — gated "lower"
         f"decode_kernel_unified_legacy_step_ratio_{suffix}":
             dk.get("unified_legacy_step_ratio"),
-        f"decode_kernel_fused_tail_step_ratio_{suffix}":
-            dk.get("fused_unfused_step_ratio"),
         # long-context streaming (ISSUE 20): the ITL price
         # of attending beyond HBM at the 4x-budget rung,
         # token-identity-gated at capture — gated "lower"
@@ -494,20 +491,17 @@ def run_kv_quant_ab(model_cfg, base_kwargs=None, *, seconds=10.0,
 
 def run_decode_kernel_ab(model_cfg, base_kwargs=None, *, rows=8,
                          n_chips=1, logf=None):
-    """Ragged-kernel + fused-tail A/B for extras["decode_kernel"]
-    (ISSUE 18): step time of the frozen pre-PR-18 kernel vs the unified
-    ragged kernel vs unified + fused sampling tail, token-identity
-    enforced in-phase.
+    """Ragged-kernel A/B for extras["decode_kernel"] (ISSUE 18): step
+    time of the frozen pre-PR-18 kernel vs the unified ragged kernel,
+    token-identity enforced in-phase.
 
     Each arm is ONE jitted "decode step" at the model's geometry:
     paged attention over ragged lengths -> a head projection -> the
-    sampling tail. Arms: (a) legacy (s, hkv)-grid kernel + unfused tail,
-    (b) unified ragged kernel + unfused tail, (c) unified + fused tail
-    (the production common path — what a decode window runs per step).
-    All three must sample IDENTICAL tokens (top_p = 1 workload); the
-    unified/legacy step-time ratio is the tentpole's no-regression gate
-    (<= 1.0, BASELINE.json `decode_kernel_unified_legacy_step_ratio_*`)
-    and the fused/unfused ratio prices the tail fusion. CPU runs both
+    sampling tail. Arms: (a) legacy (s, hkv)-grid kernel, (b) unified
+    ragged kernel. Both must sample IDENTICAL tokens (top_p = 1
+    workload); the unified/legacy step-time ratio is the tentpole's
+    no-regression gate (<= 1.0, BASELINE.json
+    `decode_kernel_unified_legacy_step_ratio_*`). CPU runs both
     kernels in interpret mode (program-count overhead dominates: the
     ragged kernel launches s programs vs the legacy s*hkv); the TPU
     ladder item (BENCH_SELF_r18_ragged_tpu) gives the hardware verdict.
@@ -547,19 +541,16 @@ def run_decode_kernel_ab(model_cfg, base_kwargs=None, *, rows=8,
     keys = sampler.make_keys(jnp.arange(s, dtype=jnp.int32),
                              jnp.zeros((s,), jnp.int32))
 
-    def make_step(kernel, fused):
+    def make_step(kernel):
         def f(q, k, v, pt, lens, w_head, temp, top_k, top_p, keys):
             attn = kernel(q, k, v, pt, lens, interpret=interpret)
             logits = attn.reshape(s, h * hd) @ w_head
-            if fused:
-                return sampler.sample_fused(logits, temp, top_k, keys)
             return sampler.sample(logits, temp, top_k, top_p, keys)
         return jax.jit(f)
 
     arms = {
-        "legacy": make_step(decode_paged_attention_legacy, False),
-        "unified": make_step(decode_paged_attention, False),
-        "unified_fused": make_step(decode_paged_attention, True),
+        "legacy": make_step(decode_paged_attention_legacy),
+        "unified": make_step(decode_paged_attention),
     }
     args = (q, k, v, pt, lens, w_head, temp, top_k, top_p, keys)
     toks, ms = {}, {}
@@ -571,9 +562,7 @@ def run_decode_kernel_ab(model_cfg, base_kwargs=None, *, rows=8,
             out = fn(*args)
         out.block_until_ready()
         ms[name] = (_time.perf_counter() - t0) / reps * 1e3
-    identical = bool(np.array_equal(toks["legacy"], toks["unified"])
-                     and np.array_equal(toks["unified"],
-                                        toks["unified_fused"]))
+    identical = bool(np.array_equal(toks["legacy"], toks["unified"]))
     # token identity is the phase's correctness gate, not a soft metric
     assert identical, {k2: v2.tolist() for k2, v2 in toks.items()}
     res = {
@@ -581,19 +570,13 @@ def run_decode_kernel_ab(model_cfg, base_kwargs=None, *, rows=8,
         "page_size": ps, "interpret": interpret,
         "legacy_step_ms": round(ms["legacy"], 3),
         "unified_step_ms": round(ms["unified"], 3),
-        "unified_fused_step_ms": round(ms["unified_fused"], 3),
         "unified_legacy_step_ratio": round(
             ms["unified"] / ms["legacy"], 4) if ms["legacy"] else None,
-        "fused_unfused_step_ratio": round(
-            ms["unified_fused"] / ms["unified"], 4)
-        if ms["unified"] else None,
         "tokens_identical": identical,
     }
     logf(f"decode kernel A/B ({'interpret' if interpret else 'tpu'}): "
          f"legacy {ms['legacy']:.2f} ms -> unified {ms['unified']:.2f} ms "
-         f"(ratio {res['unified_legacy_step_ratio']}), fused tail "
-         f"{ms['unified_fused']:.2f} ms "
-         f"(ratio {res['fused_unfused_step_ratio']}); tokens identical")
+         f"(ratio {res['unified_legacy_step_ratio']}); tokens identical")
     return res
 
 
@@ -1838,8 +1821,7 @@ def run_phases(st: Run) -> None:
     if os.environ.get("BENCH_DECODE_KERNEL", "1") != "0" and left(120):
         st.set_phase("decode_kernel_ab")
         log("phase: decode kernel A/B — frozen legacy vs unified ragged "
-            "kernel vs unified + fused sampling tail, token-identity "
-            "enforced (ISSUE 18)")
+            "kernel, token-identity enforced (ISSUE 18)")
         st.evidence("decode_kernel", "decode kernel A/B",
                     lambda: run_decode_kernel_ab(
                         model_cfg, PAGE_KWARGS, n_chips=n_chips, logf=log))

@@ -327,8 +327,8 @@ def chat(port: int, body: dict, first_token: threading.Event = None,
 
 TEMPLATE_TOKENS = 25
 GREEDY = {"temperature": 0.0}
-SAMPLED = {"temperature": 0.8, "top_p": 1.0}        # fused sampling tail
-SAMPLED_TOP_P = {"temperature": 0.8, "top_p": 0.9}  # sort-based tail
+SAMPLED = {"temperature": 0.8, "top_p": 1.0}        # the API's default
+SAMPLED_TOP_P = {"temperature": 0.8, "top_p": 0.9}  # same window program
 CHAIN_LEN = 390
 
 

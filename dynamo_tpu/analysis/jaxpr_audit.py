@@ -272,8 +272,7 @@ def audit_engine_entry_points() -> List[Finding]:
     findings: List[Finding] = []
 
     decode_fn = functools.partial(
-        _engine_decode_window, cfg, eos, None, nw, ps, False, False, True,
-        False)
+        _engine_decode_window, cfg, eos, None, nw, ps, False, False, True)
     decode_args = (params, cache, i32((s,)), i32((s,)), i32((s, pb)),
                    i32((s, pb)), i32((s,)), f32((s,)), i32((s,)),
                    jnp.ones((s,), jnp.float32), i32((s,)), i32((s,)),

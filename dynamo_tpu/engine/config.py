@@ -283,7 +283,7 @@ class ModelConfig:
         named "k" because it is what the absorbed queries are scored
         against: a single head of kv_lora_rank + qk_rope_head_dim whose
         first kv_lora_rank columns are also the values. init_cache, the
-        shardings, the page-byte gauges and every refusal read this."""
+        shardings, the page-byte gauges and `refuse_unserved` read this."""
         if self.is_mla:
             return {"k": (1, self.kv_lora_rank + self.qk_rope_head_dim)}
         return {"k": (self.num_kv_heads, self.head_dim),
@@ -501,6 +501,98 @@ class EngineConfig:
     # knob — decode rows ride every step, so there is no streak to
     # bound. 0 = unbounded (old prefill-priority).
     max_prefill_streak: int = 2
+
+
+# -- what a kind of cache is not served with ----------------------------------
+
+# A row a consumer, a column a store beside the plain paged K / V pool
+# (ModelConfig.state_leaves, the one-leaf kv_cache_leaves,
+# window_cache_leaves). An entry is the reason the store gives, "" where
+# the consumer's name says it all, None where the store IS served with it.
+# Each consumer moves, shares, shards, packs or rolls back a sequence's
+# context as K and V pages of Hkv heads in ONE pool. A recurrent state has
+# no page to go with (the state after another sequence's tokens exists
+# nowhere; a rejected draft's update cannot be undone); a latent cache is
+# one leaf of one head; a window pool is a second page list that forgets.
+# Prefix reuse is not a row: the scheduler switches it off for a state and
+# for a window pool, and says so once in the log. A fourth store is one
+# more column.
+UNSERVED = (
+    # consumer, recurrent state, one-leaf latent cache, window pool
+    ("feature", "", "", ""),
+    ("another store", None, None, ""),
+    ("mesh",
+     "the state slots and a share's expert exchange are one device's",
+     "the one KV head cannot be sharded",
+     "parallel/mesh.kv_shard_layout and the pp / sp programs know one "
+     "pool"),
+    ("kv_quant", "", "the codec is per K and V row",
+     "the codec's scale leaves follow one pool"),
+    ("quant", "ops/quant.py names the wq/wk/wv leaves",
+     "ops/quant.py names the wq/wk/wv leaves",
+     "ops/quant.py names params['layers']"),
+    ("decode_kernel",
+     "the Pallas kernel's window carries the cache alone",
+     "the Pallas kernel reads separate K and V pages",
+     "the Pallas kernel has no window and walks one page table"),
+    ("vision", "", None, ""),
+    ("tiers", "", "", ""),
+    ("spec_decode", "a rejected draft's state update has no rollback", None,
+     "a verify block is not planned over two page lists"),
+)
+
+
+def refuse_unserved(model_cfg: ModelConfig,
+                    engine_cfg: Optional[EngineConfig] = None,
+                    mesh=None, feature: str = "") -> None:
+    """THE place that says what a model's cache cannot be served with yet
+    (`UNSERVED`); a model with plain K / V pages alone passes. The engine
+    calls it at construction with its configuration and mesh, and the
+    entry points that move whole pages by the names "k" and "v" (disagg
+    transfer, the shared pool) call it with `feature` when they are
+    reached. The stores are asked in turn (state, latent, window) and the
+    first with a reason raises, under its own opening sentence."""
+    cfg = model_cfg
+    if not (cfg.has_linear_layers or cfg.is_mla or cfg.window_pool):
+        return      # the page movers ask on every call
+    ecfg = engine_cfg or EngineConfig()
+    kv_quant = cfg.kv_quant or ecfg.kv_quant
+    # consumer -> how this call names it ("": not asked for)
+    asked = {
+        "feature": feature,
+        "another store": "latent or linear attention beside the window "
+        "layers" * (cfg.is_mla or cfg.has_linear_layers),
+        "mesh": f"a {dict(mesh.shape)} mesh (--tp/--pp/--ep/--sp/--dp)"
+        if mesh is not None and mesh.size > 1 else "",
+        "kv_quant": f"kv_quant={kv_quant!r}" * bool(kv_quant),
+        "quant": f"quant={cfg.quant!r}" * bool(cfg.quant),
+        "decode_kernel": f"decode_kernel={cfg.decode_kernel!r}"
+        * (cfg.decode_kernel not in ("auto", "off")),
+        "vision": "a vision tower" * (cfg.vision is not None),
+        "tiers": "the host / disk KV tiers and streamed decode "
+        "(--host-pages, --disk-pages, --stream-pages)" * bool(
+            ecfg.host_pages or ecfg.disk_pages or ecfg.stream_pages),
+        "spec_decode": f"spec_decode={ecfg.spec_decode!r}"
+        * bool(ecfg.spec_decode),
+    }
+    stores = (
+        (cfg.has_linear_layers,
+         f"linear-attention layers keep a recurrent state a sequence "
+         f"({cfg.state_bytes_per_slot()} bytes)"),
+        (cfg.is_mla,
+         f"latent attention keeps ONE cache leaf of width "
+         f"{cfg.kv_lora_rank + cfg.qk_rope_head_dim} a token"),
+        (cfg.window_pool,
+         f"{cfg.num_window_layers} sliding layers keep their last "
+         f"{cfg.sliding_window} tokens in a page pool of their own"),
+    )
+    for column, (held, opening) in enumerate(stores, start=1):
+        why = [asked[row[0]] + (f": {row[column]}" if row[column] else "")
+               for row in UNSERVED
+               if asked[row[0]] and row[column] is not None]
+        if held and why:
+            raise ValueError(f"{cfg.name}: {opening}; not served with it "
+                             f"yet: " + "; ".join(why))
 
 
 # -- named architectures ------------------------------------------------------

@@ -25,7 +25,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+from dynamo_tpu.engine.config import (
+    EngineConfig, ModelConfig, refuse_unserved,
+)
 from dynamo_tpu.engine.kv_cache import SequenceState
 from dynamo_tpu.engine.offload import CopyStream, HostKvPool
 from dynamo_tpu.engine.sampler import (
@@ -89,10 +91,7 @@ class NativeEngine:
         # VERDICT r3 weak #7 + r4 #6); logprob/penalty plans fall back to
         # per-token dispatch.
         self.pp = self.mesh.shape.get("pp", 1)
-        llama.refuse_unserved_recurrent_state(model_cfg, engine_cfg,
-                                              self.mesh)
-        llama.refuse_unserved_latent_cache(model_cfg, engine_cfg, self.mesh)
-        llama.refuse_unserved_window_cache(model_cfg, engine_cfg, self.mesh)
+        refuse_unserved(model_cfg, engine_cfg, self.mesh)
         if model_cfg.experts_held and model_cfg.moe_impl == "dispatch" \
                 and not model_cfg.moe_dropless:
             raise ValueError(
@@ -306,7 +305,7 @@ class NativeEngine:
         # pipeline occupancy counters (EngineMetrics / /metrics gauges)
         self.decode_windows = 0       # windows dispatched via the window path
         self.decode_dispatches = 0    # device program launches in decode
-        self.decode_kernel_tag = ""   # last window's attention+tail tag
+        self.decode_kernel_tag = ""   # last window's attention-path tag
         self.decode_host_syncs = 0    # blocking output fetches in decode
         self.decode_plan_uploads = 0  # windows that staged fresh host arrays
         self.host_buffers = 0         # host->device buffers the step path
@@ -473,28 +472,19 @@ class NativeEngine:
         # window (scheduler.window_ladder)
         from dynamo_tpu.engine.scheduler import window_ladder
         self._window_sizes = window_ladder(engine_cfg.decode_steps)
-        # `fused` picks the top_p-free sample_fused tail (sampler.py) for
-        # plans whose every row has top_p disabled — the common serving
-        # shape. It is a static key bit like greedy, so for a fixed
-        # workload the dispatched program count is unchanged (the
-        # _note_program pin); fused is only ever staged with
-        # greedy=False, with_lp=False (see _run_decode), so the sampled
-        # hot path swaps sorts for one argsort without a fallback branch
-        # inside the program.
         self._decode_fns = {
-            (rp, lp, greedy, fused, nw): jax.jit(
+            (rp, lp, greedy, nw): jax.jit(
                 _named(self._window_name(nw), _packed(
                     functools.partial(
                         _engine_decode_window, model_cfg, eos_tuple,
                         kernel_mesh, nw, engine_cfg.page_size, rp, lp,
-                        greedy, fused),
+                        greedy),
                     WINDOW_OPERANDS + state_op + wwin_op
                     + ("rep_penalty",) * rp,
                     ("hist",) * rp, carried=True)),
                 static_argnums=(3,), donate_argnums=(1,))
             for rp in (False, True) for lp in (False, True)
-            for greedy in (False, True) for fused in (False, True)
-            for nw in self._window_sizes
+            for greedy in (False, True) for nw in self._window_sizes
         }
         # speculative decoding (engine/spec.py): ONE verify program over a
         # fixed [S, spec_k+1] block — a prefill-shaped forward whose
@@ -562,16 +552,14 @@ class NativeEngine:
         if self.pp > 1:
             from dynamo_tpu.models.pp import pp_decode_window
             self._pp_decode_fns = {
-                (nw, greedy, fused): jax.jit(
+                (nw, greedy): jax.jit(
                     _named(self._window_name(nw), _packed(
                         functools.partial(
                             pp_decode_window, self.model_cfg, eos_tuple,
-                            self.mesh, nw, engine_cfg.page_size, greedy,
-                            fused),
+                            self.mesh, nw, engine_cfg.page_size, greedy),
                         PP_WINDOW_OPERANDS, carried=True)),
                     static_argnums=(3,), donate_argnums=(1,))
                 for nw in self._window_sizes for greedy in (False, True)
-                for fused in (False, True)
             }
         # disaggregation: whole-page gather/scatter on the
         # [L, Hkv, P, ps, hd] cache (the TPU equivalent of the reference's
@@ -977,7 +965,7 @@ class NativeEngine:
 
     # where a program key holds its bucket: the `[Bb, Tb]` grid of an
     # `_engine_step` or a verify block, a decode window's rung
-    _BUCKET_AT = {"step": 4, "window": 5, "ppwindow": 3, "verify": 1}
+    _BUCKET_AT = {"step": 4, "window": 4, "ppwindow": 2, "verify": 1}
 
     @classmethod
     def _key_bucket(cls, key: Optional[tuple]):
@@ -1561,15 +1549,7 @@ class NativeEngine:
             if drafts is not None:
                 block = self._stage_spec(plan, drafts, samp[4], samp[5])
             else:
-                # fused sampling tail: sampled plans whose every row has
-                # top_p disabled (the common serving shape) take the
-                # top_p-free sample_fused tail inside the window —
-                # logprobs plans keep the unfused tail (they already pay
-                # the full-vocab log_softmax)
-                fused = (not greedy and not with_lp
-                         and self._samp_cache.fused_eligible)
-                staged = self._stage_window(plan, samp, rp, with_lp,
-                                            greedy, fused)
+                staged = self._stage_window(plan, samp, rp, with_lp, greedy)
         if drafts is not None:
             return self._run_spec_decode(plan, drafts, block)
         outs, nxt = self._dispatch_staged(staged, staged["first"])
@@ -1620,7 +1600,7 @@ class NativeEngine:
                      if w >= max(1, plan.n_window)), self._window_sizes[0])
 
     def _stage_window(self, plan: DecodePlan, samp, rp, with_lp: bool,
-                      greedy: bool, fused: bool = False) -> dict:
+                      greedy: bool) -> dict:
         """Stage the device-side plan arrays for a decode window.
 
         Split-KV base width (VERDICT r3 missing #2): the base gather covers
@@ -1648,7 +1628,7 @@ class NativeEngine:
                tuple((len(s.pages), s.wfirst, len(s.wpages)) if s else 0
                      for s in plan.seqs),
                plan.page_table.shape[1], base_pb, plan.stop_ids.shape[1],
-               rp is None, with_lp, greedy, fused)
+               rp is None, with_lp, greedy)
         st = self._dec_state
         if st is not None and st["sig"] == sig and rp is None:
             dev = st["dev"]
@@ -1675,19 +1655,18 @@ class NativeEngine:
         nw = self._window_rung(plan)
         pregather = llama._decode_kernel_mode(self.model_cfg) is None
         return {"sig": sig, "dev": dev, "first": first, "nw": nw,
-                "key": (rp is not None, with_lp, greedy, fused, nw),
+                "key": (rp is not None, with_lp, greedy, nw),
                 # recompile detection (_dispatch_phase): the decode-window
                 # program is keyed by its variant grid entry plus every
                 # bucketed dim
                 "program": ("window", rp is not None, with_lp, greedy,
-                            fused, nw, len(plan.seqs),
+                            nw, len(plan.seqs),
                             plan.page_table.shape[1], base_pb,
                             plan.stop_ids.shape[1]),
                 # per-window attribution tag (`decode_kernel_tag`):
-                # which attention path + sampling tail this window's one
-                # device program runs
-                "tag": (("gather" if pregather else "ragged")
-                        + ("+fused" if fused else "")),
+                # which attention path this window's one device program
+                # runs
+                "tag": "gather" if pregather else "ragged",
                 # valid-KV capacity of the staged base table; the kernel
                 # path streams from the global cache and has no base cap
                 "base_cap": base_pb * ps if pregather else None,
@@ -1710,7 +1689,7 @@ class NativeEngine:
                         axis=1)
 
     def _stage_pp_window(self, plan: DecodePlan, samp,
-                         greedy: bool, fused: bool = False) -> dict:
+                         greedy: bool) -> dict:
         """Stage a pipeline-parallel decode window (models/pp.py). Same
         device-resident reuse contract as _stage_window: an unchanged slot
         set + page allocation feeds the previous window's (token, position,
@@ -1720,7 +1699,7 @@ class NativeEngine:
                      for s in plan.seqs),
                tuple(len(s.pages) if s else 0 for s in plan.seqs),
                plan.page_table.shape[1], plan.stop_ids.shape[1],
-               "pp", greedy, fused)
+               "pp", greedy)
         st = self._dec_state
         if st is not None and st["sig"] == sig:
             dev = st["dev"]
@@ -1737,11 +1716,11 @@ class NativeEngine:
             self.decode_plan_uploads += 1
         nw = self._window_rung(plan)
         return {"sig": sig, "dev": dev, "first": first, "nw": nw,
-                "key": (nw, greedy, fused),
-                "program": ("ppwindow", greedy, fused, nw, len(plan.seqs),
+                "key": (nw, greedy),
+                "program": ("ppwindow", greedy, nw, len(plan.seqs),
                             plan.page_table.shape[1],
                             plan.stop_ids.shape[1]),
-                "tag": "pp" + ("+fused" if fused else ""),
+                "tag": "pp",
                 "base_cap": None, "pp": True}
 
     def _dispatch_staged(self, staged: dict, carry):
@@ -1862,12 +1841,10 @@ class NativeEngine:
         with self.phases.phase("upload"):
             samp = self._sampling_arrays(plan.seqs)
             greedy = self._samp_cache.all_greedy
-            fused = not greedy and self._samp_cache.fused_eligible
             if self.pp > 1:
-                staged = self._stage_pp_window(plan, samp, greedy, fused)
+                staged = self._stage_pp_window(plan, samp, greedy)
             else:
-                staged = self._stage_window(plan, samp, None, False,
-                                            greedy, fused)
+                staged = self._stage_window(plan, samp, None, False, greedy)
         outs, nxt = self._dispatch_staged(staged, staged["first"])
         self._dec_state = {"sig": staged["sig"], "dev": staged["dev"],
                            "next": nxt}
@@ -2279,8 +2256,7 @@ class NativeEngine:
             if drafts is not None:
                 block = self._stage_spec(plan, drafts, samp[4], samp[5])
             elif plan.n_window > 1 and not with_lp and rp is None:
-                fused = not greedy and self._samp_cache.fused_eligible
-                staged = self._stage_pp_window(plan, samp, greedy, fused)
+                staged = self._stage_pp_window(plan, samp, greedy)
             else:
                 step = self._stage_step(plan, plan.seqs)
         if drafts is not None:
@@ -2419,12 +2395,9 @@ class NativeEngine:
             # mid-sequence chunk the ring path must not see. SP engines are
             # the prefill side of disaggregation, not the decode side.
             return None
-        llama.refuse_unserved_recurrent_state(
+        refuse_unserved(
             self.model_cfg, feature="disagg transfer (a remote prefill "
-            "leaves pages and no state)")
-        llama.refuse_unserved_window_cache(
-            self.model_cfg, feature="disagg transfer (a remote prefill "
-            "leaves the pages of one pool)")
+            "leaves K and V pages of one pool, and no state)")
         # per-hash copy settling happens inside the prefix walk, as in
         # add_request (this path also matches against the host tier)
         return self.scheduler.add_remote(
@@ -2482,13 +2455,7 @@ class NativeEngine:
         """Gather whole KV pages -> ({k,v[,k_scale,v_scale]}, on-device):
         values [L, Hkv, Nb, ps, hd] plus scale stacks [L, Hkv, Nb, ps] on
         kv_quant engines — the stored representation, never dequantized."""
-        llama.refuse_unserved_recurrent_state(
-            self.model_cfg, feature="whole-page extraction (disagg "
-            "transfer, the shared KV pool)")
-        llama.refuse_unserved_latent_cache(
-            self.model_cfg, feature="whole-page extraction (disagg "
-            "transfer, the shared KV pool)")
-        llama.refuse_unserved_window_cache(
+        refuse_unserved(
             self.model_cfg, feature="whole-page extraction (disagg "
             "transfer, the shared KV pool)")
         ids = jnp.asarray(self._bucket_ids(page_ids))
@@ -2511,13 +2478,7 @@ class NativeEngine:
         The id padding follows the SENDER's bucket (k_pages.shape[2]), not
         ours — the two engines may have different max_model_len and hence
         different page-count buckets; padding ids drop on scatter."""
-        llama.refuse_unserved_recurrent_state(
-            self.model_cfg, feature="whole-page injection (disagg "
-            "transfer, the shared KV pool)")
-        llama.refuse_unserved_latent_cache(
-            self.model_cfg, feature="whole-page injection (disagg "
-            "transfer, the shared KV pool)")
-        llama.refuse_unserved_window_cache(
+        refuse_unserved(
             self.model_cfg, feature="whole-page injection (disagg "
             "transfer, the shared KV pool)")
         if self.kv_quant and k_scale is None:
@@ -2699,12 +2660,7 @@ class NativeEngine:
         events ride the KV-event plane under `pool:{source_id}` so the
         router learns pool-resident prefixes (kv_router/protocols.py)."""
         from dynamo_tpu.engine.kv_pool import PoolPublishStream
-        llama.refuse_unserved_recurrent_state(
-            self.model_cfg, feature="the shared KV pool")
-        llama.refuse_unserved_latent_cache(
-            self.model_cfg, feature="the shared KV pool")
-        llama.refuse_unserved_window_cache(
-            self.model_cfg, feature="the shared KV pool")
+        refuse_unserved(self.model_cfg, feature="the shared KV pool")
         self.kv_pool = pool
         self.kv_pool_source = source_id
         self.scheduler.kv_pool = pool
@@ -3008,7 +2964,7 @@ def _scatter_new_kv(cache, k_news, v_news, write_idx, keys=None):
 
 def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
                           n_steps: int, page_size: int, with_rp: bool,
-                          with_lp: bool, greedy: bool, fused: bool,
+                          with_lp: bool, greedy: bool,
                           params, cache, tokens, positions, page_table,
                           base_table, max_pos, temperature, top_k, top_p,
                           seeds, counters, min_tokens, ignore_eos=None,
@@ -3060,14 +3016,10 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
     base is bounded by the window, whatever the context), its valid
     length `base_len - woff`.
 
-    with_rp / with_lp / greedy / fused pick separately-compiled variants
-    so the common greedy path pays for neither the seen-token mask, the
-    logprob log_softmax+top_k, nor the full sampling sort, and the common
-    SAMPLED path (fused: every row's top_p disabled) swaps the full
-    sort + softmax-cumsum + cutoff tail for the one-argsort
-    sample_fused tail — the whole window stays ONE device dispatch with
-    the sampling leg fused in, and uncommon shapes (top_p, logprobs)
-    recompile onto the unfused tail token-identically.
+    with_rp / with_lp / greedy pick separately-compiled variants so the
+    common greedy path pays for neither the seen-token mask, the logprob
+    log_softmax+top_k, nor the sampler's cut search; every sampled plan
+    takes the one tail (sampler.sample), whatever its rows' top_p.
     """
     s = tokens.shape[0]
     rows = jnp.arange(s)
@@ -3157,7 +3109,7 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
             logits, eos_ids, temperature, top_k, top_p, seeds, ctr,
             min_tokens, seen=seen if with_rp else None,
             rep_penalty=rep_penalty if with_rp else None, with_lp=with_lp,
-            greedy=greedy, fused=fused)
+            greedy=greedy)
         if with_rp:
             seen = seen.at[rows, nxt].set(True)
         if eos_vec is not None:

@@ -49,7 +49,6 @@ class SamplingArrayCache:
         self._key = None
         self._static = None
         self._greedy = True
-        self._fusable = True
 
     def invalidate(self) -> None:
         self._key = None
@@ -77,7 +76,6 @@ class SamplingArrayCache:
                 min_toks[i] = p.min_tokens
             self._static = (temp, top_k, top_p, seeds, min_toks)
             self._greedy = bool(np.all(temp <= 0.0))
-            self._fusable = bool(np.all(top_p >= 1.0))
             self._key = key
         temp, top_k, top_p, seeds, min_toks = self._static
         counters = np.fromiter(
@@ -89,14 +87,6 @@ class SamplingArrayCache:
     def all_greedy(self) -> bool:
         """Every slot in the last-built set samples greedily."""
         return self._greedy
-
-    @property
-    def fused_eligible(self) -> bool:
-        """Every slot in the last-built set has top_p disabled (== 1.0), so
-        the fused top_p-free sampler (`sample_fused`) draws token-identical
-        samples — the decode window's common-path tail. Rows requesting a
-        real top_p force the window onto the unfused `sample` tail."""
-        return self._fusable
 
 
 class RepPenaltyCache:
@@ -310,66 +300,12 @@ def sample(
     return jnp.where(temperature <= 0.0, greedy_tok, sampled)
 
 
-def sample_fused(
-    logits: jax.Array,        # [B, V] f32
-    temperature: jax.Array,   # [B] f32; 0 => greedy
-    top_k: jax.Array,         # [B] int32; 0 => disabled
-    keys: jax.Array,          # [B] PRNG keys (make_keys)
-) -> jax.Array:               # [B] int32
-    """The fused decode-window sampling tail: temperature + top-k only.
-
-    Valid ONLY when every row's top_p is 1.0 (disabled) — the common
-    serving shape (SamplingArrayCache.fused_eligible gates it). Token-
-    identical to `sample` there, by construction:
-
-    - order: `keep_mask` keeps the first n tokens of the stable descending
-      order (equal values by descending id). Scattering iota through that
-      SAME permutation (`ranks[order[j]] = j`) gives each token its place
-      in it, so `ranks < k` is the first k of the same order, ties
-      included.
-    - masked set: at top_p >= 1.0 `keep_mask` does not look at the mass,
-      so k alone decides there too.
-    - draw: same make_keys stream, same categorical over the same masked
-      row => the same token.
-
-    What it bought inside the jitted window, when the full tail still
-    sorted three times and gathered its mask back through the ranks: one
-    argsort and one scatter in their place. Since PR 41 `sample` finds its
-    cut without any sort, and this tail's argsort + scatter are the dearer
-    by far: 2.1-15.3 ms a call against 0.73-1.32 for `sample` on the same
-    top_p-free batch (tools/sampler_tail_bench.py on a v5e, PERF.md
-    section 6, PR 41; no benchmark cell runs such a batch; deleting this
-    tail is ROADMAP D13)."""
-    b, v = logits.shape
-    greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    temp = jnp.maximum(temperature, 1e-6)[:, None]
-    scaled = logits / temp
-
-    order = jnp.argsort(scaled, axis=-1)[:, ::-1]          # [B, V] desc perm
-    rows = jnp.arange(b, dtype=jnp.int32)[:, None]
-    iota = jnp.broadcast_to(jnp.arange(v, dtype=jnp.int32), (b, v))
-    ranks = jnp.zeros((b, v), jnp.int32).at[rows, order].set(iota)
-
-    k = jnp.where(top_k > 0, top_k, v)[:, None]
-    masked = jnp.where(ranks < k, scaled, NEG_INF)
-    sampled = jax.vmap(
-        lambda kk, row: jax.random.categorical(kk, row)
-    )(keys, masked).astype(jnp.int32)
-    return jnp.where(temperature <= 0.0, greedy_tok, sampled)
-
-
 @jax.named_scope("sampler")
 def sample_logits(logits, eos_ids, temperature, top_k, top_p, seeds,
                   counters, min_tokens, seen=None, rep_penalty=None,
-                  with_lp=False, greedy=False, fused=False):
+                  with_lp=False, greedy=False):
     """Shared tail of every engine step: repetition penalty (optional) +
     eos ban below min_tokens + sample (+ logprobs when with_lp).
-
-    `fused` selects the top_p-free `sample_fused` tail; callers must only
-    set it when every row's top_p is 1.0 (SamplingArrayCache.fused_eligible)
-    — the engine stages it as a static window-key bit, so a plan mixing in
-    a real top_p row recompiles onto the unfused tail, token-identically.
 
     Returns (tokens [B], sampled_lp [B], top_ids [B, K], top_lps [B, K]);
     the lp outputs are None unless with_lp — the full-vocab log_softmax +
@@ -392,9 +328,6 @@ def sample_logits(logits, eos_ids, temperature, top_k, top_p, seeds,
         # noise and argmax over the vocabulary cost 0.7-1.3 ms a call
         # (tools/sampler_tail_bench.py, v5e, host-timed)
         toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    elif fused:
-        keys = make_keys(seeds, counters)
-        toks = sample_fused(logits, temperature, top_k, keys)
     else:
         keys = make_keys(seeds, counters)
         toks = sample(logits, temperature, top_k, top_p, keys)

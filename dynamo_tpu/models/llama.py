@@ -489,7 +489,7 @@ def _layer_stack_shardings(cfg: ModelConfig, dense_mlp: bool,
     }
     if kind == "kda":
         # no mesh serves a recurrent state yet
-        # (refuse_unserved_recurrent_state): everything replicated
+        # (engine/config.refuse_unserved): everything replicated
         del layers["wq"]
         layers["wo"] = P(None, None, None)
         layers.update({name: P(None, None, None) for name in (
@@ -498,7 +498,7 @@ def _layer_stack_shardings(cfg: ModelConfig, dense_mlp: bool,
             "kda_a_log", "kda_dt_bias", "kda_o_norm")})
     elif cfg.is_mla:
         # the latent projection and its norm are shared by every head;
-        # no mesh serves this model yet (refuse_unserved_latent_cache)
+        # no mesh serves this model yet (engine/config.refuse_unserved)
         layers.update({"wkv_a": P(None, None, None),
                        "kv_a_norm": P(None, None),
                        "wkv_b": P(None, None, "tp")})
@@ -601,49 +601,6 @@ def init_cache(cfg: ModelConfig, num_pages: int, page_size: int,
             for name, shape in shapes.items()}
 
 
-def refuse_unserved_latent_cache(cfg: ModelConfig, engine_cfg=None,
-                                 mesh=None, feature: str = "") -> None:
-    """THE place that says what a one-leaf latent cache (`cfg.is_mla`)
-    cannot be served with yet; every other model passes. The engine calls
-    it at construction with its configuration and mesh, and the entry
-    points that move whole pages by the names "k" and "v" (disagg
-    transfer, the shared pool) call it with `feature` when they are
-    reached. Each of these reads the cache as two leaves of Hkv heads:
-    the Pallas decode kernel and its sharded form, the int8 page codec,
-    `parallel/mesh.kv_shard_layout` and the tp / pp / ep / sp programs,
-    the host and disk tiers with the streamed decode on top of them, the
-    pool service and the transfer frames (PERF.md section 7)."""
-    if not cfg.is_mla:
-        return
-    why = []
-    if feature:
-        why.append(feature)
-    if mesh is not None and mesh.size > 1:
-        why.append(f"a {dict(mesh.shape)} mesh (--tp/--pp/--ep/--sp/--dp: "
-                   f"the one KV head cannot be sharded)")
-    if cfg.kv_quant:
-        why.append(f"kv_quant={cfg.kv_quant!r} (the codec is per K and V "
-                   f"row)")
-    if cfg.quant:
-        why.append(f"quant={cfg.quant!r} (ops/quant.py names the "
-                   f"wq/wk/wv leaves)")
-    if cfg.decode_kernel not in ("auto", "off"):
-        why.append(f"decode_kernel={cfg.decode_kernel!r} (the Pallas "
-                   f"kernel reads separate K and V pages)")
-    if engine_cfg is not None:
-        if engine_cfg.host_pages or engine_cfg.disk_pages \
-                or engine_cfg.stream_pages:
-            why.append("the host / disk KV tiers and streamed decode "
-                       "(--host-pages, --disk-pages, --stream-pages)")
-        if engine_cfg.kv_quant:
-            why.append(f"kv_quant={engine_cfg.kv_quant!r}")
-    if why:
-        raise ValueError(
-            f"{cfg.name}: latent attention keeps ONE cache leaf of width "
-            f"{cfg.kv_lora_rank + cfg.qk_rope_head_dim} a token; not "
-            f"served with it yet: " + "; ".join(why))
-
-
 def init_state(cfg: ModelConfig, slots: int) -> Dict[str, jax.Array]:
     """The per-sequence recurrent state, a leaf per entry of
     `cfg.state_leaves()`: [state layers, slots + 1, ...], zeros. It lives
@@ -654,106 +611,6 @@ def init_state(cfg: ModelConfig, slots: int) -> Dict[str, jax.Array]:
     return {name: jnp.zeros((cfg.num_state_layers, slots + 1) + shape,
                             jnp.dtype(dtype))
             for name, (shape, dtype) in cfg.state_leaves().items()}
-
-
-def refuse_unserved_recurrent_state(cfg: ModelConfig, engine_cfg=None,
-                                    mesh=None, feature: str = "") -> None:
-    """THE place that says what a model with a per-sequence recurrent
-    state (`cfg.has_linear_layers`) cannot be served with yet; every other
-    model passes. Beside `refuse_unserved_latent_cache`, and called where
-    it is. Each of these moves, shares or rolls back a sequence's context
-    as PAGES, and a page has no state to go with it: a page that another
-    sequence wrote holds keys, while the state after those tokens exists
-    nowhere (prefix reuse is switched off in the scheduler instead, and
-    said once in the log); a rejected draft's state update cannot be
-    undone; the tiers, the pool and the transfer frames carry pages."""
-    if not cfg.has_linear_layers:
-        return
-    why = []
-    if feature:
-        why.append(feature)
-    if mesh is not None and mesh.size > 1:
-        why.append(f"a {dict(mesh.shape)} mesh (--tp/--pp/--ep/--sp/--dp: "
-                   f"the state slots and a share's expert exchange are "
-                   f"one device's)")
-    if cfg.kv_quant:
-        why.append(f"kv_quant={cfg.kv_quant!r}")
-    if cfg.quant:
-        why.append(f"quant={cfg.quant!r} (ops/quant.py names the "
-                   f"wq/wk/wv leaves)")
-    if cfg.decode_kernel not in ("auto", "off"):
-        why.append(f"decode_kernel={cfg.decode_kernel!r} (the Pallas "
-                   f"kernel's window carries the cache alone)")
-    if cfg.vision is not None:
-        why.append("a vision tower")
-    if engine_cfg is not None:
-        if engine_cfg.host_pages or engine_cfg.disk_pages \
-                or engine_cfg.stream_pages:
-            why.append("the host / disk KV tiers and streamed decode "
-                       "(--host-pages, --disk-pages, --stream-pages)")
-        if engine_cfg.kv_quant:
-            why.append(f"kv_quant={engine_cfg.kv_quant!r}")
-        if engine_cfg.spec_decode:
-            why.append(f"spec_decode={engine_cfg.spec_decode!r} (a "
-                       f"rejected draft's state update has no rollback)")
-    if why:
-        raise ValueError(
-            f"{cfg.name}: linear-attention layers keep a recurrent state "
-            f"a sequence ({cfg.state_bytes_per_slot()} bytes); not served "
-            f"with it yet: " + "; ".join(why))
-
-
-def refuse_unserved_window_cache(cfg: ModelConfig, engine_cfg=None,
-                                 mesh=None, feature: str = "") -> None:
-    """THE place that says what a model whose sliding layers keep their
-    pages in a pool of their own (`cfg.window_pool`) cannot be served
-    with yet; every other model passes. Beside
-    `refuse_unserved_latent_cache` / `refuse_unserved_recurrent_state`,
-    and called where they are. A sequence there has TWO page lists, and
-    the second forgets: each of these names pages by one list, moves a
-    context as the pages of one pool, or goes back over positions whose
-    window pages may be gone. Prefix reuse is switched off in the
-    scheduler instead, and said once in the log: a hit would need the
-    window layers' pages of its last `sliding_window` tokens too, and
-    those were released as their sequence moved on."""
-    if not cfg.window_pool:
-        return
-    why = []
-    if feature:
-        why.append(feature)
-    if cfg.is_mla or cfg.has_linear_layers:
-        why.append("latent or linear attention beside the window layers")
-    if mesh is not None and mesh.size > 1:
-        why.append(f"a {dict(mesh.shape)} mesh (--tp/--pp/--ep/--sp/--dp: "
-                   f"parallel/mesh.kv_shard_layout and the pp / sp "
-                   f"programs know one pool)")
-    if cfg.kv_quant:
-        why.append(f"kv_quant={cfg.kv_quant!r} (the codec's scale leaves "
-                   f"follow one pool)")
-    if cfg.quant:
-        why.append(f"quant={cfg.quant!r} (ops/quant.py names "
-                   f"params['layers'])")
-    if cfg.decode_kernel not in ("auto", "off"):
-        why.append(f"decode_kernel={cfg.decode_kernel!r} (the Pallas "
-                   f"kernel has no window and walks one page table)")
-    if cfg.vision is not None:
-        why.append("a vision tower")
-    if engine_cfg is not None:
-        if engine_cfg.host_pages or engine_cfg.disk_pages \
-                or engine_cfg.stream_pages:
-            why.append("the host / disk KV tiers and streamed decode "
-                       "(--host-pages, --disk-pages, --stream-pages)")
-        if engine_cfg.kv_quant:
-            why.append(f"kv_quant={engine_cfg.kv_quant!r}")
-        if engine_cfg.spec_decode:
-            why.append(f"spec_decode={engine_cfg.spec_decode!r} (a "
-                       f"verify block is not planned over two page "
-                       f"lists)")
-    if why:
-        raise ValueError(
-            f"{cfg.name}: {cfg.num_window_layers} sliding layers keep "
-            f"their last {cfg.sliding_window} tokens in a page pool of "
-            f"their own; not served with it yet: " + "; ".join(why))
 
 
 # -- forward ------------------------------------------------------------------

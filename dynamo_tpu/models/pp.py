@@ -377,7 +377,6 @@ def pp_decode_window(
     n_steps: int,
     page_size: int,
     greedy: bool,
-    fused: bool,
     params: Params,
     cache: Dict[str, jax.Array],
     tokens: jax.Array,       # [S] int32 — fed token per slot
@@ -413,11 +412,8 @@ def pp_decode_window(
     previously greedy-only, with sampled plans paying full host-dispatch
     latency x pipeline bubble per token). `greedy` picks the
     argmax-only compiled variant so all-greedy plans skip the sampler's
-    cut search; `fused` picks the top_p-free sample_fused tail for
-    sampled plans whose every row has top_p disabled — the same static
-    window-key bit as the single-mesh engine, so pp plans fuse the
-    common sampling tail identically. Logprob/penalty plans stay
-    per-token (the engine routes them to the fused single-step path).
+    cut search. Logprob/penalty plans stay per-token (the engine routes
+    them to the fused single-step path).
 
     Device-side finish tracking mirrors the single-mesh decode window:
     eos (unless ignore_eos), hidden stop ids, and the max_pos budget all
@@ -439,7 +435,7 @@ def pp_decode_window(
     wnds = None if lw is None else jnp.asarray(lw, jnp.int32)
     kvq = "k_scale" in cache
     fwd = functools.partial(_pp_decode_body, cfg, pp, tp, n_steps,
-                            page_size, eos_ids, greedy, fused, kvq,
+                            page_size, eos_ids, greedy, kvq,
                             wnds is not None)
     in_specs = (P("tp", None), shardings["layers"], P(None), head_spec,
                 pp_cache_sharding(), pp_cache_sharding(),
@@ -481,7 +477,7 @@ def pp_decode_window(
 
 
 def _pp_decode_body(cfg, pp, tp, n_steps, page_size, eos_ids, greedy,
-                    fused, kvq, has_wnds,
+                    kvq, has_wnds,
                     embed, layers, final_norm, head,
                     kc, vc, tokens, pos0, page_table, max_pos,
                     min_tokens, counters, ignore_eos, stop_ids,
@@ -552,7 +548,7 @@ def _pp_decode_body(cfg, pp, tp, n_steps, page_size, eos_ids, greedy,
         # real (others see garbage logits); emit gates what rides out.
         sampled, _, _, _ = sample_logits(
             lg, eos_ids, temp_mb[i], tk_mb[i], tp_mb[i], seed_mb[i],
-            ctr_mb[i] + k, mt_mb[i], greedy=greedy, fused=fused)
+            ctr_mb[i] + k, mt_mb[i], greedy=greedy)
         new_alive = alive_in
         if eos_vec is not None:
             new_alive = new_alive & (ign_mb[i] | ~eos_vec[sampled])

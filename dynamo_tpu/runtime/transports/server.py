@@ -39,6 +39,8 @@ class _Conn:
         self.responders: Dict[str, None] = {}
         self.pending_handles: Dict[int, asyncio.Future] = {}
         self.pop_tasks: Dict[int, asyncio.Task] = {}
+        # ops in flight, held strongly (the loop holds tasks weakly)
+        self._dispatching: set = set()
         self._write_lock = asyncio.Lock()
 
     async def send(self, msg):
@@ -54,7 +56,9 @@ class _Conn:
             while True:
                 # dynalint: unbounded-io-ok=idle-client-connections-are-legal
                 msg = await read_frame(self.reader)
-                asyncio.create_task(self._dispatch(msg))
+                task = asyncio.create_task(self._dispatch(msg))
+                self._dispatching.add(task)
+                task.add_done_callback(self._dispatching.discard)
         except (asyncio.IncompleteReadError, ConnectionResetError):
             pass
         finally:

@@ -44,6 +44,11 @@ class ControlPlaneClient(KVStore, Messaging):
         self._watch_queues: Dict[int, asyncio.Queue] = {}
         self._sub_queues: Dict[int, asyncio.Queue] = {}
         self._handlers: Dict[str, callable] = {}
+        # requests being handled. The loop holds tasks weakly, and a
+        # handler that waits on a stream it opened itself (call_home's
+        # handshake) is reachable from nothing else: a collection in
+        # that moment destroyed it pending, and the caller timed out
+        self._handling: set = set()
         self._reader_task: Optional[asyncio.Task] = None
         self._keepalive_tasks: Dict[int, asyncio.Task] = {}
         self._write_lock = asyncio.Lock()
@@ -155,7 +160,9 @@ class ControlPlaneClient(KVStore, Messaging):
                     if q:
                         q.put_nowait((msg["subject"], msg["payload"]))
                 elif op == "handle":
-                    asyncio.create_task(self._handle_request(msg))
+                    task = asyncio.create_task(self._handle_request(msg))
+                    self._handling.add(task)
+                    task.add_done_callback(self._handling.discard)
         except (asyncio.IncompleteReadError, ConnectionResetError,
                 asyncio.CancelledError):
             pass

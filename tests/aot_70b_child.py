@@ -78,7 +78,7 @@ def main():
     sds = jax.ShapeDtypeStruct
     dec = jax.jit(
         functools.partial(pp_decode_window, cfg, (128001,), mesh, n_steps,
-                          page_size, True, False),
+                          page_size, True),
         donate_argnums=(1,)).lower(
         params, cache,
         sds((slots,), jnp.int32), sds((slots,), jnp.int32),

@@ -12,7 +12,10 @@ Three bars, mirroring the PR's exactness contract:
   ladder runs (tools/tpu_parity_quick.py, PARITY_TPU_r06_kvq);
 - the int8 engine agrees with ITSELF across schedulers and pipeline
   depths (mixed vs alternating, depth 1 vs 2, mid-stream admissions):
-  quantization changes values, never scheduling-dependent behavior.
+  greedy streams token for token; seeded-sampled streams in the logits
+  their sampler is handed, within a stated drift, parting only at a
+  near-tie (a window keeps its own tokens in float until its end, a
+  mixed step reads them as int8: the same pages, not the same reads).
 
 Engines are module-scoped and reused (tier-1 budget); the alternating
 oracle is the same engine with `scheduler.mixed_token_budget` flipped,
@@ -113,13 +116,56 @@ def test_int8_identity_mixed_vs_alternating_and_pipelined(eng_q):
     assert eng_q.mixed_steps > m0          # fused steps really ran int8
 
 
-def test_int8_seeded_sampled_identity(eng_q):
-    """Seeded-sampled streams through the int8 engine: mixed/pipelined
-    equals the alternating reference token-for-token (same (seed,
-    counter) keys through sample_logits over int8-backed logits)."""
+# what the parity gate allows an int8 cache against its float twin in
+# absolute logit drift (bench.KVQ_DRIFT_ATOL; logits here are O(3)); the
+# two schedulers of ONE int8 engine read 0.0073 at their first divergence
+# on this CPU
+SCHEDULER_DRIFT_TOL = 0.05
+
+
+def test_int8_seeded_sampled_identity(monkeypatch):
+    """Seeded-sampled streams through the int8 engine, mixed/pipelined
+    against the alternating reference, held to what a quantized cache can
+    promise: while a row's two streams are the same tokens (so both
+    paths stand on the same context), the logits they hand the sampler
+    agree within SCHEDULER_DRIFT_TOL, and the streams part only at a
+    near-tie that this drift can turn; the first parting is printed with
+    its margin.
+
+    NOT sampled-token identity, which this test asked for until PR 44 and
+    never got (row 0 parted at token 5 on every tree). The two paths do
+    not read the same numbers: a decode window keeps the tokens of its
+    window in a float buffer and quantizes them at its end, a decode row
+    riding a mixed step reads every earlier token from int8 pages. That is
+    int8 noise in the logits (0.007 of 3.2), far inside what the parity
+    gate allows, and it turns a near-tie: row 0's 12th and 13th logits
+    lie 0.0002 apart at token 5, so top_k 12 keeps another token on
+    either path. Greedy streams, whose margins are wider, ARE identical
+    (the test above)."""
+    import jax
+    from dynamo_tpu.engine import engine as engine_mod
+    from dynamo_tpu.engine import sampler
     from tests.test_mixed_steps import (
         PROMPTS, drive_alternating, drive_with_admissions,
     )
+    real, seen = engine_mod._sample_logits, {}
+
+    def spied(logits, eos_ids, temperature, top_k, top_p, seeds, counters,
+              *args, **kwargs):
+        out = real(logits, eos_ids, temperature, top_k, top_p, seeds,
+                   counters, *args, **kwargs)
+        # (request seed, token index) names a row of a call: the seeds of
+        # the three requests differ
+        jax.debug.callback(
+            lambda lg, sd, ctr, tok: seen.update(
+                {(int(sd[i]), int(ctr[i])): (np.array(lg[i]), int(tok[i]))
+                 for i in range(len(sd))}),
+            logits, seeds, counters, out[0])
+        return out
+
+    monkeypatch.setattr(engine_mod, "_sample_logits", spied)
+    eng = NativeEngine(CFG, EngineConfig(kv_quant="int8", pipeline_depth=2,
+                                         **ENGINE_KW), seed=0)
     sampled = [
         SamplingParams(max_tokens=8, temperature=0.9, top_k=12, seed=7,
                        ignore_eos=True),
@@ -127,9 +173,52 @@ def test_int8_seeded_sampled_identity(eng_q):
                        ignore_eos=True),
         SamplingParams(max_tokens=5, temperature=0.8, seed=11,
                        ignore_eos=True)]
-    ref = drive_alternating(eng_q, "kqs-ref", sampled, PROMPTS)
-    mix = drive_with_admissions(eng_q, "kqs-mix", sampled, PROMPTS)
-    assert mix == ref
+    handed = []
+    try:
+        m0 = eng.mixed_steps
+        for drive in (drive_alternating, drive_with_admissions):
+            streams = drive(eng, f"kqs-{len(handed)}", sampled, PROMPTS)
+            jax.effects_barrier()
+            handed.append((streams, dict(seen)))
+            seen.clear()
+        assert eng.mixed_steps > m0
+    finally:
+        eng.close()
+    (ref, ref_seen), (mix, mix_seen) = handed
+    for p, ref_row, mix_row in zip(sampled, ref, mix):
+        assert len(ref_row) == len(mix_row) == p.max_tokens
+        for at in range(p.max_tokens):
+            (a, tok_a), (b, tok_b) = ref_seen[p.seed, at], mix_seen[p.seed, at]
+            assert (tok_a, tok_b) == (ref_row[at], mix_row[at])
+            drift = float(np.abs(a - b).max())
+            assert drift <= SCHEDULER_DRIFT_TOL, (p.seed, at, drift)
+            if tok_a == tok_b:
+                continue
+            # the same context, another token: only over other logits,
+            # and only at a near-tie, of the cut (a token that top-k or
+            # top-p keeps over one path's logits and not over the
+            # other's) or of the draw's perturbed scores
+            assert drift > 0.0, (p.seed, at)
+            key = sampler.make_keys(np.int32([p.seed]), np.int32([at]))[0]
+            kept_a, kept_b = (np.asarray(sampler.keep_mask(
+                x[None] / p.temperature, np.int32([p.top_k]),
+                np.float32([p.top_p])))[0] for x in (a, b))
+            scores = np.where(kept_a, a / p.temperature, -np.inf) + np.asarray(
+                jax.random.gumbel(key, a.shape, a.dtype))
+            assert int(scores.argmax()) == tok_a
+            moved = kept_a != kept_b
+            if moved.any():
+                what, margin = "cut", float(np.ptp(a[moved]))
+                assert margin <= 2 * drift, (p.seed, at, margin, drift)
+            else:
+                what = "draw"
+                margin = float(scores[tok_a] - scores[tok_b])
+                assert margin <= 2 * drift / p.temperature, (
+                    p.seed, at, margin, drift)
+            print(f"seed {p.seed} token {at}: {tok_a} (alternating) / "
+                  f"{tok_b} (mixed); logits differ by {drift:.2g}, a "
+                  f"near-tie of the {what}: margin {margin:.2g}")
+            break       # from here the two streams stand on other contexts
 
 
 # -- representation plumbing ---------------------------------------------------

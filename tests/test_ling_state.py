@@ -12,7 +12,9 @@ import jax
 import numpy as np
 import pytest
 
-from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+from dynamo_tpu.engine.config import (
+    EngineConfig, ModelConfig, refuse_unserved,
+)
 from dynamo_tpu.engine.engine import NativeEngine
 from dynamo_tpu.engine.kv_cache import StateSlots
 from dynamo_tpu.engine.scheduler import EngineRequest, SamplingParams
@@ -199,8 +201,7 @@ def test_page_moves_are_refused_by_name():
     with pytest.raises(ValueError, match="disagg transfer"):
         eng.allocate_remote(EngineRequest("r", [3, 4, 5], SamplingParams()))
     # every other model passes the same call
-    llama.refuse_unserved_recurrent_state(ModelConfig(), EngineConfig(),
-                                          feature="anything")
+    refuse_unserved(ModelConfig(), EngineConfig(), feature="anything")
 
 
 def test_a_share_needs_the_dropless_dispatch():

@@ -22,7 +22,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dynamo_tpu.engine.config import EngineConfig, RopeParams
+from dynamo_tpu.engine.config import (
+    EngineConfig, RopeParams, refuse_unserved,
+)
 from dynamo_tpu.engine.engine import NativeEngine
 from dynamo_tpu.engine.scheduler import (
     EngineRequest, SamplingParams, Scheduler,
@@ -264,9 +266,8 @@ def test_what_a_window_pool_is_not_served_with_is_refused_by_name():
         with pytest.raises(ValueError, match=word):
             NativeEngine(TINY, EngineConfig(**dict(KW, **kw)))
     with pytest.raises(ValueError, match="decode_kernel"):
-        llama.refuse_unserved_window_cache(
-            dataclasses.replace(TINY, decode_kernel="on"))
-    llama.refuse_unserved_window_cache(
+        refuse_unserved(dataclasses.replace(TINY, decode_kernel="on"))
+    refuse_unserved(
         dataclasses.replace(TINY, window_pool=False), EngineConfig(
             spec_decode="ngram"))
 

@@ -390,7 +390,7 @@ def _program_jaxprs(model_cfg):
     step = functools.partial(eng._engine_step, model_cfg, (), None, None,
                              False, False, False, None)
     window = functools.partial(eng._engine_decode_window, model_cfg, (), None,
-                               nw, _POOL_PS, False, False, False, False)
+                               nw, _POOL_PS, False, False, False)
     return {
         "engine_step": jax.make_jaxpr(step)(
             params, cache, arr((rows, chunk)), arr((rows, chunk)),

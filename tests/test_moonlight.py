@@ -27,7 +27,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+from dynamo_tpu.engine.config import (
+    EngineConfig, ModelConfig, refuse_unserved,
+)
 from dynamo_tpu.engine.engine import NativeEngine
 from dynamo_tpu.models import llama, reference
 from dynamo_tpu.models.loader import (
@@ -366,9 +368,9 @@ def test_a_mesh_is_refused_in_the_same_place():
     from jax.sharding import Mesh
     mesh = Mesh(np.asarray(jax.devices()[:2]).reshape(1, 2), ("dp", "tp"))
     with pytest.raises(ValueError, match="ONE cache leaf.*mesh"):
-        llama.refuse_unserved_latent_cache(TINY, EngineConfig(), mesh)
+        refuse_unserved(TINY, EngineConfig(), mesh)
     # every other model passes
-    llama.refuse_unserved_latent_cache(
+    refuse_unserved(
         ModelConfig(kv_quant="int8"), EngineConfig(host_pages=4), mesh)
 
 

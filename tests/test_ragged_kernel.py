@@ -1,4 +1,4 @@
-"""PR 18: ONE ragged decode kernel + fused sampling tail.
+"""PR 18: ONE ragged decode kernel; PR 44: ONE sampling tail.
 
 Two gates in one file:
 
@@ -10,12 +10,13 @@ Two gates in one file:
    the "token-identical to HEAD" argument at the kernel layer; engine-level
    token identity (greedy + seeded-sampled) rides on top.
 
-2. The fused-sampler contract — `fused` is a static window-key bit:
-   common plans (sampled, top_p == 1, no logprobs) dispatch the fused
-   argsort-rank tail inside the decode window; uncommon shapes (top_p,
-   logprobs, greedy) route to the unfused tail; both produce identical
-   tokens (the rank-scatter equivalence argued in docs/PERF.md §3g), and
-   a fixed workload compiles the same number of programs either way.
+2. The sampling tail's contract — every sampled plan takes
+   `sampler.sample`, whatever its rows' top_p: no static bit of the
+   window key tells a top_p-free batch from one with a top_p row (one
+   program, no recompile), a top_p-free batch draws what `sample` draws
+   on the same logits, temperature-0 rows inside a sampled window get
+   their argmax, and a fixed workload mints no program on its second
+   pass.
 """
 import dataclasses
 
@@ -223,47 +224,110 @@ def test_engine_int8_kernel_matches_gather(params):
     assert off == kern
 
 
-# -- fused sampling tail: routing, identity, recompiles -----------------------
+# -- one sampling tail: one window program a sampled plan, whatever top_p ------
 
 
-def test_fused_bit_routing():
-    """The fused tail runs exactly for common plans: sampled with
-    top_p == 1 and no logprobs. top_p < 1, logprobs, and greedy all fall
-    back to the unfused tail (token-identically — the tail bit never
-    changes WHAT is sampled, only how the ranks are materialized)."""
-    base = ModelConfig(dtype="float32", max_model_len=256)
-    _, eng = _gen(base)
-    assert eng.decode_kernel_tag.endswith("+fused")
-    assert eng.decode_dispatches == eng.decode_windows > 0
-    _, eng = _gen(base, params=dataclasses.replace(SAMPLED, top_p=0.9))
-    assert "+fused" not in eng.decode_kernel_tag
-    _, eng = _gen(base, params=dataclasses.replace(SAMPLED, logprobs=0))
-    assert "+fused" not in eng.decode_kernel_tag
-    _, eng = _gen(base, params=SamplingParams(max_tokens=5, temperature=0.0,
-                                              ignore_eos=True))
-    assert "+fused" not in eng.decode_kernel_tag
+def _window_programs(eng):
+    return {k for k in eng._seen_programs if k[0] == "window"}
 
 
-def test_fused_equals_unfused_tokens(monkeypatch):
-    """Forcing the unfused tail on a fused-eligible workload reproduces
-    the exact token stream (docs/PERF.md §3g rank-scatter equivalence)."""
-    from dynamo_tpu.engine import sampler as sampler_mod
-    base = ModelConfig(dtype="float32", max_model_len=256)
-    fused, eng = _gen(base)
-    assert eng.decode_kernel_tag.endswith("+fused")
-    monkeypatch.setattr(sampler_mod.SamplingArrayCache, "fused_eligible",
-                        property(lambda self: False))
-    unfused, eng = _gen(base)
-    assert "+fused" not in eng.decode_kernel_tag
-    assert fused == unfused
-
-
-def test_fused_mixed_batch_tokens_identical(monkeypatch):
-    """A mixed batch (one greedy row via temperature 0, one sampled row)
-    stays fused-eligible — sample_fused resolves temp <= 0 rows to argmax
-    in-program — and matches the unfused tail row for row."""
-    from dynamo_tpu.engine import sampler as sampler_mod
+def _run_batch(eng, reqs, prompt_shift=0):
+    """Serve `reqs` ((request id, SamplingParams) pairs) as one batch;
+    tokens by request id."""
     from dynamo_tpu.engine.scheduler import EngineRequest
+    toks = {rid: [] for rid, _ in reqs}
+    for i, (rid, p) in enumerate(reqs):
+        eng.add_request(EngineRequest(
+            rid, [t + prompt_shift + 7 * i for t in PROMPT], p))
+    while eng.has_work():
+        for ev in eng.step():
+            if ev.token is not None:
+                toks[ev.request_id].append(ev.token)
+    return toks
+
+
+def test_top_p_row_dispatches_the_same_window_program():
+    """A top_p-free sampled batch (the API's default request) and the same
+    batch with one row at top_p 0.95 run ONE window program: the second
+    batch mints no window key. While a static `fused` bit rode the key the
+    second batch compiled every sampled window again."""
+    base = ModelConfig(dtype="float32", max_model_len=256)
+    eng = NativeEngine(base, ECFG, seed=0)
+    try:
+        free = [("a0", SAMPLED), ("a1", dataclasses.replace(SAMPLED, seed=5))]
+        _run_batch(eng, free)
+        windows = _window_programs(eng)
+        assert windows
+        mixed = [("b0", SAMPLED),
+                 ("b1", dataclasses.replace(SAMPLED, seed=5, top_p=0.95))]
+        # other prompts of the same length: a prefix-cache hit would
+        # shorten the prefill chunk, which is a step program's business
+        _run_batch(eng, mixed, prompt_shift=100)
+        assert _window_programs(eng) == windows
+        assert eng.decode_kernel_tag == "gather"   # the attention path alone
+    finally:
+        eng.close()
+
+
+def _top_k_only_tokens(logits, temperature, top_k, keys):
+    """The tail a top_p-free row is owed, written the slow way: rank every
+    token by one descending argsort, keep ranks < k, draw."""
+    v = logits.shape[-1]
+    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+    ranks = jnp.argsort(jnp.argsort(scaled, axis=-1)[:, ::-1], axis=-1)
+    k = jnp.where(top_k > 0, top_k, v)[:, None]
+    masked = jnp.where(ranks < k, scaled, -1e30)
+    drawn = jax.vmap(jax.random.categorical)(keys, masked).astype(jnp.int32)
+    return jnp.where(temperature <= 0.0, jnp.argmax(logits, -1), drawn)
+
+
+def test_top_p_free_tokens_equal_sample_on_the_same_logits(monkeypatch):
+    """Seeded tokens of a top_p 1.0, top_k 12 batch through the engine
+    equal `sampler.sample` called alone on the logits the programs handed
+    their tail (top_p 1.0 switches keep_mask's mass condition off), and
+    both equal the rank-by-one-argsort tail that such a batch used to
+    take inside the window."""
+    from dynamo_tpu.engine import sampler as sampler_mod
+    real, calls = sampler_mod.sample, []
+
+    def spied(logits, temperature, top_k, top_p, keys):
+        toks = real(logits, temperature, top_k, top_p, keys)
+        jax.debug.callback(lambda *xs: calls.append(xs), logits,
+                           temperature, top_k, top_p, keys, toks)
+        return toks
+
+    monkeypatch.setattr(sampler_mod, "sample", spied)
+    base = ModelConfig(dtype="float32", max_model_len=256)
+    p = dataclasses.replace(SAMPLED, top_k=12, top_p=1.0)
+    eng = NativeEngine(base, ECFG, seed=0)
+    try:
+        served = _run_batch(eng, [("r0", p),
+                                  ("r1", dataclasses.replace(p, seed=9))])
+    finally:
+        jax.effects_barrier()
+        eng.close()
+    assert any(k[0] == "window" for k in eng._seen_programs)
+    assert len(calls) >= SAMPLED.max_tokens
+    emitted = set()
+    for logits, temperature, top_k, top_p, keys, toks in calls:
+        live = np.asarray(temperature) > 0        # padding rows: temp 0
+        assert np.all(np.asarray(top_p)[live] == 1.0)
+        assert np.all(np.asarray(top_k)[live] == 12)
+        alone = real(logits, temperature, top_k, jnp.ones_like(top_p), keys)
+        slow = _top_k_only_tokens(logits, temperature, top_k, keys)
+        np.testing.assert_array_equal(np.asarray(alone), toks)
+        np.testing.assert_array_equal(np.asarray(slow), toks)
+        emitted.update(np.asarray(toks)[live].tolist())
+    # what the tail drew is what the requests were sent
+    assert all(len(t) == SAMPLED.max_tokens for t in served.values())
+    assert set(served["r0"]) | set(served["r1"]) <= emitted
+
+
+def test_greedy_rows_in_a_sampled_window_match_each_row_alone():
+    """A batch mixing a temperature-0 row and a sampled row is ONE sampled
+    window (`sample` resolves a temperature-0 row to the argmax
+    in-program); each row gets the tokens it gets when served alone, the
+    greedy one by the argmax-only program."""
     base = ModelConfig(dtype="float32", max_model_len=256)
     reqs = [
         ("greedy", SamplingParams(max_tokens=6, temperature=0.0,
@@ -271,38 +335,42 @@ def test_fused_mixed_batch_tokens_identical(monkeypatch):
         ("sampled", dataclasses.replace(SAMPLED, seed=77)),
     ]
 
-    def run():
+    def run(batch):
         eng = NativeEngine(base, ECFG, seed=0)
-        toks = {rid: [] for rid, _ in reqs}
-        for rid, p in reqs:
-            eng.add_request(EngineRequest(rid, PROMPT, p))
         try:
-            while eng.has_work():
-                for ev in eng.step():
-                    if ev.token is not None:
-                        toks[ev.request_id].append(ev.token)
-            return toks
+            toks = _run_batch(eng, batch)
+            return toks, {k[3] for k in _window_programs(eng)}
         finally:
             eng.close()
 
-    fused = run()
-    monkeypatch.setattr(sampler_mod.SamplingArrayCache, "fused_eligible",
-                        property(lambda self: False))
-    assert run() == fused
+    together, greedy_bits = run(reqs)
+    assert greedy_bits == {False}
+    for i, (rid, p) in enumerate(reqs):
+        # the batch's i-th prompt: _run_batch shifts a row's prompt by 7 * i
+        eng = NativeEngine(base, ECFG, seed=0)
+        try:
+            alone = eng.generate([t + 7 * i for t in PROMPT], p, rid)
+            assert {k[3] for k in _window_programs(eng)} == {p.temperature
+                                                             <= 0.0}
+        finally:
+            eng.close()
+        assert alone == together[rid], rid
 
 
-def test_fused_flag_is_static_no_recompiles():
-    """Recompile pin (_note_program): the fused bit is part of the staged
-    window's program key and constant for a fixed workload — a second
-    identical request mints ZERO new programs."""
+def test_fixed_sampled_workload_mints_no_program_on_its_second_pass():
+    """Recompile pin (_note_program): a second pass of a fixed sampled
+    workload mints ZERO new programs, and the first minted exactly as
+    many window programs as it has rungs to run."""
     base = ModelConfig(dtype="float32", max_model_len=256)
     eng = NativeEngine(base, ECFG, seed=0)
     try:
         eng.generate(PROMPT, SAMPLED, "a")
         programs = set(eng._seen_programs)
+        assert len(_window_programs(eng)) == len(
+            {k[4] for k in _window_programs(eng)})
         # distinct same-length prompt: prefix-cache reuse would otherwise
         # legitimately shrink request b's prefill chunk (a different
-        # program, but not a fused-bit recompile)
+        # program, but not a sampling-tail recompile)
         eng.generate([t + 100 for t in PROMPT],
                      dataclasses.replace(SAMPLED, seed=99), "b")
         assert eng._seen_programs == programs

@@ -570,6 +570,52 @@ def test_tcp_control_plane_end_to_end():
     run(main())
 
 
+def test_a_request_in_its_handshake_survives_a_collection(monkeypatch):
+    """The control-plane client holds the requests it is handling. The
+    loop holds tasks weakly, and a handler waiting for the handshake of
+    a stream it dialled itself (dataplane.call_home) is reachable from
+    that stream alone, whose protocol holds its reader weakly: a
+    collection in that moment destroyed the task pending ("Task was
+    destroyed but it is pending!") and the caller ran into its ack
+    timeout. test_tcp_control_plane_end_to_end failed so on every tree up
+    to PR 44, whenever the two tests before it had moved the collector's
+    count to that moment; here the collection is made there."""
+    import gc
+
+    from dynamo_tpu.runtime import component, dataplane
+    on_connect = dataplane.DataPlaneServer._on_connect
+
+    async def collect_first(self, reader, writer):
+        # the responder has sent its hello and waits for the answer
+        await asyncio.sleep(0.05)
+        gc.collect()
+        await on_connect(self, reader, writer)
+
+    monkeypatch.setattr(dataplane.DataPlaneServer, "_on_connect",
+                        collect_first)
+    monkeypatch.setattr(component, "DISPATCH_ACK_TIMEOUT_S", 5.0)
+
+    async def main():
+        server = await ControlPlaneServer(port=0).start()
+        try:
+            rt1 = await DistributedRuntime.connect("127.0.0.1", server.port, "w1")
+            rt2 = await DistributedRuntime.connect("127.0.0.1", server.port, "c1")
+            ep = rt1.namespace("ns").component("echo").endpoint("generate")
+            await ep.serve(echo_engine)
+            client = rt2.namespace("ns").component("echo").endpoint(
+                "generate").client()
+            await client.start()
+            await client.wait_for_instances()
+            frames = [f async for f in await client.generate({"n": 2})]
+            assert [f["i"] for f in frames] == [0, 1]
+            await rt1.shutdown()
+            await rt2.shutdown()
+        finally:
+            await server.stop()
+
+    run(main())
+
+
 def test_dataplane_uses_uds_same_host_and_tcp_when_disabled(monkeypatch):
     """SURVEY §2.1 alternative data plane (the reference's ZMQ/IPC
     option): same-host call-home streams ride the requester's advertised

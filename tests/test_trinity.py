@@ -22,7 +22,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dynamo_tpu.engine.config import EngineConfig, RopeParams
+from dynamo_tpu.engine.config import (
+    EngineConfig, RopeParams, refuse_unserved,
+)
 from dynamo_tpu.engine.engine import NativeEngine
 from dynamo_tpu.engine.scheduler import Scheduler
 from dynamo_tpu.models import llama, reference
@@ -392,11 +394,9 @@ def test_what_a_window_pool_is_not_served_with_is_still_refused():
         with pytest.raises(ValueError, match=word):
             NativeEngine(TINY, EngineConfig(**dict(KW, **kw)))
     with pytest.raises(ValueError, match="decode_kernel"):
-        llama.refuse_unserved_window_cache(
-            dataclasses.replace(TINY, decode_kernel="on"))
+        refuse_unserved(dataclasses.replace(TINY, decode_kernel="on"))
     with pytest.raises(ValueError, match="quant"):
-        llama.refuse_unserved_window_cache(
-            dataclasses.replace(TINY, quant="int8"))
+        refuse_unserved(dataclasses.replace(TINY, quant="int8"))
     # a checkpoint is refused: the catalog gives no tensor names
     from dynamo_tpu.models.loader import load_params_from_hf
     with pytest.raises(ValueError, match="no checkpoint mapping"):
@@ -485,7 +485,7 @@ def program_texts(name, rows=8, chunk=16, pages=8, base_pages=8):
     nw = window_ladder(ecfg.decode_steps)[0]
     window = named(functools.partial(
         eng._engine_decode_window, cfg, (), None, nw, ecfg.page_size,
-        False, False, False, False), names[:2])
+        False, False, False), names[:2])
     window_args = (params, cache, vec, vec, arr((rows, pages)),
                    arr((rows, base_pages)), vec, arr((rows,), f32), vec,
                    arr((rows,), f32), vec, vec, vec, arr((rows,), jnp.bool_),

@@ -302,7 +302,7 @@ def build_programs(config_dir: str, rows: int, chunk: int, pages: int,
         eng._named("engine_decode_window_full", with_slots(
             functools.partial(
                 eng._engine_decode_window, cfg, (), kernel_mesh, nw,
-                ecfg.page_size, False, False, False, False),
+                ecfg.page_size, False, False, False),
             ("wtable", "woff") * bool(wsched))),
         donate_argnums=(1,))
     window_args = (params, cache, vec, vec, arr((rows, pages)),

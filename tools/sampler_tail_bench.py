@@ -1,7 +1,7 @@
 """Time the sampler tail on the attached device: `sampler.sample` (the cut
 found by a threshold search, PR 41) against the one-sort tail it replaced
-(kept in tests/test_sampler_tail.py), and `sampler.sample_fused` beside
-`sample` on a batch without top_p.
+(kept in tests/test_sampler_tail.py), and `sample` on a batch without
+top_p (the API's default request).
 
     chiprun -- python3 tools/sampler_tail_bench.py            # the chip
     python3 tools/sampler_tail_bench.py --aot                 # compile only,
@@ -14,10 +14,11 @@ Moonlight; [8, 200192]: Trinity; [8, 98304]: Mellum; [64, 39296]: Ling),
 every row at the traffic's temperature 0.7 over bfloat16-rounded logits:
 one JSON line a reading, milliseconds a call (median of 20 after 3 warm
 calls). Forms `one_sort` and `sample` run at the traffic's top_p 0.95,
-`fused_p1` and `sample_p1` at top_p 1.0 and top_k 50 (ROADMAP D13's
-number); the line after them counts where the new mask and tokens differ
-from the three-sort oracle's and the one-sort tail's (the kept prefix
-may differ in length inside the float64 band the test states). PERF.md
+`sample_p1` at top_p 1.0 and top_k 50 (no cell sends such a batch:
+ROADMAP queue R); the line after them counts where the new mask and
+tokens differ from the three-sort oracle's and the one-sort tail's (the
+kept prefix may differ in length inside the float64 band the test
+states). PERF.md
 section 6, PRs 28 and 41 quote its output. A time comes from the chip
 only: `--aot` proves that the chip's compiler takes each form, counts its
 sorts and gathers, and prints no time.
@@ -58,10 +59,6 @@ def inputs(b, v, top_k=0, top_p=0.95):
             sampler.make_keys(rows + 17, rows * 5))
 
 
-def fused(logits, temperature, top_k, top_p, keys):
-    return sampler.sample_fused(logits, temperature, top_k, keys)
-
-
 def main() -> None:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--aot", action="store_true")
@@ -82,9 +79,7 @@ def main() -> None:
     p1 = {"top_k": 50, "top_p": 1.0}
     forms = (("one_sort", jax.jit(one_sort_sample), {}),
              ("sample", jax.jit(sampler.sample), {}),
-             ("fused_p1", jax.jit(fused), p1),
              ("sample_p1", jax.jit(sampler.sample), p1))
-    fns = {form: fn for form, fn, _ in forms}
     for b, v in SHAPES:
         for form, fn, how in forms:
             xs = inputs(b, v, **how)
@@ -113,7 +108,6 @@ def main() -> None:
             got_keep, got_tok = map(np.asarray, jax.jit(tail)(*xs))
             scaled = xs[0] / xs[1][:, None]
             one_keep = jax.jit(one_sort_keep_mask)(scaled, xs[2], xs[3])
-            xs1 = inputs(b, v, **p1)
             print(json.dumps({
                 "shape": [b, v],
                 "kept_a_row": [int(got_keep.sum(-1).min()),
@@ -124,9 +118,6 @@ def main() -> None:
                     (np.asarray(one_keep) != got_keep).sum()),
                 "token_mismatches": int(
                     (np.asarray(want_tok) != got_tok).sum()),
-                "token_mismatches_fused_p1": int(
-                    (np.asarray(fns["fused_p1"](*xs1))
-                     != np.asarray(fns["sample_p1"](*xs1))).sum()),
             }), flush=True)
 
 
