@@ -167,6 +167,42 @@ class ModelConfig:
     expert_first: int = 0
     moe_n_group: int = 1
     moe_topk_group: int = 1
+    # The parallel hybrid block (Falcon-H1): EVERY layer has a Mamba-2
+    # state-space mixer BESIDE its softmax attention, both reading the one
+    # normed input, their outputs summed before the residual (layer kind
+    # "par", `layer_kinds`). `mamba_d_ssm` > 0 switches it on: the mixer
+    # has `mamba_n_heads` heads of `mamba_d_head` (their product is
+    # `mamba_d_ssm`), `mamba_n_groups` groups of B and C of
+    # `mamba_d_state` each (a head reads group h // (heads / groups)), a
+    # causal depth-wise convolution of `mamba_d_conv` taps WITH a bias
+    # over x | B | C, and a gated grouped RMSNorm, the gate first. Such a
+    # layer holds K / V pages AND a per-sequence state (`state_leaves`):
+    # the [heads, d_head, d_state] float32 matrix and the last
+    # `mamba_d_conv - 1` pre-convolution inputs. `mamba_chunk_size` is the
+    # published kernel's block and is recorded only: the served block is
+    # ops/state_space.BLOCK, which does not change the result.
+    mamba_d_ssm: int = 0
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 128
+    # its muP multipliers, each applied where the published code applies
+    # it (models/llama.py; 1.0 traces nothing): on the attention branch's
+    # input, its keys before RoPE and its output; on the mixer's input,
+    # the five segments z | x | B | C | dt of its input projection
+    # (`ssm_multipliers`) and its output; on the MLP's gate before the
+    # activation and on its output (`mlp_multipliers`); on the logits.
+    # The embeddings' multiplier is `embed_scale`.
+    attention_in_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    ssm_out_multiplier: float = 1.0
+    mlp_multipliers: tuple = (1.0, 1.0)
+    lm_head_multiplier: float = 1.0
     # decode attention impl: "auto" and "off" are the XLA gather path on
     # every platform (models/llama._decode_kernel_mode says why); "on" is
     # the compiled Pallas kernel and raises at engine construction where it
@@ -217,15 +253,31 @@ class ModelConfig:
         return self.kv_lora_rank > 0
 
     @property
-    def has_linear_layers(self) -> bool:
-        return self.linear_group_size > 0
+    def has_ssm(self) -> bool:
+        """The parallel hybrid block: a state-space mixer beside softmax
+        attention in every layer."""
+        return self.mamba_d_ssm > 0
+
+    @property
+    def has_state(self) -> bool:
+        """Holds a recurrent state a sequence (`state_leaves`): linear-
+        attention layers, or a state-space mixer beside attention."""
+        return self.linear_group_size > 0 or self.has_ssm
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels of the mixer's convolution: x | B | C."""
+        return self.mamba_d_ssm \
+            + 2 * self.mamba_n_groups * self.mamba_d_state
 
     def layer_kinds(self) -> tuple:
         """Each layer's attention kind, in order: "kda" | "mla" | "mha" |
         "swa" (a sliding layer served from the window pool; "mha" in all
-        but its cache and its RoPE table). A model without linear layers
-        and without a window pool is one kind throughout."""
-        own = "mla" if self.is_mla else "mha"
+        but its cache and its RoPE table) | "par" (softmax attention AND
+        a state-space mixer side by side: the one kind that lies on the
+        paged cache's layer axis and on the state's). A model without
+        linear layers and without a window pool is one kind throughout."""
+        own = "par" if self.has_ssm else "mla" if self.is_mla else "mha"
         g = self.linear_group_size
         if self.window_pool:
             return tuple("swa" if s else own for s in self.sliding_layers())
@@ -236,7 +288,8 @@ class ModelConfig:
     def num_cache_layers(self) -> int:
         """Layers that hold pages of the FULL pool: every page of their
         sequence's context (all but the linear and the window layers)."""
-        return sum(kind in ("mha", "mla") for kind in self.layer_kinds())
+        return sum(kind in ("mha", "mla", "par")
+                   for kind in self.layer_kinds())
 
     @property
     def num_window_layers(self) -> int:
@@ -245,7 +298,7 @@ class ModelConfig:
 
     @property
     def num_state_layers(self) -> int:
-        return sum(kind == "kda" for kind in self.layer_kinds())
+        return sum(kind in ("kda", "par") for kind in self.layer_kinds())
 
     @property
     def local_experts(self) -> int:
@@ -255,11 +308,19 @@ class ModelConfig:
     def state_leaves(self) -> dict:
         """THE description of the per-sequence recurrent state, beside
         `kv_cache_leaves`: leaf -> (shape a slot and layer, dtype), each
-        stored [state layers, slots, ...]. Empty for a model without
-        linear layers. `kda_s` is the delta rule's matrix, float32
-        whatever the model's dtype; `kda_conv` the last conv_size - 1
-        inputs of the q | k | v convolution."""
-        if not self.has_linear_layers:
+        stored [state layers, slots, ...], by the kind that holds one.
+        Empty for a model without a state. `kda_s` is the delta rule's
+        matrix, float32 whatever the model's dtype; `kda_conv` the last
+        conv_size - 1 inputs of the q | k | v convolution. The parallel
+        block's: `ssm_s`, the mixer's [heads, d_head, d_state] matrix,
+        float32 likewise, and `ssm_conv`, the last d_conv - 1 inputs of
+        the x | B | C convolution."""
+        if self.has_ssm:
+            return {"ssm_s": ((self.mamba_n_heads, self.mamba_d_head,
+                               self.mamba_d_state), "float32"),
+                    "ssm_conv": ((self.mamba_d_conv - 1,
+                                  self.mamba_conv_dim), self.dtype)}
+        if not self.has_state:
             return {}
         h, d = self.num_heads, self.linear_head_dim
         return {"kda_s": ((h, d, d), "float32"),
@@ -267,7 +328,8 @@ class ModelConfig:
                              self.dtype)}
 
     def state_bytes_per_slot(self) -> int:
-        """Bytes one sequence's state holds, all linear layers."""
+        """Bytes one sequence's state holds, all the layers that keep
+        one."""
         total = 0
         for shape, dtype in self.state_leaves().values():
             n = 1
@@ -512,7 +574,9 @@ class EngineConfig:
 # Each consumer moves, shares, shards, packs or rolls back a sequence's
 # context as K and V pages of Hkv heads in ONE pool. A recurrent state has
 # no page to go with (the state after another sequence's tokens exists
-# nowhere; a rejected draft's update cannot be undone); a latent cache is
+# nowhere; a rejected draft's update cannot be undone), whether its layers
+# hold no pages (linear attention) or plain K / V pages beside it (the
+# parallel block: the pages alone are half a sequence); a latent cache is
 # one leaf of one head; a window pool is a second page list that forgets.
 # Prefix reuse is not a row: the scheduler switches it off for a state and
 # for a window pool, and says so once in the log. A fourth store is one
@@ -553,15 +617,15 @@ def refuse_unserved(model_cfg: ModelConfig,
     reached. The stores are asked in turn (state, latent, window) and the
     first with a reason raises, under its own opening sentence."""
     cfg = model_cfg
-    if not (cfg.has_linear_layers or cfg.is_mla or cfg.window_pool):
+    if not (cfg.has_state or cfg.is_mla or cfg.window_pool):
         return      # the page movers ask on every call
     ecfg = engine_cfg or EngineConfig()
     kv_quant = cfg.kv_quant or ecfg.kv_quant
     # consumer -> how this call names it ("": not asked for)
     asked = {
         "feature": feature,
-        "another store": "latent or linear attention beside the window "
-        "layers" * (cfg.is_mla or cfg.has_linear_layers),
+        "another store": "a latent cache or a recurrent state beside the "
+        "window layers" * (cfg.is_mla or cfg.has_state),
         "mesh": f"a {dict(mesh.shape)} mesh (--tp/--pp/--ep/--sp/--dp)"
         if mesh is not None and mesh.size > 1 else "",
         "kv_quant": f"kv_quant={kv_quant!r}" * bool(kv_quant),
@@ -575,9 +639,11 @@ def refuse_unserved(model_cfg: ModelConfig,
         "spec_decode": f"spec_decode={ecfg.spec_decode!r}"
         * bool(ecfg.spec_decode),
     }
+    keeper = "a state-space mixer beside attention keeps" if cfg.has_ssm \
+        else "linear-attention layers keep"
     stores = (
-        (cfg.has_linear_layers,
-         f"linear-attention layers keep a recurrent state a sequence "
+        (cfg.has_state,
+         f"{keeper} a recurrent state a sequence "
          f"({cfg.state_bytes_per_slot()} bytes)"),
         (cfg.is_mla,
          f"latent attention keeps ONE cache leaf of width "

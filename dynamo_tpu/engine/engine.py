@@ -180,12 +180,13 @@ class NativeEngine:
                                         scale_shape=(page_shape[:-1]
                                                      if self.kv_quant
                                                      else None))
-        # a model with linear-attention layers keeps a recurrent state a
-        # sequence: one slot a decode slot, and one a row of a prefill
-        # batch (a sequence has pages from its first chunk and a decode
-        # slot only at its last)
+        # a model with linear-attention layers, or with a state-space
+        # mixer beside its attention, keeps a recurrent state a sequence:
+        # one slot a decode slot, and one a row of a prefill batch (a
+        # sequence has pages from its first chunk and a decode slot only
+        # at its last)
         self._state_slots = 0
-        if model_cfg.has_linear_layers:
+        if model_cfg.has_state:
             self._state_slots = engine_cfg.max_slots \
                 + max(1, engine_cfg.max_prefill_batch)
         # a model whose sliding layers keep a page pool of their own:
@@ -1099,7 +1100,8 @@ class NativeEngine:
         if self._state_slots:
             small += (plan.state_slots,)
             real = (plan.write_idx >= 0).sum(axis=1)
-            splits = llama.kda_mix_splits(*plan.write_idx.shape)
+            splits = llama.mix_splits(self.model_cfg,
+                                      *plan.write_idx.shape)
             self._account_linattn(
                 int(real.sum()), int((plan.state_slots >= 0).sum()),
                 inplace=int((real == 1).sum()) if splits else 0,
@@ -1134,15 +1136,18 @@ class NativeEngine:
     def _account_linattn(self, tokens: int, rows: int,
                          window_steps: int = 0, inplace: int = 0,
                          flat: bool = False) -> None:
-        """`llm_engine_linattn_*_total`, from a step's plan on the host:
-        the (token, linear layer) state updates of its real tokens,
+        """`llm_engine_linattn_*_total`, the series of a recurrent state
+        whatever layer keeps it (linear attention; the parallel block's
+        state-space mixer), from a step's plan on the host:
+        the (token, state layer) state updates of its real tokens,
         which of them rode an `_engine_step` (`window_steps` 0; the
         chunkwise form's, and the one-token rows beside them), which
-        were made where the state rests (`kda_step_slots`: every token
-        of a decode window, and the `inplace` one-token rows of a step
-        that `llama.kda_mix_splits`), whether such a step's linear
-        layers worked over a compact step's `flat` token rows
-        (`llama.kda_mix_rows` where `_dense_rows` is the flat width; else
+        were made where the state rests (`kda_step_slots`,
+        `ssd_step_slots`: every token of a decode window, and the
+        `inplace` one-token rows of a step that `llama.mix_splits`),
+        whether such a step's state layers worked over a compact step's
+        `flat` token rows (`llama.kda_mix_rows`, `ssm_mix_rows` where
+        `_dense_rows` is the flat width; else
         over the grid's), the state bytes its live `rows`
         read and wrote (every touched slot's state, once each way, a
         linear layer and a step), and the device steps that is over. A

@@ -3,7 +3,9 @@ DeepSeek-V3's latent attention, sigmoid router, shared experts and dense
 lead: `_mla_front`, `_mlp_block`, `layer_groups`; and afmoe's head-wise
 QK-norm, output gate, RoPE-less full layers and dense lead in front of a
 window pool's period loop: `qkv_proj`, `_out_gate`, `rope_table`,
-`layer_period`).
+`layer_period`; and Falcon-H1's parallel block, a Mamba-2 state-space mixer
+beside softmax attention in every layer, with its muP multipliers:
+`_ssm_front`, `ssm_decode`, `ssm_mix_rows`, `_times`).
 
 Functional JAX, TPU-first:
 - parameters are a pytree of arrays **stacked over layers** and the layer loop
@@ -46,6 +48,7 @@ from dynamo_tpu.ops.linear_attention import (
     conv_one_token, conv_with_tail, kda_chunk, kda_step_slots, l2_normalize,
 )
 from dynamo_tpu.ops.kv_quant import validate_mode as _validate_kv_quant
+from dynamo_tpu.ops.state_space import ssd_chunk, ssd_step_slots
 from dynamo_tpu.ops.moe import (
     moe_dispatch_mlp, moe_dispatch_mlp_sharded, moe_dropless_mlp, route,
 )
@@ -140,11 +143,13 @@ class LayerRun(NamedTuple):
     first: int        # the run's first layer, in the model's order
     count: int
     dense: bool       # a dense MLP (False: experts)
-    kind: str         # attention kind: "mha" | "mla" | "kda" | "swa"
+    kind: str         # attention kind: "mha" | "mla" | "kda" | "swa" | "par"
     # the run's first layer among the layers that share its STORE: the
     # paged cache's layer axis runs over the "mha" / "mla" layers, the
     # recurrent state's over the "kda" ones, the window pool's over the
-    # "swa" ones (== first where all alike)
+    # "swa" ones (== first where all alike). A "par" layer lies on the
+    # cache's axis AND on the state's: such a model is one run, in which
+    # both are the layer's own index
     store_first: int
 
     @property
@@ -183,8 +188,8 @@ def layer_runs(cfg: ModelConfig) -> tuple:
     param_shardings, forward(), decode_forward() and models/loader.py
     all walk this."""
     lead = cfg.first_dense_layers if cfg.is_moe else 0
-    own = "mla" if cfg.is_mla else "mha"
     kinds = cfg.layer_kinds()
+    own = "par" if cfg.has_ssm else "mla" if cfg.is_mla else "mha"
     seen = dict.fromkeys(kinds, 0)
 
     def like_runs(kinds, prefix):
@@ -308,9 +313,16 @@ def _init_layer_stack(keys, cfg: ModelConfig, l: int, dense_mlp: bool,
     more = jax.random.split(jax.random.fold_in(keys[0], 31), 6)
     if kind == "kda":
         hd = cfg.linear_head_dim
+    # a leaf behind a muP multiplier (the parallel block's; 1.0 elsewhere)
+    # is drawn that much LARGER, so that the function the seeded weights
+    # give is the one they would give with every multiplier at 1: with
+    # the published multipliers on fan-in-scaled draws the logits would
+    # be 1/128 of a nat apart and no comparison could see the model
+    m_gate, m_down = cfg.mlp_multipliers
     layers = {
         "attn_norm": jnp.ones((l, d), dt),
-        "wo": dense(keys[3], (l, h * hd, d), h * hd),
+        "wo": dense(keys[3], (l, h * hd, d),
+                    h * hd * cfg.attention_out_multiplier ** 2),
         "mlp_norm": jnp.ones((l, d), dt),
     }
     if kind == "kda":
@@ -359,9 +371,13 @@ def _init_layer_stack(keys, cfg: ModelConfig, l: int, dense_mlp: bool,
     else:
         layers.update({
             "wq": dense(keys[0], (l, d, h * hd), d),
-            "wk": dense(keys[1], (l, d, hkv * hd), d),
+            "wk": dense(keys[1], (l, d, hkv * hd),
+                        d * cfg.key_multiplier ** 2),
             "wv": dense(keys[2], (l, d, hkv * hd), d),
         })
+        if kind == "par":
+            layers.update(_init_ssm_leaves(
+                jax.random.fold_in(keys[0], 91), cfg, l, near_one))
         if cfg.attn_out_gate:
             layers["w_out_gate"] = dense(jax.random.fold_in(more[5], 5),
                                          (l, d, h * hd), d)
@@ -407,11 +423,11 @@ def _init_layer_stack(keys, cfg: ModelConfig, l: int, dense_mlp: bool,
             })
     else:
         layers.update({
-            "w_gate": dense(keys[5], (l, d, f), d),
+            "w_gate": dense(keys[5], (l, d, f), d * m_gate ** 2),
             "w_up": dense(keys[6], (l, d, f), d),
-            "w_down": dense(keys[7], (l, f, d), f),
+            "w_down": dense(keys[7], (l, f, d), f * m_down ** 2),
         })
-    if cfg.has_linear_layers or cfg.window_pool:
+    if cfg.has_state or cfg.window_pool:
         # a hybrid's block norms too are drawn away from one
         layers["attn_norm"] = near_one(jax.random.fold_in(more[5], 3), (l, d))
         layers["mlp_norm"] = near_one(jax.random.fold_in(more[5], 4), (l, d))
@@ -421,6 +437,55 @@ def _init_layer_stack(keys, cfg: ModelConfig, l: int, dense_mlp: bool,
             layers["post_mlp_norm"] = near_one(
                 jax.random.fold_in(more[5], 7), (l, d))
     return layers
+
+
+def ssm_segments(cfg: ModelConfig) -> np.ndarray:
+    """`ssm_multipliers` spread over the columns of the mixer's input
+    projection, z | x | B | C | dt: the constant vector the published
+    code calls `mup_vector`, float32 on the host."""
+    gn = cfg.mamba_n_groups * cfg.mamba_d_state
+    return np.repeat(np.asarray(cfg.ssm_multipliers, np.float32),
+                     [cfg.mamba_d_ssm, cfg.mamba_d_ssm, gn, gn,
+                      cfg.mamba_n_heads])
+
+
+def _init_ssm_leaves(key, cfg: ModelConfig, l: int, near_one) -> Params:
+    """The state-space mixer's leaves of a "par" stack of `l` layers,
+    seeded (`_init_layer_stack`). `ssm_in` [D, z | x | B | C | dt]: each
+    segment's columns drawn 1 / (ssm_in_multiplier x its entry of
+    ssm_multipliers) larger, the dt columns at half of that besides (the
+    time step should follow its bias more than the token). A_log = log(1
+    .. H) and D = 1 as published. dt_bias: each head's memory 1 / (dt A)
+    is drawn log-uniform over 3 .. 300 tokens, dt_bias = softplus^-1 of
+    that dt, so the decays span a few to a few hundred tokens: a state
+    that forgot within a token would make the mixer a function of the
+    current token alone, and one that never forgot would show no decay.
+    The convolution's bias and the gated norm's weight are drawn away
+    from their neutral values."""
+    dt, f32 = _dtype(cfg), jnp.float32
+    d, ds, h = cfg.hidden_size, cfg.mamba_d_ssm, cfg.mamba_n_heads
+    kk = jax.random.split(key, 6)
+    seg = ssm_segments(cfg) * cfg.ssm_in_multiplier
+    seg[-h:] *= 2.0
+    a = np.arange(1, h + 1, dtype=np.float32)
+    tau = jnp.exp(jax.random.uniform(kk[3], (l, h), f32, math.log(3.0),
+                                     math.log(300.0)))
+    dt0 = 1.0 / (tau * a)
+    return {
+        "ssm_in": (jax.random.normal(kk[0], (l, d, seg.size), f32)
+                   * d ** -0.5 / seg).astype(dt),
+        "ssm_conv_w": (jax.random.normal(
+            kk[1], (l, cfg.mamba_d_conv, cfg.mamba_conv_dim), f32)
+            * cfg.mamba_d_conv ** -0.5).astype(dt),
+        "ssm_conv_b": (0.1 * jax.random.normal(
+            kk[2], (l, cfg.mamba_conv_dim), f32)).astype(dt),
+        "ssm_a_log": jnp.broadcast_to(jnp.log(a), (l, h)),
+        "ssm_d": jnp.ones((l, h), f32),
+        "ssm_dt_bias": jnp.log(jnp.expm1(dt0)),
+        "ssm_norm": near_one(kk[4], (l, ds)),
+        "ssm_out": (jax.random.normal(kk[5], (l, ds, d), f32)
+                    * ds ** -0.5 / cfg.ssm_out_multiplier).astype(dt),
+    }
 
 
 def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
@@ -443,11 +508,13 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
             else jax.random.split(jax.random.fold_in(rng, 1 + run.first),
                                   12),
             cfg, run.count, run.dense, run.kind)
-    if cfg.has_linear_layers or cfg.window_pool:
+    if cfg.has_state or cfg.window_pool:
         params["final_norm"] = (1.0 + 0.1 * jax.random.normal(
             jax.random.fold_in(rng, 77), (d,), jnp.float32)).astype(dt)
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = dense(keys[9], (d, cfg.vocab_size), d)
+        # behind its multiplier, like a "par" layer's leaves
+        params["lm_head"] = dense(keys[9], (d, cfg.vocab_size),
+                                  d * cfg.lm_head_multiplier ** 2)
     if cfg.vision is not None:
         from dynamo_tpu.models import vision
         params["vision"] = vision.init_params(keys[10], cfg)
@@ -509,6 +576,13 @@ def _layer_stack_shardings(cfg: ModelConfig, dense_mlp: bool,
             layers["w_attn_gate"] = P(None, None, None)
     else:
         layers.update({"wk": P(None, None, "tp"), "wv": P(None, None, "tp")})
+        if kind == "par":
+            # no mesh serves a recurrent state: the mixer is replicated
+            layers.update({name: P(None, None, None) for name in (
+                "ssm_in", "ssm_conv_w", "ssm_out")})
+            layers.update({name: P(None, None) for name in (
+                "ssm_conv_b", "ssm_a_log", "ssm_d", "ssm_dt_bias",
+                "ssm_norm")})
         if cfg.attn_out_gate:
             layers["w_out_gate"] = P(None, None, "tp")
     if cfg.post_norms:
@@ -678,11 +752,24 @@ def _lead_scope(run: LayerRun):
         else contextlib.nullcontext()
 
 
-def _full_scope(cfg: ModelConfig):
+def _full_scope(cfg: ModelConfig, kind: str = ""):
     """`attention.full` around a full layer's attention where the model
-    also has window layers (`attention.window`); no scope elsewhere."""
+    also has window layers (`attention.window`); `attention` inside
+    `block.parallel` where a state-space mixer stands beside it (`kind`
+    "par"); no scope elsewhere."""
+    if kind == "par":
+        return jax.named_scope("block.parallel/attention")
     return jax.named_scope("attention.full") if cfg.window_pool \
         else contextlib.nullcontext()
+
+
+def _times(x: jax.Array, m: float) -> jax.Array:
+    """x times a muP multiplier of the configuration, the product formed
+    in float32 and rounded once to x's dtype, as the published code's
+    tensor-times-scalar does; 1.0 traces nothing."""
+    if m == 1.0:
+        return x
+    return (x.astype(jnp.float32) * m).astype(x.dtype)
 
 
 def rope_table(cfg: ModelConfig, kind: str = "") -> tuple:
@@ -761,10 +848,11 @@ def _moe_mlp(x: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
 def _dense_mlp(x: jax.Array, lp: Params, cfg: ModelConfig,
                leaves: tuple = ("w_gate", "w_up", "w_down")) -> jax.Array:
     w_gate, w_up, w_down = (wmat(lp[name], x.dtype) for name in leaves)
-    gate = jnp.einsum("btd,df->btf", x, w_gate)
+    m_gate, m_down = cfg.mlp_multipliers   # the gate's before the SiLU
+    gate = _times(jnp.einsum("btd,df->btf", x, w_gate), m_gate)
     up = jnp.einsum("btd,df->btf", x, w_up)
     act = mlp_activation(gate, cfg) * up
-    return jnp.einsum("btf,fd->btd", act, w_down)
+    return _times(jnp.einsum("btf,fd->btd", act, w_down), m_down)
 
 
 def qkv_proj(xn: jax.Array, lp: Params, cfg: ModelConfig):
@@ -775,7 +863,8 @@ def qkv_proj(xn: jax.Array, lp: Params, cfg: ModelConfig):
     values, one weight vector shared by the heads. What reaches the
     cache is the normed (and, where the kind rotates, rotated) k."""
     q = jnp.einsum("btd,de->bte", xn, wmat(lp["wq"], xn.dtype))
-    k = jnp.einsum("btd,de->bte", xn, wmat(lp["wk"], xn.dtype))
+    k = _times(jnp.einsum("btd,de->bte", xn, wmat(lp["wk"], xn.dtype)),
+               cfg.key_multiplier)
     v = jnp.einsum("btd,de->bte", xn, wmat(lp["wv"], xn.dtype))
     if cfg.attn_bias:
         q, k, v = q + lp["wq_b"], k + lp["wk_b"], v + lp["wv_b"]
@@ -881,14 +970,18 @@ def layer_front(x: jax.Array, lp: Params, cfg: ModelConfig,
     and v None: the attention ops take the whole row as its values and
     `_mla_out` keeps the latent columns of what they return. A linear
     layer (`kind` "kda", `_kda_front`) hands back what its state update
-    takes instead: the convolution's inputs, the decay and beta."""
+    takes instead: the convolution's inputs, the decay and beta. A
+    parallel block (`kind` "par") takes this path for its attention
+    branch (its multipliers on the normed input and on k); its mixer
+    reads the same input on its own (`_ssm_front`)."""
     if kind == "kda":
         return _kda_front(x, lp, cfg)
     if cfg.is_mla:
         return _mla_front(x, lp, cfg, positions)
     b, t = x.shape[:2]
     h, hkv = heads
-    xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
+    xn = _times(rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps,
+                         cfg.norm_plus_one), cfg.attention_in_multiplier)
     q, k, v = qkv_proj(xn, lp, cfg)
     rope = rope_table(cfg, kind)
 
@@ -1104,6 +1197,27 @@ def kda_rows(valid: jax.Array, start: jax.Array) -> KdaRows:
                    jnp.sum(n_valid > 1).astype(jnp.int32))
 
 
+def _chunk_group(rows: KdaRows, j, group: int, long_at, n_slots: int,
+                 valid, keep):
+    """Group `j` of a split step's chunk rows, `group` rows of
+    `rows.order`: (at [group] their slots, out of range for a row that is
+    dead here; live [group]; valid_g [group, T] their real tokens; keep_g
+    [group] not fresh; cells [group, T] their token rows). Past the last
+    row (the last group's fill), and a row of one token or none: read
+    clipped, every write dropped."""
+    b, tq = valid.shape
+    r = rows.order.at[j * group + jnp.arange(group)].get(
+        mode="fill", fill_value=b)
+    at = long_at.at[r].get(mode="fill", fill_value=-1)
+    live = at >= 0
+    at = jnp.where(live, at, n_slots)
+    valid_g = valid.at[r].get(mode="clip") & live[:, None]
+    keep_g = keep.at[r].get(mode="clip")
+    cells = rows.start.at[r].get(mode="clip")[:, None] \
+        + jnp.arange(tq, dtype=jnp.int32)[None, :]
+    return at, live, valid_g, keep_g, cells
+
+
 def kda_mix_rows(state: tuple, lk, slots: jax.Array, lp: Params,
                  cfg: ModelConfig, x, rows: KdaRows, valid, fresh,
                  group: int = KDA_GROUP_ROWS):
@@ -1128,7 +1242,7 @@ def kda_mix_rows(state: tuple, lk, slots: jax.Array, lp: Params,
     row of padding as in `kda_mix`, which is what the tests hold this
     to. Returns (state, with o's real rows written)."""
     kda_s, kda_conv, o = state
-    b, tq = valid.shape
+    tq = valid.shape[1]
     n, n_slots = x.shape[0], kda_s.shape[1]
     keep = ~fresh
     one = rows.n_valid == 1
@@ -1143,17 +1257,8 @@ def kda_mix_rows(state: tuple, lk, slots: jax.Array, lp: Params,
 
     def chunk_group(j, carry):
         o, kda_s, kda_conv = carry
-        # past the last row (the last group's fill), and a row of one
-        # token or none: read clipped, every write dropped
-        r = rows.order.at[j * group + jnp.arange(group)].get(
-            mode="fill", fill_value=b)
-        at = long_at.at[r].get(mode="fill", fill_value=-1)
-        live = at >= 0
-        at = jnp.where(live, at, n_slots)
-        valid_g = valid.at[r].get(mode="clip") & live[:, None]
-        keep_g = keep.at[r].get(mode="clip")
-        cells = rows.start.at[r].get(mode="clip")[:, None] \
-            + jnp.arange(tq, dtype=jnp.int32)[None, :]
+        at, _, valid_g, keep_g, cells = _chunk_group(
+            rows, j, group, long_at, n_slots, valid, keep)
         pre, g, beta = _kda_front(x.at[cells].get(mode="clip"), lp, cfg)
         with jax.named_scope("linattn.conv"):
             tail = kda_conv.at[lk, at].get(mode="clip")
@@ -1217,8 +1322,175 @@ def _kda_out(o: jax.Array, x: jax.Array, lp: Params,
         return (o * gate).astype(x.dtype)
 
 
+# -- the parallel block's state-space mixer -----------------------------------
+
+def mix_splits(cfg: ModelConfig, rows: int, tq: int) -> bool:
+    """Whether a [rows, tq] step's state layers work over its rows by what
+    each holds: the state-space mixer always (`ssm_mix_rows` is its one
+    form), the linear layers where `kda_mix_splits`."""
+    return cfg.has_ssm or kda_mix_splits(rows, tq)
+
+
+def _ssm_front(x: jax.Array, lp: Params, cfg: ModelConfig):
+    """The mixer's token-wise front half: x [B, T, D] -> (z [B, T, d_ssm]
+    the gate, xbc [B, T, d_ssm + 2 G N] x | B | C BEFORE their
+    convolution, dt [B, T, H] before its bias and softplus), in the
+    model's dtype: the block's norm, the input multiplier, `ssm_in`, and
+    the constant vector that multiplies the five segments z | x | B | C |
+    dt by `ssm_multipliers`."""
+    xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
+    ds, conv = cfg.mamba_d_ssm, cfg.mamba_conv_dim
+    with jax.named_scope("block.parallel/ssm.in_proj"):
+        p = jnp.einsum("btd,de->bte", _times(xn, cfg.ssm_in_multiplier),
+                       wmat(lp["ssm_in"], xn.dtype))
+        if any(m != 1.0 for m in cfg.ssm_multipliers):
+            p = (p.astype(jnp.float32) * ssm_segments(cfg)).astype(p.dtype)
+    return p[..., :ds], p[..., ds:ds + conv], p[..., ds + conv:]
+
+
+def _ssm_inputs(y: jax.Array, dt: jax.Array, lp: Params, cfg: ModelConfig):
+    """The convolution's output y [..., d_ssm + 2 G N] float32 (bias
+    added) and the raw dt [..., H] -> what the scan takes, float32: x
+    [..., H, P], B, C [..., G, N] after the SiLU, dt = softplus(dt +
+    dt_bias), A = -exp(A_log) [H], D [H]."""
+    f32 = jnp.float32
+    h, g, n = cfg.mamba_n_heads, cfg.mamba_n_groups, cfg.mamba_d_state
+    ds = cfg.mamba_d_ssm
+    y = jax.nn.silu(y)
+    lead = y.shape[:-1]
+    return (y[..., :ds].reshape(lead + (h, cfg.mamba_d_head)),
+            y[..., ds:ds + g * n].reshape(lead + (g, n)),
+            y[..., ds + g * n:].reshape(lead + (g, n)),
+            jax.nn.softplus(dt.astype(f32) + lp["ssm_dt_bias"].astype(f32)),
+            -jnp.exp(lp["ssm_a_log"].astype(f32)), lp["ssm_d"].astype(f32))
+
+
+def _ssm_out(y: jax.Array, z: jax.Array, lp: Params,
+             cfg: ModelConfig) -> jax.Array:
+    """The mixer's back half before `ssm_out`: y [..., H, P] float32, z
+    [..., d_ssm] the gate -> the gated grouped RMSNorm, the gate FIRST
+    (`mamba_norm_before_gate` false): y * SiLU(z), the variance over each
+    of the G groups of d_ssm / G, one weight of d_ssm; in z's dtype."""
+    with jax.named_scope("block.parallel/ssm.norm_out"):
+        f32 = jnp.float32
+        y = y.reshape(z.shape).astype(f32) * jax.nn.silu(z.astype(f32))
+        by_group = y.shape[:-1] + (cfg.mamba_n_groups, -1)
+        y = y.reshape(by_group)
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                              + cfg.rms_norm_eps)
+        return (y.reshape(z.shape) * lp["ssm_norm"].astype(f32)
+                ).astype(z.dtype)
+
+
+def ssm_decode(state: tuple, l, slots: jax.Array, lp: Params,
+               cfg: ModelConfig, z, xbc, dt, valid, fresh=None):
+    """The mixer for one token a row (a decode step's rows, a mixed
+    step's one-token rows), between `_ssm_front` and `ssm_out`, every row
+    updated where its state rests (`ssd_step_slots`). state: (ssm_s [L,
+    slots + 1, H, P, N], ssm_conv [L, slots + 1, K - 1, d_ssm + 2 G N]),
+    `l` this layer's index in them; z [B, d_ssm], xbc [B, d_ssm + 2 G N],
+    dt [B, H]; valid [B]: rows that are live (a finished or padding row,
+    or one another form takes, is a dead row to the kernel and writes
+    nothing); fresh [B]: the row starts its sequence, from zeros whatever
+    its slot held (a decode step has none). Returns (state, o [B, d_ssm]
+    in the model's dtype, past the gated norm)."""
+    ssm_s, ssm_conv = state
+    slots = jnp.where(valid, slots, -1)
+    at = _slot_index(slots, ssm_conv.shape[1])
+    with jax.named_scope("block.parallel/ssm.conv"):
+        tail = ssm_conv.at[l, at].get(mode="clip")
+        if fresh is not None:
+            tail = jnp.where(fresh[:, None, None], 0, tail)
+        y, tail = conv_one_token(xbc, tail, lp["ssm_conv_w"],
+                                 lp["ssm_conv_b"])
+        x, b, c, dt, a, d = _ssm_inputs(y, dt, lp, cfg)
+        ssm_conv = ssm_conv.at[l, at].set(tail, mode="drop")
+    with jax.named_scope("block.parallel/ssm.step"):
+        y, ssm_s = ssd_step_slots(ssm_s, l, slots, x, dt, a, b, c, d, fresh)
+    return (ssm_s, ssm_conv), _ssm_out(y, z, lp, cfg)
+
+
+def ssm_mix_rows(state: tuple, l, slots: jax.Array, lp: Params,
+                 cfg: ModelConfig, x, rows: KdaRows, valid, fresh,
+                 group: int = KDA_GROUP_ROWS):
+    """The mixer from the block's norm to the input of `ssm_out` for a
+    [B, T] step, over the step's ROWS by what each holds, never over its
+    grid: `kda_mix_rows` for the state-space scan, and the mixer's ONE
+    form for a step (at any number of rows). x [B * T, D]: the step's
+    token rows in either layout (`kda_rows`); state: (ssm_s, ssm_conv, o
+    [B * T, d_ssm] in the model's dtype), `o` a scratch the layers
+    share: each real token's row is overwritten, no other row is read.
+
+    A row of ONE token (a decode row, a one-token chunk): its token is
+    token row `start`; the front half over [B, D], then what a decode
+    window's step does (`ssm_decode`). A row of more (a chunk row):
+    `group` of them at a time, for as many groups as the step holds; the
+    front half, the convolution (continued from the slot's tail) and
+    `ssd_chunk` run over [group, T, ...] alone, padding at dt = 0 (an
+    exact no-op on the state); each row's state and tail are gathered
+    and scattered once. Each kind is dead to the other. `fresh` [B]: the
+    row starts its sequence (position 0), so it starts from zeros
+    whatever the slot held: a reused slot needs no clearing. A row
+    without a slot, and a row of padding, write nothing. Returns (state,
+    with o's real rows written)."""
+    ssm_s, ssm_conv, o = state
+    tq = valid.shape[1]
+    n, n_slots = x.shape[0], ssm_s.shape[1]
+    keep = ~fresh
+    one = rows.n_valid == 1
+    z, xbc, dt = _ssm_front(
+        x.at[rows.start].get(mode="clip")[:, None], lp, cfg)
+    (ssm_s, ssm_conv), o1 = ssm_decode(
+        (ssm_s, ssm_conv), l, slots, lp, cfg, z[:, 0], xbc[:, 0], dt[:, 0],
+        one, fresh)
+    o = o.at[jnp.where(one, rows.start, n)].set(o1, mode="drop")
+    long_at = jnp.where(rows.n_valid > 1, _slot_index(slots, n_slots), -1)
+
+    def chunk_group(j, carry):
+        o, ssm_s, ssm_conv = carry
+        at, live, valid_g, keep_g, cells = _chunk_group(
+            rows, j, group, long_at, n_slots, valid, keep)
+        z, xbc, dt = _ssm_front(x.at[cells].get(mode="clip"), lp, cfg)
+        with jax.named_scope("block.parallel/ssm.conv"):
+            tail = ssm_conv.at[l, at].get(mode="clip")
+            y, tail = conv_with_tail(
+                xbc, jnp.where(keep_g[:, None, None], tail, 0),
+                lp["ssm_conv_w"],
+                jnp.sum(valid_g, axis=1).astype(jnp.int32),
+                lp["ssm_conv_b"])
+            xs, bs, cs, dt, a, d = _ssm_inputs(y, dt, lp, cfg)
+            ssm_conv = ssm_conv.at[l, at].set(
+                tail.astype(ssm_conv.dtype), mode="drop")
+        with jax.named_scope("block.parallel/ssm.chunk"):
+            # a row's state by a slice at (layer, slot), never a gather
+            # over the leaf: XLA:TPU splits a gather's 256-wide rows into
+            # two 128-lane halves by slicing the WHOLE leaf first (1.7
+            # GB copied a layer and step, a third of a mixed step: PERF.md
+            # section 6, PR 45). A dead row's slot is out of range: the
+            # slice clamps to the leaf's last, scratch slot, whose
+            # content a dead row (dt = 0) hands back as it found it
+            where = [(l, at[i], 0, 0, 0) for i in range(group)]
+            s0 = jnp.concatenate([jax.lax.dynamic_slice(
+                ssm_s, w, (1, 1) + ssm_s.shape[2:])[0] for w in where])
+            s0 = jnp.where((keep_g | ~live)[:, None, None, None], s0, 0.0)
+            y_g, s1 = ssd_chunk(
+                xs, jnp.where(valid_g[:, :, None], dt, 0.0), a, bs, cs, d,
+                s0)
+            for i, w in enumerate(where):
+                ssm_s = jax.lax.dynamic_update_slice(
+                    ssm_s, s1[i][None, None], w)
+        o_g = _ssm_out(y_g, z, lp, cfg)
+        o = o.at[jnp.where(valid_g, cells, n).reshape(-1)].set(
+            o_g.reshape((group * tq,) + o_g.shape[2:]), mode="drop")
+        return o, ssm_s, ssm_conv
+
+    o, ssm_s, ssm_conv = jax.lax.fori_loop(
+        0, -(-rows.n_long // group), chunk_group, (o, ssm_s, ssm_conv))
+    return ssm_s, ssm_conv, o
+
+
 def layer_back(x: jax.Array, attn: jax.Array, lp: Params, cfg: ModelConfig,
-               mlp, reduce=None, kind: str = ""):
+               mlp, reduce=None, kind: str = "", ssm=None):
 
     """(x [B, T, D], attn [B, T, ...heads]) -> (next x, the MLP's stats):
     output projection, residual, MLP norm, `mlp(xn, lp)` -> (out, stats),
@@ -1227,7 +1499,10 @@ def layer_back(x: jax.Array, attn: jax.Array, lp: Params, cfg: ModelConfig,
     comes BEFORE the post-norm, which is nonlinear and must see the whole
     output, not a shard's partial sum. Latent attention hands over the
     weighted latents; their value projection (`_mla_out`) comes first.
-    Softmax attention's output gate (`_out_gate`) sits before `wo`."""
+    Softmax attention's output gate (`_out_gate`) sits before `wo`.
+    `ssm` (a parallel block, `kind` "par"): the mixer's output past its
+    gated norm [B, T, d_ssm]; its projection `ssm_out` is ADDED to
+    attention's, each times its multiplier, before the one residual."""
     b, t = x.shape[:2]
     if kind == "kda":
         attn = _kda_out(attn, x, lp, cfg)
@@ -1239,6 +1514,10 @@ def layer_back(x: jax.Array, attn: jax.Array, lp: Params, cfg: ModelConfig,
         attn = _out_gate(attn.reshape(b, t, -1), x, lp, cfg)
     out = jnp.einsum("bte,ed->btd", attn.reshape(b, t, -1),
                      wmat(lp["wo"], x.dtype))
+    if kind == "par":
+        out = _times(out, cfg.attention_out_multiplier) + _times(
+            jnp.einsum("bte,ed->btd", ssm, wmat(lp["ssm_out"], x.dtype)),
+            cfg.ssm_out_multiplier)
     if reduce is not None:
         out = reduce(out)
     if cfg.post_norms:
@@ -1268,6 +1547,14 @@ def lm_logits(x: jax.Array, final_norm: jax.Array, head: jax.Array,
     final soft-cap. Takes the two arrays, not `params`: a pp stage holds
     stage-local ones."""
     x = rms_norm(x, final_norm, cfg.rms_norm_eps, cfg.norm_plus_one)
+    if cfg.lm_head_multiplier != 1.0:
+        # the multiplier meets the products' float32 sums, not their
+        # rounding to the model's dtype: one rounding fewer (an 8-bit
+        # mantissa on a logit of 4 is a step of 1/32 nat) for 33 MB more
+        # written a step at [64, 261120]
+        logits = jnp.einsum("...d,dv->...v", x, wmat(head, x.dtype),
+                            preferred_element_type=jnp.float32)
+        return _softcap(logits * cfg.lm_head_multiplier, cfg.final_softcap)
     logits = jnp.einsum("...d,dv->...v", x, wmat(head, x.dtype))
     return _softcap(logits.astype(jnp.float32), cfg.final_softcap)
 
@@ -1284,7 +1571,7 @@ def decode_forward(
     mesh=None,
     with_aux: bool = False,
     window: Optional[tuple] = None,  # split-KV window fast path, see below
-    state: Optional[tuple] = None,   # ((kda_s, kda_conv), slots [B])
+    state: Optional[tuple] = None,   # (the state's leaves, slots [B])
     swa: Optional[tuple] = None,     # the window layers' split-KV view
 ) -> tuple:
     """Deferred-write decode step: the KV cache is READ-ONLY.
@@ -1300,11 +1587,14 @@ def decode_forward(
     layers' new rows come back LAST, as (wk_new, wv_new [Lw, B, Hkv,
     hd]): k_new / v_new cover the layers of the full pool.
 
-    `state` (a model with linear-attention layers): the recurrent state's
-    leaves and each row's slot in them. Unlike the cache it is WRITTEN
-    here, a layer at a time (`kda_decode`), and handed back as the last
+    `state` (a model with linear-attention layers, or with a state-space
+    mixer beside its attention): the recurrent state's leaves and each
+    row's slot in them. Unlike the cache it is WRITTEN here, a layer at a
+    time (`kda_decode`, `ssm_decode`), and handed back as the last
     element of the result: the caller carries it to the next step. The
-    new-row stacks k_new / v_new then cover the layers that HAVE a cache.
+    new-row stacks k_new / v_new then cover the layers that HAVE a cache
+    (a parallel block has both: its layer emits its rows AND carries the
+    state).
 
     Returns (last_logits [B, V] f32, k_new [L, B, Hkv, hd],
     v_new [L, B, Hkv, hd], aux) — the caller scatters the new kv rows into
@@ -1375,7 +1665,18 @@ def decode_forward(
                 lid - run.first, run.dense), kind="kda")
         return (x, st), drop_stats if moe_aux else None
 
-    def layer_step(x, xs, run, expert_stacks, stack=None):
+    def par_step_layer(carry, xs, run, expert_stacks):
+        """A parallel block: the mixer's state update from the block's
+        input, then `layer_step`, which adds its output to attention's."""
+        x, st = carry
+        lp, lid = xs[:2]
+        z, xbc, dt = _ssm_front(x, lp, cfg)
+        st, o = ssm_decode(st, run.store_index(lid), state[1], lp, cfg,
+                           z[:, 0], xbc[:, 0], dt[:, 0], row_valid)
+        x, out = layer_step(x, xs, run, expert_stacks, ssm=o[:, None])
+        return (x, st), out
+
+    def layer_step(x, xs, run, expert_stacks, stack=None, ssm=None):
         lp, lid, wnd, win = xs
         first, dense = run.first, run.dense
         if lp is None:
@@ -1406,7 +1707,7 @@ def decode_forward(
             kb, vb, kw, vw = win if whole else tuple(
                 None if a is None else jax.lax.dynamic_index_in_dim(
                     a, cl, keepdims=False) for a in win_leaves)
-            with _full_scope(cfg):
+            with _full_scope(cfg, run.kind):
                 attn = decode_attention_split(
                     q[:, 0], kb, vb, kw, vw, k_new, v_new, base_lens,
                     win_lens, softcap=cfg.attn_softcap, window=wnd,
@@ -1447,7 +1748,8 @@ def decode_forward(
         x, drop_stats = layer_back(
             x, attn, lp, cfg, lambda xn, lp: _mlp_block(
                 xn, lp, cfg, mesh, token_valid, expert_stacks,
-                lid if whole else lid - first, dense))
+                lid if whole else lid - first, dense),
+            **({} if ssm is None else dict(kind=run.kind, ssm=ssm)))
         return x, (k_new, v_new, drop_stats if moe_aux else None)
 
     k_news, v_news, drops = [], [], []
@@ -1471,11 +1773,17 @@ def decode_forward(
         xs = (scan_layers, part(layer_ids),
               None if layer_wnd is None else part(layer_wnd),
               win_leaves if window is not None and whole else None)
-        with _lead_scope(run):
-            x, (k_g, v_g, drop_g) = jax.lax.scan(
-                functools.partial(layer_step, run=run,
+        if run.kind == "par":
+            (x, st), (k_g, v_g, drop_g) = jax.lax.scan(
+                functools.partial(par_step_layer, run=run,
                                   expert_stacks=expert_stacks),
-                x, xs)
+                (x, st), xs)
+        else:
+            with _lead_scope(run):
+                x, (k_g, v_g, drop_g) = jax.lax.scan(
+                    functools.partial(layer_step, run=run,
+                                      expert_stacks=expert_stacks),
+                    x, xs)
         (wk_news if run.kind == "swa" else k_news).append(k_g)
         (wv_news if run.kind == "swa" else v_news).append(v_g)
         drops.append(_sum_stats(drop_g))
@@ -1721,7 +2029,7 @@ def forward(
     # a step whose linear layers work over its rows (`kda_mix_rows`): a
     # row's tokens are contiguous token rows in both layouts
     kda_plan = None
-    if cfg.has_linear_layers and kda_mix_splits(b, tq):
+    if cfg.has_state and mix_splits(cfg, b, tq):
         row0 = jnp.arange(b, dtype=jnp.int32) * tq
         kda_plan = kda_rows(grid_valid, row0 if sel is None else jnp.where(
             fits, sel.slot[row0], row0))
@@ -1776,8 +2084,9 @@ def forward(
                 return to_grid(q), to_grid(k), to_grid(v)
             return to_grid(q), unflat(k), None if v is None else unflat(v)
 
-        def back(sel, x, attn, stored=False):
-            # stored: attn is [B * Tq, ...] token rows in x's own layout
+        def back(sel, x, attn, ssm=None, stored=False):
+            # stored: attn is [B * Tq, ...] token rows in x's own layout,
+            # as a parallel block's `ssm` (its mixer's output) always is
             block = functools.partial(
                 _mlp_block, cfg=cfg, mesh=mesh, stacks=expert_stacks,
                 lid=lid if whole else lid - first, dense=dense)
@@ -1787,7 +2096,8 @@ def forward(
                 return layer_back(
                     x, attn, lp_of(), cfg, lambda xn, lp: block(
                         xn, lp, token_valid=grid_valid if moe_aux else None),
-                    kind=kind)
+                    kind=kind, ssm=None if ssm is None
+                    else ssm.reshape(b, tq, -1))
 
             def mlp(xn, lp):
                 if grid_mlp and not dense:
@@ -1798,7 +2108,8 @@ def forward(
                              if moe_aux else None)
             x, stats = layer_back(
                 flat(x), attn[None, :width] if stored else from_grid(attn),
-                lp_of(), cfg, mlp, kind=kind)
+                lp_of(), cfg, mlp, kind=kind,
+                ssm=None if ssm is None else ssm[None, :width])
             return unflat(x), stats
 
         if kind == "kda" and kda_plan is not None:
@@ -1812,6 +2123,15 @@ def forward(
             x, drop_stats = either(functools.partial(back, stored=True),
                                    x, state[2])
             return (x, pool, state, wpool), drop_stats
+        if kind == "par":
+            # a parallel block's mixer, whole, over the step's rows, from
+            # the block's input: no [B, Tq] tensor of its width, and the
+            # state leaves in no `cond`. Its output rides state[2] to the
+            # block's one `back`, behind the attention below
+            state = ssm_mix_rows(
+                state, sl, meta.state_slots, lp_of(), cfg,
+                x.reshape(n, -1), kda_plan, grid_valid,
+                meta.positions[:, 0] == 0)
         q, k, v = either(front, x)
         if kind == "kda":
             # the grid's part of a linear layer: each row's convolution
@@ -1863,14 +2183,15 @@ def forward(
         else:
             # the one op that needs the grid: every query beside its row's
             # page table, against the pool just written
-            with _full_scope(cfg):
+            with _full_scope(cfg, kind):
                 attn = paged_attention(
                     q, kc, vc, meta.page_table, meta.kv_lens,
                     meta.positions, softcap=cfg.attn_softcap, window=wnd,
                     q_scale=cfg.query_scale, k_scale=ksc, v_scale=vsc,
                     layer=sl)
 
-        x, drop_stats = either(back, x, attn)
+        x, drop_stats = either(back, x, attn, state[2]) if kind == "par" \
+            else either(back, x, attn)
         return (x, pool, state, wpool), drop_stats
 
     # the stacked leaves ride the scan's carry whole, in the stored
@@ -1883,8 +2204,10 @@ def forward(
     wpool_keys = tuple(cfg.window_cache_leaves())
     wpool = tuple(cache[key] for key in wpool_keys)
     if kda_plan is not None:
-        # the scratch that carries a linear layer's o to its back half
-        state += (jnp.zeros((n, cfg.num_heads, cfg.linear_head_dim),
+        # the scratch that carries a state layer's o to its back half
+        state += (jnp.zeros((n, cfg.mamba_d_ssm), _dtype(cfg))
+                  if cfg.has_ssm else
+                  jnp.zeros((n, cfg.num_heads, cfg.linear_head_dim),
                             jnp.float32),)
     drops = []
     period = layer_period(cfg)

@@ -33,6 +33,7 @@ ARCHES = {
     "BailingHybridForCausalLM": "bailing_hybrid",
     "MellumForCausalLM": "mellum",
     "AfmoeForCausalLM": "afmoe",
+    "FalconH1ForCausalLM": "falcon_h1",
 }
 # the families whose sliding layers are served as WINDOWS, from a page
 # pool of their own (`window_pool`): their serving length is not capped
@@ -53,7 +54,8 @@ def config_from_hf(hf: Dict[str, Any], name: str = "") -> ModelConfig:
     own = {"deepseek_v3": deepseek_v3_fields,
            "bailing_hybrid": bailing_hybrid_fields,
            "mellum": mellum_fields,
-           "afmoe": afmoe_fields}.get(
+           "afmoe": afmoe_fields,
+           "falcon_h1": falcon_h1_fields}.get(
                family, lambda hf: {})(hf)
     if hf.get("clip_qkv") is not None:
         # OLMoE's optional clamp of q/k/v to +-clip_qkv is not modeled:
@@ -409,6 +411,56 @@ def afmoe_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
         moe_routed_scale=float(hf.get("route_scale", 1.0)))
 
 
+def falcon_h1_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """The ModelConfig fields of a `falcon_h1` config.json (Falcon-H1):
+    every block a Mamba-2 mixer BESIDE grouped-query attention on the one
+    normed input (`mamba_d_ssm` > 0: layer kind "par"), a dense SwiGLU
+    behind it, plain RoPE at `rope_theta`, and muP multipliers on every
+    branch, each kept as the field of its name (`embedding_multiplier` is
+    `embed_scale`). What is not modelled is refused here, by key."""
+    refuse = _refuser(hf)
+    for key in ("attention_bias", "mamba_proj_bias", "mlp_bias",
+                "projectors_bias", "mamba_norm_before_gate"):
+        refuse(key, lambda v: not v, f"{key}: false")
+    refuse("mamba_rms_norm", lambda v: v in (None, True),
+           "the gated RMSNorm after the scan")
+    refuse("mamba_conv_bias", lambda v: v in (None, True),
+           "a bias on the mixer's convolution")
+    refuse("mamba_use_mlp", lambda v: v in (None, True),
+           "an MLP in every block")
+    refuse("attn_layer_indices", lambda v: v is None,
+           "attention in every block")
+    refuse("hidden_act", lambda v: v in (None, "silu"), "SiLU")
+    heads, d_head = int(hf["mamba_n_heads"]), int(hf["mamba_d_head"])
+    d_ssm = hf.get("mamba_d_ssm")
+    if d_ssm is None:
+        d_ssm = int(hf["mamba_expand"] * hf["hidden_size"])
+    if heads * d_head != d_ssm or heads % int(hf["mamba_n_groups"]):
+        raise ValueError(
+            f"mamba_n_heads {heads} x mamba_d_head {d_head} must be "
+            f"mamba_d_ssm {d_ssm}, the heads a multiple of mamba_n_groups "
+            f"{hf['mamba_n_groups']}")
+    return dict(
+        mamba_d_ssm=int(d_ssm), mamba_n_heads=heads, mamba_d_head=d_head,
+        mamba_n_groups=int(hf["mamba_n_groups"]),
+        mamba_d_state=int(hf["mamba_d_state"]),
+        mamba_d_conv=int(hf.get("mamba_d_conv", 4)),
+        mamba_chunk_size=int(hf.get("mamba_chunk_size", 128)),
+        embed_scale=float(hf.get("embedding_multiplier", 1.0)),
+        lm_head_multiplier=float(hf.get("lm_head_multiplier", 1.0)),
+        attention_in_multiplier=float(
+            hf.get("attention_in_multiplier", 1.0)),
+        key_multiplier=float(hf.get("key_multiplier", 1.0)),
+        attention_out_multiplier=float(
+            hf.get("attention_out_multiplier", 1.0)),
+        ssm_in_multiplier=float(hf.get("ssm_in_multiplier", 1.0)),
+        ssm_out_multiplier=float(hf.get("ssm_out_multiplier", 1.0)),
+        ssm_multipliers=tuple(
+            float(m) for m in hf.get("ssm_multipliers") or (1.0,) * 5),
+        mlp_multipliers=tuple(
+            float(m) for m in hf.get("mlp_multipliers") or (1.0, 1.0)))
+
+
 def rope_params(entry: Dict[str, Any], hf: Dict[str, Any]):
     """One entry of `rope_parameters` -> RopeParams. Plain RoPE
     ("default") and YaRN are modelled; longrope, llama3, linear and
@@ -482,8 +534,17 @@ def load_params_from_hf(path: str, cfg: ModelConfig,
       .mlp.shared_experts.{gate,up,down}_proj.weight.T -> ws_gate/up/down
       model.norm.weight                  -> final_norm
       lm_head.weight.T                   -> lm_head (absent when tied)
+    FalconH1 (`transformers`' own names; the attention leaves as above):
+      .pre_ff_layernorm / model.final_layernorm -> mlp_norm[i] / final_norm
+      .feed_forward.{gate,up,down}_proj.weight.T -> w_gate/w_up/w_down[i]
+      .mamba.in_proj.weight.T            -> ssm_in[i]  (z | x | B | C | dt)
+      .mamba.conv1d.weight [C, 1, K] / .bias -> ssm_conv_w[i] [K, C] /
+                                            ssm_conv_b[i]
+      .mamba.{A_log,D,dt_bias}           -> ssm_a_log / ssm_d /
+                                            ssm_dt_bias[i], float32
+      .mamba.norm.weight / .out_proj.weight.T -> ssm_norm[i] / ssm_out[i]
     """
-    if cfg.has_linear_layers or cfg.window_pool:
+    if cfg.linear_group_size or cfg.window_pool:
         # the catalog gives these families' configs and no tensor names:
         # a guessed mapping would serve another function under its name
         raise ValueError(
@@ -508,6 +569,16 @@ def load_params_from_hf(path: str, cfg: ModelConfig,
         return _load_deepseek_v3(raw, cfg, t, w)
 
     fused_qkv = "model.layers.0.self_attn.qkv_proj.weight" in raw  # Phi-3
+    # transformers' `falcon_h1` layout, told by the file's own tensor names
+    # as Phi-3's and OLMoE's are, not by the mechanism: another model with
+    # a state-space mixer brings its own names, and is refused until
+    # they are written here
+    falcon_h1 = "model.layers.0.mamba.in_proj.weight" in raw
+    if cfg.has_ssm and not falcon_h1:
+        raise ValueError(
+            f"{cfg.name}: a state-space mixer whose tensors are not named "
+            f"as transformers' falcon_h1 names them (model.layers.N.mamba."
+            f"in_proj.weight): no checkpoint mapping for it")
     qo, ko = cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim
 
     def qkv(i, part):  # split Phi-3's fused [q|k|v, in] rows, then transpose
@@ -532,8 +603,26 @@ def load_params_from_hf(path: str, cfg: ModelConfig,
         "mlp_norm": stack(
             lambda i: w(f"model.layers.{i}.pre_feedforward_layernorm.weight"
                         if cfg.post_norms else
+                        f"model.layers.{i}.pre_ff_layernorm.weight"
+                        if falcon_h1 else
                         f"model.layers.{i}.post_attention_layernorm.weight")),
     }
+    mlp = "feed_forward" if falcon_h1 else "mlp"
+    if falcon_h1:
+        mamba = "model.layers.{}.mamba."
+        layers.update({
+            "ssm_in": stack(lambda i: t(mamba.format(i) + "in_proj.weight")),
+            "ssm_conv_w": stack(lambda i: np.asarray(
+                raw[mamba.format(i) + "conv1d.weight"][:, 0, :].T, dtype=dt)),
+            "ssm_conv_b": stack(lambda i: w(mamba.format(i) + "conv1d.bias")),
+            "ssm_norm": stack(lambda i: w(mamba.format(i) + "norm.weight")),
+            "ssm_out": stack(
+                lambda i: t(mamba.format(i) + "out_proj.weight")),
+        })
+        for ours, theirs in (("ssm_a_log", "A_log"), ("ssm_d", "D"),
+                             ("ssm_dt_bias", "dt_bias")):
+            layers[ours] = stack(lambda i, p=theirs: np.asarray(
+                raw[mamba.format(i) + p], np.float32))
     if cfg.post_norms:
         layers["post_attn_norm"] = stack(
             lambda i: w(f"model.layers.{i}.post_attention_layernorm.weight"))
@@ -578,16 +667,17 @@ def load_params_from_hf(path: str, cfg: ModelConfig,
             lambda i: t(f"model.layers.{i}.mlp.down_proj.weight"))
     else:
         layers["w_gate"] = stack(
-            lambda i: t(f"model.layers.{i}.mlp.gate_proj.weight"))
+            lambda i: t(f"model.layers.{i}.{mlp}.gate_proj.weight"))
         layers["w_up"] = stack(
-            lambda i: t(f"model.layers.{i}.mlp.up_proj.weight"))
+            lambda i: t(f"model.layers.{i}.{mlp}.up_proj.weight"))
         layers["w_down"] = stack(
-            lambda i: t(f"model.layers.{i}.mlp.down_proj.weight"))
+            lambda i: t(f"model.layers.{i}.{mlp}.down_proj.weight"))
 
     params: Dict[str, Any] = {
         "embed": w("model.embed_tokens.weight"),
         "layers": layers,
-        "final_norm": w("model.norm.weight"),
+        "final_norm": w("model.final_layernorm.weight" if falcon_h1
+                        else "model.norm.weight"),
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = t("lm_head.weight")

@@ -162,6 +162,46 @@ of these lines). RMSNorm eps 1e-5, final norm, untied head, no biases.
               `moe_intermediate_size` on every token. `n_group` 1: no
               group limit.
 
+And from the row `Falcon-H1-34B-Instruct` of the architecture catalog
+(`model_type` falcon_h1), read against `transformers`' own
+`modeling_falcon_h1.py` (4.57.6, its non-kernel path; tests/
+test_falcon_h1.py holds this file to it on a tiny configuration with every
+multiplier active; benchmark/reference/falcon_h1.py is the benchmark's
+copy of these lines). RMSNorm eps 1e-5, `final_layernorm`, untied head,
+no bias on any projection. With h the residual stream:
+
+  model       h0 = E[ids] * `embedding_multiplier` (`embed_scale`); the
+              blocks; logits = (RMSNorm(h) W_head) * `lm_head_multiplier`.
+  block       u = RMSNorm(h; attn_norm). TWO branches read the same u and
+              are ADDED (`parallel_block`): h = h + `ssm_out_multiplier`
+              SSM(u) + `attention_out_multiplier` Attn(
+              `attention_in_multiplier` u); then h = h + MLP(RMSNorm(h;
+              mlp_norm)).
+  Attn        `attention` above, no QK-norm, with k = (u Wk) *
+              `key_multiplier` BEFORE RoPE (plain, theta 1e11).
+  SSM         (`mixer_ssm`, Mamba-2 as the per-token recurrence) p =
+              ((`ssm_in_multiplier` u) W_in) * mup, W_in to z | x | B | C
+              | dt (d_ssm | d_ssm | G N | G N | H), mup the constant
+              vector that multiplies those five segments by
+              `ssm_multipliers`[0..4]. x | B | C pass a depth-wise causal
+              convolution of `mamba_d_conv` taps WITH bias, then SiLU. x
+              -> [H, P] heads, B, C -> [G, N] groups (head h reads group
+              h // (H / G)). dt_h = softplus(dt_h + dt_bias_h), A_h =
+              -exp(A_log_h); per head S [P, N]: S_t = exp(dt_t A) S_{t-1}
+              + dt_t x_t B_t^T, y_t = S_t C_t + D_h x_t. Then the gated
+              grouped norm, the gate FIRST (`mamba_norm_before_gate`
+              false): y = RMSNorm_groups(y * SiLU(z)), the variance over
+              each of the G groups of d_ssm / G, one weight of d_ssm;
+              W_out.
+  the served form  (models/llama._ssm_front / ssm_mix_rows / ssm_decode,
+              ops/state_space.py, the same function): a prompt chunk's
+              tokens at once by the quadratic form inside blocks of 64
+              with the cumulative-decay mask, the state passed between
+              blocks and kept in a per-sequence slot beside the K / V
+              pages the same layer holds; one token a row in place.
+  MLP         down(up(x) * SiLU(gate(x) * `mlp_multipliers`[0])) *
+              `mlp_multipliers`[1] (`dense_mlp`'s `multipliers`).
+
 Departures from the published model, each deliberate:
   * weights are taken in this repo's layout: projections stored
     [in, out] (the checkpoint's are [out, in]; models/loader.py
@@ -245,13 +285,15 @@ def rope(x, positions, theta, yarn=None):
 
 
 def attention(x, lp, *, num_heads, num_kv_heads, head_dim, rope_theta,
-              rms_norm_eps, qk_norm, window=0, yarn=None):
+              rms_norm_eps, qk_norm, window=0, yarn=None,
+              key_multiplier=1.0):
     """`window` > 0: a query at p sees keys j with p - window < j <= p.
     `qk_norm` True: over the whole projection; "head": over each head,
     one weight vector for all. `rope_theta` None: no rotation. A
-    `w_out_gate` leaf: the output times sigmoid(x Wg) before Wo."""
+    `w_out_gate` leaf: the output times sigmoid(x Wg) before Wo.
+    `key_multiplier` scales k before RoPE."""
     t = x.shape[0]
-    q, k, v = x @ lp["wq"], x @ lp["wk"], x @ lp["wv"]
+    q, k, v = x @ lp["wq"], (x @ lp["wk"]) * key_multiplier, x @ lp["wv"]
     if "wq_b" in lp:
         q, k, v = q + lp["wq_b"], k + lp["wk_b"], v + lp["wv_b"]
     if qk_norm is True:              # over the whole projection, pre-split
@@ -376,9 +418,68 @@ def attention_kda(x, lp, *, num_heads, head_dim, lower_bound, rms_norm_eps,
     return o.reshape(t, h * d) @ lp["wo"]
 
 
-def dense_mlp(x, lp, names=("w_gate", "w_up", "w_down")):
+def ssm_recurrence(consts, s, xs, state_dtype=F32):
+    """One token of the state-space scan: (consts (A, D [H]), s [H, P,
+    N], (x [H, P], dt [H], b, c [H, N])) -> (s', y [H, P]): decay, the
+    input weighed by dt along B, read along C, the skip."""
+    a, d = consts
+    x_t, dt_t, b_t, c_t = xs
+    s = jnp.exp(dt_t * a)[:, None, None] * s \
+        + (dt_t[:, None] * x_t)[:, :, None] * b_t[:, None, :]
+    s = round_to(s, state_dtype)
+    return s, jnp.einsum("hpn,hn->hp", s, c_t) + d[:, None] * x_t
+
+
+def mixer_ssm(x, lp, *, n_heads, d_head, n_groups, d_state, rms_norm_eps,
+              in_multiplier=1.0, multipliers=(1.0,) * 5, state_dtype=F32):
+    """The Mamba-2 mixer as the per-token recurrence. x [T, D], the
+    normed input. `state_dtype`: what S is rounded to after every token
+    (float32: not at all)."""
+    t, h, g, n = x.shape[0], n_heads, n_groups, d_state
+    ds = h * d_head
+    sizes = [ds, ds, g * n, g * n, h]
+    mup = jnp.concatenate([jnp.full((size,), m, F32)
+                           for size, m in zip(sizes, multipliers)])
+    p = ((x * in_multiplier) @ lp["ssm_in"]) * mup
+    z, xbc, dt = p[:, :ds], p[:, ds:-h], p[:, -h:]
+    xbc = jax.nn.silu(causal_conv(xbc, lp["ssm_conv_w"]) + lp["ssm_conv_b"])
+    xs = xbc[:, :ds].reshape(t, h, d_head)
+    # a head reads its group's B and C
+    b, c = (jnp.repeat(v.reshape(t, g, n), h // g, axis=1)
+            for v in (xbc[:, ds:ds + g * n], xbc[:, ds + g * n:]))
+    dt = jax.nn.softplus(dt + lp["ssm_dt_bias"])
+    _, y = jax.lax.scan(
+        functools.partial(ssm_recurrence,
+                          (-jnp.exp(lp["ssm_a_log"]), lp["ssm_d"]),
+                          state_dtype=state_dtype),
+        jnp.zeros((h, d_head, n), F32), (xs, dt, b, c))
+    y = y.reshape(t, ds) * jax.nn.silu(z)                  # the gate first
+    y = y.reshape(t, g, ds // g)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                          + rms_norm_eps)
+    return (y.reshape(t, ds) * lp["ssm_norm"]) @ lp["ssm_out"]
+
+
+def parallel_block(x, lp, *, attn, ssm, attention_in_multiplier=1.0,
+                   attention_out_multiplier=1.0, ssm_out_multiplier=1.0,
+                   state_dtype=F32, without_ssm=False):
+    """Both mixers of a parallel block on the one normed input x [T, D],
+    their outputs added, each times its multiplier. `attn`, `ssm`: the
+    keyword arguments of `attention` and `mixer_ssm`. `without_ssm`
+    leaves the state-space branch out (a control of the comparison)."""
+    out = attention_out_multiplier * attention(
+        x * attention_in_multiplier, lp, **attn)
+    if without_ssm:
+        return out
+    return out + ssm_out_multiplier * mixer_ssm(
+        x, lp, state_dtype=state_dtype, **ssm)
+
+
+def dense_mlp(x, lp, names=("w_gate", "w_up", "w_down"),
+              multipliers=(1.0, 1.0)):
     gate, up, down = (lp[name] for name in names)
-    return (jax.nn.silu(x @ gate) * (x @ up)) @ down
+    return ((jax.nn.silu((x @ gate) * multipliers[0]) * (x @ up)) @ down) \
+        * multipliers[1]
 
 
 def group_limited(pick, n_group, topk_group):
@@ -434,18 +535,30 @@ def layer(x, lp, *, num_heads, num_kv_heads, head_dim, rope_theta,
           rms_norm_eps, qk_norm=False, num_experts=0,
           num_experts_per_tok=0, norm_topk_prob=True, mla=None,
           moe_scoring="softmax", moe_routed_scale=1.0, kda=None,
-          n_group=1, topk_group=1, expert_first=0, window=0, yarn=None):
+          n_group=1, topk_group=1, expert_first=0, window=0, yarn=None,
+          par=None, mlp_multipliers=(1.0, 1.0)):
     """One pre-norm residual block. x: [T, D]; lp: this layer's weights,
     float32. `mla`: attention_mla's sizes (a dict) for latent attention;
     `kda`: attention_kda's, for a layer that has its leaves. A layer
     without a `router` leaf has a dense MLP. `window`, `yarn`: THIS
     layer's sliding width and RoPE scaling (`layer_kind_kwargs`). A
     layer with `post_attn_norm` / `post_mlp_norm` leaves norms each
-    half's output before the residual (four norms a block)."""
+    half's output before the residual (four norms a block). `par`:
+    `parallel_block`'s arguments after `attn`, for a layer with a
+    state-space mixer beside its attention (`key_multiplier` among
+    them, which is attention's)."""
     def post(out, name):
         return rms_norm(out, lp[name], rms_norm_eps) if name in lp else out
     xn = rms_norm(x, lp["attn_norm"], rms_norm_eps)
-    if "kda_wqkv" in lp:
+    if "ssm_in" in lp:
+        par = dict(par)
+        out = parallel_block(
+            xn, lp, attn=dict(
+                num_heads=num_heads, num_kv_heads=num_kv_heads,
+                head_dim=head_dim, rope_theta=rope_theta,
+                rms_norm_eps=rms_norm_eps, qk_norm=qk_norm,
+                key_multiplier=par.pop("key_multiplier")), **par)
+    elif "kda_wqkv" in lp:
         out = attention_kda(xn, lp, num_heads=num_heads,
                             rms_norm_eps=rms_norm_eps, **kda)
     elif mla:
@@ -470,7 +583,7 @@ def layer(x, lp, *, num_heads, num_kv_heads, head_dim, rope_theta,
                          **(dict(n_group=n_group, topk_group=topk_group)
                             if n_group > 1 else {}))
     else:
-        out = dense_mlp(xn, lp)
+        out = dense_mlp(xn, lp, multipliers=mlp_multipliers)
     return x + post(out, "post_mlp_norm")
 
 
@@ -488,6 +601,22 @@ def arch_kwargs(cfg) -> dict:
                        rope_sliding=dataclasses.asdict(cfg.rope_sliding))
     if cfg.embed_scale:     # beside them: `forward` takes it out again
         by_kind["embed_scale"] = cfg.embed_scale
+    if cfg.has_ssm:
+        by_kind.update(
+            lm_head_multiplier=cfg.lm_head_multiplier,
+            mlp_multipliers=tuple(cfg.mlp_multipliers),
+            par=dict(
+                key_multiplier=cfg.key_multiplier,
+                attention_in_multiplier=cfg.attention_in_multiplier,
+                attention_out_multiplier=cfg.attention_out_multiplier,
+                ssm_out_multiplier=cfg.ssm_out_multiplier,
+                ssm=dict(n_heads=cfg.mamba_n_heads,
+                         d_head=cfg.mamba_d_head,
+                         n_groups=cfg.mamba_n_groups,
+                         d_state=cfg.mamba_d_state,
+                         rms_norm_eps=cfg.rms_norm_eps,
+                         in_multiplier=cfg.ssm_in_multiplier,
+                         multipliers=tuple(cfg.ssm_multipliers))))
     return dict(num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
                 head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
                 rms_norm_eps=cfg.rms_norm_eps, qk_norm=cfg.qk_norm,
@@ -498,7 +627,7 @@ def arch_kwargs(cfg) -> dict:
                 moe_routed_scale=cfg.moe_routed_scale,
                 kda=dict(head_dim=cfg.linear_head_dim,
                          lower_bound=cfg.linear_gate_lower_bound)
-                if cfg.has_linear_layers else None,
+                if cfg.linear_group_size else None,
                 n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group,
                 expert_first=cfg.expert_first, **by_kind)
 
@@ -566,6 +695,7 @@ def forward(params, tokens, **arch):
         "layer_types", "sliding_window", "rope_full", "rope_sliding")
         if k in arch}
     embed_scale = arch.pop("embed_scale", 0.0)
+    lm_head_multiplier = arch.pop("lm_head_multiplier", 1.0)
     with jax.default_matmul_precision("highest"):
         params = jax.tree.map(lambda a: jnp.asarray(a, F32), params)
         # ids the engine served  # dynalint: disable-next-line=R1
@@ -579,4 +709,4 @@ def forward(params, tokens, **arch):
         x = rms_norm(x, params["final_norm"], arch["rms_norm_eps"])
         head = params["lm_head"] if "lm_head" in params \
             else params["embed"].T
-        return x @ head
+        return (x @ head) * lm_head_multiplier

@@ -65,11 +65,12 @@ def l2_normalize(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
 
-def conv_with_tail(pre, tail, w, n_valid):
+def conv_with_tail(pre, tail, w, n_valid, bias=None):
     """The causal depthwise convolution of a chunk that continues a
     sequence. pre [B, T, C]: the chunk's inputs; tail [B, K - 1, C]: the
     K - 1 inputs before it (zeros at a sequence's start); w [K, C];
-    n_valid [B]: the row's real tokens, a prefix of the T. Returns (y
+    n_valid [B]: the row's real tokens, a prefix of the T; bias [C], for
+    a convolution that has one (the state-space mixer's). Returns (y
     [B, T, C] float32, the next tail [B, K - 1, C]: the last K - 1 of
     tail | pre[:n_valid], so a row with no real token keeps its own)."""
     k = w.shape[0]
@@ -77,18 +78,22 @@ def conv_with_tail(pre, tail, w, n_valid):
     xp = jnp.concatenate([tail.astype(pre.dtype), pre], axis=1)
     wf = w.astype(F32)
     y = sum(wf[j] * xp[:, j:j + t].astype(F32) for j in range(k))
+    if bias is not None:
+        y = y + bias.astype(F32)
     at = n_valid[:, None] + jnp.arange(k - 1, dtype=n_valid.dtype)[None, :]
     return y, jnp.take_along_axis(xp, at[:, :, None], axis=1)
 
 
-def conv_one_token(pre, tail, w):
+def conv_one_token(pre, tail, w, bias=None):
     """`conv_with_tail` for ONE token a row, the one definition every
     one-token form shares (a decode window's step, a mixed step's decode
-    rows): pre [B, C], tail [B, K - 1, C], w [K, C] -> (y [B, C] float32,
-    the next tail [B, K - 1, C] in the tail's dtype). One tap window a
-    row: K * C values, whatever the step's grid."""
+    rows): pre [B, C], tail [B, K - 1, C], w [K, C], bias [C] or None ->
+    (y [B, C] float32, the next tail [B, K - 1, C] in the tail's dtype).
+    One tap window a row: K * C values, whatever the step's grid."""
     xp = jnp.concatenate([tail, pre[:, None].astype(tail.dtype)], axis=1)
     y = jnp.sum(w.astype(F32)[None] * xp.astype(F32), axis=1)
+    if bias is not None:
+        y = y + bias.astype(F32)
     return y, xp[:, 1:]
 
 

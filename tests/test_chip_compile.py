@@ -119,6 +119,50 @@ def test_the_state_update_compiles_for_a_v5e_and_moves_no_copy_of_the_leaf(
     assert _STATE_MOVES.findall(_state_layers_hlo(one_chip, False)) != []
 
 
+# falcon-h1-34b's state leaf at 6 blocks and 68 slots (64 + 3 + scratch)
+_SSM_STATE = (6, 68, 32, 128, 256)
+_SSM_MOVES = re.compile(
+    r"=\s*f32\[(?:6,68|64),32,128,256\]\S*\s+"
+    r"(copy|fusion|gather|scatter|dynamic-slice|dynamic-update-slice)\(")
+
+
+def _ssm_layers_hlo(one_chip, impl: str) -> str:
+    """Optimised HLO of a scan over the six blocks' one-token state
+    updates of a 64-row decode step that carries the state leaf, as
+    `decode_forward` does: `ssd_step_slots` by the slot-addressed kernel
+    ("pallas"), or by `ssd_step` on gathered rows ("plain")."""
+    from dynamo_tpu.ops import state_space as ss
+
+    def arr(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    l, _, h, p, n = _SSM_STATE
+
+    def layers(leaf, slots, x, dt, a, b, c, d):
+        def body(leaf, at):
+            y, leaf = ss.ssd_step_slots(leaf, at, slots, x, dt, a, b, c, d,
+                                        impl=impl)
+            return leaf, y
+        return jax.lax.scan(body, leaf, jnp.arange(l))
+    return jax.jit(layers, donate_argnums=0).lower(
+        arr(*_SSM_STATE), arr(64, dtype=jnp.int32), arr(64, h, p),
+        arr(64, h), arr(h), arr(64, 2, n), arr(64, 2, n), arr(h)
+    ).compile().as_text()
+
+
+def test_the_ssd_state_update_compiles_for_a_v5e_and_moves_no_copy_of_the_leaf(
+        one_chip):
+    """`ops/state_space.ssd_step_slots` at falcon-h1-34b's served shape is
+    taken by the chip's compiler (8 heads of [128, 256] float32 a block,
+    in and out double-buffered, inside the default scoped VMEM), the leaf
+    aliased through it: no op copies, gathers or scatters the leaf or a
+    [64, 32, 128, 256] copy of the rows' states; the gather / update /
+    scatter form is caught doing so."""
+    hlo = _ssm_layers_hlo(one_chip, "pallas")
+    assert hlo.count("tpu_custom_call") >= 1 and "ssd_step_slots" in hlo
+    assert _SSM_MOVES.findall(hlo) == []
+    assert _SSM_MOVES.findall(_ssm_layers_hlo(one_chip, "plain")) != []
+
+
 # a [64, 64] mixed step of ling-3.0-flash-vl: 3 chunk rows beside 60 decode
 # rows, 4096 cells of which 252 hold a token
 _GRID_WIDE = re.compile(r"=\s*f32\[(?:64,64|4096),(?:12288|32,128)\]\S*\s+"

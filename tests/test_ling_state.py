@@ -270,7 +270,7 @@ def test_the_older_models_have_one_kind_and_no_state():
                 ModelConfig(kv_lora_rank=16, qk_nope_head_dim=8,
                             qk_rope_head_dim=4, num_experts=16,
                             first_dense_layers=1)):
-        assert not cfg.has_linear_layers and cfg.state_leaves() == {}
+        assert not cfg.has_state and cfg.state_leaves() == {}
         assert cfg.num_cache_layers == cfg.num_layers
         assert all(r.store_first == r.first for r in llama.layer_runs(cfg))
         assert llama.init_state(cfg, 4) == {}

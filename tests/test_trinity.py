@@ -437,7 +437,15 @@ PARENT_PROGRAMS = {
     ("rehearsal-tiny-moonlight", "window"): "1f212b404bab2dbb",
     ("rehearsal-tiny-mellum", "step"): "6921cab248da7e90",
     ("rehearsal-tiny-mellum", "window"): "826993a03ea7d66a",
+    # PR 45: Ling (Kimi-Delta layers over state slots, one latent-attention
+    # layer, experts), whose chunk rows' bookkeeping and convolution the
+    # state-space mixer now shares (llama._chunk_group, conv_with_tail,
+    # conv_one_token): traced from PR 45's parent (3e6f8f0) at 16 rows,
+    # where the linear layers split a step's rows (at 8 they do not)
+    ("rehearsal-tiny-ling", "step"): "6a79565f07f66d72",
+    ("rehearsal-tiny-ling", "window"): "d8709fd5313f3673",
 }
+PROGRAM_ROWS = {"rehearsal-tiny-ling": 16}
 
 
 def program_texts(name, rows=8, chunk=16, pages=8, base_pages=8):
@@ -468,6 +476,12 @@ def program_texts(name, rows=8, chunk=16, pages=8, base_pages=8):
     cache = jax.eval_shape(lambda: llama.init_cache(
         cfg, 64, ecfg.page_size, window_pages))
     names = ("wtable", "woff", "wwrite_idx") * bool(cfg.window_pool)
+    state = ("state_slots",) * bool(cfg.state_leaves())
+    if state:
+        # a recurrent state rides the cache dict: a slot a row and one a
+        # row of a prefill batch
+        cache = {**cache, **jax.eval_shape(lambda: llama.init_state(
+            cfg, rows + ecfg.max_prefill_batch))}
 
     def named(fn, names):
         if not names:
@@ -478,14 +492,14 @@ def program_texts(name, rows=8, chunk=16, pages=8, base_pages=8):
     f32, vec = jnp.float32, arr((rows,))
     step = named(functools.partial(
         eng._engine_step, cfg, (), None, None, False, False, False, None),
-        names)
+        names + state)
     step_args = (params, cache, arr((rows, chunk)), arr((rows, chunk)),
                  arr((rows, pages)), vec, arr((rows, chunk)), vec,
                  arr((rows,), f32), vec, arr((rows,), f32), vec, vec, vec)
     nw = window_ladder(ecfg.decode_steps)[0]
     window = named(functools.partial(
         eng._engine_decode_window, cfg, (), None, nw, ecfg.page_size,
-        False, False, False), names[:2])
+        False, False, False), names[:2] + state)
     window_args = (params, cache, vec, vec, arr((rows, pages)),
                    arr((rows, base_pages)), vec, arr((rows,), f32), vec,
                    arr((rows,), f32), vec, vec, vec, arr((rows,), jnp.bool_),
@@ -493,6 +507,9 @@ def program_texts(name, rows=8, chunk=16, pages=8, base_pages=8):
     if cfg.window_pool:
         step_args += (arr((rows, wtable(chunk))), vec, arr((rows, chunk)))
         window_args += (arr((rows, wtable(1))), vec)
+    if state:
+        step_args += (vec,)
+        window_args += (vec,)
     return {key: re.sub(r"0x[0-9a-f]+", "0x",
                         str(jax.make_jaxpr(fn)(*args)))
             for key, fn, args in (("step", step, step_args),
@@ -502,7 +519,7 @@ def program_texts(name, rows=8, chunk=16, pages=8, base_pages=8):
 @pytest.mark.parametrize("name", sorted({n for n, _ in PARENT_PROGRAMS}))
 @pytest.mark.parametrize("program", ["step", "window"])
 def test_an_older_models_program_is_the_parents(name, program):
-    text = program_texts(name)[program]
+    text = program_texts(name, rows=PROGRAM_ROWS.get(name, 8))[program]
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == PARENT_PROGRAMS[name, program], (name, program)
 

@@ -20,8 +20,10 @@ print its readings, with the served model or the reference mutated.
 
 `--prompt-seeds a,b,c` reads further draws of the prompts,
 `--then-float8` the float8 reference and `--then-bf16-state` the reference
-with its recurrent state in bfloat16, all in the one served process (a
-start costs minutes at these widths): one JSON line each.
+with its recurrent state in bfloat16, `--then-controls` every entry of the
+check's own `CONTROLS` (a check that names its controls:
+reference_logits_falcon_h1.py), all in the one served process (a start
+costs minutes at these widths): one JSON line each.
 `--served-from FILE` reads the controls alone over what an earlier run of
 the cell served (the check leaves it in the run's output directory).
 
@@ -123,6 +125,8 @@ async def probe(args) -> None:
         readings.append((seeds[0], "ref-float8"))
     if args.then_bf16_state:
         readings.append((seeds[0], "ref-bf16-state"))
+    controls = getattr(check, "CONTROLS", {}) if args.then_controls else {}
+    readings += [(seeds[0], name) for name in controls]
     served_rows = None
     if args.served_from:
         # what an earlier run served (the check's own file in that run's
@@ -140,9 +144,21 @@ async def probe(args) -> None:
             diffs = []
             more = {"state_dtype": "bfloat16"} \
                 if mutation == "ref-bf16-state" else {}
-            if served_rows is not None:
+            if mutation in controls:
+                more = dict(controls[mutation])
+            elif cast is not None or not controls:
+                more["cast"] = cast
+            if served_rows is None and hasattr(check, "serve"):
+                # a check that also reads the engine's state: serve once,
+                # keep it in memory, every reading over it
+                served_rows = await check.serve(ctx)
+            if served_rows is None and mutation in controls:
+                # the controls read what the first reading served
+                with open(check.served_path(ctx)) as f:
+                    more["served"] = json.load(f)
+            elif served_rows is not None:
                 more["served"] = served_rows
-            got = await check.measure(ctx, cast=cast, keep=diffs, **more)
+            got = await check.measure(ctx, keep=diffs, **more)
             with open(os.path.join(
                     out_dir, f"diffs-{mutation}-{seed}.json"), "w") as f:
                 json.dump(sorted(diffs), f)
@@ -157,6 +173,8 @@ async def probe(args) -> None:
                    limits=check.LIMITS[got["dtype"]],
                    device=served.worker.engine.device_info())
         print(json.dumps(got), flush=True)
+        with open(os.path.join(out_dir, "readings.jsonl"), "a") as f:
+            f.write(json.dumps(got) + "\n")
 
 
 def main() -> None:
@@ -180,6 +198,9 @@ def main() -> None:
                    help="after them, the reference with its recurrent "
                         "state rounded to bfloat16 (a configuration with "
                         "linear-attention layers)")
+    p.add_argument("--then-controls", action="store_true",
+                   help="after them, every control the check names in its "
+                        "CONTROLS, each over what the first reading served")
     p.add_argument("--served-from", default="",
                    help="the controls alone, over what an earlier run "
                         "served (chiprun_out/benchmark/<cell>/<run>/"

@@ -194,11 +194,13 @@ def _devices():
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     # code that asks jax.default_backend() sees the CPU here: take the
-    # branch the chip takes (ops/moe.py's grouped matmul kernel, ops/
-    # linear_attention.py's slot-addressed state update)
-    from dynamo_tpu.ops import linear_attention, moe
+    # branch the chip takes (ops/moe.py's grouped matmul kernel, the
+    # slot-addressed state updates of ops/linear_attention.py and
+    # ops/state_space.py)
+    from dynamo_tpu.ops import linear_attention, moe, state_space
     moe.grouped_matmul_impl = lambda: "gmm"
     linear_attention.kda_step_slots_impl = lambda: "pallas"
+    state_space.ssd_step_slots_impl = lambda: "pallas"
     return (list(topo.devices),
             f"described {topo.devices[0].device_kind} (v5e:2x2), no chip")
 
@@ -262,7 +264,7 @@ def build_programs(config_dir: str, rows: int, chunk: int, pages: int,
         lambda: llama.init_cache(cfg, num_pages, ecfg.page_size,
                                  window_pages)),
         llama.cache_shardings(cfg))
-    state = cfg.has_linear_layers
+    state = cfg.has_state
     if state:
         # the recurrent state rides the cache dict (NativeEngine), one
         # slot a decode row and one a row of a prefill batch
