@@ -332,6 +332,12 @@ class NativeEngine:
         self.mixed_steps_replanned = 0  # planned again after the commit
         #                                 before them (the plan made ahead
         #                                 came to nothing)
+        # a change of step kind, mixed <-> decode window (_note_kind)
+        self.handovers = 0          # committed steps of another kind
+        #                             than the committed step before them
+        self.handovers_chained = 0  # of those, dispatched before that
+        #                             step was fetched
+        self._last_kind: Optional[str] = None
         # cumulative MoE capacity-drop counters (dispatch impl only)
         self.moe_dropped_tokens = 0.0
         self.moe_routed_tokens = 0.0
@@ -395,6 +401,20 @@ class NativeEngine:
             np.full((engine_cfg.max_slots
                      + max(1, engine_cfg.max_prefill_batch),), -1, np.int32),
             self._replicated)
+        # the two hand-overs between the chains (_launch_ahead): a window
+        # dispatched behind a mixed step takes its rows' pending tokens
+        # from that step's tokens, a mixed step behind a window takes the
+        # window's last tokens at the length a step's own have. Each out
+        # with the sharding a window's own carry has, and compiled here,
+        # so that no window of traffic meets either first
+        self._carry_fn = jax.jit(_carry_behind,
+                                 out_shardings=self._replicated)
+        self._prev_fn = jax.jit(
+            functools.partial(_tokens_behind, self._no_prev.shape[0]),
+            out_shardings=self._replicated)
+        self._prev_fn(self._carry_fn(self._no_prev, jax.device_put(
+            np.zeros((engine_cfg.max_slots, 4), np.int32),
+            self._replicated)))
 
         init_cache = jax.jit(
             functools.partial(
@@ -980,8 +1000,11 @@ class NativeEngine:
         t0 = time.perf_counter()
         with self.phases.phase("dispatch", annotation="compile"):
             yield
+        sch = self.scheduler
         logging.getLogger(__name__).info(
-            "first dispatch of %s: %.2fs", key, time.perf_counter() - t0)
+            "first dispatch of %s: %.2fs (%d rows running, %d waiting)",
+            key, time.perf_counter() - t0,
+            sum(s is not None for s in sch.running), len(sch.waiting))
 
     def _mark_planned(self, seqs) -> None:
         """A prefill or mixed step is about to run these rows: a request
@@ -1058,6 +1081,7 @@ class NativeEngine:
             events.append(self._postprocess(seq, tok))
         st1 = (STREAM_STATS.prefetch_hit, STREAM_STATS.prefetch_late,
                STREAM_STATS.pages_spilled, STREAM_STATS.stall_steps)
+        self._note_kind(None)
         self._ledger_record(
             "stream", 1, 1, 1 if tok is not None else 0, 1,
             stream_hit=st1[0] - st0[0], stream_late=st1[1] - st0[1],
@@ -1088,11 +1112,9 @@ class NativeEngine:
         prev = self._no_prev
         if after is not None:
             prev = after["prev"]
-            row_of = {id(row[1]): row[0] for row in after["rows"]}
-            for i, seq in enumerate(plan.seqs):
-                if seq is not None and plan.is_decode[i] \
-                        and seq.output[-1] == PENDING_TOKEN:
-                    src[i] = row_of[id(seq)]
+            src = self._fed_rows(after, (
+                seq if plan.is_decode[i] else None
+                for i, seq in enumerate(plan.seqs)), len(src))
         # STEP_OPERANDS' order, then the variant's own (__init__)
         small = (plan.tokens, plan.positions, plan.page_table, plan.kv_lens,
                  plan.write_idx, plan.last_idx, temp, top_k, top_p, seeds,
@@ -1121,6 +1143,20 @@ class NativeEngine:
             int(plan.kv_lens.sum()), plan.page_table.size,
             *self._window_reads(plan, plan.kv_lens))
         return key, (prev, *self._stage_operands(small, own)), with_lp
+
+    @staticmethod
+    def _fed_rows(after: dict, seqs, rows: int) -> np.ndarray:
+        """[rows] int32 `src`: for each decode row of a plan made behind
+        the step in flight `after`, the row of that step's tokens its
+        last token stands in while it is PENDING_TOKEN on the host, else
+        -1 (`after["rows"]`: `_open_mixed`'s or `_open_window`'s)."""
+        src = np.full((rows,), -1, np.int32)
+        row_of = {id(row[1]): row[0] for row in after["rows"]}
+        for i, seq in enumerate(seqs):
+            if seq is not None and seq.output \
+                    and seq.output[-1] == PENDING_TOKEN:
+                src[i] = row_of[id(seq)]
+        return src
 
     def _dense_rows(self, plan) -> int:
         """The token rows the token-wise layers of `plan`'s `_engine_step`
@@ -1299,6 +1335,7 @@ class NativeEngine:
                     seq, tok, float(lps[0][i]), lps[1][i], lps[2][i]))
             else:
                 events.append(self._postprocess(seq, tok))
+        self._note_kind(None)
         self._ledger_record(
             "prefill", len(plan.seqs),
             sum(1 for s in plan.seqs if s is not None),
@@ -1376,59 +1413,116 @@ class NativeEngine:
                                       after=after)
         outs, prev = self._dispatch_step(staged)
         self._copy_outs_async(outs)
+        # the decode rows advance outside the window program: any saved
+        # device-resident window carry is stale
+        self._dec_state = None
         return {"plan": plan, "key": staged[0], "outs": outs, "prev": prev,
-                "dead": set(), "rows": ()}
+                "dead": set(), "rows": (), "ahead": False}
 
     def _chain_step(self) -> List[StepOutput]:
-        """Advance the chain of mixed steps by one step() call. A mixed
-        step N is in flight (`self._flight`: the call before dispatched
-        it):
+        """Advance the chain by one step() call from a mixed step N in
+        flight (`self._flight`: the call before dispatched it):
 
         1. open N's commit (_open_mixed): everything the host knows of
            it without its tokens. Counts advance, a last chunk's row
            takes its slot, a row whose token is its last by `max_tokens`
            gives up slot and pages; the tokens themselves stand as
            PENDING_TOKEN;
-        2. plan N+1 on that state with the ordinary planner
-           (Scheduler.schedule_ahead), so an end by length, a first
-           token and an arrival are simply what the plan finds, and
-           dispatch it: a row that decodes on reads its token from N's
-           on the device;
+        2. plan the step after N on that state with the ordinary planner
+           (_plan_ahead: a mixed step while a prompt waits, else the
+           decode window), so an end by length, a first token and an
+           arrival are simply what the plan finds, and dispatch it: a
+           row that decodes on reads its token from N's on the device;
         3. fetch N (the one host sync) and close its commit
-           (_close_mixed) while N+1 runs: the tokens take their places,
-           stops are seen, the events go out.
+           (_close_mixed) while that step runs: the tokens take their
+           places, stops are seen, the events go out.
 
         An end the host could not foresee (a stop id, EOS) and an abort
-        leave N+1 in flight with a row nobody holds any more: the next
-        call opens it for the rows still live (_mixed_live) and never
-        throws it away, because a recurrent state it advanced cannot be
-        run twice (docs/PERF.md has the exactness argument). Where no
-        mixed step can follow, 1 and 3 are the synchronous commit. Every
-        phase is entered at most once a call, as everywhere."""
+        leave the step behind N in flight with a row nobody holds any
+        more: it is committed for the rows still live (_mixed_live,
+        _live_rows) and never thrown away, because a recurrent state it
+        advanced cannot be run twice (docs/PERF.md has the exactness
+        argument). Where no step can go ahead, 1 and 3 are the
+        synchronous commit. Every phase is entered at most once a call,
+        as everywhere."""
         flight, self._flight = self._flight, None
-        follow = plan = None
         with self.phases.phase("plan"):
             self._open_mixed(flight)
-            ahead = bool(self.scheduler.waiting) and self._chain_ok()
-            if ahead:
-                plan = self.scheduler.schedule_ahead()
-                self._process_offloads()
-                self._process_onboards()
-                self._process_pool_injects()
-            self._ahead_failed = ahead and plan is None
+            plan = self._plan_ahead() if self._chain_ok() else None
         if plan is not None:
-            self.step_count += 1
-            self._mark_planned(plan.seqs)
-            follow = self._launch_mixed(plan, after=flight)
-            self.mixed_steps_chained += 1
+            self._launch_ahead(plan, flight)
+            self.mixed_steps_chained += self._flight is not None
         # this call commits N, whatever it dispatched
         self._call_key = flight["key"]
         sampled = self._fetch_step(flight["outs"],
-                                   in_flight=follow is not None)
+                                   in_flight=plan is not None)
         with self.phases.phase("commit"):
             events = self._close_mixed(flight, sampled)
-        self._flight = follow
+        self._settle_window()
         return events
+
+    def _plan_ahead(self):
+        """The step to dispatch behind the step in flight, planned on
+        the state its open commit leaves: a MixedPlan while a prompt
+        waits, else a DecodePlan that may enter the pipeline
+        (_pipeline_ok), else None: the planner would have had to preempt
+        or found no row. The next call then plans with nothing in
+        flight, as it always did. For the caller that `_chain_ok()`
+        allows, inside its `plan` phase."""
+        sch = self.scheduler
+        if sch.waiting:
+            plan = sch.schedule_ahead()
+            self._process_offloads()
+            self._process_onboards()
+            self._process_pool_injects()
+            self._ahead_failed = plan is None
+            return plan
+        plan = sch.schedule_decode_ahead()
+        return plan if plan is not None and self._pipeline_ok(plan) \
+            else None
+
+    def _launch_ahead(self, plan, after: dict) -> None:
+        """upload and dispatch of `plan` behind the step in flight
+        `after`, whichever kind each is: the new step is left in flight
+        in its own place (`self._flight`, `self._pipeline`), marked
+        `ahead`."""
+        self.step_count += 1
+        # the rows advance outside the last window's carry
+        self._dec_state = None
+        if isinstance(plan, MixedPlan):
+            self._mark_planned(plan.seqs)
+            self._flight = self._launch_mixed(plan, after=after)
+            self._flight["ahead"] = True
+        else:
+            self._prime_pipeline(plan, after=after)
+
+    def _settle_window(self) -> None:
+        """The commit just closed may have ended a row of the window
+        dispatched behind it (a stop id, EOS): the window stays in
+        flight for the rows still live, flagged as a follow-up is that a
+        commit ended a row under (_pipeline_step), or is let go where no
+        row of it lives on."""
+        pend = self._pipeline
+        if pend is None or self._membership_intact(pend["plan"]):
+            return
+        self.pipeline_fallbacks += 1
+        self._dec_state = None
+        if any(self._live_rows(pend["plan"])):
+            pend["drain"] = pend["reconciled"] = True
+        else:
+            self.window_steps_discarded += pend["staged"]["nw"]
+            self._pipeline = None
+
+    def _note_kind(self, kind: Optional[str], ahead: bool = False) -> None:
+        """A device step of `kind` ("mixed", "decode": a window; None:
+        any other) is being committed. `handovers` counts the commits
+        whose kind differs from the commit before them, mixed against
+        window; `handovers_chained` those of them that were dispatched
+        before the step in front of them was fetched (`ahead`)."""
+        last, self._last_kind = self._last_kind, kind
+        if last and kind and last != kind:
+            self.handovers += 1
+            self.handovers_chained += bool(ahead)
 
     def _mixed_live(self, flight: dict) -> List[bool]:
         """Which rows of the mixed step in flight still hold the sequence
@@ -1486,9 +1580,10 @@ class NativeEngine:
             tok = seq.output[at] = int(sampled[i])
             if not plan.is_decode[i]:
                 self._mark_first_token(seq)
-            events.append(self._postprocess(seq, tok, opened=(p, ended)))
-        self._dec_state = None
+            events.append(self._postprocess(
+                seq, tok, opened=(p, ended, at + 1)))
         self.mixed_steps += 1
+        self._note_kind("mixed", flight["ahead"])
         self._ledger_record(
             "mixed", len(plan.seqs), flight["live"], sum(plan.n_valid),
             int(plan.tokens.size), dense=self._dense_rows(plan),
@@ -1535,6 +1630,7 @@ class NativeEngine:
         # device-resident window carry (token/position/counter) is stale
         self._dec_state = None
         self.mixed_steps += 1
+        self._note_kind("mixed")
         self._ledger_record(
             "mixed", len(plan.seqs),
             sum(1 for s in plan.seqs if s is not None),
@@ -1605,7 +1701,7 @@ class NativeEngine:
                      if w >= max(1, plan.n_window)), self._window_sizes[0])
 
     def _stage_window(self, plan: DecodePlan, samp, rp, with_lp: bool,
-                      greedy: bool) -> dict:
+                      greedy: bool, after: Optional[dict] = None) -> dict:
         """Stage the device-side plan arrays for a decode window.
 
         Split-KV base width (VERDICT r3 missing #2): the base gather covers
@@ -1619,7 +1715,13 @@ class NativeEngine:
         refreshing), reuse the device plan arrays and feed the last
         window's final (token, position, counter) device arrays straight
         back in — steady-state windows then upload NOTHING. Runs inside
-        the caller's `upload` phase."""
+        the caller's `upload` phase.
+
+        `after`: the step in flight the plan was made behind
+        (_launch_ahead). A row whose last token that step is still
+        sampling takes it from that step's tokens on the device: the
+        staged carry has a fourth column, `src` (_fed_rows), and
+        `_carry_behind` makes the window's [S, 3] of it there."""
         temp, top_k, top_p, seeds, counters, min_toks = samp
         ps = self.cfg.page_size
         if self._window_pages:
@@ -1652,10 +1754,16 @@ class NativeEngine:
                 small += (plan.state_slots,)
             if self._window_pages:
                 small += (plan.wtable, plan.woff)
-            own = (self._window_carry(plan, counters),)
+            carry = self._window_carry(plan, counters)
+            if after is not None:
+                carry = np.concatenate((carry, self._fed_rows(
+                    after, plan.seqs, len(plan.seqs))[:, None]), axis=1)
+            own = (carry,)
             if rp is not None:
                 small, own = small + (rp[1],), (rp[0],) + own
             *dev, first = self._stage_operands(small, own, commit=True)
+            if after is not None:
+                first = self._carry_fn(after["prev"], first)
             self.decode_plan_uploads += 1
         nw = self._window_rung(plan)
         pregather = llama._decode_kernel_mode(self.model_cfg) is None
@@ -1758,11 +1866,16 @@ class NativeEngine:
 
     def _fetch_and_commit(self, plan: DecodePlan, outs,
                           in_flight: bool = False,
-                          reconciled: bool = False) -> List[StepOutput]:
+                          reconciled: bool = False, ahead: bool = False,
+                          opened: Optional[dict] = None
+                          ) -> List[StepOutput]:
         """Blocking output fetch + host commit for one window.
-        `in_flight`: a follow-up window was dispatched before this fetch,
+        `in_flight`: the step behind it was dispatched before this fetch,
         so the device stays busy through the commit. `reconciled`: the
-        window ran under a commit that ended one of its rows."""
+        window ran under a commit that ended one of its rows. `ahead`:
+        it was itself dispatched before the step in front of it was
+        fetched. `opened`: `_open_window`'s, where its commit was opened
+        before this fetch."""
         with self.phases.phase("wait"):
             toks, lps, top_ids, top_lps, aux = \
                 jax.device_get(outs)  # dynalint: sync-point — the one
@@ -1774,7 +1887,8 @@ class NativeEngine:
             self._account_moe(aux, window=True)
         with self.phases.phase("commit"):
             return self._commit_window(plan, np.asarray(toks), lps,
-                                       top_ids, top_lps, reconciled)
+                                       top_ids, top_lps, reconciled, ahead,
+                                       opened)
 
     # -- overlapped decode pipeline ------------------------------------------
 
@@ -1836,20 +1950,23 @@ class NativeEngine:
                     return False
         return True
 
-    def _prime_pipeline(self, plan: DecodePlan
+    def _prime_pipeline(self, plan: DecodePlan,
+                        after: Optional[dict] = None
                         ) -> Optional[List[StepOutput]]:
         """Dispatch `plan`'s window and DEFER its commit: outputs start an
         async device->host copy and the events surface on the next step()
         call, which dispatches the follow-up window before fetching them.
         Returns None when the plan turns out ineligible (caller falls back
-        to the synchronous path)."""
+        to the synchronous path). `after`: the step in flight the plan
+        was made behind (_launch_ahead, _stage_window)."""
         with self.phases.phase("upload"):
             samp = self._sampling_arrays(plan.seqs)
             greedy = self._samp_cache.all_greedy
             if self.pp > 1:
                 staged = self._stage_pp_window(plan, samp, greedy)
             else:
-                staged = self._stage_window(plan, samp, None, False, greedy)
+                staged = self._stage_window(plan, samp, None, False, greedy,
+                                            after=after)
         outs, nxt = self._dispatch_staged(staged, staged["first"])
         self._dec_state = {"sig": staged["sig"], "dev": staged["dev"],
                            "next": nxt}
@@ -1860,6 +1977,8 @@ class NativeEngine:
             # 0 = the plan's own window, each follow-up increments it
             "j": 0,
             "t_dispatch": time.perf_counter(),
+            # dispatched before the step in front of it was fetched
+            "ahead": after is not None,
         }
         return []
 
@@ -1923,12 +2042,26 @@ class NativeEngine:
            same sequence (_commit_window's identity guard), and the step
            after that re-plans. What the row that ended wrote meanwhile
            lands past its committed positions, in pages the staged table
-           owned (docs/PERF.md has the full exactness argument)."""
+           owned (docs/PERF.md has the full exactness argument).
+
+        Where a request waits, the mixed step that takes it in is
+        planned BEFORE the window is fetched, as the step after a mixed
+        step is (_chain_step): the window's commit is opened
+        (_open_window: the tokens of its rows stand as PENDING_TOKEN, a
+        row whose budget ends inside it gives up its slot), the ordinary
+        planner plans on that state (_plan_ahead), the step is
+        dispatched with its decode rows' tokens read from this window's
+        carry on the device, and 2 and 3 then close the commit while it
+        runs. A stop the host could not foresee cuts its row back
+        (_place_token); the step behind is committed for the rows still
+        live. With nothing waiting a window that ends a chain is
+        committed with nothing behind it, as ever: a row that ended is a
+        slot whose next request is on its way, and a window made ahead
+        would make it wait (PERF.md section 6, PR 46)."""
         pend, self._pipeline = self._pipeline, None
         self.step_count += 1
         plan, staged = pend["plan"], pend["staged"]
-        # this call commits the staged window, whether or not it chains
-        self._call_key = staged["program"]
+        follow = ahead = after = None
         with self.phases.phase("plan"):
             self._process_offloads()
             self._process_onboards()
@@ -1937,11 +2070,10 @@ class NativeEngine:
                 chain = False   # flagged reconcile: commit, then re-plan
             elif self.scheduler.waiting or self.scheduler.pending_onboards \
                     or self.scheduler.pending_pool_injects:
-                # admission pending: drain the pipeline — the in-flight
-                # window is COMMITTED below (reconciled, never discarded)
-                # and the next step() plans a mixed prefill+decode step,
-                # so the arrival costs steady decode at most this one
-                # un-overlapped window before the pipeline re-primes
+                # admission pending: no further window off this plan. The
+                # in-flight window is COMMITTED below (reconciled, never
+                # discarded) and the next step is the mixed prefill+decode
+                # step that takes the arrival in
                 chain = False
             elif not self._membership_intact(plan):
                 chain = False   # abort mid-window: commit what's valid
@@ -1951,18 +2083,33 @@ class NativeEngine:
                 chain = False
             else:
                 chain = self._followup_fits(plan, pend["j"] + 1)
-        follow = None
+            if not chain and self.scheduler.waiting and self._chain_ok():
+                after = self._open_window(pend)
+                ahead = self._plan_ahead()
         if chain:
             follow_outs, follow_nxt = self._dispatch_staged(
                 staged, pend["nxt"])
             self._copy_outs_async(follow_outs)
             follow = {"plan": plan, "staged": staged, "outs": follow_outs,
                       "nxt": follow_nxt, "j": pend["j"] + 1,
-                      "t_dispatch": time.perf_counter()}
+                      "t_dispatch": time.perf_counter(), "ahead": False}
+        elif ahead is not None:
+            after["prev"] = self._prev_fn(pend["nxt"])
+            self._launch_ahead(ahead, after)
+        # this call commits the staged window, whatever it dispatched
+        self._call_key = staged["program"]
         events = self._fetch_and_commit(
-            plan, pend["outs"], in_flight=follow is not None,
-            reconciled=pend.get("reconciled", False))
+            plan, pend["outs"],
+            in_flight=follow is not None or ahead is not None,
+            reconciled=pend.get("reconciled", False), ahead=pend["ahead"],
+            opened=after)
         self.pipeline_windows += 1
+        if after is not None:
+            # the commit ran while the step made ahead did, where one was
+            self.pipeline_overlapped += ahead is not None
+            if ahead is None and not self._membership_intact(plan):
+                self._dec_state = None
+            return events
         intact = self._membership_intact(plan)
         if follow is not None:
             if intact:
@@ -1990,17 +2137,64 @@ class NativeEngine:
                 # commit it next step for those rows, then re-plan, as
                 # for a grown slot set. The slot set changed, so the
                 # next window stages afresh
-                self.pipeline_fallbacks += 1
-                self._dec_state = None
-                if any(self._live_rows(plan)):
-                    follow["drain"] = follow["reconciled"] = True
-                    self._pipeline = follow
-                else:
-                    # no row lives on: there is no one to commit it for
-                    self.window_steps_discarded += staged["nw"]
+                self._pipeline = follow
+                self._settle_window()
         elif not intact:
             self._dec_state = None
         return events
+
+    def _open_window(self, pend: dict) -> dict:
+        """The commit of the window in flight, as far as the host knows
+        it before the window's tokens: `_commit_window`'s scheduler calls
+        in `_commit_window`'s order, step by step over the rows still
+        live, each sampled token standing as PENDING_TOKEN. A row whose
+        budget ends inside the window stops there and gives up its
+        decode slot and its state slot (Scheduler.release_row); its pages
+        go when the window's tokens are known (_place_token), because
+        the pages those tokens fill are sealed by content. Returns what
+        the step planned behind it is staged from and the commit is
+        closed with: `rows` (row, sequence, its params, where in its
+        output the window's first token goes, how many it gets, ended),
+        `at` (the same by row) and `live`."""
+        plan, sch = pend["plan"], self.scheduler
+        live = self._live_rows(plan)
+        rows = [[i, seq, sch.params[seq.request_id], len(seq.output), 0,
+                 False] for i, seq in enumerate(plan.seqs) if live[i]]
+        for _ in range(pend["staged"]["nw"]):
+            for row in rows:
+                _, seq, p, _, _, ended = row
+                if ended:
+                    continue
+                sch.commit_decode_token(seq, PENDING_TOKEN)
+                row[4] += 1
+                if len(seq.output) >= p.max_tokens:
+                    row[5] = True
+                    sch.release_row(seq)
+        return {"rows": rows, "at": {row[0]: row for row in rows},
+                "live": live}
+
+    def _place_token(self, row: list, step: int, tok: int) -> StepOutput:
+        """Token `step` of an opened window's row takes its place: stop
+        conditions run on it as on any token. At the row's end, by its
+        budget (seen when the commit was opened) or by a stop the host
+        could not foresee, which cuts the row back to this token, the
+        sequence is finished, the pages the window's tokens filled
+        sealed first; a row that goes on has them sealed at the
+        window's last token."""
+        _, seq, p, at, count, _ = row
+        sch = self.scheduler
+        seq.output[at + step] = tok
+        ev = self._postprocess(seq, tok, opened=(p, True, at + step + 1))
+        last = step == count - 1
+        if ev.finished and not last:
+            del seq.output[at + step + 1:]
+            seq.num_cached -= count - 1 - step
+            seq.num_computed -= count - 1 - step
+        if ev.finished or last:
+            sch._seal_full_pages(seq)
+        if ev.finished:
+            sch.finish(seq)
+        return ev
 
     # dynalint: hot-path-end
 
@@ -2175,6 +2369,7 @@ class NativeEngine:
         self.spec_steps += 1
         # ledger: the verify block charges [S, k+1] bucket tokens; the
         # accepted drafts + the model's own token are the useful part
+        self._note_kind(None)
         self._ledger_record(
             "spec", s_count,
             sum(1 for s in plan.seqs if s is not None),
@@ -2183,13 +2378,19 @@ class NativeEngine:
 
     def _commit_window(self, plan: DecodePlan, toks: np.ndarray, lps=None,
                        top_ids=None, top_lps=None,
-                       reconciled: bool = False) -> List[StepOutput]:
+                       reconciled: bool = False, ahead: bool = False,
+                       opened: Optional[dict] = None) -> List[StepOutput]:
         """Unpack a [N, S] window of sampled tokens step-major so each
         request's tokens stream in generation order; stop accounting a
         sequence at its first finished token (later window tokens for it
         are garbage by construction). `reconciled`: a follow-up window
         that a commit ended a row under; it is committed for the rows
-        still live like any other, and counted."""
+        still live like any other, and counted. `ahead`: the window was
+        dispatched before the step in front of it was fetched
+        (_note_kind). `opened`: the commit was opened before the fetch
+        (_open_window) and this is the rest of it: the rows are the ones
+        live then, and each token takes the place held for it
+        (_place_token)."""
         n_steps = toks.shape[0]
         self.step_count += n_steps - 1             # window counts as N steps
         events: List[StepOutput] = []
@@ -2201,16 +2402,26 @@ class NativeEngine:
         # double-free pages (or poison a reused request id); the
         # synchronous path commits immediately after scheduling, so the
         # guard is vacuous there
-        live = self._live_rows(plan)
+        live = self._live_rows(plan) if opened is None else opened["live"]
         n_live = sum(live)
         if not n_live:
             # every row left while the window ran: its rung reached no one
             self.window_steps_discarded += n_steps
-        elif reconciled:
-            self.window_steps_reconciled += n_steps
+        else:
+            self._note_kind("decode", ahead)
+            if reconciled:
+                self.window_steps_reconciled += n_steps
         for step in range(n_steps):
             for i, seq in enumerate(plan.seqs):
                 if not live[i] or seq.request_id in done:
+                    continue
+                if opened is not None:
+                    ev = self._place_token(opened["at"][i], step,
+                                           int(toks[step, i]))
+                    events.append(ev)
+                    if ev.finished:
+                        done.add(seq.request_id)
+                        finish_step[seq.request_id] = step
                     continue
                 self.scheduler.commit_decode_token(seq, int(toks[step, i]))
                 if lps is not None:
@@ -2289,6 +2500,7 @@ class NativeEngine:
                     lps[2][i]))
             else:
                 events.append(self._postprocess(seq, seq.output[-1]))
+        self._note_kind("decode")
         self._ledger_record("decode", len(plan.seqs), len(events),
                             len(events), len(plan.seqs), events=events)
         return events
@@ -2297,11 +2509,12 @@ class NativeEngine:
                      lp: Optional[float] = None, top_ids=None,
                      top_lps=None, opened: Optional[tuple] = None
                      ) -> StepOutput:
-        """`opened`: (params, ended) of a row whose commit was opened
-        before its token was known (_open_mixed): the params it had then
-        and whether its end by length has been carried out."""
-        p, ended = opened or (self.scheduler.params[seq.request_id], False)
-        n_out = len(seq.output)
+        """`opened`: (params, ended, tokens out) of a row whose commit
+        was opened before `tok` was known (_open_mixed, _open_window):
+        the params it had then, whether the caller carries out its end
+        itself, and its output's length with `tok` its last."""
+        p, ended, n_out = opened or (
+            self.scheduler.params[seq.request_id], False, len(seq.output))
         finish = None
         emit: Optional[int] = tok
         # Hidden stop ids always stop and are never emitted. EOS before
@@ -2593,6 +2806,8 @@ class NativeEngine:
         m.mixed_steps = self.mixed_steps
         m.mixed_steps_chained = self.mixed_steps_chained
         m.mixed_steps_replanned = self.mixed_steps_replanned
+        m.handovers = self.handovers
+        m.handovers_chained = self.handovers_chained
         m.decode_stall_steps = self.decode_stall_steps
         # KV representation gauges (ops/kv_quant.py): bytes one page
         # occupies in HBM (k+v+scales) and the quant mode's bit width
@@ -2792,6 +3007,24 @@ class NativeEngine:
                 sch.allocator.free(pid)
             POOL_STATS.prefetch_pages += warmed
         return warmed
+
+
+def _carry_behind(prev_tokens, carry):
+    """The [S, 3] carry of a window dispatched behind a step in flight:
+    `carry` [S, 4] is the host's (token, position, counter, src); a row
+    with `src >= 0` takes its token from `prev_tokens[src]`, the tokens of
+    the step in front of it, which never left the device."""
+    src = carry[:, 3]
+    tok = jnp.where(src >= 0, prev_tokens[jnp.maximum(src, 0)], carry[:, 0])
+    return carry[:, :3].at[:, 0].set(tok)
+
+
+def _tokens_behind(cap: int, carry):
+    """A window's last tokens (its [S, 3] carry out) at the one length
+    `cap` an `_engine_step` takes the tokens of the step before it in:
+    row i is slot i's."""
+    return jnp.full((cap,), -1, jnp.int32).at[:carry.shape[0]].set(
+        carry[:, 0])
 
 
 def pack_operands(arrays) -> tuple:

@@ -282,6 +282,11 @@ class EngineMetrics:
     # nothing (it would have needed a preemption, or found no row)
     mixed_steps_chained: int = 0
     mixed_steps_replanned: int = 0
+    # a change of step kind: committed device steps whose kind (mixed /
+    # decode window) differs from the committed step before them, and
+    # those of them that were dispatched before that step was fetched
+    handovers: int = 0
+    handovers_chained: int = 0
     # KV representation (ops/kv_quant.py): bytes one page occupies in
     # HBM (k+v+scales), quant bit width (0 = unquantized pages), and
     # cumulative transfer volume in the WIRE representation — quantized
@@ -915,6 +920,16 @@ class Scheduler:
         self._free_window_pages(seq)
         self.params.pop(seq.request_id, None)
 
+    def release_row(self, seq: SequenceState) -> None:
+        """The first half of `finish` for a row whose end by length lies
+        inside a window still in flight (NativeEngine._open_window): its
+        decode slot and its state slot go now, so the step planned behind
+        the window can hand them on; its pages go with `finish`, once the
+        window's tokens are known and the pages they fill are sealed."""
+        self.running[seq.slot] = None
+        seq.slot = -1
+        self._free_state(seq)
+
     def abort(self, request_id: str) -> bool:
         for seq in list(self.waiting):
             if seq.request_id == request_id:
@@ -1039,9 +1054,14 @@ class Scheduler:
         n_full = valid // ps
         while len(seq.page_hashes) < n_full:
             i = len(seq.page_hashes)
+            page = all_tokens[i * ps:(i + 1) * ps]
+            if PENDING_TOKEN in page:
+                # a window's commit is open (NativeEngine._open_window):
+                # the page is sealed when its tokens are known
+                break
             parent = seq.page_hashes[-1] if seq.page_hashes else 0
-            h = self.allocator.seal(seq.pages[i], parent, all_tokens[i * ps:(i + 1) * ps])
-            seq.page_hashes.append(h)
+            seq.page_hashes.append(
+                self.allocator.seal(seq.pages[i], parent, page))
 
     def set_mixed_token_budget(self, budget: int) -> int:
         """Runtime actuation point for the mixed-step token budget —
@@ -1266,6 +1286,17 @@ class Scheduler:
             return None
         return self._schedule_mixed(ahead=True)
 
+    def schedule_decode_ahead(self) -> Optional[DecodePlan]:
+        """The decode window to dispatch behind a step still in flight
+        whose commit the engine has opened, or None where the next step
+        is no window or cannot be planned without evicting a sequence:
+        `schedule_ahead` for the other kind of step, under the same rule
+        (a victim would re-queue with tokens nobody knows yet). A row's
+        pending token is fed from the device by the engine."""
+        if self.waiting or self.stream_active or self.cfg.sp != 1:
+            return None
+        return self._schedule_decode(ahead=True)
+
     def _schedule_mixed(self, ahead: bool = False) -> Optional[MixedPlan]:
         """One fused prefill+decode step (MixedPlan), or None when no
         prefill row is admissible right now (`ahead`: or when a running
@@ -1450,7 +1481,9 @@ class Scheduler:
         """Single-row convenience (tests drive the scheduler with this)."""
         return self.commit_prefill_row(plan, 0, sampled_token)
 
-    def _schedule_decode(self) -> Optional[DecodePlan]:
+    def _schedule_decode(self, ahead: bool = False) -> Optional[DecodePlan]:
+        """`ahead`: None where a row's window would need a preemption
+        (schedule_decode_ahead)."""
         active = [s for s in self.running if s is not None]
         if not active:
             return None
@@ -1483,6 +1516,8 @@ class Scheduler:
             upto = max(seq.total_len + 1, min(seq.total_len + n_window,
                                               limit))
             while seq.slot >= 0 and not self._ensure_pages(seq, upto):
+                if ahead:
+                    return None
                 # memory-pressure preemption: lowest class first,
                 # youngest within a class; victim starvation bounded by
                 # the class-band requeue + queue aging limit (R19)

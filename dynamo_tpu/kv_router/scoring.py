@@ -57,6 +57,10 @@ class WorkerMetrics:
     # and mixed steps planned again after the commit before them
     mixed_steps_chained: int = 0
     mixed_steps_replanned: int = 0
+    # changes of step kind (mixed <-> decode window), and those whose
+    # second step was dispatched before the first was fetched
+    handovers: int = 0
+    handovers_chained: int = 0
     # KV representation (ops/kv_quant.py): HBM bytes per page, quant bit
     # width (0 = unquantized), cumulative wire-representation transfer
     # volume (quantized bytes on kv_quant engines)

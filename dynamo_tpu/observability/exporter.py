@@ -113,6 +113,12 @@ class MetricsExporter:
                 ("mixed_steps_replanned",
                  "Mixed steps planned again after the commit before "
                  "them, the plan made ahead of it having come to nothing"),
+                ("handovers",
+                 "Committed steps whose kind (mixed / decode window) "
+                 "differs from the committed step before them"),
+                ("handovers_chained",
+                 "Of those, the steps dispatched before the step of the "
+                 "other kind in front of them was fetched"),
             )}
         # KV representation gauges (ops/kv_quant.py): page HBM footprint,
         # quant mode bit width (0 = unquantized, 8 = int8 pages), and
@@ -383,6 +389,9 @@ class MetricsExporter:
                 worker_id, value=m.mixed_steps_chained)
             self.g_pipe["mixed_steps_replanned"].set(
                 worker_id, value=m.mixed_steps_replanned)
+            self.g_pipe["handovers"].set(worker_id, value=m.handovers)
+            self.g_pipe["handovers_chained"].set(
+                worker_id, value=m.handovers_chained)
             self.g_kv_repr["page_bytes"].set(
                 worker_id, value=m.kv_page_bytes)
             self.g_kv_repr["quant_mode"].set(

@@ -384,9 +384,17 @@ def test_a_pipelined_window_is_counted_once(recorded):
     assert primed and all(
         r["tokens"] == 0 and "commit" not in r["phases"]
         and "dispatch" in r["phases"] for r in primed)
-    # a window that was only primed or chained, and the mixed step a
-    # chain of mixed steps starts with (NativeEngine._chain_step)
-    assert {r["kind"] for r in primed} == {"decode", "mixed"}
+    # a window that was only primed or chained. The mixed step that
+    # takes `b` in has no call of its own: the call that commits the
+    # window in front of it plans, uploads and dispatches it first
+    # (NativeEngine._launch_ahead), and has the kind of what it committed
+    assert {r["kind"] for r in primed} == {"decode"}
+    handed = [r for r, nxt in zip(calls, calls[1:])
+              if r["kind"] == "decode" and r["dev_steps"]
+              and nxt["kind"] == "mixed"]
+    assert handed and all(
+        {"plan", "upload", "dispatch", "wait", "commit"} <= set(r["phases"])
+        and r["bucket"] == 8 for r in handed)
     assert all(r["bucket"] == 8 for r in primed if r["kind"] == "decode")
     windows = [r for r in calls if r["kind"] == "decode" and r["dev_steps"]]
     assert stats.steps_decode == len(windows)
