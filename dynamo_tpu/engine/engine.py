@@ -1169,6 +1169,17 @@ class NativeEngine:
             return compact[0]
         return int(plan.tokens.size)
 
+    def _step_forms(self, plan) -> dict:
+        """What the ledger records of the forms `plan`'s `_engine_step`
+        took, each decided as the program decides it: `dense`, the token
+        rows its token-wise layers ran over (`_dense_rows`), and
+        `attn_rows`, whether its attention ran over its real queries
+        (a compact step of a shape where llama.step_attention_rows)."""
+        dense = self._dense_rows(plan)
+        return {"dense": dense, "attn_rows": (
+            dense < plan.tokens.size and llama.step_attention_rows(
+                self.model_cfg, plan.tokens.shape[1]))}
+
     def _account_linattn(self, tokens: int, rows: int,
                          window_steps: int = 0, inplace: int = 0,
                          flat: bool = False) -> None:
@@ -1340,7 +1351,7 @@ class NativeEngine:
             "prefill", len(plan.seqs),
             sum(1 for s in plan.seqs if s is not None),
             sum(plan.n_valid), int(plan.tokens.size),
-            dense=self._dense_rows(plan), events=events)
+            **self._step_forms(plan), events=events)
         return events
 
     def _run_mixed(self, plan: MixedPlan) -> List[StepOutput]:
@@ -1586,7 +1597,7 @@ class NativeEngine:
         self._note_kind("mixed", flight["ahead"])
         self._ledger_record(
             "mixed", len(plan.seqs), flight["live"], sum(plan.n_valid),
-            int(plan.tokens.size), dense=self._dense_rows(plan),
+            int(plan.tokens.size), **self._step_forms(plan),
             events=events)
         return events
 
@@ -1635,7 +1646,7 @@ class NativeEngine:
             "mixed", len(plan.seqs),
             sum(1 for s in plan.seqs if s is not None),
             sum(plan.n_valid), int(plan.tokens.size),
-            dense=self._dense_rows(plan), events=events)
+            **self._step_forms(plan), events=events)
         return events
 
     def _run_decode(self, plan: DecodePlan) -> List[StepOutput]:

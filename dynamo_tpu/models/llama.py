@@ -38,9 +38,10 @@ from jax.sharding import PartitionSpec as P
 
 from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.ops.attention import (
-    _softcap, compact_index, compact_step, decode_attention_deferred,
-    decode_attention_split, kv_write_plan, paged_attention, stored_kv_rows,
-    write_kv_rows,
+    StepRows, _softcap, attend, attention_rows, attention_rows_pay,
+    compact_index, compact_step, decode_attention_deferred,
+    decode_attention_split, gather_kv, kv_write_plan, paged_attention,
+    step_rows, stored_kv_rows, write_kv_rows,
 )
 from dynamo_tpu.ops.kv_quant import cache_keys
 from dynamo_tpu.ops.linear_attention import BLOCK as KDA_BLOCK
@@ -1174,30 +1175,7 @@ def kda_mix(state: tuple, lk, slots: jax.Array, lp: Params,
     return (kda_s, kda_conv), o
 
 
-class KdaRows(NamedTuple):
-    """What `kda_mix_rows` reads of a step's plan, the same for every
-    linear layer: computed once a program, outside the layer scan
-    (`kda_rows`)."""
-    start: jax.Array    # [B] the token row that holds a row's first token
-    n_valid: jax.Array  # [B] a row's real tokens
-    order: jax.Array    # [B] rows, longest first
-    n_long: jax.Array   # () chunk rows: rows of more than one token
-
-
-def kda_rows(valid: jax.Array, start: jax.Array) -> KdaRows:
-    """valid [B, T]: real tokens, a prefix of each row; start [B]: where
-    in the step's B * T token rows a row's tokens begin. They are
-    CONTIGUOUS there in both layouts a step has: on the grid row r
-    starts at r * T, and a compact step (ops/attention.compact_index)
-    keeps the grid's row-major order, so row r starts at the flat row of
-    its first cell."""
-    n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
-    return KdaRows(start.astype(jnp.int32), n_valid,
-                   jnp.argsort(-n_valid).astype(jnp.int32),
-                   jnp.sum(n_valid > 1).astype(jnp.int32))
-
-
-def _chunk_group(rows: KdaRows, j, group: int, long_at, n_slots: int,
+def _chunk_group(rows: StepRows, j, group: int, long_at, n_slots: int,
                  valid, keep):
     """Group `j` of a split step's chunk rows, `group` rows of
     `rows.order`: (at [group] their slots, out of range for a row that is
@@ -1219,12 +1197,12 @@ def _chunk_group(rows: KdaRows, j, group: int, long_at, n_slots: int,
 
 
 def kda_mix_rows(state: tuple, lk, slots: jax.Array, lp: Params,
-                 cfg: ModelConfig, x, rows: KdaRows, valid, fresh,
+                 cfg: ModelConfig, x, rows: StepRows, valid, fresh,
                  group: int = KDA_GROUP_ROWS):
     """A linear layer from `_kda_front` to the input of `_kda_out` for a
     step that `kda_mix_splits`, over the step's ROWS by what each holds,
     never over its [B, T] grid. x [B * T, D]: the step's token rows in
-    either layout (`kda_rows`); state: (kda_s, kda_conv, o [B * T, H,
+    either layout (`step_rows`); state: (kda_s, kda_conv, o [B * T, H,
     d] float32), `o` a scratch the layers share: each real token's row
     is overwritten, no other row is read.
 
@@ -1411,13 +1389,13 @@ def ssm_decode(state: tuple, l, slots: jax.Array, lp: Params,
 
 
 def ssm_mix_rows(state: tuple, l, slots: jax.Array, lp: Params,
-                 cfg: ModelConfig, x, rows: KdaRows, valid, fresh,
+                 cfg: ModelConfig, x, rows: StepRows, valid, fresh,
                  group: int = KDA_GROUP_ROWS):
     """The mixer from the block's norm to the input of `ssm_out` for a
     [B, T] step, over the step's ROWS by what each holds, never over its
     grid: `kda_mix_rows` for the state-space scan, and the mixer's ONE
     form for a step (at any number of rows). x [B * T, D]: the step's
-    token rows in either layout (`kda_rows`); state: (ssm_s, ssm_conv, o
+    token rows in either layout (`step_rows`); state: (ssm_s, ssm_conv, o
     [B * T, d_ssm] in the model's dtype), `o` a scratch the layers
     share: each real token's row is overwritten, no other row is read.
 
@@ -1843,6 +1821,14 @@ def step_compaction(write_idx, sp_mesh=None) -> Optional[tuple]:
     return compact_step(write_idx)
 
 
+def step_attention_rows(cfg: ModelConfig, chunk: int) -> bool:
+    """ops/attention.attention_rows_pay for `cfg`'s attention layers in a
+    compact step of `chunk` columns: for the program and for the host's
+    count of the steps that ran the row form."""
+    return attention_rows_pay(chunk, cfg.num_heads, sum(
+        h * w for h, w in cfg.kv_cache_leaves().values()))
+
+
 def forward(
     params: Params,
     cfg: ModelConfig,
@@ -1867,15 +1853,25 @@ def forward(
     step holds 31 + 16 real tokens in 512 grid cells; its tokens are
     gathered into `width` flat rows (ops/attention.compact_step: 128
     there), and norm, projections, RoPE, `wo`, the MLP or experts and
-    the residuals run over [1, width, D]. Attention and the KV-row
-    write need the grid and the pool: q is spread back to [B, Tq, ...],
-    the new rows lead the token rows the write takes them from, and
-    attention's output is gathered again. A linear layer of a step that
+    the residuals run over [1, width, D]. The KV-row write needs the
+    pool: the new rows lead the token rows the write takes them from.
+    Attention needs each row's pages. Where the grid's scores outweigh
+    what is gathered for them (`step_attention_rows`: [8, 64] and
+    [64, 64] steps, not [32, 16] ones) the pages are gathered once for
+    all rows outside every `cond` (ops/attention.gather_kv) and
+    attention runs inside the back half's `cond` (`attend_back`): over
+    the flat rows it computes the step's real queries alone, every
+    row's last beside the chunk rows' own
+    (ops/attention.attention_rows), never the [B, Tq] grid of them.
+    Elsewhere it runs over the grid outside the `cond`s: q is spread
+    back to [B, Tq, ...] and the output gathered again. A linear layer
+    of a step that
     `kda_mix_splits` needs neither: it runs whole over the step's rows
     (`kda_mix_rows`: a row's tokens are contiguous token rows in both
     layouts), outside every `cond`, and hands `back` its output as
     token rows in x's own layout. A step with more real tokens
-    than `width` takes the same halves at the grid's full width: each
+    than `width` takes the same halves at the grid's full width, and
+    attention over the grid (ops/attention.attend): each
     half of a layer (`layer_front`, `layer_back`) is one `jax.lax.cond`
     on the step's real-token count, inside the same program; a shape
     whose grid is no larger than `width` has no `cond`. Padding cells'
@@ -1981,8 +1977,9 @@ def forward(
 
     # the token rows are [B, Tq, ...] arrays throughout. A compact step
     # (`sel`, where `fits`) keeps its real tokens in the first `width` of
-    # the B * Tq rows, and the token-wise halves of a layer run over
-    # those alone: each half is one branch of a `cond`, whose other
+    # the B * Tq rows, and the token-wise halves of a layer (and, where
+    # `rows_attn`, the attention between them) run over those alone:
+    # each half is one branch of a `cond`, whose other
     # branch is the same half over all the rows, the grid. The pool
     # never enters a `cond`: XLA:TPU copied both leaves in and out of
     # every layer's write when the layer scan sat inside one (PERF.md
@@ -2026,13 +2023,19 @@ def forward(
                         ).reshape((b, tq) + a.shape[2:])
 
     flat_positions = None if sel is None else from_grid(meta.positions)
-    # a step whose linear layers work over its rows (`kda_mix_rows`): a
-    # row's tokens are contiguous token rows in both layouts
-    kda_plan = None
-    if cfg.has_state and mix_splits(cfg, b, tq):
+    # what the row forms read of the plan: a row's tokens are contiguous
+    # token rows in both layouts. `kda_plan`: a step whose state layers
+    # work over its rows (`kda_mix_rows`, `ssm_mix_rows`); `rows_attn`: a
+    # compact step whose attention does in its flat branch
+    # (`attention_rows`), where the shape says that pays
+    rows_plan = kda_plan = None
+    state_rows = cfg.has_state and mix_splits(cfg, b, tq)
+    rows_attn = sel is not None and step_attention_rows(cfg, tq)
+    if rows_attn or state_rows:
         row0 = jnp.arange(b, dtype=jnp.int32) * tq
-        kda_plan = kda_rows(grid_valid, row0 if sel is None else jnp.where(
+        rows_plan = step_rows(grid_valid, row0 if sel is None else jnp.where(
             fits, sel.slot[row0], row0))
+        kda_plan = rows_plan if state_rows else None
 
     def embed_rows(sel):
         if sel is None:
@@ -2071,10 +2074,12 @@ def forward(
                 return lp
 
         def front(sel, x):
-            """-> q, k, v as token rows [B, Tq, heads, hd]: q at its
-            cells, for attention; k and v where their rows are written
-            from. A linear layer: what its state update takes, all three
-            at their cells."""
+            """-> q, k, v as token rows [B, Tq, heads, hd]: k and v in
+            x's own layout, where their rows are written from; q where
+            attention reads it, in that layout for the row form
+            (`attend_back`), at its cells for the grid form. A linear
+            layer on the grid (`kda_mix`): what its state update takes,
+            all three at their cells."""
             if sel is None:
                 return layer_front(x, lp_of(), cfg, meta.positions, heads,
                                    kind)
@@ -2082,7 +2087,8 @@ def forward(
                                   heads, kind)
             if kind == "kda":
                 return to_grid(q), to_grid(k), to_grid(v)
-            return to_grid(q), unflat(k), None if v is None else unflat(v)
+            return (unflat(q) if rows_attn else to_grid(q)), unflat(k), \
+                None if v is None else unflat(v)
 
         def back(sel, x, attn, ssm=None, stored=False):
             # stored: attn is [B * Tq, ...] token rows in x's own layout,
@@ -2111,6 +2117,45 @@ def forward(
                 lp_of(), cfg, mlp, kind=kind,
                 ssm=None if ssm is None else ssm[None, :width])
             return unflat(x), stats
+
+        def attend_back(sel, x, q, k, v, ssm=None, *, scope, lens,
+                        positions, wnd):
+            """A layer from its gathered K / V [Hkv, B, Lk, hd] on (the
+            pool itself enters no `cond`): attention, then `back`. Over
+            the grid every query of [B, Tq] is computed; a compact step
+            computes its real ones, each row's last beside the chunk
+            rows' own (ops/attention.attention_rows), from q's flat rows
+            into the flat rows `back` reads."""
+            with scope(), jax.named_scope("attention"):
+                if sel is None:
+                    attn = attend(q, k, v, lens, positions,
+                                  cfg.attn_softcap, wnd, cfg.query_scale)
+                else:
+                    attn = attention_rows(
+                        flat(q)[0], k, v, lens, positions, rows_plan,
+                        grid_valid, cfg.attn_softcap, wnd, cfg.query_scale)
+            return back(sel, x, attn, ssm, stored=sel is not None)
+
+        def attention_back(x, q, kc, vc, table, lens, positions, wnd, scope,
+                           ksc=None, vsc=None, ssm=()):
+            """Attention of every query beside its row's page table,
+            against the pool just written, then the back half. The row
+            form (`rows_attn`): the pages gathered once for all rows, out
+            here, and the queries meet them in `back`'s own `cond`. Else
+            the grid form, whole, outside it."""
+            if rows_attn:
+                with scope(), jax.named_scope("attention"):
+                    kv = gather_kv(kc, vc, table, q.dtype, ksc, vsc, sl)
+                return either(functools.partial(
+                    attend_back, lens=lens, positions=positions, wnd=wnd,
+                    scope=scope), x, q, *kv, *ssm)
+            with scope():
+                attn = paged_attention(
+                    q, kc, vc, table, lens, positions,
+                    softcap=cfg.attn_softcap, window=wnd,
+                    q_scale=cfg.query_scale, k_scale=ksc, v_scale=vsc,
+                    layer=sl)
+            return either(back, x, attn, *ssm)
 
         if kind == "kda" and kda_plan is not None:
             # a linear layer of a split step, whole, over the step's
@@ -2148,12 +2193,10 @@ def forward(
                 wpool, tuple(r.reshape((1, n) + r.shape[2:])
                              for r in stored_kv_rows(k, v, False)),
                 wwrite_plan, sl[None])
-            with jax.named_scope("attention.window"):
-                attn = paged_attention(
-                    q, wpool[0], wpool[1], meta.wtable, wkv_lens,
-                    wpositions, softcap=cfg.attn_softcap, window=swa_wnd,
-                    q_scale=cfg.query_scale, layer=sl)
-            x, drop_stats = either(back, x, attn)
+            x, drop_stats = attention_back(
+                x, q, wpool[0], wpool[1], meta.wtable, wkv_lens, wpositions,
+                swa_wnd, functools.partial(jax.named_scope,
+                                           "attention.window"))
             return (x, pool, state, wpool), drop_stats
         # rows as stored (an int8 pool quantizes them here, at capture);
         # [B, Tq, Hkv, ...] -> this layer's [1, B*Tq, Hkv, ...]
@@ -2181,14 +2224,11 @@ def forward(
             attn = ring_attention(q, k, v, meta.positions, kv_positions,
                                   sp_mesh)
         else:
-            # the one op that needs the grid: every query beside its row's
-            # page table, against the pool just written
-            with _full_scope(cfg, kind):
-                attn = paged_attention(
-                    q, kc, vc, meta.page_table, meta.kv_lens,
-                    meta.positions, softcap=cfg.attn_softcap, window=wnd,
-                    q_scale=cfg.query_scale, k_scale=ksc, v_scale=vsc,
-                    layer=sl)
+            x, drop_stats = attention_back(
+                x, q, kc, vc, meta.page_table, meta.kv_lens, meta.positions,
+                wnd, functools.partial(_full_scope, cfg, kind), ksc, vsc,
+                (state[2],) if kind == "par" else ())
+            return (x, pool, state, wpool), drop_stats
 
         x, drop_stats = either(back, x, attn, state[2]) if kind == "par" \
             else either(back, x, attn)

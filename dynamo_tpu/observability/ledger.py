@@ -100,6 +100,11 @@ class LedgerStats:
         #                           over: a compact step's flat width
         #                           (models/llama.forward), else the charge
         "compact_steps_total",    # steps that took the compact branch
+        "attn_split_steps_total",  # of the compact steps, those whose
+        #                           attention ran over their real queries,
+        #                           a row's last beside the chunk rows'
+        #                           own (ops/attention.attention_rows):
+        #                           the shapes where attention_rows_pay
         "useful_tokens_prefill",  # per-kind padding-waste split:
         "padded_tokens_prefill",  # prefill chunk rows
         "useful_tokens_decode",   # decode window (steps x slots)
@@ -406,11 +411,12 @@ class StepLedger:
                     stream_hit: int = 0, stream_late: int = 0,
                     stream_spilled: int = 0, stream_stalls: int = 0,
                     dense: Optional[int] = None, dev_steps: int = 1,
-                    events=()) -> None:
+                    events=(), attn_rows: bool = False) -> None:
         """Record one committed device step. Every argument is
         already-known host state — the disabled path is this one branch.
         `dense`: the token rows the step's token-wise layers ran over
-        where that is less than `padded` (a compact step).
+        where that is less than `padded` (a compact step); `attn_rows`:
+        such a step's attention ran the row form.
         The stream_* kwargs are this step's window-pool deltas (0 on
         non-streamed kinds); they attribute the prefetch leg per step
         in the drained JSONL. `dev_steps`: the device steps the
@@ -450,6 +456,7 @@ class StepLedger:
         if dense is not None and dense < padded:
             s.tokens_dense += dense
             s.compact_steps_total += 1
+            s.attn_split_steps_total += int(attn_rows)
         else:
             s.tokens_dense += padded
         k = kind if kind in ("prefill", "decode", "mixed") else "decode"

@@ -36,6 +36,7 @@ keep per-stage and per-layer pools of their own.
 # host syncs (.item(), device_get, float()) are dynalint R6 findings
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -99,43 +100,41 @@ def gather_values(cache: jax.Array, scale: Optional[jax.Array],
                            dtype)
 
 
-@jax.named_scope("attention")
-def paged_attention(
+def gather_kv(k_cache: jax.Array, v_cache: Optional[jax.Array],
+              page_table: jax.Array, dtype,
+              k_scale: Optional[jax.Array] = None,
+              v_scale: Optional[jax.Array] = None,
+              layer: Optional[jax.Array] = None) -> tuple:
+    """(k, v), each [Hkv, B, Pb*ps, hd]: what `page_table` names of both
+    leaves (gather_values); v is None for a one-leaf cache (`v_cache`
+    None), whose gathered keys serve as values whole."""
+    k = gather_values(k_cache, k_scale, page_table, dtype, layer)
+    if v_cache is None:
+        return k, None
+    return k, gather_values(v_cache, v_scale, page_table, dtype, layer)
+
+
+def attend(
     q: jax.Array,            # [B, Tq, H, hd]
-    k_cache: jax.Array,      # [Hkv, P, ps, hd]
-    v_cache: Optional[jax.Array],  # [Hkv, P, ps, hd]; None: a one-leaf cache
-    page_table: jax.Array,   # [B, Pb] int32
+    k: jax.Array,            # [Hkv, B, Lk, hd] — the rows' gathered keys
+    v: Optional[jax.Array],  # [Hkv, B, Lk, hd]; None: the keys are the values
     kv_lens: jax.Array,      # [B] int32 — valid kv length per sequence
     q_positions: jax.Array,  # [B, Tq] int32 — absolute position of each query
     softcap: float = 0.0,
     window: Optional[jax.Array] = None,  # scalar int32 sliding width
     q_scale: float = 0.0,
-    k_scale: Optional[jax.Array] = None,  # [Hkv, P, ps] f32 — int8 cache
-    v_scale: Optional[jax.Array] = None,
-    layer: Optional[jax.Array] = None,  # caches/scales are [L, ...] stacks
 ) -> jax.Array:
-    """Causal attention of q against the paged KV prefix. Returns [B, Tq, H, hd].
-
-    With `layer` the caches (and scales) are the stacked leaves and only
-    the pages of that layer which `page_table` names are read
-    (gather_pages).
-
-    A one-leaf cache (latent attention, `v_cache` None): here, in
-    decode_attention_split and in decode_attention_deferred the gathered
-    keys serve as values whole, and the caller keeps the columns of the
-    output that are its values (models/llama._mla_out): an eighth more
-    multiply-adds than slicing the operand, and no copy of the gathered
-    rows."""
+    """Causal attention of every query of a [B, Tq] grid against its
+    row's gathered keys (gather_kv): float32 scores, masked by the row's
+    length, the query's position and the window, one softmax a query.
+    Returns [B, Tq, H, hd]. THE arithmetic of the gather path: the grid
+    form (paged_attention) is one call of it, the row form
+    (attention_rows) one call a piece."""
     b, tq, h, hd = q.shape
-    hkv = k_cache.shape[0 if layer is None else 1]
+    hkv, _, lk = k.shape[:3]
     g = h // hkv
-
-    # int8 cache: dequantized at the gather boundary; downstream math is
-    # unchanged
-    k = gather_values(k_cache, k_scale, page_table, q.dtype, layer)
-    v = k if v_cache is None else gather_values(
-        v_cache, v_scale, page_table, q.dtype, layer)
-    lk = k.shape[2]                        # k, v: [Hkv, B, Lk, hd]
+    if v is None:
+        v = k
 
     qg = q.reshape(b, tq, hkv, g, hd)
     scores = jnp.einsum(
@@ -161,6 +160,131 @@ def paged_attention(
     v = jnp.where(valid[None, :, :, None], v.astype(jnp.float32), 0.0)
     out = jnp.einsum("bkgts,kbsd->btkgd", probs, v)
     return out.reshape(b, tq, h, hd).astype(q.dtype)
+
+
+@jax.named_scope("attention")
+def paged_attention(
+    q: jax.Array,            # [B, Tq, H, hd]
+    k_cache: jax.Array,      # [Hkv, P, ps, hd]
+    v_cache: Optional[jax.Array],  # [Hkv, P, ps, hd]; None: a one-leaf cache
+    page_table: jax.Array,   # [B, Pb] int32
+    kv_lens: jax.Array,      # [B] int32 — valid kv length per sequence
+    q_positions: jax.Array,  # [B, Tq] int32 — absolute position of each query
+    softcap: float = 0.0,
+    window: Optional[jax.Array] = None,  # scalar int32 sliding width
+    q_scale: float = 0.0,
+    k_scale: Optional[jax.Array] = None,  # [Hkv, P, ps] f32 — int8 cache
+    v_scale: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,  # caches/scales are [L, ...] stacks
+) -> jax.Array:
+    """Causal attention of q against the paged KV prefix. Returns [B, Tq, H, hd].
+
+    With `layer` the caches (and scales) are the stacked leaves and only
+    the pages of that layer which `page_table` names are read
+    (gather_pages). An int8 cache is dequantized at the gather boundary;
+    downstream math is unchanged.
+
+    A one-leaf cache (latent attention, `v_cache` None): here, in
+    attention_rows, in decode_attention_split and in
+    decode_attention_deferred the gathered keys serve as values whole,
+    and the caller keeps the columns of the output that are its values
+    (models/llama._mla_out): an eighth more multiply-adds than slicing
+    the operand, and no copy of the gathered rows."""
+    k, v = gather_kv(k_cache, v_cache, page_table, q.dtype, k_scale,
+                     v_scale, layer)
+    return attend(q, k, v, kv_lens, q_positions, softcap, window, q_scale)
+
+
+class StepRows(NamedTuple):
+    """What the row forms read of a step's plan (attention_rows; models/
+    llama.kda_mix_rows, ssm_mix_rows), the same for every layer: computed
+    once a program, outside the layer scan (`step_rows`)."""
+    start: jax.Array    # [B] the token row that holds a row's first token
+    n_valid: jax.Array  # [B] a row's real tokens
+    order: jax.Array    # [B] rows, longest first
+    n_long: jax.Array   # () chunk rows: rows of more than one token
+
+
+def step_rows(valid: jax.Array, start: jax.Array) -> StepRows:
+    """valid [B, T]: real tokens, a prefix of each row; start [B]: where
+    in the step's B * T token rows a row's tokens begin. They are
+    CONTIGUOUS there in both layouts a step has: on the grid row r
+    starts at r * T, and a compact step (compact_index) keeps the grid's
+    row-major order, so row r starts at the flat row of its first cell."""
+    n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
+    return StepRows(start.astype(jnp.int32), n_valid,
+                    jnp.argsort(-n_valid).astype(jnp.int32),
+                    jnp.sum(n_valid > 1).astype(jnp.int32))
+
+
+def attention_rows_pay(chunk: int, heads: int, kv_values: int) -> bool:
+    """Whether a compact `[rows, chunk]` step's attention takes the row
+    form (attention_rows) or stays on the grid outside the `cond`
+    (paged_attention), a STATIC fact of the step's shape and the model:
+    a row and key of the grid's float32 scores (`heads` x `chunk`) hold
+    at least as many values as the row and key gathered for them
+    (`kv_values`: kv heads x width, summed over the cache's leaves).
+    Where they do ([8, 64] and [64, 64] steps of every served model)
+    the scores are what the grid form spends its time on and the row
+    form takes 15-33 % of it; where they do not (a [32, 16] step: 512
+    against Mistral's 2048, 256 against OLMoE's 4096) the gathered K / V
+    are, and handing them to a `cond` cost more than the scores saved
+    (21.8 -> 24.2 ms a Mistral step, 25.5 -> 33.6 an OLMoE one: PERF.md
+    section 6, PR 47)."""
+    return heads * chunk >= kv_values
+
+
+def attention_rows(
+    q: jax.Array,            # [N, H, hd] — the step's token rows
+    k: jax.Array,            # [Hkv, B, Lk, hd] — the rows' gathered keys
+    v: Optional[jax.Array],  # None: the keys are the values
+    kv_lens: jax.Array,      # [B] int32
+    q_positions: jax.Array,  # [B, Tq] int32, on the grid
+    rows: StepRows,
+    valid: jax.Array,        # [B, Tq] bool: real tokens, a prefix of each row
+    softcap: float = 0.0,
+    window: Optional[jax.Array] = None,
+    q_scale: float = 0.0,
+) -> jax.Array:
+    """`attend` for a step's REAL queries alone, over its ROWS by what
+    each holds and never over its [B, Tq] grid; q: the step's token rows
+    in either layout (`step_rows`). Returns [N, H, hd]: each real token's
+    row written, every other row zero.
+
+    Every row's LAST real token is one query against the row's keys: one
+    `attend` over [B, 1], which is all a decode row (a one-token row)
+    asks for. A row of more (a chunk row): ONE a loop pass, for as many
+    passes as the step holds such rows, its [1, Tq] queries against that
+    row's keys alone (a slice of the gathered K / V; three rows a pass
+    gathered them and cost twice the time at every served shape, PERF.md
+    section 6, PR 47). Each real query sees the keys, mask, scale,
+    softcap and float32 softmax that the grid form gives it; the grid's
+    other cells (512 - 47 of a [32, 16] mixed step's, 4096 - 253 of a
+    [64, 64] one's) are not computed."""
+    n = q.shape[0]
+    tq = valid.shape[1]
+    att = functools.partial(attend, softcap=softcap, window=window,
+                            q_scale=q_scale)
+    last_t = jnp.maximum(rows.n_valid - 1, 0)
+    last = rows.start + last_t
+    o1 = att(q.at[last].get(mode="clip")[:, None], k, v, kv_lens,
+             jnp.take_along_axis(q_positions, last_t[:, None], axis=1))
+    o = jnp.zeros_like(q).at[
+        jnp.where(rows.n_valid == 1, last, n)].set(o1[:, 0], mode="drop")
+
+    def one(a, r, axis=0):      # row r of `a`, kept as an axis of one
+        return jax.lax.dynamic_slice_in_dim(a, r, 1, axis)
+
+    def chunk_row(j, o):
+        r = rows.order[j]       # the longest rows lead: one of `n_long`
+        # its token rows, past the step's last one read clipped
+        cells = rows.start[r] + jnp.arange(tq, dtype=jnp.int32)
+        o_r = att(q.at[cells].get(mode="clip")[None], one(k, r, 1),
+                  None if v is None else one(v, r, 1), one(kv_lens, r),
+                  one(q_positions, r))
+        return o.at[jnp.where(valid[r], cells, n)].set(o_r[0], mode="drop")
+
+    return jax.lax.fori_loop(0, rows.n_long, chunk_row, o)
 
 
 @jax.named_scope("attention")

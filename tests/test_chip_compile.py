@@ -219,3 +219,47 @@ def test_a_mixed_steps_linear_layers_compile_for_a_v5e_over_its_rows(
     assert _grid_wide_ops(hlo) == []
     assert _LEAF_MOVES.findall(hlo) == []
     assert _grid_wide_ops(_mixed_layers_hlo(one_chip, "grid")) != []
+
+
+def _attention_hlo(one_chip, form: str) -> str:
+    """Optimised HLO of one layer's attention of Moonlight's usual
+    [8, 64] mixed step (seven decode rows beside one 64-token chunk,
+    4096 keys a row of the one 576-wide latent leaf, 16 heads) on
+    gathered keys: over the grid (`attend`) or over the step's rows
+    (`attention_rows` on its 256 flat rows), tools/
+    attention_rows_bench.py's two programs."""
+    from dynamo_tpu.ops import attention as attn
+    rows, chunk, heads, hd, keys = 8, 64, 16, 576, 4096
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    k, lens = arr((1, rows, keys, hd)), arr((rows,), jnp.int32)
+    pos, valid = arr((rows, chunk), jnp.int32), arr((rows, chunk), jnp.bool_)
+    if form == "grid":
+        return jax.jit(lambda q, k, lens, pos: attn.attend(
+            q, k, None, lens, pos)).lower(
+            arr((rows, chunk, heads, hd)), k, lens, pos).compile().as_text()
+    return jax.jit(lambda q, k, lens, pos, valid, start: attn.attention_rows(
+        q, k, None, lens, pos, attn.step_rows(valid, start), valid)).lower(
+        arr((256, heads, hd)), k, lens, pos, valid, lens).compile().as_text()
+
+
+def _largest_f32(hlo: str) -> int:
+    """Elements of the largest float32 array an op of `hlo` makes."""
+    sizes = [functools.reduce(lambda a, b: a * int(b), dims.split(","), 1)
+             for dims in re.findall(r"=\s*f32\[([0-9,]+)\]", hlo)]
+    return max(sizes)
+
+
+def test_a_mixed_steps_attention_compiles_for_a_v5e_over_its_rows(one_chip):
+    """The row form at a served shape is taken by the chip's compiler
+    and makes no float32 array as large as the grid's scores (8 x 16 x
+    64 x 4096: 134 MB a tensor a layer): its largest is the upcast of the
+    gathered keys, which the grid form makes too, and its chunk row's
+    scores are an eighth of the grid's. The grid form is caught making
+    them."""
+    scores = 8 * 16 * 64 * 4096
+    rows_hlo = _attention_hlo(one_chip, "rows")
+    assert "while" in rows_hlo
+    assert _largest_f32(rows_hlo) == 8 * 4096 * 576 < scores
+    assert _largest_f32(_attention_hlo(one_chip, "grid")) >= scores

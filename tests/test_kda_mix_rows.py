@@ -83,7 +83,7 @@ def _rows_form(lp, state, valid, slots, fresh, x, layout, group=None):
         at = np.arange(N)
         rows, start = x.reshape(N, -1), first
     kw = {} if group is None else {"group": group}
-    plan = llama.kda_rows(jnp.asarray(valid), jnp.asarray(start))
+    plan = llama.step_rows(jnp.asarray(valid), jnp.asarray(start))
     # what no layer wrote must reach no result
     scratch = jnp.full((N, h, d), np.nan, jnp.float32)
     kda_s, kda_conv, o = jax.jit(functools.partial(

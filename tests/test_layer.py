@@ -243,8 +243,12 @@ def test_layer_scans_hold_the_reference_layers_projections(name):
         assert sum(prims[p] for p in _DOTS) == projections + attn + mlp, (
             prims, projections, attn, mlp)
     # the engine's step (`last_idx`) at a grid larger than its flat rows:
-    # the layer's token-wise halves twice, once a branch of their `cond`s,
-    # and attention ONCE, between them and outside both
+    # the layer's token-wise halves twice, once a branch of their `cond`s.
+    # Attention ONCE, between them and outside both, where the row form
+    # does not pay at this shape; where it does, inside the back half's:
+    # the grid form in one branch, the row form in the other (one query a
+    # row, and a chunk row's own inside its loop: the same two matmuls
+    # each)
     step = _layer_scan(jax.make_jaxpr(
         lambda p, c, t, pos, pt, kl, wi, last: llama.forward(
             p, cfg, t, c, AttnMetadata(pos, pt, kl, wi), with_aux=True,
@@ -252,5 +256,7 @@ def test_layer_scans_hold_the_reference_layers_projections(name):
         params, cache, _i32(16, 16), _i32(16, 16), _i32(16, pb), _i32(16),
         _i32(16, 16), _i32(16)), nl)
     assert step["cond"] == 2 and not [p for p in _COLLECTIVES if step[p]]
-    assert sum(step[p] for p in _DOTS) == 2 * (projections + mlp) + paged, (
+    forms = 3 if llama.step_attention_rows(cfg, 16) else 1
+    assert sum(step[p] for p in _DOTS) \
+        == 2 * (projections + mlp) + forms * paged, (
         step, projections, paged, mlp)

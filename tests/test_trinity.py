@@ -14,6 +14,7 @@ engine takes the capacity form (ModelConfig.moe_dropless), which drops,
 and the published model's 128 take the dropless one.
 """
 import dataclasses
+import functools
 import hashlib
 import sys
 
@@ -522,6 +523,44 @@ def test_an_older_models_program_is_the_parents(name, program):
     text = program_texts(name, rows=PROGRAM_ROWS.get(name, 8))[program]
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == PARENT_PROGRAMS[name, program], (name, program)
+
+
+# PR 48: the same at the SERVED widths and step shapes
+# (`benchmark/configs/<name>`, the cells' own [rows, chunk]), traced from
+# PR 48's parent (b77b8fd). A [32, 16] step keeps its attention on the
+# grid outside the `cond`s (ops/attention.attention_rows_pay says the row
+# form does not pay there) and no decode window holds the changed code:
+# those are the parent's to the character. An [8, 64] or [64, 64] step
+# takes the row form inside the back half's `cond`, MEANT to change:
+# Trinity's digest is this tree's (the parent's read 332fb541881f7a7b).
+SERVED_PROGRAMS = {
+    ("mistral-7b", 32, 16, "step"): "3048639dbbb4697c",
+    ("mistral-7b", 32, 16, "window"): "af20e6991fff77ee",
+    ("mixtral-8x7b", 32, 16, "step"): "657974782ae31be3",
+    ("mixtral-8x7b", 32, 16, "window"): "4ec4c91e571b5d13",
+    ("olmoe-1b-7b", 32, 16, "step"): "512b02ddc6af76a2",
+    ("olmoe-1b-7b", 32, 16, "window"): "3e4a829462e81321",
+    ("moonlight-16b-a3b", 8, 64, "window"): "659294f9b1fcc10f",
+    ("mellum2-12b-a2.5b", 8, 64, "window"): "a35fc1fb307f3aa2",
+    ("trinity-mini", 8, 64, "step"): "5c5360c8188fecef",
+    ("trinity-mini", 8, 64, "window"): "88a92f6215229ff7",
+    ("ling-3.0-flash-vl", 64, 64, "window"): "4499a3a1552d90c3",
+    ("falcon-h1-34b", 64, 64, "window"): "279a188e98469eba",
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _served_digests(name, rows, chunk):
+    return {key: hashlib.sha256(text.encode()).hexdigest()[:16]
+            for key, text in program_texts(name, rows=rows,
+                                           chunk=chunk).items()}
+
+
+@pytest.mark.parametrize("name,rows,chunk,program", sorted(SERVED_PROGRAMS))
+def test_a_served_shapes_program_is_the_one_recorded(name, rows, chunk,
+                                                     program):
+    assert _served_digests(name, rows, chunk)[program] \
+        == SERVED_PROGRAMS[name, rows, chunk, program]
 
 
 def test_the_new_models_programs_hold_what_the_old_ones_lack():

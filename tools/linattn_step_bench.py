@@ -172,7 +172,7 @@ def mixed_layers_of(form: str, cfg: ModelConfig, plan: dict, group: int = 0):
     h, d = cfg.num_heads, cfg.linear_head_dim
 
     def run(kda_s, kda_conv, x, layers):
-        rows = llama.kda_rows(valid, slot[first] if fits else first)
+        rows = llama.step_rows(valid, slot[first] if fits else first)
 
         def layer(carry, xs):
             kda_s, kda_conv, o = carry
