@@ -3,6 +3,16 @@
 
   {"name", "layer", "unit", "better", "moves", "reader", "expr"}
 
+One file holds an expression. A metric that reads what an accepted one
+reads, in a cell the accepted one's list does not name (only a benchmark
+PR may append to a list), says so instead of copying it:
+
+  {"name", ..., "expr_of": "<the accepted metric's name>"}
+
+and `load_metric` hands back that file's `expr` under this name. The
+named file holds an `expr` of its own: no chains. The next benchmark PR
+deletes such a file and appends its cell to the named metric's list.
+
 `reader` names where the number comes from (`prom`, `engine`, `trace`,
 `client`: recorded for the reader of the file; the evaluator below takes
 every source alike) and `expr` is a small tree:
@@ -18,6 +28,14 @@ every source alike) and `expr` is a small tree:
                                   busy_s, chips
   {"trace_module_median_s": "<pattern>"}   median duration of device-0
                                   program executions whose name matches
+  {"trace_window_step_median_s": "<base>"}   seconds a device step of the
+                                  LONGEST decode-window rung the traced
+                                  slice holds: the median of `<base>_full`
+                                  / run.decode_steps where the slice holds
+                                  one, else of the longest `<base>_w<n>`
+                                  / n (the rung is in the program's name)
+  {"trace_window_rung_steps": "<base>"}   that rung's steps: which rung
+                                  the leaf above read
   {"trace_op_share": "<pattern>"} time in ops matching / busy time
   {"client": "<key>"}             from the load generator's rows
   {"peak": "<key>"}               peaks.json for this device_kind
@@ -63,7 +81,37 @@ def load_metric(name: str, root: str) -> dict:
     if spec.get("reader") not in READER_KINDS:
         raise ValueError(f"{path}: unknown reader kind "
                          f"{spec.get('reader')!r} (known: {READER_KINDS})")
+    if "expr_of" in spec:
+        held = load_metric(spec["expr_of"], root)
+        if "expr" in spec or "expr_of" in held:
+            raise ValueError(f"{path}: `expr_of` stands where an `expr` "
+                             f"would, and names a file that holds one")
+        spec["expr"] = held["expr"]
     return spec
+
+
+def window_rung(modules: dict, base: str, full_steps):
+    """(steps, median seconds of one execution) of the longest rung of the
+    decode-window ladder among `modules` ({program name: [durations_s]}),
+    or None where it holds none. `<base>_full` is the ladder's top and
+    runs `full_steps` device steps, `<base>_w<n>` runs n. A cell that is
+    mostly mixed steps does not hold a full window in every 4 s slice
+    (ledger, PR 46's notes): a shorter rung spreads the window's once-only
+    gather over fewer steps, so its step reads longer, never shorter."""
+    full, rung = re.compile(base + "_full"), re.compile(base + r"_w(\d+)$")
+    by_steps = {}
+    for name, ds in modules.items():
+        if full.search(name):
+            steps = full_steps
+        else:
+            m = rung.search(name)
+            steps = int(m.group(1)) if m else None
+        if steps and ds:
+            by_steps.setdefault(int(steps), []).extend(ds)
+    if not by_steps:
+        return None
+    steps = max(by_steps)
+    return steps, statistics.median(by_steps[steps])
 
 
 def _delta(pair, key):
@@ -101,6 +149,15 @@ def evaluate(expr, ctx: dict):
                                    .get("modules") or {}).items()
                 if pat.search(name) for d in ds]
         return statistics.median(durs) if durs else None
+    for leaf in ("trace_window_step_median_s", "trace_window_rung_steps"):
+        if leaf in expr:
+            found = window_rung(
+                (ctx.get("trace") or {}).get("modules") or {}, expr[leaf],
+                (ctx.get("run") or {}).get("decode_steps"))
+            if found is None:
+                return None
+            steps, median = found
+            return median / steps if leaf.endswith("_s") else float(steps)
     if "trace_op_share" in expr:
         tr = ctx.get("trace") or {}
         pat = re.compile(expr["trace_op_share"])

@@ -355,6 +355,7 @@ async def bench(args, bench_json: dict, cell_entry: dict) -> dict:
             await asyncio.sleep(max(0.0, t0 - 0.2 - time.monotonic()))
             eng0, prom0 = await served.engine_metrics(), await served.prom()
         trace_dir, trace_wall = os.path.join(out_dir, "trace"), None
+        stopping = None
         if args.trace:
             slice_s = min(TRACE_SLICE_S, args.seconds / 3)
             await asyncio.sleep(max(0.0, t0 + (args.seconds - slice_s) / 2
@@ -365,10 +366,20 @@ async def bench(args, bench_json: dict, cell_entry: dict) -> dict:
             ts = time.monotonic()
             await asyncio.sleep(slice_s)
             trace_wall = time.monotonic() - ts
-            await asyncio.get_running_loop().run_in_executor(
+            # stopping writes the file, and on a busy cell that outlasts
+            # the window: the end-of-window scrape does not wait for it,
+            # or it reads the batch draining behind the clients' cut and
+            # counts what that first dispatches as compiled in the window
+            stopping = asyncio.get_running_loop().run_in_executor(
                 None, jax.profiler.stop_trace)
         await asyncio.sleep(max(0.0, t0 + args.seconds - time.monotonic()))
         eng1, prom1 = await served.engine_metrics(), await served.prom()
+        scrape_late_s = time.monotonic() - (t0 + args.seconds)
+        if stopping is not None:
+            await stopping
+            log(f"profiler stopped {time.monotonic() - ts - trace_wall:.1f}s "
+                f"after the slice; the scrape came {scrape_late_s:.3f}s "
+                f"after the window's end")
         await read_event(proc, "done", args.seconds + 90.0)
         if args.sweep:
             await sweep(args, proc, plan, out_dir)
@@ -449,6 +460,11 @@ async def bench(args, bench_json: dict, cell_entry: dict) -> dict:
             if v is not None:
                 result["metrics"][m["name"]] = {"value": v,
                                                 "unit": m["unit"]}
+        # everything an expression can read, kept: a later metric, or an
+        # old one under a new name, is evaluated on this run again without
+        # the chip (tests/test_harness.py does)
+        with open(os.path.join(out_dir, "layer_context.json"), "w") as f:
+            json.dump(rctx, f, default=str)
         trace.pop("all_ops", None)
         trace.pop("modules", None)
         with open(os.path.join(out_dir, "trace_reduced.json"), "w") as f:
@@ -466,6 +482,7 @@ async def bench(args, bench_json: dict, cell_entry: dict) -> dict:
             "setup": {"ready_s": t_ready - T_START,
                       "checks_s": t_checked - t_ready,
                       "warmup_s": warm["warm_s"], "setup_s": setup_s},
+            "scrape_late_s": scrape_late_s,
             "programs_first_dispatched": prom1.get("llm_engine_recompiles"),
             "engine_delta": {k: eng1[k] - eng0[k] for k in eng0
                              if isinstance(eng0[k], (int, float))},

@@ -56,17 +56,14 @@ TWINS = {"ssm.fh1_state_rw_mb": "linattn.state_rw_mb",
          "attn.fh1_kv_read_mb": "attn.kv_read_mb",
          "attn.fh1_kv_pad_share": "attn.kv_pad_share",
          "device.fh1_window_step_ms": "device.window_step_ms",
-         "step.fh1_mixed_period_ms": "step.mixed_period_ms",
-         "step.fh1_window_period_ms": "step.window_period_ms",
-         "step.fh1_mixed_time_share": "step.mixed_time_share",
          # the review round's: where itl_p95_ms stands among the gaps, the
          # flat width --max-prefill-batch 3 is set for, the chain, the host
          "stream.fh1_gap_mixed_share": "stream.gap_mixed_share",
          "stream.fh1_gap_mixed_ms": "stream.gap_mixed_ms",
          "stream.fh1_gap_window_ms": "stream.gap_window_ms",
-         "ssm.fh1_flat_step_share": "linattn.flat_step_share",
-         "pipeline.fh1_mixed_chained_share": "pipeline.mixed_chained_share",
-         "host.fh1_exposed_between_ms": "host.exposed_between_ms"}
+         "ssm.fh1_flat_step_share": "linattn.flat_step_share"}
+# the step periods, the chain and the host between two steps are every
+# cell's since PR 49 (no `workloads` key): their five copies went
 NEW = {"device.fh1_window_roofline", "device.fh1_ssm_kernel_share",
        "device.fh1_ssm_step_roofline", *TWINS}
 
@@ -204,12 +201,13 @@ def test_the_cell_and_its_metrics_are_entries_of_the_benchmark():
                           "moves", "workloads"}
     assert {mine[n]["layer"] for n in NEW if n.startswith("ssm.")} \
         == {"linear attention and state"}
-    # no accepted metric's list names the new cell
-    assert not any(CELL in m.get("workloads", ())
-                   for m in b["per_layer"] if m["name"] not in NEW)
+    # (an accepted entry's list MAY name this cell: since PR 49 a cell is
+    # named in a list and never in a metric's name, and what stays true,
+    # that no two entries read one expression in one cell, is
+    # test_benchmark_lists.py's)
     # a twin is its original's expression and entry under its own name
     for name, of in TWINS.items():
-        spec, old = (load("layer_metrics", f"{n}.json") for n in (name, of))
+        spec, old = (readers.load_metric(n, HERE) for n in (name, of))
         assert spec["expr"] == old["expr"], name
         entry = by_name(b["per_layer"], of)
         assert {k: v for k, v in mine[name].items()
@@ -295,8 +293,8 @@ KERNEL = (432000 * 8388608 / 50.0 / 819e9) / (0.2 * 3.0 / 4.0)
     ("stream.fh1_gap_window_ms", 60.0),
     # 1500 steps of the state, 800 of them windows: 693 of 700 flat
     ("ssm.fh1_flat_step_share", 99.0),
-    ("pipeline.fh1_mixed_chained_share", 82.5),
-    ("host.fh1_exposed_between_ms", 1.3)])
+    ("pipeline.mixed_chained_share", 82.5),
+    ("host.exposed_between_ms", 1.3)])
 def test_the_metric_files_evaluate_on_recorded_sources(name, want):
     ctx = {"prom": (PROM_0, PROM_1), "engine": (ENGINE_0, ENGINE_1),
            "peak": {"hbm_bytes_per_s": 819e9},

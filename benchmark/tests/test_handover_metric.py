@@ -30,7 +30,7 @@ def test_the_share_on_two_scrapes(program):
     assert got is None if want is None else got == pytest.approx(want)
 
 
-def test_the_entry_is_appended_and_lists_every_cell():
+def test_the_entry_is_every_cells():
     """(That an entry agrees with its file is test_harness's
     test_benchmark_json_names_units_and_files, for every metric.)"""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -39,9 +39,7 @@ def test_the_entry_is_appended_and_lists_every_cell():
     assert entry == {
         "name": METRIC, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "engine host loop",
-        "moves": "output_tok_s",
-        "workloads": [w["name"] for w in bench["workloads"]]}
-    assert bench["per_layer"].index(entry) >= 126
+        "moves": "output_tok_s"}   # no `workloads` key: every cell's
 
 
 def test_the_counters_are_engine_metrics_fields():

@@ -1,5 +1,6 @@
 """By hand, on the CPU: `python -m pytest benchmark/tests -q`.
 Not part of the repo's tier-1 suite (tests/)."""
+import functools
 import json
 import os
 import re
@@ -209,8 +210,9 @@ def ctx(**kw):
                     "decode_step_bytes": 8.19e9},
             "trace": {"busy_s": 3.0, "window_s": 4.0, "chips": 1,
                       "program_mean_s": 0.85 / 6,
-                      "modules": {"jit__engine_decode_window": [0.16] * 5,
-                                  "jit__engine_step": [0.05]},
+                      "modules": {"jit_engine_decode_window_full": [0.16] * 5,
+                                  "jit_engine_decode_window_w2": [0.05],
+                                  "jit_engine_step": [0.05]},
                       "all_ops": [["fusion.1", 2.0], ["all-reduce.3", 0.6]]}}
     base.update(kw)
     return base
@@ -230,8 +232,9 @@ def ctx(**kw):
     ("sched.decode_stall_share", 5.0),
     ("host.overlap_share", 75.0),
     ("device.idle_share", 25.0),
-    ("device.decode_step_ms", 20.0),
-    ("device.decode_window_roofline", 50.0),
+    ("device.window_step_ms", 20.0),      # the full rung's 0.16 s / 8
+    ("device.window_roofline", 50.0),
+    ("device.window_rung_steps", 8.0),
     ("device.collective_share", 20.0)])
 def test_layer_metric_files_on_recorded_sources(name, want):
     spec = readers.load_metric(name, HERE)
@@ -386,27 +389,74 @@ def test_configs_differ_from_the_published_file_in_depth_only():
 
 # -- end to end on the CPU, and adding files only ---------------------------
 
+REHEARSAL_SEED = 2**31 + 17
+
+
 def run_rehearsal(root, workload, seconds="4"):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, os.path.join(root, "benchmark", "run.py"),
-         "--workload", workload, "--seed", str(2**31 + 17), "--seconds",
+         "--workload", workload, "--seed", str(REHEARSAL_SEED), "--seconds",
          seconds, "--trace", "1", "--rehearsal"],
         env=env, capture_output=True, text=True, timeout=1500)
     assert out.returncode == 0, out.stderr[-3000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
+@functools.lru_cache(maxsize=None)
+def rehearsed(workload):
+    """One rehearsal a cell for this file's tests: minutes on the CPU."""
+    return run_rehearsal(ROOT, workload)
+
+
 @pytest.mark.parametrize("workload", ["mistral-7b.decode-closed",
                                       "mistral-7b.chat-open"])
 def test_rehearsal_end_to_end(workload):
-    line = run_rehearsal(ROOT, workload)
+    line = rehearsed(workload)
     assert line["correct"] is False and line["rehearsal"] is True
     assert line["device"]["platform"] == "cpu"
-    assert line["attempted"] > 0 and line["failed"] == 0
+    # an open request of 64-128 tokens ends inside a 4 s window; a closed
+    # one of 384-512 need not on this CPU: that steps ran is what is held
+    assert line["failed"] == 0
+    if workload.endswith("-open"):
+        assert line["attempted"] > 0
+    assert line["metrics"]["host.resume_ms"]["value"] > 0
     assert line["metrics"]["warmup.compiles_in_window"]["value"] == 0
     assert "device.idle_share" not in line["metrics"]   # no CPU number
+
+
+def load_context(path):
+    """A traced run's `layer_context.json` (run.py leaves one in the run's
+    directory) as `readers.evaluate`'s `ctx`."""
+    with open(path) as f:
+        ctx = json.load(f)
+    return {k: tuple(v) if k in ("prom", "engine") else v
+            for k, v in ctx.items()}
+
+
+def test_the_fold_moves_no_value():
+    """PR 48's 128 expressions and today's list on ONE rehearsal run's
+    context (`layer_context.json`, which every traced run leaves): every
+    former (cell, name) value equals, to the last digit, the value of the
+    entry that reads it in that cell today. A CPU trace has no device
+    plane, so the trace leaves read nothing on both sides here: that side
+    is tests/test_benchmark_lists.py's made-up modules and the chip's."""
+    import test_benchmark_lists as lists
+    cell = "mistral-7b.decode-closed"
+    rehearsed(cell)
+    ctx = load_context(os.path.join(
+        ROOT, "chiprun_out", "benchmark", cell, f"s{REHEARSAL_SEED}-t1",
+        "layer_context.json"))
+    read = 0
+    for former in lists.FORMER["per_layer"]:
+        was = readers.evaluate(former["expr"], ctx)
+        for c in former.get("workloads", lists.FORMER["cells"]):
+            now, = lists.now_named(former, c)
+            assert readers.evaluate(now["expr"], ctx) == was, \
+                (former["name"], c, now["name"])
+        read += was is not None
+    assert read >= 60   # counters and client rows: most of the list
 
 
 def test_a_cell_mix_metric_and_check_are_added_as_files_only(tmp_path):

@@ -55,13 +55,6 @@ METRICS = {
     "moe.swa_pad_share": "MoE dispatch",
     "moe.swa_dropped_share": "MoE dispatch",
     "device.swa_moe_kernel_share": "MoE dispatch",
-    "step.swa_mixed_period_ms": "engine host loop",
-    "step.swa_window_period_ms": "engine host loop",
-    "step.swa_mixed_time_share": "scheduler",
-    "host.swa_exposed_between_ms": "engine host loop",
-    "host.swa_resume_ms": "engine host loop",
-    "host.swa_emit_ms": "engine host loop",
-    "host.swa_submit_ms": "engine host loop",
     "stream.swa_gap_mixed_share": "scheduler",
     "stream.swa_gap_mixed_ms": "scheduler",
     "stream.swa_gap_window_ms": "scheduler"}
@@ -73,16 +66,10 @@ TWINS = {"moe.swa_dropped_share": "moe.dropped_share",
          "moe.swa_window_experts_hit": "moe.mla_window_experts_hit",
          "device.swa_moe_kernel_share": "device.moe_kernel_share",
          "device.swa_window_step_ms": "device.window_step_ms",
-         # the step periods, the host between two steps and the gaps
-         # between a stream's tokens (PR 35's layers), which this cell's
-         # bottleneck list in PERF.md rests on
-         "step.swa_mixed_period_ms": "step.mixed_period_ms",
-         "step.swa_window_period_ms": "step.window_period_ms",
-         "step.swa_mixed_time_share": "step.mixed_time_share",
-         "host.swa_exposed_between_ms": "host.exposed_between_ms",
-         "host.swa_resume_ms": "host.resume_ms",
-         "host.swa_emit_ms": "host.emit_ms",
-         "host.swa_submit_ms": "host.submit_ms",
+         # the gaps between a stream's tokens (PR 35's layer). The step
+         # periods and the host between two steps, which this cell's
+         # bottleneck list in PERF.md rests on, are every cell's since PR
+         # 49 (no `workloads` key) and their seven copies went
          "stream.swa_gap_mixed_share": "stream.gap_mixed_share",
          "stream.swa_gap_mixed_ms": "stream.gap_mixed_ms",
          "stream.swa_gap_window_ms": "stream.gap_window_ms"}
@@ -181,8 +168,8 @@ def test_the_sizes_are_the_arithmetic_of_the_file_beside_them():
     weights, kv = roofline["expr"]["args"][1]["args"][0]["args"][0]["args"]
     assert weights["args"][0] == {"const": fixed}
     assert weights["args"][1]["args"][0] == {"const": per_hit}
-    assert weights["args"][1]["args"][1] == load(
-        "layer_metrics", "moe.swa_window_experts_hit.json")["expr"]
+    assert weights["args"][1]["args"][1] == readers.load_metric(
+        "moe.swa_window_experts_hit", HERE)["expr"]
     # KV by kind, as attn.swa_kv_read_mb has it
     assert kv == load("layer_metrics", "attn.swa_kv_read_mb.json")[
         "expr"]["args"][0]
@@ -228,12 +215,13 @@ def test_the_cell_and_its_metrics_are_entries_of_the_benchmark():
             == (m["unit"], m["better"], layer)
         assert m["source"] == ("device_trace" if spec["reader"] == "trace"
                                else "program_counter")
-    # the lists of the other metrics do not name the new cell
-    others = [m for m in b["per_layer"] if m["name"] not in METRICS]
-    assert not any(CELL in m.get("workloads", ()) for m in others)
+    # (an accepted entry's list MAY name this cell: since PR 49 a cell is
+    # named in a list and never in a metric's name, and what stays true,
+    # that no two entries read one expression in one cell, is
+    # test_benchmark_lists.py's)
     # a twin is the accepted metric's expression under a name of its own
     for name, of in TWINS.items():
-        spec, old = (load("layer_metrics", f"{n}.json") for n in (name, of))
+        spec, old = (readers.load_metric(n, HERE) for n in (name, of))
         assert spec["expr"] == old["expr"], name
         assert (spec["unit"], spec["better"]) == (old["unit"],
                                                   old["better"]), name
@@ -341,13 +329,13 @@ STEP_BYTES = 966246912 + 148635648 * 41 + KV_BYTES
     ("moe.swa_pad_share", 100 * (1 - 24e5 / 32e5)),
     ("moe.swa_experts_hit", 59.0),
     ("moe.swa_window_experts_hit", 41.0),
-    ("step.swa_mixed_period_ms", 36.0),
-    ("step.swa_window_period_ms", 16.0),
-    ("step.swa_mixed_time_share", 100 * 28.8 / (28.8 + 25.6)),
-    ("host.swa_exposed_between_ms", 0.7),
-    ("host.swa_resume_ms", 0.1),
-    ("host.swa_emit_ms", 0.2),
-    ("host.swa_submit_ms", 0.3),
+    ("step.mixed_period_ms", 36.0),
+    ("step.window_period_ms", 16.0),
+    ("step.mixed_time_share", 100 * 28.8 / (28.8 + 25.6)),
+    ("host.exposed_between_ms", 0.7),
+    ("host.resume_ms", 0.1),
+    ("host.emit_ms", 0.2),
+    ("host.submit_ms", 0.3),
     ("stream.swa_gap_mixed_share", 32.0),
     ("stream.swa_gap_mixed_ms", 36.0),
     ("stream.swa_gap_window_ms", 16.0)])
@@ -369,7 +357,10 @@ def test_the_metric_files_evaluate_on_recorded_sources(name, want):
     empty = {"prom": ({}, {}), "engine": ({}, {}), "trace": {},
              "client": {}, "peak": {}, "run": {}}
     assert readers.evaluate(spec["expr"], empty) is None
-    assert set(METRICS) >= {name}
+    # the cell's own, or every cell's (no `workloads` key, since PR 49)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry = by_name(json.load(f)["per_layer"], name)
+    assert name in METRICS or "workloads" not in entry
 
 
 def test_each_check_applies_to_its_own_configuration_alone():
