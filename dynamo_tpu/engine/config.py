@@ -58,7 +58,9 @@ class ModelConfig:
     # sliding_window > 0): one entry a layer, "sliding_attention" |
     # "full_attention", as HF's `layer_types`. () = Gemma-2's default,
     # even layers sliding and odd ones global. Any pattern is one list:
-    # Gemma-2's alternation, every layer sliding, three sliding to one full
+    # Gemma-2's alternation, every layer sliding, three sliding to one full.
+    # With `conv_l_cache` > 0 the entries are "conv" | "full_attention"
+    # instead: which layers are gated short convolutions (`layer_kinds`)
     layer_types: tuple = ()
     # How a sliding layer is SERVED. False (Gemma-2): a mask of its width
     # over the full-length gather of the one pool every layer shares, one
@@ -203,6 +205,20 @@ class ModelConfig:
     ssm_out_multiplier: float = 1.0
     mlp_multipliers: tuple = (1.0, 1.0)
     lm_head_multiplier: float = 1.0
+    # Gated short-convolution layers (LFM2). `conv_l_cache` K > 0 switches
+    # them on, and `layer_types` then says which layers they are, one
+    # entry a layer, "conv" | "full_attention" (layer kind "conv" in
+    # `layer_kinds`). Such a layer's mixer is B | C | u = xn W_in [D, 3D];
+    # c = a causal depth-wise convolution of K taps over B * u (NO
+    # activation, no bias: models/loader.py refuses a file with one);
+    # out = (C * c) W_out. It holds no pages and no matrix state: its
+    # per-sequence state is the last K - 1 rows of B * u (`state_leaves`:
+    # ONE leaf, `conv_tail`).
+    conv_l_cache: int = 0
+    # what the router adds to the sum of the k kept weights before it
+    # divides by it (`norm_topk_prob`): the published constant of the
+    # family (DeepSeek-V3's 1e-20; LFM2's 1e-6)
+    moe_renorm_eps: float = 1e-20
     # decode attention impl: "auto" and "off" are the XLA gather path on
     # every platform (models/llama._decode_kernel_mode says why); "on" is
     # the compiled Pallas kernel and raises at engine construction where it
@@ -259,10 +275,17 @@ class ModelConfig:
         return self.mamba_d_ssm > 0
 
     @property
+    def has_conv(self) -> bool:
+        """Gated short-convolution layers among its layers (`layer_types`
+        says which)."""
+        return self.conv_l_cache > 0
+
+    @property
     def has_state(self) -> bool:
         """Holds a recurrent state a sequence (`state_leaves`): linear-
-        attention layers, or a state-space mixer beside attention."""
-        return self.linear_group_size > 0 or self.has_ssm
+        attention layers, a state-space mixer beside attention, or gated
+        short-convolution layers (whose state is their tail alone)."""
+        return self.linear_group_size > 0 or self.has_ssm or self.has_conv
 
     @property
     def mamba_conv_dim(self) -> int:
@@ -275,10 +298,23 @@ class ModelConfig:
         "swa" (a sliding layer served from the window pool; "mha" in all
         but its cache and its RoPE table) | "par" (softmax attention AND
         a state-space mixer side by side: the one kind that lies on the
-        paged cache's layer axis and on the state's). A model without
-        linear layers and without a window pool is one kind throughout."""
+        paged cache's layer axis and on the state's) | "conv" (a gated
+        short convolution: no pages, a tail on the state's axis; given by
+        `layer_types`, as the sliding layers are). A model without
+        linear layers, conv layers and a window pool is one kind
+        throughout."""
         own = "par" if self.has_ssm else "mla" if self.is_mla else "mha"
         g = self.linear_group_size
+        if self.has_conv:
+            if len(self.layer_types) != self.num_layers or \
+                    set(self.layer_types) - {"conv", "full_attention"}:
+                raise ValueError(
+                    f"{self.name}: layer_types {self.layer_types!r}: one "
+                    f"of conv | full_attention a layer "
+                    f"({self.num_layers}) is what a model with conv "
+                    f"layers gives")
+            return tuple("conv" if t == "conv" else own
+                         for t in self.layer_types)
         if self.window_pool:
             return tuple("swa" if s else own for s in self.sliding_layers())
         return tuple(own if not g or (i + 1) % g == 0 else "kda"
@@ -298,7 +334,8 @@ class ModelConfig:
 
     @property
     def num_state_layers(self) -> int:
-        return sum(kind in ("kda", "par") for kind in self.layer_kinds())
+        return sum(kind in ("kda", "par", "conv")
+                   for kind in self.layer_kinds())
 
     @property
     def local_experts(self) -> int:
@@ -314,7 +351,11 @@ class ModelConfig:
         conv_size - 1 inputs of the q | k | v convolution. The parallel
         block's: `ssm_s`, the mixer's [heads, d_head, d_state] matrix,
         float32 likewise, and `ssm_conv`, the last d_conv - 1 inputs of
-        the x | B | C convolution."""
+        the x | B | C convolution. A conv layer's: `conv_tail` alone, the
+        last conv_l_cache - 1 rows of B * u, in the model's dtype."""
+        if self.has_conv:
+            return {"conv_tail": ((self.conv_l_cache - 1, self.hidden_size),
+                                  self.dtype)}
         if self.has_ssm:
             return {"ssm_s": ((self.mamba_n_heads, self.mamba_d_head,
                                self.mamba_d_state), "float32"),
@@ -640,7 +681,8 @@ def refuse_unserved(model_cfg: ModelConfig,
         * bool(ecfg.spec_decode),
     }
     keeper = "a state-space mixer beside attention keeps" if cfg.has_ssm \
-        else "linear-attention layers keep"
+        else "gated short-convolution layers keep a convolution tail," \
+        if cfg.has_conv else "linear-attention layers keep"
     stores = (
         (cfg.has_state,
          f"{keeper} a recurrent state a sequence "

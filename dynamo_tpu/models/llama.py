@@ -5,7 +5,10 @@ QK-norm, output gate, RoPE-less full layers and dense lead in front of a
 window pool's period loop: `qkv_proj`, `_out_gate`, `rope_table`,
 `layer_period`; and Falcon-H1's parallel block, a Mamba-2 state-space mixer
 beside softmax attention in every layer, with its muP multipliers:
-`_ssm_front`, `ssm_decode`, `ssm_mix_rows`, `_times`).
+`_ssm_front`, `ssm_decode`, `ssm_mix_rows`, `_times`; and LFM2's gated
+short-convolution layers, a kind by `layer_types` that holds a tail and no
+pages, served by the period loop: `_conv_front`, `conv_decode`,
+`conv_mix_rows`).
 
 Functional JAX, TPU-first:
 - parameters are a pytree of arrays **stacked over layers** and the layer loop
@@ -144,18 +147,21 @@ class LayerRun(NamedTuple):
     first: int        # the run's first layer, in the model's order
     count: int
     dense: bool       # a dense MLP (False: experts)
-    kind: str         # attention kind: "mha" | "mla" | "kda" | "swa" | "par"
+    # attention kind: "mha" | "mla" | "kda" | "swa" | "par" | "conv"
+    kind: str
     # the run's first layer among the layers that share its STORE: the
     # paged cache's layer axis runs over the "mha" / "mla" layers, the
     # recurrent state's over the "kda" ones, the window pool's over the
-    # "swa" ones (== first where all alike). A "par" layer lies on the
+    # "swa" ones, and a conv model's state axis over its "conv" ones (==
+    # first where all alike). A "par" layer lies on the
     # cache's axis AND on the state's: such a model is one run, in which
     # both are the layer's own index
     store_first: int
 
     @property
     def is_lead(self) -> bool:
-        """A stack of a `window_pool` model's dense lead (`layer_runs`)."""
+        """A stack of the dense lead in front of a period loop
+        (`layer_runs`: a `window_pool` model's, a conv model's)."""
         return self.key.startswith("lead")
 
     def store_index(self, lid):
@@ -185,7 +191,11 @@ def layer_runs(cfg: ModelConfig) -> tuple:
     like kinds each (`lead0`, ...), which come first in this tuple, run
     before the loop over periods, and lie FIRST in their kind's store
     (the window pool's layer axis: the lead's "swa" layers, then
-    `run0`'s). init_params,
+    `run0`'s). A model with gated short-convolution layers (`has_conv`)
+    is split the same way, a stack a KIND behind its lead's own stacks
+    (C C | F C C C F C C C ...: `lead0` the dense conv layers, `run0` all
+    "mha" layers, `run1` all "conv" ones), the lead's conv layers FIRST
+    on the state's layer axis. init_params,
     param_shardings, forward(), decode_forward() and models/loader.py
     all walk this."""
     lead = cfg.first_dense_layers if cfg.is_moe else 0
@@ -206,7 +216,7 @@ def layer_runs(cfg: ModelConfig) -> tuple:
                                      kind, seen[kind]))
             seen[kind] += 1
         return tuple(runs)
-    if cfg.window_pool:
+    if cfg.window_pool or cfg.has_conv:
         rest = kinds[lead:]
         return like_runs(kinds[:lead], "lead") + tuple(
             LayerRun(f"run{i}", lead + rest.index(kind), rest.count(kind),
@@ -223,8 +233,9 @@ def layer_runs(cfg: ModelConfig) -> tuple:
 
 
 class LayerPeriod(NamedTuple):
-    """The order in which a `window_pool` model's loop takes the layers
-    of its kind stacks: `count` periods, each the same `parts`, after the
+    """The order in which the loop of a model with a stack a kind (a
+    `window_pool` model, a conv model) takes the layers of its kind
+    stacks: `count` periods, each the same `parts`, after the
     `lead` runs that stand in front of the loop."""
     count: int
     # ((index in layer_runs, the kind's layers a period, the part's first
@@ -237,9 +248,11 @@ class LayerPeriod(NamedTuple):
 
 
 def layer_period(cfg: ModelConfig) -> Optional[LayerPeriod]:
-    """None but for a `window_pool` model. Its kinds repeat with a period
-    (S S S F: the shortest prefix whose repetition is the model; the
-    whole model where nothing repeats), and ONE compiled loop serves it:
+    """None but for a `window_pool` model and a model with conv layers
+    (`has_conv`). Its kinds repeat with a period (S S S F, F C C C: the
+    shortest prefix whose repetition is the model; the whole model where
+    nothing repeats, as behind LFM2's published irregular tail: served
+    right, a layer body a part), and ONE compiled loop serves it:
     a scan over periods whose body is a scan a part (models/llama.forward,
     decode_forward). A scan a run of like layers, as the hybrid has,
     would compile six layer bodies for S S S F x 3 where this compiles
@@ -247,7 +260,7 @@ def layer_period(cfg: ModelConfig) -> Optional[LayerPeriod]:
     The period is found over the layers AFTER a dense lead
     (`first_dense_layers`), whose runs stand before the loop
     (`LayerPeriod.lead`): one more layer body a run of the lead."""
-    if not cfg.window_pool:
+    if not (cfg.window_pool or cfg.has_conv):
         return None
     runs = layer_runs(cfg)
     lead = sum(run.is_lead for run in runs)
@@ -352,6 +365,16 @@ def _init_layer_stack(keys, cfg: ModelConfig, l: int, dense_mlp: bool,
                                                           f32),
             "kda_o_norm": near_one(kk[4], (l, hd)),
         })
+    elif kind == "conv":
+        # the taps drawn at unit output variance, no two alike: a path
+        # that skipped or swapped one is seen
+        layers.update({
+            "wo": dense(keys[3], (l, d, d), d),
+            "conv_in": dense(keys[0], (l, d, 3 * d), d),
+            "conv_w": (jax.random.normal(
+                jax.random.fold_in(keys[0], 73), (l, cfg.conv_l_cache, d),
+                jnp.float32) * cfg.conv_l_cache ** -0.5).astype(dt),
+        })
     elif cfg.is_mla:
         r, dn, dr = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim)
@@ -387,13 +410,13 @@ def _init_layer_stack(keys, cfg: ModelConfig, l: int, dense_mlp: bool,
             "post_attn_norm": jnp.ones((l, d), dt),
             "post_mlp_norm": jnp.ones((l, d), dt),
         })
-    if cfg.attn_bias:
+    if cfg.attn_bias and kind != "conv":
         layers.update({
             "wq_b": jnp.zeros((l, h * hd), dt),
             "wk_b": jnp.zeros((l, hkv * hd), dt),
             "wv_b": jnp.zeros((l, hkv * hd), dt),
         })
-    if cfg.qk_norm:
+    if cfg.qk_norm and kind != "conv":
         # one weight vector for all heads where the norm is a head's
         per_head = cfg.qk_norm == "head"
         layers.update({
@@ -564,6 +587,12 @@ def _layer_stack_shardings(cfg: ModelConfig, dense_mlp: bool,
             "kda_wqkv", "kda_conv_w", "kda_wf", "kda_wg", "kda_wb")})
         layers.update({name: P(None, None) for name in (
             "kda_a_log", "kda_dt_bias", "kda_o_norm")})
+    elif kind == "conv":
+        # no mesh serves a recurrent state: everything replicated
+        del layers["wq"]
+        layers.update({"wo": P(None, None, None),
+                       "conv_in": P(None, None, None),
+                       "conv_w": P(None, None, None)})
     elif cfg.is_mla:
         # the latent projection and its norm are shared by every head;
         # no mesh serves this model yet (engine/config.refuse_unserved)
@@ -591,13 +620,13 @@ def _layer_stack_shardings(cfg: ModelConfig, dense_mlp: bool,
             "post_attn_norm": P(None, None),
             "post_mlp_norm": P(None, None),
         })
-    if cfg.attn_bias:
+    if cfg.attn_bias and kind != "conv":
         layers.update({
             "wq_b": P(None, "tp"),
             "wk_b": P(None, "tp"),
             "wv_b": P(None, "tp"),
         })
-    if cfg.qk_norm:
+    if cfg.qk_norm and kind != "conv":
         # a head's norm has one weight vector, whole on every shard
         spec = P(None, None) if cfg.qk_norm == "head" else P(None, "tp")
         layers.update({"q_norm": spec, "k_norm": spec})
@@ -746,8 +775,8 @@ def yarn_inv_freq(p, dim: int) -> np.ndarray:
 
 
 def _lead_scope(run: LayerRun):
-    """`layers.lead` around the scan of a window-pool model's dense lead
-    (`layer_runs`), whose layer body is its own beside the bodies of the
+    """`layers.lead` around the scan of the dense lead in front of a
+    period loop (`layer_runs`), whose layer body is its own beside the bodies of the
     period loop; no scope around any other run."""
     return jax.named_scope("layers.lead") if run.is_lead \
         else contextlib.nullcontext()
@@ -1304,9 +1333,10 @@ def _kda_out(o: jax.Array, x: jax.Array, lp: Params,
 
 def mix_splits(cfg: ModelConfig, rows: int, tq: int) -> bool:
     """Whether a [rows, tq] step's state layers work over its rows by what
-    each holds: the state-space mixer always (`ssm_mix_rows` is its one
-    form), the linear layers where `kda_mix_splits`."""
-    return cfg.has_ssm or kda_mix_splits(rows, tq)
+    each holds: the state-space mixer and the short convolution always
+    (`ssm_mix_rows`, `conv_mix_rows`: each its mixer's one form), the
+    linear layers where `kda_mix_splits`."""
+    return cfg.has_ssm or cfg.has_conv or kda_mix_splits(rows, tq)
 
 
 def _ssm_front(x: jax.Array, lp: Params, cfg: ModelConfig):
@@ -1467,6 +1497,101 @@ def ssm_mix_rows(state: tuple, l, slots: jax.Array, lp: Params,
     return ssm_s, ssm_conv, o
 
 
+# -- the gated short convolution ------------------------------------------------
+
+def _conv_front(x: jax.Array, lp: Params, cfg: ModelConfig):
+    """A conv layer's token-wise front half: x [..., D] -> (g [..., D] =
+    B * u, the convolution's input, whose last rows are the layer's
+    state; c [..., D] the gate on its output), in the model's dtype: the
+    block's norm, `conv_in` [D, B | C | u]."""
+    d = cfg.hidden_size
+    xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
+    with jax.named_scope("shortconv.in_proj"):
+        p = jnp.einsum("...d,de->...e", xn, wmat(lp["conv_in"], xn.dtype))
+    with jax.named_scope("shortconv.gate"):
+        return p[..., :d] * p[..., 2 * d:], p[..., d:2 * d]
+
+
+def _conv_out(y: jax.Array, c: jax.Array) -> jax.Array:
+    """The convolution's output y [..., D] float32 times its gate c, in
+    the gate's dtype: what `wo` (the layer's `out_proj`) takes."""
+    with jax.named_scope("shortconv.gate"):
+        return (c.astype(jnp.float32) * y).astype(c.dtype)
+
+
+def conv_decode(state: tuple, l, slots: jax.Array, lp: Params,
+                g, c, valid, fresh=None):
+    """The mixer for one token a row (a decode step's rows, a mixed
+    step's one-token rows), between `_conv_front` and `wo`. state:
+    (conv_tail [Lc, slots + 1, K - 1, D],), `l` this layer's index in it;
+    g, c [B, D]; valid [B]: rows that are live (a finished or padding
+    row, or one another form takes, reads clipped and writes nothing);
+    fresh [B]: the row starts its sequence, from zeros whatever its slot
+    held (a decode step has none). Returns (state, o [B, D])."""
+    conv_tail, = state
+    at = _slot_index(jnp.where(valid, slots, -1), conv_tail.shape[1])
+    with jax.named_scope("shortconv.taps"):
+        tail = conv_tail.at[l, at].get(mode="clip")
+        if fresh is not None:
+            tail = jnp.where(fresh[:, None, None], 0, tail)
+        y, tail = conv_one_token(g, tail, lp["conv_w"])
+        conv_tail = conv_tail.at[l, at].set(tail, mode="drop")
+    return (conv_tail,), _conv_out(y, c)
+
+
+def conv_mix_rows(state: tuple, l, slots: jax.Array, lp: Params,
+                  cfg: ModelConfig, x, rows: StepRows, valid, fresh,
+                  group: int = KDA_GROUP_ROWS):
+    """A conv layer from the block's norm to the input of `wo` for a
+    [B, T] step, over the step's ROWS by what each holds, never over its
+    grid: `ssm_mix_rows` for a state that is a tail alone, and the
+    mixer's ONE form for a step. x [B * T, D]: the step's token rows in
+    either layout (`step_rows`); state: (conv_tail, o [B * T, D] in the
+    model's dtype), `o` a scratch the layers share: each real token's
+    row is overwritten, no other row is read.
+
+    A row of ONE token: its token is token row `start`; the front half
+    over [B, D], then what a decode window's step does (`conv_decode`).
+    A row of more (a chunk row): `group` of them at a time; the front
+    half and the convolution, continued from the slot's tail
+    (`conv_with_tail`: a row's `n_valid` real tokens move its tail, so a
+    chunk of one real token keeps the older row), run over [group, T,
+    ...] alone. Each kind is dead to the other. `fresh` [B]: the row
+    starts its sequence (position 0), from zeros whatever the slot held.
+    A row without a slot, and a row of padding, write nothing. Returns
+    (state, with o's real rows written)."""
+    conv_tail, o = state
+    tq = valid.shape[1]
+    n, n_slots = x.shape[0], conv_tail.shape[1]
+    keep = ~fresh
+    one = rows.n_valid == 1
+    g, c = _conv_front(x.at[rows.start].get(mode="clip"), lp, cfg)
+    (conv_tail,), o1 = conv_decode((conv_tail,), l, slots, lp, g, c, one,
+                                   fresh)
+    o = o.at[jnp.where(one, rows.start, n)].set(o1, mode="drop")
+    long_at = jnp.where(rows.n_valid > 1, _slot_index(slots, n_slots), -1)
+
+    def chunk_group(j, carry):
+        o, conv_tail = carry
+        at, _, valid_g, keep_g, cells = _chunk_group(
+            rows, j, group, long_at, n_slots, valid, keep)
+        g, c = _conv_front(x.at[cells].get(mode="clip"), lp, cfg)
+        with jax.named_scope("shortconv.taps"):
+            tail = conv_tail.at[l, at].get(mode="clip")
+            y, tail = conv_with_tail(
+                g, jnp.where(keep_g[:, None, None], tail, 0), lp["conv_w"],
+                jnp.sum(valid_g, axis=1).astype(jnp.int32))
+            conv_tail = conv_tail.at[l, at].set(
+                tail.astype(conv_tail.dtype), mode="drop")
+        o = o.at[jnp.where(valid_g, cells, n).reshape(-1)].set(
+            _conv_out(y, c).reshape(group * tq, -1), mode="drop")
+        return o, conv_tail
+
+    o, conv_tail = jax.lax.fori_loop(
+        0, -(-rows.n_long // group), chunk_group, (o, conv_tail))
+    return conv_tail, o
+
+
 def layer_back(x: jax.Array, attn: jax.Array, lp: Params, cfg: ModelConfig,
                mlp, reduce=None, kind: str = "", ssm=None):
 
@@ -1480,18 +1605,24 @@ def layer_back(x: jax.Array, attn: jax.Array, lp: Params, cfg: ModelConfig,
     Softmax attention's output gate (`_out_gate`) sits before `wo`.
     `ssm` (a parallel block, `kind` "par"): the mixer's output past its
     gated norm [B, T, d_ssm]; its projection `ssm_out` is ADDED to
-    attention's, each times its multiplier, before the one residual."""
+    attention's, each times its multiplier, before the one residual. A
+    conv layer (`kind` "conv") hands over its gated convolution [B, T,
+    D], and `wo` is its `out_proj`."""
     b, t = x.shape[:2]
     if kind == "kda":
         attn = _kda_out(attn, x, lp, cfg)
+    elif kind == "conv":
+        pass
     elif cfg.is_mla:
         attn = _mla_out(attn.reshape(b, t, cfg.num_heads, -1), lp, cfg)
         if cfg.mla_gate:
             attn = _mla_gate(attn, x, lp, cfg)
     elif cfg.attn_out_gate:
         attn = _out_gate(attn.reshape(b, t, -1), x, lp, cfg)
-    out = jnp.einsum("bte,ed->btd", attn.reshape(b, t, -1),
-                     wmat(lp["wo"], x.dtype))
+    with jax.named_scope("shortconv.out_proj") if kind == "conv" \
+            else contextlib.nullcontext():
+        out = jnp.einsum("bte,ed->btd", attn.reshape(b, t, -1),
+                         wmat(lp["wo"], x.dtype))
     if kind == "par":
         out = _times(out, cfg.attention_out_multiplier) + _times(
             jnp.einsum("bte,ed->btd", ssm, wmat(lp["ssm_out"], x.dtype)),
@@ -1643,6 +1774,26 @@ def decode_forward(
                 lid - run.first, run.dense), kind="kda")
         return (x, st), drop_stats if moe_aux else None
 
+    def stack_layer(stack, lid, run):
+        """A period's part: layer `lid` read from its kind's stack."""
+        return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+            a, lid - run.first, keepdims=False), stack)
+
+    def conv_step_layer(carry, xs, run, expert_stacks, stack=None):
+        """A conv layer: no cache row, its tail moved on a token."""
+        x, st = carry
+        lp, lid = xs[:2]
+        if lp is None:
+            lp = stack_layer(stack, lid, run)
+        g, c = _conv_front(x, lp, cfg)
+        st, o = conv_decode(st, run.store_index(lid), state[1], lp,
+                            g[:, 0], c[:, 0], row_valid)
+        x, drop_stats = layer_back(
+            x, o[:, None], lp, cfg, lambda xn, lp: _mlp_block(
+                xn, lp, cfg, mesh, token_valid, expert_stacks,
+                lid - run.first, run.dense), kind="conv")
+        return (x, st), drop_stats if moe_aux else None
+
     def par_step_layer(carry, xs, run, expert_stacks):
         """A parallel block: the mixer's state update from the block's
         input, then `layer_step`, which adds its output to attention's."""
@@ -1658,9 +1809,7 @@ def decode_forward(
         lp, lid, wnd, win = xs
         first, dense = run.first, run.dense
         if lp is None:
-            # a period's part: the layer read from its kind's stack
-            lp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
-                a, lid - first, keepdims=False), stack)
+            lp = stack_layer(stack, lid, run)
         # this layer's index in the cache's (and the window's) layer axis
         cl = run.store_index(lid)
         q, k, v = layer_front(x, lp, cfg, positions[:, None], heads,
@@ -1741,11 +1890,14 @@ def decode_forward(
         scan_layers, expert_stacks = (params[name], None) if dense \
             else split_expert_stacks(params[name], cfg, mesh)
         part = _group_rows(whole, first, count)
-        if run.kind == "kda":
-            (x, st), drop_g = jax.lax.scan(
-                functools.partial(kda_step_layer, run=run,
-                                  expert_stacks=expert_stacks),
-                (x, st), (scan_layers, part(layer_ids)))
+        if run.kind in ("kda", "conv"):
+            with _lead_scope(run):
+                (x, st), drop_g = jax.lax.scan(
+                    functools.partial(
+                        kda_step_layer if run.kind == "kda"
+                        else conv_step_layer, run=run,
+                        expert_stacks=expert_stacks),
+                    (x, st), (scan_layers, part(layer_ids)))
             drops.append(_sum_stats(drop_g))
             continue
         xs = (scan_layers, part(layer_ids),
@@ -1768,18 +1920,28 @@ def decode_forward(
     if period is not None:
         # ONE loop: a scan over periods, a scan a part inside it; the new
         # rows come out [periods, a kind's layers a period, ...] = the
-        # kind's store order, behind the lead's
-        loop = range(period.lead, len(runs))
+        # kind's store order, behind the lead's. A conv part emits no
+        # rows: its tails ride the loop's carry beside x
         stacks = {ri: split_expert_stacks(params[runs[ri].key], cfg, mesh)
-                  for ri in loop}
+                  for ri in range(period.lead, len(runs))}
+        loop = [ri for ri in stacks if runs[ri].kind != "conv"]
 
-        def period_step(x, p):
+        def period_step(carry, p):
+            x, st = carry
             rows = {ri: ([], []) for ri in loop}
             stats = []
             for ri, per, offset, count in period.parts:
                 run = runs[ri]
                 lids = run.first + p * per + offset \
                     + jnp.arange(count, dtype=jnp.int32)
+                if run.kind == "conv":
+                    (x, st), drop_g = jax.lax.scan(
+                        functools.partial(conv_step_layer, run=run,
+                                          expert_stacks=stacks[ri][1],
+                                          stack=stacks[ri][0]),
+                        (x, st), (None, lids))
+                    stats.append(_sum_stats(drop_g))
+                    continue
                 x, (k_g, v_g, drop_g) = jax.lax.scan(
                     functools.partial(layer_step, run=run,
                                       expert_stacks=stacks[ri][1],
@@ -1788,11 +1950,13 @@ def decode_forward(
                 rows[ri][0].append(k_g)
                 rows[ri][1].append(v_g)
                 stats.append(_sum_stats(drop_g))
-            return x, (tuple(tuple(jnp.concatenate(g, axis=0) for g in kv)
-                             for kv in rows.values()), _merge_stats(stats))
+            return (x, st), (
+                tuple(tuple(jnp.concatenate(g, axis=0) for g in kv)
+                      for kv in rows.values()), _merge_stats(stats))
 
-        x, (rows, drop_p) = jax.lax.scan(
-            period_step, x, jnp.arange(period.count, dtype=jnp.int32))
+        (x, st), (rows, drop_p) = jax.lax.scan(
+            period_step, (x, st),
+            jnp.arange(period.count, dtype=jnp.int32))
         for ri, (k_g, v_g) in zip(loop, rows):
             k_g, v_g = (g.reshape((-1,) + g.shape[2:]) for g in (k_g, v_g))
             (wk_news if runs[ri].kind == "swa" else k_news).append(k_g)
@@ -2168,6 +2332,16 @@ def forward(
             x, drop_stats = either(functools.partial(back, stored=True),
                                    x, state[2])
             return (x, pool, state, wpool), drop_stats
+        if kind == "conv":
+            # a conv layer, whole, over the step's rows: its tails in no
+            # `cond` (the branches of `back` read o's rows)
+            state = conv_mix_rows(
+                state, sl, meta.state_slots, lp_of(), cfg,
+                x.reshape(n, -1), kda_plan, grid_valid,
+                meta.positions[:, 0] == 0)
+            x, drop_stats = either(functools.partial(back, stored=True),
+                                   x, state[1])
+            return (x, pool, state, wpool), drop_stats
         if kind == "par":
             # a parallel block's mixer, whole, over the step's rows, from
             # the block's input: no [B, Tq] tensor of its width, and the
@@ -2247,6 +2421,8 @@ def forward(
         # the scratch that carries a state layer's o to its back half
         state += (jnp.zeros((n, cfg.mamba_d_ssm), _dtype(cfg))
                   if cfg.has_ssm else
+                  jnp.zeros((n, cfg.hidden_size), _dtype(cfg))
+                  if cfg.has_conv else
                   jnp.zeros((n, cfg.num_heads, cfg.linear_head_dim),
                             jnp.float32),)
     drops = []

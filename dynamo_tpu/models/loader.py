@@ -34,6 +34,8 @@ ARCHES = {
     "MellumForCausalLM": "mellum",
     "AfmoeForCausalLM": "afmoe",
     "FalconH1ForCausalLM": "falcon_h1",
+    "Lfm2ForCausalLM": "lfm2",
+    "Lfm2MoeForCausalLM": "lfm2_moe",
 }
 # the families whose sliding layers are served as WINDOWS, from a page
 # pool of their own (`window_pool`): their serving length is not capped
@@ -55,7 +57,8 @@ def config_from_hf(hf: Dict[str, Any], name: str = "") -> ModelConfig:
            "bailing_hybrid": bailing_hybrid_fields,
            "mellum": mellum_fields,
            "afmoe": afmoe_fields,
-           "falcon_h1": falcon_h1_fields}.get(
+           "falcon_h1": falcon_h1_fields,
+           "lfm2": lfm2_fields, "lfm2_moe": lfm2_fields}.get(
                family, lambda hf: {})(hf)
     if hf.get("clip_qkv") is not None:
         # OLMoE's optional clamp of q/k/v to +-clip_qkv is not modeled:
@@ -461,6 +464,84 @@ def falcon_h1_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
             float(m) for m in hf.get("mlp_multipliers") or (1.0, 1.0)))
 
 
+def lfm2_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """The ModelConfig fields of an `lfm2` / `lfm2_moe` config.json
+    (LFM2-8B-A1B): `layer_types` saying which layers are gated short
+    convolutions of `conv_L_cache` taps ("conv": layer kind "conv", a
+    tail and no pages) and which grouped-query attention with an RMSNorm
+    over each head's q and k ("full_attention"); RMSNorm eps under the
+    key `norm_eps`; the head tied to the embedding table unless the file
+    says otherwise (the family's default: `Lfm2Config`). `lfm2_moe`:
+    `num_dense_layers` leading layers with a dense SwiGLU of
+    `intermediate_size`, then `num_experts` experts of
+    `moe_intermediate_size` behind a sigmoid router whose `expert_bias`
+    (`use_expert_bias`) picks and does not weigh, the k picked
+    renormalised over their sum + 1e-6 under `norm_topk_prob` and scaled
+    by `routed_scaling_factor` (the expert block is the published class's
+    code: `transformers` here has the dense family alone). `lfm2` (the
+    dense family): a SwiGLU in every layer, its width
+    `intermediate_size` as `block_auto_adjust_ff_dim` adjusts it. What is
+    not modelled is refused here, by key."""
+    refuse = _refuser(hf)
+    layers = int(hf["num_hidden_layers"])
+    types = hf.get("layer_types")
+    if types is None and hf.get("full_attn_idxs") is not None:
+        types = ["full_attention" if i in hf["full_attn_idxs"] else "conv"
+                 for i in range(layers)]
+    if not types or len(types) != layers or \
+            set(types) - {"conv", "full_attention"}:
+        raise ValueError(
+            f"layer_types={types!r}: one of conv | full_attention a layer "
+            f"({layers}) is what is modelled")
+    refuse("conv_bias", lambda v: not v,
+           "conv_bias: false (no bias on the convolution and its two "
+           "projections)")
+    refuse("conv_L_cache", lambda v: v is None or int(v) >= 2,
+           "a convolution of at least two taps")
+    refuse("attention_bias", lambda v: not v, "no attention bias")
+    for key in ("n_group", "topk_group"):
+        refuse(key, lambda v: v in (None, 1), "one expert group")
+    for key in ("num_shared_experts", "n_shared_experts",
+                "num_nextn_predict_layers", "sliding_window"):
+        refuse(key, lambda v: not v, f"{key} absent")
+    moe = hf.get("model_type") == "lfm2_moe" or "num_experts" in hf
+    width = int(hf.get("block_ff_dim", hf["intermediate_size"]))
+    # a file without a conv layer is plain attention throughout
+    conv = "conv" in types
+    own = dict(
+        layer_types=tuple(types) if conv else (),
+        conv_l_cache=int(hf.get("conv_L_cache", 3)) if conv else 0,
+        qk_norm="head",
+        rms_norm_eps=float(hf.get("norm_eps", 1e-5)),
+        rope_theta=float(hf.get("rope_theta", hf.get("theta", 1e6))),
+        tie_word_embeddings=bool(hf.get(
+            "tie_word_embeddings", hf.get("tie_embedding", True))))
+    if not moe:
+        if hf.get("block_auto_adjust_ff_dim", True):
+            # `Lfm2MLP`'s own arithmetic
+            width = int(2 * width / 3)
+            if hf.get("block_ffn_dim_multiplier", 1.0) is not None:
+                width = int(hf.get("block_ffn_dim_multiplier", 1.0) * width)
+                multiple = int(hf.get("block_multiple_of", 256))
+                width = multiple * ((width + multiple - 1) // multiple)
+        return dict(own, intermediate_size=width)
+    refuse("block_auto_adjust_ff_dim", lambda v: not v,
+           "the widths as the file gives them")
+    lead = int(hf.get("num_dense_layers") or 0)
+    if not 0 <= lead < layers:
+        raise ValueError(f"num_dense_layers={lead} of {layers} layers: at "
+                         f"least one expert layer behind the lead is what "
+                         f"is modelled")
+    return dict(
+        own, num_experts=int(hf["num_experts"]),
+        intermediate_size=int(hf["moe_intermediate_size"]),
+        dense_intermediate_size=width, first_dense_layers=lead,
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        moe_scoring="sigmoid", moe_renorm_eps=1e-6,
+        moe_router_bias=bool(hf.get("use_expert_bias", True)),
+        moe_routed_scale=float(hf.get("routed_scaling_factor", 1.0)))
+
+
 def rope_params(entry: Dict[str, Any], hf: Dict[str, Any]):
     """One entry of `rope_parameters` -> RopeParams. Plain RoPE
     ("default") and YaRN are modelled; longrope, llama3, linear and
@@ -552,6 +633,13 @@ def load_params_from_hf(path: str, cfg: ModelConfig,
             f"linear-attention layers or a window pool (its tensor names "
             f"are not known); remove the *.safetensors to serve seeded "
             f"weights")
+    if cfg.has_conv and cfg.is_moe:
+        # `transformers` here has the dense `lfm2` family alone: the
+        # expert block's tensor names are not known
+        raise ValueError(
+            f"{cfg.name}: no checkpoint mapping for the expert block of a "
+            f"model with conv layers (its tensor names are not known); "
+            f"remove the *.safetensors to serve seeded weights")
     import jax.numpy as jnp
     dt = jnp.empty((), dtype or cfg.dtype).dtype
     raw = _read_all_tensors(path)
@@ -567,6 +655,8 @@ def load_params_from_hf(path: str, cfg: ModelConfig,
 
     if cfg.is_mla:
         return _load_deepseek_v3(raw, cfg, t, w)
+    if cfg.has_conv:
+        return _load_lfm2(raw, cfg, t, w)
 
     fused_qkv = "model.layers.0.self_attn.qkv_proj.weight" in raw  # Phi-3
     # transformers' `falcon_h1` layout, told by the file's own tensor names
@@ -751,6 +841,63 @@ def _load_deepseek_v3(raw, cfg: ModelConfig, t, w) -> Dict[str, Any]:
     }
     for name, first, count, dense in layer_groups(cfg):
         params[name] = group(first, count, dense)
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = t("lm_head.weight")
+    return params
+
+
+def _load_lfm2(raw, cfg: ModelConfig, t, w) -> Dict[str, Any]:
+    """`load_params_from_hf` for `transformers`' dense `lfm2` names, a
+    stack a layer kind (models/llama.layer_runs):
+      model.layers.{i}.operator_norm / .ffn_norm -> attn_norm / mlp_norm
+      .conv.in_proj.weight.T  (B | C | u)        -> conv_in
+      .conv.conv.weight [D, 1, K]                -> conv_w [K, D]
+      .conv.out_proj.weight.T / .self_attn.out_proj.weight.T -> wo
+      .self_attn.{q,k}_layernorm.weight          -> q_norm / k_norm
+      .feed_forward.w1 / w3 / w2 .weight.T       -> w_gate / w_up / w_down
+      model.embedding_norm.weight                -> final_norm"""
+    from dynamo_tpu.models.llama import layer_runs
+    kinds = cfg.layer_kinds()
+    pre = "model.layers.{}."
+
+    def group(run):
+        ids = [i for i, kind in enumerate(kinds) if kind == run.kind]
+
+        def stack(fn):
+            return np.stack([fn(pre.format(i)) for i in ids])
+        layers = {
+            "attn_norm": stack(lambda p: w(p + "operator_norm.weight")),
+            "mlp_norm": stack(lambda p: w(p + "ffn_norm.weight")),
+            "w_gate": stack(lambda p: t(p + "feed_forward.w1.weight")),
+            "w_up": stack(lambda p: t(p + "feed_forward.w3.weight")),
+            "w_down": stack(lambda p: t(p + "feed_forward.w2.weight")),
+        }
+        if run.kind == "conv":
+            layers.update({
+                "conv_in": stack(lambda p: t(p + "conv.in_proj.weight")),
+                "conv_w": stack(lambda p: np.asarray(
+                    raw[p + "conv.conv.weight"][:, 0, :].T,
+                    dtype=layers["attn_norm"].dtype)),
+                "wo": stack(lambda p: t(p + "conv.out_proj.weight")),
+            })
+            return layers
+        attn = "self_attn."
+        layers.update({
+            "wq": stack(lambda p: t(p + attn + "q_proj.weight")),
+            "wk": stack(lambda p: t(p + attn + "k_proj.weight")),
+            "wv": stack(lambda p: t(p + attn + "v_proj.weight")),
+            "wo": stack(lambda p: t(p + attn + "out_proj.weight")),
+            "q_norm": stack(lambda p: w(p + attn + "q_layernorm.weight")),
+            "k_norm": stack(lambda p: w(p + attn + "k_layernorm.weight")),
+        })
+        return layers
+
+    params: Dict[str, Any] = {
+        "embed": w("model.embed_tokens.weight"),
+        "final_norm": w("model.embedding_norm.weight"),
+    }
+    for run in layer_runs(cfg):
+        params[run.key] = group(run)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = t("lm_head.weight")
     return params

@@ -202,6 +202,36 @@ no bias on any projection. With h the residual stream:
   MLP         down(up(x) * SiLU(gate(x) * `mlp_multipliers`[0])) *
               `mlp_multipliers`[1] (`dense_mlp`'s `multipliers`).
 
+And from the row `LFM2-8B-A1B` of the architecture catalog (`model_type`
+lfm2_moe), the non-expert parts read against `transformers`' own
+`modeling_lfm2.py` (4.57.6, the dense family's class, its non-kernel path;
+tests/test_lfm2.py holds this file to it on a tiny configuration;
+benchmark/reference/lfm2.py is the benchmark's copy of these lines).
+RMSNorm eps `norm_eps`, plain weight; the final norm is the leaf the
+checkpoint calls `embedding_norm`; the head is the embedding table.
+
+  block       h = h + mixer(RMSNorm(h; operator_norm)); h = h +
+              ffn(RMSNorm(h; ffn_norm)). `layer_types[i]` says which
+              mixer layer i has: "conv" | "full_attention".
+  conv        (`short_conv`) B | C | u = x W_in [D, 3 D], in that order;
+              g = B * u; c_t = sum_{j < K} w[j] * g_{t - (K - 1) + j}, a
+              causal depth-wise convolution of K = `conv_L_cache` taps
+              with zeros before the sequence and NO activation; out = (C *
+              c) W_out. What a sequence carries from token to token is the
+              last K - 1 rows of g (`tails`).
+  attention   `attention` above with `qk_norm` "head": an RMSNorm over
+              each head's values of q and of k (`q_layernorm`,
+              `k_layernorm`, one weight of head_dim for all heads) BEFORE
+              rotate-half RoPE at `rope_theta`; heads of hidden / H.
+  ffn         the first `num_dense_layers` layers a SwiGLU of
+              `intermediate_size`; the others (code-sourced: the installed
+              `transformers` has no `lfm2_moe`) `expert_mlp` with s =
+              sigmoid(x Wr) in float32 over all E; idx = top_k(s + b), b
+              the `expert_bias` (`use_expert_bias`), which picks and does
+              not weigh; w = s[idx] / (sum + 1e-6) (`norm_topk_prob`; the
+              constant is `renorm_eps`) x `routed_scaling_factor`; no
+              shared expert, no groups.
+
 Departures from the published model, each deliberate:
   * weights are taken in this repo's layout: projections stored
     [in, out] (the checkpoint's are [out, in]; models/loader.py
@@ -370,6 +400,21 @@ def causal_conv(x, w):
     return sum(w[j] * xp[j:j + x.shape[0]] for j in range(k))
 
 
+def short_conv(x, lp, tails=None):
+    """The gated short convolution (module docstring). x [T, D], the
+    normed input. `tails`: a list that takes this layer's state after
+    the sequence, the last K - 1 rows of B * u [K - 1, D] (zeros where
+    the sequence is shorter)."""
+    d = x.shape[1]
+    p = x @ lp["conv_in"]
+    g = p[:, :d] * p[:, 2 * d:]
+    if tails is not None:
+        k = lp["conv_w"].shape[0]
+        tails.append(jnp.concatenate(
+            [jnp.zeros((k - 1, d), g.dtype), g])[-(k - 1):])
+    return (p[:, d:2 * d] * causal_conv(g, lp["conv_w"])) @ lp["wo"]
+
+
 def l2_normalize(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
@@ -496,9 +541,10 @@ def group_limited(pick, n_group, topk_group):
 
 def router_weights(x, lp, *, num_experts_per_tok, norm_topk_prob,
                    moe_scoring="softmax", moe_routed_scale=1.0,
-                   n_group=1, topk_group=1):
+                   n_group=1, topk_group=1, renorm_eps=1e-20):
     """[T, E] float32: each token's weight on every expert, zero outside
-    its top-k. A `router_bias` leaf picks and does not weigh."""
+    its top-k. A `router_bias` leaf picks and does not weigh.
+    `renorm_eps`: what `norm_topk_prob` adds to the kept weights' sum."""
     logits = x @ lp["router"]                                  # [T, E]
     scores = (jax.nn.sigmoid(logits) if moe_scoring == "sigmoid"
               else jax.nn.softmax(logits, axis=-1))
@@ -510,7 +556,7 @@ def router_weights(x, lp, *, num_experts_per_tok, norm_topk_prob,
     weights = scores * mask
     if norm_topk_prob:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
-                             + 1e-20)
+                             + renorm_eps)
     return weights * moe_routed_scale
 
 
@@ -536,7 +582,8 @@ def layer(x, lp, *, num_heads, num_kv_heads, head_dim, rope_theta,
           num_experts_per_tok=0, norm_topk_prob=True, mla=None,
           moe_scoring="softmax", moe_routed_scale=1.0, kda=None,
           n_group=1, topk_group=1, expert_first=0, window=0, yarn=None,
-          par=None, mlp_multipliers=(1.0, 1.0)):
+          par=None, mlp_multipliers=(1.0, 1.0), renorm_eps=1e-20,
+          tails=None):
     """One pre-norm residual block. x: [T, D]; lp: this layer's weights,
     float32. `mla`: attention_mla's sizes (a dict) for latent attention;
     `kda`: attention_kda's, for a layer that has its leaves. A layer
@@ -546,7 +593,8 @@ def layer(x, lp, *, num_heads, num_kv_heads, head_dim, rope_theta,
     half's output before the residual (four norms a block). `par`:
     `parallel_block`'s arguments after `attn`, for a layer with a
     state-space mixer beside its attention (`key_multiplier` among
-    them, which is attention's)."""
+    them, which is attention's). A layer with a `conv_in` leaf is a
+    gated short convolution (`short_conv`, which `tails` goes to)."""
     def post(out, name):
         return rms_norm(out, lp[name], rms_norm_eps) if name in lp else out
     xn = rms_norm(x, lp["attn_norm"], rms_norm_eps)
@@ -558,6 +606,8 @@ def layer(x, lp, *, num_heads, num_kv_heads, head_dim, rope_theta,
                 head_dim=head_dim, rope_theta=rope_theta,
                 rms_norm_eps=rms_norm_eps, qk_norm=qk_norm,
                 key_multiplier=par.pop("key_multiplier")), **par)
+    elif "conv_in" in lp:
+        out = short_conv(xn, lp, tails)
     elif "kda_wqkv" in lp:
         out = attention_kda(xn, lp, num_heads=num_heads,
                             rms_norm_eps=rms_norm_eps, **kda)
@@ -581,7 +631,12 @@ def layer(x, lp, *, num_heads, num_kv_heads, head_dim, rope_theta,
                          moe_routed_scale=moe_routed_scale,
                          expert_first=expert_first,
                          **(dict(n_group=n_group, topk_group=topk_group)
-                            if n_group > 1 else {}))
+                            if n_group > 1 else {}),
+                         # only where a family has its own constant: the
+                         # older families' callers (and the tests that
+                         # stand a mutant router in) know no such argument
+                         **(dict(renorm_eps=renorm_eps)
+                            if renorm_eps != 1e-20 else {}))
     else:
         out = dense_mlp(xn, lp, multipliers=mlp_multipliers)
     return x + post(out, "post_mlp_norm")
@@ -599,6 +654,9 @@ def arch_kwargs(cfg) -> dict:
                        sliding_window=cfg.sliding_window,
                        rope_full=dataclasses.asdict(cfg.rope_full),
                        rope_sliding=dataclasses.asdict(cfg.rope_sliding))
+    if cfg.has_conv:
+        by_kind = dict(layer_types=tuple(cfg.layer_types),
+                       renorm_eps=cfg.moe_renorm_eps)
     if cfg.embed_scale:     # beside them: `forward` takes it out again
         by_kind["embed_scale"] = cfg.embed_scale
     if cfg.has_ssm:
@@ -638,8 +696,10 @@ def layer_kind_kwargs(index, layer_types=(), sliding_window=0,
     model whose `layer_types` says which layers slide: its window (0 on a
     full layer) and its RoPE (`rope_theta`, and `yarn` where the kind's
     `rope_type` is yarn; `rope_theta` None where it is none: the kind
-    has no positional embedding). {} for a model of one kind."""
-    if not layer_types:
+    has no positional embedding). {} for a model of one kind, and for
+    one whose kinds share one RoPE (conv layers beside attention: the
+    layer's own leaves say which it is)."""
+    if not layer_types or rope_full is None:
         return {}
     sliding = layer_types[index] == "sliding_attention"
     p = rope_sliding if sliding else rope_full
@@ -690,7 +750,9 @@ def layers_in_order(params, layer_types=()) -> list:
 def forward(params, tokens, **arch):
     """tokens [T] -> logits [T, V] float32: one full forward pass over one
     sequence. `params` is the engine's tree (models/llama.init_params /
-    models/loader.load_params_from_hf), in any dtype: upcast here."""
+    models/loader.load_params_from_hf), in any dtype: upcast here.
+    `tails` (a list): takes every conv layer's state after the sequence,
+    in layer order (`short_conv`)."""
     by_kind = {k: arch.pop(k) for k in (
         "layer_types", "sliding_window", "rope_full", "rope_sliding")
         if k in arch}

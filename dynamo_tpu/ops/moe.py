@@ -77,7 +77,7 @@ def group_limited(pick, n_group: int, topk_group: int):
 
 def route_topk(x, router, k: int, renorm: bool, scoring: str = "softmax",
                bias=None, scale: float = 1.0, n_group: int = 1,
-               topk_group: int = 1):
+               topk_group: int = 1, renorm_eps: float = 1e-20):
     """Router: x [..., D], router [D, E] -> (weights [..., k] float32,
     expert ids [..., k]). The scores are a float32 softmax (or, `scoring`
     "sigmoid", independent sigmoids) over ALL experts at the highest
@@ -86,7 +86,9 @@ def route_topk(x, router, k: int, renorm: bool, scoring: str = "softmax",
     flips). `renorm` rescales the k kept weights to sum to one (Mixtral;
     on the plain softmax router computed as the softmax over the k
     chosen logits, the same numbers); without it they stay as they are
-    (OLMoE, `norm_topk_prob: false`). `bias` [E] (DeepSeek-V3's
+    (OLMoE, `norm_topk_prob: false`); the general form divides by their
+    sum plus `renorm_eps`, the family's published constant (DeepSeek-V3's
+    1e-20, LFM2's 1e-6). `bias` [E] (DeepSeek-V3's
     `e_score_correction_bias`) is added to the scores to PICK the k
     experts and is no part of their weights; `scale` multiplies the
     weights last. `n_group` > 1: the pick is group-limited
@@ -111,7 +113,7 @@ def route_topk(x, router, k: int, renorm: bool, scoring: str = "softmax",
     weights = jnp.take_along_axis(scores, idx, axis=-1)
     if renorm:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
-                             + 1e-20)
+                             + renorm_eps)
     return weights * scale, idx
 
 
@@ -121,7 +123,8 @@ def route(x, lp, cfg):
     return route_topk(x, lp["router"], cfg.num_experts_per_tok,
                       cfg.norm_topk_prob, cfg.moe_scoring,
                       lp.get("router_bias"), cfg.moe_routed_scale,
-                      cfg.moe_n_group, cfg.moe_topk_group)
+                      cfg.moe_n_group, cfg.moe_topk_group,
+                      cfg.moe_renorm_eps)
 
 
 def moe_stats(routed, dropped, expert_rows, experts_hit):

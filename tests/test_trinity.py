@@ -546,6 +546,11 @@ SERVED_PROGRAMS = {
     ("trinity-mini", 8, 64, "window"): "88a92f6215229ff7",
     ("ling-3.0-flash-vl", 64, 64, "window"): "4499a3a1552d90c3",
     ("falcon-h1-34b", 64, 64, "window"): "279a188e98469eba",
+    # PR 50: LFM2 at its cell's shape, this tree's own (the parent cannot
+    # trace it): a lead's conv body before the period loop's two, the
+    # tails in the window's carry beside the attention layers' new rows
+    ("lfm2-8b-a1b", 8, 64, "step"): "ef012449ae3183b4",
+    ("lfm2-8b-a1b", 8, 64, "window"): "30ba5ac52d223f9b",
 }
 
 
@@ -571,6 +576,24 @@ def test_the_new_models_programs_hold_what_the_old_ones_lack():
     for key in ("step", "window"):
         assert text[key].count("logistic") > \
             program_texts("rehearsal-tiny-mellum")[key].count("logistic")
+
+
+def test_the_programs_trace_three_layer_bodies():
+    """The rehearsal configuration's step and window programs: each conv
+    body multiplies by `conv_in` (twice in a step: the one-token rows and
+    a group of chunk rows), each expert body routes once; two conv bodies
+    (the lead's, the loop's) and two expert bodies (F, C), whatever the
+    depth."""
+    from tests.test_trinity import program_texts
+    text = program_texts("rehearsal-tiny-lfm2")
+    for key, per_body in (("step", 2), ("window", 1)):
+        # a product by `conv_in` [128, 384] leaves 384 columns
+        uses = [line for line in text[key].splitlines()
+                if ",384] = dot_general" in line]
+        assert len(uses) == 2 * per_body, (key, len(uses))
+        # the sigmoids (routers, SiLUs) of three bodies, read off this
+        # tree: a fourth body would add to them
+        assert text[key].count("logistic") == 4, key
 
 
 def test_the_benchmarks_copy_of_the_reference_is_this_one():
