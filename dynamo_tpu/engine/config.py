@@ -227,6 +227,15 @@ class ModelConfig:
     # runs under shard_map over the "tp" axis (ops/paged_attention.py
     # decode_paged_attention_sharded).
     decode_kernel: str = "auto"
+    # KV heads that share one row of the paged pool: DERIVED, never a
+    # knob. `kv_heads_per_row` below is the rule (a function of the
+    # shapes, the cache's kind and the mesh's `tp`); NativeEngine resolves
+    # it once at construction, overwrites whatever stands here, and its
+    # programs close over the result. 1 = a row is one head's `head_dim`
+    # values; f > 1 = f adjacent heads of `head_dim` < 128 fill one
+    # 128-lane row (`kv_cache_leaves` hands out the stored shape,
+    # models/llama.layer_front forms the rows).
+    kv_row_heads: int = 1
     # Multimodal (Qwen2-VL-style); None means text-only.
     vision: Optional["VisionConfig"] = None
 
@@ -380,27 +389,33 @@ class ModelConfig:
         return total * self.num_state_layers
 
     def kv_cache_leaves(self) -> dict:
-        """THE description of the paged cache: value leaf -> (kv heads,
-        width), each stored [L, heads, pages, page_size, width]. Two
-        leaves of num_kv_heads x head_dim, or under latent attention ONE,
+        """THE description of the paged cache AS IT IS STORED: value leaf
+        -> (rows a token, width), each stored [L, rows, pages, page_size,
+        width]. Two leaves of num_kv_heads x head_dim, f = `kv_row_heads`
+        adjacent heads to a row: (num_kv_heads / f, f x head_dim), the
+        same bytes whatever f (row r holds heads r f .. r f + f - 1, head
+        j of them in lanes j head_dim ..). Under latent attention ONE,
         named "k" because it is what the absorbed queries are scored
         against: a single head of kv_lora_rank + qk_rope_head_dim whose
         first kv_lora_rank columns are also the values. init_cache, the
         shardings, the page-byte gauges and `refuse_unserved` read this."""
         if self.is_mla:
             return {"k": (1, self.kv_lora_rank + self.qk_rope_head_dim)}
-        return {"k": (self.num_kv_heads, self.head_dim),
-                "v": (self.num_kv_heads, self.head_dim)}
+        return {"k": self._kv_row, "v": self._kv_row}
 
     def window_cache_leaves(self) -> dict:
-        """The window layers' leaves, beside `kv_cache_leaves`: value leaf
-        -> (kv heads, width), each stored [window layers, heads, window
-        pages, page_size, width] over the SECOND page pool. Empty for a
-        model without a window pool."""
+        """The window layers' leaves, beside `kv_cache_leaves` and by the
+        same rule: value leaf -> (rows a token, width), each stored
+        [window layers, rows, window pages, page_size, width] over the
+        SECOND page pool. Empty for a model without a window pool."""
         if not self.window_pool:
             return {}
-        return {"wk": (self.num_kv_heads, self.head_dim),
-                "wv": (self.num_kv_heads, self.head_dim)}
+        return {"wk": self._kv_row, "wv": self._kv_row}
+
+    @property
+    def _kv_row(self) -> tuple:
+        f = self.kv_row_heads
+        return self.num_kv_heads // f, f * self.head_dim
 
     def kv_bytes_per_token(self) -> int:
         """Bytes one token holds in the unquantized FULL pool, all the
@@ -431,6 +446,43 @@ class ModelConfig:
         prefill chunk re-reads an expert's weights once per row tile
         (8.6 ms a layer against 5.9)."""
         return self.num_experts > 8
+
+
+# lanes of a TPU register tile's minor axis: the width of a pool row that
+# XLA:TPU leaves where it rests (PERF.md section 6, PR 51)
+LANES = 128
+
+
+def kv_heads_per_row(cfg: ModelConfig, tp: int = 1) -> int:
+    """THE rule for `ModelConfig.kv_row_heads`: how many adjacent KV heads
+    share one row of the paged pool, read from shapes and the cache's
+    kind alone. A pool whose rows are `head_dim` < 128 lanes wide rests
+    on a TPU with its PAGE axis minor, and every program that writes rows
+    into it re-lays both leaves out, whole, once an attention layer
+    (LFM2's 64-wide heads: 8.6 of a 28.6 ms step, PERF.md section 6, PR
+    51); f = 128 / head_dim heads to a row make it the 128-lane row every
+    other pool already is, over the same bytes. 1 wherever that is not an
+    exact view of the same result: head_dim does not divide 128 (96), a
+    "tp" shard's heads do not fill whole rows, a latent cache (one leaf of
+    one head), an int8 pool (a row's scale is per head: two heads to a
+    row would share one), or the Pallas decode kernel asked for (it takes
+    its scale from the page's width and packs tiles its own way,
+    ops/paged_attention._kernel_pack)."""
+    hd = cfg.head_dim
+    if not 0 < hd < LANES or LANES % hd:
+        return 1
+    f = LANES // hd
+    if (cfg.is_mla or cfg.kv_quant or cfg.num_kv_heads % (tp * f)
+            or cfg.decode_kernel not in ("auto", "off")):
+        return 1
+    return f
+
+
+def with_kv_rows(cfg: ModelConfig, tp: int = 1) -> ModelConfig:
+    """`cfg` as an engine on a mesh of `tp` serves it: `kv_row_heads` by
+    the rule. NativeEngine, and the tools and tests that build its
+    programs without one (tools/pool_ops.py)."""
+    return dataclasses.replace(cfg, kv_row_heads=kv_heads_per_row(cfg, tp))
 
 
 @dataclasses.dataclass(frozen=True)

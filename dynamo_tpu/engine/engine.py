@@ -26,7 +26,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.engine.config import (
-    EngineConfig, ModelConfig, refuse_unserved,
+    EngineConfig, ModelConfig, kv_heads_per_row, refuse_unserved,
 )
 from dynamo_tpu.engine.kv_cache import SequenceState
 from dynamo_tpu.engine.offload import CopyStream, HostKvPool
@@ -158,6 +158,15 @@ class NativeEngine:
                     f"decode_kernel='on': num_heads={h} / num_kv_heads="
                     f"{hkv} not divisible by tp={tp}; use "
                     f"decode_kernel='auto'")
+        # how many KV heads share a row of the device pool: resolved HERE,
+        # once, from shapes and the mesh (engine/config.kv_heads_per_row);
+        # every program closes over it through `model_cfg`. Streamed
+        # decode (engine/streaming.py) attends over pages staged from the
+        # host tier beside the resident ones, in the form they travel in:
+        # its pool keeps a head to a row
+        model_cfg = dataclasses.replace(
+            model_cfg, kv_row_heads=1 if engine_cfg.stream_pages
+            else kv_heads_per_row(model_cfg, tp))
         self.model_cfg = model_cfg
         self.cfg = engine_cfg
         self.eos_token_ids = set(eos_token_ids or ())
@@ -170,7 +179,8 @@ class NativeEngine:
                           engine_cfg.page_size, model_cfg.head_dim)
             # tier slabs store the DEVICE representation verbatim: int8
             # pages + f32 scale rows under kv_quant (spill/promote never
-            # dequantize; checksums cover the quantized bytes)
+            # dequantize; checksums cover the quantized bytes), a head a
+            # row whatever the pool's rows hold (extract_pages)
             np_dtype = (np.dtype(np.int8) if self.kv_quant
                         else jnp.empty((), model_cfg.dtype).dtype)
             self.host_pool = HostKvPool(engine_cfg.host_pages, page_shape,
@@ -283,6 +293,7 @@ class NativeEngine:
             flops_per_token=model_flops_per_token(model_cfg)
             + sampler_flops_per_token(model_cfg))
         self.ledger.stats.kv_bytes_per_token = model_cfg.kv_bytes_per_token()
+        self.ledger.stats.kv_heads_per_row = model_cfg.kv_row_heads
         self.ledger.stats.kv_bytes_per_token_full = \
             model_cfg.kv_bytes_per_token()
         self.ledger.stats.kv_bytes_per_token_window = \
@@ -582,12 +593,17 @@ class NativeEngine:
                     static_argnums=(3,), donate_argnums=(1,))
                 for nw in self._window_sizes for greedy in (False, True)
             }
-        # disaggregation: whole-page gather/scatter on the
-        # [L, Hkv, P, ps, hd] cache (the TPU equivalent of the reference's
-        # NIXL read/write_blocks, SURVEY.md §2.7); ids are bucketed,
-        # out-of-range ids are dropped
-        self._extract_fn = jax.jit(_extract_pages)
-        self._inject_fn = jax.jit(_inject_pages, donate_argnums=(0,))
+        # disaggregation: whole-page gather/scatter on the cache (the TPU
+        # equivalent of the reference's NIXL read/write_blocks, SURVEY.md
+        # §2.7); ids are bucketed, out-of-range ids are dropped. Pages
+        # leave and enter as [L, Hkv, Nb, ps, hd] whatever a row of THIS
+        # pool holds (`kv_row_heads`, which follows the mesh: the two
+        # ends of a transfer may differ)
+        self._extract_fn = jax.jit(functools.partial(
+            _extract_pages, row_heads=model_cfg.kv_row_heads))
+        self._inject_fn = jax.jit(functools.partial(
+            _inject_pages, row_heads=model_cfg.kv_row_heads),
+            donate_argnums=(0,))
         # sharded parallel transfer (disagg/remote_transfer.py): one
         # jitted slice-scatter per shard-slice plan entry — the set is
         # bounded by the transfer layout (parallel/mesh.kv_shard_layout)
@@ -2683,7 +2699,9 @@ class NativeEngine:
     def extract_pages(self, page_ids) -> dict:
         """Gather whole KV pages -> ({k,v[,k_scale,v_scale]}, on-device):
         values [L, Hkv, Nb, ps, hd] plus scale stacks [L, Hkv, Nb, ps] on
-        kv_quant engines — the stored representation, never dequantized."""
+        kv_quant engines — the stored representation, never dequantized,
+        a head a row (a pool of `kv_row_heads` > 1 re-views the pages it
+        gathered: _logical_pages)."""
         refuse_unserved(
             self.model_cfg, feature="whole-page extraction (disagg "
             "transfer, the shared KV pool)")
@@ -2792,7 +2810,8 @@ class NativeEngine:
         fn = self._inject_shard_fns.get(key)
         if fn is None:
             fn = self._inject_shard_fns[key] = jax.jit(
-                functools.partial(_inject_pages_slice, slices=key),
+                functools.partial(_inject_pages_slice, slices=key,
+                                  row_heads=self.model_cfg.kv_row_heads),
                 donate_argnums=(0,))
         self.cache = fn(self.cache, jnp.asarray(ids), pages)
 
@@ -3141,25 +3160,51 @@ def _named(name: str, fn):
     return program
 
 
-def _extract_pages(cache, ids):
+def _logical_pages(pages, f: int):
+    """Pages of a pool whose rows hold f KV heads [L, Hkv / f, Nb, ps,
+    f * hd] -> [L, Hkv, Nb, ps, hd], the form a page has outside the
+    device pool (the wire, the offload tiers, the shared pool, their
+    checksums): one transpose of the handful of pages moved."""
+    l, rows, nb, ps, width = pages.shape
+    return pages.reshape(l, rows, nb, ps, f, width // f).transpose(
+        0, 1, 4, 2, 3, 5).reshape(l, rows * f, nb, ps, width // f)
+
+
+def _stored_pages(pages, f: int):
+    """The way back: [L, Hkv, Nb, ps, hd] -> [L, Hkv / f, Nb, ps, f * hd]."""
+    l, hkv, nb, ps, hd = pages.shape
+    return pages.reshape(l, hkv // f, f, nb, ps, hd).transpose(
+        0, 1, 3, 4, 2, 5).reshape(l, hkv // f, nb, ps, f * hd)
+
+
+def _extract_pages(cache, ids, row_heads: int = 1):
     """Gather pages by ids [Nb] along the page axis (2) of EVERY cache
-    leaf — values [L, Hkv, P, ps, hd] and, on kv_quant engines, the
-    scale stacks [L, Hkv, P, ps] move with the same ids."""
+    leaf -> values [L, Hkv, Nb, ps, hd] and, on kv_quant engines, the
+    scale stacks [L, Hkv, Nb, ps], which move with the same ids. A pool
+    of `row_heads` > 1 heads a row (never quantized) hands its pages out
+    a head a row, as every other pool does (_logical_pages)."""
     # dynalint: kv-codec — whole-page moves keep the stored (possibly
     # quantized) representation; no value decode happens here
-    return {key: jnp.take(arr, ids, axis=2) for key, arr in cache.items()}
+    pages = {key: jnp.take(arr, ids, axis=2) for key, arr in cache.items()}
+    if row_heads > 1:
+        pages = {key: _logical_pages(arr, row_heads)
+                 for key, arr in pages.items()}
+    return pages
 
 
-def _inject_pages(cache, ids, pages):
+def _inject_pages(cache, ids, pages, row_heads: int = 1):
     """Scatter pages into the cache at ids; out-of-range ids are dropped.
     `pages` carries the same leaf set as the cache (values + scales on
-    kv_quant engines)."""
+    kv_quant engines), a head a row; a pool of `row_heads` > 1 takes them
+    re-viewed to its rows (_stored_pages)."""
     # dynalint: kv-codec — whole-page moves of the stored representation
+    if row_heads > 1:
+        pages = {key: _stored_pages(pages[key], row_heads) for key in cache}
     return {key: cache[key].at[:, :, ids].set(pages[key], mode="drop")
             for key in cache}
 
 
-def _inject_pages_slice(cache, ids, pages, slices=()):
+def _inject_pages_slice(cache, ids, pages, slices=(), row_heads: int = 1):
     """Scatter a shard slice of pages into the cache at ids: `slices`
     ((axis, start, count), ...) are STATIC bounds over the leading
     (layer, kv-head) axes — one compiled program per shard-plan entry.
@@ -3168,7 +3213,11 @@ def _inject_pages_slice(cache, ids, pages, slices=()):
     which numpy semantics keep in place as the single advanced index):
     a direct strided scatter on the donated buffer, never a
     materialized sub-cache copy — the per-chunk inject cost is O(chunk
-    slice), not O(cache)."""
+    slice), not O(cache). A pool of `row_heads` f > 1 heads a row takes a
+    slice of KV heads (which a sender's plan may cut anywhere) as one
+    such scatter a lane group: head h lands in row h // f, lanes
+    (h % f) * hd .., so the heads of the slice that share a lane group
+    are every f-th, and a run of rows."""
     out = {}
     # dynalint: kv-codec — whole-page slice moves keep the stored
     # (possibly quantized) representation; scale leaves share axes 0/1
@@ -3178,7 +3227,22 @@ def _inject_pages_slice(cache, ids, pages, slices=()):
         for axis, start, count in slices:
             idx[axis] = slice(start, start + count)
         idx[2] = ids
-        out[key] = arr.at[tuple(idx)].set(pages[key], mode="drop")
+        if row_heads == 1:
+            out[key] = arr.at[tuple(idx)].set(pages[key], mode="drop")
+            continue
+        heads = range(arr.shape[1] * row_heads)[idx[1]]   # of the slice
+        hd = arr.shape[-1] // row_heads
+        for lane in range(row_heads):
+            first = (lane - heads.start) % row_heads
+            mine = heads[first::row_heads]
+            if not mine:
+                continue
+            idx[1] = slice(mine[0] // row_heads,
+                           mine[0] // row_heads + len(mine))
+            idx[4] = slice(lane * hd, (lane + 1) * hd)
+            arr = arr.at[tuple(idx)].set(
+                pages[key][:, first::row_heads], mode="drop")
+        out[key] = arr
     return out
 
 

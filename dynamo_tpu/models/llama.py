@@ -655,7 +655,9 @@ def _layer_stack_shardings(cfg: ModelConfig, dense_mlp: bool,
 
 
 def cache_sharding(cfg: ModelConfig) -> P:
-    """KV cache [L, Hkv, P, ps, hd]: shard kv heads over tp.
+    """KV cache [L, rows, P, ps, width] (`cfg.kv_cache_leaves`: a row is
+    one kv head's hd values, or f adjacent heads'): shard the rows over
+    tp, whole rows a shard (engine/config.kv_heads_per_row).
 
     Head-major so one (head, page) slice is a contiguous [ps, hd] block —
     the decode kernel's DMA unit (ops/paged_attention.py)."""
@@ -681,10 +683,12 @@ def cache_shardings(cfg: ModelConfig) -> Dict[str, P]:
 
 def init_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                window_pages: int = 0) -> Dict[str, jax.Array]:
-    """The paged pool, a leaf per entry of `cfg.kv_cache_leaves()`:
-    [L, heads, pages, page_size, width]; and, for a model with a window
-    pool, a leaf per entry of `cfg.window_cache_leaves()` over ITS
-    layers and `window_pages` pages."""
+    """The paged pool as it is stored, a leaf per entry of
+    `cfg.kv_cache_leaves()`: [L, rows, pages, page_size, width], a row
+    `cfg.kv_row_heads` adjacent kv heads of head_dim each (one, unless
+    an engine resolved otherwise); and, for a model with a window pool,
+    a leaf per entry of `cfg.window_cache_leaves()` over ITS layers and
+    `window_pages` pages."""
     shapes = {name: (cfg.num_cache_layers, heads, num_pages, page_size,
                      width)
               for name, (heads, width) in cfg.kv_cache_leaves().items()}
@@ -995,7 +999,12 @@ def layer_front(x: jax.Array, lp: Params, cfg: ModelConfig,
     """x [B, T, D] -> q [B, T, H, hd], k, v [B, T, Hkv, hd]: attention
     norm, QKV projection (bias, QK-norm), split into heads, RoPE on q and
     k where `kind` has one (`rope_table`). `heads` = (H, Hkv) as the caller holds them: a "tp" shard of a
-    manual mesh passes its local counts. Under latent attention
+    manual mesh passes its local counts. Where the pool's rows hold f =
+    `cfg.kv_row_heads` > 1 heads, all three come out over ROWS, after the
+    norms and RoPE: k, v [B, T, Hkv / f, f * hd] (a view: the heads of a
+    row are adjacent) and q [B, T, H, f * hd] (`_query_rows`); every
+    caller then hands attention `attn_scale(cfg)` and `layer_back` keeps
+    each head's own lanes of the output. Under latent attention
     (`_mla_front`) q is the absorbed query, k the token's ONE cache row
     and v None: the attention ops take the whole row as its values and
     `_mla_out` keeps the latent columns of what they return. A linear
@@ -1018,8 +1027,54 @@ def layer_front(x: jax.Array, lp: Params, cfg: ModelConfig,
     def heads_of(a, n):
         a = a.reshape(b, t, n, cfg.head_dim)
         return a if rope is None else apply_rope(a, positions, *rope)
-    return heads_of(q, h), heads_of(k, hkv), \
+    q, k, v = heads_of(q, h), heads_of(k, hkv), \
         v.reshape(b, t, hkv, cfg.head_dim)
+    f = cfg.kv_row_heads
+    if f == 1:
+        return q, k, v
+    # the pool's rows hold f adjacent KV heads (`kv_cache_leaves`): k and
+    # v as stored, a view; each query over its KV head's lanes of the row
+    return _query_rows(q, cfg), k.reshape(b, t, hkv // f, -1), \
+        v.reshape(b, t, hkv // f, -1)
+
+
+def _own_lanes(cfg: ModelConfig, h: int) -> np.ndarray:
+    """[h, f] bool: which of a pool row's f heads is query head i's own.
+    Head i reads KV head i // g (g = H / Hkv), which lies in row
+    i // (g f), lanes ((i // g) % f) * hd ..; a "tp" shard's heads start
+    on a row's edge (`kv_heads_per_row`), so its local i says the same."""
+    f = cfg.kv_row_heads
+    return ((np.arange(h) // cfg.q_per_kv) % f)[:, None] == np.arange(f)
+
+
+def _query_rows(q: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """q [B, T, H, hd] -> [B, T, H, f * hd], zero outside its KV head's
+    lanes: q . row is then the published score exactly (the row's other
+    heads meet zeros), the standard grouping holds over Hkv / f rows of
+    g f heads each, and no attention op knows the row is shared."""
+    own = _own_lanes(cfg, q.shape[2])[:, :, None]
+    return jnp.where(own, q[..., None, :], 0).reshape(
+        q.shape[:3] + (-1,))
+
+
+def _head_values(attn: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """The way back: attention's output over pool rows [B, T, H, f * hd]
+    (each head weighted the WHOLE row's values) -> [B, T, H, hd], each
+    head's own lanes."""
+    b, t, h = attn.shape[:3]
+    own = _own_lanes(cfg, h)[:, :, None]
+    return jnp.sum(jnp.where(
+        own, attn.reshape(b, t, h, cfg.kv_row_heads, -1), 0), axis=-2)
+
+
+def attn_scale(cfg: ModelConfig) -> float:
+    """What every attention op is handed as `q_scale`. 0.0 selects
+    `width ** -0.5` from the OPERAND's last axis (ops/attention._scale),
+    which is head_dim only while a row is one head: where rows are shared
+    the scale is named."""
+    if cfg.kv_row_heads == 1:
+        return cfg.query_scale
+    return cfg.query_scale or cfg.head_dim ** -0.5
 
 
 def _mla_up_proj(lp: Params, cfg: ModelConfig, dtype) -> tuple:
@@ -1596,7 +1651,9 @@ def layer_back(x: jax.Array, attn: jax.Array, lp: Params, cfg: ModelConfig,
                mlp, reduce=None, kind: str = "", ssm=None):
 
     """(x [B, T, D], attn [B, T, ...heads]) -> (next x, the MLP's stats):
-    output projection, residual, MLP norm, `mlp(xn, lp)` -> (out, stats),
+    (softmax attention over pool rows of several heads: each head's own
+    lanes first, `_head_values`,) output projection, residual, MLP norm,
+    `mlp(xn, lp)` -> (out, stats),
     residual, with Gemma's post-norms where the configuration has them.
     `reduce` sums a partial product over the caller's manual "tp" axis; it
     comes BEFORE the post-norm, which is nonlinear and must see the whole
@@ -1617,8 +1674,12 @@ def layer_back(x: jax.Array, attn: jax.Array, lp: Params, cfg: ModelConfig,
         attn = _mla_out(attn.reshape(b, t, cfg.num_heads, -1), lp, cfg)
         if cfg.mla_gate:
             attn = _mla_gate(attn, x, lp, cfg)
-    elif cfg.attn_out_gate:
-        attn = _out_gate(attn.reshape(b, t, -1), x, lp, cfg)
+    else:
+        if cfg.kv_row_heads > 1:
+            attn = _head_values(
+                attn.reshape(b, t, -1, cfg.kv_row_heads * cfg.head_dim), cfg)
+        if cfg.attn_out_gate:
+            attn = _out_gate(attn.reshape(b, t, -1), x, lp, cfg)
     with jax.named_scope("shortconv.out_proj") if kind == "conv" \
             else contextlib.nullcontext():
         out = jnp.einsum("bte,ed->btd", attn.reshape(b, t, -1),
@@ -1825,7 +1886,7 @@ def decode_forward(
                 attn = decode_attention_split(
                     q[:, 0], kb, vb, kw, vw, k_new, v_new, swa[4], win_lens,
                     softcap=cfg.attn_softcap, window=swa_wnd,
-                    q_scale=cfg.query_scale)
+                    q_scale=attn_scale(cfg))
         elif window is not None:
             # one layer group: the window's leaves are the scan's xs. A
             # second group reads its layers from the whole leaves by
@@ -1838,7 +1899,7 @@ def decode_forward(
                 attn = decode_attention_split(
                     q[:, 0], kb, vb, kw, vw, k_new, v_new, base_lens,
                     win_lens, softcap=cfg.attn_softcap, window=wnd,
-                    q_scale=cfg.query_scale)
+                    q_scale=attn_scale(cfg))
         elif kernel_mode is not None:
             interp = kernel_mode == "interpret"
             # int8 caches hand the kernels the raw pages plus the scale
@@ -1870,7 +1931,7 @@ def decode_forward(
                 # dynalint: kv-codec — consumer dequantizes at gather
                 q[:, 0], cache["k"], cache.get("v"), k_new, v_new,
                 page_table, prefix_lens, softcap=cfg.attn_softcap,
-                window=wnd, q_scale=cfg.query_scale,
+                window=wnd, q_scale=attn_scale(cfg),
                 k_scale=scales[0], v_scale=scales[1], layer=cl)
         x, drop_stats = layer_back(
             x, attn, lp, cfg, lambda xn, lp: _mlp_block(
@@ -1997,7 +2058,7 @@ def forward(
     params: Params,
     cfg: ModelConfig,
     tokens: jax.Array,            # [B, Tq] int32
-    cache: Dict[str, jax.Array],  # {"k","v"}: [L, Hkv, P, ps, hd]
+    cache: Dict[str, jax.Array],  # {"k","v"}: cfg.kv_cache_leaves()
     meta: AttnMetadata,
     input_embeds: Optional[jax.Array] = None,  # [B, Tq, D] overrides tokens
     embeds_mask: Optional[jax.Array] = None,   # [B, Tq] bool: mix per-token
@@ -2293,11 +2354,11 @@ def forward(
             with scope(), jax.named_scope("attention"):
                 if sel is None:
                     attn = attend(q, k, v, lens, positions,
-                                  cfg.attn_softcap, wnd, cfg.query_scale)
+                                  cfg.attn_softcap, wnd, attn_scale(cfg))
                 else:
                     attn = attention_rows(
                         flat(q)[0], k, v, lens, positions, rows_plan,
-                        grid_valid, cfg.attn_softcap, wnd, cfg.query_scale)
+                        grid_valid, cfg.attn_softcap, wnd, attn_scale(cfg))
             return back(sel, x, attn, ssm, stored=sel is not None)
 
         def attention_back(x, q, kc, vc, table, lens, positions, wnd, scope,
@@ -2317,7 +2378,7 @@ def forward(
                 attn = paged_attention(
                     q, kc, vc, table, lens, positions,
                     softcap=cfg.attn_softcap, window=wnd,
-                    q_scale=cfg.query_scale, k_scale=ksc, v_scale=vsc,
+                    q_scale=attn_scale(cfg), k_scale=ksc, v_scale=vsc,
                     layer=sl)
             return either(back, x, attn, *ssm)
 
@@ -2396,7 +2457,7 @@ def forward(
                     layer=sl[None])[:, None]
         elif use_ring:
             attn = ring_attention(q, k, v, meta.positions, kv_positions,
-                                  sp_mesh)
+                                  sp_mesh, scale=attn_scale(cfg))
         else:
             x, drop_stats = attention_back(
                 x, q, kc, vc, meta.page_table, meta.kv_lens, meta.positions,

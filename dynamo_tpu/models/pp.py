@@ -198,7 +198,7 @@ def _stage(cfg: ModelConfig, tp: int, x, layers, kc, vc,
             kc, vc = write_kv_pages(kc, vc, k, v, meta.write_idx)
         attn = paged_attention(q, kc, vc, meta.page_table, meta.kv_lens,
                                meta.positions, softcap=cfg.attn_softcap,
-                               window=wnd, q_scale=cfg.query_scale,
+                               window=wnd, q_scale=llama.attn_scale(cfg),
                                k_scale=ksc_l, v_scale=vsc_l)
         x, _ = llama.layer_back(x, attn, lp, cfg, mlp, psum_tp)
         ys = (kc, vc, ksc_l, vsc_l) if kvq else (kc, vc)

@@ -169,6 +169,9 @@ class LedgerStats:
         #                           them: rows x table width x page size
         "kv_bytes_per_token",     # gauge: bytes a token holds in the
         #                           cache, all layers (set at engine start)
+        "kv_heads_per_row",       # gauge: KV heads that share one row of
+        #                           the device pool (engine/config.
+        #                           kv_heads_per_row; 1 = a head a row)
         # a model whose sliding layers keep a page pool of their own
         # (ModelConfig.window_pool; engine._account_attention,
         # _account_window_pool). The two series above then count the

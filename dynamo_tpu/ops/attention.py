@@ -25,6 +25,15 @@ separate [n_kv_heads, num_pages, page_size, head_dim] arrays per layer
 (head, page) slice contiguous (the decode kernel's DMA unit) and lets the
 kv-head axis shard cleanly over the `tp` mesh axis.
 
+Every shape below is written for a pool row of ONE kv head, [L, Hkv, P,
+ps, hd]. What is stored is `ModelConfig.kv_cache_leaves()`: where
+head_dim < 128 an engine may keep f adjacent heads to a 128-lane row,
+[L, Hkv / f, P, ps, f * hd] over the same bytes (engine/config.
+kv_heads_per_row), and nothing here knows: models/llama.layer_front
+hands these ops Hkv / f "heads" of width f * hd, queries that are zero
+outside their own head's lanes, and the scale by name (`q_scale`; 0
+would read the ROW's width, `_scale`).
+
 The engine's programs touch the stacked leaves [L, Hkv, P, ps, hd] only
 through `gather_pages(..., layer=)` (the pages a page table names, by
 (layer, page)) and `write_kv_rows` (the rows a step produced, by (layer,
@@ -48,6 +57,8 @@ NEG_INF = -1e30
 
 
 def _scale(hd: int, q_scale: float) -> float:
+    """`hd`: the operand's last axis, which is head_dim only while a pool
+    row is one head; a caller whose rows hold more names the scale."""
     return q_scale if q_scale else hd ** -0.5
 
 
@@ -61,7 +72,9 @@ def _softcap(scores: jax.Array, cap: float) -> jax.Array:
 def gather_pages(cache: jax.Array, page_table: jax.Array,
                  layer: Optional[jax.Array] = None) -> jax.Array:
     """[Hkv, P, ps, hd] gathered by [B, Pb] -> [Hkv, B, Pb*ps, hd]; a
-    scale leaf [Hkv, P, ps] gives [Hkv, B, Pb*ps].
+    scale leaf [Hkv, P, ps] gives [Hkv, B, Pb*ps]. (As stored: Hkv and
+    hd are the pool's rows a token and their width, whatever a row
+    holds.)
 
     With `layer` (a traced int32 scalar) `cache` is the STACKED leaf
     [L, Hkv, P, ps, ...] and the pages are read by (layer, page) in one
@@ -546,6 +559,7 @@ def compact_index(plan: KvWritePlan, width: int) -> CompactIndex:
 def write_kv_rows(
     pools: tuple,        # stacked leaves [L, Hkv, P, ps, hd] / scales [L, Hkv, P, ps]
     rows: tuple,         # a leaf each: [Lw, N, Hkv, hd] / [Lw, N, Hkv]
+    #                      (as stored: rows of f heads are [.., Hkv/f, f*hd])
     plan: KvWritePlan,
     layers: jax.Array,   # [Lw] int32: the layer rows[:, i] belong to
 ) -> tuple:
@@ -559,7 +573,11 @@ def write_kv_rows(
     to hd: a copy of the whole leaf there and back, 2 x 2.1 GB a leaf
     for Mistral-7B-16 at 1024 pages (PERF.md section 6, PR 26). The
     operand keeps its five axes, so a pool sharded over kv heads (`tp`)
-    is written shard by shard with no collective.
+    is written shard by shard with no collective. The window is a whole
+    lane tile only where the pool's row is 128 wide: a 64-wide row rests
+    pages-minor and this scatter re-laid BOTH leaves out, whole, once a
+    layer, which is why 64-wide heads are stored two to a row (PERF.md
+    section 6, PR 51).
 
     Rows are written a block of KV_WRITE_BLOCK valid tokens at a time in
     a loop whose trip count follows `plan.n_valid`: padding rows and

@@ -45,13 +45,13 @@ def _flash_update(q, k, v, qpos, kpos, m, l, acc, scale):
     return m_new, l_new, acc_new
 
 
-def _ring_local(axis: str, n: int, q, k, v, qpos, kpos):
+def _ring_local(axis: str, n: int, scale: float, q, k, v, qpos, kpos):
     """Per-shard body: local q stays, k/v/kpos rotate n times."""
     b, tq, h, hd = q.shape
     hkv = k.shape[2]
     g = h // hkv
     qg = q.reshape(b, tq, hkv, g, hd)
-    scale = hd ** -0.5
+    scale = scale or hd ** -0.5
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     # mark the fresh accumulators as device-varying over the ring axis so
@@ -88,13 +88,14 @@ def ring_attention(
     kv_positions: jax.Array,  # [B, T] int32; -1 = padding
     mesh: Mesh,
     axis: str = "sp",
+    scale: float = 0.0,     # 0 = hd ** -0.5 (rows of several heads name it)
 ) -> jax.Array:
     """Exact causal attention with the sequence sharded over `axis`."""
     n = mesh.shape[axis]
     seq = P(None, axis, None, None)
     pos = P(None, axis)
     f = jax.shard_map(
-        functools.partial(_ring_local, axis, n),
+        functools.partial(_ring_local, axis, n, scale),
         mesh=mesh,
         in_specs=(seq, seq, seq, pos, pos),
         out_specs=seq,

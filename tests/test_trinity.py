@@ -24,7 +24,7 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.engine.config import (
-    EngineConfig, RopeParams, refuse_unserved,
+    EngineConfig, RopeParams, refuse_unserved, with_kv_rows,
 )
 from dynamo_tpu.engine.engine import NativeEngine
 from dynamo_tpu.engine.scheduler import Scheduler
@@ -430,10 +430,17 @@ def test_the_window_tables_width_follows_the_window():
 # lost its sort (with tests/test_sampler_tail.one_sort_keep_mask patched
 # over it, all eight read PR 40's digests again: nothing else moved).
 PARENT_PROGRAMS = {
-    ("rehearsal-tiny", "step"): "8b3a1bcc2cd60304",
-    ("rehearsal-tiny", "window"): "f9966fa4a465991d",
-    ("rehearsal-tiny-olmoe", "step"): "fc8c262d1fc40d22",
-    ("rehearsal-tiny-olmoe", "window"): "c484f91bd464d383",
+    # PR 51 replaced these four, and MEANT to: 4 KV heads of 32 share one
+    # 128-lane pool row (engine/config.kv_heads_per_row gives f = 4), so
+    # `layer_front` forms rows, the queries are zero-extended and the
+    # scale is named; with the rule patched to 1 they read PR 50's
+    # 8b3a1bcc2cd60304 / f9966fa4a465991d / fc8c262d1fc40d22 /
+    # c484f91bd464d383 again. The six below have 2 KV heads or a latent
+    # cache, keep a head a row and stand
+    ("rehearsal-tiny", "step"): "d6d4e28b3a6aa3ba",
+    ("rehearsal-tiny", "window"): "e8e6031c9fbd2901",
+    ("rehearsal-tiny-olmoe", "step"): "fc391189438bccfd",
+    ("rehearsal-tiny-olmoe", "window"): "214e56cb1f87ea0a",
     ("rehearsal-tiny-moonlight", "step"): "285056b88b57c562",
     ("rehearsal-tiny-moonlight", "window"): "1f212b404bab2dbb",
     ("rehearsal-tiny-mellum", "step"): "6921cab248da7e90",
@@ -464,7 +471,8 @@ def program_texts(name, rows=8, chunk=16, pages=8, base_pages=8):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs", name,
                            "config.json")) as f:
-        cfg = config_from_hf(json.load(f), name=name)
+        # the pool's rows as NativeEngine resolves them on one device
+        cfg = with_kv_rows(config_from_hf(json.load(f), name=name))
     ecfg = EngineConfig()
 
     def arr(shape, dtype=jnp.int32):
@@ -548,9 +556,13 @@ SERVED_PROGRAMS = {
     ("falcon-h1-34b", 64, 64, "window"): "279a188e98469eba",
     # PR 50: LFM2 at its cell's shape, this tree's own (the parent cannot
     # trace it): a lead's conv body before the period loop's two, the
-    # tails in the window's carry beside the attention layers' new rows
-    ("lfm2-8b-a1b", 8, 64, "step"): "ef012449ae3183b4",
-    ("lfm2-8b-a1b", 8, 64, "window"): "30ba5ac52d223f9b",
+    # tails in the window's carry beside the attention layers' new rows.
+    # PR 51 replaced both, and MEANT to (they read ef012449ae3183b4 /
+    # 30ba5ac52d223f9b): two 64-wide KV heads share a pool row, the pool
+    # is [3, 4, P, 64, 128]; the 12 above (128-wide heads, or a latent
+    # cache) are untouched
+    ("lfm2-8b-a1b", 8, 64, "step"): "58702a7655d63973",
+    ("lfm2-8b-a1b", 8, 64, "window"): "f697e06a037af8e9",
 }
 
 

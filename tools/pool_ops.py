@@ -213,7 +213,7 @@ def build_programs(config_dir: str, rows: int, chunk: int, pages: int,
     import dataclasses
 
     from dynamo_tpu.engine import engine as eng
-    from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.engine.config import EngineConfig, with_kv_rows
     from dynamo_tpu.engine.scheduler import window_ladder
     from dynamo_tpu.models import llama
     from dynamo_tpu.models.loader import config_from_hf
@@ -232,6 +232,8 @@ def build_programs(config_dir: str, rows: int, chunk: int, pages: int,
     ecfg = EngineConfig()
     num_pages = int(serve.get("--num-pages", ecfg.num_pages))
     tp = int(serve.get("--tp", 1))
+    # the pool's rows as NativeEngine resolves them for this mesh
+    cfg = with_kv_rows(cfg, tp)
     devices, target = _devices()
     # as NativeEngine: parameters and pool by the model's PartitionSpecs
     # over the mesh, every plan array replicated
