@@ -270,9 +270,11 @@ class NativeEngine:
         # step() charges the gap to `between` (note_idle resets it)
         self._t_step_exit: Optional[float] = None
         # the caller's marks inside that gap (note_between), and the
-        # program key of the step() call in progress (_dispatch_phase)
+        # program key and step kind of what the step() call in progress
+        # launched (_dispatch_phase)
         self._between_marks: Optional[tuple] = None
         self._call_key: Optional[tuple] = None
+        self._call_kind: Optional[str] = None
         # requests that have not sampled a first token yet:
         # request_id -> [t_add, t_first_planned | None, steps, trace]
         # (the engine-side split of first-token time, _mark_planned)
@@ -283,15 +285,10 @@ class NativeEngine:
         # array), branch-only when DYN_LEDGER=0; drains as JSONL, folds
         # into the llm_engine_* gauges
         from dynamo_tpu.observability.ledger import (
-            StepLedger, install_jax_listeners, model_flops_per_token,
-            sampler_flops_per_token,
+            StepLedger, install_jax_listeners,
         )
         install_jax_listeners()
-        # MFU denominator counts the fused sampling tail's vocab-sized
-        # device work alongside the model matmuls (PR 18)
-        self.ledger = StepLedger(
-            flops_per_token=model_flops_per_token(model_cfg)
-            + sampler_flops_per_token(model_cfg))
+        self.ledger = StepLedger()
         self.ledger.stats.kv_bytes_per_token = model_cfg.kv_bytes_per_token()
         self.ledger.stats.kv_heads_per_row = model_cfg.kv_row_heads
         self.ledger.stats.kv_bytes_per_token_full = \
@@ -308,6 +305,13 @@ class NativeEngine:
         # flat once the bucket ladder is warm)
         self._seen_programs: set = set()
         self._pending_recompiles = 0
+        # program launches so far: the `seq` of a dispatch's annotation
+        self._dispatch_seq = 0
+        # key -> [the jitted function, its arguments as shapes, the `seq`
+        # of its last launch]: what lowers a dispatched program again, so
+        # that a profiler capture can leave the optimised HLO of what it
+        # saw beside its trace (`program_texts`)
+        self._programs: Dict[tuple, list] = {}
         self.phases.stats = self.ledger.stats
         # the phases double as trace spans under the "engine" scope
         # (runtime/tracing.py defer_phase — the hot-path deferred
@@ -862,7 +866,10 @@ class NativeEngine:
                 parts = (marks[0] - self._t_step_exit, marks[1] - marks[0],
                          marks[2] - marks[1], t_entry - marks[2])
                 self.ledger.split_between(parts)
-        self._call_key = None
+        self._call_key = self._call_kind = None
+        # the marks the call's record reads a drain and a stall off
+        # (ledger.close_call): each a counter the engine keeps
+        before = (self.pipeline_fallbacks, len(self._seen_programs))
         try:
             return self._step()
         finally:
@@ -872,7 +879,10 @@ class NativeEngine:
                 "decode" if key and key[0] in ("window", "ppwindow")
                 else "mixed" if self._flight is not None else "",
                 self._key_bucket(key), t_entry, t_exit, between,
-                parts, self.phases.take_call())
+                parts, self.phases.take_call(), self.phases.exposed,
+                launched=self._call_kind,
+                ended_row=self.pipeline_fallbacks != before[0],
+                first_dispatch=len(self._seen_programs) != before[1])
 
     def _step(self) -> List[StepOutput]:
         if self._pipeline is not None:
@@ -986,19 +996,58 @@ class NativeEngine:
                    self.scheduler.params[seq.request_id].logprobs is not None
                    for seq in reqs)
 
-    def _dispatch_phase(self, key: tuple):
+    def _dispatch_phase(self, key: tuple, kind: str, fn, args: tuple):
         """The `dispatch` phase of one program launch. Recompile detection
         lives here: the first dispatch of a (program, bucket-shape) key is
         an XLA compile or a cache load that stalls the loop, so it is
         annotated `engine.compile` (a trace then names the step that
         stalled), logged once with its seconds, and counted as a
-        recompile on the next ledger sample."""
-        self._call_key = key
+        recompile on the next ledger sample. The annotation carries what
+        is launched as stats beside its name: the step's `kind`
+        ("prefill" | "mixed" | "window" | "verify"), its bucket (`rows`
+        and `chunk` of an `_engine_step` or a verify block, `rows` and
+        `rung` of a window), `seq` (the launches so far) and `ahead` (a
+        program was still in flight): a capture's reducer gives every
+        program the device ran the bucket of its launch
+        (observability/profile.py). `fn(*args)` is the launch itself:
+        a key's first dispatch keeps `fn` and the arguments' shapes
+        (`program_texts`)."""
+        self._call_key, self._call_kind = key, kind
+        self._dispatch_seq += 1
+        stats = {"kind": kind, "seq": self._dispatch_seq,
+                 "ahead": int(self.phases.device_busy)}
+        if kind == "window":
+            stats["rung"] = self._key_bucket(key)
+            stats["rows"] = key[-3 if key[0] == "ppwindow" else -4]
+        else:
+            stats["rows"], stats["chunk"] = self._key_bucket(key)
         if key in self._seen_programs:
-            return self.phases.phase("dispatch")
+            self._programs[key][2] = self._dispatch_seq
+            return self.phases.phase("dispatch", stats=stats)
         self._seen_programs.add(key)
+        self._programs[key] = [fn, jax.tree.map(_abstract, args),
+                               self._dispatch_seq]
         self._pending_recompiles += 1
-        return self._first_dispatch(key)
+        return self._first_dispatch(key, stats)
+
+    def program_texts(self, since: int = 0) -> Dict[str, str]:
+        """The optimised HLO text of every program launched after the
+        `since`-th dispatch (`_dispatch_seq`; 0: all of them), by a name
+        that says its module and bucket. Each is lowered again from the
+        shapes of its first dispatch: the same jaxpr and lowering as the
+        call itself, so jax hands back the executable it already holds
+        and nothing is compiled (tests/test_step_tracing.py). For a
+        capture's end (llm/worker.py capture_profile), off the engine's
+        thread: a trace's ops carry no scope, the HLO's `op_name`s do."""
+        out = {}
+        for key, (fn, avals, last) in list(self._programs.items()):
+            if last <= since:
+                continue
+            name = fn.__wrapped__.__name__ + "".join(
+                f"-{'x'.join(map(str, d)) if isinstance(d, tuple) else d}"
+                for d in key[1:] if d is not None and d is not False)
+            out[name] = fn.lower(*avals).compile().as_text()
+        return out
 
     # where a program key holds its bucket: the `[Bb, Tb]` grid of an
     # `_engine_step` or a verify block, a decode window's rung
@@ -1012,9 +1061,10 @@ class NativeEngine:
         return key[at] if at is not None else None
 
     @contextlib.contextmanager
-    def _first_dispatch(self, key: tuple):
+    def _first_dispatch(self, key: tuple, stats: dict):
         t0 = time.perf_counter()
-        with self.phases.phase("dispatch", annotation="compile"):
+        with self.phases.phase("dispatch", annotation="compile",
+                               stats=stats):
             yield
         sch = self.scheduler
         logging.getLogger(__name__).info(
@@ -1110,7 +1160,7 @@ class NativeEngine:
         prefill or mixed step); the caller commits."""
         with self.phases.phase("upload"):
             staged = self._stage_step(plan, reqs, mixed)
-        return self._launch_step(staged)
+        return self._launch_step(staged, "mixed" if mixed else "prefill")
 
     def _stage_step(self, plan, reqs, mixed: bool = False,
                     after: Optional[dict] = None) -> tuple:
@@ -1301,15 +1351,15 @@ class NativeEngine:
         self.ledger.stats.host_buffers_total += len(staged)
         return (layout, *staged)
 
-    def _dispatch_step(self, staged: tuple) -> tuple:
-        """dispatch of a staged `_engine_step` program: (its outputs,
-        still on the device; its tokens as the step behind it reads
-        them)."""
+    def _dispatch_step(self, staged: tuple, kind: str) -> tuple:
+        """dispatch of a staged `_engine_step` program, a step of `kind`
+        ("prefill" | "mixed"): (its outputs, still on the device; its
+        tokens as the step behind it reads them)."""
         key, args, _ = staged
-        with self._dispatch_phase(key):
-            # key[1:4] is the variant: (with_rp, with_lp, with_mm)
-            *outs, self.cache, aux, prev = self._step_fns[key[1:4]](
-                self.params, self.cache, *args)
+        # key[1:4] is the variant: (with_rp, with_lp, with_mm)
+        fn, args = self._step_fns[key[1:4]], (self.params, self.cache, *args)
+        with self._dispatch_phase(key, kind, fn, args):
+            *outs, self.cache, aux, prev = fn(*args)
         return (*outs, aux), prev
 
     def _fetch_step(self, outs: tuple, with_lp: bool = False,
@@ -1325,9 +1375,9 @@ class NativeEngine:
         self._last_logprobs = (lp, top_ids, top_lps) if with_lp else None
         return np.asarray(tokens)
 
-    def _launch_step(self, staged: tuple):
+    def _launch_step(self, staged: tuple, kind: str = "prefill"):
         """dispatch + wait of a staged `_engine_step` program."""
-        outs, _ = self._dispatch_step(staged)
+        outs, _ = self._dispatch_step(staged, kind)
         return self._fetch_step(outs, with_lp=staged[2])
 
     def _run_prefill(self, plan: PrefillPlan) -> List[StepOutput]:
@@ -1438,7 +1488,7 @@ class NativeEngine:
         with self.phases.phase("upload"):
             staged = self._stage_step(plan, plan.seqs, mixed=True,
                                       after=after)
-        outs, prev = self._dispatch_step(staged)
+        outs, prev = self._dispatch_step(staged, "mixed")
         self._copy_outs_async(outs)
         # the decode rows advance outside the window program: any saved
         # device-resident window carry is stale
@@ -1869,15 +1919,16 @@ class NativeEngine:
         penalty plan, history) + a [S, 3] (token, position, counter)
         carry. Returns (outs, next_carry) with outs still ON DEVICE — the
         caller decides when to sync."""
-        with self._dispatch_phase(staged["program"]):
+        fn = (self._pp_decode_fns if staged["pp"]
+              else self._decode_fns)[staged["key"]]
+        args = (self.params, self.cache, carry, *staged["dev"])
+        with self._dispatch_phase(staged["program"], "window", fn, args):
             if staged["pp"]:
-                toks, self.cache, nxt = self._pp_decode_fns[staged["key"]](
-                    self.params, self.cache, carry, *staged["dev"])
+                toks, self.cache, nxt = fn(*args)
                 outs = (toks, None, None, None, {})
             else:
                 toks, lps, top_ids, top_lps, self.cache, aux, nxt = \
-                    self._decode_fns[staged["key"]](
-                        self.params, self.cache, carry, *staged["dev"])
+                    fn(*args)
                 outs = (toks, lps, top_ids, top_lps, aux)
         self.decode_windows += 1
         if "attn" in staged:
@@ -2345,9 +2396,9 @@ class NativeEngine:
         first finished event, mirroring _commit_window.
         """
         key, args = block
-        with self._dispatch_phase(key):
-            pred, self.cache, aux = self._verify_fn(
-                self.params, self.cache, *args)
+        args = (self.params, self.cache, *args)
+        with self._dispatch_phase(key, "verify", self._verify_fn, args):
+            pred, self.cache, aux = self._verify_fn(*args)
         with self.phases.phase("wait"):
             pred, aux = jax.device_get((pred, aux))
         self.phases.device_busy = False
@@ -2867,8 +2918,12 @@ class NativeEngine:
         m.engine_steps = self.ledger.steps
         m.engine_recompiles = self.ledger.recompiles_total
         m.engine_tok_s = round(self.ledger.tok_s, 3)
-        m.engine_mfu = round(self.ledger.mfu, 6)
         m.engine_pad_frac = round(self.ledger.pad_fraction(), 4)
+        # the two exposures and the stalls (ledger.close_call)
+        for name in ("host_exposed_handover_seconds",
+                     "host_exposed_drain_seconds", "period_stalls_total",
+                     "period_stall_seconds", "period_stall_wait_seconds"):
+            setattr(m, name, getattr(self.ledger.stats, name))
         if self.host_pool is not None:
             m.kv_host_pages_used = self.host_pool.used
             m.kv_host_pages_total = self.host_pool.capacity
@@ -3039,6 +3094,16 @@ class NativeEngine:
         return warmed
 
 
+def _abstract(x):
+    """An argument of a program as its shape: an array's shape, dtype
+    and, where it is committed to one, its sharding (what `jit` keys a
+    lowering on); anything else (the static operand layout) as it is."""
+    if not isinstance(x, jax.Array):
+        return x
+    return jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=x.sharding if x.committed else None)
+
+
 def _carry_behind(prev_tokens, carry):
     """The [S, 3] carry of a window dispatched behind a step in flight:
     `carry` [S, 4] is the host's (token, position, counter, src); a row
@@ -3131,16 +3196,19 @@ def _packed(fn, names: tuple, own: tuple = (), carried: bool = False,
     array and hands on the next in the same form, so a chained window is
     fed the device's own. A `fed` program (`_engine_step`) takes the
     tokens of the step before it in the same place, a device array too."""
+    @jax.named_scope("step")
     def program(params, cache, *args):
         kw = {}
         if fed:
             kw["prev_tokens"], *args = args
-        if carried:
-            carry, *args = args
-            kw.update(tokens=carry[:, 0], positions=carry[:, 1],
-                      counters=carry[:, 2])
-        layout, buf, *rest = args
-        kw.update(zip(names, unpack_operands(layout, buf), strict=True))
+        with jax.named_scope("step.unpack"):
+            if carried:
+                carry, *args = args
+                kw.update(tokens=carry[:, 0], positions=carry[:, 1],
+                          counters=carry[:, 2])
+            layout, buf, *rest = args
+            kw.update(zip(names, unpack_operands(layout, buf),
+                          strict=True))
         kw.update(zip(own, rest, strict=True))
         out = fn(params, cache, **kw)
         if carried:
@@ -3246,6 +3314,7 @@ def _inject_pages_slice(cache, ids, pages, slices=(), row_heads: int = 1):
     return out
 
 
+@jax.named_scope("kv.write")
 def _scatter_new_kv(cache, k_news, v_news, write_idx, keys=None):
     """One in-place scatter of all layers' new kv rows (deferred write).
 
@@ -3361,11 +3430,13 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
         # spares the gathered base (537 MB a leaf for Mistral-7B-16 at 32
         # slots x 512 tokens) the fill pass of take's default mode, a
         # broadcast and a select as large as the base itself
+        @jax.named_scope("attention.gather")
         def gather_base(c, table=base_table):
             g = jnp.take(c, table.reshape(-1), axis=2, mode="clip")
             return g.reshape(c.shape[0], hkv_n, s,
                              table.shape[1] * page_size, hd)
 
+        @jax.named_scope("attention.gather")
         def gather_base_scale(sc):
             g = jnp.take(sc, base_table.reshape(-1), axis=2, mode="clip")
             return g.reshape(l, hkv_n, s, lb)
@@ -3415,6 +3486,7 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
         return jnp.where(writable & (at >= 0),
                          page * page_size + pos % page_size, -1)
 
+    @jax.named_scope("sampler")
     def sample_and_track(logits, ctr, seen, alive):
         """Shared step tail: sampling + rep-penalty seen set + eos alive.
         One definition so the kernel and pregather bodies can't diverge."""
@@ -3478,21 +3550,24 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
             # the window layers' rows: into their buffer at step t, and
             # out for their own end-of-window writeback
             w_news = more[-1]
-            swa_win = tuple(jax.lax.dynamic_update_index_in_dim(
-                buf, new.transpose(0, 2, 1, 3).astype(buf.dtype), t, axis=3)
-                for buf, new in zip(swa_win, w_news))
+            with jax.named_scope("kv.window"):
+                swa_win = tuple(jax.lax.dynamic_update_index_in_dim(
+                    buf, new.transpose(0, 2, 1, 3).astype(buf.dtype), t,
+                    axis=3) for buf, new in zip(swa_win, w_news))
             w_out = (*w_news, window_write_idx(pos, writable))
         # this step's rows land at window index t for every slot; slots
         # that may not write (finished/padding) still store garbage there
         # but their win_len stops growing, so attention never reads it.
         # The global-cache slot for the end-of-window writeback is
         # tracked separately (dropped rows get index -1).
-        kw = jax.lax.dynamic_update_index_in_dim(
-            kw, k_news.transpose(0, 2, 1, 3).astype(kw.dtype), t, axis=3)
-        if vw is not None:
-            vw = jax.lax.dynamic_update_index_in_dim(
-                vw, v_news.transpose(0, 2, 1, 3).astype(vw.dtype), t,
+        with jax.named_scope("kv.window"):
+            kw = jax.lax.dynamic_update_index_in_dim(
+                kw, k_news.transpose(0, 2, 1, 3).astype(kw.dtype), t,
                 axis=3)
+            if vw is not None:
+                vw = jax.lax.dynamic_update_index_in_dim(
+                    vw, v_news.transpose(0, 2, 1, 3).astype(vw.dtype), t,
+                    axis=3)
         nxt, lp, top_ids, top_lps, seen, alive = sample_and_track(
             logits, ctr, seen, alive)
         return (kw, vw, state, swa_win, nxt, pos + 1, ctr + 1, seen,

@@ -305,15 +305,21 @@ class EngineMetrics:
     kv_transfer_link_timeouts: int = 0
     # per-step resource ledger (observability/ledger.py): committed
     # device steps, recompile events (first dispatch of a new
-    # (program, bucket) key), EWMA instantaneous useful tok/s, MFU
-    # estimate (0 without a configured peak), cumulative bucket-ladder
-    # padding-waste fraction, and offload tier occupancy — the
-    # per-worker signals observability/fleet.py's rollup consumes
+    # (program, bucket) key), EWMA instantaneous useful tok/s,
+    # cumulative bucket-ladder padding-waste fraction, and offload tier
+    # occupancy — the per-worker signals observability/fleet.py's
+    # rollup consumes
     engine_steps: int = 0
     engine_recompiles: int = 0
     engine_tok_s: float = 0.0
-    engine_mfu: float = 0.0
     engine_pad_frac: float = 0.0
+    # `host_exposed_seconds` by the call it accrued in, and the calls
+    # whose period stalled (observability/ledger.py close_call)
+    host_exposed_handover_seconds: float = 0.0
+    host_exposed_drain_seconds: float = 0.0
+    period_stalls_total: int = 0
+    period_stall_seconds: float = 0.0
+    period_stall_wait_seconds: float = 0.0
     kv_host_pages_used: int = 0
     kv_host_pages_total: int = 0
     kv_disk_pages_used: int = 0

@@ -199,8 +199,8 @@ class HttpService:
             for name in PoolRingStats.FIELDS}
         # per-step engine ledger (observability/ledger.py LEDGER_STATS):
         # step counts per kind, recompiles, bucket-ladder padding waste,
-        # KV tier occupancy, batch occupancy, queue depth, EWMA tok/s
-        # and the MFU estimate — same render-time fold as the rest
+        # KV tier occupancy, batch occupancy, queue depth and EWMA tok/s:
+        # the same render-time fold as the rest
         from dynamo_tpu.observability.ledger import LedgerStats
         self._engine = {
             name: m.gauge(f"llm_engine_{name}",
@@ -299,7 +299,9 @@ class HttpService:
 
     async def _debug_profile(self, req: Request) -> Response:
         """`POST /debug/profile?seconds=<n>`: one bounded JAX profiler
-        capture of an in-process engine (llm/worker.py capture_profile).
+        capture of an in-process engine (llm/worker.py capture_profile);
+        answers with the trace's directory, the path of its
+        `profile_summary.json` and that table's top level.
         404 unless DYN_JAX_PROFILE_DIR is set: the variable means
         "captures are allowed, and go here"."""
         import os
@@ -320,10 +322,10 @@ class HttpService:
             raise HttpError(400, "seconds must be in (0, 60]")
         out_dir = os.path.join(base, time.strftime("capture-%Y%m%d-%H%M%S"))
         try:
-            await worker.capture_profile(seconds, out_dir)
+            captured = await worker.capture_profile(seconds, out_dir)
         except RuntimeError as e:
             raise HttpError(409, str(e))
-        return Response.json({"trace_dir": out_dir, "seconds": seconds})
+        return Response.json({"seconds": seconds, **captured})
 
     def _refresh_robustness_gauges(self) -> None:
         """Fold the process-global fault/integrity/drain counters into

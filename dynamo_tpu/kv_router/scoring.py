@@ -76,12 +76,11 @@ class WorkerMetrics:
     kv_transfer_stale_chunks: int = 0
     kv_transfer_link_timeouts: int = 0
     # per-step ledger figures (observability/ledger.py): steps,
-    # recompile events, EWMA tok/s, MFU estimate, padding-waste
-    # fraction, and offload tier occupancy (fleet rollup inputs)
+    # recompile events, EWMA tok/s, padding-waste fraction, and
+    # offload tier occupancy (fleet rollup inputs)
     engine_steps: int = 0
     engine_recompiles: int = 0
     engine_tok_s: float = 0.0
-    engine_mfu: float = 0.0
     engine_pad_frac: float = 0.0
     kv_host_pages_used: int = 0
     kv_host_pages_total: int = 0

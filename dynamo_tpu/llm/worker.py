@@ -19,6 +19,7 @@ import glob
 import json
 import logging
 import os
+import sys
 import time
 from typing import AsyncIterator, Dict, Optional
 
@@ -163,17 +164,24 @@ class NativeEngineWorker(AsyncEngine):
         self._loop_task = asyncio.create_task(self._step_loop())
         return self
 
-    async def capture_profile(self, seconds: float, out_dir: str) -> str:
-        """One bounded JAX profiler capture of the serving loop: start,
-        sleep `seconds`, stop (in the executor: stopping serialises the
-        trace), Python tracer off (it slows the host it measures).
-        Refuses while one runs, here or anywhere else in the process (the
-        JAX trace is process-global). The capture holds the engine's
-        `engine.<phase>` and the loop's `worker.*` annotations next to
-        the device's lines; benchmark/harness/trace_reduce.py reduces it.
-        Beside the `*.xplane.pb` it leaves `steps.jsonl`, the engine's
-        step() records of the captured stretch on the trace's own clock
-        (`_write_step_records`). Returns `out_dir`."""
+    async def capture_profile(self, seconds: float, out_dir: str) -> dict:
+        """One bounded JAX profiler capture of the serving loop, and its
+        table: start, sleep `seconds`, stop (in the executor: stopping
+        serialises the trace), Python tracer off (it slows the host it
+        measures); then `python -m dynamo_tpu.observability.profile
+        <out_dir>` in a CHILD process, which reads the xplane alone and
+        writes `profile_summary.json` beside it: neither the engine's
+        thread nor the event loop waits on the reduction. Refuses while
+        one runs, here or anywhere else in the process (the JAX trace is
+        process-global). The capture holds the engine's `engine.<phase>`
+        and the loop's `worker.*` annotations next to the device's lines,
+        each `engine.dispatch` with what it launched as its stats. Beside
+        the `*.xplane.pb` it leaves `programs/*.hlo.txt`, the optimised
+        HLO of the programs the engine launched meanwhile
+        (NativeEngine.program_texts): a trace's ops name no scope, their
+        `op_name`s there do. Returns {"trace_dir", "summary": the file's
+        path (None where the child failed), the summary's top level, and
+        "cost_s": what stopping, the programs' texts and the child took}."""
         if self._capturing:
             raise RuntimeError("a profiler capture is already running")
         import jax
@@ -181,72 +189,57 @@ class NativeEngineWorker(AsyncEngine):
         try:
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
+            since = getattr(self.engine, "_dispatch_seq", 0)
             jax.profiler.start_trace(out_dir, profiler_options=opts)
-            t_start = time.perf_counter()
+            cost = {}
             try:
                 await asyncio.sleep(seconds)
             finally:
-                # one instant on both clocks: perf_counter, which times
-                # the records, and the wall clock, which the trace's
-                # host events count from the trace's own start
-                anchor = (time.perf_counter_ns(), time.time_ns())
                 await asyncio.get_running_loop().run_in_executor(
-                    None, self._stop_capture, out_dir, t_start, anchor)
+                    None, self._stop_capture, out_dir, since, cost)
+            log.info("jax profiler: %.1fs captured to %s (stop %.1fs, the "
+                     "programs' HLO %.2fs)", seconds, out_dir,
+                     cost.get("stop", 0.0), cost.get("programs", 0.0))
+            out = {"trace_dir": out_dir, "summary": None, "cost_s": cost}
+            t_child = time.perf_counter()
+            import dynamo_tpu
+            root = os.path.dirname(os.path.dirname(dynamo_tpu.__file__))
+            child = await asyncio.create_subprocess_exec(
+                sys.executable, "-m", "dynamo_tpu.observability.profile",
+                out_dir, stdout=asyncio.subprocess.PIPE,
+                # the child reads a file: it is never to reach for a chip
+                env={**os.environ, "JAX_PLATFORMS": "cpu",
+                     "PYTHONPATH": os.pathsep.join(filter(None, (
+                         root, os.environ.get("PYTHONPATH"))))})
+            stdout, _ = await child.communicate()
+            cost["child"] = time.perf_counter() - t_child
+            if child.returncode == 0:
+                out.update(json.loads(stdout.splitlines()[-1]))
+            else:
+                log.error("no summary of %s: the reducer exited %d",
+                          out_dir, child.returncode)
+            return out
         finally:
             self._capturing = False
-        log.info("jax profiler: %.1fs captured to %s", seconds, out_dir)
-        return out_dir
 
-    def _stop_capture(self, out_dir: str, t_start: float,
-                      anchor: tuple) -> None:
+    def _stop_capture(self, out_dir: str, since: int, cost: dict) -> None:
         import jax
+        t0 = time.perf_counter()
         jax.profiler.stop_trace()
+        t1 = time.perf_counter()
+        cost["stop"] = t1 - t0
+        texts = getattr(self.engine, "program_texts", None)
         try:
-            self._write_step_records(out_dir, t_start, anchor)
+            path = sorted(glob.glob(os.path.join(
+                out_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+            home = os.path.join(os.path.dirname(path), "programs")
+            os.makedirs(home, exist_ok=True)
+            for name, text in (texts(since) if texts else {}).items():
+                with open(os.path.join(home, name + ".hlo.txt"), "w") as f:
+                    f.write(text)
+            cost["programs"] = time.perf_counter() - t1
         except Exception:  # the capture itself is whole without them
-            log.exception("no steps.jsonl beside the capture")
-
-    def _write_step_records(self, out_dir: str, t_start: float,
-                            anchor: tuple) -> None:
-        """`steps.jsonl` beside the capture's `*.xplane.pb`: a first line
-        with the anchor (one instant as `perf_counter_ns` and as
-        `trace_ns`, the clock the xplane's host events carry:
-        nanoseconds since the `profile_start_time` its `Task
-        Environment` plane states), then the StepLedger's record of
-        every step() call whose period overlaps the capture, its
-        `t_entry` / `t_exit` and each phase's start converted to
-        `trace_ns`. A gap of the device can then be put down to the kind
-        and bucket of the calls around it and to the part of `between`
-        it fell in, which no annotation can say (an annotation may not
-        cross an `await`)."""
-        from jax.profiler import ProfileData
-        path = sorted(glob.glob(os.path.join(
-            out_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
-        zero = next(
-            int(value) for plane in ProfileData.from_file(path).planes
-            if plane.name == "Task Environment"
-            for name, value in plane.stats if name == "profile_start_time")
-        perf_ns, wall_ns = anchor
-        shift = wall_ns - zero - perf_ns
-
-        def trace_ns(t: float) -> int:
-            return round(t * 1e9) + shift
-
-        lines = [{"anchor": {"perf_counter_ns": perf_ns,
-                             "trace_ns": perf_ns + shift},
-                  "capture_ns": [trace_ns(t_start), perf_ns + shift]}]
-        for rec in self.engine.ledger.calls(t_start, perf_ns / 1e9):
-            for key in ("ts", "dt", "tok_s", "mfu"):
-                del rec[key]
-            rec["t_entry_ns"] = trace_ns(rec.pop("t_entry"))
-            rec["t_exit_ns"] = trace_ns(rec.pop("t_exit"))
-            rec["phases"] = {
-                name: {"start_ns": trace_ns(t0), "seconds": dt}
-                for name, (t0, dt) in rec["phases"].items()}
-            lines.append(rec)
-        with open(os.path.join(os.path.dirname(path), "steps.jsonl"),
-                  "w") as f:
-            f.writelines(json.dumps(line) + "\n" for line in lines)
+            log.exception("no programs beside the capture")
 
     async def stop(self) -> None:
         if self._loop_task:

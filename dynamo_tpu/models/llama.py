@@ -831,6 +831,7 @@ def rope_table(cfg: ModelConfig, kind: str = "") -> tuple:
     return p.theta, yarn_inv_freq(p, cfg.head_dim), scale
 
 
+@jax.named_scope("attention.rope")
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
                inv_freq: Optional[np.ndarray] = None,
                scale: float = 1.0) -> jax.Array:
@@ -878,7 +879,6 @@ def _moe_mlp(x: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
     return jnp.einsum("betd,bte->btd", down.astype(jnp.float32), combine).astype(x.dtype)
 
 
-@jax.named_scope("mlp")
 def _dense_mlp(x: jax.Array, lp: Params, cfg: ModelConfig,
                leaves: tuple = ("w_gate", "w_up", "w_down")) -> jax.Array:
     w_gate, w_up, w_down = (wmat(lp[name], x.dtype) for name in leaves)
@@ -889,6 +889,7 @@ def _dense_mlp(x: jax.Array, lp: Params, cfg: ModelConfig,
     return _times(jnp.einsum("btf,fd->btd", act, w_down), m_down)
 
 
+@jax.named_scope("attention.qkv")
 def qkv_proj(xn: jax.Array, lp: Params, cfg: ModelConfig):
     """x -> (q [B, T, H*hd], k, v [B, T, Hkv*hd]), before the split into
     heads and RoPE. With `cfg.qk_norm` True (OLMoE) q and k each pass an
@@ -950,7 +951,8 @@ def _mlp_block(xn: jax.Array, lp: Params, cfg: ModelConfig, mesh,
         with jax.named_scope("mlp.dense_lead"):
             return _dense_mlp(xn, lp, cfg), None
     if not cfg.is_moe:
-        return _dense_mlp(xn, lp, cfg), None
+        with jax.named_scope("mlp"):
+            return _dense_mlp(xn, lp, cfg), None
     if cfg.moe_impl == "dense":
         out, stats = _moe_mlp(xn, lp, cfg), None
     elif mesh is not None and mesh.shape.get("ep", 1) > 1:
@@ -1019,8 +1021,9 @@ def layer_front(x: jax.Array, lp: Params, cfg: ModelConfig,
         return _mla_front(x, lp, cfg, positions)
     b, t = x.shape[:2]
     h, hkv = heads
-    xn = _times(rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps,
-                         cfg.norm_plus_one), cfg.attention_in_multiplier)
+    with jax.named_scope("norm.attn"):
+        xn = _times(rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps,
+                             cfg.norm_plus_one), cfg.attention_in_multiplier)
     q, k, v = qkv_proj(xn, lp, cfg)
     rope = rope_table(cfg, kind)
 
@@ -1103,7 +1106,9 @@ def _mla_front(x: jax.Array, lp: Params, cfg: ModelConfig,
     b, t = x.shape[:2]
     h, r = cfg.num_heads, cfg.kv_lora_rank
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
+    with jax.named_scope("norm.attn"):
+        xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps,
+                      cfg.norm_plus_one)
     with jax.named_scope("attention.mla.q"):
         q = jnp.einsum("btd,de->bte", xn, wmat(lp["wq"], xn.dtype)
                        ).reshape(b, t, h, dn + dr)
@@ -1175,8 +1180,11 @@ def _kda_front(x: jax.Array, lp: Params, cfg: ModelConfig):
     b, t = x.shape[:2]
     h, d = cfg.num_heads, cfg.linear_head_dim
     f32 = jnp.float32
-    xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
-    pre = jnp.einsum("btd,de->bte", xn, wmat(lp["kda_wqkv"], xn.dtype))
+    with jax.named_scope("norm.attn"):
+        xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps,
+                      cfg.norm_plus_one)
+    with jax.named_scope("linattn.in_proj"):
+        pre = jnp.einsum("btd,de->bte", xn, wmat(lp["kda_wqkv"], xn.dtype))
     with jax.named_scope("linattn.gate"):
         f = jnp.einsum("btd,de->bte", xn, wmat(lp["kda_wf"], xn.dtype)
                        ).astype(f32) + lp["kda_dt_bias"].astype(f32)
@@ -1401,7 +1409,9 @@ def _ssm_front(x: jax.Array, lp: Params, cfg: ModelConfig):
     model's dtype: the block's norm, the input multiplier, `ssm_in`, and
     the constant vector that multiplies the five segments z | x | B | C |
     dt by `ssm_multipliers`."""
-    xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
+    with jax.named_scope("norm.attn"):
+        xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps,
+                      cfg.norm_plus_one)
     ds, conv = cfg.mamba_d_ssm, cfg.mamba_conv_dim
     with jax.named_scope("block.parallel/ssm.in_proj"):
         p = jnp.einsum("btd,de->bte", _times(xn, cfg.ssm_in_multiplier),
@@ -1560,7 +1570,9 @@ def _conv_front(x: jax.Array, lp: Params, cfg: ModelConfig):
     state; c [..., D] the gate on its output), in the model's dtype: the
     block's norm, `conv_in` [D, B | C | u]."""
     d = cfg.hidden_size
-    xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
+    with jax.named_scope("norm.attn"):
+        xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps,
+                      cfg.norm_plus_one)
     with jax.named_scope("shortconv.in_proj"):
         p = jnp.einsum("...d,de->...e", xn, wmat(lp["conv_in"], xn.dtype))
     with jax.named_scope("shortconv.gate"):
@@ -1647,6 +1659,21 @@ def conv_mix_rows(state: tuple, l, slots: jax.Array, lp: Params,
     return conv_tail, o
 
 
+def _layer_scan(*args, **kwargs):
+    """`jax.lax.scan` over a model's layers (or its periods): what the
+    loop itself does with its stacked operands (a layer's slice of every
+    xs leaf read, of every ys leaf written) counts to `layers.stack`; the
+    body's ops name their own scopes, which stand inside it. A scope of
+    its own a call: a period's scan holds its parts' scans, and ONE
+    `named_scope` object entered inside itself hands the outer one the
+    inner one's name stack back."""
+    with jax.named_scope("layers.stack"):
+        return jax.lax.scan(*args, **kwargs)
+
+# the scope of a layer's output projection `wo`, by the layer's kind
+_WO_SCOPE = {"conv": "shortconv.out_proj", "kda": "linattn.wo"}
+
+
 def layer_back(x: jax.Array, attn: jax.Array, lp: Params, cfg: ModelConfig,
                mlp, reduce=None, kind: str = "", ssm=None):
 
@@ -1680,27 +1707,32 @@ def layer_back(x: jax.Array, attn: jax.Array, lp: Params, cfg: ModelConfig,
                 attn.reshape(b, t, -1, cfg.kv_row_heads * cfg.head_dim), cfg)
         if cfg.attn_out_gate:
             attn = _out_gate(attn.reshape(b, t, -1), x, lp, cfg)
-    with jax.named_scope("shortconv.out_proj") if kind == "conv" \
-            else contextlib.nullcontext():
+    with jax.named_scope(_WO_SCOPE.get(kind, "attention.wo")):
         out = jnp.einsum("bte,ed->btd", attn.reshape(b, t, -1),
                          wmat(lp["wo"], x.dtype))
     if kind == "par":
-        out = _times(out, cfg.attention_out_multiplier) + _times(
-            jnp.einsum("bte,ed->btd", ssm, wmat(lp["ssm_out"], x.dtype)),
-            cfg.ssm_out_multiplier)
+        with jax.named_scope("block.parallel/ssm.out_proj"):
+            out = _times(out, cfg.attention_out_multiplier) + _times(
+                jnp.einsum("bte,ed->btd", ssm,
+                           wmat(lp["ssm_out"], x.dtype)),
+                cfg.ssm_out_multiplier)
     if reduce is not None:
         out = reduce(out)
     if cfg.post_norms:
-        out = rms_norm(out, lp["post_attn_norm"], cfg.rms_norm_eps,
-                       cfg.norm_plus_one)
+        with jax.named_scope("norm.post"):
+            out = rms_norm(out, lp["post_attn_norm"], cfg.rms_norm_eps,
+                           cfg.norm_plus_one)
     x = x + out
-    xn = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_plus_one)
+    with jax.named_scope("norm.mlp"):
+        xn = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps,
+                      cfg.norm_plus_one)
     out, stats = mlp(xn, lp)
     if reduce is not None:
         out = reduce(out)
     if cfg.post_norms:
-        out = rms_norm(out, lp["post_mlp_norm"], cfg.rms_norm_eps,
-                       cfg.norm_plus_one)
+        with jax.named_scope("norm.post"):
+            out = rms_norm(out, lp["post_mlp_norm"], cfg.rms_norm_eps,
+                           cfg.norm_plus_one)
     return x + out, stats
 
 
@@ -1711,6 +1743,7 @@ def lm_head(params: Params, cfg: ModelConfig) -> jax.Array:
             else params["lm_head"])
 
 
+@jax.named_scope("head")
 def lm_logits(x: jax.Array, final_norm: jax.Array, head: jax.Array,
               cfg: ModelConfig) -> jax.Array:
     """x [..., D] -> float32 logits [..., V]: final norm, head matmul,
@@ -1799,10 +1832,11 @@ def decode_forward(
     kvq = bool(_validate_kv_quant(cfg.kv_quant))
     lw = cfg.layer_windows()
     layer_wnd = None if lw is None else jnp.asarray(lw, jnp.int32)
-    # ids validated at admission (_validate_prompt); decode feeds only
-    # committed sampler outputs  # dynalint: disable-next-line=R1
-    x = scale_embeds(jnp.take(params["embed"], tokens, axis=0),
-                     cfg)[:, None]  # [B, 1, D]
+    with jax.named_scope("embed"):
+        # ids validated at admission (_validate_prompt); decode feeds only
+        # committed sampler outputs  # dynalint: disable-next-line=R1
+        x = scale_embeds(jnp.take(params["embed"], tokens, axis=0),
+                         cfg)[:, None]  # [B, 1, D]
     layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
     moe_aux = cfg.is_moe and cfg.moe_impl == "dispatch"
     token_valid = valid[:, None] if (moe_aux and valid is not None) else None
@@ -1821,6 +1855,7 @@ def decode_forward(
     swa_wnd = jnp.int32(cfg.sliding_window) if cfg.window_pool else None
     row_valid = valid if valid is not None else jnp.ones(tokens.shape, bool)
 
+    @jax.named_scope("layers.body")
     def kda_step_layer(carry, xs, run, expert_stacks):
         """A linear layer: no cache row, a state update instead."""
         x, st = carry
@@ -1835,11 +1870,13 @@ def decode_forward(
                 lid - run.first, run.dense), kind="kda")
         return (x, st), drop_stats if moe_aux else None
 
+    @jax.named_scope("layers.stack")
     def stack_layer(stack, lid, run):
         """A period's part: layer `lid` read from its kind's stack."""
         return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
             a, lid - run.first, keepdims=False), stack)
 
+    @jax.named_scope("layers.body")
     def conv_step_layer(carry, xs, run, expert_stacks, stack=None):
         """A conv layer: no cache row, its tail moved on a token."""
         x, st = carry
@@ -1855,6 +1892,7 @@ def decode_forward(
                 lid - run.first, run.dense), kind="conv")
         return (x, st), drop_stats if moe_aux else None
 
+    @jax.named_scope("layers.body")
     def par_step_layer(carry, xs, run, expert_stacks):
         """A parallel block: the mixer's state update from the block's
         input, then `layer_step`, which adds its output to attention's."""
@@ -1866,6 +1904,7 @@ def decode_forward(
         x, out = layer_step(x, xs, run, expert_stacks, ssm=o[:, None])
         return (x, st), out
 
+    @jax.named_scope("layers.body")
     def layer_step(x, xs, run, expert_stacks, stack=None, ssm=None):
         lp, lid, wnd, win = xs
         first, dense = run.first, run.dense
@@ -1953,7 +1992,7 @@ def decode_forward(
         part = _group_rows(whole, first, count)
         if run.kind in ("kda", "conv"):
             with _lead_scope(run):
-                (x, st), drop_g = jax.lax.scan(
+                (x, st), drop_g = _layer_scan(
                     functools.partial(
                         kda_step_layer if run.kind == "kda"
                         else conv_step_layer, run=run,
@@ -1965,13 +2004,13 @@ def decode_forward(
               None if layer_wnd is None else part(layer_wnd),
               win_leaves if window is not None and whole else None)
         if run.kind == "par":
-            (x, st), (k_g, v_g, drop_g) = jax.lax.scan(
+            (x, st), (k_g, v_g, drop_g) = _layer_scan(
                 functools.partial(par_step_layer, run=run,
                                   expert_stacks=expert_stacks),
                 (x, st), xs)
         else:
             with _lead_scope(run):
-                x, (k_g, v_g, drop_g) = jax.lax.scan(
+                x, (k_g, v_g, drop_g) = _layer_scan(
                     functools.partial(layer_step, run=run,
                                       expert_stacks=expert_stacks),
                     x, xs)
@@ -1996,14 +2035,14 @@ def decode_forward(
                 lids = run.first + p * per + offset \
                     + jnp.arange(count, dtype=jnp.int32)
                 if run.kind == "conv":
-                    (x, st), drop_g = jax.lax.scan(
+                    (x, st), drop_g = _layer_scan(
                         functools.partial(conv_step_layer, run=run,
                                           expert_stacks=stacks[ri][1],
                                           stack=stacks[ri][0]),
                         (x, st), (None, lids))
                     stats.append(_sum_stats(drop_g))
                     continue
-                x, (k_g, v_g, drop_g) = jax.lax.scan(
+                x, (k_g, v_g, drop_g) = _layer_scan(
                     functools.partial(layer_step, run=run,
                                       expert_stacks=stacks[ri][1],
                                       stack=stacks[ri][0]),
@@ -2015,7 +2054,7 @@ def decode_forward(
                 tuple(tuple(jnp.concatenate(g, axis=0) for g in kv)
                       for kv in rows.values()), _merge_stats(stats))
 
-        (x, st), (rows, drop_p) = jax.lax.scan(
+        (x, st), (rows, drop_p) = _layer_scan(
             period_step, (x, st),
             jnp.arange(period.count, dtype=jnp.int32))
         for ri, (k_g, v_g) in zip(loop, rows):
@@ -2133,6 +2172,7 @@ def forward(
     heads = (cfg.num_heads, cfg.num_kv_heads)
     kvq = bool(_validate_kv_quant(cfg.kv_quant))
 
+    @jax.named_scope("embed")
     def embed(tokens, input_embeds, embeds_mask):
         if input_embeds is None:
             # admission validated the ids  # dynalint: disable-next-line=R1
@@ -2232,17 +2272,21 @@ def forward(
         return jax.lax.cond(fits, functools.partial(fn, sel),
                             functools.partial(fn, None), *operands)
 
+    @jax.named_scope("step.compact")
     def flat(a):        # token rows [B, Tq, ...] -> the flat [1, W, ...]
         return a.reshape((1, n) + a.shape[2:])[:, :width]
 
+    @jax.named_scope("step.compact")
     def unflat(a):      # [1, W, ...] -> token rows, the rest zero
         pad = [(0, 0), (0, n - width)] + [(0, 0)] * (a.ndim - 2)
         return jnp.pad(a, pad).reshape((b, tq) + a.shape[2:])
 
+    @jax.named_scope("step.compact")
     def from_grid(a):   # cells [B, Tq, ...] -> their flat rows [1, W, ...]
         return jnp.take(a.reshape((n,) + a.shape[2:]), sel.cells, axis=0,
                         mode="clip")[None]
 
+    @jax.named_scope("step.compact")
     def to_grid(a):     # flat rows [1, W, ...] -> their cells [B, Tq, ...]
         return jnp.take(a[0], sel.slot, axis=0, mode="clip"
                         ).reshape((b, tq) + a.shape[2:])
@@ -2276,6 +2320,7 @@ def forward(
         x = jax.lax.with_sharding_constraint(
             x, NamedSharding(sp_mesh, P(None, "sp", None)))
 
+    @jax.named_scope("layers.body")
     def layer_step(carry, layer, stack, run, expert_stacks):
         # pool: (k, v[, k_scale, v_scale]) stacks; state: the recurrent
         # state's leaves, () for a model without linear layers; wpool:
@@ -2289,6 +2334,7 @@ def forward(
             # a `cond` branch is handed its operands as buffers: a layer's
             # slice of the stack would be copied for it, so the branches
             # read the stack where it lies
+            @jax.named_scope("layers.stack")
             def lp_of():
                 return jax.tree.map(
                     lambda a: jax.lax.dynamic_index_in_dim(
@@ -2498,7 +2544,7 @@ def forward(
         scan_xs = (scan_layers if sel is None else None, part(layer_ids),
                    None if layer_wnd is None else part(layer_wnd))
         with _lead_scope(run):
-            (x, pool, state, wpool), drop_g = jax.lax.scan(
+            (x, pool, state, wpool), drop_g = _layer_scan(
                 functools.partial(layer_step, stack=scan_layers, run=run,
                                   expert_stacks=expert_stacks),
                 (x, pool, state, wpool), scan_xs)
@@ -2515,14 +2561,14 @@ def forward(
                 run = runs[ri]
                 lids = run.first + p * per + offset \
                     + jnp.arange(count, dtype=jnp.int32)
-                carry, drop_g = jax.lax.scan(
+                carry, drop_g = _layer_scan(
                     functools.partial(layer_step, stack=stacks[ri][0],
                                       run=run, expert_stacks=stacks[ri][1]),
                     carry, (None, lids, None))
                 stats.append(_sum_stats(drop_g))
             return carry, _merge_stats(stats)
 
-        (x, pool, state, wpool), drop_p = jax.lax.scan(
+        (x, pool, state, wpool), drop_p = _layer_scan(
             period_step, (x, pool, state, wpool),
             jnp.arange(period.count, dtype=jnp.int32))
         drops.append(_sum_stats(drop_p))

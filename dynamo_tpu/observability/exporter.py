@@ -151,7 +151,7 @@ class MetricsExporter:
             )}
         # per-step ledger figures (observability/ledger.py via
         # EngineMetrics): committed steps, recompile events, EWMA tok/s,
-        # MFU estimate, padding-waste fraction, offload tier occupancy
+        # padding-waste fraction, offload tier occupancy
         self.g_engine = {
             name: r.gauge(f"{PREFIX}_engine_{name}", help_, labels)
             for name, help_ in (
@@ -159,8 +159,6 @@ class MetricsExporter:
                 ("recompiles",
                  "New (program, bucket) keys dispatched (XLA compiles)"),
                 ("tok_s", "EWMA instantaneous useful tokens/s"),
-                ("mfu", "Model FLOPs utilization estimate (0 = no peak "
-                        "configured)"),
                 ("pad_frac",
                  "Cumulative bucket-ladder padding-waste fraction"),
                 ("host_pages_used", "Host-DRAM KV tier pages in use"),
@@ -416,7 +414,6 @@ class MetricsExporter:
             self.g_engine["recompiles"].set(
                 worker_id, value=m.engine_recompiles)
             self.g_engine["tok_s"].set(worker_id, value=m.engine_tok_s)
-            self.g_engine["mfu"].set(worker_id, value=m.engine_mfu)
             self.g_engine["pad_frac"].set(
                 worker_id, value=m.engine_pad_frac)
             self.g_engine["host_pages_used"].set(

@@ -34,7 +34,7 @@ log = logging.getLogger("dynamo_tpu.fleet")
 WORKER_FIELDS = (
     "kv_active_blocks", "kv_total_blocks", "request_active_slots",
     "num_requests_waiting", "gpu_cache_usage_perc", "engine_tok_s",
-    "engine_mfu", "engine_pad_frac", "engine_recompiles",
+    "engine_pad_frac", "engine_recompiles",
     "kv_host_pages_used", "kv_transfer_bytes",
 )
 

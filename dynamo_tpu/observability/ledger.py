@@ -42,9 +42,10 @@ Per-step sample schema (one JSONL record per step after `drain()`):
     {"ts", "dt", "kind", "rows", "rows_live", "tokens_useful",
      "tokens_padded", "kv_used", "kv_total", "host_used", "host_total",
      "disk_used", "disk_total", "waiting", "recompiles", "stream_hit",
-     "stream_late", "stream_spilled", "stream_stalls", "tok_s", "mfu",
+     "stream_late", "stream_spilled", "stream_stalls", "tok_s",
      "dev_steps", "streams", "tokens", "bucket", "t_entry", "t_exit",
-     "between", "resume", "emit", "apply_pending", "submit", "phases"}
+     "between", "resume", "emit", "apply_pending", "submit", "phases",
+     "stall"}
 `kind` is the step kind ("prefill" | "decode" | "mixed" | "spec" |
 "stream" — the last is a tiered-KV streamed long-context step, whose
 stream_* columns carry that step's window-pool prefetch deltas);
@@ -65,6 +66,8 @@ worker's loop marks them (`resume`, `emit`, `apply_pending`, `submit`:
 llm/worker.py `_step_loop`), and `phases`, name -> [start, seconds] of
 the PhaseTimer phases this call ran. `between` + `t_exit` - `t_entry`
 is the call's PERIOD: over a busy stretch the periods tile the wall time.
+`stall`: the call committed a step, first dispatched no program, and its
+period passed STALL_PERIOD_S all the same (`period_stalls_total`).
 
 docs/OBSERVABILITY.md §5 documents the gauge catalog and the fleet
 rollup (observability/fleet.py) that consumes the per-worker fields.
@@ -130,7 +133,6 @@ class LedgerStats:
         "stream_stall_steps",
         "queue_depth",            # last step: requests waiting
         "tok_s",                  # EWMA instantaneous useful tok/s
-        "mfu",                    # tok_s * flops/token / peak (0 = no peak)
         "samples_dropped",        # ring overwrites (oldest lost)
         # the engine host loop's own clock (observability/metrics.py
         # PhaseTimer), cumulative seconds as floats, all step kinds:
@@ -245,6 +247,27 @@ class LedgerStats:
         "host_submit_seconds",    # -> step() entry: the executor's pick-up
         "host_exposed_between_seconds",  # of `between`, the part with no
         #                           program in flight (PhaseTimer.add)
+        # `host_exposed_seconds` by the call in whose period it accrued
+        # (close_call): two particular exposures, each by itself; what
+        # is left of the sum is the steady loop's
+        "host_exposed_drain_seconds",     # the calls after a commit that
+        #                           ended a row under a window in flight
+        #                           (the commits `pipeline_fallbacks`
+        #                           counts), up to and with the first that
+        #                           launches a program again
+        "host_exposed_handover_seconds",  # a call that LAUNCHES a step of
+        #                           another kind than the launch before it
+        #                           (mixed against window: the hand-overs
+        #                           `handovers` counts at their commit, a
+        #                           call later), unless it is a drain's
+        # calls that committed a step, first dispatched no program, and
+        # whose period passed STALL_PERIOD_S: no capture is long enough
+        # to catch one, a run can now say it had one
+        "period_stalls_total",
+        "period_stall_seconds",       # their periods
+        "period_stall_wait_seconds",  # of those, inside `wait`: the
+        #                           device's or the runtime's side, not
+        #                           the host's phases
         # what made each gap between two commits to one stream
         # (_account_gaps): the gap before the FIRST token a commit gives
         # a stream goes whole to one class, by the programs committed
@@ -302,33 +325,9 @@ def install_jax_listeners() -> None:
     monitoring.register_event_listener(on_event)
 
 
-def model_flops_per_token(cfg) -> float:
-    """Matmul FLOPs one decoded token costs (2 x active matmul params):
-    attention projections + MLP (active experts only on MoE) + lm head.
-    Attention score/value FLOPs are context-dependent and excluded, so
-    this is a floor — the resulting MFU is conservative. `cfg` is a
-    ModelConfig (engine/config.py)."""
-    d = cfg.hidden_size
-    q = cfg.num_heads * cfg.head_dim
-    kv = cfg.num_kv_heads * cfg.head_dim
-    attn = d * q + 2 * d * kv + q * d
-    mlp = 3 * d * cfg.intermediate_size
-    if cfg.num_experts:
-        mlp *= cfg.num_experts_per_tok
-    head = d * cfg.vocab_size
-    return 2.0 * (cfg.num_layers * (attn + mlp) + head)
-
-
-def sampler_flops_per_token(cfg) -> float:
-    """FLOPs the fused sampling tail spends per decoded token (PR 18):
-    with the tail fused into the decode window program, its vocab-sized
-    work (temperature scale, rank mask, gumbel draw — ~5 elementwise
-    passes over [V], sort excluded as comparison-not-FLOP) executes on
-    the device inside the step the ledger meters, so the MFU denominator
-    counts it. Kept separate from `model_flops_per_token` (whose formula
-    is load-bearing for existing consumers); the engine passes the sum."""
-    return 5.0 * cfg.vocab_size
-
+# a call's period past this is a stall (close_call): the longest sound
+# period in the ledger is a window of 8 x 27 ms
+STALL_PERIOD_S = 0.5
 
 _KINDS = ("prefill", "decode", "mixed", "spec", "stream")
 
@@ -339,12 +338,12 @@ _KEYS = ("ts", "dt", "kind", "rows", "rows_live", "tokens_useful",
          "tokens_padded", "kv_used", "kv_total", "host_used",
          "host_total", "disk_used", "disk_total", "waiting",
          "recompiles", "stream_hit", "stream_late", "stream_spilled",
-         "stream_stalls", "tok_s", "mfu", "dev_steps", "streams",
+         "stream_stalls", "tok_s", "dev_steps", "streams",
          "tokens", "bucket", "t_entry", "t_exit", "between", "resume",
-         "emit", "apply_pending", "submit", "phases")
+         "emit", "apply_pending", "submit", "phases", "stall")
 _DEV_STEPS = _KEYS.index("dev_steps")
 _CALL = _KEYS.index("bucket")
-_NO_CALL = (None, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0, None)
+_NO_CALL = (None, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0, None, False)
 
 
 class StepLedger:
@@ -353,15 +352,13 @@ class StepLedger:
     `stats` defaults to the process-global LEDGER_STATS (what /metrics
     renders); pass a private LedgerStats for isolation in tests. The
     EWMA smoothing (`tok_s`) uses alpha=0.2 over per-step instantaneous
-    rates; `peak_flops` (DYN_PEAK_TFLOPS e12, or `configure()`) turns
-    the rate into an MFU estimate — 0.0 when no peak is known (CPU)."""
+    rates."""
 
     EWMA_ALPHA = 0.2
 
     def __init__(self, capacity: Optional[int] = None,
                  enabled: Optional[bool] = None,
-                 stats: Optional[LedgerStats] = None,
-                 flops_per_token: float = 0.0):
+                 stats: Optional[LedgerStats] = None):
         if enabled is None:
             enabled = os.environ.get("DYN_LEDGER", "1") not in ("", "0")
         if capacity is None:
@@ -369,9 +366,6 @@ class StepLedger:
         self.enabled = bool(enabled)
         self.capacity = max(1, int(capacity))
         self.stats = stats if stats is not None else LEDGER_STATS
-        self.flops_per_token = float(flops_per_token)
-        self.peak_flops = float(
-            os.environ.get("DYN_PEAK_TFLOPS", "0")) * 1e12
         self._recs: List[list] = []
         self._pos = 0
         self.dropped = 0
@@ -384,6 +378,14 @@ class StepLedger:
         self._programs = 0
         self._last_ts = 0.0
         self._tok_s = 0.0
+        # the engine's exposed seconds (PhaseTimer.exposed) as the last
+        # close_call found them, and whether a call it closed ended a
+        # row under a window in flight and none has launched a program
+        # since: the next call's period is a drain's
+        self._exposed_seen = 0.0
+        self._draining = False
+        # the kind of the last program launched, "mixed" or "window"
+        self._launched: Optional[str] = None
         # per-INSTANCE cumulative counters (metrics() reads these; the
         # shared `stats` fold is process-cumulative across engines)
         self.steps = 0
@@ -392,15 +394,12 @@ class StepLedger:
         self.padded_total = 0
 
     def configure(self, enabled: Optional[bool] = None,
-                  capacity: Optional[int] = None,
-                  peak_tflops: Optional[float] = None) -> "StepLedger":
+                  capacity: Optional[int] = None) -> "StepLedger":
         if enabled is not None:
             self.enabled = enabled
         if capacity is not None:
             self.capacity = max(1, int(capacity))
             self._recs, self._pos, self._open = [], 0, None
-        if peak_tflops is not None:
-            self.peak_flops = peak_tflops * 1e12
         return self
 
     # -- recording (deferred-recorder discipline: host ints only) -------------
@@ -435,15 +434,12 @@ class StepLedger:
         if 0.0 < dt < 60.0:
             inst = useful / dt
             self._tok_s += self.EWMA_ALPHA * (inst - self._tok_s)
-        mfu = 0.0
-        if self.peak_flops > 0.0 and self.flops_per_token > 0.0:
-            mfu = self._tok_s * self.flops_per_token / self.peak_flops
         self._open = self._append(
             [now, dt, kind, rows, rows_live, useful, padded,
              kv_used, kv_total, host_used, host_total,
              disk_used, disk_total, waiting, recompiles,
              stream_hit, stream_late, stream_spilled, stream_stalls,
-             self._tok_s, mfu, dev_steps, streams, tokens, *_NO_CALL])
+             self._tok_s, dev_steps, streams, tokens, *_NO_CALL])
         self.steps += 1
         self.recompiles_total += recompiles
         self.useful_total += useful
@@ -481,7 +477,6 @@ class StepLedger:
         s.stream_pages_spilled += stream_spilled
         s.stream_stall_steps += stream_stalls
         s.tok_s = self._tok_s
-        s.mfu = mfu
 
     def _append(self, rec: list) -> list:
         if len(self._recs) < self.capacity:
@@ -555,20 +550,59 @@ class StepLedger:
 
     def close_call(self, dispatched: str, bucket, t_entry: float,
                    t_exit: float, between: float, parts: tuple,
-                   phases: Dict[str, list]) -> None:
+                   phases: Dict[str, list], exposed: float = 0.0,
+                   launched: Optional[str] = None,
+                   ended_row: bool = False,
+                   first_dispatch: bool = False) -> None:
         """The end of one step() call: fold its period into the series of
         its kind and give its record the call's clock. The kind is the
         committed step's; `dispatched` ("decode") names a call that
         committed nothing and primed or chained a window; a call that
         did neither found nothing to run, adds to `period_other_seconds`
         and leaves no record. `parts`: the four parts of `between`, for
-        the record (split_between has summed them)."""
+        the record (split_between has summed them). `exposed`: the
+        engine's cumulative exposed seconds (PhaseTimer.exposed); what
+        they grew by since the last call closed accrued in THIS call's
+        period, and goes to `host_exposed_drain_seconds` while the loop
+        drains: from the call after one whose commit ended a row under a
+        window in flight (`ended_row`, as a call says of itself) up to
+        and with the first call that `launched` a program again (the
+        drained window's own commit is exposed in one call, the plan,
+        upload and dispatch behind it in the next); else to
+        `host_exposed_handover_seconds` where the step this call
+        `launched` (its kind; None: it launched none) is of another kind
+        than the launch before it, mixed against window: a hand-over made
+        ahead exposes nothing, one made late its plan, upload and
+        dispatch. (By the kind of the step a call COMMITS, as `handovers`
+        counts, the sum read 0.0 s over 119 hand-overs on the chip: on
+        the two-deep loop a step commits a call after its launch, when
+        nothing is exposed any more.) `first_dispatch`: the call launched
+        a program for the first time (a compile is no stall)."""
         if not self.enabled:
             return
         rec, self._open = self._open, None
         kind = rec[2] if rec is not None else dispatched
         s = self.stats
         period = between + t_exit - t_entry
+        grew = max(0.0, exposed - self._exposed_seen)
+        self._exposed_seen = exposed
+        handover = False
+        if launched:
+            now = launched if launched in ("mixed", "window") else None
+            handover = bool(now and self._launched
+                            and now != self._launched)
+            self._launched = now
+        if self._draining:
+            s.host_exposed_drain_seconds += grew
+        elif handover:
+            s.host_exposed_handover_seconds += grew
+        self._draining = ended_row or (self._draining and not launched)
+        stall = rec is not None and not first_dispatch \
+            and period > STALL_PERIOD_S
+        if stall:
+            s.period_stalls_total += 1
+            s.period_stall_seconds += period
+            s.period_stall_wait_seconds += phases.get("wait", (0, 0.0))[1]
         s.period_seconds += period
         if kind == "mixed":
             s.period_mixed_seconds += period
@@ -581,20 +615,15 @@ class StepLedger:
                 return
             rec = self._append(
                 [time.monotonic(), 0.0, kind, *(0,) * 16, self._tok_s,
-                 self.mfu, 0, 0, 0, *_NO_CALL])
-        rec[_CALL:] = (bucket, t_entry, t_exit, between, *parts, phases)
+                 0, 0, 0, *_NO_CALL])
+        rec[_CALL:] = (bucket, t_entry, t_exit, between, *parts, phases,
+                       stall)
 
     # -- derived figures (engine metrics()) -----------------------------------
 
     @property
     def tok_s(self) -> float:
         return self._tok_s
-
-    @property
-    def mfu(self) -> float:
-        if self.peak_flops > 0.0 and self.flops_per_token > 0.0:
-            return self._tok_s * self.flops_per_token / self.peak_flops
-        return 0.0
 
     def pad_fraction(self) -> float:
         """Cumulative padded-but-useless fraction of device step tokens
@@ -609,29 +638,20 @@ class StepLedger:
         """Samples `drain()` would return: the calls that committed."""
         return sum(1 for rec in self._recs if rec[_DEV_STEPS])
 
-    def calls(self, t0: float = float("-inf"), t1: float = float("inf"),
-              clear: bool = False) -> List[Dict[str, Any]]:
-        """The ring, oldest first, as JSONL-ready dicts: the whole of it,
-        or, given a stretch [t0, t1] (`time.perf_counter()`), every
-        closed call whose period overlaps it, the ones that committed
-        nothing included. A sample that no call has closed (the call in
-        progress on the engine's thread, a sample recorded outside
-        step()) has no clock and belongs to no stretch."""
+    def calls(self, clear: bool = False) -> List[Dict[str, Any]]:
+        """The ring, oldest first, as JSONL-ready dicts: every call's
+        record, the ones that committed nothing included. A sample that
+        no call has closed yet (the call in progress on the engine's
+        thread, a sample recorded outside step()) has `phases` None."""
         recs = self._recs[self._pos:] + self._recs[:self._pos]
         if clear:
             self._recs, self._pos, self._open = [], 0, None
-        whole = t0 == float("-inf") and t1 == float("inf")
         out = []
         for rec in recs:
             d = dict(zip(_KEYS, rec))
-            if not whole and (d["phases"] is None
-                              or d["t_entry"] - d["between"] > t1
-                              or d["t_exit"] < t0):
-                continue
             d["ts"] = round(d["ts"], 6)
             d["dt"] = round(d["dt"], 6)
             d["tok_s"] = round(d["tok_s"], 3)
-            d["mfu"] = round(d["mfu"], 6)
             out.append(d)
         return out
 

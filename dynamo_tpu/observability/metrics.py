@@ -16,6 +16,53 @@ from typing import Dict, List, Optional, Sequence, Tuple
 LabelKey = Tuple[str, ...]
 
 
+# The names a `jax.named_scope` of a step program may carry: the closed
+# list a device trace is read by (observability/profile.py puts every op's
+# self time down to the innermost of these on its `op_name`;
+# docs/OBSERVABILITY.md section 5 says what each covers). A leaf's FAMILY
+# is what stands before its first dot, after the last slash
+# (`attention.mla.absorb` -> `attention`, `block.parallel/ssm.conv` ->
+# `ssm`). `step` and `layers.body` enclose a whole program and a whole
+# layer body: what rests on them is the glue no narrower scope names
+# (index plans, residual adds, reshapes). tests/test_step_tracing.py walks
+# the jaxpr of every served program and fails on a scope outside this
+# list and, inside a layer body, on an equation in none.
+SCOPES = (
+    # a program outside its layers
+    "step", "step.unpack", "step.compact", "embed", "head", "sampler",
+    "kv.write", "kv.window",
+    # a layer body, whatever its kind
+    "layers.body", "layers.lead", "layers.stack",
+    "norm.attn", "norm.mlp", "norm.post",
+    # softmax attention, latent attention among it
+    "attention", "attention.qkv", "attention.rope", "attention.qk_norm",
+    "attention.head_qk_norm", "attention.gather", "attention.window",
+    "attention.full", "attention.out_gate", "attention.wo",
+    "attention.mla.q", "attention.mla.latent", "attention.mla.absorb",
+    "attention.mla.out", "attention.mla.gate",
+    # the MLP, dense or experts
+    "mlp", "mlp.dense_lead", "moe", "moe.route", "moe.route.groups",
+    "moe.dispatch", "moe.experts", "moe.combine", "moe.shared",
+    # linear attention (Kimi Delta) over state slots
+    "linattn.in_proj", "linattn.gate", "linattn.conv", "linattn.chunk",
+    "linattn.step", "linattn.out", "linattn.wo",
+    # a parallel block: attention beside a state-space mixer
+    "block.parallel/attention", "block.parallel/ssm.in_proj",
+    "block.parallel/ssm.conv", "block.parallel/ssm.step",
+    "block.parallel/ssm.chunk", "block.parallel/ssm.norm_out",
+    "block.parallel/ssm.out_proj",
+    # a gated short convolution
+    "shortconv.in_proj", "shortconv.gate", "shortconv.taps",
+    "shortconv.out_proj",
+)
+
+
+def scope_family(leaf: str) -> str:
+    """`attention.mla.absorb` -> `attention`; `block.parallel/ssm.conv`
+    -> `ssm`."""
+    return leaf.rsplit("/", 1)[-1].split(".", 1)[0]
+
+
 class PhaseTimer:
     """Cumulative wall-time attribution across named phases.
 
@@ -102,10 +149,16 @@ class PhaseTimer:
         return call
 
     @contextlib.contextmanager
-    def phase(self, name: str, annotation: Optional[str] = None):
+    def phase(self, name: str, annotation: Optional[str] = None,
+              stats: Optional[dict] = None):
         """Time one phase. `annotation` renames the span a trace shows
         (the engine opens a program's first dispatch as `compile`); the
-        seconds still accumulate under `name`."""
+        seconds still accumulate under `name`. `stats` ride the
+        annotation as TraceMe stats, beside its name and not in it (the
+        engine gives a dispatch the identity of what it launched: a
+        capture then joins the k-th dispatch to the k-th program the
+        device ran, observability/profile.py); outside a capture they
+        cost the dict."""
         cls = PhaseTimer._annotation
         if cls is None:
             from jax.profiler import TraceAnnotation as cls
@@ -113,7 +166,8 @@ class PhaseTimer:
         label = annotation or name
         t0 = time.perf_counter()
         try:
-            with cls(f"{self.trace_scope or 'phase'}.{label}"):
+            with cls(f"{self.trace_scope or 'phase'}.{label}",
+                     **(stats or {})):
                 yield
         finally:
             dt = time.perf_counter() - t0

@@ -69,6 +69,7 @@ def _softcap(scores: jax.Array, cap: float) -> jax.Array:
     return jnp.tanh(scores / cap) * cap
 
 
+@jax.named_scope("attention.gather")
 def gather_pages(cache: jax.Array, page_table: jax.Array,
                  layer: Optional[jax.Array] = None) -> jax.Array:
     """[Hkv, P, ps, hd] gathered by [B, Pb] -> [Hkv, B, Pb*ps, hd]; a
@@ -100,6 +101,7 @@ def gather_pages(cache: jax.Array, page_table: jax.Array,
     return gathered.reshape((hkv, b, pb * ps) + gathered.shape[3:])
 
 
+@jax.named_scope("attention.gather")
 def gather_values(cache: jax.Array, scale: Optional[jax.Array],
                   page_table: jax.Array, dtype,
                   layer: Optional[jax.Array] = None) -> jax.Array:
@@ -113,6 +115,7 @@ def gather_values(cache: jax.Array, scale: Optional[jax.Array],
                            dtype)
 
 
+@jax.named_scope("attention.gather")
 def gather_kv(k_cache: jax.Array, v_cache: Optional[jax.Array],
               page_table: jax.Array, dtype,
               k_scale: Optional[jax.Array] = None,
@@ -461,6 +464,7 @@ def decode_attention_deferred(
 KV_WRITE_BLOCK = 32
 
 
+@jax.named_scope("kv.write")
 def stored_kv_rows(k_new: jax.Array, v_new: Optional[jax.Array],
                    quant: bool) -> tuple:
     """New K/V rows [..., hd] as the pool stores them, a tuple in
@@ -537,6 +541,7 @@ class CompactIndex(NamedTuple):
     plan: KvWritePlan   # the KV writes of the token rows, flat ones first
 
 
+@jax.named_scope("step.compact")
 def compact_index(plan: KvWritePlan, width: int) -> CompactIndex:
     """The compaction index of a step whose grid is larger than `width`
     (compact_step), from the grid's own write plan: its `order` already
@@ -556,6 +561,7 @@ def compact_index(plan: KvWritePlan, width: int) -> CompactIndex:
     return CompactIndex(cells, live, slot, flat)
 
 
+@jax.named_scope("kv.write")
 def write_kv_rows(
     pools: tuple,        # stacked leaves [L, Hkv, P, ps, hd] / scales [L, Hkv, P, ps]
     rows: tuple,         # a leaf each: [Lw, N, Hkv, hd] / [Lw, N, Hkv]

@@ -172,7 +172,7 @@ def ssd_step_slots(ssm_s, layer, slots, x, dt, a, b, c, d, fresh=None,
                    jax.ShapeDtypeStruct(ssm_s.shape, ssm_s.dtype)],
         # operands count the three prefetched scalars: the leaf is the 6th
         input_output_aliases={5: 1},
-        # the op's name in a device trace (device.fh1_ssm_kernel_share)
+        # the op's name in a device trace (its share of the busy time reads it)
         name="ssd_step_slots",
         interpret=impl == "interpret",
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), at,

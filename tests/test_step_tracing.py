@@ -11,11 +11,14 @@
 - `engine.queue` / `engine.prefill` split a request's first-token time
   under its `worker.generate` span, one histogram observation each;
 - every series, field and module a listed layer metric reads exists;
-- the worker's bounded `capture_profile` and its `/debug/profile` route;
+- the worker's bounded `capture_profile`, the table its child process
+  leaves, and its `/debug/profile` route;
 - one record a `step()` call (ISSUE 35): periods by kind tile the busy
   wall time, the worker's marks partition `between`, every gap between
-  a stream's commits goes whole to one class, and `steps.jsonl` puts the
-  records on a capture's own clock.
+  a stream's commits goes whole to one class;
+- a dispatch says what it launched as the annotation's stats, beside its
+  name (ISSUE 52), the two exposures partition `host_exposed_seconds`
+  with the steady loop's rest, and a stalled period is counted.
 """
 import asyncio
 import contextlib
@@ -206,10 +209,10 @@ def test_upload_opens_once_a_step_and_encloses_the_staging():
     real_phase, real_stage = eng.phases.phase, eng._stage_operands
 
     @contextlib.contextmanager
-    def phase(name, annotation=None):
+    def phase(name, annotation=None, stats=None):
         open_now.append(name)
         try:
-            with real_phase(name, annotation):
+            with real_phase(name, annotation, stats):
                 yield
         finally:
             open_now.pop()
@@ -459,10 +462,11 @@ def test_an_aborted_stream_leaves_no_last_commit():
     assert not eng.ledger._last_commit
 
 
-def test_a_sample_no_call_has_closed_belongs_to_no_stretch():
-    """A capture's `steps.jsonl` asks for a stretch from another thread
-    than the engine's: the sample of the call in progress there has no
-    clock yet and is left out; the whole ring and `drain()` list it."""
+def test_a_sample_no_call_has_closed_has_no_clock_yet():
+    """The sample of the call in progress has no clock until the call is
+    closed: the ring and `drain()` list it with `phases` None. (The
+    stretch form of `calls()`, which chose a capture's records for
+    `steps.jsonl`, went with that file in PR 52.)"""
     from dynamo_tpu.observability.ledger import StepLedger
     led = StepLedger(capacity=8, enabled=True, stats=LedgerStats())
     sample = ("mixed", 4, 3, 5, 64) + (0,) * 8
@@ -472,9 +476,107 @@ def test_a_sample_no_call_has_closed_belongs_to_no_stretch():
     led.record_step(*sample)
     assert [r["phases"] is None for r in led.calls()] == [False, True]
     assert len(led.drain(clear=False)) == len(led) == 2
-    assert [r["t_exit"] for r in led.calls(9.0, 11.0)] == [10.5]
-    assert [r["t_exit"] for r in led.calls(0.0, 9.8)] == [10.5]    # between
-    assert led.calls(0.0, 9.7) == led.calls(10.6, 11.0) == []
+    assert [r["t_exit"] for r in led.calls()] == [10.5, 0.0]
+
+
+# -- the two exposures and the stalls (ISSUE 52) --------------------------------
+
+@pytest.mark.parametrize("run", ["sync", "pipelined", "alternating",
+                                 "mixed-only", "window-only"])
+def test_the_two_exposures_never_exceed_the_exposed_sum(recorded, run):
+    """What accrued in a drain's period and in a hand-over's are parts of
+    `host_exposed_seconds`: neither is negative, together they do not
+    pass it, and with the steady loop's rest (what is left, no third
+    counter) they sum to it."""
+    stats = recorded[run][0]
+    drain, handover = (stats.host_exposed_drain_seconds,
+                       stats.host_exposed_handover_seconds)
+    assert drain >= 0.0 and handover >= 0.0
+    rest = stats.host_exposed_seconds - drain - handover
+    assert rest >= -1e-9
+    assert drain + handover + rest == pytest.approx(
+        stats.host_exposed_seconds)
+
+
+def test_a_handover_charges_its_own_period_alone(recorded):
+    """A synchronous loop that alternates prefill steps and windows hands
+    over at every change of kind mixed <-> window; one that only runs
+    windows never does, and charges nothing."""
+    assert recorded["window-only"][0].host_exposed_handover_seconds == 0.0
+    assert recorded["sync"][0].host_exposed_handover_seconds > 0.0
+
+
+def _closed(ledger, phases, period, **kw):
+    """Close one made-up call of `period` seconds that committed a step."""
+    ledger.record_step("decode", 4, 4, 4, 4, 0, 1, 0, 0, 0, 0, 0, 0)
+    ledger.close_call("decode", 8, 10.0, 10.0 + period, 0.0,
+                      (0.0,) * 4, phases, **kw)
+
+
+def test_a_made_up_long_wait_counts_one_stall():
+    from dynamo_tpu.observability.ledger import STALL_PERIOD_S, StepLedger
+    assert STALL_PERIOD_S == 0.5
+    stats = LedgerStats()
+    ledger = StepLedger(stats=stats, enabled=True)
+    _closed(ledger, {"wait": [10.0, 0.8], "commit": [10.8, 0.01]}, 0.81)
+    assert stats.period_stalls_total == 1
+    assert stats.period_stall_seconds == pytest.approx(0.81)
+    assert stats.period_stall_wait_seconds == pytest.approx(0.8)
+    assert ledger.calls()[-1]["stall"] is True
+    # a sound period is none
+    _closed(ledger, {"wait": [11.0, 0.2]}, 0.21)
+    assert stats.period_stalls_total == 1
+    assert ledger.calls()[-1]["stall"] is False
+
+
+def test_a_first_dispatch_is_no_stall():
+    from dynamo_tpu.observability.ledger import StepLedger
+    stats = LedgerStats()
+    ledger = StepLedger(stats=stats, enabled=True)
+    _closed(ledger, {"dispatch": [10.0, 2.5], "wait": [12.5, 0.1]}, 2.6,
+            first_dispatch=True)
+    assert stats.period_stalls_total == 0
+    assert stats.period_stall_seconds == 0
+    # and a call that committed nothing has no period to stall
+    ledger.close_call("decode", 8, 20.0, 21.0, 0.0, (0.0,) * 4,
+                      {"dispatch": [20.0, 1.0]})
+    assert stats.period_stalls_total == 0
+
+
+def test_the_exposures_go_to_the_call_they_accrued_in():
+    """`exposed` is the engine's cumulative sum: what it grew by since the
+    last close goes to the drain from the call AFTER one that ended a row
+    up to and with the first that launches a program again, else to the
+    hand-over where the call launches a step of another kind than the
+    launch before it (mixed against window), else nowhere."""
+    from dynamo_tpu.observability.ledger import StepLedger
+    stats = LedgerStats()
+    ledger = StepLedger(stats=stats, enabled=True)
+    _closed(ledger, {}, 0.1, exposed=1.0, launched="mixed")      # steady
+    _closed(ledger, {}, 0.1, exposed=1.5, launched="window")     # handed over
+    _closed(ledger, {}, 0.1, exposed=1.75, launched="window",
+            ended_row=True)                                      # steady
+    # the drained window's commit, nothing launched behind it ...
+    _closed(ledger, {}, 0.1, exposed=2.75)
+    # ... and the plan, upload and dispatch of what comes next: a mixed
+    # step, but the period is the drain's
+    _closed(ledger, {}, 0.1, exposed=2.875, launched="mixed")
+    _closed(ledger, {}, 0.1, exposed=3.0, launched="mixed")      # steady
+    # a prefill between a window and a mixed step is no hand-over
+    _closed(ledger, {}, 0.1, exposed=3.25, launched="prefill")
+    _closed(ledger, {}, 0.1, exposed=3.5, launched="window")
+    assert stats.host_exposed_handover_seconds == pytest.approx(0.5)
+    assert stats.host_exposed_drain_seconds == pytest.approx(1.125)
+
+
+def test_a_served_engine_marks_its_calls(recorded):
+    """The engine hands close_call its own marks: a compile (every
+    program of these short runs is first dispatched inside them) is no
+    stall, however long, and every record says so."""
+    for run in ("sync", "pipelined"):
+        stats, _, calls, _ = recorded[run]
+        assert stats.period_stalls_total == 0
+        assert all(c["stall"] is False for c in calls)
 
 
 def _serve_two(worker):
@@ -752,13 +854,41 @@ def test_an_aborted_request_leaves_no_mark():
 
 # -- (f) what the listed layer metrics read exists -----------------------------
 
-def _listed_metrics():
+def _kept_metrics():
+    """PR 48's `per_layer` (benchmark/tests/fixtures/per_layer_pr48.json:
+    128 entries, each with its file's `expr`): the KEPT list the guard
+    below is parametrised over, so that a fold of today's entries takes
+    no case away (ROADMAP M12 (i))."""
+    with open(os.path.join(REPO, "benchmark", "tests", "fixtures",
+                           "per_layer_pr48.json")) as f:
+        return json.load(f)["per_layer"]
+
+
+def _todays_metrics():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        return [m["name"] for m in json.load(f)["per_layer"]]
+        return {m["name"]: readers.load_metric(
+            m["name"], os.path.join(REPO, "benchmark"))["expr"]
+            for m in json.load(f)["per_layer"]}
+
+
+# what `trace_window_step_median_s` took the place of (PR 49), as
+# benchmark/tests/test_benchmark_lists.py::with_the_leaf has it
+_OLD_STEP = {"op": "div", "args": [
+    {"trace_module_median_s": "engine_decode_window_full"},
+    {"run": "decode_steps"}]}
+
+
+def _with_the_leaf(expr):
+    if expr == _OLD_STEP:
+        return {"trace_window_step_median_s": "engine_decode_window"}
+    if "args" in expr:
+        return {**expr, "args": [_with_the_leaf(a) for a in expr["args"]]}
+    return expr
 
 
 def _leaves(expr, out):
-    for key in ("prom", "prom_at_start", "engine", "trace_module_median_s"):
+    for key in ("prom", "prom_at_start", "engine", "trace_module_median_s",
+                "trace_window_step_median_s", "trace_window_rung_steps"):
         if key in expr:
             out.append((key, expr[key]))
     if "prom_hist_mean" in expr:
@@ -807,21 +937,97 @@ def served_sources():
     return asyncio.run(main())
 
 
-@pytest.mark.parametrize("metric", _listed_metrics())
-def test_listed_layer_metric_reads_something_that_exists(
-        served_sources, metric):
-    """The guard that a rename cannot silently turn a metric into None."""
+def _reads_something(metric, expr, served_sources):
     prom, fields, modules = served_sources
-    spec = readers.load_metric(metric, os.path.join(REPO, "benchmark"))
-    for kind, what in _leaves(spec["expr"], []):
+    for kind, what in _leaves(expr, []):
         if kind in ("prom", "prom_at_start"):
             assert what in prom, f"{metric}: no series {what} on /metrics"
         elif kind == "engine":
             assert what in fields, f"{metric}: no EngineMetrics.{what}"
         else:
-            pat = re.compile(what)
+            # a window leaf names the ladder's base: `<base>_full` or a
+            # `<base>_w<n>` rung is what it reads
+            pat = re.compile(what + ("" if kind.endswith("median_s")
+                                     and "window" not in kind
+                                     else r"_(full|w\d+)$"
+                                     if "window" in kind else ""))
             assert any(pat.search(m) for m in modules), \
                 f"{metric}: no program matches {what!r}"
+
+
+@pytest.mark.parametrize("former", _kept_metrics(),
+                         ids=lambda m: m["name"])
+def test_listed_layer_metric_reads_something_that_exists(
+        served_sources, former):
+    """The guard that a rename cannot silently turn a metric into None,
+    over the KEPT list: each of PR 48's 128 names is looked up through
+    today's entry of the same expression (whatever it is called and
+    whichever cells it lists), and what that expression reads exists."""
+    want = json.dumps(_with_the_leaf(former["expr"]), sort_keys=True)
+    found = [name for name, expr in _todays_metrics().items()
+             if json.dumps(expr, sort_keys=True) == want]
+    assert found, f"{former['name']}: no entry of today reads its expression"
+    _reads_something(found[0], json.loads(want), served_sources)
+
+
+def test_every_entry_of_today_reads_something_that_exists(served_sources):
+    """The same guard over what later PRs listed (a rung's steps, a conv
+    layer's state): one case, so that the count above is the kept list's."""
+    for name, expr in _todays_metrics().items():
+        _reads_something(name, expr, served_sources)
+
+
+# the expressions that wait for the `benchmark` PR with room in `per_layer`
+# (ISSUE 52: 125 of 128 are taken): it lifts each into
+# `benchmark/layer_metrics/<name>.json` unchanged, layer "engine host
+# loop", keyless (every engine exports the counters, so every cell's)
+WAITING_METRICS = {
+    # ms of exposed host time a hand-over (a committed step of another
+    # kind than the one before it); moves output_tok_s
+    "host.exposed_handover_ms": {"op": "mul", "args": [{"const": 1000}, {
+        "op": "div", "args": [
+            {"prom": "llm_engine_host_exposed_handover_seconds"},
+            {"op": "add", "args": [{"engine": "handovers"},
+                                   {"const": 1e-9}]}]}]},
+    # ms of exposed host time a drain (the call after a commit that
+    # ended a row under a window in flight): S17 (c) by itself; moves
+    # output_tok_s
+    "host.exposed_drain_ms": {"op": "mul", "args": [{"const": 1000}, {
+        "op": "div", "args": [
+            {"prom": "llm_engine_host_exposed_drain_seconds"},
+            {"op": "add", "args": [{"engine": "pipeline_fallbacks"},
+                                   {"const": 1e-9}]}]}]},
+    # % of the busy wall time inside stalled periods; moves itl_p95_ms
+    "step.stall_share": {"op": "mul", "args": [{"const": 100}, {
+        "op": "div", "args": [
+            {"prom": "llm_engine_period_stall_seconds"},
+            {"prom": "llm_engine_period_seconds"}]}]},
+}
+
+
+@pytest.mark.parametrize("name", sorted(WAITING_METRICS))
+def test_a_waiting_metric_evaluates_on_what_is_served(served_sources, name):
+    """Each waiting expression through the benchmark's own evaluator: its
+    leaves exist on the tiny service, and over a window in which the
+    counters moved it is the number its comment says."""
+    prom, fields, _ = served_sources
+    expr = WAITING_METRICS[name]
+    _reads_something(name, expr, served_sources)
+    before = {**{k: 0.0 for k in prom}, **{k: 0 for k in fields}}
+    after = dict(before)
+    after.update(llm_engine_host_exposed_handover_seconds=0.06,
+                 handovers=20, llm_engine_host_exposed_drain_seconds=0.15,
+                 pipeline_fallbacks=10, llm_engine_period_stall_seconds=2.0,
+                 llm_engine_period_seconds=50.0)
+    ctx = {"prom": (before, after), "engine": (before, after)}
+    assert readers.evaluate(expr, ctx) == pytest.approx({
+        "host.exposed_handover_ms": 3.0, "host.exposed_drain_ms": 15.0,
+        "step.stall_share": 4.0}[name])
+    # and on the service's own scrape against itself: no division by
+    # zero where no hand-over or drain fell in the window
+    same = {"prom": (prom, prom), "engine": (fields, fields)}
+    got = readers.evaluate(expr, same)
+    assert got is None or got == 0.0
 
 
 def test_full_window_pattern_matches_one_rung_only(served_sources):
@@ -836,6 +1042,7 @@ def test_full_window_pattern_matches_one_rung_only(served_sources):
 
 def test_capture_profile_is_bounded_and_refuses_a_second(tmp_path):
     from dynamo_tpu.llm.worker import NativeEngineWorker
+    from dynamo_tpu.observability import profile
 
     async def main():
         worker = await NativeEngineWorker(make_engine()).start()
@@ -845,24 +1052,39 @@ def test_capture_profile_is_bounded_and_refuses_a_second(tmp_path):
             await asyncio.sleep(0.05)
             with pytest.raises(RuntimeError, match="already running"):
                 await worker.capture_profile(0.1, str(tmp_path / "two"))
-            assert await first == str(tmp_path / "one")
+            got = await first
             # and again, once the first has stopped
             await worker.capture_profile(0.1, str(tmp_path / "two"))
+            return got
         finally:
             await worker.stop()
-    asyncio.run(main())
+    got = asyncio.run(main())
+    assert got["trace_dir"] == str(tmp_path / "one")
     for d in ("one", "two"):
-        assert trace_reduce.find_xplane(str(tmp_path / d))
+        xplane = trace_reduce.find_xplane(str(tmp_path / d))
+        assert os.path.isfile(os.path.join(os.path.dirname(xplane),
+                                           profile.SUMMARY))
+    assert got["summary"] == os.path.join(
+        os.path.dirname(trace_reduce.find_xplane(got["trace_dir"])),
+        profile.SUMMARY)
 
 
 @pytest.fixture(scope="module")
-def captured_steps(tmp_path_factory):
-    """A `capture_profile` of a worker serving two requests: the planes
-    of its xplane and the lines of the `steps.jsonl` beside it."""
+def captured_serving(tmp_path_factory):
+    """A `capture_profile` of a worker serving two requests: what it
+    answered, the planes of its xplane, and the processes the worker
+    started meanwhile."""
     from dynamo_tpu.llm.worker import NativeEngineWorker
+    from dynamo_tpu.observability import profile
     eng = make_engine(pipeline_depth=2)
     eng.generate(list(range(10, 40)), sampled(12), "warm")
     out = str(tmp_path_factory.mktemp("steps"))
+    children = []
+    spawn = asyncio.create_subprocess_exec
+
+    async def spy(*argv, **kw):
+        children.append((argv, kw.get("env", {})))
+        return await spawn(*argv, **kw)
 
     async def main():
         worker = await NativeEngineWorker(eng).start()
@@ -876,58 +1098,88 @@ def captured_steps(tmp_path_factory):
                           Context(f"{tag}1"), max_tokens=20),
                 _generate(worker, f"{tag}2", list(range(shift, shift + 60)),
                           Context(f"{tag}2"), max_tokens=12))
+        asyncio.create_subprocess_exec = spy
         try:
             await serve("w", 70)
             capture = asyncio.create_task(worker.capture_profile(1.0, out))
             await asyncio.sleep(0.1)
             await serve("c", 130)
-            await capture
+            return await capture
         finally:
+            asyncio.create_subprocess_exec = spawn
             await worker.stop()
-    asyncio.run(main())
-    xplane = trace_reduce.find_xplane(out)
-    with open(os.path.join(os.path.dirname(xplane), "steps.jsonl")) as f:
-        lines = [json.loads(line) for line in f]
-    return trace_reduce.load_planes(xplane), lines
+    got = asyncio.run(main())
+    xplane = profile.find_xplane(out)
+    return got, profile.load_planes(xplane), children, eng
 
 
-def test_steps_jsonl_encloses_its_engine_host_events(captured_steps):
-    """On the trace's own clock every record's interval encloses that
-    call's `engine.*` host events within 100 us, no two records
-    overlap, and a phase a record names starts where its event does."""
-    planes, (head, *recs) = captured_steps
-    slack = 100_000
-    anchor = head["anchor"]
-    assert set(anchor) == {"perf_counter_ns", "trace_ns"}
-    lo, hi = head["capture_ns"]
-    assert 0 <= lo < hi == anchor["trace_ns"]
-    assert len(recs) >= 4
-    assert {"mixed", "decode"} <= {r["kind"] for r in recs}
-    for prev, rec in zip(recs, recs[1:]):
-        assert prev["t_entry_ns"] < prev["t_exit_ns"] <= rec["t_entry_ns"]
-    events = [e for _, evs in _engine_events(planes) for e in evs]
-    assert len(events) >= 4 * len(recs) - 8
-    off = []      # a phase's recorded start less its event's
-    for start, end, name in events:
-        home = [r for r in recs if r["t_entry_ns"] - slack <= start
-                and end <= r["t_exit_ns"] + slack]
-        if not home:
-            # only a call the capture cut at either end may have none
-            assert end <= recs[0]["t_entry_ns"] + slack \
-                or start >= recs[-1]["t_exit_ns"] - slack, name
-            continue
-        assert len(home) == 1, (name, start, home)
-        phase = name.split(".", 1)[1]
-        phase = "dispatch" if phase == "compile" else phase
-        # the phase is this call's own (one event a phase and call); its
-        # clock is read just outside the annotation, so the two starts
-        # agree but for a preemption between the two reads
-        off.append(abs(home[0]["phases"][phase]["start_ns"] - start))
-    assert len(off) >= 4 * (len(recs) - 2)
-    assert sorted(off)[len(off) // 2] <= slack
-    # every phase of a call inside the capture has its event
-    assert len(off) >= sum(len(r["phases"]) - ("between" in r["phases"])
-                           for r in recs[1:-1])
+def test_a_dispatch_says_what_it_launched_beside_its_name(captured_serving):
+    """The annotation's NAME stays `engine.dispatch` (the benchmark's
+    reducer labels a gap by it); what it launched rides as stats: kind,
+    bucket, a running `seq`, and `ahead` where a program was in flight."""
+    _, planes, _, _ = captured_serving
+    events = [ev for pname, lines in planes if pname.startswith("/host:")
+              for _, evs in lines for ev in evs
+              if ev[2].startswith("engine.")]
+    names = {ev[2] for ev in events}
+    assert names <= {f"engine.{p}" for p in PHASES} | {"engine.compile"}
+    launches = sorted((ev for ev in events if ev[2] == "engine.dispatch"),
+                      key=lambda ev: ev[0])
+    assert len(launches) >= 4
+    for ev in launches:
+        stats = ev[3]
+        assert stats["kind"] in ("mixed", "prefill", "window")
+        assert set(stats) == {"kind", "seq", "ahead", "rows"} | (
+            {"rung"} if stats["kind"] == "window" else {"chunk"})
+    seqs = [ev[3]["seq"] for ev in launches]
+    assert seqs == list(range(seqs[0], seqs[0] + len(seqs)))
+    assert {"mixed", "window"} <= {ev[3]["kind"] for ev in launches}
+    assert 1 in {ev[3]["ahead"] for ev in launches}     # two deep
+    # no other phase carries stats (a key's first dispatch, named
+    # `engine.compile`, is a dispatch too)
+    assert all(not ev[3] for ev in events
+               if ev[2] not in ("engine.dispatch", "engine.compile"))
+
+
+def test_the_summary_is_written_by_a_child(captured_serving):
+    """The reduction runs `python -m dynamo_tpu.observability.profile` in
+    a child that is held off the chip; the answer is the file's path and
+    top level; beside the xplane lie the programs launched meanwhile."""
+    from dynamo_tpu.observability import profile
+    got, _, children, eng = captured_serving
+    (argv, env), = children
+    assert argv[1:3] == ("-m", "dynamo_tpu.observability.profile")
+    assert argv[3] == got["trace_dir"] and env["JAX_PLATFORMS"] == "cpu"
+    home = os.path.dirname(got["summary"])
+    assert os.path.basename(got["summary"]) == profile.SUMMARY
+    with open(got["summary"]) as f:
+        summary = json.load(f)
+    # a CPU capture has no device plane: the table says so by what it lacks
+    assert summary["device"]["chips"] == 0
+    assert got["device"] == summary["device"]
+    assert summary["source"]["reduce_s"] > 0
+    texts = sorted(os.listdir(os.path.join(home, "programs")))
+    assert texts and all(t.endswith(".hlo.txt") for t in texts)
+    assert any(t.startswith("engine_step-") for t in texts)
+    assert any(t.startswith("engine_decode_window_") for t in texts)
+    assert len(texts) <= len(eng._programs)
+    assert not os.path.exists(os.path.join(home, "steps.jsonl"))
+
+
+def test_program_texts_compiles_nothing():
+    """`program_texts` lowers a dispatched program again from its first
+    dispatch's shapes: jax hands back the executable it holds."""
+    eng = make_engine()
+    eng.generate(list(range(10, 40)), sampled(12), "one")
+    eng.generate(list(range(10, 40)), sampled(12), "two")
+    before = LEDGER_STATS.jax_compiles
+    texts = eng.program_texts()
+    assert LEDGER_STATS.jax_compiles == before
+    assert len(texts) == len(eng._programs) >= 2
+    for text in texts.values():
+        assert 'op_name="jit(engine_' in text
+    # a capture that began after the last launch holds no program
+    assert eng.program_texts(since=eng._dispatch_seq) == {}
 
 
 @pytest.mark.parametrize("env,query,status", [
@@ -961,9 +1213,14 @@ def test_debug_profile_route(tmp_path, monkeypatch, env, query, status):
     got, body = asyncio.run(main())
     assert got == status
     if status == 200:
-        out = json.loads(body)["trace_dir"]
+        answer = json.loads(body)
+        out = answer["trace_dir"]
         assert out.startswith(str(tmp_path))
         assert trace_reduce.find_xplane(out)
+        # the summary's path and its top level
+        assert os.path.isfile(answer["summary"])
+        assert answer["summary"].startswith(out)
+        assert {"device", "programs", "idle_gaps", "seconds", "cost_s"} <= set(answer)
 
 
 def test_no_whole_life_profile_hook_is_left():

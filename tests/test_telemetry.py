@@ -15,7 +15,7 @@ import math
 import pytest
 
 from dynamo_tpu.observability.ledger import (
-    LedgerStats, StepLedger, model_flops_per_token,
+    LedgerStats, StepLedger,
 )
 from dynamo_tpu.observability.metrics import Histogram
 from dynamo_tpu.observability.slo import (
@@ -234,19 +234,6 @@ def test_ledger_per_kind_padding_attribution_and_pad_fraction():
     assert s["recompiles"] == 2
 
 
-def test_ledger_mfu_needs_peak_and_flops():
-    from dynamo_tpu.engine.config import ModelConfig
-    cfg = ModelConfig()
-    fpt = model_flops_per_token(cfg)
-    assert fpt > 0
-    led = StepLedger(capacity=8, enabled=True, stats=LedgerStats(),
-                     flops_per_token=fpt)
-    assert led.mfu == 0.0               # no peak configured
-    led.configure(peak_tflops=1.0)
-    led._tok_s = 1000.0
-    assert led.mfu == pytest.approx(1000.0 * fpt / 1e12)
-
-
 def test_ledger_jsonl_write_policy(tmp_path):
     led = StepLedger(capacity=8, enabled=True, stats=LedgerStats())
     _sample(led)
@@ -258,7 +245,8 @@ def test_ledger_jsonl_write_policy(tmp_path):
     assert rows[0]["kind"] == "decode"
     assert set(rows[0]) >= {"ts", "dt", "kind", "tokens_useful",
                             "tokens_padded", "kv_used", "recompiles",
-                            "tok_s", "mfu"}
+                            "tok_s", "stall"}
+    assert "mfu" not in rows[0]
 
 
 # -- SLO watchdog --------------------------------------------------------------

@@ -33,47 +33,18 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
-            "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
-            "u64": 8}
+# the one parser of optimised HLO lines in the tree lives with the
+# capture's reducer, which reads the same text for its scopes
+from dynamo_tpu.observability.profile import (  # noqa: E402
+    _INSTR, _OPNAME, _SHAPE, shape_bytes, split_computations,
+)
+
 # an output of these is a name for bytes that are already there
 _NO_MOVE = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
             "conditional", "call", "opt-barrier", "constant"}
-_SHAPE = re.compile(r"\b(pred|s8|u8|bf16|f16|s16|u16|f32|s32|u32|f64|s64|u64)"
-                    r"\[([0-9,]*)\](\{[^}]*\})?")
-_INSTR = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*?)\s([\w\-]+)\(")
 _SOURCE = re.compile(r'source_file="([^"]*)"(?:\s+source_line=(\d+))?')
 _FRAME = re.compile(r"stack_frame_id=(\d+)")
-_OPNAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"calls=%?([\w.\-]+)")
-
-
-def shape_bytes(text: str) -> int:
-    """Largest array in an HLO result type (a tuple's largest element)."""
-    best = 0
-    for dt, dims, _ in _SHAPE.findall(text):
-        n = ITEMSIZE[dt]
-        for d in filter(None, dims.split(",")):
-            n *= int(d)
-        best = max(best, n)
-    return best
-
-
-def split_computations(hlo: str) -> Dict[str, List[str]]:
-    """HLO module text -> {computation name: its instruction lines}."""
-    comps: Dict[str, List[str]] = {}
-    cur: Optional[str] = None
-    for line in hlo.splitlines():
-        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$",
-                        line)
-        if head:
-            cur = head.group(1)
-            comps[cur] = []
-        elif line.startswith("}"):
-            cur = None
-        elif cur is not None and "=" in line:
-            comps[cur].append(line)
-    return comps
 
 
 def stack_frames(hlo: str) -> Dict[int, str]:
