@@ -236,6 +236,13 @@ class ModelConfig:
     # 128-lane row (`kv_cache_leaves` hands out the stored shape,
     # models/llama.layer_front forms the rows).
     kv_row_heads: int = 1
+    # Lanes one row of the paged pool is STORED in: DERIVED like
+    # `kv_row_heads`, by `kv_row_lanes` below, and resolved with it. 0 =
+    # the model's own width. `kv_cache_leaves` reads it for a latent
+    # cache, whose kv_lora_rank + qk_rope_head_dim values (576) are
+    # stored in the next multiple of 128 lanes (640), the rest zeros
+    # (models/llama._mla_front); every other row is `kv_row_heads` heads.
+    kv_row_lanes: int = 0
     # Multimodal (Qwen2-VL-style); None means text-only.
     vision: Optional["VisionConfig"] = None
 
@@ -397,11 +404,27 @@ class ModelConfig:
         j of them in lanes j head_dim ..). Under latent attention ONE,
         named "k" because it is what the absorbed queries are scored
         against: a single head of kv_lora_rank + qk_rope_head_dim whose
-        first kv_lora_rank columns are also the values. init_cache, the
-        shardings, the page-byte gauges and `refuse_unserved` read this."""
+        first kv_lora_rank columns are also the values, stored
+        `kv_row_lanes` wide where an engine resolved that (the lanes past
+        `latent_width` are zeros). init_cache, the shardings, the
+        page-byte gauges and `step_attention_rows` read this."""
         if self.is_mla:
-            return {"k": (1, self.kv_lora_rank + self.qk_rope_head_dim)}
+            return {"k": (1, self.latent_width + self.kv_row_pad)}
         return {"k": self._kv_row, "v": self._kv_row}
+
+    @property
+    def latent_width(self) -> int:
+        """Values a token and layer holds under latent attention: the
+        latent and the one rotated key part every head shares."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def kv_row_pad(self) -> int:
+        """Zero lanes of a stored pool row past the model's values: a
+        latent row held in whole lane tiles (`kv_row_lanes`), 0 elsewhere.
+        Pages leave the pool without them (engine._logical_pages)."""
+        return max(self.kv_row_lanes - self.latent_width, 0) \
+            if self.is_mla else 0
 
     def window_cache_leaves(self) -> dict:
         """The window layers' leaves, beside `kv_cache_leaves` and by the
@@ -418,10 +441,14 @@ class ModelConfig:
         return self.num_kv_heads // f, f * self.head_dim
 
     def kv_bytes_per_token(self) -> int:
-        """Bytes one token holds in the unquantized FULL pool, all the
-        layers that have pages there."""
-        return self._token_bytes(self.num_cache_layers,
-                                 self.kv_cache_leaves())
+        """Bytes one token's K and V are, unquantized, over all the layers
+        that have pages in the FULL pool: the MODEL's figure, what a step
+        must read a token of context. The pool's own is the stored row
+        (`kv_cache_leaves`; `EngineMetrics.kv_page_bytes`), which is wider
+        where a latent row is padded to whole lane tiles."""
+        leaves = {"k": (1, self.latent_width)} if self.is_mla \
+            else self.kv_cache_leaves()
+        return self._token_bytes(self.num_cache_layers, leaves)
 
     def window_kv_bytes_per_token(self) -> int:
         """Bytes one token holds in the window pool, all window layers."""
@@ -453,6 +480,20 @@ class ModelConfig:
 LANES = 128
 
 
+def _rows_as_published(cfg: ModelConfig) -> bool:
+    """Whether the pool keeps the model's own rows, a head of its own
+    width each, because another row would not be an exact view of the
+    same result: an int8 pool (a row's scale is the ROW's: two heads to a
+    row would share one, and the page movers re-view value leaves, not
+    scales), or the Pallas decode kernel asked for (it takes its scale
+    from the page's width and packs tiles its own way,
+    ops/paged_attention._kernel_pack). Streamed decode is the third such
+    form and the engine's to know (`EngineConfig.stream_pages`,
+    NativeEngine.__init__): its staged pages meet the resident ones in the
+    form they travel in."""
+    return bool(cfg.kv_quant) or cfg.decode_kernel not in ("auto", "off")
+
+
 def kv_heads_per_row(cfg: ModelConfig, tp: int = 1) -> int:
     """THE rule for `ModelConfig.kv_row_heads`: how many adjacent KV heads
     share one row of the paged pool, read from shapes and the cache's
@@ -464,25 +505,48 @@ def kv_heads_per_row(cfg: ModelConfig, tp: int = 1) -> int:
     other pool already is, over the same bytes. 1 wherever that is not an
     exact view of the same result: head_dim does not divide 128 (96), a
     "tp" shard's heads do not fill whole rows, a latent cache (one leaf of
-    one head), an int8 pool (a row's scale is per head: two heads to a
-    row would share one), or the Pallas decode kernel asked for (it takes
-    its scale from the page's width and packs tiles its own way,
-    ops/paged_attention._kernel_pack)."""
+    one head: `kv_row_lanes` below widens ITS row), or a pool that
+    `_rows_as_published`."""
     hd = cfg.head_dim
     if not 0 < hd < LANES or LANES % hd:
         return 1
     f = LANES // hd
-    if (cfg.is_mla or cfg.kv_quant or cfg.num_kv_heads % (tp * f)
-            or cfg.decode_kernel not in ("auto", "off")):
+    if (cfg.is_mla or _rows_as_published(cfg)
+            or cfg.num_kv_heads % (tp * f)):
         return 1
     return f
 
 
+def kv_row_lanes(cfg: ModelConfig, tp: int = 1) -> int:
+    """THE rule for `ModelConfig.kv_row_lanes`, beside `kv_heads_per_row`
+    and read from the same things: the lanes one row of the paged pool is
+    stored in. Every cache of K and V heads: `kv_heads_per_row` x
+    head_dim, what it was. A latent cache: its kv_lora_rank +
+    qk_rope_head_dim values in the next whole number of 128-lane tiles
+    (576 -> 640), the rest zeros in the stored row and in the query
+    (models/llama._mla_front), so that q . row adds exact zeros and the
+    values' pad columns are dropped with the rope columns
+    (`_mla_out`). A 576-wide row is 4.5 tiles: such a pool rests
+    pages-minor on a TPU too, and every program copied it whole on the
+    way in and on the way out (Moonlight: 3.6 ms a program, PERF.md
+    section 6, PR 53). The price is the pad's share of the pool's bytes
+    (a ninth), which `kv_bytes_per_token` does not count and
+    `kv_page_bytes` does. The model's own width for a pool that
+    `_rows_as_published` (no engine serves a latent cache that way yet,
+    `refuse_unserved`; the reasons are the forms' own)."""
+    if not cfg.is_mla:
+        return kv_heads_per_row(cfg, tp) * cfg.head_dim
+    if _rows_as_published(cfg):
+        return cfg.latent_width
+    return -(-cfg.latent_width // LANES) * LANES
+
+
 def with_kv_rows(cfg: ModelConfig, tp: int = 1) -> ModelConfig:
-    """`cfg` as an engine on a mesh of `tp` serves it: `kv_row_heads` by
-    the rule. NativeEngine, and the tools and tests that build its
-    programs without one (tools/pool_ops.py)."""
-    return dataclasses.replace(cfg, kv_row_heads=kv_heads_per_row(cfg, tp))
+    """`cfg` as an engine on a mesh of `tp` serves it: `kv_row_heads` and
+    `kv_row_lanes` by their rules. The tools and tests that build an
+    engine's programs without one (tools/pool_ops.py)."""
+    return dataclasses.replace(cfg, kv_row_heads=kv_heads_per_row(cfg, tp),
+                               kv_row_lanes=kv_row_lanes(cfg, tp))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -741,7 +805,7 @@ def refuse_unserved(model_cfg: ModelConfig,
          f"({cfg.state_bytes_per_slot()} bytes)"),
         (cfg.is_mla,
          f"latent attention keeps ONE cache leaf of width "
-         f"{cfg.kv_lora_rank + cfg.qk_rope_head_dim} a token"),
+         f"{cfg.latent_width} a token"),
         (cfg.window_pool,
          f"{cfg.num_window_layers} sliding layers keep their last "
          f"{cfg.sliding_window} tokens in a page pool of their own"),

@@ -26,7 +26,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.engine.config import (
-    EngineConfig, ModelConfig, kv_heads_per_row, refuse_unserved,
+    EngineConfig, ModelConfig, kv_heads_per_row, kv_row_lanes,
+    refuse_unserved,
 )
 from dynamo_tpu.engine.kv_cache import SequenceState
 from dynamo_tpu.engine.offload import CopyStream, HostKvPool
@@ -158,15 +159,18 @@ class NativeEngine:
                     f"decode_kernel='on': num_heads={h} / num_kv_heads="
                     f"{hkv} not divisible by tp={tp}; use "
                     f"decode_kernel='auto'")
-        # how many KV heads share a row of the device pool: resolved HERE,
-        # once, from shapes and the mesh (engine/config.kv_heads_per_row);
-        # every program closes over it through `model_cfg`. Streamed
-        # decode (engine/streaming.py) attends over pages staged from the
-        # host tier beside the resident ones, in the form they travel in:
-        # its pool keeps a head to a row
+        # what a row of the device pool holds (KV heads that share it, the
+        # lanes it is stored in): resolved HERE, once, from shapes and the
+        # mesh (engine/config.kv_heads_per_row, kv_row_lanes); every
+        # program closes over it through `model_cfg`. Streamed decode
+        # (engine/streaming.py) attends over pages staged from the host
+        # tier beside the resident ones, in the form they travel in: its
+        # pool keeps the model's own rows
+        streamed = bool(engine_cfg.stream_pages)
         model_cfg = dataclasses.replace(
-            model_cfg, kv_row_heads=1 if engine_cfg.stream_pages
-            else kv_heads_per_row(model_cfg, tp))
+            model_cfg,
+            kv_row_heads=1 if streamed else kv_heads_per_row(model_cfg, tp),
+            kv_row_lanes=0 if streamed else kv_row_lanes(model_cfg, tp))
         self.model_cfg = model_cfg
         self.cfg = engine_cfg
         self.eos_token_ids = set(eos_token_ids or ())
@@ -291,6 +295,7 @@ class NativeEngine:
         self.ledger = StepLedger()
         self.ledger.stats.kv_bytes_per_token = model_cfg.kv_bytes_per_token()
         self.ledger.stats.kv_heads_per_row = model_cfg.kv_row_heads
+        self.ledger.stats.kv_row_lanes = model_cfg.kv_cache_leaves()["k"][1]
         self.ledger.stats.kv_bytes_per_token_full = \
             model_cfg.kv_bytes_per_token()
         self.ledger.stats.kv_bytes_per_token_window = \
@@ -602,12 +607,13 @@ class NativeEngine:
         # §2.7); ids are bucketed, out-of-range ids are dropped. Pages
         # leave and enter as [L, Hkv, Nb, ps, hd] whatever a row of THIS
         # pool holds (`kv_row_heads`, which follows the mesh: the two
-        # ends of a transfer may differ)
-        self._extract_fn = jax.jit(functools.partial(
-            _extract_pages, row_heads=model_cfg.kv_row_heads))
-        self._inject_fn = jax.jit(functools.partial(
-            _inject_pages, row_heads=model_cfg.kv_row_heads),
-            donate_argnums=(0,))
+        # ends of a transfer may differ; `kv_row_pad`, a latent row's
+        # zero lanes, which stay behind)
+        rows = dict(row_heads=model_cfg.kv_row_heads,
+                    pad=model_cfg.kv_row_pad)
+        self._extract_fn = jax.jit(functools.partial(_extract_pages, **rows))
+        self._inject_fn = jax.jit(functools.partial(_inject_pages, **rows),
+                                  donate_argnums=(0,))
         # sharded parallel transfer (disagg/remote_transfer.py): one
         # jitted slice-scatter per shard-slice plan entry — the set is
         # bounded by the transfer layout (parallel/mesh.kv_shard_layout)
@@ -2862,7 +2868,8 @@ class NativeEngine:
         if fn is None:
             fn = self._inject_shard_fns[key] = jax.jit(
                 functools.partial(_inject_pages_slice, slices=key,
-                                  row_heads=self.model_cfg.kv_row_heads),
+                                  row_heads=self.model_cfg.kv_row_heads,
+                                  pad=self.model_cfg.kv_row_pad),
                 donate_argnums=(0,))
         self.cache = fn(self.cache, jnp.asarray(ids), pages)
 
@@ -3228,51 +3235,61 @@ def _named(name: str, fn):
     return program
 
 
-def _logical_pages(pages, f: int):
+def _logical_pages(pages, f: int, pad: int = 0):
     """Pages of a pool whose rows hold f KV heads [L, Hkv / f, Nb, ps,
     f * hd] -> [L, Hkv, Nb, ps, hd], the form a page has outside the
     device pool (the wire, the offload tiers, the shared pool, their
-    checksums): one transpose of the handful of pages moved."""
+    checksums): one transpose of the handful of pages moved. A row stored
+    with `pad` zero lanes past the model's values (a latent row in whole
+    lane tiles, engine/config.kv_row_lanes) leaves without them."""
     l, rows, nb, ps, width = pages.shape
-    return pages.reshape(l, rows, nb, ps, f, width // f).transpose(
-        0, 1, 4, 2, 3, 5).reshape(l, rows * f, nb, ps, width // f)
+    hd = (width - pad) // f
+    return pages[..., :f * hd].reshape(l, rows, nb, ps, f, hd).transpose(
+        0, 1, 4, 2, 3, 5).reshape(l, rows * f, nb, ps, hd)
 
 
-def _stored_pages(pages, f: int):
-    """The way back: [L, Hkv, Nb, ps, hd] -> [L, Hkv / f, Nb, ps, f * hd]."""
+def _stored_pages(pages, f: int, pad: int = 0):
+    """The way back: [L, Hkv, Nb, ps, hd] -> [L, Hkv / f, Nb, ps, f * hd
+    + pad], the pad lanes ZEROS whatever the slot held."""
     l, hkv, nb, ps, hd = pages.shape
-    return pages.reshape(l, hkv // f, f, nb, ps, hd).transpose(
+    rows = pages.reshape(l, hkv // f, f, nb, ps, hd).transpose(
         0, 1, 3, 4, 2, 5).reshape(l, hkv // f, nb, ps, f * hd)
+    return jnp.pad(rows, [(0, 0)] * 4 + [(0, pad)]) if pad else rows
 
 
-def _extract_pages(cache, ids, row_heads: int = 1):
+def _extract_pages(cache, ids, row_heads: int = 1, pad: int = 0):
     """Gather pages by ids [Nb] along the page axis (2) of EVERY cache
     leaf -> values [L, Hkv, Nb, ps, hd] and, on kv_quant engines, the
     scale stacks [L, Hkv, Nb, ps], which move with the same ids. A pool
-    of `row_heads` > 1 heads a row (never quantized) hands its pages out
-    a head a row, as every other pool does (_logical_pages)."""
+    of `row_heads` > 1 heads a row, or of rows `pad` lanes wider than
+    the model's (neither ever quantized), hands its pages out a head a
+    row at the model's width, as every other pool does
+    (_logical_pages)."""
     # dynalint: kv-codec — whole-page moves keep the stored (possibly
     # quantized) representation; no value decode happens here
     pages = {key: jnp.take(arr, ids, axis=2) for key, arr in cache.items()}
-    if row_heads > 1:
-        pages = {key: _logical_pages(arr, row_heads)
+    if row_heads > 1 or pad:
+        pages = {key: _logical_pages(arr, row_heads, pad)
                  for key, arr in pages.items()}
     return pages
 
 
-def _inject_pages(cache, ids, pages, row_heads: int = 1):
+def _inject_pages(cache, ids, pages, row_heads: int = 1, pad: int = 0):
     """Scatter pages into the cache at ids; out-of-range ids are dropped.
     `pages` carries the same leaf set as the cache (values + scales on
-    kv_quant engines), a head a row; a pool of `row_heads` > 1 takes them
-    re-viewed to its rows (_stored_pages)."""
+    kv_quant engines), a head a row at the model's width; a pool of
+    `row_heads` > 1 or of padded rows takes them re-viewed to its rows
+    (_stored_pages)."""
     # dynalint: kv-codec — whole-page moves of the stored representation
-    if row_heads > 1:
-        pages = {key: _stored_pages(pages[key], row_heads) for key in cache}
+    if row_heads > 1 or pad:
+        pages = {key: _stored_pages(pages[key], row_heads, pad)
+                 for key in cache}
     return {key: cache[key].at[:, :, ids].set(pages[key], mode="drop")
             for key in cache}
 
 
-def _inject_pages_slice(cache, ids, pages, slices=(), row_heads: int = 1):
+def _inject_pages_slice(cache, ids, pages, slices=(), row_heads: int = 1,
+                        pad: int = 0):
     """Scatter a shard slice of pages into the cache at ids: `slices`
     ((axis, start, count), ...) are STATIC bounds over the leading
     (layer, kv-head) axes — one compiled program per shard-plan entry.
@@ -3285,7 +3302,9 @@ def _inject_pages_slice(cache, ids, pages, slices=(), row_heads: int = 1):
     slice of KV heads (which a sender's plan may cut anywhere) as one
     such scatter a lane group: head h lands in row h // f, lanes
     (h % f) * hd .., so the heads of the slice that share a lane group
-    are every f-th, and a run of rows."""
+    are every f-th, and a run of rows. A pool of rows `pad` lanes wider
+    than the model's (a head a row) takes the slice's pages with zeros
+    in the pad."""
     out = {}
     # dynalint: kv-codec — whole-page slice moves keep the stored
     # (possibly quantized) representation; scale leaves share axes 0/1
@@ -3296,7 +3315,9 @@ def _inject_pages_slice(cache, ids, pages, slices=(), row_heads: int = 1):
             idx[axis] = slice(start, start + count)
         idx[2] = ids
         if row_heads == 1:
-            out[key] = arr.at[tuple(idx)].set(pages[key], mode="drop")
+            out[key] = arr.at[tuple(idx)].set(
+                _stored_pages(pages[key], 1, pad) if pad else pages[key],
+                mode="drop")
             continue
         heads = range(arr.shape[1] * row_heads)[idx[1]]   # of the slice
         hd = arr.shape[-1] // row_heads
@@ -3430,9 +3451,23 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
         # spares the gathered base (537 MB a leaf for Mistral-7B-16 at 32
         # slots x 512 tokens) the fill pass of take's default mode, a
         # broadcast and a select as large as the base itself
+        # a latent cache (ONE row a token) is gathered a (layer, page) an
+        # index, over the layer and page axes as the one axis they
+        # already are: a `take` along the page axis alone moves all the
+        # layers' rows an index, and XLA:TPU splits such a gather once an
+        # index's rows pass half a megabyte (Moonlight's 9 x 64 x 640
+        # bf16). A K / V pool is split by its rows, which are a view; the
+        # one row of a latent pool by COLUMNS, each piece a slice of the
+        # whole pool, 1.5 GB moved a window (PERF.md section 6, PR 53)
         @jax.named_scope("attention.gather")
         def gather_base(c, table=base_table):
-            g = jnp.take(c, table.reshape(-1), axis=2, mode="clip")
+            if cfg.is_mla:
+                pages = jnp.arange(c.shape[0])[:, None] * c.shape[2] \
+                    + table.reshape(-1)
+                g = jnp.take(c.reshape((-1,) + c.shape[3:]), pages, axis=0,
+                             mode="clip")
+            else:
+                g = jnp.take(c, table.reshape(-1), axis=2, mode="clip")
             return g.reshape(c.shape[0], hkv_n, s,
                              table.shape[1] * page_size, hd)
 

@@ -1074,7 +1074,12 @@ def attn_scale(cfg: ModelConfig) -> float:
     """What every attention op is handed as `q_scale`. 0.0 selects
     `width ** -0.5` from the OPERAND's last axis (ops/attention._scale),
     which is head_dim only while a row is one head: where rows are shared
-    the scale is named."""
+    the scale is named. A latent row's width is neither the width of
+    a head's key (qk_nope_head_dim + qk_rope_head_dim) nor, stored in
+    whole lane tiles, the model's own: named too."""
+    if cfg.is_mla:
+        return cfg.query_scale or (
+            cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
     if cfg.kv_row_heads == 1:
         return cfg.query_scale
     return cfg.query_scale or cfg.head_dim ** -0.5
@@ -1090,7 +1095,10 @@ def _mla_up_proj(lp: Params, cfg: ModelConfig, dtype) -> tuple:
 def _mla_front(x: jax.Array, lp: Params, cfg: ModelConfig,
                positions: jax.Array):
     """Multi-head latent attention in the absorbed form, the front half:
-    x [B, T, D] -> (q [B, T, H, r + dr], row [B, T, 1, r + dr], None).
+    x [B, T, D] -> (q [B, T, H, w], row [B, T, 1, w], None), w the
+    width the pool stores a row in (`cfg.kv_cache_leaves`): r + dr, or
+    that in whole lane tiles with zeros in the lanes past r + dr of BOTH
+    (engine/config.kv_row_lanes), which adds exact zeros to q . row.
 
     `row` is what the cache stores for a token, once: the latent
     c = RMSNorm(x Wkv_a[:, :r]; kv_a_norm) and the rotated key part
@@ -1122,18 +1130,26 @@ def _mla_front(x: jax.Array, lp: Params, cfg: ModelConfig,
         if cfg.mla_qk_norm:
             k_pe = rms_norm(k_pe, lp["mla_k_norm"], cfg.rms_norm_eps)
         k_pe = apply_rope(k_pe, positions, cfg.rope_theta)
-        row = jnp.concatenate([c[:, :, None, :], k_pe], axis=-1)
+        row = _stored_row(jnp.concatenate([c[:, :, None, :], k_pe],
+                                          axis=-1), cfg)
     with jax.named_scope("attention.mla.absorb"):
         w_uk, _ = _mla_up_proj(lp, cfg, xn.dtype)
         q_lat = jnp.einsum("bthn,rhn->bthr", q[..., :dn], w_uk)
-        q = jnp.concatenate([q_lat, q_pe], axis=-1)
+        q = _stored_row(jnp.concatenate([q_lat, q_pe], axis=-1), cfg)
     return q, row, None
+
+
+def _stored_row(a: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """[..., r + dr] -> [..., the stored row's width], zeros in the pad."""
+    pad = cfg.kv_row_pad
+    return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)]) if pad else a
 
 
 def _mla_out(attn: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
     """The absorbed form's back half: the softmax-weighted cache rows
-    [B, T, H, r + dr], of which the first r columns are o_lat (the
-    values of a one-leaf cache are its latent columns) -> [B, T, H, hd]
+    [B, T, H, the stored width], of which the first r columns are o_lat
+    (the values of a one-leaf cache are its latent columns; the rope
+    columns and a stored row's zero pad are dropped) -> [B, T, H, hd]
     through each head's W_UV."""
     with jax.named_scope("attention.mla.out"):
         _, w_uv = _mla_up_proj(lp, cfg, attn.dtype)

@@ -174,6 +174,11 @@ class LedgerStats:
         "kv_heads_per_row",       # gauge: KV heads that share one row of
         #                           the device pool (engine/config.
         #                           kv_heads_per_row; 1 = a head a row)
+        "kv_row_lanes",           # gauge: lanes that row is STORED in
+        #                           (engine/config.kv_row_lanes: heads x
+        #                           head_dim; a latent row's 576 values
+        #                           in 640). kv_bytes_per_token stays the
+        #                           MODEL's bytes, pad lanes not counted
         # a model whose sliding layers keep a page pool of their own
         # (ModelConfig.window_pool; engine._account_attention,
         # _account_window_pool). The two series above then count the

@@ -130,11 +130,12 @@ def test_served_logits_match_the_plain_reference(served_f32):
     # scratch slot of the slot-addressed update (llama.init_state)
     slots = ENGINE_KW["max_slots"] + EngineConfig().max_prefill_batch + 1
     assert stats["cache"] == {
-        "k": ((1, 1, 64, 16, 40), "float32"),
+        "k": ((1, 1, 64, 16, 128), "float32"),    # 32 + 8 in a lane tile
         "kda_s": ((7, slots, 4, 16, 16), "float32"),
         "kda_conv": ((7, slots, 3, 192), "float32")}
     assert stats["slots_used"] == 0          # every sequence finished
-    assert stats["page_bytes"] == 16 * 40 * 4
+    assert stats["page_bytes"] == 16 * 128 * 4      # the pool's
+    assert TINY.kv_bytes_per_token() == 40 * 4      # the model's
     assert stats["slot_bytes"] == TINY.state_bytes_per_slot() \
         == 7 * (4 * 16 * 16 * 4 + 3 * 192 * 4)
 

@@ -28,7 +28,7 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.engine.config import (
-    EngineConfig, ModelConfig, refuse_unserved,
+    EngineConfig, ModelConfig, refuse_unserved, with_kv_rows,
 )
 from dynamo_tpu.engine.engine import NativeEngine
 from dynamo_tpu.models import llama, reference
@@ -284,11 +284,25 @@ def bench_config(name="moonlight-16b-a3b"):
 def test_the_cache_is_one_leaf_of_576_at_the_published_widths():
     cfg = config_from_hf(bench_config(), name="moonlight")
     assert cfg.num_layers == 9
+    # the MODEL's row: 512 latent + 64 rope values a token and layer, and
+    # the bytes a step must read a token of context (the gauge
+    # `llm_engine_kv_bytes_per_token`, `attn.kv_read_mb`, the window's
+    # roofline). A raw configuration's pool is that wide
     shapes = jax.eval_shape(lambda: llama.init_cache(cfg, 8, 64))
     assert {k: v.shape for k, v in shapes.items()} == {
         "k": (9, 1, 8, 64, 576)}
-    assert shapes["k"].dtype == jnp.bfloat16
     assert cfg.kv_bytes_per_token() == 9 * 576 * 2 == 10368
+    # what an engine STORES (engine/config.kv_row_lanes, PR 53): the row
+    # in five whole 128-lane tiles, 64 zero lanes, a ninth more pool
+    # bytes (`llm_engine_kv_row_lanes`, `kv_page_bytes`); the model's
+    # figure does not move with it
+    served = with_kv_rows(cfg)
+    assert (served.kv_row_lanes, served.kv_row_pad) == (640, 64)
+    shapes = jax.eval_shape(lambda: llama.init_cache(served, 8, 64))
+    assert {k: v.shape for k, v in shapes.items()} == {
+        "k": (9, 1, 8, 64, 640)}
+    assert shapes["k"].dtype == jnp.bfloat16
+    assert served.kv_bytes_per_token() == 10368
     # what expanded keys and values would hold
     assert 9 * 16 * (192 + 128) * 2 == 92160
     assert set(llama.cache_shardings(cfg)) == {"k"}
@@ -312,9 +326,11 @@ def test_the_cache_is_one_leaf_of_576_at_the_published_widths():
 
 def test_the_engine_stores_no_expanded_keys_or_values(served_f32):
     *_, stats = served_f32
-    # [L, 1 head, pages, page size, latent 32 + rope 8]
-    assert stats["cache"] == {"k": (3, 1, 64, 16, 40)}
-    assert stats["page_bytes"] == 3 * 16 * 40 * 4
+    # [L, 1 head, pages, page size, latent 32 + rope 8 in one lane tile]:
+    # a page's bytes are the pool's (the allocator's figure), a token's
+    # the model's
+    assert stats["cache"] == {"k": (3, 1, 64, 16, 128)}
+    assert stats["page_bytes"] == 3 * 16 * 128 * 4
     assert stats["kv_bytes"] == 3 * 40 * 4
 
 

@@ -435,14 +435,23 @@ PARENT_PROGRAMS = {
     # `layer_front` forms rows, the queries are zero-extended and the
     # scale is named; with the rule patched to 1 they read PR 50's
     # 8b3a1bcc2cd60304 / f9966fa4a465991d / fc8c262d1fc40d22 /
-    # c484f91bd464d383 again. The six below have 2 KV heads or a latent
-    # cache, keep a head a row and stand
+    # c484f91bd464d383 again. The four of Moonlight and Mellum below have
+    # 2 KV heads, keep a head a row and stand
     ("rehearsal-tiny", "step"): "d6d4e28b3a6aa3ba",
     ("rehearsal-tiny", "window"): "e8e6031c9fbd2901",
     ("rehearsal-tiny-olmoe", "step"): "fc391189438bccfd",
     ("rehearsal-tiny-olmoe", "window"): "214e56cb1f87ea0a",
-    ("rehearsal-tiny-moonlight", "step"): "285056b88b57c562",
-    ("rehearsal-tiny-moonlight", "window"): "1f212b404bab2dbb",
+    # PR 53 replaced Moonlight's two and Ling's two, and MEANT to: a
+    # latent row's 32 + 8 values are stored in one whole 128-lane tile
+    # (engine/config.kv_row_lanes), so `_mla_front` zero-extends q and
+    # the row and the pool is [L, 1, P, ps, 128], and a latent window
+    # gathers its base a (layer, page) an index (engine.gather_base);
+    # with the rule patched to 0, and for the windows the base's `take`
+    # along the page axis put back, they read the parent's
+    # 285056b88b57c562 / 1f212b404bab2dbb / 6a79565f07f66d72 /
+    # d8709fd5313f3673 again. No other moved
+    ("rehearsal-tiny-moonlight", "step"): "c173084287903862",
+    ("rehearsal-tiny-moonlight", "window"): "7718b8012f9b8fa7",
     ("rehearsal-tiny-mellum", "step"): "6921cab248da7e90",
     ("rehearsal-tiny-mellum", "window"): "826993a03ea7d66a",
     # PR 45: Ling (Kimi-Delta layers over state slots, one latent-attention
@@ -450,8 +459,8 @@ PARENT_PROGRAMS = {
     # state-space mixer now shares (llama._chunk_group, conv_with_tail,
     # conv_one_token): traced from PR 45's parent (3e6f8f0) at 16 rows,
     # where the linear layers split a step's rows (at 8 they do not)
-    ("rehearsal-tiny-ling", "step"): "6a79565f07f66d72",
-    ("rehearsal-tiny-ling", "window"): "d8709fd5313f3673",
+    ("rehearsal-tiny-ling", "step"): "c576e97dc97f4766",
+    ("rehearsal-tiny-ling", "window"): "dc3f1b566de8abdf",
 }
 PROGRAM_ROWS = {"rehearsal-tiny-ling": 16}
 
@@ -548,11 +557,15 @@ SERVED_PROGRAMS = {
     ("mixtral-8x7b", 32, 16, "window"): "4ec4c91e571b5d13",
     ("olmoe-1b-7b", 32, 16, "step"): "512b02ddc6af76a2",
     ("olmoe-1b-7b", 32, 16, "window"): "3e4a829462e81321",
-    ("moonlight-16b-a3b", 8, 64, "window"): "659294f9b1fcc10f",
+    # PR 53: the two latent windows below are this tree's, MEANT to
+    # change (576 -> 640 lanes a stored row, the base gathered a (layer,
+    # page) an index; with both undone they read 659294f9b1fcc10f /
+    # 4499a3a1552d90c3, PR 48's parent's)
+    ("moonlight-16b-a3b", 8, 64, "window"): "d52ec8825dce4249",
     ("mellum2-12b-a2.5b", 8, 64, "window"): "a35fc1fb307f3aa2",
     ("trinity-mini", 8, 64, "step"): "5c5360c8188fecef",
     ("trinity-mini", 8, 64, "window"): "88a92f6215229ff7",
-    ("ling-3.0-flash-vl", 64, 64, "window"): "4499a3a1552d90c3",
+    ("ling-3.0-flash-vl", 64, 64, "window"): "b5cf7118a170b93f",
     ("falcon-h1-34b", 64, 64, "window"): "279a188e98469eba",
     # PR 50: LFM2 at its cell's shape, this tree's own (the parent cannot
     # trace it): a lead's conv body before the period loop's two, the
@@ -560,7 +573,7 @@ SERVED_PROGRAMS = {
     # PR 51 replaced both, and MEANT to (they read ef012449ae3183b4 /
     # 30ba5ac52d223f9b): two 64-wide KV heads share a pool row, the pool
     # is [3, 4, P, 64, 128]; the 12 above (128-wide heads, or a latent
-    # cache) are untouched
+    # cache, which PR 53 took up) are untouched
     ("lfm2-8b-a1b", 8, 64, "step"): "58702a7655d63973",
     ("lfm2-8b-a1b", 8, 64, "window"): "f697e06a037af8e9",
 }
