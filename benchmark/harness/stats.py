@@ -141,6 +141,9 @@ def client_side(rows, t0: float, seconds: float, kind: str) -> dict:
         out["ttft_from_send_mean_s"] = sum(ttft) / len(ttft)
     gaps = pooled_gaps(rows, t0, t0 + seconds)
     if gaps:
+        # the end-to-end itl_p95_ms again: per layer in a cell where it is
+        # not end to end (layer_metrics/stream.itl_p95_ms.json)
+        out["itl_p95_s"] = percentile(gaps, 95)
         out["itl_p99_s"] = percentile(gaps, 99)
     if kind == "open" and att:
         ttft_due = ttft_from_due(att, seconds)

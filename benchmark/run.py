@@ -14,8 +14,9 @@ request): backend start, seeded on-device init, the set-up checks
 (checks/*.py), the warm-up walk over every (program, bucket) the cell's
 traffic can reach. Then the window: `--seconds` of the cell's traffic.
 `--trace 0` prints the cell's end-to-end metrics; `--trace 1` runs the same
-traffic, traces ~4 s in the middle of the window and prints the per-layer
-metrics and a `breakdown`.
+traffic, traces ~4 s in the middle of the window (longer, up to twice
+that, where no decode window was dispatched inside the 4 s:
+`slice_with_a_window`) and prints the per-layer metrics and a `breakdown`.
 
 The last line of stdout is one JSON object: `correct`, `attempted`,
 `failed`, `metrics`, `device` (and `breakdown`). No TPU, an unknown
@@ -50,6 +51,7 @@ from harness.loadgen import Row, do_request   # noqa: E402
 from harness.modeldir import build_model_dir   # noqa: E402
 
 TRACE_SLICE_S = 4.0
+TRACE_EXTEND_STEP_S = 0.5
 READY_TIMEOUT_S = 1000.0
 LADDER_KEYS = ("page_size", "prefill_buckets", "mixed_token_budget",
                "max_prefill_chunk", "max_prefill_batch", "max_slots",
@@ -204,6 +206,36 @@ async def run_checks(ctx: CheckCtx, meta: dict) -> list:
     return problems
 
 
+async def slice_with_a_window(windows, slice_s: float,
+                              step_s: float = TRACE_EXTEND_STEP_S,
+                              sleep=asyncio.sleep) -> tuple:
+    """Hold an open trace for `slice_s`, and where no decode window was
+    DISPATCHED inside that (`windows()`: the engine's count of window
+    programs launched; a count of commits would also take a window that
+    ran before the trace began), go on in steps of `step_s` until one
+    was, and one step more so that it runs inside the trace: at most
+    2 x `slice_s` in all. A `context-closed` cell spends ~70 % of its
+    time in mixed steps, and one dispatch that stalls for ~2 s beside
+    them (1 traced run in 24, PERF.md section 6, PR 54) leaves a slice
+    without a window and every window-step and window-roofline metric
+    with nothing to read (ledger, PRs 48 and 52). A slice that holds a
+    window is held exactly `slice_s`, as before PR 54. Returns
+    the windows dispatched inside the planned slice (0: the slice as it
+    was until PR 53 held none) and inside the whole trace."""
+    w0 = await windows()
+    await sleep(slice_s)
+    in_slice = seen = await windows() - w0
+    waited = 0.0
+    while not seen and waited + 2 * step_s <= slice_s:
+        await sleep(step_s)
+        waited += step_s
+        seen = await windows() - w0
+    if seen and not in_slice:
+        await sleep(step_s)
+        seen = await windows() - w0
+    return in_slice, seen
+
+
 async def read_event(proc, name: str, timeout: float) -> dict:
     """Next stdout line of the child that is the named event."""
     deadline = time.monotonic() + timeout
@@ -355,7 +387,7 @@ async def bench(args, bench_json: dict, cell_entry: dict) -> dict:
             await asyncio.sleep(max(0.0, t0 - 0.2 - time.monotonic()))
             eng0, prom0 = await served.engine_metrics(), await served.prom()
         trace_dir, trace_wall = os.path.join(out_dir, "trace"), None
-        stopping = None
+        stopping, trace_windows = None, (None, None)
         if args.trace:
             slice_s = min(TRACE_SLICE_S, args.seconds / 3)
             await asyncio.sleep(max(0.0, t0 + (args.seconds - slice_s) / 2
@@ -364,7 +396,10 @@ async def bench(args, bench_json: dict, cell_entry: dict) -> dict:
             opts.python_tracer_level = 0   # it slows the host it measures
             jax.profiler.start_trace(trace_dir, profiler_options=opts)
             ts = time.monotonic()
-            await asyncio.sleep(slice_s)
+
+            async def windows():
+                return (await served.engine_metrics())["decode_windows"]
+            trace_windows = await slice_with_a_window(windows, slice_s)
             trace_wall = time.monotonic() - ts
             # stopping writes the file, and on a busy cell that outlasts
             # the window: the end-of-window scrape does not wait for it,
@@ -377,7 +412,10 @@ async def bench(args, bench_json: dict, cell_entry: dict) -> dict:
         scrape_late_s = time.monotonic() - (t0 + args.seconds)
         if stopping is not None:
             await stopping
-            log(f"profiler stopped {time.monotonic() - ts - trace_wall:.1f}s "
+            log(f"traced {trace_wall:.2f}s, {trace_windows[1]} decode windows "
+                f"dispatched in it ({trace_windows[0]} in the first "
+                f"{slice_s:.1f}s); profiler stopped "
+                f"{time.monotonic() - ts - trace_wall:.1f}s "
                 f"after the slice; the scrape came {scrape_late_s:.3f}s "
                 f"after the window's end")
         await read_event(proc, "done", args.seconds + 90.0)
@@ -417,8 +455,15 @@ async def bench(args, bench_json: dict, cell_entry: dict) -> dict:
                   (int(m.get("peak_bytes_in_use", 0)) for m in mem),
                   default=0)}
 
+    e2e_cells = {m["name"]: m.get("workloads")
+                 for m in bench_json["end_to_end"]}
+
     def in_cell(m):
-        return "workloads" not in m or cell_entry["name"] in m["workloads"]
+        """A listed cell's; without a list every cell's, and for a
+        per-layer metric every cell's that reports the end-to-end metric
+        it moves (itl_p95_ms is end to end in some cells only)."""
+        cells = m.get("workloads", e2e_cells.get(m.get("moves")))
+        return cells is None or cell_entry["name"] in cells
 
     result = {"correct": not problems and e2e["failed"] == 0,
               "attempted": e2e["attempted"], "failed": e2e["failed"],
@@ -483,6 +528,12 @@ async def bench(args, bench_json: dict, cell_entry: dict) -> dict:
                       "checks_s": t_checked - t_ready,
                       "warmup_s": warm["warm_s"], "setup_s": setup_s},
             "scrape_late_s": scrape_late_s,
+            # a traced run: the measured length of the slice, the decode
+            # windows dispatched inside it, and inside its planned length
+            # (0 there: the slice was held on until one came)
+            "trace_slice_s": trace_wall,
+            "trace_window_programs": trace_windows[1],
+            "trace_window_programs_planned_slice": trace_windows[0],
             "programs_first_dispatched": prom1.get("llm_engine_recompiles"),
             "engine_delta": {k: eng1[k] - eng0[k] for k in eng0
                              if isinstance(eng0[k], (int, float))},

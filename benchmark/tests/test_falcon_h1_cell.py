@@ -48,24 +48,17 @@ PUBLISHED = {
     "vocab_size": 261120}
 REDUCED = {"num_hidden_layers": 6}
 ADDED = {"architectures", "torch_dtype", "num_hidden_layers_published"}
-# the accepted metrics whose lists of cells name other cells, twinned for
-# this cell under names of its own
-TWINS = {"ssm.fh1_state_rw_mb": "linattn.state_rw_mb",
-         "ssm.fh1_chunk_token_share": "linattn.chunk_token_share",
-         "ssm.fh1_inplace_share": "linattn.inplace_share",
-         "attn.fh1_kv_read_mb": "attn.kv_read_mb",
-         "attn.fh1_kv_pad_share": "attn.kv_pad_share",
-         "device.fh1_window_step_ms": "device.window_step_ms",
-         # the review round's: where itl_p95_ms stands among the gaps, the
-         # flat width --max-prefill-batch 3 is set for, the chain, the host
-         "stream.fh1_gap_mixed_share": "stream.gap_mixed_share",
-         "stream.fh1_gap_mixed_ms": "stream.gap_mixed_ms",
-         "stream.fh1_gap_window_ms": "stream.gap_window_ms",
-         "ssm.fh1_flat_step_share": "linattn.flat_step_share"}
-# the step periods, the chain and the host between two steps are every
-# cell's since PR 49 (no `workloads` key): their five copies went
-NEW = {"device.fh1_window_roofline", "device.fh1_ssm_kernel_share",
-       "device.fh1_ssm_step_roofline", *TWINS}
+# the accepted metrics whose lists name this cell since PR 54 (until then
+# each was twinned for it under an `fh1_` name of its own); the gaps, the
+# window step, the step periods, the chain and the host between two steps
+# are every cell's (no `workloads` key)
+SHARED = {"linattn.state_rw_mb", "linattn.chunk_token_share",
+          "linattn.inplace_share", "linattn.flat_step_share",
+          "attn.kv_read_mb", "attn.kv_pad_share"}
+EVERY = {"device.window_step_ms", "stream.gap_mixed_share",
+         "stream.gap_mixed_ms", "stream.gap_window_ms"}
+OWN = {"device.fh1_window_roofline", "device.fh1_ssm_kernel_share",
+       "device.fh1_ssm_step_roofline"}
 
 
 def load(*parts):
@@ -194,26 +187,21 @@ def test_the_cell_and_its_metrics_are_entries_of_the_benchmark():
     assert config["source"] == SOURCE
     assert config["file"] == f"benchmark/configs/{CONFIG}/config.json"
     assert len(config["why"]) <= 200 and len(cell["why"]) <= 200
-    mine = {name: by_name(b["per_layer"], name) for name in NEW}
+    mine = {name: by_name(b["per_layer"], name) for name in OWN | SHARED}
+    for name in OWN:
+        assert mine[name]["workloads"] == [CELL]
+    # since PR 49 a cell is named in a list and never in a metric's name:
+    # what this cell shares with others it reads under the accepted
+    # entries, whose lists name it (PR 54 folded its `fh1_` stand-ins)
+    for name in SHARED:
+        assert CELL in mine[name]["workloads"], name
     for m in mine.values():
-        assert m["workloads"] == [CELL]
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-    assert {mine[n]["layer"] for n in NEW if n.startswith("ssm.")} \
+    for name in EVERY:
+        assert "workloads" not in by_name(b["per_layer"], name)
+    assert {mine[n]["layer"] for n in SHARED if n.startswith("linattn.")} \
         == {"linear attention and state"}
-    # (an accepted entry's list MAY name this cell: since PR 49 a cell is
-    # named in a list and never in a metric's name, and what stays true,
-    # that no two entries read one expression in one cell, is
-    # test_benchmark_lists.py's)
-    # a twin is its original's expression and entry under its own name
-    for name, of in TWINS.items():
-        spec, old = (readers.load_metric(n, HERE) for n in (name, of))
-        assert spec["expr"] == old["expr"], name
-        entry = by_name(b["per_layer"], of)
-        assert {k: v for k, v in mine[name].items()
-                if k not in ("name", "workloads")} \
-            == {k: v for k, v in entry.items()
-                if k not in ("name", "workloads")}, name
     for m in b["per_layer"]:
         if CELL in m.get("workloads", [CELL]):
             readers.load_metric(m["name"], HERE)
@@ -278,21 +266,21 @@ KERNEL = (432000 * 8388608 / 50.0 / 819e9) / (0.2 * 3.0 / 4.0)
 
 
 @pytest.mark.parametrize("name,want", [
-    ("ssm.fh1_state_rw_mb", 128 * SLOT / 1e6),
-    ("ssm.fh1_chunk_token_share", 40.0),
-    ("ssm.fh1_inplace_share", 80.0),
-    ("attn.fh1_kv_read_mb", 49152 * 12288 / 1e6),
-    ("attn.fh1_kv_pad_share", 100 * (1 - 30000 / 49152)),
-    ("device.fh1_window_step_ms", 20.0),
+    ("linattn.state_rw_mb", 128 * SLOT / 1e6),
+    ("linattn.chunk_token_share", 40.0),
+    ("linattn.inplace_share", 80.0),
+    ("attn.kv_read_mb", 49152 * 12288 / 1e6),
+    ("attn.kv_pad_share", 100 * (1 - 30000 / 49152)),
+    ("device.window_step_ms", 20.0),
     # 11.45 GB / 819e9 = 14.0 ms against a 60 ms window of 3: 69.9 %
     ("device.fh1_window_roofline", 100 * (STEP_BYTES / 819e9) / 0.020),
     ("device.fh1_ssm_kernel_share", 20.0),
     ("device.fh1_ssm_step_roofline", 100 * KERNEL),
-    ("stream.fh1_gap_mixed_share", 25.0),
-    ("stream.fh1_gap_mixed_ms", 44.0),
-    ("stream.fh1_gap_window_ms", 60.0),
+    ("stream.gap_mixed_share", 25.0),
+    ("stream.gap_mixed_ms", 44.0),
+    ("stream.gap_window_ms", 60.0),
     # 1500 steps of the state, 800 of them windows: 693 of 700 flat
-    ("ssm.fh1_flat_step_share", 99.0),
+    ("linattn.flat_step_share", 99.0),
     ("pipeline.mixed_chained_share", 82.5),
     ("host.exposed_between_ms", 1.3)])
 def test_the_metric_files_evaluate_on_recorded_sources(name, want):

@@ -171,7 +171,12 @@ def test_failed_requests_count_as_misses():
     assert out["metrics"]["ttft_p50_ms"] == pytest.approx(200.0)
     short = row(10, 105.0, 105.0, [105.1], 2)   # one frame short of two
     assert not stats.request_ok(short)
-    assert stats.client_side(rows, 100.0, 10.0, "open")["late_p95_s"] == 0.0
+    side = stats.client_side(rows, 100.0, 10.0, "open")
+    assert side["late_p95_s"] == 0.0
+    # the per-layer tail of a cell where `itl_p95_ms` is not end to end is
+    # the end-to-end arithmetic on the same rows (stream.itl_p95_ms)
+    assert side["itl_p95_s"] * 1e3 == out["metrics"]["itl_p95_ms"]
+    assert side["itl_p95_s"] <= side["itl_p99_s"]
 
 
 def test_closed_window_cuts_unfinished_requests():
@@ -341,8 +346,10 @@ def test_benchmark_json_names_units_and_files():
         spec = readers.load_metric(m["name"], HERE)
         assert (spec["layer"], spec["unit"], spec["moves"]) == (
             m["layer"], m["unit"], m["moves"])
+        # a list names cells that report what the entry moves; without a
+        # list the entry is every such cell's (run.py `in_cell`)
         moved = next(e for e in b["end_to_end"] if e["name"] == m["moves"])
-        assert set(m.get("workloads", cells)) <= \
+        assert set(m.get("workloads", ())) <= \
             set(moved.get("workloads", cells))
     used = set()
     for w in b["workloads"]:
@@ -451,7 +458,7 @@ def test_the_fold_moves_no_value():
     read = 0
     for former in lists.FORMER["per_layer"]:
         was = readers.evaluate(former["expr"], ctx)
-        for c in former.get("workloads", lists.FORMER["cells"]):
+        for c in lists.cells_of(former):
             now, = lists.now_named(former, c)
             assert readers.evaluate(now["expr"], ctx) == was, \
                 (former["name"], c, now["name"])

@@ -38,19 +38,17 @@ PUBLISHED = {
 REDUCED = {"num_hidden_layers": 14,
            "layer_types": PUBLISHED["layer_types"][:14]}
 ADDED = {"architectures", "torch_dtype", "num_hidden_layers_published"}
-# the accepted metrics whose lists of cells name other cells: this cell
-# reads each under a name of its own, a file that holds `expr_of`
-STAND_INS = {"moe.conv_experts_hit": "moe.experts_hit",
-             "moe.conv_pad_share": "moe.pad_share",
-             "moe.conv_dropped_share": "moe.dropped_share",
-             "device.conv_moe_kernel_share": "device.moe_kernel_share",
-             "moe.conv_window_experts_hit": "moe.mla_window_experts_hit",
-             "device.conv_window_step_ms": "device.window_step_ms",
-             "attn.conv_kv_read_mb": "attn.kv_read_mb",
-             "linattn.conv_state_rw_mb": "linattn.state_rw_mb",
-             "linattn.conv_chunk_token_share": "linattn.chunk_token_share"}
+# the accepted metrics whose lists name this cell since PR 54 (until then
+# it read each through a `conv_` stand-in, a file that held `expr_of`);
+# `attn.kv_pad_share` is new on it: ISSUE 50 named it, PR 50 had no room
+SHARED = {"moe.experts_hit", "moe.pad_share", "moe.dropped_share",
+          "device.moe_kernel_share", "moe.window_experts_hit",
+          "attn.kv_read_mb", "attn.kv_pad_share", "linattn.state_rw_mb",
+          "linattn.chunk_token_share"}
+EVERY = {"device.window_step_ms", "attn.split_step_share",
+         "stream.gap_mixed_share", "stream.gap_mixed_ms",
+         "stream.gap_window_ms"}
 OWN = {"device.shortconv_window_roofline", "device.shortconv_mixed_roofline"}
-NEW = {*OWN, *STAND_INS}
 
 
 def load(*parts):
@@ -157,34 +155,25 @@ def test_the_cell_and_its_metrics_are_entries_of_the_benchmark():
     assert config["source"] == SOURCE
     assert config["file"] == f"benchmark/configs/{CONFIG}/config.json"
     assert len(config["why"]) <= 200 and len(cell["why"]) <= 200
-    mine = {name: by_name(b["per_layer"], name) for name in NEW}
-    assert len(mine) <= 12
+    mine = {name: by_name(b["per_layer"], name) for name in OWN | SHARED}
     for m in mine.values():
-        assert m["workloads"] == [CELL]
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-    # nothing else of the benchmark names the cell but the one accepted
-    # entry whose list tier 1 pins to EVERY cell (tests/
-    # test_attention_rows.py): the cell was appended to it, as a PR that
-    # adds a cell may
+    # nothing else of the benchmark names the cell: two expressions of
+    # its own, and the accepted entries whose lists it is on
     assert {m["name"] for m in b["per_layer"]
-            if CELL in m.get("workloads", ())} \
-        == NEW | {"attn.split_step_share"}
+            if CELL in m.get("workloads", ())} == OWN | SHARED
     for name in OWN:
+        assert mine[name]["workloads"] == [CELL]
         assert (mine[name]["unit"], mine[name]["better"],
                 mine[name]["source"], mine[name]["moves"]) == (
                     "%", "higher", "device_trace", "tpot_p50_ms")
-    # a stand-in is its original's expression and entry under its own name
-    for name, of in STAND_INS.items():
-        assert load("layer_metrics", name + ".json")["expr_of"] == of
-        spec, old = (readers.load_metric(n, HERE) for n in (name, of))
-        assert spec["expr"] == old["expr"], name
-        entry = by_name(b["per_layer"], of)
-        assert CELL not in entry.get("workloads", ())
-        assert {k: v for k, v in mine[name].items()
-                if k not in ("name", "workloads")} \
-            == {k: v for k, v in entry.items()
-                if k not in ("name", "workloads")}, name
+    # what every engine exports has no list at all
+    for name in EVERY:
+        assert "workloads" not in by_name(b["per_layer"], name)
+    # no file of the cell's stands in for another any more (PR 54)
+    assert not [f for f in os.listdir(os.path.join(HERE, "layer_metrics"))
+                if ".conv_" in f]
     for m in b["per_layer"]:
         if CELL in m.get("workloads", [CELL]):
             readers.load_metric(m["name"], HERE)
@@ -240,12 +229,12 @@ MIXED_BYTES = FIXED + 31 * HIT + 16 * SLOT + 32768 * 6144
 
 
 @pytest.mark.parametrize("name,want", [
-    ("moe.conv_experts_hit", (1400 * 31 + 800 * 20) / 2200),
-    ("moe.conv_window_experts_hit", 20.0),
-    ("device.conv_window_step_ms", 10.0),
-    ("attn.conv_kv_read_mb", 32768 * 6144 / 1e6),
-    ("linattn.conv_state_rw_mb", 16 * SLOT / 1e6),
-    ("linattn.conv_chunk_token_share", 90.0),
+    ("moe.experts_hit", (1400 * 31 + 800 * 20) / 2200),
+    ("moe.window_experts_hit", 20.0),
+    ("device.window_step_ms", 10.0),
+    ("attn.kv_read_mb", 32768 * 6144 / 1e6),
+    ("linattn.state_rw_mb", 16 * SLOT / 1e6),
+    ("linattn.chunk_token_share", 90.0),
     # 6.34 GB / 819e9 = 7.7 ms against a 10 ms window step: 77 %
     ("device.shortconv_window_roofline",
      100 * (WINDOW_BYTES / 819e9) / 0.010),
@@ -441,7 +430,7 @@ def test_the_cells_rehearsal_serves_and_its_check_passes_in_float32():
     assert run.returncode == 0, run.stderr[-2000:]
     line = json.loads(run.stdout.strip().splitlines()[-1])
     assert line["rehearsal"] and line["failed"] == 0
-    assert "moe.conv_experts_hit" in line["metrics"]
+    assert "moe.experts_hit" in line["metrics"]
     probe = subprocess.run(
         [sys.executable, os.path.join(ROOT, "tools",
                                       "olmoe_reference_probe.py"),

@@ -51,18 +51,14 @@ REDUCED = {"num_hidden_layers": 8, "num_experts": 128, "vocab_size": 39296}
 ADDED = {"architectures", "model_type", "torch_dtype", "tie_word_embeddings",
          "num_experts_published", "expert_first", "vocab_size_published",
          "num_hidden_layers_published"}
-# the accepted metrics whose lists of cells name other cells, twinned for
-# this cell under names of its own
-TWINS = {"moe.ling_dropped_share": "moe.dropped_share",
-         "moe.ling_pad_share": "moe.pad_share",
-         "device.ling_moe_kernel_share": "device.moe_kernel_share",
-         "device.ling_window_step_ms": "device.window_step_ms",
-         "moe.ling_window_experts_hit": "moe.mla_window_experts_hit",
-         "moe.ling_experts_hit": "moe.experts_hit",
-         "attn.ling_kv_pad_share": "attn.kv_pad_share",
-         "attn.ling_kv_read_mb": "attn.kv_read_mb"}
-NEW = {"linattn.state_rw_mb", "linattn.chunk_token_share",
-       "moe.share_held_share", "device.ling_window_roofline", *TWINS}
+# the accepted metrics whose lists name this cell since PR 54 (until then
+# each was twinned for it under a `ling_` name of its own)
+SHARED = {"moe.dropped_share", "moe.pad_share", "device.moe_kernel_share",
+          "moe.window_experts_hit", "moe.experts_hit", "attn.kv_pad_share",
+          "attn.kv_read_mb", "linattn.state_rw_mb",
+          "linattn.chunk_token_share", "linattn.inplace_share",
+          "linattn.flat_step_share"}
+OWN = {"moe.share_held_share", "device.ling_window_roofline"}
 
 
 def load(*parts):
@@ -203,28 +199,23 @@ def test_the_cell_and_its_metrics_are_entries_of_the_benchmark():
     assert config["source"] == SOURCE
     assert config["file"] == f"benchmark/configs/{CONFIG}/config.json"
     assert len(config["why"]) <= 200 and len(cell["why"]) <= 200
-    mine = {name: by_name(b["per_layer"], name) for name in NEW}
+    mine = {name: by_name(b["per_layer"], name) for name in OWN | SHARED}
+    for name in OWN:
+        assert mine[name]["workloads"] == [CELL]
+    # since PR 49 a cell is named in a list and never in a metric's name:
+    # what this cell shares with others it reads under the accepted
+    # entries, whose lists name it (PR 54 folded its `ling_` stand-ins)
+    for name in SHARED:
+        assert CELL in mine[name]["workloads"], name
+    assert "workloads" not in by_name(b["per_layer"],
+                                      "device.window_step_ms")
     for m in mine.values():
-        assert m["workloads"] == [CELL]
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
     assert mine["linattn.state_rw_mb"]["layer"] \
         == mine["linattn.chunk_token_share"]["layer"] \
         == "linear attention and state"
     assert mine["device.ling_window_roofline"]["layer"] == "device programs"
-    # (an accepted entry's list MAY name this cell: since PR 49 a cell is
-    # named in a list and never in a metric's name, and what stays true,
-    # that no two entries read one expression in one cell, is
-    # test_benchmark_lists.py's)
-    # a twin is its original's expression and entry under its own name
-    for name, of in TWINS.items():
-        spec, old = (readers.load_metric(n, HERE) for n in (name, of))
-        assert spec["expr"] == old["expr"], name
-        entry = by_name(b["per_layer"], of)
-        assert {k: v for k, v in mine[name].items()
-                if k not in ("name", "workloads")} \
-            == {k: v for k, v in entry.items()
-                if k not in ("name", "workloads")}, name
     for m in b["per_layer"]:
         if CELL in m.get("workloads", [CELL]):
             readers.load_metric(m["name"], HERE)
@@ -282,11 +273,11 @@ STEP_BYTES = 1423234176 + 70778880 * 80 + 128 * SLOT + 49152 * 1152
     ("linattn.state_rw_mb", 128 * SLOT / 1e6),
     ("linattn.chunk_token_share", 40.0),
     ("moe.share_held_share", 25.0),
-    ("moe.ling_window_experts_hit", 80.0),
-    ("moe.ling_dropped_share", 0.0),
-    ("moe.ling_pad_share", 100 * (1 - 2.5e5 / 1.0e7)),
-    ("device.ling_moe_kernel_share", 100 * 1.2 / 3.0),
-    ("device.ling_window_step_ms", 20.0),
+    ("moe.window_experts_hit", 80.0),
+    ("moe.dropped_share", 0.0),
+    ("moe.pad_share", 100 * (1 - 2.5e5 / 1.0e7)),
+    ("device.moe_kernel_share", 100 * 1.2 / 3.0),
+    ("device.window_step_ms", 20.0),
     # 9.09 GB / 819e9 = 11.1 ms against a 160 ms window of 8: 55.5 %
     ("device.ling_window_roofline", 100 * (STEP_BYTES / 819e9) / 0.020)])
 def test_the_metric_files_evaluate_on_recorded_sources(name, want):

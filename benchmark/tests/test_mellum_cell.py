@@ -43,36 +43,28 @@ PUBLISHED = {
     "vocab_size": 98304, "use_sliding_window": True}
 CUT = {"num_hidden_layers", "layer_types", "mlp_layer_types"}
 METRICS = {
-    "attn.swa_kv_read_mb": "attention",
-    "attn.swa_window_read_share": "attention",
-    "attn.swa_kv_pad_share": "attention",
-    "kv.swa_window_pages_held": "scheduler",
-    "kv.swa_pages_released": "scheduler",
-    "device.swa_window_step_ms": "device programs",
+    "attn.pools_kv_read_mb": "attention",
+    "attn.window_read_share": "attention",
+    "attn.pools_kv_pad_share": "attention",
+    "kv.window_pages_held": "scheduler",
+    "kv.window_pages_released": "scheduler",
+    "device.window_step_ms": "device programs",
     "device.swa_window_roofline": "device programs",
-    "moe.swa_experts_hit": "MoE dispatch",
-    "moe.swa_window_experts_hit": "MoE dispatch",
-    "moe.swa_pad_share": "MoE dispatch",
-    "moe.swa_dropped_share": "MoE dispatch",
-    "device.swa_moe_kernel_share": "MoE dispatch",
-    "stream.swa_gap_mixed_share": "scheduler",
-    "stream.swa_gap_mixed_ms": "scheduler",
-    "stream.swa_gap_window_ms": "scheduler"}
-# the accepted metrics whose lists of cells name other cells alone,
-# twinned for this cell under names of its own
-TWINS = {"moe.swa_dropped_share": "moe.dropped_share",
-         "moe.swa_pad_share": "moe.pad_share",
-         "moe.swa_experts_hit": "moe.experts_hit",
-         "moe.swa_window_experts_hit": "moe.mla_window_experts_hit",
-         "device.swa_moe_kernel_share": "device.moe_kernel_share",
-         "device.swa_window_step_ms": "device.window_step_ms",
-         # the gaps between a stream's tokens (PR 35's layer). The step
-         # periods and the host between two steps, which this cell's
-         # bottleneck list in PERF.md rests on, are every cell's since PR
-         # 49 (no `workloads` key) and their seven copies went
-         "stream.swa_gap_mixed_share": "stream.gap_mixed_share",
-         "stream.swa_gap_mixed_ms": "stream.gap_mixed_ms",
-         "stream.swa_gap_window_ms": "stream.gap_window_ms"}
+    "moe.experts_hit": "MoE dispatch",
+    "moe.window_experts_hit": "MoE dispatch",
+    "moe.pad_share": "MoE dispatch",
+    "moe.dropped_share": "MoE dispatch",
+    "device.moe_kernel_share": "MoE dispatch",
+    "stream.gap_mixed_share": "scheduler",
+    "stream.gap_mixed_ms": "scheduler",
+    "stream.gap_window_ms": "scheduler"}
+# what every engine exports has no list since PR 54 (the gaps between a
+# stream's tokens, the window step; the step periods and the host between
+# two steps since PR 49); the others' lists name this cell, which read
+# each under a `swa_` name of its own until PR 54
+EVERY = {"device.window_step_ms", "stream.gap_mixed_share",
+         "stream.gap_mixed_ms", "stream.gap_window_ms"}
+ITL = set()    # the gaps are terms of a stream's pace: tpot_p50_ms (PR 54)
 
 
 def load(*parts):
@@ -169,9 +161,9 @@ def test_the_sizes_are_the_arithmetic_of_the_file_beside_them():
     assert weights["args"][0] == {"const": fixed}
     assert weights["args"][1]["args"][0] == {"const": per_hit}
     assert weights["args"][1]["args"][1] == readers.load_metric(
-        "moe.swa_window_experts_hit", HERE)["expr"]
-    # KV by kind, as attn.swa_kv_read_mb has it
-    assert kv == load("layer_metrics", "attn.swa_kv_read_mb.json")[
+        "moe.window_experts_hit", HERE)["expr"]
+    # KV by kind, as attn.pools_kv_read_mb has it
+    assert kv == load("layer_metrics", "attn.pools_kv_read_mb.json")[
         "expr"]["args"][0]
     # a quarter of one chip's memory is passed by the weights alone, and
     # what is reserved fits the chip
@@ -205,26 +197,25 @@ def test_the_cell_and_its_metrics_are_entries_of_the_benchmark():
     assert not any(w["chips"] != 1 for w in b["workloads"])
     for name, layer in METRICS.items():
         m = by_name(b["per_layer"], name)
-        # a twin moves what the accepted metric moves
-        moves = by_name(b["per_layer"], TWINS[name])["moves"] \
-            if name in TWINS else "tpot_p50_ms"
-        assert m["moves"] == moves and m["workloads"] == [CELL]
+        assert m["moves"] == ("itl_p95_ms" if name in ITL
+                              else "tpot_p50_ms")
+        # in a list, or every cell's: never in a metric's name
+        if name in EVERY:
+            assert "workloads" not in m
+        else:
+            assert CELL in m["workloads"]
         assert m["layer"] == layer
         spec = readers.load_metric(name, HERE)
         assert (spec["unit"], spec["better"], spec["layer"]) \
             == (m["unit"], m["better"], layer)
         assert m["source"] == ("device_trace" if spec["reader"] == "trace"
                                else "program_counter")
-    # (an accepted entry's list MAY name this cell: since PR 49 a cell is
-    # named in a list and never in a metric's name, and what stays true,
-    # that no two entries read one expression in one cell, is
-    # test_benchmark_lists.py's)
-    # a twin is the accepted metric's expression under a name of its own
-    for name, of in TWINS.items():
-        spec, old = (readers.load_metric(n, HERE) for n in (name, of))
-        assert spec["expr"] == old["expr"], name
-        assert (spec["unit"], spec["better"]) == (old["unit"],
-                                                  old["better"]), name
+    # the one expression with this cut's constants is this cell's alone;
+    # the two-pool readings are Trinity's too
+    assert by_name(b["per_layer"], "device.swa_window_roofline")[
+        "workloads"] == [CELL]
+    assert len(by_name(b["per_layer"], "attn.pools_kv_read_mb")[
+        "workloads"]) == 2
     for m in b["per_layer"]:
         if CELL in m.get("workloads", [CELL]):
             readers.load_metric(m["name"], HERE)
@@ -315,20 +306,20 @@ STEP_BYTES = 966246912 + 148635648 * 41 + KV_BYTES
 
 
 @pytest.mark.parametrize("name,want", [
-    ("attn.swa_kv_read_mb", KV_BYTES / 1e6),
-    ("attn.swa_window_read_share", 100 * 18 / 64),
-    ("attn.swa_kv_pad_share", 100 * (1 - (28e6 * 6144 + 8e6 * 18432)
+    ("attn.pools_kv_read_mb", KV_BYTES / 1e6),
+    ("attn.window_read_share", 100 * 18 / 64),
+    ("attn.pools_kv_pad_share", 100 * (1 - (28e6 * 6144 + 8e6 * 18432)
                                      / (32.768e6 * 6144 + 9.216e6 * 18432))),
-    ("kv.swa_window_pages_held", 17.5),
-    ("kv.swa_pages_released", 0.125),
+    ("kv.window_pages_held", 17.5),
+    ("kv.window_pages_released", 0.125),
     # 7.43 GB / 819e9 = 9.07 ms against a 112 ms window of 8: 64.8 %
     ("device.swa_window_roofline", 100 * (STEP_BYTES / 819e9) / 0.014),
-    ("device.swa_window_step_ms", 14.0),
-    ("device.swa_moe_kernel_share", 100 * 1.2 / 3.0),
-    ("moe.swa_dropped_share", 0.0),
-    ("moe.swa_pad_share", 100 * (1 - 24e5 / 32e5)),
-    ("moe.swa_experts_hit", 59.0),
-    ("moe.swa_window_experts_hit", 41.0),
+    ("device.window_step_ms", 14.0),
+    ("device.moe_kernel_share", 100 * 1.2 / 3.0),
+    ("moe.dropped_share", 0.0),
+    ("moe.pad_share", 100 * (1 - 24e5 / 32e5)),
+    ("moe.experts_hit", 59.0),
+    ("moe.window_experts_hit", 41.0),
     ("step.mixed_period_ms", 36.0),
     ("step.window_period_ms", 16.0),
     ("step.mixed_time_share", 100 * 28.8 / (28.8 + 25.6)),
@@ -336,9 +327,9 @@ STEP_BYTES = 966246912 + 148635648 * 41 + KV_BYTES
     ("host.resume_ms", 0.1),
     ("host.emit_ms", 0.2),
     ("host.submit_ms", 0.3),
-    ("stream.swa_gap_mixed_share", 32.0),
-    ("stream.swa_gap_mixed_ms", 36.0),
-    ("stream.swa_gap_window_ms", 16.0)])
+    ("stream.gap_mixed_share", 32.0),
+    ("stream.gap_mixed_ms", 36.0),
+    ("stream.gap_window_ms", 16.0)])
 def test_the_metric_files_evaluate_on_recorded_sources(name, want):
     ctx = {"prom": (PROM_0, PROM_1), "engine": ({}, {}),
            "peak": {"hbm_bytes_per_s": 819e9},
@@ -437,15 +428,14 @@ def test_rehearsal_of_the_new_cell():
     assert line["attempted"] >= 0 and line["failed"] == 0
     metrics = line["metrics"]
     assert metrics["warmup.compiles_in_window"]["value"] == 0
-    assert 0 < metrics["attn.swa_kv_pad_share"]["value"] < 100
-    assert metrics["attn.swa_kv_read_mb"]["value"] > 0
-    assert 20 < metrics["attn.swa_window_read_share"]["value"] < 45
-    assert 10 <= metrics["kv.swa_window_pages_held"]["value"] <= 25
+    assert 0 < metrics["attn.pools_kv_pad_share"]["value"] < 100
+    assert metrics["attn.pools_kv_read_mb"]["value"] > 0
+    assert 20 < metrics["attn.window_read_share"]["value"] < 45
+    assert 10 <= metrics["kv.window_pages_held"]["value"] <= 25
     assert "device.swa_window_roofline" not in metrics     # no CPU time
-    assert "moe.dropped_share" not in metrics     # another cell's list
     assert "attn.kv_read_mb" not in metrics
-    assert metrics["moe.swa_dropped_share"]["value"] == 0
-    assert 1 <= metrics["moe.swa_experts_hit"]["value"] <= 16
+    assert metrics["moe.dropped_share"]["value"] == 0
+    assert 1 <= metrics["moe.experts_hit"]["value"] <= 16
     with open(os.path.join(ROOT, "chiprun_out", "benchmark", CELL,
                            f"s{2**31 + 17}-t1", "run.json")) as f:
         side = json.load(f)

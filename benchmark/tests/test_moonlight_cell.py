@@ -35,13 +35,11 @@ PUBLISHED = {
     "topk_method": "noaux_tc", "v_head_dim": 128, "vocab_size": 163840}
 SOURCE = ("https://huggingface.co/moonshotai/Moonlight-16B-A3B/blob/main/"
           "config.json")
-# the accepted metrics whose lists of cells name OLMoE's or the dense cells
-# alone, twinned for this cell under names of its own
-TWINS = {"moe.mla_dropped_share": "moe.dropped_share",
-         "moe.mla_pad_share": "moe.pad_share",
-         "moe.mla_experts_hit": "moe.experts_hit",
-         "device.mla_moe_kernel_share": "device.moe_kernel_share",
-         "device.mla_window_step_ms": "device.window_step_ms"}
+# the accepted metrics whose lists name this cell since PR 54 (until then
+# each was twinned for it under a `mla_` name of its own)
+SHARED = {"moe.dropped_share", "moe.pad_share", "moe.experts_hit",
+          "device.moe_kernel_share", "moe.window_experts_hit",
+          "attn.kv_pad_share", "attn.kv_read_mb"}
 
 
 def load(*parts):
@@ -119,7 +117,7 @@ def test_the_sizes_are_the_arithmetic_of_the_file_beside_them():
     assert weights["args"][0] == {"const": fixed}
     assert weights["args"][1]["args"][0] == {"const": per_hit}
     assert weights["args"][1]["args"][1] == load(
-        "layer_metrics", "moe.mla_window_experts_hit.json")["expr"]
+        "layer_metrics", "moe.window_experts_hit.json")["expr"]
     # slots a step x bytes a token, as attn.kv_read_mb has them
     assert latents == load("layer_metrics", "attn.kv_read_mb.json")[
         "expr"]["args"][0]
@@ -140,30 +138,17 @@ def test_the_cell_and_its_metrics_are_entries_of_the_benchmark():
     assert config["source"] == SOURCE
     assert len(config["why"]) <= 200 and len(cell["why"]) <= 200
     # by name, never by position: later PRs append, a benchmark PR folds
-    mine = {m["name"]: m for m in b["per_layer"]
-            if m["name"] in {"attn.kv_pad_share", "attn.kv_read_mb",
-                             "device.mla_window_roofline",
-                             "moe.mla_window_experts_hit", *TWINS}}
-    assert len(mine) == len(TWINS) + 4
-    for m in mine.values():
-        assert m["moves"] == "tpot_p50_ms" and m["workloads"] == [CELL]
-    assert mine["attn.kv_pad_share"]["layer"] == "attention"
-    assert mine["device.mla_window_roofline"]["layer"] == "device programs"
-    # (an accepted entry's list MAY name this cell: since PR 49 a cell is
-    # named in a list and never in a metric's name, and what stays true,
-    # that no two entries read one expression in one cell, is
-    # test_benchmark_lists.py's)
-    accepted = {m["name"]: m for m in b["per_layer"]
-                if m["name"] not in mine}
-    # a twin is the accepted metric's expression and entry under a name
-    # of its own: the MoE block's and the window's readings in this cell
-    for name, of in TWINS.items():
-        spec, old = (readers.load_metric(n, HERE) for n in (name, of))
-        assert spec["expr"] == old["expr"]
-        assert {k: v for k, v in mine[name].items()
-                if k not in ("name", "workloads")} \
-            == {k: v for k, v in accepted[of].items()
-                if k not in ("name", "workloads")}
+    listed = {m["name"]: m for m in b["per_layer"]}
+    own = listed["device.mla_window_roofline"]
+    assert own["workloads"] == [CELL] and own["layer"] == "device programs"
+    # since PR 49 a cell is named in a list and never in a metric's name:
+    # the MoE block's, the window's and the attention's readings in this
+    # cell are the accepted entries', whose lists name it
+    for name in SHARED:
+        assert CELL in listed[name]["workloads"], name
+        assert listed[name]["moves"] == "tpot_p50_ms"
+    assert "workloads" not in listed["device.window_step_ms"]
+    assert listed["attn.kv_pad_share"]["layer"] == "attention"
     for m in b["per_layer"]:
         if CELL in m.get("workloads", [CELL]):
             readers.load_metric(m["name"], HERE)
@@ -224,12 +209,12 @@ STEP_BYTES = 1336237056 + 138412032 * 35 + 32e6 / 1000 * 10368
     ("attn.kv_read_mb", 32e6 / 1000 * 10368 / 1e6),
     # 6.51 GB / 819e9 = 7.95 ms against a 128 ms window of 8: 49.7 %
     ("device.mla_window_roofline", 100 * (STEP_BYTES / 819e9) / 0.016),
-    ("device.mla_window_step_ms", 16.0),
-    ("device.mla_moe_kernel_share", 100 * 1.2 / 3.0),
-    ("moe.mla_dropped_share", 0.0),
-    ("moe.mla_pad_share", 100 * (1 - 24e5 / 32e5)),
-    ("moe.mla_experts_hit", 59.0),
-    ("moe.mla_window_experts_hit", 35.0)])
+    ("device.window_step_ms", 16.0),
+    ("device.moe_kernel_share", 100 * 1.2 / 3.0),
+    ("moe.dropped_share", 0.0),
+    ("moe.pad_share", 100 * (1 - 24e5 / 32e5)),
+    ("moe.experts_hit", 59.0),
+    ("moe.window_experts_hit", 35.0)])
 def test_the_metric_files_evaluate_on_recorded_sources(name, want):
     ctx = {"prom": (PROM_0, PROM_1), "engine": ({}, {}),
            "peak": {"hbm_bytes_per_s": 819e9},
@@ -345,16 +330,15 @@ def test_rehearsal_of_the_new_cell():
     assert 0 < metrics["attn.kv_pad_share"]["value"] < 100
     assert metrics["attn.kv_read_mb"]["value"] > 0
     assert "device.mla_window_roofline" not in metrics     # no CPU time
-    assert "moe.dropped_share" not in metrics     # OLMoE's list, not ours
-    assert metrics["moe.mla_dropped_share"]["value"] == 0
-    assert 1 <= metrics["moe.mla_experts_hit"]["value"] <= 16
-    assert 0 <= metrics["moe.mla_pad_share"]["value"] < 100
+    assert metrics["moe.dropped_share"]["value"] == 0
+    assert 1 <= metrics["moe.experts_hit"]["value"] <= 16
+    assert 0 <= metrics["moe.pad_share"]["value"] < 100
     # six seconds on the CPU admit prompts and reach no decode window: the
     # window's own reading (0 / 0 layer calls) is left out, as the
     # roofline is; where one ran, it touches no more experts than a chunk
-    hit = metrics.get("moe.mla_window_experts_hit")
+    hit = metrics.get("moe.window_experts_hit")
     assert hit is None or 1 <= hit["value"] \
-        <= metrics["moe.mla_experts_hit"]["value"]
+        <= metrics["moe.experts_hit"]["value"]
     with open(os.path.join(ROOT, "chiprun_out", "benchmark", CELL,
                            f"s{2**31 + 17}-t1", "run.json")) as f:
         side = json.load(f)

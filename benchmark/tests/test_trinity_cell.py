@@ -25,26 +25,22 @@ NAME = "trinity-mini"
 CELL = NAME + ".context-closed"
 SOURCE = "https://huggingface.co/arcee-ai/Trinity-Mini/blob/main/config.json"
 CUT = {"num_hidden_layers", "num_dense_layers", "layer_types"}
-# name -> (layer, the accepted metric whose expression it is)
+# name -> layer. But for the roofline, whose constants are this cut's,
+# each is an accepted metric whose list names this cell since PR 54 (it
+# read each under an `afm_` name of its own until then); the window step,
+# the step periods and the host loop are every cell's (no `workloads` key)
 METRICS = {
-    "attn.afm_kv_read_mb": ("attention", "attn.swa_kv_read_mb"),
-    "attn.afm_window_read_share": ("attention",
-                                   "attn.swa_window_read_share"),
-    "attn.afm_kv_pad_share": ("attention", "attn.swa_kv_pad_share"),
-    "kv.afm_window_pages_held": ("scheduler", "kv.swa_window_pages_held"),
-    "kv.afm_pages_released": ("scheduler", "kv.swa_pages_released"),
-    "device.afm_window_step_ms": ("device programs",
-                                  "device.window_step_ms"),
-    "device.afm_window_roofline": ("device programs", None),
-    "moe.afm_experts_hit": ("MoE dispatch", "moe.experts_hit"),
-    "moe.afm_window_experts_hit": ("MoE dispatch",
-                                   "moe.mla_window_experts_hit"),
-    "moe.afm_pad_share": ("MoE dispatch", "moe.pad_share"),
-    "moe.afm_dropped_share": ("MoE dispatch", "moe.dropped_share"),
-    "device.afm_moe_kernel_share": ("MoE dispatch",
-                                    "device.moe_kernel_share")}
-# the step periods and the host loop are every cell's since PR 49 (their
-# entries have no `workloads` key): the three `step.afm_*` copies went
+    "attn.pools_kv_read_mb": "attention",
+    "attn.window_read_share": "attention",
+    "attn.pools_kv_pad_share": "attention",
+    "kv.window_pages_held": "scheduler",
+    "kv.window_pages_released": "scheduler",
+    "device.afm_window_roofline": "device programs",
+    "moe.experts_hit": "MoE dispatch",
+    "moe.window_experts_hit": "MoE dispatch",
+    "moe.pad_share": "MoE dispatch",
+    "moe.dropped_share": "MoE dispatch",
+    "device.moe_kernel_share": "MoE dispatch"}
 
 
 def load(*parts):
@@ -160,10 +156,10 @@ def test_the_sizes_are_the_arithmetic_of_the_file_beside_them():
     assert weights["args"][0] == {"const": fixed}
     assert weights["args"][1]["args"][0] == {"const": per_hit}
     assert weights["args"][1]["args"][1] == readers.load_metric(
-        "moe.afm_window_experts_hit", HERE)["expr"]
-    # KV by kind, as attn.afm_kv_read_mb has it
+        "moe.window_experts_hit", HERE)["expr"]
+    # KV by kind, as attn.pools_kv_read_mb has it
     assert kv == readers.load_metric(
-        "attn.afm_kv_read_mb", HERE)[
+        "attn.pools_kv_read_mb", HERE)[
         "expr"]["args"][0]
     # but for the two constants it is Mellum's expression
     swa = json.dumps(load("layer_metrics",
@@ -206,24 +202,20 @@ def test_the_cell_and_its_metrics_are_entries_of_the_benchmark():
     assert config["source"] == SOURCE
     assert config["file"] == f"benchmark/configs/{NAME}/config.json"
     assert len(config["why"]) <= 200 and len(cell["why"]) <= 200
-    for name, (layer, of) in METRICS.items():
+    for name, layer in METRICS.items():
         m = by_name(b["per_layer"], name)
         assert CELL in m["workloads"] and m["layer"] == layer
+        assert m["moves"] == "tpot_p50_ms"
         spec = readers.load_metric(name, HERE)
         assert (spec["unit"], spec["better"], spec["layer"],
                 spec["moves"]) == (m["unit"], m["better"], layer,
                                    m["moves"])
         assert m["source"] == ("device_trace" if spec["reader"] == "trace"
                                else "program_counter")
-        if of is None:
-            continue
-        # a twin is the accepted metric's expression under a name of its
-        # own, and moves what that metric moves
-        old = readers.load_metric(of, HERE)
-        assert spec["expr"] == old["expr"], name
-        assert (spec["unit"], spec["better"]) == (old["unit"],
-                                                  old["better"]), name
-        assert m["moves"] == by_name(b["per_layer"], of)["moves"]
+    assert by_name(b["per_layer"], "device.afm_window_roofline")[
+        "workloads"] == [CELL]
+    assert "workloads" not in by_name(b["per_layer"],
+                                      "device.window_step_ms")
     # every metric this cell reports has its file
     for m in b["per_layer"]:
         if CELL in m.get("workloads", [CELL]):
@@ -297,20 +289,20 @@ STEP_BYTES = 1220633088 + 50331648 * 50 + KV_BYTES
 
 
 @pytest.mark.parametrize("name,want", [
-    ("attn.afm_kv_read_mb", KV_BYTES / 1e6),
-    ("attn.afm_window_read_share", 100 * 34 / 64),
-    ("attn.afm_kv_pad_share", 100 * (1 - (28e6 * 2048 + 15e6 * 8192)
+    ("attn.pools_kv_read_mb", KV_BYTES / 1e6),
+    ("attn.window_read_share", 100 * 34 / 64),
+    ("attn.pools_kv_pad_share", 100 * (1 - (28e6 * 2048 + 15e6 * 8192)
                                      / (32.768e6 * 2048 + 17.408e6 * 8192))),
-    ("kv.afm_window_pages_held", 33.5),
-    ("kv.afm_pages_released", 0.125),
+    ("kv.window_pages_held", 33.5),
+    ("kv.window_pages_released", 0.125),
     # 3.95 GB / 819e9 = 4.8 ms against a 64 ms window of 8: 60 %
     ("device.afm_window_roofline", 100 * (STEP_BYTES / 819e9) / 0.008),
-    ("device.afm_window_step_ms", 8.0),
-    ("device.afm_moe_kernel_share", 100 * 1.2 / 3.0),
-    ("moe.afm_dropped_share", 0.0),
-    ("moe.afm_pad_share", 100 * (1 - 24e5 / 32e5)),
-    ("moe.afm_experts_hit", 120.0),
-    ("moe.afm_window_experts_hit", 50.0),
+    ("device.window_step_ms", 8.0),
+    ("device.moe_kernel_share", 100 * 1.2 / 3.0),
+    ("moe.dropped_share", 0.0),
+    ("moe.pad_share", 100 * (1 - 24e5 / 32e5)),
+    ("moe.experts_hit", 120.0),
+    ("moe.window_experts_hit", 50.0),
     ("step.mixed_period_ms", 30.0),
     ("step.window_period_ms", 10.0),
     ("step.mixed_time_share", 100 * 24.0 / (24.0 + 16.0))])
@@ -460,14 +452,14 @@ def test_rehearsal_of_the_new_cell():
     assert line["attempted"] >= 0 and line["failed"] == 0
     metrics = line["metrics"]
     assert metrics["warmup.compiles_in_window"]["value"] == 0
-    assert 0 < metrics["attn.afm_kv_pad_share"]["value"] < 100
-    assert metrics["attn.afm_kv_read_mb"]["value"] > 0
-    assert 45 < metrics["attn.afm_window_read_share"]["value"] < 60
-    assert 20 <= metrics["kv.afm_window_pages_held"]["value"] <= 41
-    assert metrics["kv.afm_pages_released"]["value"] > 0
+    assert 0 < metrics["attn.pools_kv_pad_share"]["value"] < 100
+    assert metrics["attn.pools_kv_read_mb"]["value"] > 0
+    assert 45 < metrics["attn.window_read_share"]["value"] < 60
+    assert 20 <= metrics["kv.window_pages_held"]["value"] <= 41
+    assert metrics["kv.window_pages_released"]["value"] > 0
     assert "device.afm_window_roofline" not in metrics     # no CPU time
-    assert metrics["moe.afm_dropped_share"]["value"] == 0
-    assert 1 <= metrics["moe.afm_experts_hit"]["value"] <= 16
+    assert metrics["moe.dropped_share"]["value"] == 0
+    assert 1 <= metrics["moe.experts_hit"]["value"] <= 16
     assert metrics["step.mixed_period_ms"]["value"] > 0
     with open(os.path.join(ROOT, "chiprun_out", "benchmark", CELL,
                            f"s{2**31 + 17}-t1", "run.json")) as f:
