@@ -215,6 +215,20 @@ class ModelConfig:
     # per-sequence state is the last K - 1 rows of B * u (`state_leaves`:
     # ONE leaf, `conv_tail`).
     conv_l_cache: int = 0
+    # Power-retention layers (Brumby; arXiv:2507.04239). `retention_degree`
+    # p > 0 switches them on (2 is what is modelled), for EVERY layer
+    # (layer kind "ret" in `layer_kinds`): the block, q | k | v
+    # projections, `qk_norm` and RoPE are the softmax model's own, and the
+    # mixer is o_t[h] = sum_i w[t, i] v_i / sum_i w[t, i] with w[t, i] =
+    # (g_{i+1} .. g_t) (q_t[h] . k_i)^p, g = sigmoid(xn W_g + b_g) a
+    # key-value head and token (leaves `ret_wg` [D, Hkv], `ret_bg` [Hkv]),
+    # no softmax, no scale, no output gate. Such a layer holds NO pages: its whole context is a
+    # per-sequence state (`state_leaves`), the [Hkv, head_dim, F] float32
+    # matrix and the [Hkv, F] normaliser over the F = `retention_features`
+    # degree-2 features of a key (ops/power_retention.phi), constant in
+    # the context's length. A model of such layers alone has no paged
+    # cache at all (`num_cache_layers` 0, `kv_cache_leaves` empty).
+    retention_degree: int = 0
     # what the router adds to the sum of the k kept weights before it
     # divides by it (`norm_topk_prob`): the published constant of the
     # family (DeepSeek-V3's 1e-20; LFM2's 1e-6)
@@ -297,11 +311,24 @@ class ModelConfig:
         return self.conv_l_cache > 0
 
     @property
+    def has_retention(self) -> bool:
+        """Power-retention layers, every layer (`retention_degree`)."""
+        return self.retention_degree > 0
+
+    @property
+    def retention_features(self) -> int:
+        """F: the degree-2 features of one head_dim-vector as the state
+        holds them (ops/power_retention.features: 8320 at 128)."""
+        return self.head_dim * (self.head_dim // 2 + 1)
+
+    @property
     def has_state(self) -> bool:
         """Holds a recurrent state a sequence (`state_leaves`): linear-
-        attention layers, a state-space mixer beside attention, or gated
-        short-convolution layers (whose state is their tail alone)."""
-        return self.linear_group_size > 0 or self.has_ssm or self.has_conv
+        attention layers, a state-space mixer beside attention, gated
+        short-convolution layers (whose state is their tail alone), or
+        power-retention layers (whose state is all they hold)."""
+        return self.linear_group_size > 0 or self.has_ssm \
+            or self.has_conv or self.has_retention
 
     @property
     def mamba_conv_dim(self) -> int:
@@ -316,9 +343,12 @@ class ModelConfig:
         a state-space mixer side by side: the one kind that lies on the
         paged cache's layer axis and on the state's) | "conv" (a gated
         short convolution: no pages, a tail on the state's axis; given by
-        `layer_types`, as the sliding layers are). A model without
-        linear layers, conv layers and a window pool is one kind
-        throughout."""
+        `layer_types`, as the sliding layers are) | "ret" (power
+        retention: no pages, a matrix state on the state's axis; every
+        layer of a model that has any). A model without linear layers,
+        conv layers and a window pool is one kind throughout."""
+        if self.has_retention:
+            return ("ret",) * self.num_layers
         own = "par" if self.has_ssm else "mla" if self.is_mla else "mha"
         g = self.linear_group_size
         if self.has_conv:
@@ -350,7 +380,7 @@ class ModelConfig:
 
     @property
     def num_state_layers(self) -> int:
-        return sum(kind in ("kda", "par", "conv")
+        return sum(kind in ("kda", "par", "conv", "ret")
                    for kind in self.layer_kinds())
 
     @property
@@ -368,7 +398,14 @@ class ModelConfig:
         block's: `ssm_s`, the mixer's [heads, d_head, d_state] matrix,
         float32 likewise, and `ssm_conv`, the last d_conv - 1 inputs of
         the x | B | C convolution. A conv layer's: `conv_tail` alone, the
-        last conv_l_cache - 1 rows of B * u, in the model's dtype."""
+        last conv_l_cache - 1 rows of B * u, in the model's dtype. A
+        power-retention layer's: `ret_s`, the [Hkv, head_dim, F] matrix,
+        values-major so that the features lie on the lanes, and `ret_z`,
+        the [Hkv, F] normaliser, float32 both."""
+        if self.has_retention:
+            hkv, f = self.num_kv_heads, self.retention_features
+            return {"ret_s": ((hkv, self.head_dim, f), "float32"),
+                    "ret_z": ((hkv, f), "float32")}
         if self.has_conv:
             return {"conv_tail": ((self.conv_l_cache - 1, self.hidden_size),
                                   self.dtype)}
@@ -406,8 +443,11 @@ class ModelConfig:
         against: a single head of kv_lora_rank + qk_rope_head_dim whose
         first kv_lora_rank columns are also the values, stored
         `kv_row_lanes` wide where an engine resolved that (the lanes past
-        `latent_width` are zeros). init_cache, the shardings, the
+        `latent_width` are zeros). Empty for a model none of whose layers
+        holds a page (power retention). init_cache, the shardings, the
         page-byte gauges and `step_attention_rows` read this."""
+        if not self.num_cache_layers:
+            return {}
         if self.is_mla:
             return {"k": (1, self.latent_width + self.kv_row_pad)}
         return {"k": self._kv_row, "v": self._kv_row}
@@ -508,7 +548,7 @@ def kv_heads_per_row(cfg: ModelConfig, tp: int = 1) -> int:
     one head: `kv_row_lanes` below widens ITS row), or a pool that
     `_rows_as_published`."""
     hd = cfg.head_dim
-    if not 0 < hd < LANES or LANES % hd:
+    if not 0 < hd < LANES or LANES % hd or not cfg.num_cache_layers:
         return 1
     f = LANES // hd
     if (cfg.is_mla or _rows_as_published(cfg)
@@ -534,6 +574,8 @@ def kv_row_lanes(cfg: ModelConfig, tp: int = 1) -> int:
     `kv_page_bytes` does. The model's own width for a pool that
     `_rows_as_published` (no engine serves a latent cache that way yet,
     `refuse_unserved`; the reasons are the forms' own)."""
+    if not cfg.num_cache_layers:
+        return 0        # no layer holds a page: no row is stored
     if not cfg.is_mla:
         return kv_heads_per_row(cfg, tp) * cfg.head_dim
     if _rows_as_published(cfg):
@@ -798,7 +840,12 @@ def refuse_unserved(model_cfg: ModelConfig,
     }
     keeper = "a state-space mixer beside attention keeps" if cfg.has_ssm \
         else "gated short-convolution layers keep a convolution tail," \
-        if cfg.has_conv else "linear-attention layers keep"
+        if cfg.has_conv else "power-retention layers keep their whole " \
+        "context in" if cfg.has_retention else "linear-attention layers keep"
+    # what was only ever about pages has nothing to act on where no layer
+    # holds one, and says that instead of its own reason
+    pageless = {} if cfg.num_cache_layers else dict.fromkeys(
+        ("kv_quant", "decode_kernel"), "no layer of it holds a page")
     stores = (
         (cfg.has_state,
          f"{keeper} a recurrent state a sequence "
@@ -811,9 +858,10 @@ def refuse_unserved(model_cfg: ModelConfig,
          f"{cfg.sliding_window} tokens in a page pool of their own"),
     )
     for column, (held, opening) in enumerate(stores, start=1):
-        why = [asked[row[0]] + (f": {row[column]}" if row[column] else "")
+        why = [asked[row[0]] + (f": {reason}" if reason else "")
                for row in UNSERVED
-               if asked[row[0]] and row[column] is not None]
+               for reason in [pageless.get(row[0], row[column])]
+               if asked[row[0]] and reason is not None]
         if held and why:
             raise ValueError(f"{cfg.name}: {opening}; not served with it "
                              f"yet: " + "; ".join(why))

@@ -199,6 +199,9 @@ class NativeEngine:
         # one slot a decode slot, and one a row of a prefill batch (a
         # sequence has pages from its first chunk and a decode slot only
         # at its last)
+        # whether any layer holds pages (none of a power-retention model's
+        # does: no pool leaf, no base to gather, no `attn_*` series)
+        self._paged = model_cfg.num_cache_layers > 0
         self._state_slots = 0
         if model_cfg.has_state:
             self._state_slots = engine_cfg.max_slots \
@@ -295,7 +298,9 @@ class NativeEngine:
         self.ledger = StepLedger()
         self.ledger.stats.kv_bytes_per_token = model_cfg.kv_bytes_per_token()
         self.ledger.stats.kv_heads_per_row = model_cfg.kv_row_heads
-        self.ledger.stats.kv_row_lanes = model_cfg.kv_cache_leaves()["k"][1]
+        # (0 lanes: no layer holds a page, so no row is stored)
+        self.ledger.stats.kv_row_lanes = model_cfg.kv_cache_leaves().get(
+            "k", (0, 0))[1]
         self.ledger.stats.kv_bytes_per_token_full = \
             model_cfg.kv_bytes_per_token()
         self.ledger.stats.kv_bytes_per_token_window = \
@@ -1257,13 +1262,15 @@ class NativeEngine:
                          flat: bool = False) -> None:
         """`llm_engine_linattn_*_total`, the series of a recurrent state
         whatever layer keeps it (linear attention; the parallel block's
-        state-space mixer), from a step's plan on the host:
+        state-space mixer; a conv layer's tail; power retention's matrix
+        and normaliser), from a step's plan on the host:
         the (token, state layer) state updates of its real tokens,
         which of them rode an `_engine_step` (`window_steps` 0; the
         chunkwise form's, and the one-token rows beside them), which
         were made where the state rests (`kda_step_slots`,
-        `ssd_step_slots`: every token of a decode window, and the
-        `inplace` one-token rows of a step that `llama.mix_splits`),
+        `ssd_step_slots`, `retention_step_slots`: every token of a decode
+        window, and the `inplace` one-token rows of a step that
+        `llama.mix_splits`),
         whether such a step's state layers worked over a compact step's
         `flat` token rows (`llama.kda_mix_rows`, `ssm_mix_rows` where
         `_dense_rows` is the flat width; else
@@ -1300,7 +1307,11 @@ class NativeEngine:
         the FULL pool's tables; `llm_engine_attn_kv_window_tokens_total`
         / `_window_slots_total` count the window pool's, for the layers
         that read it (`_window_reads`): window slots / slots is the share
-        of a full-length gather that a window layer's gather is."""
+        of a full-length gather that a window layer's gather is. None of
+        them moves for a model none of whose layers holds a page: nothing
+        attends and nothing is gathered."""
+        if not self._paged:
+            return
         stats = self.ledger.stats
         stats.attn_kv_tokens_total += kv_tokens
         stats.attn_kv_slots_total += table_pages * self.cfg.page_size
@@ -1813,6 +1824,10 @@ class NativeEngine:
         base_pages = max(1, int(-(-int(base_lens.max()) // ps)))
         base_pb = min(next_bucket(base_pages, self.scheduler.page_buckets),
                       plan.page_table.shape[1])
+        if not self._paged:
+            # no layer gathers a base: the live-KV bucket names no program
+            # of such a model, and its base table keeps the plan's width
+            base_pb = plan.page_table.shape[1]
         sig = (tuple((s.request_id, s.epoch) if s else None
                      for s in plan.seqs),
                tuple((len(s.pages), s.wfirst, len(s.wpages)) if s else 0
@@ -3436,14 +3451,21 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
     else:
         eos_vec = None
 
-    l, hkv_n, n_pages, ps, hd = cache["k"].shape  # dynalint: kv-codec
+    # a model none of whose layers holds a page (power retention) has no
+    # pool leaf: its window has no base, no buffer and no writeback, and
+    # its whole context rides the state leaves below
+    paged = "k" in cache
+    if paged:
+        l, hkv_n, n_pages, ps, hd = cache["k"].shape  # dynalint: kv-codec
     kvq = bool(cfg.kv_quant)
     # the Pallas-kernel decode path streams pages from the global cache
     # itself — it keeps the original carry-the-cache window (per-step
     # scatter); the split-KV fast path applies to the XLA gather mode
     pregather = llama._decode_kernel_mode(cfg) is None
 
-    if pregather:
+    if not paged:
+        kb = vb = kw0 = vw0 = None
+    elif pregather:
         base_pb = base_table.shape[1]
         lb = base_pb * page_size
 
@@ -3570,11 +3592,11 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
         prefix = jnp.clip(pos, 0, max_pos + 1)
         # tokens written in-window so far; window index j == step index
         # (all slots step together), valid entries are j < win_len
-        win_len = prefix - base_len
+        win_len = prefix - base_len if paged else None
         logits, k_news, v_news, aux, *more = llama.decode_forward(
             params, cfg, tok, cache, page_table, prefix, pos,
             valid=writable, mesh=kernel_mesh, with_aux=True,
-            window=(kb, vb, kw, vw, base_len, win_len),
+            window=(kb, vb, kw, vw, base_len, win_len) if paged else None,
             state=(state, state_slots) if state_keys else None,
             swa=None if swa_win is None
             else (wkb, wvb, *swa_win, base_len - woff))
@@ -3596,9 +3618,10 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
         # The global-cache slot for the end-of-window writeback is
         # tracked separately (dropped rows get index -1).
         with jax.named_scope("kv.window"):
-            kw = jax.lax.dynamic_update_index_in_dim(
-                kw, k_news.transpose(0, 2, 1, 3).astype(kw.dtype), t,
-                axis=3)
+            if kw is not None:
+                kw = jax.lax.dynamic_update_index_in_dim(
+                    kw, k_news.transpose(0, 2, 1, 3).astype(kw.dtype), t,
+                    axis=3)
             if vw is not None:
                 vw = jax.lax.dynamic_update_index_in_dim(
                     vw, v_news.transpose(0, 2, 1, 3).astype(vw.dtype), t,
@@ -3608,7 +3631,7 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
         return (kw, vw, state, swa_win, nxt, pos + 1, ctr + 1, seen,
                 alive), \
             (nxt, lp, top_ids, top_lps, aux, k_news, v_news,
-             global_write_idx(pos, writable), w_out)
+             global_write_idx(pos, writable) if paged else None, w_out)
 
     alive0 = max_pos >= 0
     if not pregather:
@@ -3635,7 +3658,7 @@ def _engine_decode_window(cfg: ModelConfig, eos_ids: tuple, kernel_mesh,
             1, 0, 2, 3, 4).reshape(-1, n_steps * s, hkv_n, hd)
 
     pools = _scatter_new_kv(cache, flat_rows(k_all), flat_rows(v_all),
-                            widx_all.reshape(-1))
+                            widx_all.reshape(-1)) if paged else {}
     if w_all:
         # the window layers' rows -> the window pool, the same way
         wk_all, wv_all, wwidx_all = w_all

@@ -8,7 +8,9 @@ beside softmax attention in every layer, with its muP multipliers:
 `_ssm_front`, `ssm_decode`, `ssm_mix_rows`, `_times`; and LFM2's gated
 short-convolution layers, a kind by `layer_types` that holds a tail and no
 pages, served by the period loop: `_conv_front`, `conv_decode`,
-`conv_mix_rows`).
+`conv_mix_rows`; and Brumby's power-retention layers, a kind that holds
+its whole context in a matrix state and no page at all: `_ret_front`,
+`ret_decode`, `ret_mix_rows`).
 
 Functional JAX, TPU-first:
 - parameters are a pytree of arrays **stacked over layers** and the layer loop
@@ -52,6 +54,9 @@ from dynamo_tpu.ops.linear_attention import (
     conv_one_token, conv_with_tail, kda_chunk, kda_step_slots, l2_normalize,
 )
 from dynamo_tpu.ops.kv_quant import validate_mode as _validate_kv_quant
+from dynamo_tpu.ops.power_retention import (
+    retention_chunk, retention_step_slots,
+)
 from dynamo_tpu.ops.state_space import ssd_chunk, ssd_step_slots
 from dynamo_tpu.ops.moe import (
     moe_dispatch_mlp, moe_dispatch_mlp_sharded, moe_dropless_mlp, route,
@@ -147,7 +152,7 @@ class LayerRun(NamedTuple):
     first: int        # the run's first layer, in the model's order
     count: int
     dense: bool       # a dense MLP (False: experts)
-    # attention kind: "mha" | "mla" | "kda" | "swa" | "par" | "conv"
+    # attention kind: "mha" | "mla" | "kda" | "swa" | "par" | "conv" | "ret"
     kind: str
     # the run's first layer among the layers that share its STORE: the
     # paged cache's layer axis runs over the "mha" / "mla" layers, the
@@ -200,7 +205,8 @@ def layer_runs(cfg: ModelConfig) -> tuple:
     all walk this."""
     lead = cfg.first_dense_layers if cfg.is_moe else 0
     kinds = cfg.layer_kinds()
-    own = "par" if cfg.has_ssm else "mla" if cfg.is_mla else "mha"
+    own = "ret" if cfg.has_retention else "par" if cfg.has_ssm \
+        else "mla" if cfg.is_mla else "mha"
     seen = dict.fromkeys(kinds, 0)
 
     def like_runs(kinds, prefix):
@@ -402,6 +408,22 @@ def _init_layer_stack(keys, cfg: ModelConfig, l: int, dense_mlp: bool,
         if kind == "par":
             layers.update(_init_ssm_leaves(
                 jax.random.fold_in(keys[0], 91), cfg, l, near_one))
+        if kind == "ret":
+            # the gate g = sigmoid(xn W_g + b_g) a key-value head: each
+            # head's bias drawn so that its memory 1 / (1 - g) is log-
+            # uniform over 10 .. 1000 tokens (g 0.9 .. 0.999), the token's
+            # own part moving the logit by half a unit: a gate of all ones
+            # would hide a path that dropped it, and one that forgot
+            # within a few tokens a state older than a chunk
+            kg = jax.random.split(jax.random.fold_in(keys[0], 113), 2)
+            tau = jnp.exp(jax.random.uniform(
+                kg[1], (l, hkv), jnp.float32, math.log(10.0),
+                math.log(1000.0)))
+            layers.update({
+                "ret_wg": (0.5 * jax.random.normal(
+                    kg[0], (l, d, hkv), jnp.float32) * d ** -0.5).astype(dt),
+                "ret_bg": jnp.log(tau - 1.0),
+            })
         if cfg.attn_out_gate:
             layers["w_out_gate"] = dense(jax.random.fold_in(more[5], 5),
                                          (l, d, h * hd), d)
@@ -613,6 +635,10 @@ def _layer_stack_shardings(cfg: ModelConfig, dense_mlp: bool,
             layers.update({name: P(None, None) for name in (
                 "ssm_conv_b", "ssm_a_log", "ssm_d", "ssm_dt_bias",
                 "ssm_norm")})
+        if kind == "ret":
+            # no mesh serves a recurrent state: the gate is replicated
+            layers.update({"ret_wg": P(None, None, None),
+                           "ret_bg": P(None, None)})
         if cfg.attn_out_gate:
             layers["w_out_gate"] = P(None, None, "tp")
     if cfg.post_norms:
@@ -688,7 +714,8 @@ def init_cache(cfg: ModelConfig, num_pages: int, page_size: int,
     `cfg.kv_row_heads` adjacent kv heads of head_dim each (one, unless
     an engine resolved otherwise); and, for a model with a window pool,
     a leaf per entry of `cfg.window_cache_leaves()` over ITS layers and
-    `window_pages` pages."""
+    `window_pages` pages. No leaf at all for a model none of whose layers
+    holds a page (power retention): its context is `init_state`'s."""
     shapes = {name: (cfg.num_cache_layers, heads, num_pages, page_size,
                      width)
               for name, (heads, width) in cfg.kv_cache_leaves().items()}
@@ -1412,10 +1439,12 @@ def _kda_out(o: jax.Array, x: jax.Array, lp: Params,
 
 def mix_splits(cfg: ModelConfig, rows: int, tq: int) -> bool:
     """Whether a [rows, tq] step's state layers work over its rows by what
-    each holds: the state-space mixer and the short convolution always
-    (`ssm_mix_rows`, `conv_mix_rows`: each its mixer's one form), the
-    linear layers where `kda_mix_splits`."""
-    return cfg.has_ssm or cfg.has_conv or kda_mix_splits(rows, tq)
+    each holds: the state-space mixer, the short convolution and power
+    retention always (`ssm_mix_rows`, `conv_mix_rows`, `ret_mix_rows`:
+    each its mixer's one form), the linear layers where
+    `kda_mix_splits`."""
+    return cfg.has_ssm or cfg.has_conv or cfg.has_retention \
+        or kda_mix_splits(rows, tq)
 
 
 def _ssm_front(x: jax.Array, lp: Params, cfg: ModelConfig):
@@ -1675,6 +1704,142 @@ def conv_mix_rows(state: tuple, l, slots: jax.Array, lp: Params,
     return conv_tail, o
 
 
+# -- power retention ----------------------------------------------------------
+
+# how many of a step's chunk rows `ret_mix_rows` takes through the
+# chunkwise form at a time. One: a row's state is 34 MB a layer at the
+# served size, and the group's states are the form's one copy of them
+RET_GROUP_ROWS = 1
+
+
+def _ret_front(x: jax.Array, lp: Params, cfg: ModelConfig,
+               positions: jax.Array):
+    """A power-retention layer's token-wise front half: x [B, T, D],
+    positions [B, T] -> (q [B, T, H, hd], k, v [B, T, Hkv, hd], log_g [B,
+    T, Hkv]), float32: the block's norm, the softmax model's own q | k | v
+    projections, head norms and RoPE (`qkv_proj`, `rope_table`), and the
+    gate log g = log_sigmoid(xn W_g + b_g), in (-inf, 0), a key-value
+    head."""
+    b, t = x.shape[:2]
+    f32 = jnp.float32
+    with jax.named_scope("norm.attn"):
+        xn = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps,
+                      cfg.norm_plus_one)
+    q, k, v = qkv_proj(xn, lp, cfg)
+    rope = rope_table(cfg, "ret")
+
+    def heads_of(a, n):
+        a = a.reshape(b, t, n, cfg.head_dim)
+        return a if rope is None else apply_rope(a, positions, *rope)
+    q, k = heads_of(q, cfg.num_heads), heads_of(k, cfg.num_kv_heads)
+    with jax.named_scope("retention.front"):
+        log_g = jax.nn.log_sigmoid(jnp.einsum(
+            "btd,dc->btc", xn, wmat(lp["ret_wg"], xn.dtype)).astype(f32)
+            + lp["ret_bg"].astype(f32))
+        return q.astype(f32), k.astype(f32), \
+            v.reshape(b, t, cfg.num_kv_heads, cfg.head_dim).astype(f32), log_g
+
+
+def ret_decode(state: tuple, l, slots: jax.Array, q, k, v, log_g, valid,
+               fresh=None):
+    """The mixer for one token a row (a decode step's rows, a mixed
+    step's one-token rows), between `_ret_front` and `wo`, every row
+    updated where its state rests (`retention_step_slots`: no [B, Hkv,
+    hd, F] copy of the rows' states exists). state: (ret_s [L, slots + 1,
+    Hkv, hd, F], ret_z [L, slots + 1, Hkv, F]), `l` this layer's index in
+    them; q [B, H, hd], k, v [B, Hkv, hd], log_g [B, Hkv]; valid [B]:
+    rows that are live (a finished or padding row, or one another form
+    takes, is a dead row to the kernel and writes nothing); fresh [B]:
+    the row starts its sequence, from zeros whatever its slot held (a
+    decode step has none). Returns (state, o [B, H, hd] float32)."""
+    ret_s, ret_z = state
+    with jax.named_scope("retention.step"):
+        o, ret_s, ret_z = retention_step_slots(
+            ret_s, ret_z, l, jnp.where(valid, slots, -1), q, k, v, log_g,
+            fresh)
+    return (ret_s, ret_z), o
+
+
+def ret_mix_rows(state: tuple, l, slots: jax.Array, lp: Params,
+                 cfg: ModelConfig, x, positions, rows: StepRows, valid,
+                 fresh, group: int = RET_GROUP_ROWS):
+    """A power-retention layer from the block's norm to the input of `wo`
+    for a [B, T] step, over the step's ROWS by what each holds, never
+    over its grid: `ssm_mix_rows` for a state that is the layer's whole
+    context, and the mixer's ONE form for a step. x [B * T, D]: the
+    step's token rows in either layout (`step_rows`); positions [B, T]:
+    the grid's (a row's real tokens are a prefix of it); state: (ret_s,
+    ret_z, o [B * T, H hd] in the model's dtype), `o` a scratch the
+    layers share: each real token's row is overwritten, no other row is
+    read.
+
+    A row of ONE token: its token is token row `start`, at its row's
+    first position; the front half over [B, D], then what a decode
+    window's step does (`ret_decode`). A row of more (a chunk row):
+    `group` of them at a time; the front half and `retention_chunk` run
+    over [group, T, ...] alone, padding at log g = 0 and k = v = 0 (an
+    exact no-op on the state); each row's state is sliced out of the
+    leaves at (layer, slot) and written back there, never gathered over
+    the leaf (`ssm_mix_rows` says why). Each kind is dead to the other.
+    `fresh` [B]: the row starts its sequence (position 0), from zeros
+    whatever the slot held. A row without a slot, and a row of padding,
+    write nothing. Returns (state, with o's real rows written)."""
+    ret_s, ret_z, o = state
+    tq = valid.shape[1]
+    n, n_slots = x.shape[0], ret_s.shape[1]
+    keep = ~fresh
+    one = rows.n_valid == 1
+    q, k, v, log_g = _ret_front(
+        x.at[rows.start].get(mode="clip")[:, None], lp, cfg,
+        positions[:, :1])
+    (ret_s, ret_z), o1 = ret_decode(
+        (ret_s, ret_z), l, slots, q[:, 0], k[:, 0], v[:, 0], log_g[:, 0],
+        one, fresh)
+    o = o.at[jnp.where(one, rows.start, n)].set(
+        o1.reshape(o1.shape[0], -1).astype(o.dtype), mode="drop")
+    long_at = jnp.where(rows.n_valid > 1, _slot_index(slots, n_slots), -1)
+
+    def chunk_group(j, carry):
+        o, ret_s, ret_z = carry
+        at, live, valid_g, keep_g, cells = _chunk_group(
+            rows, j, group, long_at, n_slots, valid, keep)
+        r = rows.order.at[j * group + jnp.arange(group)].get(
+            mode="fill", fill_value=0)
+        q, k, v, log_g = _ret_front(
+            x.at[cells].get(mode="clip"), lp, cfg,
+            positions.at[r].get(mode="clip"))
+        with jax.named_scope("retention.chunk"):
+            m = valid_g[:, :, None, None]
+            k, v = jnp.where(m, k, 0.0), jnp.where(m, v, 0.0)
+            log_g = jnp.where(valid_g[:, :, None], log_g, 0.0)
+            # a dead row's slot is out of range: the slice clamps to the
+            # leaves' last, scratch slot, whose content a dead row (an
+            # identity update) hands back as it found it
+            where = [(l, at[i], 0, 0) for i in range(group)]
+            s0 = jnp.concatenate([jax.lax.dynamic_slice(
+                ret_s, w + (0,), (1, 1) + ret_s.shape[2:])[0]
+                for w in where])
+            z0 = jnp.concatenate([jax.lax.dynamic_slice(
+                ret_z, w, (1, 1) + ret_z.shape[2:])[0] for w in where])
+            start = keep_g | ~live
+            o_g, s1, z1 = retention_chunk(
+                q, k, v, log_g,
+                jnp.where(start[:, None, None, None], s0, 0.0),
+                jnp.where(start[:, None, None], z0, 0.0))
+            for i, w in enumerate(where):
+                ret_s = jax.lax.dynamic_update_slice(
+                    ret_s, s1[i][None, None], w + (0,))
+                ret_z = jax.lax.dynamic_update_slice(
+                    ret_z, z1[i][None, None], w)
+        o = o.at[jnp.where(valid_g, cells, n).reshape(-1)].set(
+            o_g.reshape(group * tq, -1).astype(o.dtype), mode="drop")
+        return o, ret_s, ret_z
+
+    o, ret_s, ret_z = jax.lax.fori_loop(
+        0, -(-rows.n_long // group), chunk_group, (o, ret_s, ret_z))
+    return ret_s, ret_z, o
+
+
 def _layer_scan(*args, **kwargs):
     """`jax.lax.scan` over a model's layers (or its periods): what the
     loop itself does with its stacked operands (a layer's slice of every
@@ -1687,7 +1852,8 @@ def _layer_scan(*args, **kwargs):
         return jax.lax.scan(*args, **kwargs)
 
 # the scope of a layer's output projection `wo`, by the layer's kind
-_WO_SCOPE = {"conv": "shortconv.out_proj", "kda": "linattn.wo"}
+_WO_SCOPE = {"conv": "shortconv.out_proj", "kda": "linattn.wo",
+             "ret": "retention.out"}
 
 
 def layer_back(x: jax.Array, attn: jax.Array, lp: Params, cfg: ModelConfig,
@@ -1707,11 +1873,12 @@ def layer_back(x: jax.Array, attn: jax.Array, lp: Params, cfg: ModelConfig,
     gated norm [B, T, d_ssm]; its projection `ssm_out` is ADDED to
     attention's, each times its multiplier, before the one residual. A
     conv layer (`kind` "conv") hands over its gated convolution [B, T,
-    D], and `wo` is its `out_proj`."""
+    D], and `wo` is its `out_proj`; a power-retention layer (`kind`
+    "ret") its quotient [B, T, H hd], and `wo` is its `o_proj`."""
     b, t = x.shape[:2]
     if kind == "kda":
         attn = _kda_out(attn, x, lp, cfg)
-    elif kind == "conv":
+    elif kind in ("conv", "ret"):
         pass
     elif cfg.is_mla:
         attn = _mla_out(attn.reshape(b, t, cfg.num_heads, -1), lp, cfg)
@@ -1909,6 +2076,22 @@ def decode_forward(
         return (x, st), drop_stats if moe_aux else None
 
     @jax.named_scope("layers.body")
+    def ret_step_layer(carry, xs, run, expert_stacks):
+        """A power-retention layer: no cache row, its state moved on a
+        token where it rests."""
+        x, st = carry
+        lp, lid = xs
+        q, k, v, log_g = _ret_front(x, lp, cfg, positions[:, None])
+        st, o = ret_decode(st, run.store_index(lid), state[1], q[:, 0],
+                           k[:, 0], v[:, 0], log_g[:, 0], row_valid)
+        x, drop_stats = layer_back(
+            x, o.reshape(o.shape[0], 1, -1).astype(x.dtype), lp, cfg,
+            lambda xn, lp: _mlp_block(
+                xn, lp, cfg, mesh, token_valid, expert_stacks,
+                lid - run.first, run.dense), kind="ret")
+        return (x, st), drop_stats if moe_aux else None
+
+    @jax.named_scope("layers.body")
     def par_step_layer(carry, xs, run, expert_stacks):
         """A parallel block: the mixer's state update from the block's
         input, then `layer_step`, which adds its output to attention's."""
@@ -2006,12 +2189,12 @@ def decode_forward(
         scan_layers, expert_stacks = (params[name], None) if dense \
             else split_expert_stacks(params[name], cfg, mesh)
         part = _group_rows(whole, first, count)
-        if run.kind in ("kda", "conv"):
+        if run.kind in ("kda", "conv", "ret"):
             with _lead_scope(run):
                 (x, st), drop_g = _layer_scan(
                     functools.partial(
-                        kda_step_layer if run.kind == "kda"
-                        else conv_step_layer, run=run,
+                        {"kda": kda_step_layer, "conv": conv_step_layer,
+                         "ret": ret_step_layer}[run.kind], run=run,
                         expert_stacks=expert_stacks),
                     (x, st), (scan_layers, part(layer_ids)))
             drops.append(_sum_stats(drop_g))
@@ -2104,9 +2287,11 @@ def step_compaction(write_idx, sp_mesh=None) -> Optional[tuple]:
 def step_attention_rows(cfg: ModelConfig, chunk: int) -> bool:
     """ops/attention.attention_rows_pay for `cfg`'s attention layers in a
     compact step of `chunk` columns: for the program and for the host's
-    count of the steps that ran the row form."""
-    return attention_rows_pay(chunk, cfg.num_heads, sum(
-        h * w for h, w in cfg.kv_cache_leaves().values()))
+    count of the steps that ran the row form. Never for a model none of
+    whose layers attends to pages."""
+    leaves = cfg.kv_cache_leaves()
+    return bool(leaves) and attention_rows_pay(chunk, cfg.num_heads, sum(
+        h * w for h, w in leaves.values()))
 
 
 def forward(
@@ -2465,6 +2650,16 @@ def forward(
             x, drop_stats = either(functools.partial(back, stored=True),
                                    x, state[1])
             return (x, pool, state, wpool), drop_stats
+        if kind == "ret":
+            # a power-retention layer, whole, over the step's rows: its
+            # state in no `cond` (the branches of `back` read o's rows)
+            state = ret_mix_rows(
+                state, sl, meta.state_slots, lp_of(), cfg,
+                x.reshape(n, -1), meta.positions, kda_plan, grid_valid,
+                meta.positions[:, 0] == 0)
+            x, drop_stats = either(functools.partial(back, stored=True),
+                                   x, state[2])
+            return (x, pool, state, wpool), drop_stats
         if kind == "par":
             # a parallel block's mixer, whole, over the step's rows, from
             # the block's input: no [B, Tq] tensor of its width, and the
@@ -2546,6 +2741,8 @@ def forward(
                   if cfg.has_ssm else
                   jnp.zeros((n, cfg.hidden_size), _dtype(cfg))
                   if cfg.has_conv else
+                  jnp.zeros((n, cfg.num_heads * cfg.head_dim), _dtype(cfg))
+                  if cfg.has_retention else
                   jnp.zeros((n, cfg.num_heads, cfg.linear_head_dim),
                             jnp.float32),)
     drops = []

@@ -36,6 +36,7 @@ ARCHES = {
     "FalconH1ForCausalLM": "falcon_h1",
     "Lfm2ForCausalLM": "lfm2",
     "Lfm2MoeForCausalLM": "lfm2_moe",
+    "BrumbyForCausalLM": "brumby",
 }
 # the families whose sliding layers are served as WINDOWS, from a page
 # pool of their own (`window_pool`): their serving length is not capped
@@ -58,7 +59,8 @@ def config_from_hf(hf: Dict[str, Any], name: str = "") -> ModelConfig:
            "mellum": mellum_fields,
            "afmoe": afmoe_fields,
            "falcon_h1": falcon_h1_fields,
-           "lfm2": lfm2_fields, "lfm2_moe": lfm2_fields}.get(
+           "lfm2": lfm2_fields, "lfm2_moe": lfm2_fields,
+           "brumby": brumby_fields}.get(
                family, lambda hf: {})(hf)
     if hf.get("clip_qkv") is not None:
         # OLMoE's optional clamp of q/k/v to +-clip_qkv is not modeled:
@@ -542,6 +544,24 @@ def lfm2_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
         moe_routed_scale=float(hf.get("routed_scaling_factor", 1.0)))
 
 
+def brumby_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """The ModelConfig fields of a `brumby` config.json (Brumby-14B-Base):
+    Qwen3's block (head-wise QK-norm, RoPE at `rope_theta`, no bias) with
+    every layer's attention replaced by power retention. The published
+    file carries no key of the mixer itself: its degree (2) and its gate
+    are what benchmark/configs/brumby-14b/meta.json lists under
+    `assumed`; a file that does name a degree must name 2. It keeps
+    Qwen2's window keys, switched off (`use_sliding_window` false,
+    `max_window_layers` = all): a window over a state that holds the
+    whole context is not modelled, and a file that asks for one is
+    refused."""
+    refuse = _refuser(hf)
+    refuse("use_sliding_window", lambda v: not v, "false")
+    for key in ("retention_degree", "power_degree", "degree"):
+        refuse(key, lambda v: v in (None, 2), "2")
+    return dict(qk_norm="head", retention_degree=2)
+
+
 def rope_params(entry: Dict[str, Any], hf: Dict[str, Any]):
     """One entry of `rope_parameters` -> RopeParams. Plain RoPE
     ("default") and YaRN are modelled; longrope, llama3, linear and
@@ -624,6 +644,10 @@ def load_params_from_hf(path: str, cfg: ModelConfig,
       .mamba.{A_log,D,dt_bias}           -> ssm_a_log / ssm_d /
                                             ssm_dt_bias[i], float32
       .mamba.norm.weight / .out_proj.weight.T -> ssm_norm[i] / ssm_out[i]
+    Brumby (Qwen3's names; the head norms as OLMoE's, one weight of
+    head_dim each; the gate's name assumed):
+      .self_attn.g_proj.weight.T / .bias -> ret_wg[i] / ret_bg[i] (float32;
+                                            zeros where the file has none)
     """
     if cfg.linear_group_size or cfg.window_pool:
         # the catalog gives these families' configs and no tensor names:
@@ -713,6 +737,20 @@ def load_params_from_hf(path: str, cfg: ModelConfig,
                              ("ssm_dt_bias", "dt_bias")):
             layers[ours] = stack(lambda i, p=theirs: np.asarray(
                 raw[mamba.format(i) + p], np.float32))
+    if cfg.has_retention:
+        # Qwen3's names for everything else; the gate's are ASSUMED
+        # (`g_proj`, a Linear to one logit a key-value head, its bias
+        # optional): the catalog gives the config and no tensor names
+        gate = "model.layers.{}.self_attn.g_proj."
+        if gate.format(0) + "weight" not in raw:
+            raise ValueError(
+                f"{cfg.name}: no tensor {gate.format(0)}weight: the "
+                f"power-retention gate's projection is looked for under "
+                f"that (assumed) name, [num_key_value_heads, hidden_size]")
+        layers["ret_wg"] = stack(lambda i: t(gate.format(i) + "weight"))
+        layers["ret_bg"] = stack(lambda i: np.asarray(
+            raw.get(gate.format(i) + "bias",
+                    np.zeros(cfg.num_kv_heads)), np.float32))
     if cfg.post_norms:
         layers["post_attn_norm"] = stack(
             lambda i: w(f"model.layers.{i}.post_attention_layernorm.weight"))

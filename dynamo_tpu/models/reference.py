@@ -232,6 +232,37 @@ checkpoint calls `embedding_norm`; the head is the embedding table.
               constant is `renorm_eps`) x `routed_scaling_factor`; no
               shared expert, no groups.
 
+And from the row `Brumby-14B-Base` of the architecture catalog
+(https://huggingface.co/manifestai/Brumby-14B-Base/blob/main/config.json,
+`model_type` brumby: Qwen3-14B's block with every layer's attention
+replaced by power retention; the mixer is the gated power retention of
+"Scaling Context Requires Rethinking Attention", arXiv:2507.04239, at
+degree 2. The config carries no key of the mixer: its degree, its gate and
+what it keeps of Qwen3's front are benchmark/configs/brumby-14b/meta.json's
+`assumed`; benchmark/reference/brumby.py is the benchmark's copy of these
+lines). Pre-norm residual block, plain RMSNorm, eps 1e-6, dense SwiGLU,
+final norm, untied head.
+
+  mixer       (`attention_retention`, the masked QUADRATIC form: no state,
+              no chunks, no features) q = xn Wq, k = xn Wk, v = xn Wv, no
+              bias; RMSNorm over each head's values of q and of k, one
+              weight of head_dim a projection; rotate-half RoPE at
+              `rope_theta` on q and k; log g = log_sigmoid(xn Wg + bg), one
+              scalar a key-value head and token. For query head h of
+              key-value head c = h // (H / Hkv): w[t, i] = exp(sum_{l =
+              i + 1 .. t} log g_l[c]) (q_t[h] . k_i[c])^2 for i <= t; o_t[h]
+              = sum_i w[t, i] v_i[c] / sum_i w[t, i]: every weight >= 0, no
+              softmax, no scale on q . k (it cancels), the plain sum below
+              the line (the served path adds 1e-12 to it). Then Wo, no
+              output gate.
+  the served form  (ops/power_retention.py, the same function as a
+              recurrence over phi, phi(x) . phi(y) = (x . y)^2):
+              `retention_features` is phi in the layout the served state
+              holds, written as plain index arithmetic, and
+              `retention_state` the state S [Hkv, hd, F], z [Hkv, F] after
+              a sequence by the per-token recurrence: what a served
+              sequence's slot is compared with.
+
 Departures from the published model, each deliberate:
   * weights are taken in this repo's layout: projections stored
     [in, out] (the checkpoint's are [out, in]; models/loader.py
@@ -463,6 +494,76 @@ def attention_kda(x, lp, *, num_heads, head_dim, lower_bound, rms_norm_eps,
     return o.reshape(t, h * d) @ lp["wo"]
 
 
+def retention_features(x):
+    """phi(x) [..., F] of x [..., d], phi(x) . phi(y) = (x . y)^2, in the
+    layout the served state holds: feature s d + a is c_s x[a] x[(a + s)
+    mod d] for s = 0 .. d / 2, c = 1 for the squares (s = 0) and for s =
+    d / 2 (each of those pairs stands twice), sqrt 2 between."""
+    d = x.shape[-1]
+    s = jnp.arange(d // 2 + 1)[:, None]
+    a = jnp.arange(d)[None, :]
+    coef = jnp.where((s == 0) | (s == d // 2), 1.0, math.sqrt(2.0))
+    return (coef * x[..., None, :] * x[..., (a + s) % d]).reshape(
+        x.shape[:-1] + (-1,))
+
+
+def retention_state(k, v, log_g, state_dtype=F32):
+    """The power-retention state after a sequence, by the per-token
+    recurrence: k, v [T, Hkv, hd] (k normed and rotated), log_g [T, Hkv]
+    -> (S [Hkv, hd, F], z [Hkv, F]): S_t = g_t S_{t-1} + v_t phi(k_t)^T,
+    z_t = g_t z_{t-1} + phi(k_t). `state_dtype`: what both are rounded to
+    after every token (float32: not at all)."""
+    def step(carry, xs):
+        s, z = carry
+        k_t, v_t, g_t = xs
+        pk = retention_features(k_t)
+        g_t = jnp.exp(g_t)
+        s = g_t[:, None, None] * s + v_t[:, :, None] * pk[:, None, :]
+        z = g_t[:, None] * z + pk
+        return (round_to(s, state_dtype), round_to(z, state_dtype)), None
+    hkv, hd = k.shape[1:]
+    f = hd * (hd // 2 + 1)
+    return jax.lax.scan(step, (jnp.zeros((hkv, hd, f), F32),
+                               jnp.zeros((hkv, f), F32)),
+                        (k, v, log_g))[0]
+
+
+def attention_retention(x, lp, *, num_heads, num_kv_heads, head_dim,
+                        rope_theta, rms_norm_eps, softmax=False,
+                        tails=None):
+    """Gated power retention at degree 2 as the masked quadratic form
+    (module docstring). x [T, D], the normed input. `tails`: a list that
+    takes this layer's state after the sequence, (S, z) of
+    `retention_state`. `softmax`: Qwen3's own mixer behind the same front
+    (causal softmax at head_dim ** -0.5, no gate), which is how the front
+    is held to `transformers` (tests/test_brumby.py)."""
+    t, hkv = x.shape[0], num_kv_heads
+    q = rms_norm((x @ lp["wq"]).reshape(t, num_heads, head_dim),
+                 lp["q_norm"], rms_norm_eps)
+    k = rms_norm((x @ lp["wk"]).reshape(t, hkv, head_dim), lp["k_norm"],
+                 rms_norm_eps)
+    v = (x @ lp["wv"]).reshape(t, hkv, head_dim)
+    positions = jnp.arange(t)
+    q, k = rope(q, positions, rope_theta), rope(k, positions, rope_theta)
+    causal = positions[None, :] <= positions[:, None]          # [q, k]
+    qk = jnp.einsum("qcgd,kcd->cgqk",
+                    q.reshape(t, hkv, num_heads // hkv, head_dim), k)
+    if softmax:
+        w = jax.nn.softmax(jnp.where(causal, qk * head_dim ** -0.5,
+                                     -jnp.inf), axis=-1)
+    else:
+        log_g = jax.nn.log_sigmoid(x @ lp["ret_wg"] + lp["ret_bg"])
+        if tails is not None:
+            tails.append(retention_state(k, v, log_g))
+        gc = jnp.cumsum(log_g, axis=0).T                       # [Hkv, T]
+        decay = jnp.exp(jnp.where(causal, gc[:, :, None] - gc[:, None, :],
+                                  -jnp.inf))                   # [Hkv, q, k]
+        w = decay[:, None] * qk * qk
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    out = jnp.einsum("cgqk,kcd->qcgd", w, v)
+    return out.reshape(t, num_heads * head_dim) @ lp["wo"]
+
+
 def ssm_recurrence(consts, s, xs, state_dtype=F32):
     """One token of the state-space scan: (consts (A, D [H]), s [H, P,
     N], (x [H, P], dt [H], b, c [H, N])) -> (s', y [H, P]): decay, the
@@ -594,7 +695,9 @@ def layer(x, lp, *, num_heads, num_kv_heads, head_dim, rope_theta,
     `parallel_block`'s arguments after `attn`, for a layer with a
     state-space mixer beside its attention (`key_multiplier` among
     them, which is attention's). A layer with a `conv_in` leaf is a
-    gated short convolution (`short_conv`, which `tails` goes to)."""
+    gated short convolution (`short_conv`, which `tails` goes to); one
+    with a `ret_wg` leaf is power retention (`attention_retention`,
+    likewise)."""
     def post(out, name):
         return rms_norm(out, lp[name], rms_norm_eps) if name in lp else out
     xn = rms_norm(x, lp["attn_norm"], rms_norm_eps)
@@ -608,6 +711,11 @@ def layer(x, lp, *, num_heads, num_kv_heads, head_dim, rope_theta,
                 key_multiplier=par.pop("key_multiplier")), **par)
     elif "conv_in" in lp:
         out = short_conv(xn, lp, tails)
+    elif "ret_wg" in lp:
+        out = attention_retention(
+            xn, lp, num_heads=num_heads, num_kv_heads=num_kv_heads,
+            head_dim=head_dim, rope_theta=rope_theta,
+            rms_norm_eps=rms_norm_eps, tails=tails)
     elif "kda_wqkv" in lp:
         out = attention_kda(xn, lp, num_heads=num_heads,
                             rms_norm_eps=rms_norm_eps, **kda)

@@ -54,6 +54,9 @@ SCOPES = (
     # a gated short convolution
     "shortconv.in_proj", "shortconv.gate", "shortconv.taps",
     "shortconv.out_proj",
+    # power retention: a matrix state a key-value head, no pages
+    "retention.front", "retention.phi", "retention.step", "retention.chunk",
+    "retention.out",
 )
 
 

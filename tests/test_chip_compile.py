@@ -163,6 +163,53 @@ def test_the_ssd_state_update_compiles_for_a_v5e_and_moves_no_copy_of_the_leaf(
     assert _SSM_MOVES.findall(_ssm_layers_hlo(one_chip, "plain")) != []
 
 
+# brumby-14b's matrix leaf at 8 layers and 18 slots (16 + 1 + scratch)
+_RET_STATE = (8, 18, 8, 128, 8320)
+_RET_MOVES = re.compile(
+    r"=\s*f32\[(?:8,18|16),8,128,8320\]\S*\s+"
+    r"(copy|fusion|gather|scatter|dynamic-slice|dynamic-update-slice)\(")
+
+
+def _retention_layers_hlo(one_chip, impl: str) -> str:
+    """Optimised HLO of a scan over the eight layers' one-token state
+    updates of a 16-row decode step that carries both state leaves, as
+    `decode_forward` does: `retention_step_slots` by the slot-addressed
+    kernel ("pallas"), or by `retention_step` on gathered rows
+    ("plain")."""
+    from dynamo_tpu.ops import power_retention as pr
+
+    def arr(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    l, s, hkv, d, f = _RET_STATE
+
+    def layers(ret_s, ret_z, slots, q, k, v, log_g):
+        def body(carry, at):
+            o, ret_s, ret_z = pr.retention_step_slots(
+                *carry, at, slots, q, k, v, log_g, impl=impl)
+            return (ret_s, ret_z), o
+        return jax.lax.scan(body, (ret_s, ret_z), jnp.arange(l))
+    return jax.jit(layers, donate_argnums=(0, 1)).lower(
+        arr(*_RET_STATE), arr(l, s, hkv, f), arr(16, dtype=jnp.int32),
+        arr(16, 40, d), arr(16, hkv, d), arr(16, hkv, d), arr(16, hkv)
+    ).compile().as_text()
+
+
+def test_the_retention_state_update_compiles_for_a_v5e_and_moves_no_copy(
+        one_chip):
+    """`ops/power_retention.retention_step_slots` at brumby-14b's served
+    shape is taken by the chip's compiler (a block of 2 heads x 1664
+    features of [128, F] float32, in and out double-buffered, under the
+    VMEM limit the call names), the 4.9 GB leaf aliased through it: no op
+    copies, gathers or scatters the leaf or a [16, 8, 128, 8320] copy of
+    the rows' states (545 MB a layer, which is what does not fit beside
+    it); the gather / update / scatter form is caught doing so."""
+    hlo = _retention_layers_hlo(one_chip, "pallas")
+    assert hlo.count("tpu_custom_call") >= 1
+    assert "retention_step_slots" in hlo
+    assert _RET_MOVES.findall(hlo) == []
+    assert _RET_MOVES.findall(_retention_layers_hlo(one_chip, "plain")) != []
+
+
 # a [64, 64] mixed step of ling-3.0-flash-vl: 3 chunk rows beside 60 decode
 # rows, 4096 cells of which 252 hold a token
 _GRID_WIDE = re.compile(r"=\s*f32\[(?:64,64|4096),(?:12288|32,128)\]\S*\s+"

@@ -44,7 +44,8 @@ TINY = {"dense": "rehearsal-tiny", "moe": "rehearsal-tiny-olmoe",
         "window": "rehearsal-tiny-mellum", "linear": "rehearsal-tiny-ling",
         "lead+window": "rehearsal-tiny-trinity",
         "state-space": "rehearsal-tiny-falcon-h1",
-        "conv": "rehearsal-tiny-lfm2"}
+        "conv": "rehearsal-tiny-lfm2",
+        "retention": "rehearsal-tiny-brumby"}
 
 
 def _sub_jaxprs(eqn):
@@ -142,7 +143,7 @@ def test_every_equation_lies_in_a_scope_of_the_list(kind, program):
     inside a layer body, the body's scope is on the stack."""
     name = TINY[kind]
     # 16 rows: where a recurrent-state model splits a step's rows
-    rows = 16 if kind in ("linear", "state-space") else 8
+    rows = 16 if kind in ("linear", "state-space", "retention") else 8
     eqns = _walk(served_jaxprs(name, rows)[program].jaxpr)
     assert len(eqns) > 300
     strangers = collections.Counter(
@@ -175,7 +176,8 @@ def test_every_named_scope_of_the_package_is_on_the_list():
             with open(os.path.join(root, name)) as f:
                 text = f.read()
             found |= set(re.findall(r'named_scope[,(]\s*"([^"]+)"', text))
-            for line in re.findall(r"_WO_SCOPE(?: = \{|\.get\()[^\n]*", text):
+            for line in re.findall(
+                    r"_WO_SCOPE(?: = \{[^}]*|\.get\([^\n]*)", text):
                 found |= set(re.findall(r'"([a-z]+\.[a-z_.]+)"', line))
     assert found - set(SCOPES) == set()
     assert set(SCOPES) - found == set()

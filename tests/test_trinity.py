@@ -576,6 +576,13 @@ SERVED_PROGRAMS = {
     # cache, which PR 53 took up) are untouched
     ("lfm2-8b-a1b", 8, 64, "step"): "58702a7655d63973",
     ("lfm2-8b-a1b", 8, 64, "window"): "f697e06a037af8e9",
+    # PR 55: Brumby at its cell's shape (16 rows beside a 256-token
+    # chunk), this tree's own (the parent cannot trace it): one layer
+    # body of kind "ret", no pool leaf among the operands, a window
+    # without base, buffer or writeback. Every digest above stands: no
+    # older program holds a line of the new kind
+    ("brumby-14b", 16, 256, "step"): "defdcacca2ddb221",
+    ("brumby-14b", 16, 256, "window"): "acca635cef6b4aa6",
 }
 
 

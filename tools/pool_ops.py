@@ -168,10 +168,13 @@ def _devices():
     # branch the chip takes (ops/moe.py's grouped matmul kernel, the
     # slot-addressed state updates of ops/linear_attention.py and
     # ops/state_space.py)
-    from dynamo_tpu.ops import linear_attention, moe, state_space
+    from dynamo_tpu.ops import (
+        linear_attention, moe, power_retention, state_space,
+    )
     moe.grouped_matmul_impl = lambda: "gmm"
     linear_attention.kda_step_slots_impl = lambda: "pallas"
     state_space.ssd_step_slots_impl = lambda: "pallas"
+    power_retention.retention_step_slots_impl = lambda: "pallas"
     return (list(topo.devices),
             f"described {topo.devices[0].device_kind} (v5e:2x2), no chip")
 
@@ -202,6 +205,8 @@ def build_programs(config_dir: str, rows: int, chunk: int, pages: int,
         serve = dict(zip(flags[::2], flags[1::2]))
     ecfg = EngineConfig()
     num_pages = int(serve.get("--num-pages", ecfg.num_pages))
+    prefill_batch = int(serve.get("--max-prefill-batch",
+                                  ecfg.max_prefill_batch))
     tp = int(serve.get("--tp", 1))
     # the pool's rows as NativeEngine resolves them for this mesh
     cfg = with_kv_rows(cfg, tp)
@@ -243,7 +248,7 @@ def build_programs(config_dir: str, rows: int, chunk: int, pages: int,
         # slot a decode row and one a row of a prefill batch
         cache.update(abstract(
             jax.eval_shape(lambda: llama.init_state(
-                cfg, rows + ecfg.max_prefill_batch)),
+                cfg, rows + prefill_batch)),
             {name: PartitionSpec() for name in cfg.state_leaves()}))
 
     def with_slots(fn, names=()):
@@ -257,9 +262,19 @@ def build_programs(config_dir: str, rows: int, chunk: int, pages: int,
             params, cache, *args[:-len(names)],
             **dict(zip(names, args[-len(names):])))
 
-    # what ONE device holds of one layer's K pool
-    layer_pool = (cache["k"].size // cfg.num_cache_layers
-                  * cache["k"].dtype.itemsize // tp)
+    # what ONE device holds of one layer's K pool; for a model none of
+    # whose layers holds a page, of one layer's share of its largest
+    # state leaf, whose (slots, heads) axes then stand where the pool's
+    # (pages, page size) do: an op "on the pool" is one on that leaf
+    page_axis = (num_pages, ecfg.page_size)
+    if cfg.num_cache_layers:
+        layer_pool = (cache["k"].size // cfg.num_cache_layers
+                      * cache["k"].dtype.itemsize // tp)
+    else:
+        leaf = max((cache[name] for name in cfg.state_leaves()),
+                   key=lambda a: a.size)
+        layer_pool = leaf.size // leaf.shape[0] * leaf.dtype.itemsize
+        page_axis = leaf.shape[1:3]
     if tp > 1:
         target += f", --tp {tp}"
     f32 = jnp.float32
@@ -291,7 +306,7 @@ def build_programs(config_dir: str, rows: int, chunk: int, pages: int,
         window_args += (arr((rows, wtable(1))), vec)
     return ([(f"jit_engine_step[{rows},{chunk}]", step, step_args),
              (f"jit_engine_decode_window_full[{rows}x{nw}]", window,
-              window_args)], layer_pool, (num_pages, ecfg.page_size), target)
+              window_args)], layer_pool, page_axis, target)
 
 
 def main(argv=None) -> int:
